@@ -1,0 +1,703 @@
+#!/usr/bin/env python3
+"""Chip smoke: the quickest proof that the two main paths still start on
+the chip.
+
+    python chip_smoke.py            # one TPU chip: trainer, then server
+    python chip_smoke.py --chips 4  # four chips: ONLY the cross-chip paths
+
+Default run, ONE process (the trainer's buffers are freed before the
+server starts), at the full width of the 1.345B dense block (vocab
+32000, hidden 2048, ffn 5504, 16 heads x 128, bf16; weights random from
+``--seed``; depth printed):
+
+* trainer — ``build_mesh`` -> ``init_params`` -> ``init_adafactor_state``
+  -> ``make_train_step(optimizer="adafactor")``, b=8 x s=2048, full
+  remat, Pallas flash attention, tokens fed by ``paddle_tpu.io.DataLoader``
+  (2 spawned workers, shm).  Loss finite on every step, the first near
+  ln(vocab), falling when the last steps repeat one batch; the compiled
+  step contains the flash kernel's ``tpu_custom_call``.
+* server — ``GenerationServer(cfg, params, PagedKVCache(...))`` with the
+  default engine options, a page pool that is a real share of HBM, real
+  HTTP ``/generate`` + ``/generate_stream`` + ``/metrics``.  The fixed
+  prompt goes alone; the other seven are queued in a fixed order while
+  the engine thread is held, so they prefill as ONE packed wave and
+  every run compiles the same programs (a second run compiles none).
+  Every request returns the token count asked for; the fixed prompt's first
+  16 greedy tokens equal a plain non-paged recompute on the same device
+  (near-tie rule below); the compiled decode step and packed prefill
+  contain a ``tpu_custom_call``; ``flash_varlen.dense_fallback_count``
+  stays 0.
+
+``--chips 4`` runs only (a) the ``mp=4`` TP engine vs the one-chip engine
+on device 0 and (b) the ``dp2 x mp2`` sequence-parallel train step vs the
+one-device step, then checks every device holds a real share of bytes.
+
+Near-tie rule (bf16 on the chip; the repo's token-exact pins are float32
+on CPU): at a first divergence inside the 16 compared tokens the run
+passes only if the reference's top-2 logit margin there is within
+``MARGIN_TOL`` (two bf16 steps: the logits are bf16 and one step is
+2**-5 at their top values, 4 <= |logit| < 8) AND the other side picked
+one of the reference's top two; the margin is printed.  No tolerance on
+shapes, counts or completion.
+
+There is no CPU mode: without a TPU this exits non-zero at the platform
+check.  The LAST line of stdout is the device line the driver reads;
+everything else (step times, depth, compile seconds, cache directory,
+HBM in use, DataLoader transport) is printed before it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import math
+import sys
+import threading
+import time
+
+MARGIN_TOL = 0.0625      # logit units, reference top-1 minus top-2:
+#                          two bf16 steps of 2**-5
+FIRST_LOSS_TOL = 0.02    # |loss0 - (ln V + 0.5)| / ln V  (unit-variance
+#                          random logits sit ~sigma^2/2 above ln V)
+SP_LOSS_TOL = 0.01       # relative, dp2 x mp2 + SP vs one device
+COMPARE_TOKENS = 16
+MIN_BYTES_PER_DEVICE = 64 << 20
+# text a Mosaic-compiled kernel leaves in the compiled program
+KERNEL_MARKER = "tpu_custom_call"
+
+
+class SyntheticTokens:
+    """Module-level (picklable -> spawned workers) synthetic token
+    dataset, per-index seeded.  Each fetch also asserts the WORKER has
+    not initialised a jax backend: on the chip a second initialiser
+    fails or hangs instead of quietly getting a CPU."""
+
+    def __init__(self, n, seq, vocab, seed):
+        self.n, self.seq, self.vocab, self.seed = n, seq, vocab, seed
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, i):
+        import numpy as np
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "a DataLoader worker initialised a jax backend")
+        rng = np.random.RandomState(self.seed * 100003 + i)
+        return rng.randint(0, self.vocab,
+                           (self.seq + 1,)).astype(np.int64)
+
+
+@dataclasses.dataclass
+class SmokeSizes:
+    """Everything the phases size themselves by.  ``main`` builds the
+    real one; a CPU rehearsal script builds a toy one and drives the
+    same phase functions (this file has no CPU mode of its own)."""
+    vocab: int = 32000
+    hidden: int = 2048
+    ffn: int = 5504
+    heads: int = 16
+    kv_heads: int = 16
+    depth: int = 24
+    batch: int = 8
+    seq: int = 2048
+    fresh_steps: int = 4
+    repeat_steps: int = 4
+    loss_chunks: int = 4
+    # server
+    page: int = 64
+    num_pages: int = 384
+    pages_max: int = 32
+    slots: int = 32
+    # (prompt length, max_new_tokens); entry 0 is the fixed prompt
+    requests: tuple = ((37, 16), (5, 24), (64, 8), (100, 32), (200, 16),
+                       (333, 12), (17, 20), (500, 16))
+    # four-chip phases
+    depth4: int = 8
+    num_pages4: int = 256
+    seed: int = 0
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+class CompileClock:
+    """Sums jax's own compile-time events: backend compile seconds and
+    persistent-cache hits/misses for everything this process compiles."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.backend_s = 0.0
+        self.hits = self.misses = 0
+        mon.register_event_duration_secs_listener(self._dur)
+        mon.register_event_listener(self._ev)
+
+    def _dur(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.backend_s += secs
+
+    def _ev(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snap(self):
+        return (self.backend_s, self.hits, self.misses)
+
+    def since(self, snap) -> str:
+        return (f"compile_s={self.backend_s - snap[0]:.1f} "
+                f"cache_hits={self.hits - snap[1]} "
+                f"cache_misses={self.misses - snap[2]}")
+
+
+def _model_cfg(sz: SmokeSizes, depth: int, train: bool,
+               sequence_parallel: bool = False):
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama_pretrain import LlamaPretrainConfig
+    return LlamaPretrainConfig(
+        vocab_size=sz.vocab, hidden_size=sz.hidden,
+        intermediate_size=sz.ffn, num_hidden_layers=depth,
+        num_attention_heads=sz.heads, num_key_value_heads=sz.kv_heads,
+        max_seq_len=sz.seq, use_pallas_attention=True,
+        sequence_parallel=sequence_parallel, remat=train,
+        remat_policy="full", dtype=jnp.bfloat16,
+        param_dtype=jnp.float32 if train else jnp.bfloat16,
+        loss_chunks=sz.loss_chunks if train else 0)
+
+
+def _hbm(devices) -> list:
+    out = []
+    for d in devices:
+        st = d.memory_stats()
+        out.append(None if st is None else int(st["bytes_in_use"]))
+    return out
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+def _has_kernel(text: str, what: str) -> None:
+    n = text.count(KERNEL_MARKER)
+    _require(n > 0, f"{what}: no {KERNEL_MARKER} in the compiled text")
+    log(f"{what}: {n} x {KERNEL_MARKER} in the compiled text")
+
+
+# ---------------------------------------------------------------------------
+# reference: plain (non-paged, XLA attention) logits with the package's
+# own block math, for the near-tie margin
+# ---------------------------------------------------------------------------
+def _plain_logits(cfg, params, tokens):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama_pretrain import (_mm, _rms_norm,
+                                                  _trunk_scan)
+    pcfg = dataclasses.replace(cfg, use_pallas_attention=False,
+                               remat=False)
+
+    @jax.jit
+    def run(params, toks):
+        x = jnp.take(params["embed"], toks, axis=0).astype(cfg.dtype)
+        x = _trunk_scan(params["blocks"], x, pcfg, None)
+        h = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return _mm(h, params["lm_head"], cfg.dtype).astype(jnp.float32)
+
+    return run(params, tokens)
+
+
+def _check_tokens(what, cfg, params, prompt, ref, got) -> None:
+    """``got`` vs ``ref`` over the first COMPARE_TOKENS greedy tokens,
+    with the near-tie rule of the module docstring.  ``params`` must
+    live on ONE device (the reference's)."""
+    import jax.numpy as jnp
+    import numpy as np
+    ref = [int(t) for t in ref][:COMPARE_TOKENS]
+    got = [int(t) for t in got][:COMPARE_TOKENS]
+    _require(len(ref) == len(got) == COMPARE_TOKENS,
+             f"{what}: need {COMPARE_TOKENS} tokens on both sides, have "
+             f"{len(ref)} / {len(got)}")
+    seq = np.concatenate([np.asarray(prompt, np.int64),
+                          np.asarray(ref[:-1], np.int64)])
+    # causal: right-padding to a power of two changes no earlier
+    # position and keeps the number of compiled shapes small
+    padded = np.zeros((max(64, 1 << (len(seq) - 1).bit_length()),),
+                      np.int64)
+    padded[:len(seq)] = seq
+    logits = np.asarray(_plain_logits(
+        cfg, params, jnp.asarray(padded[None])))[0, :len(seq)]
+    # position p predicts token p+1: generated token t sits at
+    # len(prompt) - 1 + t
+    rows = logits[len(prompt) - 1:]
+    top2 = np.sort(rows, axis=-1)[:, -2:]
+    margins = top2[:, 1] - top2[:, 0]
+    log(f"{what}: reference top-2 margins over {COMPARE_TOKENS} tokens: "
+        f"min {margins.min():.4f} median {np.median(margins):.4f}")
+    for t, (r, g) in enumerate(zip(ref, got)):
+        if r == g:
+            continue
+        best2 = [int(i) for i in np.argsort(rows[t])[-2:]]
+        log(f"{what}: first divergence at token {t}: ref {r} got {g}; "
+            f"reference top-2 margin {margins[t]:.4f} (tolerance "
+            f"{MARGIN_TOL}), reference top-2 tokens {best2}")
+        _require(margins[t] <= MARGIN_TOL and g in best2,
+                 f"{what}: tokens diverge at {t} beyond a near-tie")
+        log(f"{what}: near-tie divergence tolerated; tokens before it "
+            f"equal")
+        return
+    log(f"{what}: all {COMPARE_TOKENS} greedy tokens equal")
+
+
+# ---------------------------------------------------------------------------
+# phase 1: trainer
+# ---------------------------------------------------------------------------
+def _token_loader(sz: SmokeSizes, n_batches: int):
+    from paddle_tpu.io import DataLoader
+    return DataLoader(
+        SyntheticTokens(n_batches * sz.batch, sz.seq, sz.vocab, sz.seed),
+        batch_size=sz.batch, num_workers=2, use_shared_memory=True)
+
+
+def _next_tokens(it):
+    import jax.numpy as jnp
+    import numpy as np
+    b = next(it)
+    return jnp.asarray(b.numpy() if hasattr(b, "numpy") else np.asarray(b))
+
+
+def _check_first_loss(sz: SmokeSizes, loss0: float) -> None:
+    lnv = math.log(sz.vocab)
+    log(f"trainer: first loss {loss0:.4f}; ln(vocab) = {lnv:.4f}")
+    _require(abs(loss0 - (lnv + 0.5)) / lnv <= FIRST_LOSS_TOL,
+             f"first loss {loss0} is not within {FIRST_LOSS_TOL:.0%} of "
+             f"ln(vocab)+0.5 = {lnv + 0.5:.3f}")
+
+
+def phase_trainer(sz: SmokeSizes, clock: CompileClock) -> None:
+    import jax
+    from paddle_tpu.models.llama_pretrain import (
+        build_mesh, init_adafactor_state, init_params, make_train_step)
+
+    cfg = _model_cfg(sz, sz.depth, train=True)
+    log(f"trainer: depth {cfg.num_hidden_layers} hidden {cfg.hidden_size} "
+        f"heads {cfg.num_attention_heads}x{cfg.head_dim} ffn "
+        f"{cfg.intermediate_size} vocab {cfg.vocab_size} b={sz.batch} "
+        f"s={sz.seq} full remat, adafactor")
+    loader = _token_loader(sz, sz.fresh_steps + 1)
+    it = iter(loader)
+    mesh = build_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1,
+                      devices=jax.devices()[:1])
+    with mesh:
+        params = init_params(cfg, jax.random.PRNGKey(sz.seed), mesh, pp=1)
+        opt_state = init_adafactor_state(params)
+        step = make_train_step(cfg, mesh, pp=1, microbatches=1, lr=1e-2,
+                               optimizer="adafactor")
+        tokens = _next_tokens(it)
+        log(f"trainer: DataLoader transport = {loader.transport}")
+        _require(loader.transport == "shm",
+                 "shared-memory transport was asked for and not got "
+                 f"(live transport: {loader.transport})")
+        snap = clock.snap()
+        t0 = time.perf_counter()
+        compiled = step.lower(params, opt_state, tokens).compile()
+        log(f"trainer: step compiled in {time.perf_counter() - t0:.1f}s "
+            f"({clock.since(snap)})")
+        ma = compiled.memory_analysis()
+        if ma is not None:
+            log("trainer: compiled memory: args "
+                f"{ma.argument_size_in_bytes / 2**30:.2f} GiB, temp "
+                f"{ma.temp_size_in_bytes / 2**30:.2f} GiB, out "
+                f"{ma.output_size_in_bytes / 2**30:.2f} GiB, aliased "
+                f"{ma.alias_size_in_bytes / 2**30:.2f} GiB")
+        _has_kernel(compiled.as_text(), "trainer step")
+
+        losses = []
+        for i in range(sz.fresh_steps + sz.repeat_steps):
+            if 0 < i < sz.fresh_steps:
+                tokens = _next_tokens(it)       # else: repeat the last
+            t0 = time.perf_counter()
+            params, opt_state, loss = compiled(params, opt_state, tokens)
+            loss = float(loss)                  # fence
+            dt = time.perf_counter() - t0
+            losses.append(loss)
+            kind = "fresh" if i < sz.fresh_steps else "repeat"
+            log(f"trainer: step {i} ({kind} batch) loss {loss:.4f} "
+                f"{dt * 1e3:.0f} ms")
+            _require(math.isfinite(loss), f"loss at step {i} is {loss}")
+    log(f"trainer: HBM bytes in use {_hbm(jax.devices()[:1])}")
+    _check_first_loss(sz, losses[0])
+    rep = losses[sz.fresh_steps - 1:]
+    _require(rep[-1] < rep[0],
+             f"loss did not fall on the repeated batch: {rep}")
+    log(f"trainer: loss on the repeated batch fell {rep[0]:.4f} -> "
+        f"{rep[-1]:.4f}")
+    for _ in it:                                # drain: workers exit
+        pass
+
+
+def _free_device_memory() -> None:
+    import jax
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+    log(f"HBM bytes in use after freeing: {_hbm(jax.devices())}")
+
+
+# ---------------------------------------------------------------------------
+# phase 2: server
+# ---------------------------------------------------------------------------
+def _prompts(sz: SmokeSizes):
+    import numpy as np
+    rng = np.random.RandomState(sz.seed + 1)
+    return [(rng.randint(1, sz.vocab, (n,)), new)
+            for n, new in sz.requests]
+
+
+def _solo_ref(cfg, params, prompt, new):
+    """Plain non-paged greedy generation (dense cache, one program) —
+    the same reference the repo's serving tests pin against."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models.decode import make_generate
+    g = make_generate(cfg, prompt_len=len(prompt), max_new_tokens=new)
+    return list(np.asarray(g(params, jnp.asarray(prompt[None]),
+                             jax.random.PRNGKey(0)))[0])
+
+
+def _decode_step_text(eng) -> str:
+    """Compiled text of the engine's (sync-lane) decode step, lowered
+    with the engine's live arguments."""
+    import jax
+    import jax.numpy as jnp
+    c = eng.cache
+    return eng._step.lower(
+        eng.params, c.kpool, c.vpool, jnp.asarray(c.tables.copy()),
+        jnp.asarray(c.lens.copy()), jnp.asarray(eng._next_tok.copy()),
+        jax.random.PRNGKey(0)).compile().as_text()
+
+
+def _packed_prefill_text(eng, T: int) -> str:
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models.paged_decode import _prefill_packed
+    c = eng.cache
+    run = _prefill_packed(eng.cfg, False, False)
+    i32 = jnp.zeros((T,), jnp.int32)
+    flag = jnp.zeros((T,), bool)
+    dummy = jnp.zeros((1,), jnp.float32)
+    return run.lower(
+        eng.params, jnp.asarray(np.zeros((1, T), np.int64)),
+        jnp.zeros((1, T), jnp.int32), jnp.zeros((1, T), jnp.int32),
+        c.kpool, c.vpool, dummy, dummy, i32, i32, flag, i32,
+        flag).compile().as_text()
+
+
+def _wait_queued(url: str, n: int, timeout: float = 60.0) -> None:
+    """Block until ``GET /health`` reports ``n`` requests queued."""
+    import urllib.request
+    end = time.monotonic() + timeout
+    while True:
+        with urllib.request.urlopen(url + "/health", timeout=60) as r:
+            queued = json.loads(r.read())["queued"]
+        if queued == n:
+            return
+        _require(queued < n and time.monotonic() < end,
+                 f"waiting for {n} queued requests, /health says {queued}")
+        time.sleep(0.005)
+
+
+def phase_server(sz: SmokeSizes, clock: CompileClock) -> None:
+    import urllib.request
+
+    import jax
+    import numpy as np
+    from paddle_tpu.inference.serving import (GenerationServer,
+                                              generate_http,
+                                              generate_http_stream)
+    from paddle_tpu.models.llama_pretrain import build_mesh, init_params
+    from paddle_tpu.models.paged_decode import PagedKVCache
+    from paddle_tpu.ops.pallas import flash_varlen
+
+    cfg = _model_cfg(sz, sz.depth, train=False)
+    log(f"server: depth {cfg.num_hidden_layers} hidden {cfg.hidden_size} "
+        f"heads {cfg.num_attention_heads}x{cfg.head_dim} ffn "
+        f"{cfg.intermediate_size} vocab {cfg.vocab_size}; "
+        f"{sz.num_pages} pages x {sz.page} tokens, {sz.slots} slots")
+    mesh = build_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1,
+                      devices=jax.devices()[:1])
+    params = init_params(cfg, jax.random.PRNGKey(sz.seed), mesh)
+    cache = PagedKVCache(cfg, num_pages=sz.num_pages,
+                         pages_max=sz.pages_max, batch=sz.slots,
+                         page=sz.page)
+    pool_bytes = cache.kpool.nbytes + cache.vpool.nbytes
+    log(f"server: page pools {pool_bytes / 2**30:.2f} GiB; HBM bytes in "
+        f"use {_hbm(jax.devices()[:1])}")
+    fallbacks0 = flash_varlen.dense_fallback_count
+    snap = clock.snap()
+    srv = GenerationServer(cfg, params, cache)
+    port = srv.start()
+    url = f"http://127.0.0.1:{port}"
+    prompts = _prompts(sz)
+    try:
+        # the fixed prompt alone first: one packed prefill, then decode
+        fixed, fixed_new = prompts[0]
+        t0 = time.perf_counter()
+        got_fixed = generate_http(url, fixed, max_new_tokens=fixed_new,
+                                  timeout=900.0)
+        log(f"server: fixed prompt ({len(fixed)} tokens) -> "
+            f"{len(got_fixed)} tokens in {time.perf_counter() - t0:.1f}s "
+            f"(compiles included)")
+        log(f"server: fixed prompt greedy tokens {list(got_fixed)}")
+        _require(len(got_fixed) == fixed_new,
+                 f"fixed prompt returned {len(got_fixed)} tokens, asked "
+                 f"{fixed_new}")
+
+        # the rest together: mixed lengths pack into ONE prefill wave and
+        # decode as one batch; the last one streams.  The engine thread
+        # is held idle until every request is queued, in a fixed order,
+        # so the wave — and with it every program this phase compiles —
+        # is the same on every run (free-running arrivals packed into
+        # different waves run to run, and a warm run missed the cache)
+        results: dict = {}
+
+        def call(i, prompt, new, stream):
+            try:
+                if stream:
+                    results[i] = list(generate_http_stream(
+                        url, prompt, max_new_tokens=new, timeout=900.0))
+                else:
+                    results[i] = generate_http(
+                        url, prompt, max_new_tokens=new, timeout=900.0)
+            except Exception as e:       # re-raised below, in the parent
+                results[i] = e
+
+        t0 = time.perf_counter()
+        threads = [threading.Thread(
+            target=call, args=(i, p, new, i == len(prompts) - 1),
+            daemon=True)
+            for i, (p, new) in enumerate(prompts) if i > 0]
+        srv.engine.has_work = lambda: False     # shadows the method
+        try:
+            for k, t in enumerate(threads, 1):
+                t.start()
+                _wait_queued(url, k)
+        finally:
+            del srv.engine.has_work             # the engine ticks again
+        for t in threads:
+            t.join(900.0)
+            _require(not t.is_alive(), "a request did not finish")
+        dt = time.perf_counter() - t0
+        total = 0
+        for i, (p, new) in enumerate(prompts):
+            if i == 0:
+                continue
+            r = results[i]
+            if isinstance(r, Exception):
+                raise r
+            _require(len(r) == new,
+                     f"request {i} (prompt {len(p)}) returned {len(r)} "
+                     f"tokens, asked {new}")
+            total += len(r)
+        log(f"server: {len(threads)} requests in one wave (one streamed), "
+            f"prompts {[len(p) for p, _ in prompts[1:]]} -> {total} "
+            f"tokens in {dt:.1f}s (compiles included)")
+
+        with urllib.request.urlopen(url + "/metrics", timeout=60) as r:
+            metrics = r.read().decode()
+        wanted = ("paddle_tpu_engine_decode_steps_total",
+                  "paddle_tpu_engine_prefill_dispatches_total",
+                  "paddle_tpu_http_generate_requests_total")
+        for line in metrics.splitlines():
+            if line.startswith(wanted):
+                log(f"server: /metrics {line}")
+        _require(all(w in metrics for w in wanted),
+                 "/metrics lacks the engine or HTTP counters")
+        eng = srv.engine
+        log(f"server: decode steps {eng.decode_steps}, prefill dispatches "
+            f"{eng.prefill_calls}, {clock.since(snap)}")
+        _require(eng.decode_steps > 0 and eng.prefill_calls == 2,
+                 "expected decode steps and exactly two prefill "
+                 "dispatches: the fixed prompt, then one packed wave")
+    finally:
+        srv.stop()
+    log(f"server: HBM bytes in use {_hbm(jax.devices()[:1])}")
+    _require(flash_varlen.dense_fallback_count == fallbacks0,
+             "flash_varlen fell back to the dense path "
+             f"{flash_varlen.dense_fallback_count - fallbacks0} times")
+    log("server: flash_varlen.dense_fallback_count unchanged (0 new)")
+
+    _has_kernel(_decode_step_text(eng), "server decode step")
+    _has_kernel(_packed_prefill_text(eng, sz.page),
+                f"server packed prefill (T={sz.page})")
+    ref = _solo_ref(cfg, params, np.asarray(fixed), fixed_new)
+    _check_tokens("server fixed prompt vs plain recompute", cfg, params,
+                  fixed, ref, got_fixed)
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: only what exists across chips, and what it is compared with
+# ---------------------------------------------------------------------------
+def _check_all_devices_hold_bytes(what: str) -> None:
+    import jax
+    use = _hbm(jax.devices())
+    log(f"{what}: bytes in use per device {use}")
+    _require(all(u is not None and u >= MIN_BYTES_PER_DEVICE for u in use),
+             f"{what}: a device holds under {MIN_BYTES_PER_DEVICE} bytes "
+             f"— state was not spread over the mesh")
+
+
+def phase_tp_engine(sz: SmokeSizes, clock: CompileClock) -> None:
+    import re
+
+    import jax
+    from paddle_tpu.models.llama_pretrain import build_mesh, init_params
+    from paddle_tpu.models.paged_decode import PagedKVCache
+    from paddle_tpu.models.serving_engine import ContinuousBatchingEngine
+
+    cfg = _model_cfg(sz, sz.depth4, train=False)
+    devs = jax.devices()
+    log(f"tp engine: depth {cfg.num_hidden_layers} (cut), hidden "
+        f"{cfg.hidden_size}, mp=4 vs one chip")
+    prompts = _prompts(sz)
+
+    def run(mesh):
+        params = init_params(cfg, jax.random.PRNGKey(sz.seed), mesh)
+        tp = mesh.shape["mp"] > 1
+        cache = PagedKVCache(cfg, num_pages=sz.num_pages4,
+                             pages_max=sz.pages_max, batch=sz.slots,
+                             page=sz.page, mesh=mesh if tp else None)
+        eng = ContinuousBatchingEngine(cfg, params, cache,
+                                       mesh=mesh if tp else None)
+        rids = [eng.submit(p, max_new_tokens=max(new, COMPARE_TOKENS))
+                for p, new in prompts]
+        done = {r.rid: list(r.generated)
+                for r in eng.run_to_completion()}
+        return params, eng, [done[r] for r in rids]
+
+    snap = clock.snap()
+    mesh1 = build_mesh(mp=1, devices=devs[:1])
+    params1, _, ref = run(mesh1)
+    log(f"tp engine: one-chip engine done ({clock.since(snap)})")
+    snap = clock.snap()
+    mesh4 = build_mesh(mp=4, devices=devs[:4])
+    _, eng4, got = run(mesh4)
+    log(f"tp engine: mp=4 engine done ({clock.since(snap)})")
+    _check_all_devices_hold_bytes("tp engine")
+    for i, ((p, new), r, g) in enumerate(zip(prompts, ref, got)):
+        _require(len(g) == max(new, COMPARE_TOKENS),
+                 f"tp request {i}: {len(g)} tokens")
+        _check_tokens(f"tp engine request {i} (prompt {len(p)})", cfg,
+                      params1, p, r, g)
+    text = _decode_step_text(eng4)
+    _has_kernel(text, "tp decode step")
+    # an all-reduce whose replica group names more than one device:
+    # explicit {{0,1,2,3}} or the iota form [1,4]<=[4]
+    cross = re.findall(
+        r"all-reduce(?:-start)?\([^\n]*replica_groups="
+        r"(\{\{\d+,[\d,]*\}[^ ]*|\[\d+,(?:[2-9]|\d\d+)\]<=\[\d+\])", text)
+    log(f"tp decode step: {len(cross)} cross-device all-reduce ops in "
+        f"the compiled text; replica_groups of the first: {cross[:1]}")
+    _require(len(cross) > 0,
+             "no cross-device all-reduce in the TP decode step")
+
+
+def phase_sp_train(sz: SmokeSizes, clock: CompileClock) -> None:
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.models.llama_pretrain import (
+        build_mesh, init_adafactor_state, init_params, make_train_step)
+
+    devs = jax.devices()
+    rng = np.random.RandomState(sz.seed + 2)
+    tokens = rng.randint(0, sz.vocab,
+                         (sz.batch, sz.seq + 1)).astype(np.int64)
+
+    def run(mesh, sp):
+        cfg = _model_cfg(sz, sz.depth4, train=True, sequence_parallel=sp)
+        with mesh:
+            params = init_params(cfg, jax.random.PRNGKey(sz.seed), mesh)
+            opt = init_adafactor_state(params)
+            step = make_train_step(cfg, mesh, lr=1e-2,
+                                   optimizer="adafactor")
+            tok = jax.device_put(tokens,
+                                 NamedSharding(mesh, P("dp", None)))
+            losses = []
+            for _ in range(3):
+                params, opt, loss = step(params, opt, tok)
+                losses.append(float(loss))
+            if mesh.size > 1:       # while the sharded state is alive
+                _check_all_devices_hold_bytes("sp train")
+        return losses
+
+    log(f"sp train: depth {sz.depth4} (cut), hidden {sz.hidden}, "
+        f"b={sz.batch} s={sz.seq}; dp2 x mp2 + sequence_parallel vs one "
+        f"device, same tokens, 3 steps")
+    snap = clock.snap()
+    ref = run(build_mesh(devices=devs[:1]), sp=False)
+    log(f"sp train: one-device losses {ref} ({clock.since(snap)})")
+    _free_device_memory()
+    snap = clock.snap()
+    got = run(build_mesh(dp=2, mp=2, devices=devs[:4]), sp=True)
+    log(f"sp train: dp2 x mp2 losses {got} ({clock.since(snap)})")
+    for i, (r, g) in enumerate(zip(ref, got)):
+        _require(math.isfinite(g) and abs(g - r) <= SP_LOSS_TOL * abs(r),
+                 f"sp train: step {i} loss {g} vs one-device {r} "
+                 f"(tolerance {SP_LOSS_TOL:.0%})")
+    log(f"sp train: losses agree within {SP_LOSS_TOL:.0%} on every step")
+
+
+# ---------------------------------------------------------------------------
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu":
+        print(f"chip_smoke: needs a TPU, jax found platform "
+              f"{platform!r}", file=sys.stderr)
+        return 1
+    if len(devices) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but jax found "
+              f"{len(devices)} device(s)", file=sys.stderr)
+        return 1
+
+    from paddle_tpu.framework.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    # this script wants EVERY program it compiles in the cache, so that
+    # a second run shows the cache works (jax's default skips sub-second
+    # compiles)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    clock = CompileClock()
+    log(f"device {platform} / {devices[0].device_kind} x {len(devices)}; "
+        f"compile cache at {cache_dir}")
+    sz = SmokeSizes(seed=args.seed)
+    t0 = time.perf_counter()
+    if args.chips == 4:
+        phase_tp_engine(sz, clock)
+        _free_device_memory()
+        phase_sp_train(sz, clock)
+    else:
+        phase_trainer(sz, clock)
+        _free_device_memory()
+        phase_server(sz, clock)
+    log(f"all phases passed in {time.perf_counter() - t0:.0f}s; "
+        f"{clock.since((0.0, 0, 0))}")
+    print(json.dumps({"ok": True, "device": {
+        "platform": platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

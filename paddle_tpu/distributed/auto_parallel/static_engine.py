@@ -242,10 +242,7 @@ def complete_jaxpr(closed_jaxpr, in_specs: Sequence[Tuple],
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             if inner is not None:
                 if not hasattr(inner, "jaxpr"):       # open jaxpr: close it
-                    try:
-                        from jax.extend.core import ClosedJaxpr as _CJ
-                    except ImportError:               # older jax layout
-                        from jax.core import ClosedJaxpr as _CJ
+                    from jax.extend.core import ClosedJaxpr as _CJ
                     inner = _CJ(inner, ())
                 sub = complete_jaxpr(inner, ispecs, mesh_axis_sizes)
                 info.reshards.extend(sub.reshards)

@@ -15,6 +15,7 @@ import sys
 import time
 from typing import List, Optional
 
+from ...framework.compile_cache import compile_cache_dir
 from .job import Job, Pod
 
 __all__ = ["Controller", "CollectiveController"]
@@ -166,6 +167,9 @@ class CollectiveController(Controller):
             endpoints = ",".join(
                 f"{h}:{6170 + j}" for h in hosts for j in range(nproc))
         base = {
+            # workers share one persistent compile cache (the
+            # environment's directory, else <checkout>/.jax_cache)
+            "JAX_COMPILATION_CACHE_DIR": compile_cache_dir(),
             "PADDLE_JOB_ID": self.job.id,
             "PADDLE_RESTART_COUNT": str(self.restart_count),
             "PADDLE_NNODES": str(nnodes),

@@ -673,8 +673,13 @@ def _agent_proc_main(spec: dict, q) -> None:
     """Entry point of a spawned agent process: resolve the engine
     factory by import path (closures over device arrays cannot cross
     a process boundary), build the agent, report the bound port, and
-    serve until told to stop — or until SIGKILL, which is the point."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    serve until told to stop — or until SIGKILL, which is the point.
+    The child runs on the device the SPEC names (``"jax_platforms"``);
+    without one it takes jax's default backend, as its parent did —
+    it never picks the CPU for itself."""
+    if spec.get("jax_platforms"):
+        import jax
+        jax.config.update("jax_platforms", spec["jax_platforms"])
     mod, _, fn = spec["factory"].partition(":")
     factory_fn = getattr(importlib.import_module(mod), fn)
     kwargs = spec.get("factory_kwargs") or {}
@@ -695,8 +700,11 @@ def spawn_agent_process(spec: dict, timeout_s: float = 180.0):
     (``multiprocessing`` spawn context — a fresh interpreter, no
     inherited JAX state) and return ``(process, (host, port))``.
     ``spec``: ``{"factory": "module:function", "factory_kwargs":
-    {...}, "agent_kwargs": {...}}`` — everything JSON-able, because
-    it crosses the process boundary.  Kill it with
+    {...}, "agent_kwargs": {...}, "jax_platforms": "cpu"}`` (the last
+    optional: the jax platform the child initialises; a chip belongs
+    to one process, so a child of a chip-holding parent must name
+    another) — everything JSON-able, because it crosses the process
+    boundary.  Kill it with
     ``os.kill(proc.pid, signal.SIGKILL)`` to exercise the real
     failure mode (no atexit, no socket FIN handshake beyond the
     kernel's RST)."""

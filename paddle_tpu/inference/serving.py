@@ -1043,6 +1043,8 @@ class GenerationServer:
                 _time.sleep(self._poll_s)
 
     def start(self) -> int:
+        from ..framework.compile_cache import enable_compile_cache
+        enable_compile_cache()
         self._httpd = ThreadingHTTPServer((self._host, self._port),
                                           self.handler_class)
         self._httpd.owner = self

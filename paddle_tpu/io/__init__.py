@@ -338,6 +338,11 @@ class DataLoader:
         self.timeout = timeout
         self.worker_init_fn = worker_init_fn
         self.persistent_workers = persistent_workers
+        # how the LAST iteration moved batches: "shm" (worker
+        # processes + native shared-memory ring), "queue" (worker
+        # processes, pickled payloads — the ring was unavailable),
+        # "threads" or "inline"; None before the first iteration
+        self.transport = None
         if persistent_workers:
             import warnings
             warnings.warn(
@@ -387,10 +392,8 @@ class DataLoader:
                 yield self.collate_fn(samples)
 
     def __iter__(self):
-        if self.num_workers == 0:
-            yield from self._iter_batches()
-            return
-        if self.is_iterable_ds:
+        if self.num_workers == 0 or self.is_iterable_ds:
+            self.transport = "inline"
             yield from self._iter_batches()
             return
         if self.use_shared_memory and self.batch_sampler is not None:
@@ -407,12 +410,14 @@ class DataLoader:
                 prefetch_factor=self.prefetch_factor,
                 worker_init_fn=self.worker_init_fn,
                 timeout=self.timeout, to_device=_tree_to_tensor)
+            self.transport = it.transport
             try:
                 yield from it
             finally:
                 it.shutdown()
             return
         # thread-pool prefetch pipeline (use_shared_memory=False path)
+        self.transport = "threads"
         pool = ThreadPoolExecutor(max_workers=self.num_workers)
         try:
             sampler_iter = iter(self.batch_sampler)

@@ -187,6 +187,10 @@ class MultiprocessBatchIterator:
                         for wid in range(self._num_workers)]
             except Exception:  # noqa: BLE001 - fall back to queue payloads
                 self._rings = []
+        # which payload transport is LIVE ("shm": the native ring;
+        # "queue": pickled through the result queue) — the fallback
+        # stays for users, but it is never silent
+        self.transport = "shm" if self._rings else "queue"
         base_seed = int.from_bytes(os.urandom(4), "little")
         for wid in range(self._num_workers):
             iq = ctx.Queue()

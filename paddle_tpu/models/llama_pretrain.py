@@ -226,7 +226,29 @@ def _mm(x, w, dt):
     return x @ w.astype(dt)
 
 
-def _rope(q, k, theta):
+def _per_shard(fn, mesh, q_heads: int, kv_heads: int, n_bshd: int,
+               rest_specs=()):
+    """Run a Pallas kernel over ``[b, s, heads, d]`` operands PER SHARD
+    of ``mesh``.  GSPMD cannot split a Mosaic kernel ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — the first thing the TPU compiler said to a multi-chip
+    train step), and attention/rope are independent per batch row and
+    per head: batch splits over ``dp``, heads over ``mp`` (when both
+    head counts divide; else every mp shard keeps all heads).  The
+    first ``n_bshd`` operands and the result(s) are ``[b, s, heads,
+    d]``; ``rest_specs`` are the specs of the remaining operands.
+    One device (or no mesh): ``fn`` itself."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    mp = mesh.shape.get("mp", 1)
+    heads = "mp" if q_heads % mp == 0 and kv_heads % mp == 0 else None
+    bshd = P("dp", None, heads, None)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(bshd,) * n_bshd + tuple(rest_specs),
+                         out_specs=bshd, check_vma=False)
+
+
+def _rope(q, k, theta, mesh=None):
     # q/k: [b, s, n, d]
     from ..flags import flags
     from ..ops.dispatch import get_op_impl
@@ -236,7 +258,9 @@ def _rope(q, k, theta):
     impl = get_op_impl("fused_rope", None)
     cos_t, sin_t = rope_tables(s, d, theta)         # [s, d/2]
     if impl is not None and flags.FLAGS_pallas_rope and d % 128 == 0:
-        return impl(q, cos_t, sin_t), impl(k, cos_t, sin_t)
+        rot = _per_shard(impl, mesh, q.shape[2], k.shape[2], 1,
+                         (P(), P()))
+        return rot(q, cos_t, sin_t), rot(k, cos_t, sin_t)
     cos = cos_t[None, :, None, :]
     sin = sin_t[None, :, None, :]
 
@@ -289,14 +313,19 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
         from ..ops.pallas.flash_varlen import (
             flash_attention_segmented, xla_segmented_sdpa)
         if cfg.use_pallas_attention and flags.FLAGS_pallas_flash_attention:
-            return flash_attention_segmented(q, k, v, seg, causal=True)
+            return _per_shard(
+                lambda q, k, v, seg: flash_attention_segmented(
+                    q, k, v, seg, causal=True),
+                mesh, q.shape[2], k.shape[2], 3,
+                (P("dp", None),))(q, k, v, jnp.asarray(seg, jnp.int32))
         return xla_segmented_sdpa(q, k, v, jnp.asarray(seg, jnp.int32),
                                   True)
     impl = get_op_impl("flash_attention", None)
     if impl is not None and cfg.use_pallas_attention and \
             flags.FLAGS_pallas_flash_attention:
         k, v = full_heads(k, v)
-        return impl(q, k, v, causal=True)
+        return _per_shard(lambda q, k, v: impl(q, k, v, causal=True),
+                          mesh, q.shape[2], k.shape[2], 3)(q, k, v)
     k, v = full_heads(k, v)
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqnd,bknd->bnqk", q, k) * scale
@@ -307,7 +336,8 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
 
-def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
+def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
+                    mesh: Optional[Mesh] = None):
     """ln1 + QKV projections + rope + GQA repeat -> q, k, v.
     Single source of block math shared by every remat policy."""
     b, s, h = x.shape
@@ -333,7 +363,7 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
         q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
         k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
         v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
-    q, k = _rope(q, k, cfg.rope_theta)
+    q, k = _rope(q, k, cfg.rope_theta, mesh)
     # GQA stays UN-repeated here: _attention's segmented flash kernel
     # indexes kv heads by group natively (the whole point of GQA — nkv
     # heads of K/V HBM traffic, not n); paths that need full heads
@@ -379,7 +409,7 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
 def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                    mesh: Optional[Mesh] = None, seg=None):
     """One transformer block; x [b, s, h] in compute dtype."""
-    q, k, v = _block_pre_attn(bp, x, cfg)
+    q, k, v = _block_pre_attn(bp, x, cfg, mesh)
     attn = _attention(q, k, v, cfg, mesh, seg)
     return _block_post_attn(bp, x, attn, cfg)
 
@@ -398,7 +428,7 @@ def _block_forward_flash_saved(bp: Dict[str, Any], x,
     The math is the shared _block_pre_attn/_block_post_attn — only the
     checkpoint boundaries differ from _block_forward."""
     pre = jax.checkpoint(
-        lambda bp, x: _block_pre_attn(bp, x, cfg))
+        lambda bp, x: _block_pre_attn(bp, x, cfg, mesh))
     post = jax.checkpoint(
         lambda bp, x, attn: _block_post_attn(bp, x, attn, cfg))
     q, k, v = pre(bp, x)
@@ -435,11 +465,13 @@ def _trunk_scan(blocks, x, cfg, mesh, seg=None):
     fwd = _remat_wrap(_block_forward, cfg)
     # Megatron-SP activation constraints are a TPU optimisation; XLA:CPU's
     # AllReducePromotion/partitioner passes crash on the collectives they
-    # produce inside scan+remat, so they're disabled on the CPU
-    # validation backend (mp weight shardings are still exercised there).
+    # produce inside scan+remat, so they're disabled when the MESH is made
+    # of CPU devices (mp weight shardings are still exercised there).  The
+    # mesh decides, not the default backend: a step compiled for described
+    # TPU devices from a CPU host keeps the constraint.
     sp_on = (cfg.sequence_parallel and mesh is not None and
              mesh.shape.get("mp", 1) > 1 and
-             jax.default_backend() != "cpu")
+             mesh.devices.flat[0].platform != "cpu")
 
     def step(carry, bp):
         out = fwd(bp, carry, cfg, mesh, seg)

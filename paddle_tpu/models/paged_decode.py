@@ -1259,21 +1259,6 @@ _step_tp_cache: dict = {}
 _tp_inner_cache: dict = {}
 
 
-def _shard_map_fn():
-    """jax.shard_map with the 0.4.x compat shim (experimental
-    namespace, check_vma→check_rep) — shared by every TP builder."""
-    try:                               # jax >= 0.5 top-level export
-        return jax.shard_map
-    except AttributeError:             # 0.4.x: experimental namespace,
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def shard_map(*a, **kw):       # ... where check_vma is check_rep
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            return _sm(*a, **kw)
-        return shard_map
-
-
 # -- quantized + overlapped TP collectives (EQuARX / T3) ------------------
 _Q8_SCALE_BYTES = 4                    # f32 per-block scales on the wire
 
@@ -1429,7 +1414,6 @@ def _build_tp_inner(cfg: LlamaPretrainConfig, mesh,
 
     from jax.sharding import PartitionSpec as P
     from .llama_pretrain import param_specs
-    shard_map = _shard_map_fn()
     from ..ops.pallas.paged_attention import (
         paged_decode_attention, paged_decode_attention_q8,
         quantize_kv_token)
@@ -1529,7 +1513,7 @@ def _build_tp_inner(cfg: LlamaPretrainConfig, mesh,
     pool_spec = P(None, None, "mp", None, None)
     scale_spec = P(None, None, "mp", None)
     if q8:
-        inner = shard_map(
+        inner = jax.shard_map(
             step_local, mesh=mesh,
             in_specs=(param_specs(cfg, pp=1), pool_spec, pool_spec,
                       scale_spec, scale_spec, P(), P(), P(), P()),
@@ -1541,7 +1525,7 @@ def _build_tp_inner(cfg: LlamaPretrainConfig, mesh,
                            key):
             return step_local(params, kpool, vpool, None, None,
                               tables, lens, tok, key)
-        inner = shard_map(
+        inner = jax.shard_map(
             without_scales, mesh=mesh,
             in_specs=(param_specs(cfg, pp=1), pool_spec, pool_spec,
                       P(), P(), P(), P()),
@@ -1976,8 +1960,9 @@ def _packed_prefill_body(cfg: LlamaPretrainConfig, q8: bool,
     if hit is not None:
         return hit
     from .decode import _grouped_attn
-    from ..ops.pallas.flash_attention import _interpret, _pick_blocks
-    from ..ops.pallas.flash_varlen import flash_attention_segmented
+    from ..ops.pallas import _common as _pallas_common
+    from ..ops.pallas.flash_varlen import (_pick_seg_blocks,
+                                           flash_attention_segmented)
 
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -1990,7 +1975,8 @@ def _packed_prefill_body(cfg: LlamaPretrainConfig, q8: bool,
         # static routing (trace-time): the Pallas kernel's block
         # skipping needs a dividing block and a real TPU; otherwise the
         # XLA mask keeps bitwise parity with the dense prefill path
-        use_kernel = (not _interpret()) and _pick_blocks(T) is not None
+        use_kernel = (not _pallas_common.interpret()
+                      and _pick_seg_blocks(T) is not None)
         if not use_kernel:
             idx = jnp.arange(T, dtype=jnp.int32)
             # segments are contiguous runs, so global causal ==
@@ -2088,10 +2074,10 @@ def _packed_prefill_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool,
     from jax.sharding import PartitionSpec as P
     from .llama_pretrain import param_specs
     from .decode import _grouped_attn
-    from ..ops.pallas.flash_attention import _interpret, _pick_blocks
-    from ..ops.pallas.flash_varlen import flash_attention_segmented
+    from ..ops.pallas import _common as _pallas_common
+    from ..ops.pallas.flash_varlen import (_pick_seg_blocks,
+                                           flash_attention_segmented)
 
-    shard_map = _shard_map_fn()
     mp = mesh.shape["mp"]
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -2106,7 +2092,8 @@ def _packed_prefill_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool,
                   stream_hist):
         B, T = toks.shape                  # B == 1
         x = _embed_vocab_parallel(params["embed"], toks, ax, dt)
-        use_kernel = (not _interpret()) and _pick_blocks(T) is not None
+        use_kernel = (not _pallas_common.interpret()
+                      and _pick_seg_blocks(T) is not None)
         if not use_kernel:
             idx = jnp.arange(T, dtype=jnp.int32)
             mask = ((seg[0][:, None] == seg[0][None, :])
@@ -2161,7 +2148,7 @@ def _packed_prefill_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool,
 
     pool_spec = P(None, None, "mp", None, None)
     scale_spec = P(None, None, "mp", None) if q8 else P()
-    run = shard_map(
+    run = jax.shard_map(
         run_local, mesh=mesh,
         in_specs=(param_specs(cfg, pp=1), P(), P(), P(), pool_spec,
                   pool_spec, scale_spec, scale_spec, P(), P(), P(),
@@ -2261,7 +2248,6 @@ def _prefill_chunk_batched_tp(cfg: LlamaPretrainConfig, mesh):
     from .llama_pretrain import param_specs
     from .decode import _grouped_attn
 
-    shard_map = _shard_map_fn()
     mp = mesh.shape["mp"]
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -2317,7 +2303,7 @@ def _prefill_chunk_batched_tp(cfg: LlamaPretrainConfig, mesh):
         return x, ks, vs
 
     pool_spec = P(None, None, "mp", None, None)
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         run_local, mesh=mesh,
         in_specs=(param_specs(cfg, pp=1), P(), pool_spec, pool_spec,
                   P(), P()),
@@ -2610,7 +2596,6 @@ def _spec_verify_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool):
     from .llama_pretrain import param_specs
     from .decode import _grouped_attn
 
-    shard_map = _shard_map_fn()
     mp = mesh.shape["mp"]
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -2688,7 +2673,7 @@ def _spec_verify_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool):
 
     pool_spec = P(None, None, "mp", None, None)
     scale_spec = P(None, None, "mp", None) if q8 else P()
-    run = shard_map(
+    run = jax.shard_map(
         run_local, mesh=mesh,
         in_specs=(param_specs(cfg, pp=1), P(), pool_spec, pool_spec,
                   scale_spec, scale_spec, P(), P()),

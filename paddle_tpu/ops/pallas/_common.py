@@ -10,9 +10,30 @@ to int32.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["idx32"]
+__all__ = ["idx32", "interpret"]
+
+
+def interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode: yes when
+    ``FLAGS_pallas_interpret`` is set or the backend is ``cpu``, no
+    (compiled by Mosaic) on ``tpu``.  Any other backend raises — a
+    kernel must never quietly run somewhere nobody chose.  Every
+    kernel module calls this through the module (``_common.interpret()``)
+    so a compile-for-TPU rehearsal can steer all of them at one name."""
+    from ...flags import flags
+    if flags.FLAGS_pallas_interpret:
+        return True
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels support the 'tpu' and 'cpu' backends, not "
+        f"{backend!r}")
 
 
 def idx32(*idx):

@@ -31,8 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _common
 from ._common import idx32
-from .flash_attention import NEG_INF, _interpret
+from .flash_attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_xla",
            "paged_decode_attention_q8", "quantize_kv_token"]
@@ -206,7 +207,7 @@ def paged_decode_attention(q, kpool, vpool, block_tables, context_lens,
     pages_max = block_tables.shape[1]
     g = n // nkv
     sm_scale = sm_scale or (1.0 / math.sqrt(d))
-    if _interpret() and not force_kernel:
+    if _common.interpret() and not force_kernel:
         return paged_decode_attention_xla(q, kpool, vpool, block_tables,
                                           context_lens, sm_scale)
     tables = jnp.asarray(block_tables, jnp.int32)
@@ -239,7 +240,7 @@ def paged_decode_attention(q, kpool, vpool, block_tables, context_lens,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool)
     return out
 
@@ -287,7 +288,7 @@ def paged_decode_attention_q8(q, kpool, vpool, kscale, vscale,
     num_pages, nkv, page, _ = kpool.shape
     pages_max = block_tables.shape[1]
     sm_scale = sm_scale or (1.0 / math.sqrt(d))
-    if _interpret() and not force_kernel:
+    if _common.interpret() and not force_kernel:
         return paged_decode_attention_q8_xla(
             q, kpool, vpool, kscale, vscale, block_tables,
             context_lens, sm_scale)
@@ -328,6 +329,6 @@ def paged_decode_attention_q8(q, kpool, vpool, kscale, vscale,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool, kscale, vscale)
     return out

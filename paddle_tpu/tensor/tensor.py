@@ -80,7 +80,7 @@ class Tensor:
             dev = list(self._data.devices())[0]
         except Exception:
             return places.CPUPlace()
-        if dev.platform in places._TPU_PLATFORMS:
+        if dev.platform == "tpu":
             return places.TPUPlace(dev.id)
         if dev.platform == "cpu":
             return places.CPUPlace()
@@ -276,14 +276,12 @@ class Tensor:
         if device is not None:
             place = places._parse_device(device) if not isinstance(
                 device, places.Place) else device
-            dev = place.jax_device()
-            if dev is not None:
-                new = Tensor.__new__(Tensor)
-                _init_raw(new, jax.device_put(out._data, dev),
-                          stop_gradient=out.stop_gradient)
-                new._grad_node = out._grad_node
-                new._out_idx = out._out_idx
-                out = new
+            new = Tensor.__new__(Tensor)
+            _init_raw(new, jax.device_put(out._data, place.jax_device()),
+                      stop_gradient=out.stop_gradient)
+            new._grad_node = out._grad_node
+            new._out_idx = out._out_idx
+            out = new
         return out
 
     def cpu(self) -> "Tensor":
@@ -357,10 +355,8 @@ def _to_jax_array(data, dtype=None, place=None):
         arr = jnp.asarray(np_arr)
     if jdt is not None and arr.dtype != jdt:
         arr = arr.astype(jdt)
-    if place is not None:
-        dev = place.jax_device() if isinstance(place, places.Place) else None
-        if dev is not None:
-            arr = jax.device_put(arr, dev)
+    if isinstance(place, places.Place):
+        arr = jax.device_put(arr, place.jax_device())
     return arr
 
 

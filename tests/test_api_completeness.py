@@ -56,6 +56,10 @@ REF = "/root/reference/python/paddle"
     ("onnx/__init__.py", "onnx"),
 ])
 def test_public_surface_complete(ref_path, module_attr):
+    import os
+    if not os.path.isdir(REF):
+        pytest.skip(f"the reference tree {REF} (outside the checkout) is "
+                    f"not on this machine")
     names = _ref_all(f"{REF}/{ref_path}")
     mod = paddle
     if module_attr:

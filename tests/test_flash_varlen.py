@@ -115,6 +115,20 @@ def test_segmented_dense_fallback_warns_and_counts():
     assert len(msgs) == 1, msgs             # once per shape
 
 
+@pytest.mark.parametrize("S,want", [
+    (2048, (512, 512)), (128, (128, 128)),
+    (64, (64, 64)),      # one block under 128 lanes: the whole row
+    (192, None),         # 3 x 64: Mosaic cannot prove the lane offset
+    (100, None),         # no dividing block at all
+])
+def test_pick_seg_blocks_only_offers_what_mosaic_accepts(S, want):
+    """Blocks under 128 are usable only as the single block of a short
+    sequence — the TPU compiler refused a 64-wide lane slice at a
+    dynamic offset (tests/test_tpu_compile.py holds the compile)."""
+    from paddle_tpu.ops.pallas import flash_varlen as fv
+    assert fv._pick_seg_blocks(S) == want
+
+
 def test_segmented_kernel_gqa_rejects_indivisible_heads():
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 128, 4, 16).astype(np.float32))

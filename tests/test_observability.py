@@ -531,7 +531,7 @@ def test_bench_init_retries_after_failure_then_succeeds(capsys):
     def flaky():
         calls.append(1)
         if len(calls) == 1:
-            raise RuntimeError("UNAVAILABLE: tunnel down")
+            raise RuntimeError("UNAVAILABLE: backend down")
         return ["fake-device"]
 
     devs, err = bench._init_devices(max_tries=3, base_delay=0.01,

@@ -106,15 +106,18 @@ def test_overlap_eos_stops_early():
     params = _params(cfg)
     rng = np.random.RandomState(2)
     prompt = rng.randint(1, 128, (6,))
-    ref = _solo_ref(cfg, params, prompt, 4)
-    eos = int(ref[1])
+    ref = _solo_ref(cfg, params, prompt, 8)
+    # eos = the first generated token (past the first) that no earlier
+    # one equals — with these weights ref[0] == ref[1], and an eos that
+    # already appeared would stop the run before the position under test
+    stop = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
     cache = PagedKVCache(cfg, num_pages=32, pages_max=8, batch=1,
                          page=16)
-    eng = ContinuousBatchingEngine(cfg, params, cache, eos_id=eos,
-                                   overlap=True)
+    eng = ContinuousBatchingEngine(cfg, params, cache,
+                                   eos_id=int(ref[stop]), overlap=True)
     eng.submit(prompt, max_new_tokens=10)
     done = eng.run_to_completion()
-    assert done[0].generated == ref[:2]      # stopped at eos, not 10
+    assert done[0].generated == ref[:stop + 1]   # stopped at eos, not 10
 
 
 def test_overlap_stop_sequence_retires_and_flushes():

@@ -384,18 +384,16 @@ def test_tp_allreduce_int8_statistical_bar():
     bar), with every sequence's first (exact-prefill-fed) token
     identical."""
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.models.paged_decode import (_make_q8_allreduce,
-                                                _shard_map_fn)
+    from paddle_tpu.models.paged_decode import _make_q8_allreduce
     # 1. the collective's own error bound: int8 wire with per-block
     #    scales keeps each hop's rounding <= 1/254 of the block max;
     #    mp-1 accumulation hops keep the total well under 2%
     mesh = _mesh(MP)
     nch, block = _q8_ring_plan(64, MP)
     ar = _make_q8_allreduce("mp", MP, 64 // nch, block)
-    sm = _shard_map_fn()
-    g = jax.jit(sm(lambda x: ar(x[0]), mesh=mesh,
-                   in_specs=(P("mp"),), out_specs=P(),
-                   check_vma=False))
+    g = jax.jit(jax.shard_map(lambda x: ar(x[0]), mesh=mesh,
+                              in_specs=(P("mp"),), out_specs=P(),
+                              check_vma=False))
     rng = np.random.RandomState(11)
     x = jnp.asarray(rng.randn(MP, 8, 64 // nch).astype(np.float32))
     got = np.asarray(g(x))

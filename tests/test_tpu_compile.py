@@ -1,0 +1,240 @@
+"""Compile the main path's kernels and step programs for a DESCRIBED TPU
+v5e, at the real 1.345B widths, with no chip attached.
+
+The TPU compiler is installed wherever the tests run; it compiles for a
+topology that is described, not attached, and refuses what the chip's
+compiler would refuse (a lane slice Mosaic cannot prove aligned, a
+kernel GSPMD cannot partition, a program over HBM).  Nothing runs, so
+these say nothing about results or time — ``chip_smoke.py`` and
+``tests/test_pallas_tpu.py`` do that on the chip.
+
+Rules this file keeps (on-chip-measurement guide §2): ONE file; the
+topology is described inside a module-scoped fixture that skips when it
+cannot be (never at import, never in a ``skipif``/``parametrize``
+argument, not in conftest, not autouse); every compile happens in the
+test's own process.  Code that asks ``jax.default_backend()`` still sees
+the CPU here, so the one place the kernels ask (``_common.interpret``)
+is steered from the ``compiled`` fixture — not through an option of the
+program.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+KERNEL = "tpu_custom_call"
+# the 1.345B block (bench.py / chip_smoke.py): widths are never cut
+VOCAB, HIDDEN, FFN, HEADS, HEAD_DIM, PAGE = 32000, 2048, 5504, 16, 128, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from paddle_tpu.models.llama_pretrain import build_mesh
+    return build_mesh(devices=topo.devices[:1])
+
+
+@pytest.fixture
+def compiled(monkeypatch):
+    """Kernels lower through Mosaic (as on the chip), not the
+    interpreter the CPU backend would pick."""
+    from paddle_tpu.ops.pallas import _common
+    monkeypatch.setattr(_common, "interpret", lambda: False)
+
+
+def _sds(mesh, shape, dtype, spec=P()):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def _text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _cfg(depth, train, sequence_parallel=False):
+    from paddle_tpu.models.llama_pretrain import LlamaPretrainConfig
+    return LlamaPretrainConfig(
+        vocab_size=VOCAB, hidden_size=HIDDEN, intermediate_size=FFN,
+        num_hidden_layers=depth, num_attention_heads=HEADS,
+        num_key_value_heads=HEADS, max_seq_len=2048,
+        use_pallas_attention=True, sequence_parallel=sequence_parallel,
+        remat=train, dtype=jnp.bfloat16,
+        param_dtype=jnp.float32 if train else jnp.bfloat16,
+        loss_chunks=4 if train else 0)
+
+
+def _param_sds(cfg, mesh):
+    """Parameter shapes from ``init_params`` itself (eval_shape: nothing
+    is allocated), placed on ``mesh`` by the model's own specs."""
+    from paddle_tpu.models.llama_pretrain import (build_mesh, init_params,
+                                                  param_specs)
+    host = build_mesh(devices=jax.devices()[:1])
+    shapes = jax.eval_shape(lambda k: init_params(cfg, k, host),
+                            jax.random.PRNGKey(0))
+    return jax.tree_util.tree_map(
+        lambda x, sp: _sds(mesh, x.shape, x.dtype, sp), shapes,
+        param_specs(cfg, 1, 1),
+        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+
+# ---------------------------------------------------------------------------
+# kernels, at the shapes the main path gives them
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("nkv", [16, 4])
+@pytest.mark.parametrize("kv_quant", [None, "int8"])
+def test_paged_decode_attention(one_chip, compiled, kv_quant, nkv):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_decode_attention_q8)
+    B, pages_max, num_pages = 32, 32, 257
+    q = _sds(one_chip, (B, HEADS, HEAD_DIM), jnp.bfloat16)
+    tables = _sds(one_chip, (B, pages_max), jnp.int32)
+    lens = _sds(one_chip, (B,), jnp.int32)
+    pool = (num_pages, nkv, PAGE, HEAD_DIM)
+    if kv_quant == "int8":
+        kp = _sds(one_chip, pool, jnp.int8)
+        sc = _sds(one_chip, pool[:-1], jnp.float32)
+        text = _text(paged_decode_attention_q8, q, kp, kp, sc, sc,
+                     tables, lens)
+    else:
+        kp = _sds(one_chip, pool, jnp.bfloat16)
+        text = _text(paged_decode_attention, q, kp, kp, tables, lens)
+    assert KERNEL in text
+
+
+def test_flash_attention_fwd_bwd(one_chip, compiled):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    x = _sds(one_chip, (8, 2048, HEADS, HEAD_DIM), jnp.bfloat16)
+    text = _text(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+    assert text.count(KERNEL) >= 2          # forward + backward kernels
+
+
+@pytest.mark.parametrize("T,nkv", [(2048, 16), (2048, 4), (64, 16)])
+def test_flash_varlen_segmented_fwd_bwd(one_chip, compiled, T, nkv):
+    """T=2048: the packed-pretrain / long-prefill shape, MHA and GQA.
+    T=64: the engine's smallest packed-prefill bucket — ONE block under
+    128 lanes, which Mosaic refused until the segment-id row was read
+    whole ("cannot statically prove that index in dimension 2 is a
+    multiple of 128")."""
+    from paddle_tpu.ops.pallas import flash_varlen
+    q = _sds(one_chip, (1, T, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = _sds(one_chip, (1, T, nkv, HEAD_DIM), jnp.bfloat16)
+    seg = _sds(one_chip, (1, T), jnp.int32)
+    before = flash_varlen.dense_fallback_count
+    text = _text(jax.grad(
+        lambda q, k, v, s: flash_varlen.flash_attention_segmented(
+            q, k, v, s, causal=True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, kv, kv, seg)
+    assert text.count(KERNEL) >= 2
+    assert flash_varlen.dense_fallback_count == before
+
+
+@pytest.mark.parametrize("N", [FFN, VOCAB])
+def test_int8_matmul(one_chip, compiled, N):
+    from paddle_tpu.ops.pallas.int8_matmul import int8_matmul
+    text = _text(int8_matmul,
+                 _sds(one_chip, (32, HIDDEN), jnp.bfloat16),
+                 _sds(one_chip, (HIDDEN, N), jnp.int8),
+                 _sds(one_chip, (N,), jnp.float32))
+    assert KERNEL in text
+
+
+# ---------------------------------------------------------------------------
+# the jitted programs around them (depth cut; widths real)
+# ---------------------------------------------------------------------------
+def _pools(cfg, mesh, num_pages, spec=P()):
+    return _sds(mesh, (cfg.num_hidden_layers, num_pages,
+                       cfg.num_key_value_heads, PAGE, cfg.head_dim),
+                jnp.bfloat16, spec)
+
+
+def test_engine_decode_step(one_chip, compiled):
+    from paddle_tpu.models.paged_decode import make_paged_decode_step
+    cfg = _cfg(2, train=False)
+    B = 32
+    pool = _pools(cfg, one_chip, 128)
+    text = make_paged_decode_step(cfg, 0.0).lower(
+        _param_sds(cfg, one_chip), pool, pool,
+        _sds(one_chip, (B, 32), jnp.int32), _sds(one_chip, (B,), jnp.int32),
+        _sds(one_chip, (B,), jnp.int64),
+        _sds(one_chip, (2,), jnp.uint32)).compile().as_text()
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("T", [64, 512, 2048])
+def test_engine_packed_prefill(one_chip, compiled, T):
+    """Every packed bucket is prefill_bucket (= page 64) times a power
+    of two; none may quietly lose the segmented kernel to the dense
+    path.  64 and 2048 are the two waves chip_smoke.py's server phase
+    makes (the fixed prompt alone, then seven prompts together)."""
+    from paddle_tpu.models.paged_decode import _prefill_packed
+    cfg = _cfg(2, train=False)
+    pool = _pools(cfg, one_chip, 128)
+    i32 = _sds(one_chip, (T,), jnp.int32)
+    flag = _sds(one_chip, (T,), jnp.bool_)
+    dummy = _sds(one_chip, (1,), jnp.float32)
+    text = _prefill_packed(cfg, False, False).lower(
+        _param_sds(cfg, one_chip), _sds(one_chip, (1, T), jnp.int64),
+        _sds(one_chip, (1, T), jnp.int32), _sds(one_chip, (1, T), jnp.int32),
+        pool, pool, dummy, dummy, i32, i32, flag, i32,
+        flag).compile().as_text()
+    assert KERNEL in text
+
+
+def test_tp_decode_step_has_cross_device_all_reduce(topo, compiled):
+    from paddle_tpu.models.llama_pretrain import build_mesh
+    from paddle_tpu.models.paged_decode import make_paged_decode_step_tp
+    mesh = build_mesh(mp=4, devices=topo.devices)
+    cfg = _cfg(2, train=False)
+    B = 32
+    pool = _pools(cfg, mesh, 128, P(None, None, "mp", None, None))
+    text = make_paged_decode_step_tp(cfg, mesh, 0.0).lower(
+        _param_sds(cfg, mesh), pool, pool,
+        _sds(mesh, (B, 32), jnp.int32), _sds(mesh, (B,), jnp.int32),
+        _sds(mesh, (B,), jnp.int64),
+        _sds(mesh, (2,), jnp.uint32)).compile().as_text()
+    assert KERNEL in text
+    assert "all-reduce" in text and "replica_groups={{0,1,2,3}}" in text
+
+
+def test_train_step_dp2_mp2_sequence_parallel(topo, compiled):
+    """The multi-chip train step with the Pallas kernels ON and the
+    sequence-parallel constraint ON.  GSPMD refuses to partition a
+    Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned"), so rope and flash run per shard
+    (``llama_pretrain._per_shard``); the SP constraint follows the
+    MESH's platform, so it is compiled here although the host is a
+    CPU."""
+    from paddle_tpu.models.llama_pretrain import (
+        build_mesh, init_adafactor_state, make_train_step)
+    mesh = build_mesh(dp=2, mp=2, devices=topo.devices)
+    cfg = _cfg(1, train=True, sequence_parallel=True)
+    with mesh:
+        params = _param_sds(cfg, mesh)
+        opt = jax.tree_util.tree_map(
+            lambda x: _sds(mesh, x.shape, x.dtype),
+            jax.eval_shape(init_adafactor_state, params))
+        step = make_train_step(cfg, mesh, lr=1e-2, optimizer="adafactor")
+        compiled_step = step.lower(
+            params, opt,
+            _sds(mesh, (8, 2049), jnp.int64, P("dp", None))).compile()
+    text = compiled_step.as_text()
+    assert text.count(KERNEL) >= 3           # rope + flash fwd/bwd
+    assert "all-reduce" in text or "reduce-scatter" in text
+    per_device = compiled_step.memory_analysis().argument_size_in_bytes
+    n_params = sum(int(np.prod(x.shape))
+                   for x in jax.tree_util.tree_leaves(params))
+    assert per_device < 0.75 * 4 * n_params   # sharded, not replicated

@@ -701,7 +701,8 @@ def test_sigkill_process_agent_mid_decode(cfg, params):
     ref = _ref(cfg, params, _PROMPTS)
     spawn_spec = RemoteSpec(
         spawn={"factory": "remote_agent_worker:make_engine",
-               "agent_kwargs": {"lease_s": 0.6}},
+               "agent_kwargs": {"lease_s": 0.6},
+               "jax_platforms": "cpu"},
         lease_s=0.6, rpc_timeout_s=0.5, max_retries=1,
         backoff_s=0.01)
     surv = _spec(cfg, params)

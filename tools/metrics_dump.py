@@ -13,7 +13,7 @@ Usage:
   python tools/metrics_dump.py transport http://127.0.0.1:8000
   python tools/metrics_dump.py traces  http://127.0.0.1:8000 [--min-ms N] [--status S]
   python tools/metrics_dump.py trace   http://127.0.0.1:8000 <rid>
-  python tools/metrics_dump.py snapshot BENCH_r05.json
+  python tools/metrics_dump.py snapshot bench_out.json
 
 ``stats`` renders ``GET /stats`` (the JSON snapshot) as an aligned
 table; ``metrics`` dumps the raw Prometheus text from ``GET /metrics``;
