@@ -220,6 +220,11 @@ FLUSH_SAFE: Dict[str, str] = {
         "the drain IS the pipeline: tokens are attributed against the "
         "dispatch-time active mask, and host-only retirements schedule "
         "_needs_flush",
+    "ContinuousBatchingEngine._drain_step":
+        "_drain_one's bookkeeping half, split off only so that the "
+        "engine.drain span covers it and not the fetch: same "
+        "attribution against the dispatch-time mask, same "
+        "_needs_flush scheduling",
     "ContinuousBatchingEngine._pipeline_flush":
         "the flush itself",
     "ContinuousBatchingEngine._quarantine":
@@ -254,10 +259,12 @@ FLUSH_SAFE: Dict[str, str] = {
         "delegates to the base admission path, which runs behind "
         "_step_inner's flush (the override only reclaims dead "
         "handoff blobs on failure)",
-    "ContinuousBatchingEngine._admit_sequential":
-        "lane choice only: both call sites (_admit_wave's sequential "
-        "path and _mixed_carve's shape-forced degrades) flush the "
-        "pipeline before handing it the popped wave",
+    "ContinuousBatchingEngine._admit_lanes":
+        "lane choice only, reached through _admit_sequential's "
+        "engine.admit span alone: both of its call sites "
+        "(_admit_wave's sequential path and _mixed_carve's "
+        "shape-forced degrades) flush the pipeline before handing it "
+        "the popped wave",
 }
 
 

@@ -46,6 +46,7 @@ from typing import List, Optional
 import numpy as np
 
 from ..observability import default_ring
+from ..profiler.utils import RecordEvent
 from ..testing import faults
 from . import Config, Predictor
 
@@ -405,41 +406,42 @@ class _GenHandler(BaseHTTPRequestHandler):
             self._reply(200, json.dumps(
                 {"rid": rid, "cancelled": bool(ok)}).encode())
             return
-        try:
-            req = json.loads(self.rfile.read(n))
-            prompt = [int(t) for t in req["prompt"]]
-            max_new = int(req.get("max_new_tokens", 64))
-            deadline = req.get("deadline_s")
-            deadline = None if deadline is None else float(deadline)
-            priority = str(req.get("priority", "normal"))
-            tenant = req.get("tenant")
-            tenant = None if tenant is None else str(tenant)
-        except Exception as e:
-            self._reply(400, f"bad payload: {type(e).__name__}".encode(),
-                        "text/plain")
-            return
-        try:
-            rid, q = srv.submit(prompt, max_new, deadline_s=deadline,
-                                priority=priority, tenant=tenant)
-        except ValueError as e:           # oversized for the pool
-            self._reply(400, f"rejected: {e}".encode(), "text/plain")
-            return
-        except QueueFullError as e:       # backpressure: come back later
-            body = f"queue full: {e}".encode()
-            self.send_response(429)
-            self.send_header("Content-Type", "text/plain")
-            # finite, throughput-derived back-off hint (whole seconds,
-            # rounded up — Retry-After takes integers)
-            self.send_header("Retry-After",
-                             str(max(1, int(-(-e.retry_after // 1)))))
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
-            return
-        except RuntimeError as e:         # engine died: retry elsewhere
-            self._reply(503, f"engine unavailable: {e}".encode(),
-                        "text/plain")
-            return
+        with RecordEvent("server.http"):
+            try:
+                req = json.loads(self.rfile.read(n))
+                prompt = [int(t) for t in req["prompt"]]
+                max_new = int(req.get("max_new_tokens", 64))
+                deadline = req.get("deadline_s")
+                deadline = None if deadline is None else float(deadline)
+                priority = str(req.get("priority", "normal"))
+                tenant = req.get("tenant")
+                tenant = None if tenant is None else str(tenant)
+            except Exception as e:
+                self._reply(400, f"bad payload: {type(e).__name__}".encode(),
+                            "text/plain")
+                return
+            try:
+                rid, q = srv.submit(prompt, max_new, deadline_s=deadline,
+                                    priority=priority, tenant=tenant)
+            except ValueError as e:           # oversized for the pool
+                self._reply(400, f"rejected: {e}".encode(), "text/plain")
+                return
+            except QueueFullError as e:       # backpressure: come back later
+                body = f"queue full: {e}".encode()
+                self.send_response(429)
+                self.send_header("Content-Type", "text/plain")
+                # finite, throughput-derived back-off hint (whole seconds,
+                # rounded up — Retry-After takes integers)
+                self.send_header("Retry-After",
+                                 str(max(1, int(-(-e.retry_after // 1)))))
+                self.send_header("Content-Length", str(len(body)))
+                self.end_headers()
+                self.wfile.write(body)
+                return
+            except RuntimeError as e:         # engine died: retry elsewhere
+                self._reply(503, f"engine unavailable: {e}".encode(),
+                            "text/plain")
+                return
         if path == "/generate":
             toks = []
             while True:
@@ -457,15 +459,17 @@ class _GenHandler(BaseHTTPRequestHandler):
                         # overload shed degraded this request (budget
                         # halved / spec off) — an honest reply says so
                         doc["degraded"] = True
-                    self._reply(200, json.dumps(doc).encode())
+                    with RecordEvent("server.deliver"):
+                        self._reply(200, json.dumps(doc).encode())
                     return
         # STREAMING: one JSON line per token as the engine produces it
         # (chunked transfer — the client reads lines incrementally)
         def chunk(data: bytes):
             faults.fire("stream_write")   # injected client disconnect
-            self.wfile.write(f"{len(data):X}\r\n".encode() + data
-                             + b"\r\n")
-            self.wfile.flush()
+            with RecordEvent("server.deliver"):
+                self.wfile.write(f"{len(data):X}\r\n".encode() + data
+                                 + b"\r\n")
+                self.wfile.flush()
 
         try:
             # the status/header writes sit INSIDE the protected block:

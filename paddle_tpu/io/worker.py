@@ -24,6 +24,8 @@ from typing import Any, Callable, List, Optional
 
 import numpy as np
 
+from ..profiler.utils import RecordEvent, TracerEventType
+
 __all__ = ["np_collate", "MultiprocessBatchIterator"]
 
 
@@ -225,6 +227,20 @@ class MultiprocessBatchIterator:
         if self._rcvd_idx >= len(self._batches):
             self.shutdown()
             raise StopIteration
+        with RecordEvent("dataloader.next", TracerEventType.Dataloader):
+            with RecordEvent("dataloader.wait",
+                             TracerEventType.Dataloader):
+                self._await_batch()
+            batch = self._reorder.pop(self._rcvd_idx)
+            self._rcvd_idx += 1
+            self._dispatch()
+            with RecordEvent("dataloader.to_device",
+                             TracerEventType.Dataloader):
+                return self._to_device(batch)
+
+    def _await_batch(self):
+        """Block on the data queue until the next batch in order is in
+        ``_reorder``."""
         waited = 0.0
         while self._rcvd_idx not in self._reorder:
             try:
@@ -258,10 +274,6 @@ class MultiprocessBatchIterator:
                         "DataLoader shm ring timed out fetching a batch")
                 payload = _shm.unpack_tree(blob)
             self._reorder[idx] = payload
-        batch = self._reorder.pop(self._rcvd_idx)
-        self._rcvd_idx += 1
-        self._dispatch()
-        return self._to_device(batch)
 
     def shutdown(self):
         for iq in self._index_queues:

@@ -349,21 +349,23 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
     rmm = get_op_impl("rmsnorm_matmul", None) \
         if flags.FLAGS_pallas_rmsnorm_matmul and \
         not isinstance(bp["wq"], dict) else None
-    if rmm is not None:
-        # block-entry fusion (PERF.md remaining lever): norm computed
-        # inside each matmul kernel, normalised y never hits HBM
-        q = rmm(x, bp["ln1"], bp["wq"].astype(dt),
-                cfg.rms_norm_eps).reshape(b, s, n, d)
-        k = rmm(x, bp["ln1"], bp["wk"].astype(dt),
-                cfg.rms_norm_eps).reshape(b, s, nkv, d)
-        v = rmm(x, bp["ln1"], bp["wv"].astype(dt),
-                cfg.rms_norm_eps).reshape(b, s, nkv, d)
-    else:
-        y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
-        q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
-        k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
-        v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
-    q, k = _rope(q, k, cfg.rope_theta, mesh)
+    with jax.named_scope("attn_qkv"):
+        if rmm is not None:
+            # block-entry fusion (PERF.md remaining lever): norm computed
+            # inside each matmul kernel, normalised y never hits HBM
+            q = rmm(x, bp["ln1"], bp["wq"].astype(dt),
+                    cfg.rms_norm_eps).reshape(b, s, n, d)
+            k = rmm(x, bp["ln1"], bp["wk"].astype(dt),
+                    cfg.rms_norm_eps).reshape(b, s, nkv, d)
+            v = rmm(x, bp["ln1"], bp["wv"].astype(dt),
+                    cfg.rms_norm_eps).reshape(b, s, nkv, d)
+        else:
+            y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+            q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
+            k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
+            v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
+    with jax.named_scope("rope"):
+        q, k = _rope(q, k, cfg.rope_theta, mesh)
     # GQA stays UN-repeated here: _attention's segmented flash kernel
     # indexes kv heads by group natively (the whole point of GQA — nkv
     # heads of K/V HBM traffic, not n); paths that need full heads
@@ -376,12 +378,19 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
     """Output projection + residual + FFN.  Weight entries may be plain
     arrays (training) or weight-only int8 dicts (the decode serving
     path) — see :func:`_mm`."""
+    b, s, h = x.shape
+    with jax.named_scope("attn_out"):
+        attn = _ckpt_name(attn.reshape(b, s, h), "attn_out")
+        x = x + _mm(attn, bp["wo"], cfg.dtype)
+    with jax.named_scope("mlp"):
+        return _ffn(bp, x, cfg)
+
+
+def _ffn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
+    """ln2 + gated FFN + residual: the ``mlp`` scope's body."""
     from ..flags import flags
     from ..ops.dispatch import get_op_impl
-    b, s, h = x.shape
     dt = cfg.dtype
-    attn = _ckpt_name(attn.reshape(b, s, h), "attn_out")
-    x = x + _mm(attn, bp["wo"], dt)
     res = x
     rmm = get_op_impl("rmsnorm_matmul", None) \
         if flags.FLAGS_pallas_rmsnorm_matmul and \
@@ -409,9 +418,11 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
 def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                    mesh: Optional[Mesh] = None, seg=None):
     """One transformer block; x [b, s, h] in compute dtype."""
-    q, k, v = _block_pre_attn(bp, x, cfg, mesh)
-    attn = _attention(q, k, v, cfg, mesh, seg)
-    return _block_post_attn(bp, x, attn, cfg)
+    with jax.named_scope("block"):
+        q, k, v = _block_pre_attn(bp, x, cfg, mesh)
+        with jax.named_scope("attn"):
+            attn = _attention(q, k, v, cfg, mesh, seg)
+        return _block_post_attn(bp, x, attn, cfg)
 
 
 def _block_forward_flash_saved(bp: Dict[str, Any], x,
@@ -431,9 +442,11 @@ def _block_forward_flash_saved(bp: Dict[str, Any], x,
         lambda bp, x: _block_pre_attn(bp, x, cfg, mesh))
     post = jax.checkpoint(
         lambda bp, x, attn: _block_post_attn(bp, x, attn, cfg))
-    q, k, v = pre(bp, x)
-    attn = _attention(q, k, v, cfg, mesh, seg)
-    return post(bp, x, attn)
+    with jax.named_scope("block"):
+        q, k, v = pre(bp, x)
+        with jax.named_scope("attn"):
+            attn = _attention(q, k, v, cfg, mesh, seg)
+        return post(bp, x, attn)
 
 
 def _remat_wrap(fwd, cfg):
@@ -480,7 +493,8 @@ def _trunk_scan(blocks, x, cfg, mesh, seg=None):
                 out, NamedSharding(mesh, P("dp", "mp", None)))
         return out, None
 
-    x, _ = jax.lax.scan(step, x, blocks)
+    with jax.named_scope("layer_scan"):
+        x, _ = jax.lax.scan(step, x, blocks)
     return x
 
 
@@ -532,7 +546,8 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
             seg_all = jnp.asarray(segment_ids, jnp.int32)
             seg_in = seg_all[:, :-1]
             seg_tg = seg_all[:, 1:]
-        x = jnp.take(params["embed"], inputs, axis=0).astype(dt)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], inputs, axis=0).astype(dt)
         cp_on = False
         if mesh is not None:
             cp_on = bool(cfg.context_parallel and
@@ -557,27 +572,28 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
             x = x.reshape(B, *x.shape[2:])
         else:
             x = _trunk_scan(params["blocks"], x, cfg, mesh, seg_in)
-        x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        if cfg.loss_chunks > 1 and seg_in is not None:
-            import warnings
-            warnings.warn(
-                "packed segment pretraining uses the unchunked loss "
-                "head (masked chunked CE not implemented); at large "
-                "vocab this materialises full [B,S,V] logits",
-                stacklevel=2)
-        if cfg.loss_chunks > 1 and seg_in is None:
-            from ..ops.chunked_loss import chunked_softmax_cross_entropy
-            return chunked_softmax_cross_entropy(
-                x, params["lm_head"], targets, cfg.loss_chunks, dt)
-        logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, -1)
-        ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-        if seg_in is not None:
-            # mask boundary targets AND padding (negative segment ids)
-            valid = jnp.logical_and(seg_in == seg_tg, seg_tg >= 0)
-            valid = valid.astype(jnp.float32)
-            return -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1.0)
-        return -jnp.mean(ll)
+        with jax.named_scope("loss_head"):
+            x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+            if cfg.loss_chunks > 1 and seg_in is not None:
+                import warnings
+                warnings.warn(
+                    "packed segment pretraining uses the unchunked loss "
+                    "head (masked chunked CE not implemented); at large "
+                    "vocab this materialises full [B,S,V] logits",
+                    stacklevel=2)
+            if cfg.loss_chunks > 1 and seg_in is None:
+                from ..ops.chunked_loss import chunked_softmax_cross_entropy
+                return chunked_softmax_cross_entropy(
+                    x, params["lm_head"], targets, cfg.loss_chunks, dt)
+            logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, -1)
+            ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+            if seg_in is not None:
+                # mask boundary targets AND padding (negative segment ids)
+                valid = jnp.logical_and(seg_in == seg_tg, seg_tg >= 0)
+                valid = valid.astype(jnp.float32)
+                return -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1.0)
+            return -jnp.mean(ll)
 
     return forward_loss
 
@@ -787,20 +803,22 @@ def make_train_step(cfg: LlamaPretrainConfig, mesh: Mesh, pp: int = 1,
                     lambda a, b: a + b.astype(a.dtype), g_acc, g)
                 return g_acc, loss
 
-            g0 = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            grads, losses = jax.lax.scan(mb_step, g0, tb)
-            grads = jax.tree_util.tree_map(
-                lambda g: g / accum_steps, grads)
-            loss = jnp.mean(losses)
-        if optimizer == "adafactor":
-            params, opt_state = adafactor_update(
-                params, grads, opt_state, lr=lr,
-                weight_decay=weight_decay, beta1=beta1)
-        else:
-            params, opt_state = adamw_update(params, grads, opt_state,
-                                             lr=lr,
-                                             weight_decay=weight_decay)
+            with jax.named_scope("grad_accum"):
+                g0 = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                grads, losses = jax.lax.scan(mb_step, g0, tb)
+                grads = jax.tree_util.tree_map(
+                    lambda g: g / accum_steps, grads)
+                loss = jnp.mean(losses)
+        with jax.named_scope("optimizer"):
+            if optimizer == "adafactor":
+                params, opt_state = adafactor_update(
+                    params, grads, opt_state, lr=lr,
+                    weight_decay=weight_decay, beta1=beta1)
+            else:
+                params, opt_state = adamw_update(
+                    params, grads, opt_state, lr=lr,
+                    weight_decay=weight_decay)
         return params, opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1))

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..profiler.utils import RecordEvent
 from ..testing import faults
 from .llama_pretrain import (LlamaPretrainConfig, _block_post_attn, _mm,
                              _rms_norm)
@@ -590,6 +591,7 @@ class PagedKVCache:
         dispatch."""
         self.write_pages_batch([(slot, ks, vs, L, first_page)])
 
+    @RecordEvent("admit.write_pages")
     def write_pages_batch(self, entries) -> None:
         """Coalesced page write for a whole admission wave: every
         entry's ``(slot, ks, vs, L, first_page)`` K/V lands through
@@ -973,14 +975,22 @@ def _rope_rows(x, theta, pos):
     """RoPE for one token per row at per-row positions ``pos [B]``;
     x [B, 1, n, d]."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = pos.astype(jnp.float32)[:, None] * inv[None]     # [B, d/2]
-    cos = jnp.cos(freqs)[:, None, None, :]
-    sin = jnp.sin(freqs)[:, None, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate([x1f * cos - x2f * sin,
-                            x2f * cos + x1f * sin], -1).astype(x.dtype)
+    with jax.named_scope("rope"):
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        freqs = pos.astype(jnp.float32)[:, None] * inv[None]     # [B, d/2]
+        cos = jnp.cos(freqs)[:, None, None, :]
+        sin = jnp.sin(freqs)[:, None, None, :]
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
+        return jnp.concatenate([x1f * cos - x2f * sin,
+                                x2f * cos + x1f * sin],
+                               -1).astype(x.dtype)
+
+
+def _embed_rows(embed, toks, dt):
+    """Embedding rows of ``toks`` in the compute dtype."""
+    with jax.named_scope("embed"):
+        return jnp.take(embed, toks, axis=0).astype(dt)
 
 
 def _decode_layer(cfg, bp, kp, vp, xc, tables, lens, page_ids, slots,
@@ -999,26 +1009,35 @@ def _decode_layer(cfg, bp, kp, vp, xc, tables, lens, page_ids, slots,
     nkv = cfg.num_key_value_heads
     dt = cfg.dtype
     B = xc.shape[0]
-    y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-    q = _mm(y, bp["wq"], dt).reshape(B, 1, n, d)
-    k = _mm(y, bp["wk"], dt).reshape(B, 1, nkv, d)
-    v = _mm(y, bp["wv"], dt).reshape(B, 1, nkv, d)
-    q = _rope_rows(q, cfg.rope_theta, lens)
-    k = _rope_rows(k, cfg.rope_theta, lens)
-    if ks is not None:
-        kq, kss = quantize_kv_token(k[:, 0])
-        vq, vss = quantize_kv_token(v[:, 0])
-        kp = kp.at[page_ids, :, slots, :].set(kq)
-        vp = vp.at[page_ids, :, slots, :].set(vq)
-        ks = ks.at[page_ids, :, slots].set(kss)
-        vs = vs.at[page_ids, :, slots].set(vss)
-        attn = paged_decode_attention_q8(q[:, 0], kp, vp, ks, vs,
-                                         tables, lens + 1)
-    else:
-        kp = kp.at[page_ids, :, slots, :].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page_ids, :, slots, :].set(v[:, 0].astype(vp.dtype))
-        attn = paged_decode_attention(q[:, 0], kp, vp, tables, lens + 1)
-    out = _block_post_attn(bp, xc, attn[:, None], cfg)
+    with jax.named_scope("block"):
+        with jax.named_scope("attn_qkv"):
+            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+            q = _mm(y, bp["wq"], dt).reshape(B, 1, n, d)
+            k = _mm(y, bp["wk"], dt).reshape(B, 1, nkv, d)
+            v = _mm(y, bp["wv"], dt).reshape(B, 1, nkv, d)
+        q = _rope_rows(q, cfg.rope_theta, lens)
+        k = _rope_rows(k, cfg.rope_theta, lens)
+        if ks is not None:
+            with jax.named_scope("kv_write"):
+                kq, kss = quantize_kv_token(k[:, 0])
+                vq, vss = quantize_kv_token(v[:, 0])
+                kp = kp.at[page_ids, :, slots, :].set(kq)
+                vp = vp.at[page_ids, :, slots, :].set(vq)
+                ks = ks.at[page_ids, :, slots].set(kss)
+                vs = vs.at[page_ids, :, slots].set(vss)
+            with jax.named_scope("paged_attn"):
+                attn = paged_decode_attention_q8(
+                    q[:, 0], kp, vp, ks, vs, tables, lens + 1)
+        else:
+            with jax.named_scope("kv_write"):
+                kp = kp.at[page_ids, :, slots, :].set(
+                    k[:, 0].astype(kp.dtype))
+                vp = vp.at[page_ids, :, slots, :].set(
+                    v[:, 0].astype(vp.dtype))
+            with jax.named_scope("paged_attn"):
+                attn = paged_decode_attention(q[:, 0], kp, vp, tables,
+                                              lens + 1)
+        out = _block_post_attn(bp, xc, attn[:, None], cfg)
     return out, kp, vp, ks, vs
 
 
@@ -1029,24 +1048,25 @@ def _pick_token(logits, temperature, key, top_k: int = 0,
     the sampling ops the generation ops feed,
     incubate top_p_sampling).  ``top_k=0`` disables k-filtering;
     ``top_p=1.0`` disables nucleus filtering; both compose."""
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1)
-    logits = logits / temperature
-    if top_k and top_k > 0:
-        kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-        logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if top_p < 1.0:
-        sorted_l = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_l, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        # keep the smallest prefix with cumulative mass >= top_p (the
-        # first token is always kept: cum shifted right by one)
-        keep = jnp.concatenate(
-            [jnp.zeros_like(cum[..., :1]), cum[..., :-1]], -1) < top_p
-        cutoff = jnp.min(jnp.where(keep, sorted_l, jnp.inf), axis=-1,
-                         keepdims=True)
-        logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-    return jax.random.categorical(key, logits, -1)
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1)
+        logits = logits / temperature
+        if top_k and top_k > 0:
+            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+            logits = jnp.where(logits < kth, -jnp.inf, logits)
+        if top_p < 1.0:
+            sorted_l = jnp.sort(logits, axis=-1)[..., ::-1]
+            probs = jax.nn.softmax(sorted_l, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            # keep the smallest prefix with cumulative mass >= top_p (the
+            # first token is always kept: cum shifted right by one)
+            keep = jnp.concatenate(
+                [jnp.zeros_like(cum[..., :1]), cum[..., :-1]], -1) < top_p
+            cutoff = jnp.min(jnp.where(keep, sorted_l, jnp.inf), axis=-1,
+                             keepdims=True)
+            logits = jnp.where(logits < cutoff, -jnp.inf, logits)
+        return jax.random.categorical(key, logits, -1)
 
 
 def _cfg_key(cfg) -> str:
@@ -1067,8 +1087,10 @@ def _build_step_fns(cfg: LlamaPretrainConfig, temperature: float,
     dt = cfg.dtype
 
     def tail(x, params):
-        h = _rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps)
-        return _mm(h, params["lm_head"], dt).astype(jnp.float32)
+        with jax.named_scope("logits"):
+            h = _rms_norm(x[:, 0], params["final_norm"],
+                          cfg.rms_norm_eps)
+            return _mm(h, params["lm_head"], dt).astype(jnp.float32)
 
     # pools ride the scan xs->ys (per-layer slices update in place
     # under donation — a carry formulation was measured to copy the
@@ -1077,7 +1099,7 @@ def _build_step_fns(cfg: LlamaPretrainConfig, temperature: float,
     def step(params, kpool, vpool, tables, lens, tok, key):
         B = tok.shape[0]
         page = kpool.shape[3]
-        x = jnp.take(params["embed"], tok[:, None], axis=0).astype(dt)
+        x = _embed_rows(params["embed"], tok[:, None], dt)
         page_ids = tables[jnp.arange(B), lens // page]       # [B]
         slots = lens % page                                  # [B]
 
@@ -1087,8 +1109,9 @@ def _build_step_fns(cfg: LlamaPretrainConfig, temperature: float,
                 cfg, bp, kp, vp, carry, tables, lens, page_ids, slots)
             return out, (kp, vp)
 
-        x, (kpool, vpool) = jax.lax.scan(
-            layer, x, (params["blocks"], kpool, vpool))
+        with jax.named_scope("pool_carry"):
+            x, (kpool, vpool) = jax.lax.scan(
+                layer, x, (params["blocks"], kpool, vpool))
         logits = tail(x, params)
         nxt = _pick_token(logits, temperature, key, top_k, top_p)
         if with_logits:
@@ -1099,7 +1122,7 @@ def _build_step_fns(cfg: LlamaPretrainConfig, temperature: float,
                 tok, key):
         B = tok.shape[0]
         page = kpool.shape[3]
-        x = jnp.take(params["embed"], tok[:, None], axis=0).astype(dt)
+        x = _embed_rows(params["embed"], tok[:, None], dt)
         page_ids = tables[jnp.arange(B), lens // page]
         slots = lens % page
 
@@ -1110,8 +1133,10 @@ def _build_step_fns(cfg: LlamaPretrainConfig, temperature: float,
                 ks, vs)
             return out, (kp, vp, ks, vs)
 
-        x, (kpool, vpool, kscale, vscale) = jax.lax.scan(
-            layer, x, (params["blocks"], kpool, vpool, kscale, vscale))
+        with jax.named_scope("pool_carry"):
+            x, (kpool, vpool, kscale, vscale) = jax.lax.scan(
+                layer, x,
+                (params["blocks"], kpool, vpool, kscale, vscale))
         logits = tail(x, params)
         nxt = _pick_token(logits, temperature, key, top_k, top_p)
         if with_logits:
@@ -1320,12 +1345,13 @@ def _embed_vocab_parallel(embed_l, tok, ax: str, dt):
     psum across the mp axis.  ``tok`` may be any shape; shared by the
     TP decode step and both TP prefill programs so their embedding
     numerics can never fork."""
-    V_l = embed_l.shape[0]
-    start = jax.lax.axis_index(ax) * V_l
-    local = tok - start
-    ok = (local >= 0) & (local < V_l)
-    x = jnp.take(embed_l, jnp.clip(local, 0, V_l - 1), axis=0)
-    return jax.lax.psum(jnp.where(ok[..., None], x, 0).astype(dt), ax)
+    with jax.named_scope("embed"):
+        V_l = embed_l.shape[0]
+        start = jax.lax.axis_index(ax) * V_l
+        local = tok - start
+        ok = (local >= 0) & (local < V_l)
+        x = jnp.take(embed_l, jnp.clip(local, 0, V_l - 1), axis=0)
+        return jax.lax.psum(jnp.where(ok[..., None], x, 0).astype(dt), ax)
 
 
 def _make_q8_allreduce(ax: str, mp: int, Hc: int, block: int):
@@ -1464,45 +1490,61 @@ def _build_tp_inner(cfg: LlamaPretrainConfig, mesh,
                 bp, kp, vp = inp
                 ks = vs = None
             xc = carry
-            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-            q = _mm(y, bp["wq"], dt).reshape(B, n_l, d)
-            k = _mm(y, bp["wk"], dt).reshape(B, 1, nkv_l, d)
-            v = _mm(y, bp["wv"], dt).reshape(B, nkv_l, d)
+            with jax.named_scope("block"):
+                out = block(bp, kp, vp, ks, vs, xc)
+            return out[0], (out[1:] if q8 else out[1:3])
+
+        def block(bp, kp, vp, ks, vs, xc):
+            with jax.named_scope("attn_qkv"):
+                y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+                q = _mm(y, bp["wq"], dt).reshape(B, n_l, d)
+                k = _mm(y, bp["wk"], dt).reshape(B, 1, nkv_l, d)
+                v = _mm(y, bp["wv"], dt).reshape(B, nkv_l, d)
             q = _rope_rows(q[:, None], cfg.rope_theta, lens)[:, 0]
             k = _rope_rows(k, cfg.rope_theta, lens)[:, 0]
             if q8:
                 # per LOCAL head quantisation — scales shard with the
                 # heads, nothing crosses the mp axis
-                kq, kss = quantize_kv_token(k)
-                vq, vss = quantize_kv_token(v)
-                kp = kp.at[page_ids, :, slots, :].set(kq)
-                vp = vp.at[page_ids, :, slots, :].set(vq)
-                ks = ks.at[page_ids, :, slots].set(kss)
-                vs = vs.at[page_ids, :, slots].set(vss)
-                attn = paged_decode_attention_q8(q, kp, vp, ks, vs,
-                                                 tables, lens + 1)
+                with jax.named_scope("kv_write"):
+                    kq, kss = quantize_kv_token(k)
+                    vq, vss = quantize_kv_token(v)
+                    kp = kp.at[page_ids, :, slots, :].set(kq)
+                    vp = vp.at[page_ids, :, slots, :].set(vq)
+                    ks = ks.at[page_ids, :, slots].set(kss)
+                    vs = vs.at[page_ids, :, slots].set(vss)
+                with jax.named_scope("paged_attn"):
+                    attn = paged_decode_attention_q8(
+                        q, kp, vp, ks, vs, tables, lens + 1)
             else:
-                kp = kp.at[page_ids, :, slots, :].set(k.astype(kp.dtype))
-                vp = vp.at[page_ids, :, slots, :].set(v.astype(vp.dtype))
-                attn = paged_decode_attention(q, kp, vp, tables,
-                                              lens + 1)
-            xc = xc + reduce_out(attn.reshape(B, n_l * d),
-                                 bp["wo"])            # row-parallel
-            res = xc
-            y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
-            act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
-                   * _mm(y2, bp["w_up"], dt))
-            return res + reduce_out(act, bp["w_down"]), \
-                ((kp, vp, ks, vs) if q8 else (kp, vp))
+                with jax.named_scope("kv_write"):
+                    kp = kp.at[page_ids, :, slots, :].set(
+                        k.astype(kp.dtype))
+                    vp = vp.at[page_ids, :, slots, :].set(
+                        v.astype(vp.dtype))
+                with jax.named_scope("paged_attn"):
+                    attn = paged_decode_attention(q, kp, vp, tables,
+                                                  lens + 1)
+            with jax.named_scope("attn_out"):
+                xc = xc + reduce_out(attn.reshape(B, n_l * d),
+                                     bp["wo"])        # row-parallel
+            with jax.named_scope("mlp"):
+                y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
+                act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
+                       * _mm(y2, bp["w_up"], dt))
+                return (xc + reduce_out(act, bp["w_down"]), kp, vp,
+                        ks, vs)
 
         xs = (params["blocks"], kpool, vpool)
         if q8:
             xs = xs + (kscale, vscale)
-        x, pools = jax.lax.scan(layer, x, xs)
-        h = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        logits_l = _mm(h, params["lm_head"], dt).astype(jnp.float32)
-        logits = jax.lax.all_gather(logits_l, ax, axis=1,
-                                    tiled=True)       # [B, V]
+        with jax.named_scope("pool_carry"):
+            x, pools = jax.lax.scan(layer, x, xs)
+        with jax.named_scope("logits"):
+            h = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+            logits_l = _mm(h, params["lm_head"],
+                           dt).astype(jnp.float32)
+            logits = jax.lax.all_gather(logits_l, ax, axis=1,
+                                        tiled=True)   # [B, V]
         nxt = _pick_token(logits, temperature, key, top_k, top_p)
         if q8:
             kpool, vpool, kscale, vscale = pools
@@ -1718,8 +1760,7 @@ def make_paged_generate_fused(cfg: LlamaPretrainConfig,
 
         def dec_step(carry, _):
             kpool, vpool, kscale, vscale, tok, lens, key = carry
-            x = jnp.take(params["embed"], tok[:, None],
-                         axis=0).astype(dt)
+            x = _embed_rows(params["embed"], tok[:, None], dt)
             page_ids = tables[jnp.arange(B), lens // page]
             slots = lens % page
 
@@ -1744,9 +1785,11 @@ def make_paged_generate_fused(cfg: LlamaPretrainConfig,
 
                 x2, (kpool, vpool) = jax.lax.scan(
                     layer, x, (params["blocks"], kpool, vpool))
-            h = _rms_norm(x2[:, 0], params["final_norm"],
-                          cfg.rms_norm_eps)
-            logits = _mm(h, params["lm_head"], dt).astype(jnp.float32)
+            with jax.named_scope("logits"):
+                h = _rms_norm(x2[:, 0], params["final_norm"],
+                              cfg.rms_norm_eps)
+                logits = _mm(h, params["lm_head"],
+                             dt).astype(jnp.float32)
             key, sub = jax.random.split(key)
             nxt = _pick_token(logits, temperature, sub, top_k, top_p)
             return (kpool, vpool, kscale, vscale, nxt, lens + 1,
@@ -1785,7 +1828,7 @@ def _prefill(cfg: LlamaPretrainConfig):
     @jax.jit
     def prefill(params, prompt):
         B, S = prompt.shape
-        x = jnp.take(params["embed"], prompt, axis=0).astype(dt)
+        x = _embed_rows(params["embed"], prompt, dt)
         causal = jnp.tril(jnp.ones((S, S), bool))
 
         def pre_layer(carry, bp):
@@ -1811,17 +1854,18 @@ def _rope_at(x, theta, pos):
     (chunked prefill: chunk tokens sit at ctx_len + arange(C));
     x [B, S, n, d].  Same split-half convention as
     llama_pretrain._rope (the cached pages were written by it)."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = pos.astype(jnp.float32)[..., None] * inv   # [(B,) S, d/2]
-    if freqs.ndim == 2:
-        freqs = freqs[None]                            # [1, S, d/2]
-    cos = jnp.cos(freqs)[:, :, None, :]
-    sin = jnp.sin(freqs)[:, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate([x1f * cos - x2f * sin,
-                            x2f * cos + x1f * sin], -1).astype(x.dtype)
+    with jax.named_scope("rope"):
+        d = x.shape[-1]
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        freqs = pos.astype(jnp.float32)[..., None] * inv   # [(B,) S, d/2]
+        if freqs.ndim == 2:
+            freqs = freqs[None]                            # [1, S, d/2]
+        cos = jnp.cos(freqs)[:, :, None, :]
+        sin = jnp.sin(freqs)[:, :, None, :]
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
+        return jnp.concatenate([x1f * cos - x2f * sin,
+                                x2f * cos + x1f * sin], -1).astype(x.dtype)
 
 
 _chunk_prefill_cache: dict = {}
@@ -1857,7 +1901,7 @@ def _prefill_chunk(cfg: LlamaPretrainConfig, q8: bool):
         P = table.shape[0]
         page = kpool.shape[3]
         S_ctx = P * page
-        x = jnp.take(params["embed"], toks, axis=0).astype(dt)
+        x = _embed_rows(params["embed"], toks, dt)
         pos = ctx_len + jnp.arange(C, dtype=jnp.int32)
         # visibility: cached slots < ctx_len, then causal within chunk
         ctx_vis = jnp.arange(S_ctx, dtype=jnp.int32) < ctx_len
@@ -1971,7 +2015,7 @@ def _packed_prefill_body(cfg: LlamaPretrainConfig, q8: bool,
     def run(params, toks, seg, pos, kpool, vpool, kscale, vscale,
             hist_page, hist_slot, pool_hist, stream_src, stream_hist):
         B, T = toks.shape                      # B == 1
-        x = jnp.take(params["embed"], toks, axis=0).astype(dt)
+        x = _embed_rows(params["embed"], toks, dt)
         # static routing (trace-time): the Pallas kernel's block
         # skipping needs a dividing block and a real TPU; otherwise the
         # XLA mask keeps bitwise parity with the dense prefill path
@@ -1991,38 +2035,42 @@ def _packed_prefill_body(cfg: LlamaPretrainConfig, q8: bool,
                 bp, kp_l, vp_l = inp
                 ks_l = vs_l = None
             xc = carry
-            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-            q = _mm(y, bp["wq"], dt).reshape(B, T, n, d)
-            k = _mm(y, bp["wk"], dt).reshape(B, T, nkv, d)
-            v = _mm(y, bp["wv"], dt).reshape(B, T, nkv, d)
-            q = _rope_at(q, cfg.rope_theta, pos)
-            k = _rope_at(k, cfg.rope_theta, pos)
-            if with_hist:
-                kh = kp_l[hist_page, :, hist_slot]     # [T, nkv, d]
-                vh = vp_l[hist_page, :, hist_slot]
-                if q8:
-                    kh = (kh.astype(jnp.float32)
-                          * ks_l[hist_page, :, hist_slot][..., None])
-                    vh = (vh.astype(jnp.float32)
-                          * vs_l[hist_page, :, hist_slot][..., None])
-                sel = pool_hist[None, :, None, None]
-                k = jnp.where(sel, kh.astype(dt)[None], k)
-                v = jnp.where(sel, vh.astype(dt)[None], v)
-                sel2 = stream_hist[None, :, None, None]
-                k = jnp.where(sel2, k[:, stream_src], k)
-                v = jnp.where(sel2, v[:, stream_src], v)
-            if use_kernel:
-                attn = flash_attention_segmented(q, k, v, seg,
-                                                 causal=True)
-            else:
-                attn = _grouped_attn(q, k, v, mask)
-            out = _block_post_attn(bp, xc, attn, cfg)
+            with jax.named_scope("block"):
+                with jax.named_scope("attn_qkv"):
+                    y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+                    q = _mm(y, bp["wq"], dt).reshape(B, T, n, d)
+                    k = _mm(y, bp["wk"], dt).reshape(B, T, nkv, d)
+                    v = _mm(y, bp["wv"], dt).reshape(B, T, nkv, d)
+                q = _rope_at(q, cfg.rope_theta, pos)
+                k = _rope_at(k, cfg.rope_theta, pos)
+                if with_hist:
+                    kh = kp_l[hist_page, :, hist_slot]     # [T, nkv, d]
+                    vh = vp_l[hist_page, :, hist_slot]
+                    if q8:
+                        kh = (kh.astype(jnp.float32)
+                              * ks_l[hist_page, :, hist_slot][..., None])
+                        vh = (vh.astype(jnp.float32)
+                              * vs_l[hist_page, :, hist_slot][..., None])
+                    sel = pool_hist[None, :, None, None]
+                    k = jnp.where(sel, kh.astype(dt)[None], k)
+                    v = jnp.where(sel, vh.astype(dt)[None], v)
+                    sel2 = stream_hist[None, :, None, None]
+                    k = jnp.where(sel2, k[:, stream_src], k)
+                    v = jnp.where(sel2, v[:, stream_src], v)
+                with jax.named_scope("varlen_attn"):
+                    if use_kernel:
+                        attn = flash_attention_segmented(q, k, v, seg,
+                                                         causal=True)
+                    else:
+                        attn = _grouped_attn(q, k, v, mask)
+                out = _block_post_attn(bp, xc, attn, cfg)
             return out, (k[0], v[0])
 
         xs = (params["blocks"], kpool, vpool)
         if q8:
             xs = xs + (kscale, vscale)
-        x, (ks, vs) = jax.lax.scan(layer, x, xs)
+        with jax.named_scope("layer_scan"):
+            x, (ks, vs) = jax.lax.scan(layer, x, xs)
         return x, ks, vs
 
     _packed_body_cache[(_cfg_key(cfg), q8, with_hist)] = run
@@ -2106,44 +2154,50 @@ def _packed_prefill_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool,
                 bp, kp_l, vp_l = inp
                 ks_l = vs_l = None
             xc = carry
-            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-            q = _mm(y, bp["wq"], dt).reshape(B, T, n_l, d)
-            k = _mm(y, bp["wk"], dt).reshape(B, T, nkv_l, d)
-            v = _mm(y, bp["wv"], dt).reshape(B, T, nkv_l, d)
-            q = _rope_at(q, cfg.rope_theta, pos)
-            k = _rope_at(k, cfg.rope_theta, pos)
-            if with_hist:
-                kh = kp_l[hist_page, :, hist_slot]   # [T, nkv_l, d]
-                vh = vp_l[hist_page, :, hist_slot]
-                if q8:
-                    kh = (kh.astype(jnp.float32)
-                          * ks_l[hist_page, :, hist_slot][..., None])
-                    vh = (vh.astype(jnp.float32)
-                          * vs_l[hist_page, :, hist_slot][..., None])
-                sel = pool_hist[None, :, None, None]
-                k = jnp.where(sel, kh.astype(dt)[None], k)
-                v = jnp.where(sel, vh.astype(dt)[None], v)
-                sel2 = stream_hist[None, :, None, None]
-                k = jnp.where(sel2, k[:, stream_src], k)
-                v = jnp.where(sel2, v[:, stream_src], v)
-            if use_kernel:
-                attn = flash_attention_segmented(q, k, v, seg,
-                                                 causal=True)
-            else:
-                attn = _grouped_attn(q, k, v, mask)
-            o = _mm(attn.reshape(B, T, n_l * d), bp["wo"], dt)
-            xc = xc + jax.lax.psum(o, ax)             # row-parallel
-            res = xc
-            y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
-            act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
-                   * _mm(y2, bp["w_up"], dt))
-            ffn = _mm(act, bp["w_down"], dt)
-            return res + jax.lax.psum(ffn, ax), (k[0], v[0])
+            with jax.named_scope("block"):
+                with jax.named_scope("attn_qkv"):
+                    y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+                    q = _mm(y, bp["wq"], dt).reshape(B, T, n_l, d)
+                    k = _mm(y, bp["wk"], dt).reshape(B, T, nkv_l, d)
+                    v = _mm(y, bp["wv"], dt).reshape(B, T, nkv_l, d)
+                q = _rope_at(q, cfg.rope_theta, pos)
+                k = _rope_at(k, cfg.rope_theta, pos)
+                if with_hist:
+                    kh = kp_l[hist_page, :, hist_slot]   # [T, nkv_l, d]
+                    vh = vp_l[hist_page, :, hist_slot]
+                    if q8:
+                        kh = (kh.astype(jnp.float32)
+                              * ks_l[hist_page, :, hist_slot][..., None])
+                        vh = (vh.astype(jnp.float32)
+                              * vs_l[hist_page, :, hist_slot][..., None])
+                    sel = pool_hist[None, :, None, None]
+                    k = jnp.where(sel, kh.astype(dt)[None], k)
+                    v = jnp.where(sel, vh.astype(dt)[None], v)
+                    sel2 = stream_hist[None, :, None, None]
+                    k = jnp.where(sel2, k[:, stream_src], k)
+                    v = jnp.where(sel2, v[:, stream_src], v)
+                with jax.named_scope("varlen_attn"):
+                    if use_kernel:
+                        attn = flash_attention_segmented(q, k, v, seg,
+                                                         causal=True)
+                    else:
+                        attn = _grouped_attn(q, k, v, mask)
+                with jax.named_scope("attn_out"):
+                    o = _mm(attn.reshape(B, T, n_l * d), bp["wo"], dt)
+                    xc = xc + jax.lax.psum(o, ax)             # row-parallel
+                with jax.named_scope("mlp"):
+                    res = xc
+                    y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
+                    act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
+                           * _mm(y2, bp["w_up"], dt))
+                    ffn = _mm(act, bp["w_down"], dt)
+                    return res + jax.lax.psum(ffn, ax), (k[0], v[0])
 
         xs = (params["blocks"], kpool, vpool)
         if q8:
             xs = xs + (kscale, vscale)
-        x, (ks, vs) = jax.lax.scan(layer, x, xs)
+        with jax.named_scope("layer_scan"):
+            x, (ks, vs) = jax.lax.scan(layer, x, xs)
         return x, ks, vs
 
     pool_spec = P(None, None, "mp", None, None)
@@ -2187,7 +2241,7 @@ def _prefill_chunk_batched(cfg: LlamaPretrainConfig):
         P = tables.shape[1]
         page = kpool.shape[3]
         S_ctx = P * page
-        x = jnp.take(params["embed"], toks, axis=0).astype(dt)
+        x = _embed_rows(params["embed"], toks, dt)
         pos = ctx_len[:, None] + jnp.arange(C, dtype=jnp.int32)
         ctx_vis = (jnp.arange(S_ctx, dtype=jnp.int32)[None]
                    < ctx_len[:, None])                 # [B, S_ctx]
@@ -2405,19 +2459,20 @@ def make_mixed_step(cfg: LlamaPretrainConfig,
         # per-token page scatter of the stream K/V (fresh chunk slots
         # land in their row's pages; history/padding slots land on
         # junk page 0 — DMA-valid, never read below lens)
-        if q8:
-            ks, ksc = quantize_kv_token(ks)
-            vs, vsc = quantize_kv_token(vs)
-        kpool = kpool.at[:, dest_page, :, dest_slot, :].set(
-            jnp.transpose(ks, (1, 0, 2, 3)).astype(kpool.dtype))
-        vpool = vpool.at[:, dest_page, :, dest_slot, :].set(
-            jnp.transpose(vs, (1, 0, 2, 3)).astype(vpool.dtype))
-        if q8:
-            kscale = kscale.at[:, dest_page, :, dest_slot].set(
-                jnp.transpose(ksc, (1, 0, 2)))
-            vscale = vscale.at[:, dest_page, :, dest_slot].set(
-                jnp.transpose(vsc, (1, 0, 2)))
-        return kpool, vpool, kscale, vscale
+        with jax.named_scope("kv_write"):
+            if q8:
+                ks, ksc = quantize_kv_token(ks)
+                vs, vsc = quantize_kv_token(vs)
+            kpool = kpool.at[:, dest_page, :, dest_slot, :].set(
+                jnp.transpose(ks, (1, 0, 2, 3)).astype(kpool.dtype))
+            vpool = vpool.at[:, dest_page, :, dest_slot, :].set(
+                jnp.transpose(vs, (1, 0, 2, 3)).astype(vpool.dtype))
+            if q8:
+                kscale = kscale.at[:, dest_page, :, dest_slot].set(
+                    jnp.transpose(ksc, (1, 0, 2)))
+                vscale = vscale.at[:, dest_page, :, dest_slot].set(
+                    jnp.transpose(vsc, (1, 0, 2)))
+            return kpool, vpool, kscale, vscale
 
     def fn(params, kpool, vpool, kscale, vscale, tables, lens, tok,
            active, remaining, eos, key, p_toks, p_seg, p_pos,
@@ -2436,9 +2491,10 @@ def make_mixed_step(cfg: LlamaPretrainConfig,
         # first-token sampling: each completing segment's LAST real
         # position through the shared logits tail (the same eager
         # tail the sequential lanes use, so greedy outputs match)
-        h = _rms_norm(x[0, sample_idx], params["final_norm"],
-                      cfg.rms_norm_eps)
-        logits = _mm(h, params["lm_head"], dt).astype(jnp.float32)
+        with jax.named_scope("logits"):
+            h = _rms_norm(x[0, sample_idx], params["final_norm"],
+                          cfg.rms_norm_eps)
+            logits = _mm(h, params["lm_head"], dt).astype(jnp.float32)
         sampled = _pick_token(logits, temperature, k_smp, top_k,
                               top_p)
         kpool, vpool, kscale, vscale = scatter(
@@ -2522,7 +2578,7 @@ def _spec_verify_body(cfg: LlamaPretrainConfig, q8: bool):
         P = tables.shape[1]
         page = kpool.shape[3]
         S_ctx = P * page
-        x = jnp.take(params["embed"], toks, axis=0).astype(dt)
+        x = _embed_rows(params["embed"], toks, dt)
         pos = ctx_len[:, None] + jnp.arange(C, dtype=jnp.int32)
         ctx_vis = (jnp.arange(S_ctx, dtype=jnp.int32)[None]
                    < ctx_len[:, None])                 # [B, S_ctx]
@@ -2551,23 +2607,27 @@ def _spec_verify_body(cfg: LlamaPretrainConfig, q8: bool):
                 bp, kp_l, vp_l = inp
                 ks_l = vs_l = None
             xc = carry
-            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-            q = _mm(y, bp["wq"], dt).reshape(B, C, n, d)
-            k = _mm(y, bp["wk"], dt).reshape(B, C, nkv, d)
-            v = _mm(y, bp["wv"], dt).reshape(B, C, nkv, d)
-            q = _rope_at(q, cfg.rope_theta, pos)
-            k = _rope_at(k, cfg.rope_theta, pos)
-            ku, vu = (_qdq(k), _qdq(v)) if q8 else (k, v)
-            ck = jnp.concatenate([gather_ctx(kp_l, ks_l), ku], axis=1)
-            cv = jnp.concatenate([gather_ctx(vp_l, vs_l), vu], axis=1)
-            attn = _grouped_attn(q, ck, cv, mask)
-            out = _block_post_attn(bp, xc, attn, cfg)
+            with jax.named_scope("block"):
+                with jax.named_scope("attn_qkv"):
+                    y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+                    q = _mm(y, bp["wq"], dt).reshape(B, C, n, d)
+                    k = _mm(y, bp["wk"], dt).reshape(B, C, nkv, d)
+                    v = _mm(y, bp["wv"], dt).reshape(B, C, nkv, d)
+                q = _rope_at(q, cfg.rope_theta, pos)
+                k = _rope_at(k, cfg.rope_theta, pos)
+                with jax.named_scope("attn"):
+                    ku, vu = (_qdq(k), _qdq(v)) if q8 else (k, v)
+                    ck = jnp.concatenate([gather_ctx(kp_l, ks_l), ku], axis=1)
+                    cv = jnp.concatenate([gather_ctx(vp_l, vs_l), vu], axis=1)
+                    attn = _grouped_attn(q, ck, cv, mask)
+                out = _block_post_attn(bp, xc, attn, cfg)
             return out, (k, v)
 
         xs = (params["blocks"], kpool, vpool)
         if q8:
             xs = xs + (kscale, vscale)
-        x, (ks, vs) = jax.lax.scan(layer, x, xs)
+        with jax.named_scope("layer_scan"):
+            x, (ks, vs) = jax.lax.scan(layer, x, xs)
         return x, ks, vs
 
     _spec_verify_cache[(_cfg_key(cfg), q8)] = run
@@ -2646,29 +2706,35 @@ def _spec_verify_body_tp(cfg: LlamaPretrainConfig, mesh, q8: bool):
                 bp, kp_l, vp_l = inp
                 ks_l = vs_l = None
             xc = carry
-            y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
-            q = _mm(y, bp["wq"], dt).reshape(B, C, n_l, d)
-            k = _mm(y, bp["wk"], dt).reshape(B, C, nkv_l, d)
-            v = _mm(y, bp["wv"], dt).reshape(B, C, nkv_l, d)
-            q = _rope_at(q, cfg.rope_theta, pos)
-            k = _rope_at(k, cfg.rope_theta, pos)
-            ku, vu = (_qdq(k), _qdq(v)) if q8 else (k, v)
-            ck = jnp.concatenate([gather_ctx(kp_l, ks_l), ku], axis=1)
-            cv = jnp.concatenate([gather_ctx(vp_l, vs_l), vu], axis=1)
-            attn = _grouped_attn(q, ck, cv, mask)
-            o = _mm(attn.reshape(B, C, n_l * d), bp["wo"], dt)
-            xc = xc + jax.lax.psum(o, ax)
-            res = xc
-            y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
-            act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
-                   * _mm(y2, bp["w_up"], dt))
-            ffn = _mm(act, bp["w_down"], dt)
-            return res + jax.lax.psum(ffn, ax), (k, v)
+            with jax.named_scope("block"):
+                with jax.named_scope("attn_qkv"):
+                    y = _rms_norm(xc, bp["ln1"], cfg.rms_norm_eps)
+                    q = _mm(y, bp["wq"], dt).reshape(B, C, n_l, d)
+                    k = _mm(y, bp["wk"], dt).reshape(B, C, nkv_l, d)
+                    v = _mm(y, bp["wv"], dt).reshape(B, C, nkv_l, d)
+                q = _rope_at(q, cfg.rope_theta, pos)
+                k = _rope_at(k, cfg.rope_theta, pos)
+                with jax.named_scope("attn"):
+                    ku, vu = (_qdq(k), _qdq(v)) if q8 else (k, v)
+                    ck = jnp.concatenate([gather_ctx(kp_l, ks_l), ku], axis=1)
+                    cv = jnp.concatenate([gather_ctx(vp_l, vs_l), vu], axis=1)
+                    attn = _grouped_attn(q, ck, cv, mask)
+                with jax.named_scope("attn_out"):
+                    o = _mm(attn.reshape(B, C, n_l * d), bp["wo"], dt)
+                    xc = xc + jax.lax.psum(o, ax)
+                with jax.named_scope("mlp"):
+                    res = xc
+                    y2 = _rms_norm(xc, bp["ln2"], cfg.rms_norm_eps)
+                    act = (jax.nn.silu(_mm(y2, bp["w_gate"], dt))
+                           * _mm(y2, bp["w_up"], dt))
+                    ffn = _mm(act, bp["w_down"], dt)
+                    return res + jax.lax.psum(ffn, ax), (k, v)
 
         xs = (params["blocks"], kpool, vpool)
         if q8:
             xs = xs + (kscale, vscale)
-        x, (ks, vs) = jax.lax.scan(layer, x, xs)
+        with jax.named_scope("layer_scan"):
+            x, (ks, vs) = jax.lax.scan(layer, x, xs)
         return x, ks, vs
 
     pool_spec = P(None, None, "mp", None, None)
@@ -2848,36 +2914,39 @@ def make_spec_step(cfg: LlamaPretrainConfig, gamma: int,
         sc_v = vscale if q8 else jnp.zeros((1,), jnp.float32)
         x, ks, vs = verify(params, cand, kpool, vpool, sc_k, sc_v,
                            tables, lens)
-        h = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        logits = _mm(h, params["lm_head"], dt).astype(jnp.float32)
-        g = jnp.argmax(logits, axis=-1).astype(tok.dtype)  # [B, C]
+        with jax.named_scope("logits"):
+            h = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+            logits = _mm(h, params["lm_head"], dt).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            g = jnp.argmax(logits, axis=-1).astype(tok.dtype)  # [B, C]
 
         # scatter the C fresh K/V per row into the target pages;
         # inactive rows and beyond-capacity positions steer to junk
         # page 0 (beyond-lens entries are masked stale until the next
         # round overwrites them)
-        pos = lens[:, None] + jnp.arange(C, dtype=lens.dtype)
-        ok = active[:, None] & (pos < S_ctx)
-        pidx = jnp.where(ok, pos // page, 0)
-        dest_page = jnp.where(
-            ok, jnp.take_along_axis(tables, pidx, axis=1), 0)
-        dp = dest_page.reshape(-1)
-        ds = (pos % page).reshape(-1)
-        Lyr, nkv_o, d_o = ks.shape[0], ks.shape[3], ks.shape[4]
-        ksf = ks.reshape(Lyr, B * C, nkv_o, d_o)
-        vsf = vs.reshape(Lyr, B * C, nkv_o, d_o)
-        if q8:
-            ksf, ksc2 = quantize_kv_token(ksf)
-            vsf, vsc2 = quantize_kv_token(vsf)
-        kpool = kpool.at[:, dp, :, ds, :].set(
-            jnp.transpose(ksf, (1, 0, 2, 3)).astype(kpool.dtype))
-        vpool = vpool.at[:, dp, :, ds, :].set(
-            jnp.transpose(vsf, (1, 0, 2, 3)).astype(vpool.dtype))
-        if q8:
-            kscale = kscale.at[:, dp, :, ds].set(
-                jnp.transpose(ksc2, (1, 0, 2)))
-            vscale = vscale.at[:, dp, :, ds].set(
-                jnp.transpose(vsc2, (1, 0, 2)))
+        with jax.named_scope("kv_write"):
+            pos = lens[:, None] + jnp.arange(C, dtype=lens.dtype)
+            ok = active[:, None] & (pos < S_ctx)
+            pidx = jnp.where(ok, pos // page, 0)
+            dest_page = jnp.where(
+                ok, jnp.take_along_axis(tables, pidx, axis=1), 0)
+            dp = dest_page.reshape(-1)
+            ds = (pos % page).reshape(-1)
+            Lyr, nkv_o, d_o = ks.shape[0], ks.shape[3], ks.shape[4]
+            ksf = ks.reshape(Lyr, B * C, nkv_o, d_o)
+            vsf = vs.reshape(Lyr, B * C, nkv_o, d_o)
+            if q8:
+                ksf, ksc2 = quantize_kv_token(ksf)
+                vsf, vsc2 = quantize_kv_token(vsf)
+            kpool = kpool.at[:, dp, :, ds, :].set(
+                jnp.transpose(ksf, (1, 0, 2, 3)).astype(kpool.dtype))
+            vpool = vpool.at[:, dp, :, ds, :].set(
+                jnp.transpose(vsf, (1, 0, 2, 3)).astype(vpool.dtype))
+            if q8:
+                kscale = kscale.at[:, dp, :, ds].set(
+                    jnp.transpose(ksc2, (1, 0, 2)))
+                vscale = vscale.at[:, dp, :, ds].set(
+                    jnp.transpose(vsc2, (1, 0, 2)))
 
         # accept fold: longest matching prefix + the correction token
         # == commit g[:, :k+1]; spec-off rows collapse to 1 (their

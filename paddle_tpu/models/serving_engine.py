@@ -43,6 +43,7 @@ import numpy as np
 from ..observability import (EngineMetrics, MetricsRegistry,
                              advance_phase, bind_engine_gauges,
                              finalize_request_trace)
+from ..profiler.utils import RecordEvent
 from ..testing import faults
 from .llama_pretrain import LlamaPretrainConfig, _mm, _rms_norm
 from .paged_decode import (PagedKVCache, _prefill, _prefill_chunk,
@@ -1590,20 +1591,21 @@ class ContinuousBatchingEngine:
             # batched first tokens from each segment's LAST real
             # position — skipped for an all-resume wave (saved tokens;
             # sampling would burn a PRNG split for nothing)
-            last = jnp.asarray([off + start + s_real - 1
-                                for _, _, _, start, s_real, _, off
-                                in plan])
-            h = _rms_norm(x[0, last], self.params["final_norm"],
-                          self.cfg.rms_norm_eps)
-            logits = _mm(h, self.params["lm_head"],
-                         self.cfg.dtype).astype(jnp.float32)
-            self._key, sub = jax.random.split(self._key)
-            # sanctioned drain, kept OFF the _fetch seam: pipeline-
-            # depth accounting (one _fetch per drained decode step) is
-            # pinned by the overlap tests
-            # analysis: ignore[sync-in-hot-path] reason=admission first-token fetch; the pipeline is flushed before any _admit_* runs
-            toks_out = np.asarray(_pick_token(
-                logits, self.temperature, sub, self.top_k, self.top_p))
+            with RecordEvent("admit.first_token_tail"):
+                last = jnp.asarray([off + start + s_real - 1
+                                    for _, _, _, start, s_real, _, off
+                                    in plan])
+                h = _rms_norm(x[0, last], self.params["final_norm"],
+                              self.cfg.rms_norm_eps)
+                logits = _mm(h, self.params["lm_head"],
+                             self.cfg.dtype).astype(jnp.float32)
+                self._key, sub = jax.random.split(self._key)
+                # sanctioned drain, kept OFF the _fetch seam:
+                # pipeline-depth accounting (one _fetch per drained
+                # decode step) is pinned by the overlap tests
+                # analysis: ignore[sync-in-hot-path] reason=admission first-token fetch; the pipeline is flushed before any _admit_* runs
+                toks_out = np.asarray(_pick_token(
+                    logits, self.temperature, sub, self.top_k, self.top_p))
         for i, (req, ctx, slot, start, s_real, Wp, off) in \
                 enumerate(plan):
             if req.generated:                    # resume after preempt
@@ -1888,6 +1890,7 @@ class ContinuousBatchingEngine:
         _finalize_trace(req)
         self._finished.append(req)
 
+    @RecordEvent("engine.sweep")
     def _sweep_cancelled_expired(self) -> None:
         """Retire cancelled/deadline-expired requests at this flush
         point.  Queued ones leave the queue (swap records discard);
@@ -2120,6 +2123,7 @@ class ContinuousBatchingEngine:
                 "engine_quarantine", error=text,
                 consecutive=self._consecutive_faults)
 
+    @RecordEvent("engine.step")
     def _step_inner(self) -> int:
         self._sweep_cancelled_expired()
         if self._mixed and (self._active or self._mixed_pref):
@@ -2226,9 +2230,23 @@ class ContinuousBatchingEngine:
         return self._preempt(keep=None, only=victims)
 
     def _admit_sequential(self, admits: List) -> None:
-        """Lane choice for one popped admission wave — shared by the
+        """One popped admission wave through its lane — shared by the
         sequential path and the mixed lane's shape-forced degrades
-        (both call it behind a flushed pipeline)."""
+        (both call it behind a flushed pipeline) — under ONE
+        ``engine.admit`` span: a ring event per wave (the ring is sized
+        for waves, not steps) and, under a profiler session, a span on
+        the device ops' clock."""
+        attrs = dict(n_requests=len(admits),
+                     tokens=sum(len(ctx) for _, ctx in admits),
+                     lane="packed" if self._packed else "bucketed")
+        span = (self.metrics.ring.span("engine.admit", **attrs)
+                if self.metrics is not None
+                else RecordEvent("engine.admit", **attrs))
+        with span:
+            self._admit_lanes(admits)
+
+    def _admit_lanes(self, admits: List) -> None:
+        """Lane choice for one admission wave."""
         for req, _ in admits:
             # the wave's wall lands in each rider's "prefill" clock
             advance_phase(req, "prefill")
@@ -2443,39 +2461,40 @@ class ContinuousBatchingEngine:
                     p_rem[slot] = req.max_new_tokens - 1
                 completing.append((slot, req))
             off += W
-        q8 = cache.kv_quant == "int8"
-        if self.overlap:
-            d = self._seed_or_refresh_dev()
-            tables_in, lens_in, tok_in = (d["tables"], d["lens"],
-                                          d["tok"])
-            act_in, rem_in = d["active"], d["remaining"]
-        else:
-            tables_in = jnp.asarray(cache.tables.copy())
-            lens_in = jnp.asarray(cache.lens.copy())
-            tok_in = jnp.asarray(self._next_tok.copy())
-            act_in = jnp.asarray(self._active_mask.astype(bool))
-            rem_in = jnp.asarray(self._remaining.copy())
-        self._key, sub = jax.random.split(self._key)
-        faults.fire("step_dispatch")
-        args = (self.params, cache.kpool, cache.vpool)
-        if q8:
-            args += (cache.kscale, cache.vscale)
-        args += (tables_in, lens_in, tok_in, act_in, rem_in,
-                 self._eos_dev, sub, jnp.asarray(toks),
-                 jnp.asarray(seg), jnp.asarray(posa),
-                 jnp.asarray(hist_page), jnp.asarray(hist_slot),
-                 jnp.asarray(pool_hist), jnp.asarray(dest_page),
-                 jnp.asarray(dest_slot), jnp.asarray(sample_idx),
-                 jnp.asarray(activate), jnp.asarray(p_first),
-                 jnp.asarray(p_sample), jnp.asarray(p_len),
-                 jnp.asarray(p_rem))
-        out = self._step_mixed(*args)
-        if q8:
-            (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
-             nxt, lens2, rem2, act2, done, ftok) = out
-        else:
-            (cache.kpool, cache.vpool, nxt, lens2, rem2, act2, done,
-             ftok) = out
+        with RecordEvent("engine.dispatch"):
+            q8 = cache.kv_quant == "int8"
+            if self.overlap:
+                d = self._seed_or_refresh_dev()
+                tables_in, lens_in, tok_in = (d["tables"], d["lens"],
+                                              d["tok"])
+                act_in, rem_in = d["active"], d["remaining"]
+            else:
+                tables_in = jnp.asarray(cache.tables.copy())
+                lens_in = jnp.asarray(cache.lens.copy())
+                tok_in = jnp.asarray(self._next_tok.copy())
+                act_in = jnp.asarray(self._active_mask.astype(bool))
+                rem_in = jnp.asarray(self._remaining.copy())
+            self._key, sub = jax.random.split(self._key)
+            faults.fire("step_dispatch")
+            args = (self.params, cache.kpool, cache.vpool)
+            if q8:
+                args += (cache.kscale, cache.vscale)
+            args += (tables_in, lens_in, tok_in, act_in, rem_in,
+                     self._eos_dev, sub, jnp.asarray(toks),
+                     jnp.asarray(seg), jnp.asarray(posa),
+                     jnp.asarray(hist_page), jnp.asarray(hist_slot),
+                     jnp.asarray(pool_hist), jnp.asarray(dest_page),
+                     jnp.asarray(dest_slot), jnp.asarray(sample_idx),
+                     jnp.asarray(activate), jnp.asarray(p_first),
+                     jnp.asarray(p_sample), jnp.asarray(p_len),
+                     jnp.asarray(p_rem))
+            out = self._step_mixed(*args)
+            if q8:
+                (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
+                 nxt, lens2, rem2, act2, done, ftok) = out
+            else:
+                (cache.kpool, cache.vpool, nxt, lens2, rem2, act2,
+                 done, ftok) = out
         self.decode_steps += 1
         self.mixed_ticks += 1
         self.mixed_prefill_tokens += fresh
@@ -2557,32 +2576,34 @@ class ContinuousBatchingEngine:
             return
         # -- synchronous lane: one fetch per tick (mirrors
         # _decode_sync's single blocking round-trip)
-        # analysis: ignore[sync-in-hot-path] reason=the synchronous (overlap=False) mixed lane's one fetch per tick — the exact counterpart of _decode_sync's blocking round-trip
-        nxt_h, ftok_h = np.asarray(nxt), np.asarray(ftok)
-        self.host_syncs += 1
-        t0 = time.perf_counter() if self.metrics is not None else 0.0
-        advanced = 0
-        for slot, req in list(self._active.items()):
-            if activate[slot]:
-                continue       # activated this tick: first decode
-                #                token arrives next tick
-            t = int(nxt_h[slot])
-            self._deliver_token(slot, req, t)
-            advanced += 1
-            self._remaining[slot] -= 1
-            if self._hit_stop(req, t) or self._remaining[slot] <= 0:
-                self._retire(slot)
-        for slot, req in completing:
-            if req.generated or self._active.get(slot) is not req:
-                continue
-            t = int(ftok_h[slot])
-            self._deliver_token(slot, req, t, count=False)
-            if self._hit_stop(req, t) or self._remaining[slot] <= 0:
-                self._retire(slot)
-        if self.metrics is not None:
-            self.metrics.tokens_generated.inc(advanced)
-            self.metrics.host_bookkeeping.observe(
-                time.perf_counter() - t0)
+        with RecordEvent("engine.fetch"):
+            # analysis: ignore[sync-in-hot-path] reason=the synchronous (overlap=False) mixed lane's one fetch per tick — the exact counterpart of _decode_sync's blocking round-trip
+            nxt_h, ftok_h = np.asarray(nxt), np.asarray(ftok)
+            self.host_syncs += 1
+        with RecordEvent("engine.drain"):
+            t0 = time.perf_counter() if self.metrics is not None else 0.0
+            advanced = 0
+            for slot, req in list(self._active.items()):
+                if activate[slot]:
+                    continue       # activated this tick: first decode
+                    #                token arrives next tick
+                t = int(nxt_h[slot])
+                self._deliver_token(slot, req, t)
+                advanced += 1
+                self._remaining[slot] -= 1
+                if self._hit_stop(req, t) or self._remaining[slot] <= 0:
+                    self._retire(slot)
+            for slot, req in completing:
+                if req.generated or self._active.get(slot) is not req:
+                    continue
+                t = int(ftok_h[slot])
+                self._deliver_token(slot, req, t, count=False)
+                if self._hit_stop(req, t) or self._remaining[slot] <= 0:
+                    self._retire(slot)
+            if self.metrics is not None:
+                self.metrics.tokens_generated.inc(advanced)
+                self.metrics.host_bookkeeping.observe(
+                    time.perf_counter() - t0)
 
     def _deliver_token(self, slot: int, req: Request, t: int,
                        count: bool = True) -> None:
@@ -2766,42 +2787,45 @@ class ContinuousBatchingEngine:
     def _decode_sync(self) -> None:
         """One decode dispatch + blocking host round-trip."""
         cache = self.cache
-        self._ensure_or_preempt()
-        tables = jnp.asarray(cache.tables.copy())
-        lens = jnp.asarray(cache.lens.copy())
-        tok = jnp.asarray(self._next_tok.copy())
-        self._key, sub = jax.random.split(self._key)
-        faults.fire("step_dispatch")
-        if cache.kv_quant == "int8":
-            (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
-             nxt) = self._step(self.params, cache.kpool, cache.vpool,
-                               cache.kscale, cache.vscale, tables,
-                               lens, tok, sub)
-        else:
-            cache.kpool, cache.vpool, nxt = self._step(
-                self.params, cache.kpool, cache.vpool, tables, lens,
-                tok, sub)
-        cache.lens = cache.lens + self._active_mask
-        self.decode_steps += 1
-        self._count_tp_dispatch()
-        # analysis: ignore[sync-in-hot-path] reason=the synchronous lane's one blocking fetch per tick IS its design (overlap=False); reachable from the mixed hot root only via the degenerate all-parked-rows-preempted fallback tick
-        nxt = np.asarray(nxt)
-        self.host_syncs += 1
-        t0 = time.perf_counter() if self.metrics is not None else 0.0
-        advanced = 0
-        for slot, req in list(self._active.items()):
-            # analysis: ignore[sync-in-hot-path] reason=host-numpy read: nxt was fetched by the sanctioned sync above (the taint walker keeps the rebind tainted)
-            t = int(nxt[slot])
-            self._deliver_token(slot, req, t)
-            advanced += 1
-            self._remaining[slot] -= 1
-            if self._hit_stop(req, t) or self._remaining[slot] <= 0:
-                self._retire(slot)
-        if self.metrics is not None:
-            self.metrics.decode_steps.inc()
-            self.metrics.tokens_generated.inc(advanced)
-            self.metrics.host_bookkeeping.observe(
-                time.perf_counter() - t0)
+        with RecordEvent("engine.dispatch"):
+            self._ensure_or_preempt()
+            tables = jnp.asarray(cache.tables.copy())
+            lens = jnp.asarray(cache.lens.copy())
+            tok = jnp.asarray(self._next_tok.copy())
+            self._key, sub = jax.random.split(self._key)
+            faults.fire("step_dispatch")
+            if cache.kv_quant == "int8":
+                (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
+                 nxt) = self._step(self.params, cache.kpool, cache.vpool,
+                                   cache.kscale, cache.vscale, tables,
+                                   lens, tok, sub)
+            else:
+                cache.kpool, cache.vpool, nxt = self._step(
+                    self.params, cache.kpool, cache.vpool, tables, lens,
+                    tok, sub)
+            cache.lens = cache.lens + self._active_mask
+            self.decode_steps += 1
+            self._count_tp_dispatch()
+        with RecordEvent("engine.fetch"):
+            # analysis: ignore[sync-in-hot-path] reason=the synchronous lane's one blocking fetch per tick IS its design (overlap=False); reachable from the mixed hot root only via the degenerate all-parked-rows-preempted fallback tick
+            nxt = np.asarray(nxt)
+            self.host_syncs += 1
+        with RecordEvent("engine.drain"):
+            t0 = time.perf_counter() if self.metrics is not None else 0.0
+            advanced = 0
+            for slot, req in list(self._active.items()):
+                # analysis: ignore[sync-in-hot-path] reason=host-numpy read: nxt was fetched by the sanctioned sync above (the taint walker keeps the rebind tainted)
+                t = int(nxt[slot])
+                self._deliver_token(slot, req, t)
+                advanced += 1
+                self._remaining[slot] -= 1
+                if self._hit_stop(req, t) or self._remaining[slot] <= 0:
+                    self._retire(slot)
+            if self.metrics is not None:
+                self.metrics.decode_steps.inc()
+                self.metrics.tokens_generated.inc(advanced)
+                self.metrics.host_bookkeeping.observe(
+                    time.perf_counter() - t0)
 
     # -- dispatch-ahead pipeline (overlap=True) ---------------------------
     def _decode_overlap(self) -> None:
@@ -2878,6 +2902,7 @@ class ContinuousBatchingEngine:
                 self._dev_dtables_version = dcache.tables_version
         return self._dev
 
+    @RecordEvent("engine.dispatch")
     def _dispatch_async(self) -> None:
         """Issue one decode step — or, with ``decode_horizon > 1``,
         one H-micro-step horizon BLOCK — chained off the
@@ -2940,6 +2965,7 @@ class ContinuousBatchingEngine:
         if self.metrics is not None:
             self.metrics.decode_steps.inc()
 
+    @RecordEvent("engine.fetch")
     def _fetch(self, *arrs):
         """Blocking device->host fetch — the pipeline's ONLY sync
         point, one call per drained step (tests count calls and their
@@ -2967,7 +2993,12 @@ class ContinuousBatchingEngine:
         # a mixed tick's first-token array rides the SAME single fetch
         # as the decode outputs — zero syncs added by the mixed lane
         # analysis: ignore[sync-in-hot-path] reason=the pipeline's one sanctioned sync point: drains the OLDEST step while a newer dispatch is already in flight
-        fetched = self._fetch(*arrs)
+        self._drain_step(e, self._fetch(*arrs), has_first)
+
+    @RecordEvent("engine.drain")
+    def _drain_step(self, e: Dict, fetched, has_first: bool) -> None:
+        """The bookkeeping of one drained single-token step: delivery,
+        retirement, the device active chain."""
         nxt, done = fetched[0], fetched[1]
         t0 = time.perf_counter() if self.metrics is not None else 0.0
         mask = self._drain_active
@@ -3031,6 +3062,7 @@ class ContinuousBatchingEngine:
         self._drain_active = self._drain_horizon_block(
             toks, dones, self._drain_active)
 
+    @RecordEvent("engine.drain")
     def _drain_horizon_block(self, toks, dones, mask):
         """Per-token host bookkeeping for one fetched horizon block —
         shared by the overlap drain and the synchronous horizon lane
@@ -3124,35 +3156,36 @@ class ContinuousBatchingEngine:
         ONE blocking fetch per tick — H tokens per blocking host
         round-trip instead of one (``overlap=False``,
         ``decode_horizon > 1``)."""
-        cache = self.cache
-        self._ensure_or_preempt(self.decode_horizon)
-        tables = jnp.asarray(cache.tables.copy())
-        lens = jnp.asarray(cache.lens.copy())
-        tok = jnp.asarray(self._next_tok.copy())
-        active = jnp.asarray(self._active_mask.astype(bool))
-        remaining = jnp.asarray(self._remaining.copy())
-        self._key, sub = jax.random.split(self._key)
-        faults.fire("step_dispatch")
-        if cache.kv_quant == "int8":
-            (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
-             toks, dones, _, _, _, _) = self._step_multi(
-                self.params, cache.kpool, cache.vpool, cache.kscale,
-                cache.vscale, tables, lens, tok, active, remaining,
-                self._eos_dev, sub)
-        else:
-            (cache.kpool, cache.vpool, toks, dones, _, _, _,
-             _) = self._step_multi(
-                self.params, cache.kpool, cache.vpool, tables, lens,
-                tok, active, remaining, self._eos_dev, sub)
-        # mirror the full horizon; retirements below zero the rows
-        # that stopped mid-block (same self-healing as the overlap
-        # mirror — here the very next lines heal it)
-        cache.lens = cache.lens + (self.decode_horizon
-                                   * self._active_mask)
-        self.decode_steps += 1
-        self._count_tp_dispatch(self.decode_horizon)
-        if self.metrics is not None:
-            self.metrics.decode_steps.inc()
+        with RecordEvent("engine.dispatch"):
+            cache = self.cache
+            self._ensure_or_preempt(self.decode_horizon)
+            tables = jnp.asarray(cache.tables.copy())
+            lens = jnp.asarray(cache.lens.copy())
+            tok = jnp.asarray(self._next_tok.copy())
+            active = jnp.asarray(self._active_mask.astype(bool))
+            remaining = jnp.asarray(self._remaining.copy())
+            self._key, sub = jax.random.split(self._key)
+            faults.fire("step_dispatch")
+            if cache.kv_quant == "int8":
+                (cache.kpool, cache.vpool, cache.kscale, cache.vscale,
+                 toks, dones, _, _, _, _) = self._step_multi(
+                    self.params, cache.kpool, cache.vpool, cache.kscale,
+                    cache.vscale, tables, lens, tok, active, remaining,
+                    self._eos_dev, sub)
+            else:
+                (cache.kpool, cache.vpool, toks, dones, _, _, _,
+                 _) = self._step_multi(
+                    self.params, cache.kpool, cache.vpool, tables, lens,
+                    tok, active, remaining, self._eos_dev, sub)
+            # mirror the full horizon; retirements below zero the rows
+            # that stopped mid-block (same self-healing as the overlap
+            # mirror — here the very next lines heal it)
+            cache.lens = cache.lens + (self.decode_horizon
+                                       * self._active_mask)
+            self.decode_steps += 1
+            self._count_tp_dispatch(self.decode_horizon)
+            if self.metrics is not None:
+                self.metrics.decode_steps.inc()
         mask = self._active_mask.astype(bool)
         # analysis: ignore[sync-in-hot-path] reason=the synchronous horizon lane's ONE blocking fetch per H-token tick (overlap=False) — the amortized counterpart of _decode_sync's per-token round-trip
         toks, dones = self._fetch(toks, dones)
@@ -3285,42 +3318,43 @@ class ContinuousBatchingEngine:
         run ahead of the drain."""
         if self._needs_flush:    # lookup-on-overlap-engine stop/preempt
             self._pipeline_flush()
-        cache, dcache = self.cache, self._spec_dcache
-        G = self.gamma
-        C = G + 1
-        self._ensure_or_preempt(C, aux_cache=dcache, aux_new=C,
-                                aux_rows=self._spec_on)
-        fused = self._spec_fused()
-        self._key, sub = jax.random.split(self._key)
-        mask = self._active_mask.astype(bool)
-        spec_rows = mask & self._spec_on
-        inputs = {
-            "tables": jnp.asarray(cache.tables.copy()),
-            "lens": jnp.asarray(cache.lens.copy()),
-            "tok": jnp.asarray(self._next_tok.copy()),
-            "active": jnp.asarray(mask),
-            "remaining": jnp.asarray(self._remaining.copy()),
-            "spec_on": jnp.asarray(self._spec_on.copy()),
-            "key": sub,
-        }
-        if self._spec.source == "draft":
-            inputs["dtables"] = jnp.asarray(dcache.tables.copy())
-            inputs["prev"] = jnp.asarray(self._prev_tok.copy())
-        else:
-            inputs["drafts"] = jnp.asarray(self._propose_lookup())
-        faults.fire("step_dispatch")
-        rets = fused(*self._spec_dispatch_args(inputs))
-        toks, dones, emits, accs, _ = self._spec_unpack(rets)
-        # mirror the worst case (C per live row, draft rows too); the
-        # drain corrects each row to its actual commit count
-        cache.lens = cache.lens + C * self._active_mask
-        if dcache is not None:
-            dcache.lens = dcache.lens + C * spec_rows.astype(
-                dcache.lens.dtype)
-        self.decode_steps += 1
-        self._count_spec_tp(C)
-        if self.metrics is not None:
-            self.metrics.decode_steps.inc()
+        with RecordEvent("engine.dispatch"):
+            cache, dcache = self.cache, self._spec_dcache
+            G = self.gamma
+            C = G + 1
+            self._ensure_or_preempt(C, aux_cache=dcache, aux_new=C,
+                                    aux_rows=self._spec_on)
+            fused = self._spec_fused()
+            self._key, sub = jax.random.split(self._key)
+            mask = self._active_mask.astype(bool)
+            spec_rows = mask & self._spec_on
+            inputs = {
+                "tables": jnp.asarray(cache.tables.copy()),
+                "lens": jnp.asarray(cache.lens.copy()),
+                "tok": jnp.asarray(self._next_tok.copy()),
+                "active": jnp.asarray(mask),
+                "remaining": jnp.asarray(self._remaining.copy()),
+                "spec_on": jnp.asarray(self._spec_on.copy()),
+                "key": sub,
+            }
+            if self._spec.source == "draft":
+                inputs["dtables"] = jnp.asarray(dcache.tables.copy())
+                inputs["prev"] = jnp.asarray(self._prev_tok.copy())
+            else:
+                inputs["drafts"] = jnp.asarray(self._propose_lookup())
+            faults.fire("step_dispatch")
+            rets = fused(*self._spec_dispatch_args(inputs))
+            toks, dones, emits, accs, _ = self._spec_unpack(rets)
+            # mirror the worst case (C per live row, draft rows too); the
+            # drain corrects each row to its actual commit count
+            cache.lens = cache.lens + C * self._active_mask
+            if dcache is not None:
+                dcache.lens = dcache.lens + C * spec_rows.astype(
+                    dcache.lens.dtype)
+            self.decode_steps += 1
+            self._count_spec_tp(C)
+            if self.metrics is not None:
+                self.metrics.decode_steps.inc()
         # analysis: ignore[sync-in-hot-path] reason=the synchronous speculative lane's ONE blocking fetch per round — the fused-round counterpart of _decode_sync's per-token round-trip
         toks, dones, emits, accs = self._fetch(toks, dones, emits,
                                                accs)
@@ -3350,6 +3384,7 @@ class ContinuousBatchingEngine:
                 self._drain_one()
             self._dev = None
 
+    @RecordEvent("engine.dispatch")
     def _dispatch_spec_async(self) -> None:
         """Issue one fused speculative round chained off the
         device-resident loop state (zero blocking host work — same
@@ -3392,6 +3427,7 @@ class ContinuousBatchingEngine:
         self._drain_active = self._drain_spec_block(
             toks, dones, emits, accs, self._drain_active)
 
+    @RecordEvent("engine.drain")
     def _drain_spec_block(self, toks, dones, emits, accs, mask):
         """Host bookkeeping for one fetched speculative round —
         shared by the sync lane and the overlap drain so emission /

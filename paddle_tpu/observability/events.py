@@ -14,10 +14,12 @@ correlation).  ``seq`` increments per event so a tailer
 (tools/metrics_dump.py) can poll ``/events?since=<seq>`` without
 duplicates.
 
-``span()`` opens a profiler ``RecordEvent`` (the span shows up in the
-profiler summary/chrome export AND the XLA device trace when a capture
-is live) and additionally emits a ring event with the measured
-duration — one annotation, three sinks.
+``span()`` opens a profiler ``RecordEvent`` carrying the span's fields
+(it shows up in the profiler summary/chrome export, and on the host
+plane of the XLA trace under any open profiler session) and additionally
+emits a ring event with the measured duration — one annotation, three
+sinks.  The engine's ``engine.admit`` goes through it: one ring event
+per admission wave.
 """
 
 from __future__ import annotations
@@ -59,7 +61,8 @@ class _RingSpan:
     def __enter__(self):
         RecordEvent, TracerEventType = _record_event_types()
         self._rec = RecordEvent(self._name,
-                                TracerEventType.UserDefined)
+                                TracerEventType.UserDefined,
+                                **self._fields)
         self._rec.begin()
         self._t0 = timeit.default_timer()
         return self

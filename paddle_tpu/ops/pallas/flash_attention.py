@@ -290,6 +290,7 @@ def _flash_fwd(q, k, v, causal):
             pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
             pl.BlockSpec((None, bq, 1), lambda i, j: idx32(i, j, 0)),
         ),
+        name="flash_fwd",
         interpret=_common.interpret(),
     )(qr, kr, vr)
     return _reshape_out(out, b, h), (qr, kr, vr, out, lse, b, h, s, d)
@@ -323,6 +324,7 @@ def _flash_bwd_vjp(causal, res, dout):
             pl.BlockSpec((None, bq, 1), lambda i, j: idx32(i, j, 0)),
         ],
         out_specs=pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
+        name="flash_bwd_dq",
         interpret=interp,
     )(qr, kr, vr, do, lse, delta)
 
@@ -344,6 +346,7 @@ def _flash_bwd_vjp(causal, res, dout):
             pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
             pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
         ),
+        name="flash_bwd_dkv",
         interpret=interp,
     )(qr, kr, vr, do, lse, delta)
 

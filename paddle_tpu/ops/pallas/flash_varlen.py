@@ -394,6 +394,7 @@ def _seg_fwd(q, k, v, seg, causal):
         ),
         out_shape=(jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
                    jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)),
+        name="flash_varlen_fwd",
         interpret=_common.interpret(),
     )(kmin, kmax, qr, kr, vr, seg_q, seg_k)
     return _reshape_out(out, b, h), (qr, kr, vr, seg, out, lse)
@@ -452,6 +453,7 @@ def _seg_bwd_vjp(causal, res, dout):
                                    lambda i, j, *_: idx32(i, j, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), qr.dtype),
+        name="flash_varlen_bwd_dq",
         interpret=interp,
     )(kmin, kmax, qr, kr, vr, seg_q, seg_k, do, lse, delta)
 
@@ -502,6 +504,7 @@ def _seg_bwd_vjp(causal, res, dout):
         # the param dtype only after the whole group has landed
         out_shape=(jax.ShapeDtypeStruct((b * nkv, s, d), jnp.float32),
                    jax.ShapeDtypeStruct((b * nkv, s, d), jnp.float32)),
+        name="flash_varlen_bwd_dkv",
         interpret=interp,
     )(qmin, qmax, qr, kr, vr, seg_q, seg_k, do, lse, delta)
 

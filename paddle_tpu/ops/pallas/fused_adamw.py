@@ -97,6 +97,7 @@ def fused_adamw(p, g, m, v, t, lr, b1=0.9, b2=0.95, eps=1e-8,
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, h), lambda i: idx32(i, 0))),
+        name="fused_adamw",
         interpret=_common.interpret(),
     )(flat2(p), flat2(g, jnp.float32), flat2(m, jnp.float32),
       flat2(v, jnp.float32), lr_arr, c1_arr, c2_arr)

@@ -106,6 +106,7 @@ def int8_matmul(x, wq, scale, out_dtype=None):
             pl.BlockSpec((1, bn), lambda i, j: idx32(0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: idx32(i, j)),
+        name="int8_matmul",
         interpret=interp,
     )(x.astype(jnp.bfloat16), wq,
       scale.astype(jnp.float32).reshape(1, -1))

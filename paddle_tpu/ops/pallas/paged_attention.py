@@ -240,6 +240,7 @@ def paged_decode_attention(q, kpool, vpool, block_tables, context_lens,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
+        name="paged_attn",
         interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool)
     return out
@@ -329,6 +330,7 @@ def paged_decode_attention_q8(q, kpool, vpool, kscale, vscale,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
+        name="paged_attn_q8",
         interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool, kscale, vscale)
     return out

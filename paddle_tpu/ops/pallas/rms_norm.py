@@ -91,6 +91,7 @@ def _fwd(x, w, eps):
                   pl.BlockSpec((1, h), lambda i: idx32(0, 0))],
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, 1), lambda i: idx32(i, 0))),
+        name="rms_norm",
         interpret=_common.interpret(),
     )(xr, w.reshape(1, -1))
     return out.reshape(orig_shape), (xr, w, rstd, orig_shape)
@@ -116,6 +117,7 @@ def _bwd_vjp(eps, res, dout):
                   pl.BlockSpec((br, h), lambda i: idx32(i, 0))],
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((8, h), lambda i: idx32(0, 0))),
+        name="rms_norm_bwd",
         interpret=_common.interpret(),
     )(xr, w.reshape(1, -1), rstd, do)
     dw = jnp.sum(dw_partial, axis=0).astype(w.dtype)

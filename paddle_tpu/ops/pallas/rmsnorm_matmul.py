@@ -94,6 +94,7 @@ def _fwd(x, wl, w, eps):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: idx32(i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        name="rmsnorm_matmul",
         interpret=_common.interpret(),
     )(xr, wl.reshape(1, H), w)
     return out.reshape(*lead, N), (x, wl, w)

@@ -110,6 +110,7 @@ def _apply(x, cos, sin, neg_sin: bool):
         ],
         out_specs=pl.BlockSpec((1, bs, n, d),
                                lambda bi, si: idx32(bi, si, 0, 0)),
+        name="rope",
         interpret=_common.interpret(),
     )(x, cos, sin)
 

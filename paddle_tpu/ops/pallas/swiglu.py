@@ -70,6 +70,7 @@ def _fwd(gate, up):
         in_specs=[pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                   pl.BlockSpec((br, h), lambda i: idx32(i, 0))],
         out_specs=pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
+        name="swiglu",
         interpret=_common.interpret(),
     )(g, u)
     return out.reshape(shape), (gate, up)
@@ -97,6 +98,7 @@ def _bwd_vjp(res, dout):
                   pl.BlockSpec((br, h), lambda i: idx32(i, 0))],
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, h), lambda i: idx32(i, 0))),
+        name="swiglu_bwd",
         interpret=_common.interpret(),
     )(g, u, do)
     return dg.reshape(shape), du.reshape(shape)

@@ -4,7 +4,8 @@ TPU-first design: a ``RecordEvent`` does two things at once —
   1. appends a wall-clock span to the in-process span buffer (used for the
      framework-side summary table and chrome-trace export), and
   2. opens a ``jax.profiler.TraceAnnotation`` so the same name shows up in
-     the XLA device trace when a ``Profiler`` capture is active.
+     the XLA device trace under any open profiler session (the Paddle-API
+     ``Profiler`` or a plain ``jax.profiler.start_trace``).
 
 Device-side op timing belongs to XLA's own profiler (captured via
 ``jax.profiler.start_trace``); the framework does not attempt to re-time
@@ -90,32 +91,41 @@ class RecordEvent(ContextDecorator):
         with paddle.profiler.RecordEvent("attention"):
             out = model(x)
 
-    or via ``begin()`` / ``end()``.  Cheap no-op when no profiler is active.
+    or via ``begin()`` / ``end()``, or as a decorator.  The
+    ``TraceAnnotation`` is opened always: it costs a flag check while no
+    profiler session is open, and under ANY open session (a plain
+    ``jax.profiler.start_trace`` included) the span lands on the host
+    plane of the trace, on the device ops' clock, with ``attrs`` as its
+    keyword arguments.  Only the span-buffer entry waits for the
+    Paddle-API ``Profiler`` to collect.
     """
 
-    def __init__(self, name, event_type=TracerEventType.PythonOp):
+    def __init__(self, name, event_type=TracerEventType.PythonOp, **attrs):
         self.name = name
         self.event_type = event_type
+        self.attrs = attrs
         self._start = None
         self._ann = None
 
+    def _recreate_cm(self):
+        # as a decorator one instance would serve every call, and the
+        # decorated function may run on several threads at once
+        return type(self)(self.name, self.event_type, **self.attrs)
+
     def begin(self):
-        if not _buffer.enabled:
-            return
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.attrs)
         self._ann.__enter__()
-        self._start = timeit.default_timer()
+        if _buffer.enabled:
+            self._start = timeit.default_timer()
 
     def end(self):
-        if self._start is None:
-            return
-        end = timeit.default_timer()
-        _buffer.add(self.name, self.event_type, self._start, end,
-                    threading.get_ident())
+        if self._start is not None:
+            _buffer.add(self.name, self.event_type, self._start,
+                        timeit.default_timer(), threading.get_ident())
+            self._start = None
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        self._start = None
 
     def __enter__(self):
         self.begin()

@@ -1,0 +1,372 @@
+"""The names the program gives its work (docs/OBSERVABILITY.md,
+"Profiler spans and scopes"): ``jax.named_scope`` names inside the step
+programs, ``name=`` on every ``pallas_call``, and ``RecordEvent`` spans
+that reach the profiler's trace under a plain
+``jax.profiler.start_trace``.  All on the CPU: scopes are read from the
+lowered text, spans from a trace the CPU backend records."""
+
+import ast
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu  # noqa: F401  (x64 before any array)
+from benchmark import xplane, xplane_meta
+from paddle_tpu.models import paged_decode, serving_engine
+from paddle_tpu.models.llama_pretrain import (
+    LlamaPretrainConfig, build_mesh, init_adafactor_state, init_params,
+    make_train_step)
+from paddle_tpu.observability import EventRing, MetricsRegistry
+from paddle_tpu.profiler.utils import (RecordEvent, _buffer,
+                                       _disable_collection, _drain_spans,
+                                       _enable_collection)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BLOCK = {"block", "attn_qkv", "rope", "attn_out", "mlp"}
+DECODE = BLOCK | {"embed", "pool_carry", "kv_write", "paged_attn",
+                  "logits", "sample"}
+PREFILL = BLOCK | {"embed", "layer_scan", "varlen_attn"}
+
+
+def scope_names(lowered) -> set:
+    """Every identifier on an op path of the lowered text."""
+    text = lowered.as_text(debug_info=True)
+    words = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        words.update(xplane_meta.path_words(path))
+    return words
+
+
+def _cfg(**kw):
+    base = dict(vocab_size=128, hidden_size=64, intermediate_size=128,
+                num_hidden_layers=2, num_attention_heads=4,
+                num_key_value_heads=2, max_seq_len=256, dtype=jnp.float32,
+                param_dtype=jnp.float32, remat=False, loss_chunks=1,
+                use_pallas_attention=False)
+    base.update(kw)
+    return LlamaPretrainConfig(**base)
+
+
+def _mesh():
+    return build_mesh(devices=jax.devices()[:1])
+
+
+# -- the train step ---------------------------------------------------------
+@pytest.mark.parametrize("accum,want", [
+    (1, BLOCK | {"embed", "layer_scan", "attn", "loss_head",
+                 "optimizer"}),
+    (2, BLOCK | {"embed", "layer_scan", "attn", "loss_head", "optimizer",
+                 "grad_accum"})])
+def test_train_step_carries_the_scopes(accum, want):
+    cfg = _cfg(remat=True, loss_chunks=2)
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        step = make_train_step(cfg, mesh, lr=1e-2, optimizer="adafactor",
+                               accum_steps=accum)
+        low = step.lower(params, opt,
+                         jax.ShapeDtypeStruct((4, 33), jnp.int64))
+    names = scope_names(low)
+    assert want <= names, want - names
+    # forward, backward and recompute of a block are told apart
+    text = low.as_text(debug_info=True)
+    assert "transpose(jvp(layer_scan))" in text or accum > 1
+    assert "rematted_computation" in text
+    # the readers key on the XLA module ``jit_step``
+    assert "jit(step)" in text
+
+
+# -- the serving step programs ------------------------------------------------
+class Recorder:
+    """Stands where the engine keeps a jitted step program: lowers the
+    first call's arguments, then calls through."""
+
+    def __init__(self, fn, into: dict, key: str):
+        self.fn, self.into, self.key = fn, into, key
+
+    def __call__(self, *args):
+        if self.key not in self.into:
+            self.into[self.key] = scope_names(self.fn.lower(*args))
+        return self.fn(*args)
+
+
+def _engine(metrics_registry=None, **kw):
+    cfg = _cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0), _mesh())
+    cache = paged_decode.PagedKVCache(cfg, num_pages=64, pages_max=8,
+                                      batch=2, page=16)
+    return serving_engine.ContinuousBatchingEngine(
+        cfg, params, cache, metrics_registry=metrics_registry, **kw)
+
+
+def _record_packed_prefill(monkeypatch, into):
+    real = serving_engine._prefill_packed
+    monkeypatch.setattr(
+        serving_engine, "_prefill_packed",
+        lambda *a: Recorder(real(*a), into, "prefill_packed"))
+
+
+def _drive(eng):
+    """Three requests, the last two arriving while the first decodes
+    (a mixed engine piggybacks only on a running decode)."""
+    rng = np.random.RandomState(3)
+
+    def submit():
+        eng.submit(rng.randint(1, 128, (int(rng.randint(20, 40)),)),
+                   max_new_tokens=6)
+    submit()
+    eng.step()
+    eng.step()
+    submit()
+    submit()
+    done = eng.finished() + eng.run_to_completion()
+    assert len(done) == 3
+
+
+LANES = {
+    "sync": (dict(), "_step", DECODE),
+    "overlap": (dict(overlap=True), "_step_async", DECODE),
+    "horizon": (dict(decode_horizon=2), "_step_multi", DECODE),
+    "mixed": (dict(mixed=True, mixed_token_budget=16), "_step_mixed",
+              DECODE | PREFILL),
+}
+
+
+@pytest.mark.parametrize("lane", sorted(LANES))
+def test_serving_step_programs_carry_the_scopes(lane, monkeypatch):
+    kw, attr, want = LANES[lane]
+    seen = {}
+    _record_packed_prefill(monkeypatch, seen)
+    eng = _engine(**kw)
+    setattr(eng, attr, Recorder(getattr(eng, attr), seen, lane))
+    _drive(eng)
+    assert want <= seen[lane], want - seen[lane]
+    # the first wave is a packed one in every lane: an idle mixed
+    # engine degrades to it
+    assert PREFILL <= seen["prefill_packed"], \
+        PREFILL - seen["prefill_packed"]
+
+
+def test_spec_step_carries_the_scopes(monkeypatch):
+    from paddle_tpu.models.serving_engine import SpecConfig
+    seen = {}
+    eng = _engine(spec=SpecConfig(gamma=2, source="prompt_lookup"))
+    real = eng._spec_fused
+    monkeypatch.setattr(eng, "_spec_fused",
+                        lambda: Recorder(real(), seen, "spec"))
+    _drive(eng)
+    want = BLOCK | {"embed", "layer_scan", "attn", "kv_write", "logits",
+                    "sample"}
+    assert want <= seen["spec"], want - seen["spec"]
+
+
+def test_every_scope_of_the_vocabulary_is_tested_somewhere():
+    tested = DECODE | PREFILL | {"attn", "loss_head", "optimizer",
+                                 "grad_accum"}
+    assert tested == set(xplane_meta.SCOPES)
+
+
+# -- Pallas kernels -----------------------------------------------------------
+def test_every_pallas_call_site_carries_a_distinct_name():
+    names = []
+    for path in sorted(glob.glob(os.path.join(
+            REPO, "paddle_tpu", "ops", "pallas", "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "attr", "") == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, f"{path}:{node.lineno} has no name="
+                assert isinstance(kw["name"], ast.Constant)
+                names.append(kw["name"].value)
+    assert len(names) == 16 and len(set(names)) == 16
+    assert set(names) == set(xplane_meta.KERNELS)
+
+
+def test_a_kernel_name_reaches_the_op_path():
+    """``pallas_call(name=)`` binds under a scope of that name: the
+    path the device trace shows ends ``<scope>/<name>/pallas_call``."""
+    from paddle_tpu.ops.pallas.rope import fused_rope, rope_tables
+    cos, sin = rope_tables(16, 128, 1e4)
+
+    def f(x):
+        with jax.named_scope("rope"):
+            return fused_rope(x, cos, sin)
+    text = jax.jit(f).lower(
+        jnp.zeros((1, 16, 2, 128), jnp.float32)).as_text(debug_info=True)
+    paths = re.findall(r'loc\("([^"]+)"', text)
+    assert "jit(f)/rope/rope/pallas_call" in paths
+    assert xplane_meta.kernel_of("jit(f)/rope/rope/pallas_call") == "rope"
+    # a program that names no kernel has a structural word there
+    assert xplane_meta.kernel_of(
+        "jit(step)/jvp()/while/body/closed_call/pallas_call") == ""
+
+
+# -- spans --------------------------------------------------------------------
+@pytest.fixture
+def cpu_trace(tmp_path):
+    """A plain ``jax.profiler.start_trace`` session (what the benchmark's
+    ``TraceSlice`` and an operator's TensorBoard capture open); yields a
+    function that stops it and reads the file."""
+    jax.profiler.start_trace(str(tmp_path))
+    stopped = []
+
+    def read():
+        if not stopped:
+            jax.profiler.stop_trace()
+            stopped.append(True)
+        with open(xplane.find_xplane(str(tmp_path)), "rb") as f:
+            return xplane_meta.parse(f.read())
+    yield read
+    if not stopped:
+        jax.profiler.stop_trace()
+
+
+def test_record_event_reaches_a_plain_jax_trace(cpu_trace):
+    assert not _buffer.enabled
+    with RecordEvent("engine.admit", n_requests=3, lane="packed"):
+        with RecordEvent("admit.write_pages"):
+            jnp.ones((8,)).block_until_ready()
+    mt = cpu_trace()
+    admit, = mt.spans(("engine.admit",))
+    inner, = mt.spans(("admit.write_pages",))
+    assert admit.attrs["n_requests"] in (3, "3")
+    assert admit.attrs["lane"] == "packed"
+    assert admit.start_s <= inner.start_s <= inner.end_s <= admit.end_s
+    assert admit.thread == inner.thread
+    # no buffer entry: only the Paddle-API Profiler collects those
+    assert _drain_spans() == []
+
+
+def test_record_event_with_no_session_buffers_nothing():
+    assert not _buffer.enabled
+    with RecordEvent("engine.step"):
+        pass
+    ev = RecordEvent("engine.fetch")
+    ev.begin()
+    ev.end()
+    ev.end()                                   # idempotent
+    assert _drain_spans() == []
+    _enable_collection()
+    try:
+        with RecordEvent("collected"):
+            pass
+        assert [s[0] for s in _drain_spans()] == ["collected"]
+    finally:
+        _disable_collection()
+        _drain_spans()
+
+
+def test_record_event_as_a_decorator_is_reentrant(cpu_trace):
+    @RecordEvent("engine.drain")
+    def down(n):
+        return n if n == 0 else down(n - 1)
+    assert down(2) == 0
+    spans = cpu_trace().spans(("engine.drain",))
+    assert len(spans) == 3
+    outer, mid, inner = sorted(spans, key=lambda h: h.start_s)
+    assert outer.end_s >= mid.end_s >= inner.end_s
+
+
+def test_ring_span_carries_its_fields_to_the_trace(cpu_trace):
+    ring = EventRing()
+    with ring.span("engine.admit", n_requests=2, tokens=70,
+                   lane="packed"):
+        pass
+    ev, = ring.recent()
+    assert ev["name"] == "engine.admit" and ev["tokens"] == 70
+    assert ev["dur_s"] >= 0
+    span, = cpu_trace().spans(("engine.admit",))
+    assert span.attrs["lane"] == "packed"
+    assert int(span.attrs["tokens"]) == 70
+
+
+def test_engine_spans_nest_on_one_thread_line(cpu_trace):
+    reg = MetricsRegistry()
+    eng = _engine(metrics_registry=reg)
+    _drive(eng)
+    mt = cpu_trace()
+    names = {h.name for h in mt.spans()}
+    assert {"engine.step", "engine.sweep", "engine.admit",
+            "engine.dispatch", "engine.fetch", "engine.drain",
+            "admit.first_token_tail", "admit.write_pages"} <= names
+    write = mt.spans(("admit.write_pages",))[0]
+    admit = [h for h in mt.spans(("engine.admit",))
+             if h.start_s <= write.start_s and write.end_s <= h.end_s]
+    assert admit, "admit.write_pages outside every engine.admit"
+    step = [h for h in mt.spans(("engine.step",))
+            if h.start_s <= admit[0].start_s
+            and admit[0].end_s <= h.end_s]
+    assert step, "engine.admit outside every engine.step"
+    assert step[0].thread == admit[0].thread == write.thread
+    assert admit[0].attrs["lane"] == "packed"
+    assert int(admit[0].attrs["n_requests"]) >= 1
+    tail = mt.spans(("admit.first_token_tail",))[0]
+    assert admit[0].start_s <= tail.start_s <= tail.end_s \
+        <= admit[0].end_s
+    # one ring event a wave, none a step
+    ring_names = [e["name"] for e in eng.metrics.ring.recent()]
+    assert ring_names.count("engine.admit") == \
+        len(mt.spans(("engine.admit",)))
+    assert "engine.step" not in ring_names
+    # every engine.step is the engine thread's
+    assert len({h.thread for h in mt.spans(("engine.step",))}) == 1
+
+
+def test_dataloader_spans(cpu_trace):
+    from paddle_tpu.io.worker import MultiprocessBatchIterator
+    it = MultiprocessBatchIterator(
+        _Rows(), [[0, 1], [2, 3]], num_workers=1,
+        to_device=lambda b: jnp.asarray(b))
+    try:
+        assert next(it).shape == (2, 4)
+    finally:
+        it.shutdown()
+    mt = cpu_trace()
+    nxt, = mt.spans(("dataloader.next",))
+    wait, = mt.spans(("dataloader.wait",))
+    dev, = mt.spans(("dataloader.to_device",))
+    assert nxt.start_s <= wait.start_s <= wait.end_s <= dev.start_s
+    assert dev.end_s <= nxt.end_s
+
+
+class _Rows:
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        return np.full((4,), i, np.int64)
+
+
+def test_server_spans(cpu_trace):
+    from paddle_tpu.inference.serving import (GenerationServer,
+                                              generate_http)
+    cfg = _cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0), _mesh())
+    cache = paged_decode.PagedKVCache(cfg, num_pages=64, pages_max=8,
+                                      batch=2, page=16)
+    srv = GenerationServer(cfg, params, cache)
+    port = srv.start()
+    try:
+        toks = generate_http(f"http://127.0.0.1:{port}",
+                             [int(t) for t in range(1, 20)],
+                             max_new_tokens=3, timeout=120.0)
+        assert len(toks) == 3
+    finally:
+        srv.stop()
+        for t in srv._threads:
+            t.join(30)
+    mt = cpu_trace()
+    http, = mt.spans(("server.http",))
+    deliver = mt.spans(("server.deliver",))
+    assert deliver and http.end_s <= deliver[0].start_s
+    steps = mt.spans(("engine.step",))
+    assert steps and http.thread != steps[0].thread
