@@ -66,10 +66,14 @@ class LlamaPretrainConfig:
     # (head<->seq all_to_all; needs heads % sep == 0).  See
     # distributed/parallel/context_parallel.py.
     context_parallel: Optional[str] = None
-    # loss head: >1 = chunked softmax cross-entropy (custom vjp that never
-    # materialises fp32 [B,S,V] logits; see ops/chunked_loss.py); 0/1 =
-    # plain log_softmax head.  The flattened token count batch*(seq-1)
-    # must be divisible by the chunk count.
+    # loss head: >1 = chunked softmax cross-entropy (ops/chunked_loss.py:
+    # one scan over that many token chunks, fp32 [B,S,V] logits never
+    # materialised; under grad the same scan forms dx and dW, three
+    # matmuls a chunk, and the backward only scales them — the residuals
+    # are a [B,S,H] activation-dtype dx and an [H,V] dW, alive only
+    # between the head's forward and its backward, which are adjacent);
+    # 0/1 = plain log_softmax head.  The flattened token count
+    # batch*(seq-1) must be divisible by the chunk count.
     loss_chunks: int = 0
 
     def __post_init__(self):

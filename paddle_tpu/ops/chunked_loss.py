@@ -8,19 +8,29 @@ read+write) and keeps them as residuals for backward — at B=8, S=2047,
 V=32000 that is ~2.1 GB per pass of pure HBM traffic and the same again in
 residency.
 
-TPU-native design: a ``jax.custom_vjp`` that
-  * forward: flattens tokens to [T,H] and scans over T-chunks, computing
-    per-chunk logits with a bf16 MXU matmul accumulated in fp32
-    (``preferred_element_type``), reducing each chunk immediately to
-    (logsumexp, target-logit) — the [C,V] block dies in VMEM/local HBM
-    instead of being written back;
-  * backward: re-runs the same scan, forming d_logits = softmax - onehot
-    per chunk (the one-hot is an iota comparison XLA fuses into the
-    subtraction) and accumulating dx and dW; nothing [T,V]-shaped is ever
-    a residual — only x, W, targets are saved.
+TPU-native design: a ``jax.custom_vjp`` over the tokens flattened to [T,H]
+and cut into chunks, one ``lax.scan`` either way.
 
-This is remat applied surgically to the loss head, with the savings
-guaranteed by construction rather than left to the global remat policy.
+  * Not differentiated (an evaluation loop): per chunk one bf16 MXU matmul
+    accumulated in fp32 (``preferred_element_type``), reduced at once to
+    (logsumexp, target logit) — the [C,V] block dies in VMEM/local HBM
+    instead of being written back.
+  * Differentiated: the loss is a scalar, so the cotangent that reaches
+    the head is a scalar, and the gradients are formed IN THE FORWARD scan
+    and scaled by that scalar afterwards.  Per chunk: the same logits
+    matmul, one max/exp/sum pass that gives both the logsumexp and the
+    softmax, d_logits = (softmax - onehot) / T (the one-hot is an iota
+    comparison XLA fuses into the subtraction) rounded to the compute
+    dtype, dx_chunk = d_logits @ W^T and dW += x_chunk^T @ d_logits in an
+    fp32 carry beside the loss sum: three matmuls and one exp pass a
+    chunk, where a backward scan of its own would need the logits (and the
+    exp) a second time.  The backward rule is two scalar multiplies.
+
+Nothing [T,V]-shaped is ever a residual.  The residuals are dx ([B,S,H],
+x's dtype) and dW ([H,V], W's dtype); they live only between the head's
+forward and its backward, which are adjacent in a train step (the head is
+the last thing forward and the first thing backward), and are the arrays
+the backward hands on anyway.  x, W and the targets are not saved.
 """
 
 from __future__ import annotations
@@ -61,38 +71,34 @@ def chunked_softmax_cross_entropy(x, w, targets, num_chunks: int = 8,
     targets: [...] int labels; the leading dims are flattened and must be
     divisible by ``num_chunks``.
     """
-    nll, _ = _ce_forward(x, w, targets, num_chunks, compute_dtype)
-    return nll
-
-
-def _ce_forward(x, w, targets, num_chunks, dt):
     xs, ts, T = _flatten(x, targets, num_chunks)
 
     def step(acc, inp):
         xc, tc = inp
-        logits = _chunk_logits(xc, w, dt)                        # [C,V] f32
+        logits = _chunk_logits(xc, w, compute_dtype)             # [C,V] f32
         lse = jax.scipy.special.logsumexp(logits, axis=-1)       # [C]
         tgt = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
         return acc + jnp.sum(lse - tgt), None
 
     total, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32), (xs, ts))
-    return total / T, (x, w, targets)
+    return total / T
 
 
 def _ce_fwd(x, w, targets, num_chunks, dt):
-    return _ce_forward(x, w, targets, num_chunks, dt)
-
-
-def _ce_bwd(num_chunks, dt, res, g):
-    x, w, targets = res
-    H, V = w.shape
+    V = w.shape[1]
     xs, ts, T = _flatten(x, targets, num_chunks)
-    scale = (g / T).astype(jnp.float32)
+    scale = jnp.float32(1.0) / T
 
-    def step(dw_acc, inp):
+    def step(carry, inp):
+        total, dw_acc = carry
         xc, tc = inp
-        logits = _chunk_logits(xc, w, dt)
-        p = jax.nn.softmax(logits, axis=-1)                      # [C,V] f32
+        logits = _chunk_logits(xc, w, dt)                        # [C,V] f32
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        e = jnp.exp(logits - m)
+        s = jnp.sum(e, axis=-1, keepdims=True)
+        lse = (jnp.log(s) + m)[:, 0]                             # [C]
+        tgt = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+        p = e / s                                                # softmax
         d_logits = (p - jax.nn.one_hot(tc, V, dtype=p.dtype)) * scale
         d_logits_c = d_logits.astype(dt)
         dxc = jax.lax.dot_general(                               # [C,H]
@@ -103,11 +109,17 @@ def _ce_bwd(num_chunks, dt, res, g):
             xc.astype(dt), d_logits_c,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dw_acc + dwc, dxc
+        return ((total + jnp.sum(lse - tgt), dw_acc + dwc),
+                dxc.astype(x.dtype))
 
-    dw, dxs = jax.lax.scan(step, jnp.zeros((H, V), jnp.float32), (xs, ts))
-    dx = dxs.reshape(x.shape)
-    return dx.astype(x.dtype), dw.astype(w.dtype), None
+    init = (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32))
+    (total, dw), dxs = jax.lax.scan(step, init, (xs, ts))
+    return total / T, (dxs.reshape(x.shape), dw.astype(w.dtype))
+
+
+def _ce_bwd(num_chunks, dt, res, g):
+    dx, dw = res
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None
 
 
 chunked_softmax_cross_entropy.defvjp(_ce_fwd, _ce_bwd)
