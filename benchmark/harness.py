@@ -6,13 +6,14 @@ cache and compile counter; the profiler slice; the result line.
 from __future__ import annotations
 
 import argparse
-import importlib
 import importlib.util
 import json
 import os
 import shutil
 import sys
 import time
+
+from . import models
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -61,8 +62,14 @@ class Cell:
     def _fill(self, name, chips, conf, traffic):
         self.name, self.chips = name, chips
         self.conf, self.traffic = conf, traffic
-        self.family = importlib.import_module(
-            "benchmark.models." + conf["family"])
+        self.family = models.family(conf)
+
+    @property
+    def block_reference(self):
+        """The plain reference of the family's block, imported on first
+        use (it imports jax; a process that only launches runs never
+        asks)."""
+        return models.block_reference(self.conf)
 
     @classmethod
     def detached(cls, name: str, chips: int, conf: dict, traffic: dict):

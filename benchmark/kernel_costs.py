@@ -1,16 +1,34 @@
 """Operations and bytes that the ALGORITHM needs, from shapes and live
 token counts — never from padded shapes, never from the program's own
-cost models.  ``conf`` is a configuration file's dict."""
+cost models.  ``conf`` is a configuration file's dict.
+
+What ONE BLOCK costs is the family's to say (``block_costs(conf)`` of
+``benchmark/models/<family>.py``, a :class:`BlockCosts`); the trunk
+around the blocks (depth, embedding, head) and the arithmetic from
+those numbers to FLOPs and bytes are here, the same for every
+architecture."""
 
 from __future__ import annotations
 
+from typing import NamedTuple
 
-def block_params(conf: dict) -> int:
-    """Matrix parameters of one block (norm vectors left out: they are
-    no matrix product)."""
-    h, f = conf["hidden_size"], conf["intermediate_size"]
-    kv = conf["num_key_value_heads"] * conf["head_dim"]
-    return 2 * h * h + 2 * h * kv + 3 * h * f
+from . import models
+
+
+class BlockCosts(NamedTuple):
+    """One block, by its configuration.  The two counts of matrix
+    parameters are equal where every token passes through every matrix;
+    a block that routes a token to some of its experts multiplies fewer
+    than it holds."""
+    matmul_params: int      # matrix parameters a token multiplies: 2 FLOPs each, forward
+    resident_params: int    # matrix parameters the block holds: the bytes a step may read
+    vector_params: int      # norm vectors and the like: no matrix product
+    attn_width: int         # heads x head_dim: QK^T and PV cost 2 x 2 x this a key a query
+    kv_values: int          # values a token leaves in the cache
+
+
+def block_costs(conf: dict) -> BlockCosts:
+    return models.family(conf).block_costs(conf)
 
 
 def head_params(conf: dict) -> int:
@@ -20,42 +38,48 @@ def head_params(conf: dict) -> int:
 def total_params(conf: dict) -> int:
     h = conf["hidden_size"]
     L = conf["num_hidden_layers"]
-    return (L * (block_params(conf) + 2 * h) + 2 * head_params(conf) + h)
+    blk = block_costs(conf)
+    return (L * (blk.resident_params + blk.vector_params)
+            + 2 * head_params(conf) + h)
 
 
 def train_flops_per_token(conf: dict, seq: int) -> float:
-    """Forward + backward, recompute not counted: 6 x (block matrices +
-    head) for the products, and causal attention's QK^T and PV: forward
-    2 * 2 * S/2 * hidden a layer a token, times 3 with the backward."""
-    L, h = conf["num_hidden_layers"], conf["hidden_size"]
-    return 6.0 * (L * block_params(conf) + head_params(conf)) \
-        + 6.0 * L * seq * h
+    """Forward + backward, recompute not counted: 6 x (the block
+    matrices a token multiplies + head) for the products, and causal
+    attention's QK^T and PV: forward 2 * 2 * S/2 * heads * head_dim a
+    layer a token, times 3 with the backward."""
+    L, blk = conf["num_hidden_layers"], block_costs(conf)
+    return 6.0 * (L * blk.matmul_params + head_params(conf)) \
+        + 6.0 * L * seq * blk.attn_width
 
 
 def prefill_flops(conf: dict, prompt_lens) -> float:
-    """Forward over whole prompts: 2 x block matrices a token, causal
-    attention 2 * S^2 * hidden a layer a sequence (QK^T and PV, half
-    masked), and the head once a sequence.  Pads are not counted."""
-    L, h = conf["num_hidden_layers"], conf["hidden_size"]
+    """Forward over whole prompts: 2 x the block matrices a token
+    multiplies, causal attention 2 * S^2 * heads * head_dim a layer a
+    sequence (QK^T and PV, half masked), and the head once a sequence.
+    Pads are not counted."""
+    L, blk = conf["num_hidden_layers"], block_costs(conf)
     toks = sum(prompt_lens)
-    attn = sum(2.0 * s * s * h for s in prompt_lens)
-    return 2.0 * L * block_params(conf) * toks + L * attn \
+    attn = sum(2.0 * s * s * blk.attn_width for s in prompt_lens)
+    return 2.0 * L * blk.matmul_params * toks + L * attn \
         + 2.0 * head_params(conf) * len(prompt_lens)
 
 
 def weight_bytes_per_chip(conf: dict, chips: int = 1,
                           itemsize: int = 2) -> float:
     """Bytes of weights one decode step reads on ONE chip: every block
-    matrix and the head (its share under tensor parallelism), and not
-    the embedding table, of which a step reads one row a sequence."""
+    matrix that is resident (a batch of tokens may reach every expert)
+    and the head (its share under tensor parallelism), and not the
+    embedding table, of which a step reads one row a sequence."""
     L = conf["num_hidden_layers"]
-    return (L * block_params(conf) + head_params(conf)) * itemsize / chips
+    return (L * block_costs(conf).resident_params
+            + head_params(conf)) * itemsize / chips
 
 
 def kv_bytes_per_token(conf: dict, chips: int = 1,
                        itemsize: int = 2) -> float:
-    return (conf["num_hidden_layers"] * 2 * conf["num_key_value_heads"]
-            * conf["head_dim"] * itemsize) / chips
+    return (conf["num_hidden_layers"] * block_costs(conf).kv_values
+            * itemsize) / chips
 
 
 def decode_step_bytes(conf: dict, resident_tokens: float,

@@ -10,10 +10,11 @@ from . import kernel_costs
 def flash_attn_train_flops_per_token(conf: dict, seq: int) -> float:
     """Causal attention forward + backward for one token of a
     ``seq``-token row, recompute not counted: QK^T and PV over the S/2
-    keys a query sees on average, 2 * 2 * S/2 * hidden a layer forward,
-    times 3 with the backward — the ``6·L·S·H`` term of
+    keys a query sees on average, 2 * 2 * S/2 * heads * head_dim a layer
+    forward, times 3 with the backward — the attention term of
     ``kernel_costs.train_flops_per_token``.  Bound: compute."""
-    return 6.0 * conf["num_hidden_layers"] * seq * conf["hidden_size"]
+    return 6.0 * conf["num_hidden_layers"] * seq \
+        * kernel_costs.block_costs(conf).attn_width
 
 
 def paged_attn_step_bytes(conf: dict, resident_tokens: float,

@@ -2,21 +2,37 @@
 biases, untied head — the one block the program's main path runs
 (``LlamaPretrainConfig``).  A configuration file names this module by
 ``"family": "llama_block"``; another family is another file here with
-the same functions.
+the same functions (``benchmark/models/__init__.py`` lists them), and
+its plain reference beside it (``llama_block_reference.py``).
 
 The benchmark, not the program, makes the weights: one jitted call from
 the seed, on the device, in the type they are used in.  The reference
-(``benchmark/reference.py``) is given these same arrays.
+is given these same arrays.  This block's program uses only names of
+the base vocabulary, so it states no ``SCOPES`` or ``KERNELS``.
 """
 
 from __future__ import annotations
 
 import math
 
+from ..kernel_costs import BlockCosts
+
 # leaves of one block, in a fixed order: a leaf's key is its index
 BLOCK_LEAVES = ("ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
                 "w_down")
 TOP_LEAVES = ("embed", "final_norm", "lm_head")
+
+
+def block_costs(conf: dict) -> BlockCosts:
+    """Every token passes through every matrix: q and o are hidden x
+    heads*head_dim, k and v hidden x kv_heads*head_dim, gate, up and
+    down hidden x intermediate."""
+    h, f = conf["hidden_size"], conf["intermediate_size"]
+    q = conf["num_attention_heads"] * conf["head_dim"]
+    kv = conf["num_key_value_heads"] * conf["head_dim"]
+    mats = 2 * h * q + 2 * h * kv + 3 * h * f
+    return BlockCosts(matmul_params=mats, resident_params=mats,
+                      vector_params=2 * h, attn_width=q, kv_values=2 * kv)
 
 
 def build_cfg(conf: dict, train: bool, job: dict | None = None):

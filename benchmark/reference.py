@@ -1,8 +1,14 @@
-"""The plain reference: the pre-norm RMSNorm / RoPE / GQA / SwiGLU block
-as published, in straightforward ``jax.numpy``, float32, every matrix
-product at ``Precision.HIGHEST`` — no kernel, no cache, no scan, no
-batching tricks.  It imports nothing of the program and is handed only
-what the benchmark itself made from the seed (weights, token ids).
+"""The plain reference's machinery, the same for every architecture:
+the matrix product (float32 at ``Precision.HIGHEST``, and the int8
+CONTROL), RMSNorm and RoPE, the trunk (embedding, the blocks in turn,
+final norm, untied head), the head's loss and gradients, adafactor, and
+the loops that drive them.  The BLOCK is the family's:
+``benchmark/models/<family>_reference.py`` (``blk`` below) gives its
+leaf names, its ``dims_of(conf)`` and ``block(x, w, dims, precision)``
+-> (output, a scalar added to the loss); ``benchmark/models/__init__.py``
+states the contract.
+Nothing here imports the program, and it is handed only what the
+benchmark itself made from the seed (weights, token ids).
 
 Serving: :func:`serve_gaps` runs one full forward pass over a prompt
 and the tokens that were served after it, layer by layer with each
@@ -69,7 +75,7 @@ def matmul(x, w, precision: str):
 
 
 # ---------------------------------------------------------------------------
-# the block
+# what every block is made of
 # ---------------------------------------------------------------------------
 def rms_norm(x, w, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
@@ -87,38 +93,20 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(x, w, dims, precision="f32"):
-    """One block on x [b, s, hidden] (float32); ``w`` the layer's nine
-    float32 leaves; ``dims`` = (heads, kv_heads, head_dim, theta, eps)."""
-    n, nkv, d, theta, eps = dims
-    b, s, h = x.shape
-    mm = functools.partial(matmul, precision=precision)
-    y = rms_norm(x, w["ln1"], eps)
-    q = rope(mm(y, w["wq"]).reshape(b, s, n, d), theta)
-    k = rope(mm(y, w["wk"]).reshape(b, s, nkv, d), theta)
-    v = mm(y, w["wv"]).reshape(b, s, nkv, d)
-    k = jnp.repeat(k, n // nkv, axis=2)
-    v = jnp.repeat(v, n // nkv, axis=2)
-    sc = jnp.einsum("bqnd,bknd->bnqk", q, k, precision=HI) / math.sqrt(d)
-    sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    a = jnp.einsum("bnqk,bknd->bqnd", p, v, precision=HI).reshape(b, s, h)
-    x = x + mm(a, w["wo"])
-    y = rms_norm(x, w["ln2"], eps)
-    return x + mm(jax.nn.silu(mm(y, w["w_gate"])) * mm(y, w["w_up"]),
-                  w["w_down"])
+# ---------------------------------------------------------------------------
+# the trunk around the family's block
+# ---------------------------------------------------------------------------
+TOP_LEAVES = ("embed", "final_norm", "lm_head")
 
 
-def dims_of(conf: dict):
-    return (conf["num_attention_heads"], conf["num_key_value_heads"],
-            conf["head_dim"], float(conf["rope_theta"]),
-            float(conf["rms_norm_eps"]))
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "precision"))
-def _block_up(x, w, dims, precision):
-    return block(x, jax.tree_util.tree_map(lambda a: a.astype(F32), w),
-                 dims, precision)
+@functools.partial(jax.jit, static_argnames=("blk", "dims", "precision"))
+def _block_up(x, w, aux, blk, dims, precision):
+    """The family's block with the layer's leaves cast up: its output,
+    and ``aux`` plus its term of the loss (summed here, so that the
+    sum costs no program of its own on the device)."""
+    y, term = blk.block(x, jax.tree_util.tree_map(
+        lambda a: a.astype(F32), w), dims, precision)
+    return y, aux + term
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "precision"))
@@ -141,7 +129,8 @@ def _layer_of(params, i, dev):
             for k, v in params["blocks"].items()}
 
 
-def forward_rows(params, conf, tokens, rows, precision="f32", dev=None):
+def forward_rows(blk, params, conf, tokens, rows, precision="f32",
+                 dev=None):
     """Logits [len(rows), vocab] (float32, on the host) of one sequence
     at positions ``rows``.  ``params`` is the benchmark's tree (stacked
     layers, any float type, any layout); ``dev`` gathers a sharded
@@ -149,30 +138,33 @@ def forward_rows(params, conf, tokens, rows, precision="f32", dev=None):
     n = len(tokens)
     toks = np.zeros((_pad_len(n),), np.int64)
     toks[:n] = tokens
-    dims = dims_of(conf)
+    dims = blk.dims_of(conf)
     emb = params["embed"]
     x = jnp.take(emb, jnp.asarray(toks), axis=0).astype(F32)[None]
     if dev is not None:
         x = jax.device_put(x, dev)
     for i in range(conf["num_hidden_layers"]):
-        x = _block_up(x, _layer_of(params, i, dev), dims, precision)
+        x, _ = _block_up(x, _layer_of(params, i, dev), np.float32(0), blk,
+                         dims, precision)
     put = (lambda a: jax.device_put(a, dev)) if dev is not None \
         else (lambda a: a)
     out = _head_rows(x[0, jnp.asarray(rows)], put(params["final_norm"]),
-                     put(params["lm_head"]), dims[4], precision)
+                     put(params["lm_head"]),
+                     float(conf["rms_norm_eps"]), precision)
     return np.asarray(out)
 
 
-def serve_gaps(params, conf, prompt, served, control=False, dev=None):
+def serve_gaps(blk, params, conf, prompt, served, control=False,
+               dev=None):
     """For one finished request: at each served token's position, how
     far the reference's logit of that token lies below the reference's
     best.  ``control=True`` reads instead the gap of the token the int8
     computation puts first at that position (it decodes nothing)."""
     seq = list(prompt) + list(served[:-1])
     rows = np.arange(len(prompt) - 1, len(seq))
-    ref = forward_rows(params, conf, seq, rows, "f32", dev)
+    ref = forward_rows(blk, params, conf, seq, rows, "f32", dev)
     if control:
-        low = forward_rows(params, conf, seq, rows, "int8", dev)
+        low = forward_rows(blk, params, conf, seq, rows, "int8", dev)
         picked = low.argmax(-1)
     else:
         picked = np.asarray(served, np.int64)
@@ -182,14 +174,23 @@ def serve_gaps(params, conf, prompt, served, control=False, dev=None):
 # ---------------------------------------------------------------------------
 # training: loss, gradients and adafactor over the first steps
 # ---------------------------------------------------------------------------
+# Rows that go through a block together.  A block's own term of the
+# loss is a mean of per-row quantities (the contract in
+# ``benchmark/models/__init__.py``), so this number changes no result.
+ROW_BLOCK = 2
+
+
 def _factored(shape) -> bool:
     return len(shape) >= 2 and shape[-1] >= 128 and shape[-2] >= 128
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "precision"))
-def _block_bwd(x, w, g, dims, precision):
-    _, vjp = jax.vjp(lambda x, w: block(x, w, dims, precision), x, w)
-    return vjp(g)
+@functools.partial(jax.jit, static_argnames=("blk", "dims", "precision"))
+def _block_bwd(x, w, g, g_aux, blk, dims, precision):
+    """Gradients of a block's input and leaves from ``g``, the gradient
+    of its output, and ``g_aux``, the weight of its own term in the
+    step's loss."""
+    _, vjp = jax.vjp(lambda x, w: blk.block(x, w, dims, precision), x, w)
+    return vjp((g, g_aux))
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "precision", "total"))
@@ -243,41 +244,55 @@ class TrainReference:
     """Float32 copy of the job's state, stepped leaf by leaf.
 
     ``leaf(path)`` returns the benchmark's stacked float32 leaf for a
-    path such as ``("blocks", "wq")`` or ``("embed",)``; the stacked
-    block leaves are split into per-layer arrays so that a layer's
-    gradient can be accumulated and applied alone."""
+    path such as ``("blocks", <a leaf of the family's block>)`` or
+    ``("embed",)``; the stacked block leaves are split into per-layer
+    arrays so that a layer's gradient can be accumulated and applied
+    alone."""
 
-    def __init__(self, conf, leaf, job, precision="f32", row_block=2):
-        self.conf, self.job, self.precision = conf, job, precision
-        self.dims = dims_of(conf)
+    def __init__(self, blk, conf, leaf, job, precision="f32"):
+        self.blk, self.conf, self.job = blk, conf, job
+        self.precision = precision
+        self.dims = blk.dims_of(conf)
+        self.eps = float(conf["rms_norm_eps"])
         self.L = conf["num_hidden_layers"]
-        self.row_block = row_block
         self.leaf = leaf
-        names = ("ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-                 "w_down")
         self.layers = [dict() for _ in range(self.L)]
-        for nm in names:
+        for nm in blk.BLOCK_LEAVES:
             stacked = leaf(("blocks", nm))
             for i in range(self.L):
                 self.layers[i][nm] = stacked[i]
             del stacked
-        self.top = {nm: leaf((nm,))
-                    for nm in ("embed", "final_norm", "lm_head")}
+        self.top = {nm: leaf((nm,)) for nm in TOP_LEAVES}
         self.opt_layers = [jax.tree_util.tree_map(_opt_init, w)
                            for w in self.layers]
         self.opt_top = jax.tree_util.tree_map(_opt_init, self.top)
         self.t = 0
         self.grad_norms = None      # of the FIRST step, per stacked leaf
 
+    def _blocks_up(self, x):
+        """Every layer's input and the last one's output, and the sum
+        of the blocks' own loss terms.  A function of its own, so that
+        no local outlives it: the backward pass pops the activations
+        one by one, and one more [rows, s, hidden] kept alive shows in
+        the device's peak."""
+        acts, aux = [x], np.float32(0)
+        for w in self.layers:
+            y, aux = _block_up(acts[-1], w, aux, self.blk, self.dims,
+                               self.precision)
+            acts.append(y)
+        return acts, aux
+
     def step(self, tokens) -> float:
-        """One training step on ``tokens`` [B, S+1]; returns its loss.
+        """One training step on ``tokens`` [B, S+1]; returns its loss:
+        the tokens' mean NLL plus every block's own term, each weighted
+        by its rows' share of the batch.
         Rows go forward in blocks; the backward pass then walks the
         layers from the top, sums a layer's gradient over the blocks,
         applies it and frees it before the next layer, so that only one
         layer's gradient is alive at a time."""
         tokens = np.asarray(tokens)
         B, S1 = tokens.shape
-        total, eps = B * (S1 - 1), self.dims[4]
+        total, eps = B * (S1 - 1), self.eps
         first = self.t == 0
         self.t += 1
         lr, wd = float(self.job["lr"]), float(self.job["weight_decay"])
@@ -285,18 +300,18 @@ class TrainReference:
         sq = lambda a: float(jnp.sum(jnp.square(a)))
         norms = {}
 
-        xs, gxs, inps, g_top, loss = [], [], [], None, 0.0
-        for r0 in range(0, B, self.row_block):
-            tok = jnp.asarray(tokens[r0:r0 + self.row_block])
+        xs, gxs, inps, shares, g_top, loss = [], [], [], [], None, 0.0
+        for r0 in range(0, B, ROW_BLOCK):
+            tok = jnp.asarray(tokens[r0:r0 + ROW_BLOCK])
             inp, tgt = tok[:, :-1], tok[:, 1:]
-            acts = [jnp.take(self.top["embed"], inp, axis=0)]
-            for w in self.layers:
-                acts.append(_block_up(acts[-1], w, self.dims,
-                                      self.precision))
+            share = np.float32(tok.shape[0] / B)
+            acts, aux = self._blocks_up(
+                jnp.take(self.top["embed"], inp, axis=0))
             part, (gx, gfn, glm) = _head_loss(
                 acts.pop(), self.top["final_norm"], self.top["lm_head"],
                 tgt, eps, self.precision, total)
-            loss += float(part)
+            loss += float(part) + float(share) * float(aux)
+            shares.append(share)
             gt = {"final_norm": gfn, "lm_head": glm}
             g_top = gt if g_top is None else _acc(g_top, gt)
             xs.append(acts)
@@ -307,7 +322,8 @@ class TrainReference:
             gw = None
             for b in range(len(xs)):
                 gxs[b], g = _block_bwd(xs[b].pop(), self.layers[i], gxs[b],
-                                       self.dims, self.precision)
+                                       shares[b], self.blk, self.dims,
+                                       self.precision)
                 gw = g if gw is None else _acc(gw, g)
             for nm in list(self.layers[i]):
                 g = gw.pop(nm)

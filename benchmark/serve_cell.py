@@ -275,15 +275,16 @@ def run(args, cell, token_override=None, control=False) -> int:
     checks = harness.Checks()
     sample = check_sample(window, args.seed, mix["check_requests"])
     dev = devices[0] if tp else None
+    blk = cell.block_reference
     t_ref = time.monotonic()
     gaps, low = [], []
     for r in sample:
         prompt = phases[1]["requests"][r["index"]]["prompt"]
         gaps.append(float(reference.serve_gaps(
-            params, cell.conf, prompt, r["tokens"], dev=dev).max()))
+            blk, params, cell.conf, prompt, r["tokens"], dev=dev).max()))
         if control:
             low.append(float(reference.serve_gaps(
-                params, cell.conf, prompt, r["tokens"], control=True,
+                blk, params, cell.conf, prompt, r["tokens"], control=True,
                 dev=dev).max()))
     n_tok = sum(len(r["tokens"]) for r in sample)
     log(f"reference: {len(sample)} requests, {n_tok} served tokens, "

@@ -3,9 +3,12 @@
 cell's step programs at their REAL sizes for a described ``v5e:2x2``,
 with no chip attached, and print what the compiler says they need.
 Nothing runs: this settles what fits (training depth, pool sizes,
-packed-prefill buckets), never a time.  Run by hand:
+packed-prefill buckets), never a time.  A cell is a workload of
+BENCHMARK.json or ``<config>:<traffic>:<chips>``; its family's module
+gives the config object and the leaves' shapes.  Run by hand:
 
-    JAX_PLATFORMS=cpu python3 benchmark/tests/aot_compile.py train 14 16
+    JAX_PLATFORMS=cpu python3 benchmark/tests/aot_compile.py train internlm2-1.8b.pretrain-2k 17 18 19 20
+    JAX_PLATFORMS=cpu python3 benchmark/tests/aot_compile.py reference internlm2-1.8b.pretrain-2k
     JAX_PLATFORMS=cpu python3 benchmark/tests/aot_compile.py serve internlm2-1.8b:chat-steady:1 64 2048 4096
     JAX_PLATFORMS=cpu python3 benchmark/tests/aot_compile.py serve mistral-7b-v0.3:chat-steady-tp4:4 2048
 """
@@ -13,8 +16,10 @@ packed-prefill buckets), never a time.  Run by hand:
 from __future__ import annotations
 
 import os
+import re
 import sys
 import time
+from collections import Counter
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -46,18 +51,43 @@ def param_sds(fam, cfg, mesh):
     return out
 
 
+# an instruction the compiler's own rematerialization pass cloned, as
+# ``as_text()`` defines it: ``%fusion.381.remat3 = bf16[...] fusion(...)``
+REMAT_CLONE = re.compile(
+    r"^\s*(?:ROOT )?%(\S+?\.remat\d*) = \S+ ([a-z\-]+)\((.*)$", re.M)
+MATMUL_PATH = re.compile(r'op_name="[^"]*/dot_general"')
+
+
+def remat_clones(text: str) -> str:
+    """What XLA rematerialized because the program was at the HBM
+    limit: the fusions that are matrix products by name (each runs a
+    second time, every step), and the other clones by opcode."""
+    matmuls, others = [], Counter()
+    for name, opcode, rest in REMAT_CLONE.findall(text):
+        if opcode == "fusion" and MATMUL_PATH.search(rest):
+            matmuls.append(name)
+        else:
+            others[opcode] += 1
+    return (f"{len(matmuls)} .remat matrix-product fusions "
+            f"{sorted(matmuls)}, {sum(others.values())} other .remat "
+            f"clones {dict(others)}")
+
+
 def report(what, compiled, t0):
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes +
              ma.output_size_in_bytes - ma.alias_size_in_bytes)
     text = compiled.as_text()
     print(f"{what}: compiled in {time.time() - t0:.0f}s; per device: args "
-          f"{ma.argument_size_in_bytes / GIB:.2f} GiB, temp "
-          f"{ma.temp_size_in_bytes / GIB:.2f}, out "
+          f"{ma.argument_size_in_bytes / GIB:.2f} GiB "
+          f"({ma.argument_size_in_bytes} B), temp "
+          f"{ma.temp_size_in_bytes / GIB:.2f} "
+          f"({ma.temp_size_in_bytes} B), out "
           f"{ma.output_size_in_bytes / GIB:.2f}, aliased "
           f"{ma.alias_size_in_bytes / GIB:.2f}, live {total / GIB:.2f}; "
           f"{text.count('tpu_custom_call')} kernels, "
-          f"{text.count('all-reduce(')} all-reduce", flush=True)
+          f"{text.count('all-reduce(')} all-reduce; "
+          f"{remat_clones(text)}", flush=True)
 
 
 def main(argv) -> int:
@@ -69,13 +99,38 @@ def main(argv) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+    if argv[1] == "reference":
+        # what the plain reference's largest program needs: the
+        # backward of the family's block on the rows the loop gives it,
+        # beside the float32 weights and adafactor state it holds
+        from benchmark import kernel_costs, reference
+        cell = harness.find_cell(argv[2])
+        job, fam, blk = cell.traffic, cell.family, cell.block_reference
+        mesh = build_mesh(devices=topo.devices[:1])
+        cfg = fam.build_cfg(cell.conf, train=True, job=job)
+        shapes = fam.leaf_shapes(cfg)
+        f32 = lambda shape: sds(mesh, shape, jnp.float32)
+        w = {nm: f32(shapes[("blocks", nm)][1:]) for nm in blk.BLOCK_LEAVES}
+        x = f32((reference.ROW_BLOCK, job["seq"], cell.conf["hidden_size"]))
+        t0 = time.time()
+        with mesh:
+            c = reference._block_bwd.lower(
+                x, w, x, f32(()), blk, blk.dims_of(cell.conf),
+                "f32").compile()
+        report(f"{cell.name} reference block backward, "
+               f"{reference.ROW_BLOCK} rows of {job['seq']}", c, t0)
+        n = kernel_costs.total_params(cell.conf)
+        print(f"{cell.name} reference holds {n} float32 parameters "
+              f"({4 * n / GIB:.2f} GiB) and their adafactor state",
+              flush=True)
+        return 0
     if argv[1] == "train":
         from paddle_tpu.models.llama_pretrain import (
             init_adafactor_state, make_train_step)
-        cell = harness.Cell("internlm2-1.8b.pretrain-2k")
+        cell = harness.find_cell(argv[2])
         job, fam = cell.traffic, cell.family
-        mesh = build_mesh(devices=topo.devices[:1])
-        for depth in map(int, argv[2:]):
+        mesh = build_mesh(devices=topo.devices[:cell.chips])
+        for depth in map(int, argv[3:]):
             conf = dict(cell.conf, num_hidden_layers=depth)
             cfg = fam.build_cfg(conf, train=True, job=job)
             t0 = time.time()
@@ -92,10 +147,10 @@ def main(argv) -> int:
                     c = step.lower(params, opt, sds(
                         mesh, (job["batch"], job["seq"] + 1),
                         jnp.int64)).compile()
-                report(f"train step depth {depth} loss_chunks "
+                report(f"{cell.name} train step depth {depth} loss_chunks "
                        f"{job['loss_chunks']}", c, t0)
             except Exception as e:
-                print(f"train step depth {depth}: REFUSED after "
+                print(f"{cell.name} train step depth {depth}: REFUSED after "
                       f"{time.time() - t0:.0f}s: {str(e)[:600]}", flush=True)
         return 0
     cell = harness.find_cell(argv[2])

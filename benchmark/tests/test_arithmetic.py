@@ -75,7 +75,7 @@ def test_shape_sweep_covers_every_page_count():
 def test_counts_against_hand_sums():
     i = conf("internlm2-1.8b")
     # q,o: 2048x2048 each; k,v: 2048x1024 each; gate,up,down: 2048x8192
-    assert kernel_costs.block_params(i) == \
+    assert kernel_costs.block_costs(i).resident_params == \
         2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 8192 == 62914560
     assert kernel_costs.head_params(i) == 2048 * 92544 == 189530112
     assert kernel_costs.total_params(i) == \
@@ -83,7 +83,7 @@ def test_counts_against_hand_sums():
     assert kernel_costs.kv_bytes_per_token(i) == 24 * 2 * 8 * 128 * 2 \
         == 96 * 1024
     m = conf("mistral-7b-v0.3")
-    assert kernel_costs.block_params(m) == \
+    assert kernel_costs.block_costs(m).resident_params == \
         2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336 == 218103808
     assert kernel_costs.total_params(m) == \
         32 * (218103808 + 8192) + 2 * 4096 * 32768 + 4096 == 7248023552

@@ -103,6 +103,6 @@ def test_serving_control_fails_the_limit():
     rng = np.random.default_rng(5)
     prompt = rng.integers(1, cfg.vocab_size, 150).tolist()
     served = rng.integers(1, cfg.vocab_size, 40).tolist()
-    low = reference.serve_gaps(params, cell.conf, prompt, served,
-                               control=True)
+    low = reference.serve_gaps(cell.block_reference, params, cell.conf,
+                               prompt, served, control=True)
     assert low.max() > cell.traffic["limits"]["served_logit_gap_max"]

@@ -76,7 +76,10 @@ def test_short_name():
 
 def test_train_readers_on_the_recorded_trace(trace):
     """The cell's per-layer readers on the recorded three steps: the
-    MFU is the step's operations over its device time, under 100 %."""
+    MFU is the step's operations over its device time, under 100 %.
+    PR 23's trace is from before the program named anything, so the
+    readers of scopes and kernel names find nothing in it and are left
+    out (``test_xplane_meta.py`` holds all ten to PR 24's trace)."""
     from benchmark import harness
     cell = harness.Cell("internlm2-1.8b.pretrain-2k")
     got = harness.read_layer_metrics(
@@ -84,7 +87,9 @@ def test_train_readers_on_the_recorded_trace(trace):
         {"tokens_per_step": 8 * 2048, "chips": 1,
          "device_kind": "TPU v5 lite"},
         {"input_wait_s": 0.06, "window_s": 30.0})
-    assert set(got) == {m["name"] for m in cell.per_layer()}
+    assert {"train_step_ms.train", "mfu_pct.train", "device_idle_pct.train",
+            "input_wait_pct.train"} <= set(got) \
+        <= {m["name"] for m in cell.per_layer()}
     assert got["train_step_ms.train"]["value"] == pytest.approx(1417.47,
                                                                 abs=0.01)
     assert got["mfu_pct.train"]["value"] == pytest.approx(49.2, abs=0.05)

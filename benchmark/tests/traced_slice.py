@@ -29,9 +29,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 
-def summary(path: str) -> dict:
+def summary(path: str, cell) -> dict:
     from benchmark import xplane, xplane_meta
-    mt = xplane_meta.load(path)
+    mt = xplane_meta.load(path).named(*xplane_meta.names_of(cell))
     tr = xplane.reduce(path)
     out = {"device_self_s": mt.device_self_s(),
            "slice_s": tr.window_s,
@@ -85,7 +85,7 @@ def main(argv) -> int:
         shutil.copyfileobj(f, g)
     print(f"[slice] trace kept: {dst} "
           f"({os.path.getsize(dst) / 2**20:.1f} MiB gzipped)")
-    print("[slice] SUMMARY " + json.dumps(summary(src)))
+    print("[slice] SUMMARY " + json.dumps(summary(src, cell)))
     return rc
 
 

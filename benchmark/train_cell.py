@@ -4,7 +4,8 @@ fed by ``paddle_tpu.io.DataLoader``, on the job a traffic file of
 
 Set-up builds ONE compiled step with its state, drives it through its
 first steps on the window's own feed, and hands that same object to the
-window.  The reference follows those first steps before the program's
+window.  The reference (``reference.TrainReference`` around the block
+of the cell's family) follows those first steps before the program's
 state exists; its time is not set-up.
 """
 
@@ -126,7 +127,8 @@ def run_reference(cell, job, leaf0, batches, precision="f32") -> dict:
     """Loss of each followed step, the first gradient's norm per leaf
     and the parameters' change per leaf, by the plain reference."""
     t = [time.monotonic()]
-    ref = TrainReference(cell.conf, leaf0, job, precision=precision)
+    ref = TrainReference(cell.block_reference, cell.conf, leaf0, job,
+                         precision=precision)
     t.append(time.monotonic())
     losses = [ref.step(b) for b in batches]
     t.append(time.monotonic())

@@ -16,11 +16,18 @@ The names the program gives its work are a closed vocabulary
 (``docs/OBSERVABILITY.md``, "Profiler spans and scopes"): ``SCOPES`` are
 ``jax.named_scope`` names inside the step programs, ``KERNELS`` the
 ``name=`` of the ``pallas_call`` sites, ``SPANS`` the ``RecordEvent``
-spans of the host code.  They are part of the yardstick.
+spans of the host code.  They are part of the yardstick.  The three
+tuples here are the BASE vocabulary, what every architecture's program
+shares; a block family whose program names more adds them as ``SCOPES``
+/ ``KERNELS`` of its module under ``benchmark/models/``, and a trace
+read for a cell (:func:`of_cell`) charges by the union.  The vocabulary
+belongs to the reading, not to the parsed file: one file read for two
+cells is parsed once and charged twice.
 """
 
 from __future__ import annotations
 
+import copy
 import functools
 import os
 import re
@@ -222,10 +229,10 @@ def path_words(tf_op: str) -> list:
     return _WORD.findall(tf_op)
 
 
-def scope_of(tf_op: str) -> str:
-    """The innermost name of ``SCOPES`` on the path."""
+def scope_of(tf_op: str, scopes=SCOPES) -> str:
+    """The innermost name of ``scopes`` on the path."""
     for w in reversed(path_words(tf_op)):
-        if w in SCOPES:
+        if w in scopes:
             return w
     return UNSCOPED
 
@@ -240,29 +247,33 @@ def phase_of(tf_op: str) -> str:
     return "other"
 
 
-def kernel_of(tf_op: str) -> str:
-    """A Pallas kernel's ``name=`` (one of ``KERNELS``): ``pallas_call``
+def kernel_of(tf_op: str, kernels=KERNELS) -> str:
+    """A Pallas kernel's ``name=`` (one of ``kernels``): ``pallas_call``
     binds under a scope of that name, so it is the component before
     ``pallas_call``; a program that names no kernel has ``closed_call``
     or ``checkpoint`` there."""
     parts = tf_op.split("/")
     if len(parts) >= 2 and parts[-1] == "pallas_call" \
-            and parts[-2] in KERNELS:
+            and parts[-2] in kernels:
         return parts[-2]
     return ""
 
 
-_KEYS = {"scope": lambda op: scope_of(op.tf_op),
-         "phase": lambda op: phase_of(op.tf_op),
-         "category": lambda op: op.category,
-         "kernel": lambda op: kernel_of(op.tf_op)}
+_KEYS = {"scope": lambda op, mt: scope_of(op.tf_op, mt.scopes),
+         "phase": lambda op, mt: phase_of(op.tf_op),
+         "category": lambda op, mt: op.category,
+         "kernel": lambda op, mt: kernel_of(op.tf_op, mt.kernels)}
 
 
 class MetaTrace:
     """``ops``: chip index -> list of ``Op`` sorted by start;
     ``modules``: chip index -> ``(name, start_s, end_s)``; ``host``:
     every event of the host plane; ``device``: the first chip as
-    ``xplane.DeviceTrace`` (busy time, idle gaps, module durations)."""
+    ``xplane.DeviceTrace`` (busy time, idle gaps, module durations);
+    ``scopes``, ``kernels``: the names ops are charged to, the base
+    vocabulary unless :meth:`named` gave another."""
+
+    scopes, kernels = SCOPES, KERNELS
 
     def __init__(self, ops: dict, modules: dict, host: list):
         self.ops, self.modules, self.host = ops, modules, host
@@ -270,6 +281,13 @@ class MetaTrace:
         self.device = DeviceTrace(
             chip, [(op.name, op.start_s, op.end_s)
                    for op in ops.get(chip, [])], modules.get(chip, []))
+
+    def named(self, scopes, kernels) -> "MetaTrace":
+        """The same events charged by another vocabulary.  A view: the
+        parsed events are shared, this trace keeps its own names."""
+        view = copy.copy(self)
+        view.scopes, view.kernels = tuple(scopes), tuple(kernels)
+        return view
 
     def chip(self) -> int:
         return min(self.ops) if self.ops else 0
@@ -279,13 +297,13 @@ class MetaTrace:
 
     def self_time_by(self, key: str) -> dict:
         """Self seconds of chip 0's ops by ``scope`` (innermost name of
-        the vocabulary on the op path), ``phase`` (forward / backward /
+        this trace's vocabulary on the op path), ``phase`` (forward / backward /
         recompute / other), ``category`` (the compiler's) or ``kernel``
         (a Pallas kernel's name; ops that are no kernel are left out)."""
         fn = _KEYS[key]
         out = defaultdict(float)
         for op in self.ops.get(self.chip(), []):
-            k = fn(op)
+            k = fn(op, self)
             if k or key != "kernel":
                 out[k] += op.self_s
         return dict(out)
@@ -427,14 +445,24 @@ def load(path: str) -> MetaTrace:
     return _load(path, st.st_mtime_ns, st.st_size)
 
 
+def names_of(cell):
+    """``(scopes, kernels)`` that a trace of ``cell`` is charged by:
+    the base vocabulary and what the cell's family adds to it."""
+    fam = cell.family
+    return (SCOPES + tuple(getattr(fam, "SCOPES", ())),
+            KERNELS + tuple(getattr(fam, "KERNELS", ())))
+
+
 def of_cell(cell, trace):
     """The file behind ``trace``, which the run of ``cell`` has just
-    recorded and ``xplane.reduce`` has read; None where it recorded
-    none (an older run's file may lie there still)."""
+    recorded and ``xplane.reduce`` has read, charged by the names of
+    the cell's family; None where it recorded none (an older run's
+    file may lie there still)."""
     from . import harness, xplane
     if trace is None:
         return None
     try:
-        return load(xplane.find_xplane(harness.run_dir(cell) + "/trace"))
+        mt = load(xplane.find_xplane(harness.run_dir(cell) + "/trace"))
     except FileNotFoundError:
         return None
+    return mt.named(*names_of(cell))
