@@ -254,11 +254,14 @@ class Checks:
 
 def result_line(cell: Cell, devices, traced: bool, correct: bool,
                 attempted: int, failed: int, metrics: dict,
-                trace=None) -> None:
-    """The run's last line of standard output."""
+                memory_peak: int, trace=None) -> None:
+    """The run's last line of standard output.  ``memory_peak`` is the
+    PROGRAM's: the cell reads it (:func:`memory_peak_bytes`) when the
+    window has closed, before the reference touches the chip — a
+    process's peak never falls again."""
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": memory_peak_bytes(devices)}
+              "memory_peak_bytes": int(memory_peak)}
     doc = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if traced:

@@ -1,6 +1,10 @@
 """Operations and bytes that single KERNELS need, by the rule of
 ``kernel_costs.py``: from shapes and live token counts, never from
-padded shapes or the program's own cost models."""
+padded shapes or the program's own cost models.  These are the kernels
+of the base vocabulary; a kernel of ONE family's own keeps its
+operations and bytes in the family's file
+(``benchmark/models/<family>.py``), where its reader finds them by
+``cell.family``."""
 
 from __future__ import annotations
 
@@ -11,10 +15,9 @@ def flash_attn_train_flops_per_token(conf: dict, seq: int) -> float:
     """Causal attention forward + backward for one token of a
     ``seq``-token row, recompute not counted: QK^T and PV over the S/2
     keys a query sees on average, 2 * 2 * S/2 * heads * head_dim a layer
-    forward, times 3 with the backward — the attention term of
-    ``kernel_costs.train_flops_per_token``.  Bound: compute."""
-    return 6.0 * conf["num_hidden_layers"] * seq \
-        * kernel_costs.block_costs(conf).attn_width
+    that attends forward, times 3 with the backward — the attention
+    term of ``kernel_costs.train_flops_per_token``.  Bound: compute."""
+    return 6.0 * seq * kernel_costs.over_layers(conf, "attn_width")
 
 
 def paged_attn_step_bytes(conf: dict, resident_tokens: float,

@@ -1,12 +1,17 @@
 """The plain reference's machinery, the same for every architecture:
 the matrix product (float32 at ``Precision.HIGHEST``, and the int8
-CONTROL), RMSNorm and RoPE, the trunk (embedding, the blocks in turn,
-final norm, untied head), the head's loss and gradients, adafactor, and
-the loops that drive them.  The BLOCK is the family's:
-``benchmark/models/<family>_reference.py`` (``blk`` below) gives its
-leaf names, its ``dims_of(conf)`` and ``block(x, w, dims, precision)``
--> (output, a scalar added to the loss); ``benchmark/models/__init__.py``
-states the contract.
+CONTROL), RMSNorm and RoPE, the walk over the layers in their order,
+the NLL over the logits and its gradients, the sum of a leaf's gradient
+over every use of it, adafactor, and the loops that drive them.  The
+BLOCKS and the model's two ENDS are the family's:
+``benchmark/models/<family>_reference.py`` (``blk`` below) gives the
+leaf names and ``block(x, w, dims, precision)`` -> (output, a scalar
+added to the loss) of each KIND of layer it has, the kind of every
+layer, its ``dims_of(conf)``, and — where they are not the bare lookup
+and ``rms_norm(x) . lm_head`` — its top leaves, ids -> the first
+block's input and the last block's output -> logits;
+``benchmark/models/__init__.py`` states the contract, :class:`Model`
+reads a family against a configuration.
 Nothing here imports the program, and it is handed only what the
 benchmark itself made from the seed (weights, token ids).
 
@@ -26,6 +31,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -94,25 +100,119 @@ def rope(x, theta):
 
 
 # ---------------------------------------------------------------------------
-# the trunk around the family's block
+# the model around the family's blocks
 # ---------------------------------------------------------------------------
+# The two ends of a family that states none: three leaves, the bare
+# lookup, and the untied head over the final norm.  ``dims`` of these
+# two is the configuration's ``rms_norm_eps``.
 TOP_LEAVES = ("embed", "final_norm", "lm_head")
 
 
-@functools.partial(jax.jit, static_argnames=("blk", "dims", "precision"))
-def _block_up(x, w, aux, blk, dims, precision):
-    """The family's block with the layer's leaves cast up: its output,
-    and ``aux`` plus its term of the loss (summed here, so that the
-    sum costs no program of its own on the device)."""
-    y, term = blk.block(x, jax.tree_util.tree_map(
-        lambda a: a.astype(F32), w), dims, precision)
+def _first_input(top, ids, eps):
+    return jnp.take(top["embed"], ids, axis=0)
+
+
+def _logits(top, x, eps, precision):
+    return matmul(rms_norm(x, top["final_norm"], eps), top["lm_head"],
+                  precision)
+
+
+class Model:
+    """A family's reference read against one configuration: the kind
+    of every layer and its index within its kind, each kind's leaves
+    and block, and the two ends (the contract:
+    ``benchmark/models/__init__.py``).  A family that states no kinds
+    is one kind, ``None``, under the paths ``("blocks", leaf)``."""
+
+    def __init__(self, blk, conf):
+        self.dims = blk.dims_of(conf)
+        self.hidden = conf["hidden_size"]
+        depth = conf["num_hidden_layers"]
+        if hasattr(blk, "KINDS"):
+            self.kinds = dict(blk.KINDS)
+            order = tuple(blk.layer_kinds(conf))
+        else:
+            self.kinds = {None: (blk.BLOCK_LEAVES, blk.block)}
+            order = (None,) * depth
+        if len(order) != depth or not set(order) <= set(self.kinds):
+            raise ValueError(
+                f"{blk.__name__}: layer_kinds gives {order} for "
+                f"{depth} layers of the kinds {sorted(self.kinds)}")
+        seen = {k: 0 for k in self.kinds}
+        self.layers = []                # (kind, index within the kind)
+        for k in order:
+            self.layers.append((k, seen[k]))
+            seen[k] += 1
+        ends = [hasattr(blk, a)
+                for a in ("TOP_LEAVES", "first_input", "logits")]
+        if any(ends) != all(ends):
+            raise ValueError(f"{blk.__name__} states some of TOP_LEAVES, "
+                             "first_input, logits and not all three")
+        if all(ends):
+            self.top_leaves = tuple(blk.TOP_LEAVES)
+            self.first_input, self.logits = blk.first_input, blk.logits
+            self.ends_dims = self.dims
+        else:
+            self.top_leaves = TOP_LEAVES
+            self.first_input, self.logits = _first_input, _logits
+            self.ends_dims = float(conf["rms_norm_eps"])
+
+    @staticmethod
+    def path(kind, leaf) -> tuple:
+        return ("blocks", leaf) if kind is None else ("blocks", kind, leaf)
+
+    def block_paths(self):
+        """(kind, leaf, path) of every stacked block leaf."""
+        return [(kind, nm, self.path(kind, nm))
+                for kind, (leaves, _) in self.kinds.items() for nm in leaves]
+
+    def reads(self, top: dict) -> tuple:
+        """The top leaves each end's result depends on (first input's,
+        logits'), found by tracing the two functions: nothing runs.
+        Each end is differentiated with respect to these alone, so that
+        no table-sized zero is ever made."""
+        shapes = {nm: jax.ShapeDtypeStruct(top[nm].shape, F32)
+                  for nm in self.top_leaves}
+
+        def of(fn):
+            jaxpr = jax.make_jaxpr(fn)(shapes).jaxpr
+            used = {id(v) for e in jaxpr.eqns for v in e.invars}
+            used |= {id(v) for v in jaxpr.outvars}
+            # a dict flattens in the order of its sorted keys
+            return tuple(nm for nm, v in zip(sorted(shapes), jaxpr.invars)
+                         if id(v) in used)
+        first = of(lambda t: self.first_input(
+            t, jnp.zeros((1, 1), jnp.int32), self.ends_dims))
+        head = of(lambda t: self.logits(
+            t, jnp.zeros((1, self.hidden), F32), self.ends_dims, "f32"))
+        unread = set(self.top_leaves) - set(first) - set(head)
+        if unread:
+            raise ValueError(f"top leaves {sorted(unread)} are read by "
+                             "neither end of the model")
+        return first, head
+
+
+def _up(w):
+    return jax.tree_util.tree_map(lambda a: a.astype(F32), w)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "dims", "precision"))
+def _block_up(x, w, aux, block, dims, precision):
+    """A block of the family with the layer's leaves cast up: its
+    output, and ``aux`` plus its term of the loss (summed here, so that
+    the sum costs no program of its own on the device)."""
+    y, term = block(x, _up(w), dims, precision)
     return y, aux + term
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "precision"))
-def _head_rows(x, final_norm, lm_head, eps, precision):
-    return matmul(rms_norm(x, final_norm.astype(F32), eps),
-                  lm_head.astype(F32), precision)
+@functools.partial(jax.jit, static_argnames=("first_input", "dims"))
+def _first_rows(top, ids, first_input, dims):
+    return first_input(_up(top), ids, dims)
+
+
+@functools.partial(jax.jit, static_argnames=("logits", "dims", "precision"))
+def _head_rows(x, top, logits, dims, precision):
+    return logits(_up(top), x, dims, precision)
 
 
 # ---------------------------------------------------------------------------
@@ -124,9 +224,12 @@ def _pad_len(n: int) -> int:
     return max(256, 1 << (n - 1).bit_length())
 
 
-def _layer_of(params, i, dev):
-    return {k: jax.device_put(v[i], dev) if dev is not None else v[i]
-            for k, v in params["blocks"].items()}
+def _layer_of(params, model, i, dev):
+    """The leaves of layer ``i``: slice ``j`` of its kind's stacks."""
+    kind, j = model.layers[i]
+    out = {nm: functools.reduce(operator.getitem, model.path(kind, nm),
+                                params)[j] for nm in model.kinds[kind][0]}
+    return out if dev is None else jax.device_put(out, dev)
 
 
 def forward_rows(blk, params, conf, tokens, rows, precision="f32",
@@ -136,21 +239,21 @@ def forward_rows(blk, params, conf, tokens, rows, precision="f32",
     layers, any float type, any layout); ``dev`` gathers a sharded
     layer onto one device first."""
     n = len(tokens)
-    toks = np.zeros((_pad_len(n),), np.int64)
-    toks[:n] = tokens
-    dims = blk.dims_of(conf)
-    emb = params["embed"]
-    x = jnp.take(emb, jnp.asarray(toks), axis=0).astype(F32)[None]
-    if dev is not None:
-        x = jax.device_put(x, dev)
-    for i in range(conf["num_hidden_layers"]):
-        x, _ = _block_up(x, _layer_of(params, i, dev), np.float32(0), blk,
-                         dims, precision)
+    toks = np.zeros((1, _pad_len(n)), np.int64)
+    toks[0, :n] = tokens
+    model = Model(blk, conf)
     put = (lambda a: jax.device_put(a, dev)) if dev is not None \
         else (lambda a: a)
-    out = _head_rows(x[0, jnp.asarray(rows)], put(params["final_norm"]),
-                     put(params["lm_head"]),
-                     float(conf["rms_norm_eps"]), precision)
+    first, head = model.reads(params)
+    x = _first_rows({nm: put(params[nm]) for nm in first},
+                    put(jnp.asarray(toks)), model.first_input,
+                    model.ends_dims)
+    for i, (kind, _) in enumerate(model.layers):
+        x, _ = _block_up(x, _layer_of(params, model, i, dev), np.float32(0),
+                         model.kinds[kind][1], model.dims, precision)
+    out = _head_rows(x[0, jnp.asarray(rows)],
+                     {nm: put(params[nm]) for nm in head}, model.logits,
+                     model.ends_dims, precision)
     return np.asarray(out)
 
 
@@ -184,24 +287,38 @@ def _factored(shape) -> bool:
     return len(shape) >= 2 and shape[-1] >= 128 and shape[-2] >= 128
 
 
-@functools.partial(jax.jit, static_argnames=("blk", "dims", "precision"))
-def _block_bwd(x, w, g, g_aux, blk, dims, precision):
+@functools.partial(jax.jit, static_argnames=("block", "dims", "precision"))
+def _block_bwd(x, w, g, g_aux, block, dims, precision):
     """Gradients of a block's input and leaves from ``g``, the gradient
     of its output, and ``g_aux``, the weight of its own term in the
     step's loss."""
-    _, vjp = jax.vjp(lambda x, w: blk.block(x, w, dims, precision), x, w)
+    _, vjp = jax.vjp(lambda x, w: block(x, w, dims, precision), x, w)
     return vjp((g, g_aux))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "precision", "total"))
-def _head_loss(x, final_norm, lm_head, targets, eps, precision, total):
-    """Sum of the rows' NLL over ``total`` tokens, and its gradients."""
-    def f(x, fn, lm):
-        logits = matmul(rms_norm(x, fn, eps), lm, precision)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
+@functools.partial(jax.jit, static_argnames=("logits", "dims", "precision",
+                                             "total"))
+def _head_loss(x, top, targets, logits, dims, precision, total):
+    """Sum of the rows' NLL over ``total`` tokens, and its gradients
+    with respect to the last block's output and the top leaves the
+    family's ``logits`` reads (``top`` holds those alone)."""
+    def f(x, top):
+        lg = logits(top, x, dims, precision)
+        lse = jax.scipy.special.logsumexp(lg, axis=-1)
+        tgt = jnp.take_along_axis(lg, targets[..., None], -1)[..., 0]
         return jnp.sum(lse - tgt) / total
-    return jax.value_and_grad(f, argnums=(0, 1, 2))(x, final_norm, lm_head)
+    return jax.value_and_grad(f, argnums=(0, 1))(x, top)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("first_input", "dims"))
+def _first_bwd(acc, top, ids, gx, first_input, dims):
+    """``acc`` plus the gradient of the top leaves the family's
+    ``first_input`` reads, from ``gx``, the gradient of the first
+    block's input.  A lookup's share is a scatter-add, which the
+    compiler makes straight into ``acc``."""
+    _, vjp = jax.vjp(lambda t: first_input(t, ids, dims), top)
+    return jax.tree_util.tree_map(jnp.add, acc, vjp(gx)[0])
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -244,25 +361,24 @@ class TrainReference:
     """Float32 copy of the job's state, stepped leaf by leaf.
 
     ``leaf(path)`` returns the benchmark's stacked float32 leaf for a
-    path such as ``("blocks", <a leaf of the family's block>)`` or
-    ``("embed",)``; the stacked block leaves are split into per-layer
+    path such as ``("blocks", <a leaf of the family's block>)``,
+    ``("blocks", <kind>, <leaf>)`` where the family states kinds, or
+    ``("embed",)``; a stacked block leaf is split into its layers'
     arrays so that a layer's gradient can be accumulated and applied
     alone."""
 
     def __init__(self, blk, conf, leaf, job, precision="f32"):
-        self.blk, self.conf, self.job = blk, conf, job
-        self.precision = precision
-        self.dims = blk.dims_of(conf)
-        self.eps = float(conf["rms_norm_eps"])
-        self.L = conf["num_hidden_layers"]
-        self.leaf = leaf
-        self.layers = [dict() for _ in range(self.L)]
-        for nm in blk.BLOCK_LEAVES:
-            stacked = leaf(("blocks", nm))
-            for i in range(self.L):
-                self.layers[i][nm] = stacked[i]
+        self.model = model = Model(blk, conf)
+        self.job, self.precision, self.leaf = job, precision, leaf
+        self.layers = [dict() for _ in model.layers]
+        for kind, nm, path in model.block_paths():
+            stacked = leaf(path)
+            for w, (k, j) in zip(self.layers, model.layers):
+                if k == kind:
+                    w[nm] = stacked[j]
             del stacked
-        self.top = {nm: leaf((nm,)) for nm in TOP_LEAVES}
+        self.top = {nm: leaf((nm,)) for nm in model.top_leaves}
+        self.reads = model.reads(self.top)
         self.opt_layers = [jax.tree_util.tree_map(_opt_init, w)
                            for w in self.layers]
         self.opt_top = jax.tree_util.tree_map(_opt_init, self.top)
@@ -275,10 +391,11 @@ class TrainReference:
         no local outlives it: the backward pass pops the activations
         one by one, and one more [rows, s, hidden] kept alive shows in
         the device's peak."""
+        model = self.model
         acts, aux = [x], np.float32(0)
-        for w in self.layers:
-            y, aux = _block_up(acts[-1], w, aux, self.blk, self.dims,
-                               self.precision)
+        for w, (kind, _) in zip(self.layers, model.layers):
+            y, aux = _block_up(acts[-1], w, aux, model.kinds[kind][1],
+                               model.dims, self.precision)
             acts.append(y)
         return acts, aux
 
@@ -289,54 +406,61 @@ class TrainReference:
         Rows go forward in blocks; the backward pass then walks the
         layers from the top, sums a layer's gradient over the blocks,
         applies it and frees it before the next layer, so that only one
-        layer's gradient is alive at a time."""
+        layer's gradient is alive at a time.  A top leaf's gradient is
+        the sum over every use of it: a tied table gets the head's
+        product and the lookup's scatter-add."""
         tokens = np.asarray(tokens)
         B, S1 = tokens.shape
-        total, eps = B * (S1 - 1), self.eps
+        total, model = B * (S1 - 1), self.model
         first = self.t == 0
         self.t += 1
         lr, wd = float(self.job["lr"]), float(self.job["weight_decay"])
         t = jnp.asarray(self.t, F32)
         sq = lambda a: float(jnp.sum(jnp.square(a)))
         norms = {}
+        top_in = {nm: self.top[nm] for nm in self.reads[0]}
+        top_out = {nm: self.top[nm] for nm in self.reads[1]}
 
         xs, gxs, inps, shares, g_top, loss = [], [], [], [], None, 0.0
         for r0 in range(0, B, ROW_BLOCK):
             tok = jnp.asarray(tokens[r0:r0 + ROW_BLOCK])
             inp, tgt = tok[:, :-1], tok[:, 1:]
             share = np.float32(tok.shape[0] / B)
-            acts, aux = self._blocks_up(
-                jnp.take(self.top["embed"], inp, axis=0))
-            part, (gx, gfn, glm) = _head_loss(
-                acts.pop(), self.top["final_norm"], self.top["lm_head"],
-                tgt, eps, self.precision, total)
+            acts, aux = self._blocks_up(_first_rows(
+                top_in, inp, model.first_input, model.ends_dims))
+            part, (gx, gt) = _head_loss(
+                acts.pop(), top_out, tgt, model.logits, model.ends_dims,
+                self.precision, total)
             loss += float(part) + float(share) * float(aux)
             shares.append(share)
-            gt = {"final_norm": gfn, "lm_head": glm}
             g_top = gt if g_top is None else _acc(g_top, gt)
             xs.append(acts)
             gxs.append(gx)
             inps.append(inp)
 
-        for i in reversed(range(self.L)):
+        for i in reversed(range(len(self.layers))):
+            kind, _ = model.layers[i]
             gw = None
             for b in range(len(xs)):
                 gxs[b], g = _block_bwd(xs[b].pop(), self.layers[i], gxs[b],
-                                       shares[b], self.blk, self.dims,
-                                       self.precision)
+                                       shares[b], model.kinds[kind][1],
+                                       model.dims, self.precision)
                 gw = g if gw is None else _acc(gw, g)
             for nm in list(self.layers[i]):
                 g = gw.pop(nm)
                 if first:
-                    norms[("blocks", nm)] = \
-                        norms.get(("blocks", nm), 0.0) + sq(g)
+                    path = model.path(kind, nm)
+                    norms[path] = norms.get(path, 0.0) + sq(g)
                 self.layers[i][nm], self.opt_layers[i][nm] = \
                     _adafactor_leaf(self.layers[i][nm], g,
                                     self.opt_layers[i][nm], t, lr, wd)
-        gemb = jnp.zeros_like(self.top["embed"])
+        g_in = {nm: g_top.pop(nm) if nm in g_top
+                else jnp.zeros_like(self.top[nm]) for nm in top_in}
         for inp, gx in zip(inps, gxs):
-            gemb = gemb.at[inp].add(gx)
-        g_top["embed"] = gemb
+            g_in = _first_bwd(g_in, top_in, inp, gx, model.first_input,
+                              model.ends_dims)
+        g_top.update(g_in)
+        del g_in        # or the table's gradient outlives its update
         for nm in list(self.top):
             g = g_top.pop(nm)
             if first:
@@ -352,10 +476,12 @@ class TrainReference:
         seed, one leaf at a time."""
         out = {}
         sq = lambda a: float(jnp.sum(jnp.square(a)))
-        for nm in self.layers[0]:
-            p0 = self.leaf(("blocks", nm))
-            out[("blocks", nm)] = math.sqrt(sum(
-                sq(self.layers[i][nm] - p0[i]) for i in range(self.L)))
+        for kind, nm, path in self.model.block_paths():
+            p0 = self.leaf(path)
+            out[path] = math.sqrt(sum(
+                sq(w[nm] - p0[j])
+                for w, (k, j) in zip(self.layers, self.model.layers)
+                if k == kind))
             del p0
         for nm in self.top:
             out[(nm,)] = math.sqrt(sq(self.top[nm] - self.leaf((nm,))))

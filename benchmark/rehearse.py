@@ -38,7 +38,7 @@ def patch_for_cpu(harness) -> dict:
     harness.memory_peak_bytes = lambda devices: 0
 
     def counts_only(cell, devices, traced, correct, attempted, failed,
-                    metrics, trace=None):
+                    metrics, memory_peak, trace=None):
         seen.update(correct=correct, attempted=attempted, failed=failed,
                     metrics_read=len(metrics))
         print(f"[rehearsal] platform {devices[0].platform} x "

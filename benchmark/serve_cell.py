@@ -6,9 +6,11 @@ process), on the mix a traffic file of ``kind: open_loop`` or
 Set-up: weights from the seed, pools, server, then one short request
 for every page count the mix's prompts can have (each compiles its
 admission shapes), then the load generator's warm-up phase, which runs
-straight into the measured phase.  After the window has closed and the
-run has drained, a seeded sample of the finished requests is compared
-with the plain reference.
+straight into the measured phase.  After the window has closed, the
+run has drained, ``memory_peak_bytes`` has been read and the pools and
+the server are freed, a seeded sample of the finished requests is
+compared with the plain reference, which is handed the weights the
+benchmark made.
 """
 
 from __future__ import annotations
@@ -270,6 +272,13 @@ def run(args, cell, token_override=None, control=False) -> int:
         e2e["gap_p95_ms"] = {"value": percentile(m["gap_ms"], 95),
                              "unit": "ms"}
 
+    # the program's peak, then its pools and server freed, then the
+    # reference: a process's peak never falls again
+    memory_peak = harness.memory_peak_bytes(devices)
+    log(f"peak HBM {memory_peak / 2**30:.2f} GiB (the program's: read "
+        f"before the reference runs)")
+    del srv, cache
+
     # correct: the served tokens of a seeded sample against the plain
     # reference, once the window has closed
     checks = harness.Checks()
@@ -294,7 +303,6 @@ def run(args, cell, token_override=None, control=False) -> int:
     if control:
         log(f"control int8: served_logit_gap_max {max(low):.6g}")
     checks.add("requests_failed", m["failed"], 0)
-    log(f"peak HBM {harness.memory_peak_bytes(devices) / 2**30:.2f} GiB")
 
     if args.trace:
         log(f"end to end (traced run, not for comparison): {e2e}")
@@ -310,5 +318,6 @@ def run(args, cell, token_override=None, control=False) -> int:
     else:
         metrics = e2e
     harness.result_line(cell, devices, bool(args.trace), checks.ok,
-                        m["attempted"], m["failed"], metrics, trace)
+                        m["attempted"], m["failed"], metrics, memory_peak,
+                        trace)
     return 0

@@ -15,6 +15,8 @@ gives the config object and the leaves' shapes.  Run by hand:
 
 from __future__ import annotations
 
+import functools
+import operator
 import os
 import re
 import sys
@@ -40,15 +42,11 @@ def sds(mesh, shape, dtype, spec=P()):
 
 def param_sds(fam, cfg, mesh):
     from paddle_tpu.models.llama_pretrain import param_specs
-    specs = param_specs(cfg, 1)
-    out = {"blocks": {}}
-    for path, shape in fam.leaf_shapes(cfg).items():
-        if path[0] == "blocks":
-            out["blocks"][path[1]] = sds(mesh, shape, cfg.param_dtype,
-                                         specs["blocks"][path[1]])
-        else:
-            out[path[0]] = sds(mesh, shape, cfg.param_dtype, specs[path[0]])
-    return out
+    from benchmark import models
+    specs, shapes = param_specs(cfg, 1), fam.leaf_shapes(cfg)
+    return models.tree_of(shapes, lambda path: sds(
+        mesh, shapes[path], cfg.param_dtype,
+        functools.reduce(operator.getitem, path, specs)))
 
 
 # an instruction the compiler's own rematerialization pass cloned, as
@@ -100,25 +98,28 @@ def main(argv) -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     if argv[1] == "reference":
-        # what the plain reference's largest program needs: the
-        # backward of the family's block on the rows the loop gives it,
-        # beside the float32 weights and adafactor state it holds
+        # what the plain reference's largest program OF EACH KIND
+        # needs: the backward of that kind's block on the rows the loop
+        # gives it, beside the float32 weights and adafactor state the
+        # reference holds
         from benchmark import kernel_costs, reference
         cell = harness.find_cell(argv[2])
-        job, fam, blk = cell.traffic, cell.family, cell.block_reference
+        job, fam = cell.traffic, cell.family
+        model = reference.Model(cell.block_reference, cell.conf)
         mesh = build_mesh(devices=topo.devices[:1])
         cfg = fam.build_cfg(cell.conf, train=True, job=job)
         shapes = fam.leaf_shapes(cfg)
         f32 = lambda shape: sds(mesh, shape, jnp.float32)
-        w = {nm: f32(shapes[("blocks", nm)][1:]) for nm in blk.BLOCK_LEAVES}
         x = f32((reference.ROW_BLOCK, job["seq"], cell.conf["hidden_size"]))
-        t0 = time.time()
-        with mesh:
-            c = reference._block_bwd.lower(
-                x, w, x, f32(()), blk, blk.dims_of(cell.conf),
-                "f32").compile()
-        report(f"{cell.name} reference block backward, "
-               f"{reference.ROW_BLOCK} rows of {job['seq']}", c, t0)
+        for kind, (leaves, block) in model.kinds.items():
+            w = {nm: f32(shapes[model.path(kind, nm)][1:]) for nm in leaves}
+            t0 = time.time()
+            with mesh:
+                c = reference._block_bwd.lower(
+                    x, w, x, f32(()), block, model.dims, "f32").compile()
+            report(f"{cell.name} reference block backward"
+                   f"{'' if kind is None else ' of kind ' + kind}, "
+                   f"{reference.ROW_BLOCK} rows of {job['seq']}", c, t0)
         n = kernel_costs.total_params(cell.conf)
         print(f"{cell.name} reference holds {n} float32 parameters "
               f"({4 * n / GIB:.2f} GiB) and their adafactor state",

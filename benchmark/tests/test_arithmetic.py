@@ -6,7 +6,8 @@ import os
 
 import pytest
 
-from benchmark import harness, kernel_costs, peaks, stats, traffic
+from benchmark import (harness, kernel_costs, kernel_costs_kernels, peaks,
+                       stats, traffic)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CONFIGS = os.path.join(os.path.dirname(HERE), "configs")
@@ -101,6 +102,28 @@ def test_counts_against_hand_sums():
         + 2.0 * 189530112
     assert kernel_costs.decode_step_bytes(i, 1000.0) == \
         (24 * 62914560 + 189530112) * 2 + 1000 * 96 * 1024
+
+
+def test_the_dense_cells_counts_to_the_unit():
+    """``internlm2-1.8b.pretrain-2k`` as every PR has read it: 8.39
+    GFLOP a token, 1.51 B parameters; summed a layer at a time now, a
+    family without kinds is eighteen layers of one kind."""
+    t = conf("internlm2-1.8b-train")
+    layers = kernel_costs.layer_costs(t)
+    assert len(layers) == 18 and set(layers) == {kernel_costs.BlockCosts(
+        62914560, 62914560, 4096, 2048, 2048, 0)}
+    assert kernel_costs.over_layers(t, "matmul_params") == 1132462080
+    assert kernel_costs.train_flops_per_token(t, 2048) == 8384937984.0
+    assert kernel_costs_kernels.flash_attn_train_flops_per_token(
+        t, 2048) == 452984832.0
+    assert kernel_costs.total_params(t) == 1511598080
+    assert kernel_costs.weight_bytes_per_chip(t) == \
+        (1132462080 + 189530112) * 2
+    assert kernel_costs.kv_bytes_per_token(t) == 18 * 2048 * 2
+    # a tied table is held once; the head's product is still made
+    tied = dict(t, tie_word_embeddings=True)
+    assert kernel_costs.total_params(tied) == 1511598080 - 189530112
+    assert kernel_costs.train_flops_per_token(tied, 2048) == 8384937984.0
 
 
 def test_unknown_device_kind_raises():

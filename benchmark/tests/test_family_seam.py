@@ -1,7 +1,7 @@
-"""The seam a block family plugs into: its reference, its costs and its
-names come from files found by the configuration's ``"family"``, and a
-second architecture is added as files, with no edit to a file that is
-there.  On the CPU, at the toy size: counts and verdicts, never a time.
+"""The seam a block family plugs into: its reference (the blocks of each
+kind of layer, the model's two ends), its costs and its names come from
+files found by the configuration's ``"family"``, and a second
+architecture is added as files, with no edit to a file that is there.  On the CPU, at the toy size: counts and verdicts, never a time.
 """
 
 import filecmp
@@ -15,6 +15,7 @@ import types
 import numpy as np
 import pytest
 
+import test_kinds
 from benchmark import (harness, kernel_costs, kernel_costs_kernels,
                        reference, rehearse, train_cell, xplane_meta)
 
@@ -41,7 +42,16 @@ def block_costs(conf):
 '''
 ROPE_LINE = 'q = rope(mm(y, w["wq"]).reshape(b, s, n, d), theta)'
 NO_ROPE_LINE = 'q = mm(y, w["wq"]).reshape(b, s, n, d)'
-DRIVER = '''import json, os, sys, types
+# A family with KINDS of layer, a tied table and multipliers
+# (``toy/hybrid_block*.py``), and three more whose reference is altered
+# by one line each (``test_kinds.ALTERED``).  The repo's program runs
+# one kind of layer, so a family with kinds cannot drive it: these drive
+# the stand-in of ``toy/hybrid_program.py`` (whole-model autodiff, the
+# program's state layout), which the driver below puts in the place of
+# ``make_train_step`` / ``init_adafactor_state`` in its own process;
+# ``train_cell.run`` and everything under it run unchanged.
+HYBRIDS = ("kinded",) + tuple(sorted(test_kinds.ALTERED))
+DRIVER = '''import importlib.util, json, os, sys, types
 
 
 def main():
@@ -49,17 +59,19 @@ def main():
     # benchmark: the copy; the program: the repo's
     sys.path[:0] = [copy_root, repo]
     os.environ["JAX_PLATFORMS"] = "cpu"
-    from benchmark import (harness, kernel_costs, rehearse, train_cell,
-                           xplane_meta)
+    from benchmark import (harness, kernel_costs, kernel_costs_kernels,
+                           rehearse, train_cell, xplane_meta)
+    from paddle_tpu.models import llama_pretrain
     seen = rehearse.patch_for_cpu(harness)
     toy = os.path.join(os.path.dirname(harness.__file__), "tests", "toy")
     job = harness.load_json(os.path.join(toy, "train_job.json"))
     out = {"harness": harness.__file__}
-    for name in ("twin", "twin_norope"):
+
+    def rehearse_family(name, trace):
         conf = harness.load_json(os.path.join(toy, "config_%s.json" % name))
         cell = harness.Cell.detached(name + ".train_job", 1, conf, job)
         args = types.SimpleNamespace(workload=cell.name, seed=2**31 + 99,
-                                     seconds=1.0, trace=1)
+                                     seconds=1.0, trace=trace)
         rc = train_cell.run(args, cell)
         scopes, kernels = xplane_meta.names_of(cell)
         out[name] = {
@@ -67,17 +79,30 @@ def main():
             "readers": seen["metrics_read"],
             "reference": cell.block_reference.__name__,
             "family": cell.family.__name__,
-            "costs": list(kernel_costs.block_costs(conf)),
+            "layer_costs": [list(c) for c in kernel_costs.layer_costs(conf)],
             "train_flops": kernel_costs.train_flops_per_token(
                 conf, job["seq"]),
+            "attn_flops": kernel_costs_kernels.
+            flash_attn_train_flops_per_token(conf, job["seq"]),
+            "total_params": kernel_costs.total_params(conf),
             "scopes_added": scopes[len(xplane_meta.SCOPES):],
             "kernels_added": kernels[len(xplane_meta.KERNELS):]}
+    for name in ("twin", "twin_norope"):
+        rehearse_family(name, 1)
+    spec = importlib.util.spec_from_file_location(
+        "hybrid_program", os.path.join(toy, "hybrid_program.py"))
+    stand_in = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(stand_in)
+    llama_pretrain.make_train_step = stand_in.make_train_step
+    llama_pretrain.init_adafactor_state = stand_in.init_adafactor_state
+    for name in @HYBRIDS@:
+        rehearse_family(name, 0)
     print("SEAM " + json.dumps(out), flush=True)
 
 
 if __name__ == "__main__":      # the DataLoader's workers import this file
     main()
-'''
+'''.replace("@HYBRIDS@", repr(HYBRIDS))
 
 
 def tree_files(root):
@@ -89,12 +114,14 @@ def tree_files(root):
     return out
 
 
-def test_a_second_family_is_files_only(tmp_path):
-    """In a copy of ``benchmark/``: two families added as files (the
-    second's reference is the first's with rope left out of one line),
-    the train rehearsal run on each.  The sound one is found, rehearsed
-    and judged correct by ITS reference, costs and names; the altered
-    one is judged not correct; no file that was there has changed."""
+@pytest.fixture(scope="module")
+def seam(tmp_path_factory):
+    """In a copy of ``benchmark/``: six families added as files — two
+    dense (the second's reference is the first's with rope left out of
+    one line), one with kinds and its three altered ones — and the train
+    rehearsal run on each, in one process.  Gives what that process
+    read, and checks first that no file that was there has changed."""
+    tmp_path = tmp_path_factory.mktemp("seam")
     copy = tmp_path / "benchmark"
     shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns(*LEFT_BEHIND))
     before = tree_files(copy)
@@ -102,14 +129,23 @@ def test_a_second_family_is_files_only(tmp_path):
     dense_ref = (models / "llama_block_reference.py").read_text()
     assert dense_ref.count(ROPE_LINE) == 1
     conf = json.loads((toy / "config.json").read_text())
+    hybrid_conf = json.loads((toy / "config_hybrid.json").read_text())
+    families = [("twin", TWIN, dense_ref, conf),
+                ("twin_norope", TWIN,
+                 dense_ref.replace(ROPE_LINE, NO_ROPE_LINE), conf)]
+    hybrid_side = (toy / "hybrid_block.py").read_text()
+    hybrid_ref = (toy / "hybrid_block_reference.py").read_text()
+    for name in HYBRIDS:
+        old, new = test_kinds.ALTERED.get(name, ("", ""))
+        assert name == "kinded" or hybrid_ref.count(old) == 1
+        families.append((name, hybrid_side, hybrid_ref.replace(old, new),
+                         hybrid_conf))
     added = set()
-    for name, ref in (("twin", dense_ref),
-                      ("twin_norope",
-                       dense_ref.replace(ROPE_LINE, NO_ROPE_LINE))):
-        (models / f"{name}_block.py").write_text(TWIN)
+    for name, side, ref, base in families:
+        (models / f"{name}_block.py").write_text(side)
         (models / f"{name}_block_reference.py").write_text(ref)
         (toy / f"config_{name}.json").write_text(json.dumps(
-            dict(conf, name=name, family=name + "_block")))
+            dict(base, name=name, family=name + "_block")))
         added |= {f"models/{name}_block.py",
                   f"models/{name}_block_reference.py",
                   f"tests/toy/config_{name}.json"}
@@ -118,13 +154,28 @@ def test_a_second_family_is_files_only(tmp_path):
     env = {k: v for k, v in os.environ.items()
            if k not in ("PYTHONPATH", "XLA_FLAGS")}
     p = subprocess.run([sys.executable, str(driver), str(tmp_path), REPO],
-                       capture_output=True, text=True, timeout=600,
+                       capture_output=True, text=True, timeout=900,
                        env=env, cwd=str(tmp_path))
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     line = [l for l in p.stdout.splitlines() if l.startswith("SEAM ")][-1]
     got = json.loads(line[5:])
-
     assert os.path.dirname(got["harness"]) == str(copy)
+
+    # files only: what was there is byte for byte the tree's
+    after = tree_files(copy)
+    assert after - before == added
+    for rel in sorted(before):
+        assert filecmp.cmp(os.path.join(BENCH, rel), copy / rel,
+                           shallow=False), rel
+    assert before == tree_files(BENCH)
+    return got, conf, hybrid_conf
+
+
+def test_a_second_family_is_files_only(seam):
+    """The sound dense family is found, rehearsed and judged correct by
+    ITS reference, costs and names; the altered one is judged not
+    correct."""
+    got, conf, _ = seam
     twin, bad = got["twin"], got["twin_norope"]
     assert twin["rc"] == 0 and twin["correct"] is True
     assert bad["rc"] == 0 and bad["correct"] is False
@@ -134,9 +185,9 @@ def test_a_second_family_is_files_only(tmp_path):
     assert bad["reference"] == "benchmark.models.twin_norope_block_reference"
     # the family's costs, not the dense block's
     dense = kernel_costs.block_costs(conf)
-    assert twin["costs"] == list(dense._replace(
-        matmul_params=dense.resident_params // 4))
     L, seq = conf["num_hidden_layers"], 128
+    assert twin["layer_costs"] == [list(dense._replace(
+        matmul_params=dense.resident_params // 4))] * L
     assert twin["train_flops"] == \
         6.0 * (L * (dense.resident_params // 4)
                + kernel_costs.head_params(conf)) \
@@ -146,13 +197,49 @@ def test_a_second_family_is_files_only(tmp_path):
     assert twin["scopes_added"] == ["twin_router", "twin_experts"]
     assert twin["kernels_added"] == ["twin_grouped_matmul"]
 
-    # files only: what was there is byte for byte the tree's
-    after = tree_files(copy)
-    assert after - before == added
-    for rel in sorted(before):
-        assert filecmp.cmp(os.path.join(BENCH, rel), copy / rel,
-                           shallow=False), rel
-    assert before == tree_files(BENCH)
+
+def test_a_family_with_kinds_and_its_own_ends_is_files_only(seam):
+    """Two kinds in a pattern, a tied table, a multiplier and a divisor,
+    through ``train_cell.run``: found by its files and judged correct."""
+    got, _, _ = seam
+    sound = got["kinded"]
+    assert sound["rc"] == 0 and sound["correct"] is True
+    assert sound["family"] == "benchmark.models.kinded_block"
+    assert sound["reference"] == "benchmark.models.kinded_block_reference"
+
+
+@pytest.mark.parametrize("what", sorted(test_kinds.ALTERED))
+def test_an_altered_family_with_kinds_is_not_correct(seam, what):
+    got, _, _ = seam
+    assert got[what]["rc"] == 0 and got[what]["correct"] is False
+    assert got[what]["reference"] == \
+        f"benchmark.models.{what}_block_reference"
+
+
+def test_costs_are_summed_by_kind_in_the_familys_order(seam):
+    """Hand sums of the toy hybrid (hidden 128, FFN 256, 2 heads / 1 KV
+    head of 64, vocabulary 384, layers mix mix attention mix): the scan
+    field enters the training count three times, the attention term
+    counts the one layer that attends, the tied table counts once."""
+    got, _, conf = seam
+    sound = got["kinded"]
+    h, f, q, kv, V, seq = 128, 256, 128, 64, 384, 128
+    mix = 2 * h * h + 3 * h * f
+    att = 2 * h * q + 2 * h * kv + 3 * h * f
+    assert [c[0] for c in sound["layer_costs"]] == [mix, mix, att, mix]
+    assert [c[3] for c in sound["layer_costs"]] == [0, 0, q, 0]
+    assert [c[5] for c in sound["layer_costs"]] == [2 * h, 2 * h, 0, 2 * h]
+    assert sound["train_flops"] == 6.0 * (3 * mix + att + h * V) \
+        + 6.0 * seq * q + 3.0 * (3 * 2 * h)
+    assert sound["attn_flops"] == 6.0 * seq * q
+    # every parameter once: what the family's own tree holds
+    assert sound["total_params"] == \
+        3 * (mix + 3 * h) + (att + 2 * h) + h * V + h
+    import math
+    fam = test_kinds.load("hybrid_block")
+    assert sound["total_params"] == sum(
+        math.prod(shape) for shape in
+        fam.leaf_shapes(fam.build_cfg(conf, train=True)).values())
 
 
 # -- the dense block's reference is the parent's, from another file -------
