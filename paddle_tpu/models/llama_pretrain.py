@@ -291,10 +291,11 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
     from ..ops.dispatch import get_op_impl
     from ..flags import flags
 
-    def full_heads(k, v):
-        # paths that cannot group natively repeat K/V up to q heads
-        if k.shape[2] != q.shape[2]:
-            rep = q.shape[2] // k.shape[2]
+    def full_heads(k, v, heads=q.shape[2]):
+        # paths that cannot group natively repeat K/V up to q heads;
+        # the dense kernels, under a mesh, up to the count mp divides
+        if k.shape[2] != heads:
+            rep = heads // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         return k, v
@@ -327,7 +328,13 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
     impl = get_op_impl("flash_attention", None)
     if impl is not None and cfg.use_pallas_attention and \
             flags.FLAGS_pallas_flash_attention:
-        k, v = full_heads(k, v)
+        # GQA-NATIVE as well: the kernels index K/V by head // group.
+        # Only where mp splits the query heads and not the KV heads
+        # (MQA over mp) are K/V repeated, up to the least count that mp
+        # divides, so that the heads still split
+        mp = 1 if mesh is None else mesh.shape.get("mp", 1)
+        if q.shape[2] % mp == 0:
+            k, v = full_heads(k, v, math.lcm(k.shape[2], mp))
         return _per_shard(lambda q, k, v: impl(q, k, v, causal=True),
                           mesh, q.shape[2], k.shape[2], 3)(q, k, v)
     k, v = full_heads(k, v)
@@ -342,8 +349,9 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
 
 def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                     mesh: Optional[Mesh] = None):
-    """ln1 + QKV projections + rope + GQA repeat -> q, k, v.
-    Single source of block math shared by every remat policy."""
+    """ln1 + QKV projections + rope -> q [b, s, n, d], k, v [b, s, nkv,
+    d]: K/V at their own head count.  Single source of block math
+    shared by every remat policy."""
     b, s, h = x.shape
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -370,10 +378,14 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
             v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
     with jax.named_scope("rope"):
         q, k = _rope(q, k, cfg.rope_theta, mesh)
-    # GQA stays UN-repeated here: _attention's segmented flash kernel
-    # indexes kv heads by group natively (the whole point of GQA — nkv
-    # heads of K/V HBM traffic, not n); paths that need full heads
-    # repeat at their own entry
+    # GQA stays UN-repeated here: _attention's flash kernels, dense and
+    # segmented, index kv heads by group natively (the whole point of
+    # GQA — nkv heads of K/V HBM traffic, not n); the paths that need
+    # full heads (context parallel, the XLA composite) repeat at their
+    # own entry.  The [b, s, n, d] views are reshapes of the projections'
+    # [b, s, n*d]: at head dim % 128 == 0 rope and dense flash address
+    # them as such, and no array with the heads on its tiles' sublanes
+    # is ever made
     return q, k, v
 
 

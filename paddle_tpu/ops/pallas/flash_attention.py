@@ -6,195 +6,219 @@ python/paddle/nn/functional/flash_attention.py:147).
 
 FlashAttention-2 style: online-softmax forward saving per-row logsumexp;
 backward recomputes per-block probabilities and accumulates dQ/dK/dV —
-O(S) memory, blocked to MXU-friendly (128, head_dim) tiles.
+O(S) memory, blocked to MXU-friendly (block, head_dim) tiles.
 
-Public layout matches the framework's sdpa: [batch, seq, heads, dim].
-Kernels run per (batch*heads) with K/V resident in VMEM (seq*dim*2B ≤
-~1MB at seq 4k, d 128 — well within the 16MB budget).
+Public layout matches the framework's sdpa: q ``[batch, seq, heads,
+dim]``, k/v ``[batch, seq, kv_heads, dim]`` with ``kv_heads`` dividing
+``heads``.  GQA is NATIVE: a query head's k/v index maps take ``head //
+group``, so no repeated K/V exists anywhere and a K/V block is fetched
+once a group; ``flash_bwd_dkv`` sums a group's query heads into the
+shared dK/dV block in fp32 (innermost grid axis) and casts once.
+
+The kernels work on ONE head's ``[rows, dim]`` tiles with that head's
+K/V (forward, dq) or Q/dO (dkv) resident in VMEM (seq*dim*2B <= ~1MB at
+seq 4k, d 128 — well within the 16MB budget).  How a tile is ADDRESSED
+depends on ``dim`` alone (``_to_kernel``): with ``dim % 128 == 0`` the
+operands stay where the projections wrote them — ``[b, s, h, d]`` ->
+``[b, s, h*d]`` is a bitcast, and a ``(rows, d)`` block at (batch, row
+block, head) on the last axis is a legal Mosaic block — so nothing is
+transposed on the way in or out; any other ``dim`` (a lane slice of 32
+or 64 is not a block Mosaic takes) goes through one ``[b, h, s, d]``
+transpose an operand.  Same kernel bodies either way.
+
+The per-row statistics (logsumexp, and delta = rowsum(dO*O), which
+``flash_bwd_dq`` forms from the tiles it already holds) live as ``[b, h,
+s/block, 1, block]``: a block's positions on LANES, the blocks on an
+untiled axis, so a kernel takes one block by its index whatever the
+block's size.  A ``[.., s, 1]`` array is 128x padded in the tiled layout
+and was re-laid out by XLA between the kernels.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
 from ._common import idx32
-from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
 
 
+def _scores(a, b):
+    """``a @ b.T`` with fp32 accumulation.  MXU dots run on the native
+    (bf16) inputs — v5e's fp32 matmul rate is ~1/4 of bf16, so upcasting
+    the operands would quarter kernel throughput for no accuracy gain."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _matmul(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _visible(q0, k0, shape, q_axis):
+    """Causal visibility of the diagonal block: ``shape`` holds query
+    positions from ``q0`` along ``q_axis``, key positions from ``k0``
+    along the other axis."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return q_pos >= k_pos
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
                 sm_scale: float, block_k: int):
-    # q_ref: [Bq, d]; k_ref/v_ref: [S, d]; o_ref: [Bq, d]; lse_ref: [Bq, 1]
-    # MXU dots run on the native (bf16) inputs with fp32 accumulation —
-    # v5e's fp32 matmul rate is ~1/4 of bf16, so upcasting the operands
-    # would quarter kernel throughput for no accuracy gain.
-    qi = pl.program_id(1)
+    # q_ref/o_ref: [Bq, d]; k_ref/v_ref: [S, d]; lse_ref: [1, Bq]
+    qi = pl.program_id(2).astype(jnp.int32)
     Bq, d = q_ref.shape
     S = k_ref.shape[0]
     q = q_ref[:]
-
-    num_k = jnp.int32(S // block_k)
 
     def body(ki, carry, masked):
         m_prev, l_prev, acc = carry
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * jnp.float32(sm_scale)
+        s = _scores(q, k) * jnp.float32(sm_scale)
         if masked:
             # only the diagonal block pays for the mask (iota+cmp+select
             # are pure VPU work; off-diagonal causal blocks are all-visible
             # because the loop bound below already excludes future blocks)
-            q_pos = qi * Bq + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(NEG_INF))
+            s = jnp.where(_visible(qi * Bq, ki * block_k, s.shape, 0),
+                          s, jnp.float32(NEG_INF))
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc = acc * alpha + _matmul(p.astype(v.dtype), v)
         return m_new, l_new, acc
 
-    m0 = jnp.full((Bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Bq, 1), jnp.float32)
-    acc0 = jnp.zeros((Bq, d), jnp.float32)
-    init = (m0, l0, acc0)
+    init = (jnp.full((Bq, 1), NEG_INF, jnp.float32),
+            jnp.zeros((Bq, 1), jnp.float32),
+            jnp.zeros((Bq, d), jnp.float32))
     assert not causal or Bq == block_k, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
         # blocks [0, qi) are fully visible; block qi is the masked diagonal
         carry = jax.lax.fori_loop(
-            jnp.int32(0), qi.astype(jnp.int32),
-            lambda ki, c: body(ki, c, masked=False), init)
-        m, l, acc = body(qi.astype(jnp.int32), carry, masked=True)
+            jnp.int32(0), qi, lambda ki, c: body(ki, c, masked=False), init)
+        m, l, acc = body(qi, carry, masked=True)
     else:
         m, l, acc = jax.lax.fori_loop(
-            jnp.int32(0), num_k,
+            jnp.int32(0), jnp.int32(S // block_k),
             lambda ki, c: body(ki, c, masked=False), init)
     l_safe = jnp.maximum(l, jnp.float32(1e-30))
     o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[:] = (m + jnp.log(l_safe)).astype(jnp.float32)
+    lse_ref[:] = (m + jnp.log(l_safe)).T
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, causal: bool, sm_scale: float, block_k: int):
-    qi = pl.program_id(1)
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                   dq_ref, delta_ref, *, causal: bool, sm_scale: float,
+                   block_k: int):
+    # q/o/do/dq: [Bq, d]; k/v: [S, d]; lse_ref (in), delta_ref (out):
+    # [1, Bq] — rows here are queries, so both turn once a grid step
+    qi = pl.program_id(2).astype(jnp.int32)
     Bq, d = q_ref.shape
     S = k_ref.shape[0]
     q = q_ref[:]
     do = do_ref[:]
-    lse = lse_ref[:]            # [Bq, 1]
-    delta = delta_ref[:]        # [Bq, 1]
-
-    num_k = jnp.int32(S // block_k)
+    lse = lse_ref[:].T          # [Bq, 1]
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
+                    axis=1, keepdims=True)
 
     def body(ki, dq, masked):
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * jnp.float32(sm_scale)
+        s = _scores(q, k) * jnp.float32(sm_scale)
         if masked:
-            q_pos = qi * Bq + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(NEG_INF))
+            s = jnp.where(_visible(qi * Bq, ki * block_k, s.shape, 0),
+                          s, jnp.float32(NEG_INF))
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * jnp.float32(sm_scale)
-        dq = dq + jax.lax.dot_general(ds.astype(k.dtype), k,
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dq
+        ds = p * (_scores(do, v) - delta) * jnp.float32(sm_scale)
+        return dq + _matmul(ds.astype(k.dtype), k)
 
     dq0 = jnp.zeros((Bq, d), jnp.float32)
     assert not causal or Bq == block_k, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
         dq = jax.lax.fori_loop(
-            jnp.int32(0), qi.astype(jnp.int32),
-            lambda ki, c: body(ki, c, masked=False), dq0)
-        dq = body(qi.astype(jnp.int32), dq, masked=True)
+            jnp.int32(0), qi, lambda ki, c: body(ki, c, masked=False), dq0)
+        dq = body(qi, dq, masked=True)
     else:
         dq = jax.lax.fori_loop(
-            jnp.int32(0), num_k,
+            jnp.int32(0), jnp.int32(S // block_k),
             lambda ki, c: body(ki, c, masked=False), dq0)
     dq_ref[:] = dq.astype(dq_ref.dtype)
+    delta_ref[:] = delta.T
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, causal: bool, sm_scale: float,
-                    block_q: int):
-    ki = pl.program_id(1)
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
+                    sm_scale: float, block_q: int):
+    # k/v/dk/dv: [Bk, d] of one KV head; q/do: [S, d], lse/delta:
+    # [S/Bq, 1, Bq] of ONE query head of its group (grid axis 3,
+    # innermost).  Scores are formed TRANSPOSED, keys on rows: a block's
+    # [1, Bq] statistics then broadcast along sublanes as they lie, and
+    # dV = P^T dO, dK = dS^T Q are plain products
+    ki = pl.program_id(2).astype(jnp.int32)
+    g = pl.program_id(3)
     Bk, d = k_ref.shape
     S = q_ref.shape[0]
     k = k_ref[:]
     v = v_ref[:]
 
-    num_q = jnp.int32(S // block_q)
-
     def body(qi, carry, masked):
         dk, dv = carry
         q = q_ref[pl.ds(qi * block_q, block_q), :]
         do = do_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qi * block_q, block_q), :]
-        delta = delta_ref[pl.ds(qi * block_q, block_q), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * jnp.float32(sm_scale)
+        lse = lse_ref[qi]
+        delta = delta_ref[qi]
+        st = _scores(k, q) * jnp.float32(sm_scale)          # [Bk, Bq]
         if masked:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, Bk), 0)
-            k_pos = ki * Bk + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, Bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(NEG_INF))
-        p = jnp.exp(s - lse)
-        pb = p.astype(do.dtype)
-        dv = dv + jax.lax.dot_general(pb, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * jnp.float32(sm_scale)
-        dk = dk + jax.lax.dot_general(ds.astype(q.dtype), q,
-                                      (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+            st = jnp.where(_visible(qi * block_q, ki * Bk, st.shape, 1),
+                           st, jnp.float32(NEG_INF))
+        pt = jnp.exp(st - lse)
+        dv = dv + _matmul(pt.astype(do.dtype), do)
+        dst = pt * (_scores(v, do) - delta) * jnp.float32(sm_scale)
+        dk = dk + _matmul(dst.astype(q.dtype), q)
         return dk, dv
 
-    dk0 = jnp.zeros((Bk, d), jnp.float32)
-    dv0 = jnp.zeros((Bk, d), jnp.float32)
+    # the group's first query head starts the fp32 sums, the others add
+    # to them: consecutive grid steps revisit the same dK/dV block
+    @pl.when(g == 0)
+    def _start():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    carry = (dk_acc[:], dv_acc[:])
     assert not causal or Bk == block_q, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
         # diagonal block qi == ki is masked; strictly-later q blocks see
         # this k block in full
-        carry = body(ki.astype(jnp.int32), (dk0, dv0), masked=True)
+        carry = body(ki, carry, masked=True)
         dk, dv = jax.lax.fori_loop(
-            ki.astype(jnp.int32) + 1, num_q,
+            ki + 1, jnp.int32(S // block_q),
             lambda qi, c: body(qi, c, masked=False), carry)
     else:
         dk, dv = jax.lax.fori_loop(
-            jnp.int32(0), num_q,
-            lambda qi, c: body(qi, c, masked=False), (dk0, dv0))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+            jnp.int32(0), jnp.int32(S // block_q),
+            lambda qi, c: body(qi, c, masked=False), carry)
+    dk_acc[:] = dk
+    dv_acc[:] = dv
+
+    @pl.when(g == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[:] = dk.astype(dk_ref.dtype)
+        dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
 def _pick_blocks(S: int):
@@ -203,8 +227,11 @@ def _pick_blocks(S: int):
     non-dividing block floor-truncates the grid and leaves rows
     uninitialized).
 
-    512 measured fastest on v5e at S=2048/d=64: grid-step overhead
-    dominates below 256, VMEM pressure caps above 512 (see BENCH notes)."""
+    512 is the only block measured at head dim 128 (PERF.md §6, PR 28;
+    v5e, 8 rows of S=2048, 16 query / 8 KV heads, 18 layers a step):
+    ``flash_fwd`` 27.3 ms a run, ``flash_bwd_dq`` 32.8, ``flash_bwd_dkv``
+    41.3 — 29.3 % of the bf16 peak together.  Smaller blocks pay more
+    grid steps a row, larger ones more VMEM a step."""
     for b in (512, 256, 128, 64, 32, 16, 8):
         if S % b == 0:
             return b, b
@@ -231,8 +258,12 @@ def causal_mask(q_len: int, k_len: int):
 def _xla_sdpa(q, k, v, causal):
     """Reference XLA attention — fallback for shapes the Pallas kernel
     does not support (indivisible S, decode q_len != kv_len).  XLA fuses
-    this well; autodiff is native."""
+    this well; autodiff is native.  GQA repeats K/V here: this is the
+    fallback, not the fast path."""
     d = q.shape[-1]
+    if k.shape[2] != q.shape[2]:
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     qf = q.astype(jnp.float32) / math.sqrt(d)
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     if causal:
@@ -243,12 +274,19 @@ def _xla_sdpa(q, k, v, causal):
 
 
 def flash_attention(q, k, v, causal: bool = False):
-    """q/k/v: [b, s, h, d] -> out [b, s, h, d].
+    """q: [b, s, h, d], k/v: [b, s, nkv, d] with nkv dividing h (GQA
+    native) -> out [b, s, h, d].
 
     Routes to the Pallas kernel when the (static) shapes fit its blocking
-    (q_len == kv_len, a power-of-two block >= 8 divides S); otherwise
-    falls back to a fused XLA attention (decode shapes, odd lengths)."""
-    if q.shape[1] == k.shape[1] and _pick_blocks(q.shape[1]) is not None:
+    (q_len == kv_len, a block :func:`_pick_blocks` offers divides
+    S); otherwise falls back to a fused XLA attention (decode shapes, odd
+    lengths)."""
+    if q.shape[2] % k.shape[2] != 0:
+        raise ValueError(
+            f"q heads {q.shape[2]} must be a multiple of kv heads "
+            f"{k.shape[2]}")
+    if q.shape[1] == k.shape[1] and \
+            _pick_blocks(q.shape[1]) is not None:
         return _flash_pallas(q, k, v, causal)
     return _xla_sdpa(q, k, v, causal)
 
@@ -259,99 +297,140 @@ def _flash_pallas(q, k, v, causal: bool = False):
     return out
 
 
-def _reshape_in(x):
-    b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+def _to_kernel(x):
+    """``[b, s, n, d]`` as the kernels address it: where it lies when a
+    head is a whole number of lane tiles (a bitcast), else heads before
+    the sequence (a transpose)."""
+    b, s, n, d = x.shape
+    if d % 128 == 0:
+        return x.reshape(b, s, n * d)
+    return x.transpose(0, 2, 1, 3)
 
 
-def _reshape_out(x, b, h):
-    bh, s, d = x.shape
-    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+def _from_kernel(x, n):
+    """:func:`_to_kernel` undone, for an array of ``n`` heads."""
+    if x.ndim == 3:
+        b, s, nd = x.shape
+        return x.reshape(b, s, n, nd // n)
+    return x.transpose(0, 2, 1, 3)
+
+
+def _tile_spec(rows, d, at):
+    """BlockSpec of ``rows`` positions of one head of a ``_to_kernel``
+    array: a ``[rows, d]`` ref.  ``at(*grid) -> (batch, head, block)``."""
+    if d % 128 == 0:
+        def index(*g):
+            b, h, r = at(*g)
+            return idx32(b, r, h)
+        return pl.BlockSpec((None, rows, d), index)
+    return pl.BlockSpec((None, None, rows, d),
+                        lambda *g: idx32(*at(*g), 0))
+
+
+def _stat_spec(blocks, block, at):
+    """BlockSpec over a ``[b, h, s/block, 1, block]`` statistics array
+    of one head: ONE block, a ``[1, block]`` ref, or (``blocks`` given)
+    all of them, ``[blocks, 1, block]``.  The last two axes are whole
+    either way, so Mosaic takes any block size."""
+    def index(*g):
+        b, h, r = at(*g)
+        return idx32(b, h, r, 0, 0)
+    return pl.BlockSpec((None, None, blocks, 1, block), index)
+
+
+def _by_query_head(group):
+    """Index maps of the (batch, query head, q block) grid that
+    ``flash_fwd`` and ``flash_bwd_dq`` share: the block's own tile, and
+    the whole K/V of the head's group.  BOTH operands int32 before
+    dividing: under jax_enable_x64 the grid indices trace as i64, and
+    Mosaic's floor_divide lowering recurses on a scalar that is not
+    int32."""
+    def tile(i, j, r):
+        return i, j, r
+
+    def kv(i, j, r):
+        return i, jnp.int32(j) // jnp.int32(group), 0
+    return tile, kv
+
+
+def _by_kv_head(group):
+    """Index maps of ``flash_bwd_dkv``'s (batch, kv head, k block, query
+    head of the group) grid: the K/V block's own tile, and the whole
+    Q/dO/statistics of one query head.  The group is INNERMOST, so its
+    heads accumulate into one resident dK/dV block."""
+    def tile(i, j, r, g):
+        return i, j, r
+
+    def head(i, j, r, g):
+        return i, jnp.int32(j) * group + jnp.int32(g), 0
+    return tile, head
 
 
 def _flash_fwd(q, k, v, causal):
     b, s, h, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
-    qr, kr, vr = _reshape_in(q), _reshape_in(k), _reshape_in(v)
+    qr, kr, vr = _to_kernel(q), _to_kernel(k), _to_kernel(v)
     bq, bk = _pick_blocks(s)
-    grid = (b * h, s // bq)
+    tile, kv = _by_query_head(h // k.shape[2])
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           block_k=bk),
-        out_shape=(jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: idx32(i, j, 0)),
-        ),
+        out_shape=(jax.ShapeDtypeStruct(qr.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, s // bq, 1, bq),
+                                        jnp.float32)),
+        grid=(b, h, s // bq),
+        in_specs=[_tile_spec(bq, d, tile), _tile_spec(s, d, kv),
+                  _tile_spec(s, d, kv)],
+        out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
         name="flash_fwd",
         interpret=_common.interpret(),
     )(qr, kr, vr)
-    return _reshape_out(out, b, h), (qr, kr, vr, out, lse, b, h, s, d)
-
-
-def _flash_fwd_vjp(q, k, v, causal):
-    out, res = _flash_fwd(q, k, v, causal)
-    return out, res
+    return _from_kernel(out, h), (qr, kr, vr, out, lse)
 
 
 def _flash_bwd_vjp(causal, res, dout):
-    qr, kr, vr, out, lse, b, h, s, d = res
+    qr, kr, vr, out, lse = res
+    b, s, h, d = dout.shape
+    nkv = kr.size // (b * s * d)
     sm_scale = 1.0 / math.sqrt(d)
-    do = _reshape_in(dout)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    do = _to_kernel(dout)
     bq, bk = _pick_blocks(s)
     interp = _common.interpret()
 
-    dq = pl.pallas_call(
+    tile, kv = _by_query_head(h // nkv)
+    dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal,
                           sm_scale=sm_scale, block_k=bk),
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), qr.dtype),
-        grid=(b * h, s // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: idx32(i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, bq, d), lambda i, j: idx32(i, j, 0)),
+        out_shape=(jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
+        grid=(b, h, s // bq),
+        in_specs=[_tile_spec(bq, d, tile), _tile_spec(s, d, kv),
+                  _tile_spec(s, d, kv), _tile_spec(bq, d, tile),
+                  _tile_spec(bq, d, tile), _stat_spec(None, bq, tile)],
+        out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
         name="flash_bwd_dq",
         interpret=interp,
-    )(qr, kr, vr, do, lse, delta)
+    )(qr, kr, vr, out, do, lse)
 
+    tile, head = _by_kv_head(h // nkv)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=bq),
-        out_shape=(jax.ShapeDtypeStruct((b * h, s, d), kr.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, d), vr.dtype)),
-        grid=(b * h, s // bk),
-        in_specs=[
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, s, d), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j: idx32(i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j: idx32(i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
-            pl.BlockSpec((None, bk, d), lambda i, j: idx32(i, j, 0)),
-        ),
+        out_shape=(jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(vr.shape, vr.dtype)),
+        grid=(b, nkv, s // bk, h // nkv),
+        in_specs=[_tile_spec(s, d, head), _tile_spec(bk, d, tile),
+                  _tile_spec(bk, d, tile), _tile_spec(s, d, head),
+                  _stat_spec(s // bq, bq, head),
+                  _stat_spec(s // bq, bq, head)],
+        out_specs=(_tile_spec(bk, d, tile), _tile_spec(bk, d, tile)),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
         name="flash_bwd_dkv",
         interpret=interp,
     )(qr, kr, vr, do, lse, delta)
 
-    return (_reshape_out(dq, b, h), _reshape_out(dk, b, h),
-            _reshape_out(dv, b, h))
+    return _from_kernel(dq, h), _from_kernel(dk, nkv), _from_kernel(dv, nkv)
 
 
-_flash_pallas.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
+_flash_pallas.defvjp(_flash_fwd, _flash_bwd_vjp)

@@ -47,26 +47,36 @@ def rope_tables(seq_len: int, head_dim: int, theta: float = 10000.0,
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
 
 
-def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, neg_sin: bool):
-    # x: [1, S_blk, N, d]; cos/sin: [S_blk, d/2] broadcast over heads
-    x = x_ref[0].astype(jnp.float32)            # [S_blk, N, d]
-    d = x.shape[-1]
-    h = d // 2
-    cos = cos_ref[:].astype(jnp.float32)[:, None, :]   # [S_blk, 1, d/2]
-    sin = sin_ref[:].astype(jnp.float32)[:, None, :]
-    if neg_sin:
-        sin = -sin
+def _rotate(x, cos, sin):
+    """Rotate-half on the last axis of fp32 ``x``; cos/sin broadcast
+    against its halves."""
+    h = x.shape[-1] // 2
     x1 = x[..., :h]
     x2 = x[..., h:]
-    lo = x1 * cos - x2 * sin
-    hi = x2 * cos + x1 * sin
-    o_ref[0] = jnp.concatenate([lo, hi], axis=-1).astype(o_ref.dtype)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, neg_sin: bool):
+    # x: [1, S_blk, N*d], heads side by side on lanes where the
+    # projection wrote them, each a static, tile-aligned lane slice;
+    # cos/sin: [S_blk, d/2], the same for every head
+    d = 2 * cos_ref.shape[-1]
+    cos = cos_ref[:].astype(jnp.float32)
+    sin = sin_ref[:].astype(jnp.float32)
+    if neg_sin:
+        sin = -sin
+    for j in range(x_ref.shape[-1] // d):
+        head = (0, slice(None), slice(j * d, (j + 1) * d))
+        o_ref[head] = _rotate(x_ref[head].astype(jnp.float32), cos,
+                              sin).astype(o_ref.dtype)
 
 
 def _composite(x, cos, sin, neg_sin: bool):
-    """Plain-XLA rotate-half (the fallback for shapes the kernel's
-    blocking cannot tile — e.g. odd sequence lengths where no 8-aligned
-    block divides S; Mosaic requires sublane blocks divisible by 8)."""
+    """Plain-XLA rotate-half (the fallback for shapes the kernel does
+    not address: a head that is not whole lane tiles, d % 128 != 0, and
+    odd sequence lengths where no 8-aligned block divides S; Mosaic
+    requires sublane blocks divisible by 8)."""
     d = x.shape[-1]
     h = d // 2
     c = cos.astype(jnp.float32)[None, :, None, :]
@@ -95,24 +105,23 @@ def _pick_block(s, n, d):
 def _apply(x, cos, sin, neg_sin: bool):
     b, s, n, d = x.shape
     bs = _pick_block(s, n, d)
-    if bs is None:
+    if bs is None or d % 128 != 0:
         return _composite(x, cos, sin, neg_sin)
-    grid = (b, s // bs)
+    # a head of whole lane tiles is addressed where the projection
+    # wrote it: [b, s, n, d] -> [b, s, n*d] is a bitcast of the matmul's
+    # output, while a [.., n, d] operand puts the HEADS on sublanes and
+    # costs a relayout on each side of the kernel
+    spec = pl.BlockSpec((1, bs, n * d), lambda bi, si: idx32(bi, si, 0))
+    table = pl.BlockSpec((bs, d // 2), lambda bi, si: idx32(si, 0))
     return pl.pallas_call(
         functools.partial(_rope_kernel, neg_sin=neg_sin),
-        out_shape=jax.ShapeDtypeStruct((b, s, n, d), x.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bs, n, d),
-                         lambda bi, si: idx32(bi, si, 0, 0)),
-            pl.BlockSpec((bs, d // 2), lambda bi, si: idx32(si, 0)),
-            pl.BlockSpec((bs, d // 2), lambda bi, si: idx32(si, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs, n, d),
-                               lambda bi, si: idx32(bi, si, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, n * d), x.dtype),
+        grid=(b, s // bs),
+        in_specs=[spec, table, table],
+        out_specs=spec,
         name="rope",
         interpret=_common.interpret(),
-    )(x, cos, sin)
+    )(x.reshape(b, s, n * d), cos, sin).reshape(x.shape)
 
 
 @jax.custom_vjp

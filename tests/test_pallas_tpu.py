@@ -58,6 +58,34 @@ def test_flash_bwd_parity():
         assert float(jnp.abs(a - b).max()) < 2e-2
 
 
+@pytest.mark.parametrize("s,h,nkv,d", [
+    (1024, 4, 4, 128), (1024, 4, 2, 128), (1024, 4, 1, 128),
+    (1024, 4, 2, 64), (192, 4, 2, 128), (576, 4, 2, 64)])
+def test_flash_gqa_fwd_bwd_parity(s, h, nkv, d):
+    """K/V at their own head count through Mosaic: head dim 128
+    addressed flat in [b, s, heads*d], 64 transposed.  1024 is two
+    512-row blocks; 192 and 576 run in blocks of 64 (three, nine)."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention, _xla_sdpa)
+    kk = jax.random.PRNGKey
+    q = jax.random.normal(kk(0), (2, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(kk(1), (2, s, nkv, d), jnp.bfloat16)
+    v = jax.random.normal(kk(2), (2, s, nkv, d), jnp.bfloat16)
+    w = jax.random.normal(kk(3), (2, s, h, d), jnp.float32)
+
+    def run(fn):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        args = (q, k, v) if fn is flash_attention else f32
+        out, vjp = jax.vjp(lambda *a: fn(*a, True).astype(jnp.float32),
+                           *args)
+        return [out] + [g.astype(jnp.float32) for g in vjp(w)]
+
+    for got, want in zip(run(flash_attention), run(_xla_sdpa)):
+        assert got.shape == want.shape
+        err = float(jnp.abs(got - want).max())
+        assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
+
+
 def test_flash_decode_and_odd_lengths():
     """q_len != kv_len (decode) and indivisible S take the XLA fallback
     and must stay finite/correct (round-1: NaN at S=129, crash at decode)."""

@@ -18,6 +18,8 @@ is steered from the ``compiled`` fixture — not through an option of the
 program.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -63,12 +65,12 @@ def _text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _cfg(depth, train, sequence_parallel=False):
+def _cfg(depth, train, sequence_parallel=False, nkv=HEADS):
     from paddle_tpu.models.llama_pretrain import LlamaPretrainConfig
     return LlamaPretrainConfig(
         vocab_size=VOCAB, hidden_size=HIDDEN, intermediate_size=FFN,
         num_hidden_layers=depth, num_attention_heads=HEADS,
-        num_key_value_heads=HEADS, max_seq_len=2048,
+        num_key_value_heads=nkv, max_seq_len=2048,
         use_pallas_attention=True, sequence_parallel=sequence_parallel,
         remat=train, dtype=jnp.bfloat16,
         param_dtype=jnp.float32 if train else jnp.bfloat16,
@@ -120,6 +122,69 @@ def test_flash_attention_fwd_bwd(one_chip, compiled):
         lambda q, k, v: flash_attention(q, k, v, True).astype(
             jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
     assert text.count(KERNEL) >= 2          # forward + backward kernels
+
+
+@pytest.mark.parametrize("s,d", [(192, 128), (576, 128), (320, 64),
+                                 (24, 32)])
+def test_flash_attention_small_blocks(one_chip, compiled, s, d):
+    """Lengths whose largest dividing block is under 128 (64, 64, 64, 8)
+    stay on the kernels, GQA 4/2, causal and not: the statistics are
+    ``[b, h, s/block, 1, block]``, a block taken by its index on an
+    untiled axis, so Mosaic is never asked to prove a lane offset of 64
+    aligned."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q = _sds(one_chip, (2, s, 4, d), jnp.bfloat16)
+    kv = _sds(one_chip, (2, s, 2, d), jnp.bfloat16)
+    for causal in (True, False):
+        text = _text(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, causal).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+        assert text.count(KERNEL) == 3
+
+
+def _moved(text, elements):
+    """Instructions of a compiled module that only MOVE an array of at
+    least ``elements`` elements: copies, transposes, the reshapes the
+    TPU compiler could not make a bitcast, and broadcasts of one."""
+    found = []
+    for shape, opcode in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (copy|transpose|reshape|broadcast)\(",
+            text):
+        if np.prod([int(n) for n in shape.split(",")]) >= elements:
+            found.append(f"{opcode} [{shape}]")
+    return found
+
+
+def test_flash_attention_gqa_reads_the_projections_where_they_lie(
+        one_chip, compiled):
+    """The pretraining cell's shape, 16 query / 8 KV heads of 128 at 8 x
+    2048, as the train step has it: the projections' ``[b, s, heads*d]``
+    through rope and flash attention, forward and backward.  The three
+    flash kernels and rope's, and NOTHING that moves a K/V-sized array
+    between them: no transpose, no relayout copy or reshape, no GQA
+    broadcast (the kernels take ``head // group``)."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.rope import fused_rope, rope_tables
+    b, s, nkv = 8, 2048, 8
+    q = _sds(one_chip, (b, s, HEADS * HEAD_DIM), jnp.bfloat16)
+    kv = _sds(one_chip, (b, s, nkv * HEAD_DIM), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, dout):
+        def attn(q, k, v):
+            cos, sin = rope_tables(s, HEAD_DIM)
+            q = fused_rope(q.reshape(b, s, HEADS, HEAD_DIM), cos, sin)
+            k = fused_rope(k.reshape(b, s, nkv, HEAD_DIM), cos, sin)
+            v = v.reshape(b, s, nkv, HEAD_DIM)
+            return flash_attention(q, k, v, True).reshape(b, s, -1)
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(dout)
+
+    text = _text(fwd_bwd, q, kv, kv, q)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rope"):
+        assert kernel in text, kernel
+    assert text.count(KERNEL) == 3 + 4      # rope: q, k, dq, dk
+    assert not _moved(text, b * s * nkv * HEAD_DIM), \
+        _moved(text, b * s * nkv * HEAD_DIM)
 
 
 @pytest.mark.parametrize("T,nkv", [(2048, 16), (2048, 4), (64, 16)])
@@ -210,18 +275,20 @@ def test_tp_decode_step_has_cross_device_all_reduce(topo, compiled):
     assert "all-reduce" in text and "replica_groups={{0,1,2,3}}" in text
 
 
-def test_train_step_dp2_mp2_sequence_parallel(topo, compiled):
+@pytest.mark.parametrize("nkv", [HEADS, 8])
+def test_train_step_dp2_mp2_sequence_parallel(topo, compiled, nkv):
     """The multi-chip train step with the Pallas kernels ON and the
     sequence-parallel constraint ON.  GSPMD refuses to partition a
     Mosaic kernel ("Mosaic kernels cannot be automatically
     partitioned"), so rope and flash run per shard
     (``llama_pretrain._per_shard``); the SP constraint follows the
     MESH's platform, so it is compiled here although the host is a
-    CPU."""
+    CPU.  ``nkv`` 8: GQA, the heads split over ``mp`` 16/8 -> 8/4 a
+    shard and the kernels keep the group ratio."""
     from paddle_tpu.models.llama_pretrain import (
         build_mesh, init_adafactor_state, make_train_step)
     mesh = build_mesh(dp=2, mp=2, devices=topo.devices)
-    cfg = _cfg(1, train=True, sequence_parallel=True)
+    cfg = _cfg(1, train=True, sequence_parallel=True, nkv=nkv)
     with mesh:
         params = _param_sds(cfg, mesh)
         opt = jax.tree_util.tree_map(

@@ -6,6 +6,7 @@ that reach the profiler's trace under a plain
 lowered text, spans from a trace the CPU backend records."""
 
 import ast
+import collections
 import glob
 import os
 import re
@@ -81,6 +82,117 @@ def test_train_step_carries_the_scopes(accum, want):
     assert "rematted_computation" in text
     # the readers key on the XLA module ``jit_step``
     assert "jit(step)" in text
+
+
+def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
+    """The primitives of ``jaxpr`` (calls, scans and remat bodies
+    walked, kernel bodies not) whose name stack holds ``scope``."""
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"
+        if scope in re.findall(r"\w+", path):
+            found[eqn.primitive.name] += 1
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += scope_primitives(sub, scope, path)
+    return found
+
+
+@pytest.mark.parametrize("heads,kv_heads,hidden,moves", [
+    (16, 8, 2048, "reshape"),       # head dim 128: addressed where it lies
+    (4, 2, 256, "transpose")])      # head dim 64: the transposing entry
+def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
+                                                   moves):
+    """What the ``attn`` scope of the train step holds with the Pallas
+    attention on and GQA: the flash kernels — forward, the recompute's
+    forward, dq (which forms delta itself), dkv — and the moves its
+    addressing needs: bitcast reshapes at head dim 128, transposes at
+    64.  K/V are never repeated: no ``broadcast_in_dim``, and nothing
+    sums a group back."""
+    cfg = _cfg(hidden_size=hidden, num_attention_heads=heads,
+               num_key_value_heads=kv_heads, num_hidden_layers=1,
+               remat=True, loss_chunks=2, use_pallas_attention=True)
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        step = make_train_step(cfg, mesh, lr=1e-2, optimizer="adafactor")
+        jaxpr = jax.make_jaxpr(step)(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    found = scope_primitives(jaxpr.jaxpr, "attn")
+    assert found["pallas_call"] == 4, found
+    assert set(found) == {"pallas_call", moves}, found
+
+
+def test_attention_head_dim_64_matches_the_composite():
+    """``_attention`` at 4 query / 2 KV heads of 64 through the flash
+    kernels' transposing entry against the XLA composite, values and
+    gradients."""
+    from paddle_tpu.flags import set_flags
+    from paddle_tpu.models.llama_pretrain import _attention
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.normal(0, 1, (2, 256, 4, 64)), jnp.float32)
+    k, v, w = (jnp.asarray(rng.normal(0, 1, (2, 256, n, 64)), jnp.float32)
+               for n in (2, 2, 4))
+
+    def grads(pallas):
+        cfg = _cfg(hidden_size=256, use_pallas_attention=pallas)
+        out, vjp = jax.vjp(lambda q, k, v: _attention(q, k, v, cfg),
+                           q, k, v)
+        return out, vjp(w)
+
+    set_flags({"FLAGS_pallas_interpret": True})
+    try:
+        out, got = grads(True)
+    finally:
+        set_flags({"FLAGS_pallas_interpret": False})
+    want_out, want = grads(False)
+    np.testing.assert_allclose(out, want_out, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+def test_attention_mqa_over_mp_still_splits_the_heads():
+    """One KV head under ``mp`` 2: K/V are repeated to TWO heads, the
+    least count ``mp`` divides (not to the four query heads), and each
+    shard runs the kernels on its 2 query heads and 1 KV head; values
+    and gradients are the composite's."""
+    from paddle_tpu.flags import set_flags
+    from paddle_tpu.models.llama_pretrain import _attention
+    rng = np.random.RandomState(8)
+    q, k, v, w = (jnp.asarray(rng.normal(0, 1, (2, 128, n, 64)),
+                              jnp.float32) for n in (4, 1, 1, 4))
+    mesh = build_mesh(mp=2, devices=jax.devices()[:2])
+
+    def grads(pallas, mesh):
+        cfg = _cfg(hidden_size=256, num_key_value_heads=1,
+                   use_pallas_attention=pallas)
+        fn = lambda q, k, v: _attention(q, k, v, cfg, mesh)  # noqa: E731
+        out, vjp = jax.vjp(fn, q, k, v)
+        return out, vjp(w), jax.make_jaxpr(fn)(q, k, v)
+
+    set_flags({"FLAGS_pallas_interpret": True})
+    try:
+        with mesh:
+            out, got, jaxpr = grads(True, mesh)
+    finally:
+        set_flags({"FLAGS_pallas_interpret": False})
+    per_shard, = (e for e in jaxpr.eqns if e.primitive.name == "shard_map")
+    assert [x.aval.shape[2] for x in per_shard.invars] == [4, 2, 2]
+    assert [x.aval.shape[2]
+            for x in per_shard.params["jaxpr"].invars] == [2, 1, 1]
+    want_out, want, _ = grads(False, None)
+    np.testing.assert_allclose(out, want_out, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
 
 
 # -- the serving step programs ------------------------------------------------
