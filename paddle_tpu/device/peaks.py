@@ -1,7 +1,7 @@
 """Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
 ONE table for every consumer — the engine's bytes-vs-FLOPs cost models
-(``models/serving_engine.py``), ``bench.py``'s MFU — so they can never
+(``models/serving_engine.py``, ``models/disagg.py``) — so they can never
 disagree about the chip.  A device that is not in the table is an
 error, not a default: a number divided by an invented peak looks like a
 measurement and is not one.

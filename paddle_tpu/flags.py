@@ -162,23 +162,6 @@ define_flag("FLAGS_pallas_rope", True,
             "(measured +2.7% on the 1.3B bench: the composite form's "
             "split/concat + fp32 broadcasts cost more than the kernel "
             "boundary — see PERF.md).")
-define_flag("FLAGS_pallas_swiglu", False,
-            "Use the Pallas swiglu kernel in the flagship trunk "
-            "(default off: measured -3.8% on the 1.3B bench — XLA "
-            "fuses silu*up into the surrounding matmuls and the kernel "
-            "boundary forces an HBM round-trip; kept for the incubate "
-            "fused-op API — see PERF.md).")
-define_flag("FLAGS_pallas_rms_norm", False,
-            "Route the flagship trunk's rms_norm through the Pallas "
-            "kernel (default off: measured -11% on the 1.3B bench — "
-            "XLA fuses the composite norm into the adjacent matmul, "
-            "the kernel boundary breaks that; see PERF.md).")
-define_flag("FLAGS_pallas_rmsnorm_matmul", False,
-            "Fuse the flagship block-entry rms_norm INTO the q/k/v and "
-            "gate/up matmul kernels (one pass over x, no normalised-"
-            "activation HBM round trip — the PERF.md 'remaining "
-            "levers' fusion).  Default off until measured on chip vs "
-            "XLA's own norm-into-matmul fusion.")
 define_flag("FLAGS_pallas_int8_matmul", True,
             "Use the Pallas weight-only int8 matmul in the decode "
             "serving path (dims must be lane-aligned; measured +23% "

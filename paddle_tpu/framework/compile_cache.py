@@ -28,7 +28,7 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Turn the persistent cache on for this process (idempotent) and
     return its directory.  Entry points call it before their first
-    compile: ``chip_smoke.py``, ``bench.py``, ``GenerationServer.start``;
+    compile: ``chip_smoke.py``, ``GenerationServer.start``;
     ``distributed.launch`` hands :func:`compile_cache_dir` to its
     workers through the environment."""
     if not os.environ.get(_ENV):

@@ -127,24 +127,12 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
 
 
 def swiglu(x, y=None, name=None):
-    """Reference: incubate swiglu — silu(x) * y (or split last dim).
-    Routes to the Pallas kernel under FLAGS_pallas_swiglu (off by
-    default: measured slower than XLA's fusion on the 1.3B bench,
-    PERF.md)."""
-    from ....flags import flags as _flags
-    from ....ops.dispatch import get_op_impl
-    impl = get_op_impl("swiglu", None)
-    use_kernel = impl is not None and _flags.FLAGS_pallas_swiglu
-
+    """Reference: incubate swiglu — silu(x) * y (or split last dim)."""
     if y is None:
         def fn(a):
             a1, a2 = jnp.split(a, 2, axis=-1)
-            if use_kernel:
-                return impl(a1, a2)
             return jax.nn.silu(a1) * a2
         return apply("swiglu", fn, as_tensor(x))
-    if use_kernel:
-        return apply("swiglu", impl, as_tensor(x), as_tensor(y))
     return apply("swiglu", lambda a, b: jax.nn.silu(a) * b,
                  as_tensor(x), as_tensor(y))
 

@@ -600,8 +600,7 @@ class GenerationServer:
         # per-request distributed tracing (docs/OBSERVABILITY.md,
         # "Tracing"): ON by default at the serving-product tier —
         # tail sampling bounds the store, and the hot-path cost is
-        # phase-clock floats at scheduler mutation points only
-        # (bench.py's serving_trace_overhead line measures it).
+        # phase-clock floats at scheduler mutation points only.
         # ``tracer=False`` disables; to aggregate several fronts,
         # share a TraceStore (one Tracer per front) — two plain
         # engines sharing one TRACER mint colliding local rids, and

@@ -130,9 +130,9 @@ def handoff_flip_gbps(prompt_len: int, decode_engine,
                       chip_flops: Optional[float] = None) -> float:
     """The link speed at which :func:`handoff_wins` flips for this
     prompt length — strictly above it, disaggregation wins.  Owns the
-    inversion of the cost-model arithmetic in one place: bench.py and
-    tests calibrate split-inducing ``handoff_gbps`` knobs from it
-    instead of re-deriving the algebra."""
+    inversion of the cost-model arithmetic in one place: tests
+    calibrate split-inducing ``handoff_gbps`` knobs from it instead of
+    re-deriving the algebra."""
     from .serving_engine import _chip_flops_default, _count_params
 
     if prompt_len <= 0:

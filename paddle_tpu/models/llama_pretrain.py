@@ -31,7 +31,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name as _ckpt_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["LlamaPretrainConfig", "init_params", "make_train_step",
@@ -55,9 +54,9 @@ class LlamaPretrainConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # remat_policy: 'full' recomputes the whole block; 'flash' saves the
-    # flash-attention residuals and remats only projections/FFN (fastest
-    # on v5e, see PERF.md); 'dots'/'names' are jax checkpoint policies.
+    # remat_policy: 'full' recomputes the whole block (what the
+    # benchmark's train cell runs); 'flash' saves the flash-attention
+    # residuals and remats only projections/FFN (no cell has timed it).
     remat_policy: str = "full"
     sequence_parallel: bool = True
     use_pallas_attention: bool = True
@@ -79,11 +78,10 @@ class LlamaPretrainConfig:
     def __post_init__(self):
         if self.num_key_value_heads is None:
             self.num_key_value_heads = self.num_attention_heads
-        if self.remat_policy not in ("full", "flash", "dots", "names",
-                                     "cheap"):
+        if self.remat_policy not in ("full", "flash"):
             raise ValueError(
-                f"remat_policy must be one of full/flash/dots/names/"
-                f"cheap, got {self.remat_policy!r}")
+                f"remat_policy must be 'full' or 'flash', "
+                f"got {self.remat_policy!r}")
         if self.context_parallel not in (None, "ring", "ulysses"):
             raise ValueError(
                 f"context_parallel must be None, 'ring' or 'ulysses', "
@@ -191,18 +189,8 @@ def init_params(cfg: LlamaPretrainConfig, key, mesh: Mesh,
 # model math (pure, bf16 compute)
 # ---------------------------------------------------------------------------
 def _rms_norm(x, w, eps):
-    from ..flags import flags
-    if flags.FLAGS_pallas_rms_norm:
-        from ..ops.dispatch import get_op_impl
-        impl = get_op_impl("rms_norm", None)
-        if impl is not None and x.shape[-1] % 128 == 0 and \
-                not isinstance(w, dict):
-            return impl(x, w.astype(x.dtype), eps)
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-    # named so the "cheap" remat policy can save ONLY the [B,S,1] rstd
-    # (the backward then skips the variance reduction re-compute while
-    # re-materialising everything O(H)-sized)
-    rstd = _ckpt_name(jax.lax.rsqrt(var + eps), "rms_rstd")
+    rstd = jax.lax.rsqrt(var + eps)
     return (x.astype(jnp.float32) * rstd).astype(
         x.dtype) * w.astype(x.dtype)
 
@@ -351,31 +339,16 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                     mesh: Optional[Mesh] = None):
     """ln1 + QKV projections + rope -> q [b, s, n, d], k, v [b, s, nkv,
     d]: K/V at their own head count.  Single source of block math
-    shared by every remat policy."""
+    shared by both remat boundaries."""
     b, s, h = x.shape
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
     dt = cfg.dtype
-    from ..flags import flags
-    from ..ops.dispatch import get_op_impl
-    rmm = get_op_impl("rmsnorm_matmul", None) \
-        if flags.FLAGS_pallas_rmsnorm_matmul and \
-        not isinstance(bp["wq"], dict) else None
     with jax.named_scope("attn_qkv"):
-        if rmm is not None:
-            # block-entry fusion (PERF.md remaining lever): norm computed
-            # inside each matmul kernel, normalised y never hits HBM
-            q = rmm(x, bp["ln1"], bp["wq"].astype(dt),
-                    cfg.rms_norm_eps).reshape(b, s, n, d)
-            k = rmm(x, bp["ln1"], bp["wk"].astype(dt),
-                    cfg.rms_norm_eps).reshape(b, s, nkv, d)
-            v = rmm(x, bp["ln1"], bp["wv"].astype(dt),
-                    cfg.rms_norm_eps).reshape(b, s, nkv, d)
-        else:
-            y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
-            q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
-            k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
-            v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
+        y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+        q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
+        k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
+        v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
     with jax.named_scope("rope"):
         q, k = _rope(q, k, cfg.rope_theta, mesh)
     # GQA stays UN-repeated here: _attention's flash kernels, dense and
@@ -396,39 +369,18 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
     path) — see :func:`_mm`."""
     b, s, h = x.shape
     with jax.named_scope("attn_out"):
-        attn = _ckpt_name(attn.reshape(b, s, h), "attn_out")
-        x = x + _mm(attn, bp["wo"], cfg.dtype)
+        x = x + _mm(attn.reshape(b, s, h), bp["wo"], cfg.dtype)
     with jax.named_scope("mlp"):
         return _ffn(bp, x, cfg)
 
 
 def _ffn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
     """ln2 + gated FFN + residual: the ``mlp`` scope's body."""
-    from ..flags import flags
-    from ..ops.dispatch import get_op_impl
     dt = cfg.dtype
-    res = x
-    rmm = get_op_impl("rmsnorm_matmul", None) \
-        if flags.FLAGS_pallas_rmsnorm_matmul and \
-        not isinstance(bp["w_gate"], dict) else None
-    if rmm is not None:
-        # FFN-entry fusion (PERF.md remaining lever) — int8 weight
-        # dicts keep the _mm path
-        gate = _ckpt_name(jax.nn.silu(rmm(
-            x, bp["ln2"], bp["w_gate"].astype(dt),
-            cfg.rms_norm_eps)), "ffn_gate")
-        up = _ckpt_name(rmm(x, bp["ln2"], bp["w_up"].astype(dt),
-                            cfg.rms_norm_eps), "ffn_up")
-        return res + _mm(gate * up, bp["w_down"], dt)
     y = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
-    sw = get_op_impl("swiglu", None)
-    if sw is not None and flags.FLAGS_pallas_swiglu:
-        act = _ckpt_name(sw(_mm(y, bp["w_gate"], dt),
-                            _mm(y, bp["w_up"], dt)), "ffn_gate")
-        return res + _mm(act, bp["w_down"], dt)
-    gate = _ckpt_name(jax.nn.silu(_mm(y, bp["w_gate"], dt)), "ffn_gate")
-    up = _ckpt_name(_mm(y, bp["w_up"], dt), "ffn_up")
-    return res + _mm(gate * up, bp["w_down"], dt)
+    gate = jax.nn.silu(_mm(y, bp["w_gate"], dt))
+    up = _mm(y, bp["w_up"], dt)
+    return x + _mm(gate * up, bp["w_down"], dt)
 
 
 def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
@@ -448,10 +400,8 @@ def _block_forward_flash_saved(bp: Dict[str, Any], x,
 
     The flash-attention call sits OUTSIDE the two checkpoint regions, so
     its custom-vjp residuals (q/k/v/o/lse) are saved for the backward
-    pass instead of re-running the O(S^2) kernel during recompute —
-    measured the best FLOPs/HBM trade on v5e at seq 2048 (the fwd kernel
-    is ~30% of a block's forward time; its residuals are ~150MB/layer at
-    b=8, which fits alongside fp32 params+moments for the 350M bench).
+    pass instead of re-running the O(S^2) kernel during recompute, at
+    the cost of holding them (q/k/v/o at the row's size, a layer).
     The math is the shared _block_pre_attn/_block_post_attn — only the
     checkpoint boundaries differ from _block_forward."""
     pre = jax.checkpoint(
@@ -472,20 +422,6 @@ def _remat_wrap(fwd, cfg):
     if cfg.remat_policy == "flash":
         # selective: block internals remat, flash residuals saved
         return _block_forward_flash_saved
-    if cfg.remat_policy == "dots":
-        # save matmul outputs, recompute elementwise/softmax in bwd —
-        # ~halves the trunk recompute FLOPs at the cost of HBM
-        pol = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        return jax.checkpoint(fwd, static_argnums=(2, 3), policy=pol)
-    if cfg.remat_policy == "names":
-        pol = jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "ffn_gate", "ffn_up")
-        return jax.checkpoint(fwd, static_argnums=(2, 3), policy=pol)
-    if cfg.remat_policy == "cheap":
-        # save ONLY tiny per-row stats ([B,S,1] rms rstd) — near-zero
-        # HBM cost; backward skips the norm reductions during recompute
-        pol = jax.checkpoint_policies.save_only_these_names("rms_rstd")
-        return jax.checkpoint(fwd, static_argnums=(2, 3), policy=pol)
     return jax.checkpoint(fwd, static_argnums=(2, 3))
 
 

@@ -648,7 +648,7 @@ class ContinuousBatchingEngine:
         self.horizon_trimmed_tokens = 0
         # padding-waste accounting across ALL prefill lanes: dispatched
         # token slots vs slots that carried no real context token
-        # (bucket/page padding) — bench.py's admission A/B reads these
+        # (bucket/page padding) — the benchmark's counters read these
         self.prefill_token_slots = 0
         self.prefill_padded_tokens = 0
         # serving counters (surfaced by GenerationServer /health)

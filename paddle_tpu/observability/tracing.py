@@ -520,6 +520,6 @@ _default_tracer = Tracer()
 
 
 def default_tracer() -> Tracer:
-    """The process-wide tracer bench.py publishes into (servers
-    default to a private Tracer per front, like their registries)."""
+    """The process-wide tracer (servers default to a private Tracer
+    per front, like their registries)."""
     return _default_tracer

@@ -11,13 +11,11 @@ from .flash_attention import flash_attention
 from .rms_norm import rms_norm
 from .fused_adamw import fused_adamw
 from .rope import fused_rope, rope_tables
-from .swiglu import swiglu
 from .int8_matmul import int8_matmul, quantize_int8
-from .rmsnorm_matmul import rmsnorm_matmul
 
 __all__ = ["flash_attention", "rms_norm", "fused_adamw", "fused_rope",
-           "rope_tables", "swiglu", "int8_matmul", "quantize_int8",
-           "rmsnorm_matmul", "register_pallas_ops"]
+           "rope_tables", "int8_matmul", "quantize_int8",
+           "register_pallas_ops"]
 
 
 def register_pallas_ops() -> None:
@@ -30,9 +28,7 @@ def register_pallas_ops() -> None:
                      fused_adamw(p, g, m, v, t, lr, b1, b2, eps, wd))
     register_op_impl("rms_norm", rms_norm)
     register_op_impl("fused_rope", fused_rope)
-    register_op_impl("swiglu", swiglu)
     register_op_impl("int8_matmul", int8_matmul)
-    register_op_impl("rmsnorm_matmul", rmsnorm_matmul)
 
 
 register_pallas_ops()

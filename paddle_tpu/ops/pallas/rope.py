@@ -11,9 +11,9 @@ pass per (batch, head) tile:
 
 cos/sin are [S, d/2] tables computed once outside (tiny).  The backward
 is the inverse rotation (sin -> -sin) — no residuals beyond the tables.
-Like swiglu, XLA usually fuses the composite form into the surrounding
-projections; the kernel is kept for fusion-boundary sites and for API
-parity, and the bench keeps whichever path measures faster (PERF.md).
+XLA can fuse the composite form into the surrounding projections; the
+flagship trunk takes the kernel where it measured ahead (head dim % 128
+== 0, FLAGS_pallas_rope; PERF.md), other head dims keep the composite.
 """
 
 from __future__ import annotations

@@ -387,7 +387,7 @@ class Profiler:
         print(table)
         return table
 
-    # convenience for bench.py: mean step time over recorded steps
+    # mean step time over recorded steps
     def step_time_ms(self, skip_first=1):
         marks = self._step_marks[skip_first:]
         if not marks:

@@ -101,8 +101,7 @@ The plane is OFF unless installed: the production hot path pays one
         ...                     # 3rd decode dispatch raises
     assert fp.counts["step_dispatch"] >= 3
 
-bench.py arms ``every=K`` rules for its fault-recovery line the same
-way.  Stdlib only.
+Stdlib only.
 """
 
 from __future__ import annotations

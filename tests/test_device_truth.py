@@ -1,9 +1,8 @@
 """Nothing on the main paths hides the device: an explicit request for
 a TPU tells the truth, one platform name, one peaks table, a compile
-cache that is placeable from outside, a visible DataLoader transport,
-and a bench exit code that reports a failed line."""
+cache that is placeable from outside, and a visible DataLoader
+transport."""
 
-import json
 import os
 
 import numpy as np
@@ -134,33 +133,6 @@ def test_dataloader_transport_shows_the_queue_fallback(monkeypatch):
                         use_shared_memory=True)
     assert len(list(loader)) == 2
     assert loader.transport == "queue"          # fell back, and says so
-
-
-# -- bench exit code --------------------------------------------------------
-def test_bench_main_exits_nonzero_when_any_line_raises(monkeypatch,
-                                                       capsys):
-    import bench
-
-    def boom():
-        raise RuntimeError("line failed")
-    for name in dir(bench):
-        if name.endswith("_line") and name not in ("_error_line",
-                                                   "_snapshot_line"):
-            monkeypatch.setattr(bench, name, lambda: {"metric": "m",
-                                                      "value": 1})
-    monkeypatch.setattr(bench, "_resnet_line", boom)
-    monkeypatch.setattr(bench, "_init_devices",
-                        lambda: (jax.devices(), None))
-    monkeypatch.setattr(bench, "_snapshot_line", lambda: {"metric": "s"})
-    monkeypatch.setattr(
-        "paddle_tpu.framework.compile_cache.enable_compile_cache",
-        lambda: None)
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code == 1
-    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
-    assert sum("error" in (ln.get("extra") or {}) for ln in lines) == 1
-    assert len(lines) == 18                     # 17 lines + the snapshot
 
 
 # -- spawned replicas -------------------------------------------------------

@@ -234,3 +234,54 @@ def test_flagship_vpp_matches_flat():
                         jax.tree_util.tree_leaves(g_flat["blocks"])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("policy", ["full", "flash"])
+def test_remat_policy_keeps_loss_and_gradients(policy, kv_heads):
+    """Where the checkpoint boundaries lie changes what is recomputed,
+    never a value: under ``full`` and under ``flash`` the loss and every
+    leaf's gradient are those of ``remat=False``.  ``full`` runs the
+    flash forward kernel a second time in the backward pass; ``flash``
+    keeps it outside the two checkpointed regions and runs it once."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama_pretrain import (
+        LlamaPretrainConfig, build_mesh, init_params, make_forward)
+
+    base = dict(vocab_size=64, hidden_size=256, intermediate_size=128,
+                num_hidden_layers=2, num_attention_heads=4,
+                num_key_value_heads=kv_heads, max_seq_len=128,
+                use_pallas_attention=True, sequence_parallel=False,
+                dtype=jnp.float32)
+    mesh = build_mesh(devices=jax.devices()[:1])
+    toks = jnp.asarray(np.random.RandomState(2).randint(0, 64, (2, 129)))
+
+    def loss_and_grads(**kw):
+        cfg = LlamaPretrainConfig(**base, **kw)
+        with mesh:
+            params = init_params(cfg, jax.random.PRNGKey(0), mesh)
+            fn = jax.value_and_grad(make_forward(cfg, mesh))
+            runs = len(re.findall(r"name=flash_fwd\b",
+                                  str(jax.make_jaxpr(fn)(params, toks))))
+            return jax.jit(fn)(params, toks), runs
+
+    (want, want_g), _ = loss_and_grads(remat=False)
+    (got, got_g), flash_fwd_runs = loss_and_grads(remat=True,
+                                                  remat_policy=policy)
+    assert flash_fwd_runs == {"full": 2, "flash": 1}[policy]
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(got_g),
+            jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("policy", ["dots", "names", "cheap"])
+def test_remat_policy_refuses_a_retired_name(policy):
+    from paddle_tpu.models.llama_pretrain import LlamaPretrainConfig
+    with pytest.raises(ValueError, match="remat_policy"):
+        LlamaPretrainConfig(remat_policy=policy)

@@ -5,9 +5,8 @@ thread-safety, Prometheus exposition format), the structured-event
 ring (bounded, seq-tagged, chrome-trace export merged with profiler
 spans), end-to-end engine instrumentation (TTFT/TPOT/queue-wait
 samples, preemption + prefix-cache counters consistent with the
-engine's own bookkeeping), the comm-watchdog routing, the bench
-backend-init hard timeout, and the metric-name lint against
-docs/OBSERVABILITY.md.
+engine's own bookkeeping), the comm-watchdog routing, and the
+metric-name lint against docs/OBSERVABILITY.md.
 """
 
 import json
@@ -498,54 +497,6 @@ def test_comm_watchdog_reports_through_observability():
 
 
 # ---------------------------------------------------------------------------
-# bench backend-init hard timeout
-# ---------------------------------------------------------------------------
-def test_bench_init_survives_wedged_backend(capsys):
-    import bench
-
-    def wedged():
-        time.sleep(60)                        # simulated hung init
-
-    t0 = time.perf_counter()
-    devs, err = bench._init_devices(max_tries=2, base_delay=0.01,
-                                    attempt_timeout=0.2,
-                                    attempt_fn=wedged)
-    elapsed = time.perf_counter() - t0
-    assert devs is None
-    assert "timed out" in err
-    assert elapsed < 10, "a wedged attempt must not eat the budget"
-    # structured heartbeat per attempt on stderr
-    lines = [json.loads(l) for l in capsys.readouterr().err.splitlines()
-             if l.startswith("{")]
-    beats = [l for l in lines if l["event"] == "backend_init_attempt"]
-    assert len(beats) == 2
-    assert all(b["ok"] is False for b in beats)
-    assert beats[0]["attempt"] == 1 and beats[1]["attempt"] == 2
-
-
-def test_bench_init_retries_after_failure_then_succeeds(capsys):
-    import bench
-
-    calls = []
-
-    def flaky():
-        calls.append(1)
-        if len(calls) == 1:
-            raise RuntimeError("UNAVAILABLE: backend down")
-        return ["fake-device"]
-
-    devs, err = bench._init_devices(max_tries=3, base_delay=0.01,
-                                    attempt_timeout=5.0,
-                                    attempt_fn=flaky)
-    assert err is None and devs == ["fake-device"]
-    lines = [json.loads(l) for l in capsys.readouterr().err.splitlines()
-             if l.startswith("{")]
-    beats = [l for l in lines if l["event"] == "backend_init_attempt"]
-    assert [b["ok"] for b in beats] == [False, True]
-    assert "UNAVAILABLE" in beats[0]["error"]
-
-
-# ---------------------------------------------------------------------------
 # naming-convention lint
 # ---------------------------------------------------------------------------
 _UNITS = ("total", "seconds", "ratio", "count", "tokens", "pages",
@@ -559,7 +510,6 @@ def test_metric_names_lint():
     ``paddle_tpu_<subsystem>_<name>_<unit>`` and is documented in
     docs/OBSERVABILITY.md."""
     import os
-    import bench
     from paddle_tpu.distributed.communication import watchdog as W
     from paddle_tpu.inference import serving
 
@@ -575,7 +525,6 @@ def test_metric_names_lint():
     mgr = W.CommTaskManager(scan_interval=60)
     mgr.bind_metrics(reg, EventRing())
     mgr.shutdown()
-    bench._bench_metrics(reg)
     serving._http_metrics(reg)
 
     doc_path = os.path.join(os.path.dirname(__file__), "..", "docs",

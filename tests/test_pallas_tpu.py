@@ -156,20 +156,6 @@ def test_rms_norm_compiled(dtype, tol):
             assert abs(num - float(gx[idx])) < 1e-2
 
 
-def test_swiglu_compiled():
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas import swiglu
-    kk = jax.random.PRNGKey
-    g = jax.random.normal(kk(0), (64, 512), jnp.bfloat16)
-    u = jax.random.normal(kk(1), (64, 512), jnp.bfloat16)
-    out = jax.jit(swiglu)(g, u)
-    gf = np.asarray(g, np.float32)
-    uf = np.asarray(u, np.float32)
-    ref = gf / (1 + np.exp(-gf)) * uf
-    assert np.abs(np.asarray(out, np.float32) - ref).max() < 3e-2
-
-
 def test_fused_rope_compiled():
     import jax
     import jax.numpy as jnp
@@ -306,38 +292,3 @@ def test_paged_decode_attention_q8_compiled():
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
                                 ref.astype(jnp.float32))))
     assert err < 3e-2, err
-
-
-def _round_bf16(a):
-    return np.asarray(jnp.asarray(a, jnp.float32).astype(jnp.bfloat16),
-                      np.float32)
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-3),
-                                       (jnp.bfloat16, 3e-2)])
-def test_rmsnorm_matmul_compiled(dtype, tol):
-    """Fused block-entry rms_norm->matmul (round-5 lever) through the
-    real Mosaic compiler: fwd parity vs a host composite, plus grads on
-    the f32 lane.  The MXU multiplies bf16 operands in one pass with
-    f32 accumulation whatever the operand dtype, so the host composite
-    rounds where the kernel does: ``x * wl`` and ``w``, with ``rstd``
-    applied after the product.  Read on the chip as shares of max|ref|
-    (PR 22): against a plain f32 host composite the kernel is off by
-    2.62e-3 and XLA's own default-precision composite by 2.22e-3 (at
-    ``highest`` precision by 4e-7) — the bf16 pass, not the kernel."""
-    from paddle_tpu.ops.pallas.rmsnorm_matmul import rmsnorm_matmul
-    kk = jax.random.PRNGKey
-    x = jax.random.normal(kk(0), (64, 512), dtype)
-    wl = (jax.random.normal(kk(1), (512,), jnp.float32) * 0.1 + 1.0)
-    w = jax.random.normal(kk(2), (512, 256), dtype) * 0.05
-    out = np.asarray(rmsnorm_matmul(x, wl.astype(dtype),
-                                    w), np.float32)
-    xf = np.asarray(x, np.float32)
-    rstd = 1.0 / np.sqrt((xf ** 2).mean(-1, keepdims=True) + 1e-6)
-    y = xf * np.asarray(wl.astype(dtype), np.float32)
-    ref = (_round_bf16(y) @ _round_bf16(w)) * rstd
-    assert np.abs(out - ref).max() < tol * max(1.0, np.abs(ref).max())
-    if dtype == jnp.float32:
-        g = jax.grad(lambda *a: (rmsnorm_matmul(*a) ** 2).sum(),
-                     argnums=(0, 1, 2))(x, wl, w)
-        assert all(np.isfinite(np.asarray(t)).all() for t in g)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 KERNEL = "tpu_custom_call"
-# the 1.345B block (bench.py / chip_smoke.py): widths are never cut
+# the 1.345B block (chip_smoke.py): widths are never cut
 VOCAB, HIDDEN, FFN, HEADS, HEAD_DIM, PAGE = 32000, 2048, 5504, 16, 128, 64
 
 

@@ -8,6 +8,7 @@ lowered text, spans from a trace the CPU backend records."""
 import ast
 import collections
 import glob
+import importlib.util
 import os
 import re
 
@@ -286,7 +287,8 @@ def test_every_scope_of_the_vocabulary_is_tested_somewhere():
 
 
 # -- Pallas kernels -----------------------------------------------------------
-def test_every_pallas_call_site_carries_a_distinct_name():
+def pallas_call_names() -> list:
+    """The ``name=`` of every ``pallas_call`` site under ops/pallas."""
     names = []
     for path in sorted(glob.glob(os.path.join(
             REPO, "paddle_tpu", "ops", "pallas", "*.py"))):
@@ -299,8 +301,29 @@ def test_every_pallas_call_site_carries_a_distinct_name():
                 assert "name" in kw, f"{path}:{node.lineno} has no name="
                 assert isinstance(kw["name"], ast.Constant)
                 names.append(kw["name"].value)
-    assert len(names) == 16 and len(set(names)) == 16
-    assert set(names) == set(xplane_meta.KERNELS)
+    return names
+
+
+def test_every_pallas_call_site_carries_a_distinct_name():
+    names = pallas_call_names()
+    assert len(names) == 13 and len(set(names)) == 13
+    # the readers' copy still lists the three names retired with their
+    # kernels (ROADMAP D14): a subset until a benchmark PR prunes it
+    assert set(names) <= set(xplane_meta.KERNELS)
+
+
+def test_the_kernels_the_entered_cell_reads_have_a_call_site():
+    """``flash_attn_roofline_pct.train`` sums the kernels its reader
+    names, and the by-kernel split of the train cell shows ``rope``: a
+    kernel renamed or deleted in the program would read as 0 there."""
+    spec = importlib.util.spec_from_file_location(
+        "flash_reader", os.path.join(
+            REPO, "benchmark", "layer_metrics",
+            "flash_attn_roofline_pct.train.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert len(reader.KERNELS) == 3
+    assert set(reader.KERNELS) | {"rope"} <= set(pallas_call_names())
 
 
 def test_a_kernel_name_reaches_the_op_path():

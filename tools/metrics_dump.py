@@ -13,7 +13,7 @@ Usage:
   python tools/metrics_dump.py transport http://127.0.0.1:8000
   python tools/metrics_dump.py traces  http://127.0.0.1:8000 [--min-ms N] [--status S]
   python tools/metrics_dump.py trace   http://127.0.0.1:8000 <rid>
-  python tools/metrics_dump.py snapshot bench_out.json
+  python tools/metrics_dump.py snapshot stats.json
 
 ``stats`` renders ``GET /stats`` (the JSON snapshot) as an aligned
 table; ``metrics`` dumps the raw Prometheus text from ``GET /metrics``;
@@ -39,10 +39,8 @@ age, reconnect/retry/heartbeat-miss counters and wire volume from
 retained trace index (``GET /traces`` — tail-sampled: slow/abnormal
 traces always kept) and ``trace`` renders one request's span tree
 (``GET /trace/<rid>``) with its phase-clock latency breakdown;
-``snapshot`` pretty-prints a snapshot
-previously written to a file
-(e.g. the ``metrics_snapshot`` line bench.py appends to BENCH_r*.json
-output).
+``snapshot`` pretty-prints a ``GET /stats`` document previously saved
+to a file.
 
 Stdlib only — usable on any host that can reach the server.
 """
@@ -445,98 +443,11 @@ def cmd_transport(args) -> int:
 
 
 def cmd_snapshot(args) -> int:
+    """A ``GET /stats`` document saved to a file, rendered as ``stats``
+    renders the live one."""
     with open(args.path) as f:
-        text = f.read()
-    # accept either a bare JSON document or JSON-lines output (bench):
-    # pick the line carrying a metrics snapshot
-    try:
-        doc = json.loads(text)
-    except json.JSONDecodeError:
-        doc = None
-        for line in text.splitlines():
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                obj = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if obj.get("metric") == "metrics_snapshot" or \
-                    "snapshot" in obj.get("extra", {}):
-                doc = obj
-        if doc is None:
-            print("no metrics snapshot found", file=sys.stderr)
-            return 1
-    snap = doc
-    # derived scalars bench.py writes next to the snapshot:
-    # host_overhead_frac (dispatch-ahead pipeline), the prefill
-    # padding-waste fraction, and the two-tier KV cache swap traffic
-    _DERIVED = ("host_overhead_frac", "prefill_padded_token_frac",
-                "swap_out_pages_total", "swap_in_pages_total",
-                "swap_bytes_total", "prefill_tokens_avoided_total",
-                "requests_faulted_total", "engine_restarts_total",
-                "requests_rejected_total",
-                # fleet tier (the serving_fleet_ab bench line's
-                # routers publish process-wide)
-                "fleet_failovers_total", "fleet_rejected_total",
-                "fleet_replica_deaths_total",
-                "fleet_replica_replaces_total",
-                # mixed prefill+decode lane (the serving_mixed_ab
-                # bench line's engine publishes process-wide)
-                "mixed_ticks_total",
-                "mixed_piggybacked_prefill_tokens_total",
-                # multi-token decode horizon (serving_horizon_ab):
-                # aggregate decode dispatches per generated token
-                # (~1/H when horizon engines dominate the window) +
-                # stop-sequence trim waste
-                "dispatches_per_token",
-                "horizon_trimmed_tokens_total",
-                # disaggregated prefill/decode (the serving_disagg_ab
-                # bench line's coordinator publishes process-wide)
-                "disagg_handoff_pages_total",
-                "disagg_handoff_bytes_total",
-                "disagg_colocated_fallback_total",
-                # tail-sampled trace store (the serving_trace_overhead
-                # bench line's tracer publishes process-wide)
-                "trace_retained_total", "trace_sampled_out_total",
-                # sockets transport (the serving_remote_ab bench
-                # line's socket-fleet arm publishes process-wide)
-                "transport_reconnects_total",
-                "transport_retries_total",
-                "transport_heartbeat_misses_total",
-                "transport_frames_total", "transport_bytes_total")
-    derived = {}
-    trace_ids = None
-    for key in ("extra", "snapshot", "metrics"):
-        if isinstance(snap, dict) and key in snap:
-            for name in _DERIVED:
-                if isinstance(snap.get(name), (int, float)):
-                    derived[name] = snap[name]
-            if isinstance(snap.get("trace_ids"), list):
-                trace_ids = snap["trace_ids"]
-            snap = snap[key]
-    print(_render_snapshot(snap))
-    if "prefill_padded_token_frac" not in derived \
-            and isinstance(snap, dict):
-        # derivable from a raw registry snapshot too: wasted prefill
-        # slots / dispatched packed-stream slots
-        padded = (snap.get(
-            "paddle_tpu_engine_prefill_padded_tokens_total") or {})
-        packed = (snap.get(
-            "paddle_tpu_engine_prefill_packed_tokens") or {})
-        if packed.get("sum"):
-            derived["prefill_padded_token_frac"] = \
-                (padded.get("value") or 0.0) / packed["sum"]
-    for name in _DERIVED:
-        if name in derived:
-            v = derived[name]
-            if name.endswith("_frac"):
-                print(f"{name} = {v:.4g}")
-            else:                       # exact page/byte/token counts
-                print(f"{name} = {int(v)}")
-    if trace_ids:
-        print("retained trace ids: " + " ".join(
-            str(t) for t in trace_ids))
+        body = json.load(f)
+    print(_render_snapshot(body.get("metrics", body)))
     return 0
 
 
