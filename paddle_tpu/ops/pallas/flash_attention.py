@@ -15,23 +15,35 @@ group``, so no repeated K/V exists anywhere and a K/V block is fetched
 once a group; ``flash_bwd_dkv`` sums a group's query heads into the
 shared dK/dV block in fp32 (innermost grid axis) and casts once.
 
-The kernels work on ONE head's ``[rows, dim]`` tiles with that head's
-K/V (forward, dq) or Q/dO (dkv) resident in VMEM (seq*dim*2B <= ~1MB at
-seq 4k, d 128 — well within the 16MB budget).  How a tile is ADDRESSED
-depends on ``dim`` alone (``_to_kernel``): with ``dim % 128 == 0`` the
-operands stay where the projections wrote them — ``[b, s, h, d]`` ->
-``[b, s, h*d]`` is a bitcast, and a ``(rows, d)`` block at (batch, row
-block, head) on the last axis is a legal Mosaic block — so nothing is
-transposed on the way in or out; any other ``dim`` (a lane slice of 32
-or 64 is not a block Mosaic takes) goes through one ``[b, h, s, d]``
-transpose an operand.  Same kernel bodies either way.
+The backward visits a (q block, k block) pair ONCE where it can
+(``ONE_PASS_DQ_BYTES``): ``flash_bwd_dkv`` holds dS^T for every pair it
+visits, so it adds dS K to the fp32 dQ of the KV head's whole group in
+VMEM scratch as well, forms delta = rowsum(dO*O) itself from ``o``, and
+returns (dk, dv, dq) — five block products and one exp pass a pair;
+``flash_bwd_dq`` does not run and no delta array exists.  The choice is
+a function of the shapes alone: where group*S*d*4 B of dQ is past the
+budget (long rows, wide groups) ``flash_bwd_dq`` (S, dP, dQ, and delta)
+runs first and ``flash_bwd_dkv`` does what it did — seven products and
+two exp passes a pair.  One kernel body either way.
 
-The per-row statistics (logsumexp, and delta = rowsum(dO*O), which
-``flash_bwd_dq`` forms from the tiles it already holds) live as ``[b, h,
-s/block, 1, block]``: a block's positions on LANES, the blocks on an
-untiled axis, so a kernel takes one block by its index whatever the
-block's size.  A ``[.., s, 1]`` array is 128x padded in the tiled layout
-and was re-laid out by XLA between the kernels.
+The kernels work on ONE head's ``[rows, dim]`` tiles with that head's
+K/V (forward, dq) or Q/dO (dkv; in one pass also O, beside the group's
+dq block) resident in VMEM (seq*dim*2B <= ~1MB at seq 4k, d 128; a call
+whose resident operands pass Mosaic's 16 MiB asks for its sum).  How a
+tile is ADDRESSED depends on ``dim`` alone (``_to_kernel``): with ``dim
+% 128 == 0`` the operands stay where the projections wrote them — ``[b,
+s, h, d]`` -> ``[b, s, h*d]`` is a bitcast, and a ``(rows, d)`` block
+at (batch, row block, head) on the last axis is a legal Mosaic block —
+so nothing is transposed on the way in or out; any other ``dim`` (a
+lane slice of 32 or 64 is not a block Mosaic takes) goes through one
+``[b, h, s, d]`` transpose an operand.  Same kernel bodies either way.
+
+The per-row statistics (logsumexp, and delta where it is an array:
+``flash_bwd_dq`` forms it from the tiles it already holds) live as
+``[b, h, s/block, 1, block]``: a block's positions on LANES, the blocks
+on an untiled axis, so a kernel takes one block by its index whatever
+the block's size.  A ``[.., s, 1]`` array is 128x padded in the tiled
+layout and was re-laid out by XLA between the kernels.
 """
 
 from __future__ import annotations
@@ -62,6 +74,12 @@ def _scores(a, b):
 
 def _matmul(a, b):
     return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _matmul_t(a, b):
+    """``a.T @ b``: the product contracts the LEFT operand's rows."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
@@ -160,35 +178,69 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     delta_ref[:] = delta.T
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                    sm_scale: float, block_q: int):
-    # k/v/dk/dv: [Bk, d] of one KV head; q/do: [S, d], lse/delta:
-    # [S/Bq, 1, Bq] of ONE query head of its group (grid axis 3,
-    # innermost).  Scores are formed TRANSPOSED, keys on rows: a block's
-    # [1, Bq] statistics then broadcast along sublanes as they lie, and
-    # dV = P^T dO, dK = dS^T Q are plain products
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
+                    causal: bool, sm_scale: float, block_q: int,
+                    also_dq: bool):
+    # k/v/dk/dv: [Bk, d] of one KV head; q/do: [S, d], lse: [S/Bq, 1, Bq]
+    # of ONE query head of its group (grid axis 3, innermost).  Scores
+    # are formed TRANSPOSED, keys on rows: a block's [1, Bq] statistics
+    # then broadcast along sublanes as they lie, and dV = P^T dO,
+    # dK = dS^T Q are plain products.
+    #
+    # Two kernels (``also_dq`` false): last_ref is the head's delta,
+    # [S/Bq, 1, Bq], and dQ is ``flash_bwd_dq``'s.  ONE pass: last_ref is
+    # the head's ``o`` [S, d], from which the head's first grid step
+    # forms delta; every block pair adds dS K to the fp32 dQ of the whole
+    # GROUP in scratch (a head's dQ is not revisited on consecutive grid
+    # steps, so it cannot be an output block of its own), and the KV
+    # head's last grid step casts it into dq_ref, the group's
+    # [S, group*d] (flat) or [group, S, d] block.
     ki = pl.program_id(2).astype(jnp.int32)
-    g = pl.program_id(3)
+    g = pl.program_id(3).astype(jnp.int32)
     Bk, d = k_ref.shape
     S = q_ref.shape[0]
     k = k_ref[:]
     v = v_ref[:]
+    if also_dq:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, delta_ref = refs
+        stat0 = g * (S // block_q)      # the head's blocks of the group's
+
+        @pl.when(ki == 0)
+        def _first_visit():
+            # the first k block meets every q block of the head
+            dq_acc[g] = jnp.zeros((S, d), jnp.float32)
+
+            def form_delta(qi, _):
+                rows = pl.ds(qi * block_q, block_q)
+                delta_ref[stat0 + qi] = jnp.sum(
+                    do_ref[rows, :].astype(jnp.float32)
+                    * last_ref[rows, :].astype(jnp.float32),
+                    axis=1, keepdims=True).T
+                return _
+            jax.lax.fori_loop(jnp.int32(0), jnp.int32(S // block_q),
+                              form_delta, None)
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
+        delta_ref, stat0 = last_ref, jnp.int32(0)
 
     def body(qi, carry, masked):
         dk, dv = carry
-        q = q_ref[pl.ds(qi * block_q, block_q), :]
-        do = do_ref[pl.ds(qi * block_q, block_q), :]
+        rows = pl.ds(qi * block_q, block_q)
+        q = q_ref[rows, :]
+        do = do_ref[rows, :]
         lse = lse_ref[qi]
-        delta = delta_ref[qi]
+        delta = delta_ref[stat0 + qi]
         st = _scores(k, q) * jnp.float32(sm_scale)          # [Bk, Bq]
         if masked:
             st = jnp.where(_visible(qi * block_q, ki * Bk, st.shape, 1),
                            st, jnp.float32(NEG_INF))
         pt = jnp.exp(st - lse)
         dv = dv + _matmul(pt.astype(do.dtype), do)
-        dst = pt * (_scores(v, do) - delta) * jnp.float32(sm_scale)
-        dk = dk + _matmul(dst.astype(q.dtype), q)
+        dst = (pt * (_scores(v, do) - delta)
+               * jnp.float32(sm_scale)).astype(q.dtype)
+        dk = dk + _matmul(dst, q)
+        if also_dq:
+            dq_acc[g, rows, :] += _matmul_t(dst, k)
         return dk, dv
 
     # the group's first query head starts the fp32 sums, the others add
@@ -215,10 +267,35 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_acc[:] = dk
     dv_acc[:] = dv
 
-    @pl.when(g == pl.num_programs(3) - 1)
+    last_head = g == pl.num_programs(3) - 1
+
+    @pl.when(last_head)
     def _finish():
         dk_ref[:] = dk.astype(dk_ref.dtype)
         dv_ref[:] = dv.astype(dv_ref.dtype)
+
+    if also_dq:
+        @pl.when(last_head & (ki == pl.num_programs(2) - 1))
+        def _finish_dq():
+            if len(dq_ref.shape) == 3:
+                dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+            else:
+                # flat: a head is d lanes of the block, at a static offset
+                for h in range(dq_acc.shape[0]):
+                    dq_ref[:, h * d:(h + 1) * d] = \
+                        dq_acc[h].astype(dq_ref.dtype)
+
+
+# The backward runs in ONE pass (``flash_bwd_dkv`` sums dQ too and
+# ``flash_bwd_dq`` does not run) where the fp32 dQ of one KV head's
+# group, group*S*d*4 B, is at most this much VMEM scratch; longer rows
+# and wider groups keep the two kernels.  Measured at 2 MiB (S 2048,
+# group 2, d 128: 74.1 -> 53.2 ms a step) and, by the host's clock, at
+# 4 MiB (S 4096: 71.5 -> 49.6); compiled for a described v5e at 4 MiB
+# with S 8192 (PERF.md §6, PR 30).
+ONE_PASS_DQ_BYTES = 4 << 20
+# what Mosaic gives a kernel unless the call asks for more (v5e: of 128 MiB)
+_DEFAULT_VMEM_LIMIT = 16 << 20
 
 
 def _pick_blocks(S: int):
@@ -227,10 +304,11 @@ def _pick_blocks(S: int):
     non-dividing block floor-truncates the grid and leaves rows
     uninitialized).
 
-    512 is the only block measured at head dim 128 (PERF.md §6, PR 28;
+    512 is the only block measured at head dim 128 (PERF.md §6, PR 30;
     v5e, 8 rows of S=2048, 16 query / 8 KV heads, 18 layers a step):
-    ``flash_fwd`` 27.3 ms a run, ``flash_bwd_dq`` 32.8, ``flash_bwd_dkv``
-    41.3 — 29.3 % of the bf16 peak together.  Smaller blocks pay more
+    ``flash_fwd`` 27.3 ms a run, the one-pass ``flash_bwd_dkv`` 53.2
+    (PR 28's two kernels: ``flash_bwd_dq`` 32.8 + ``flash_bwd_dkv``
+    41.3) — 34.9 % of the bf16 peak together.  Smaller blocks pay more
     grid steps a row, larger ones more VMEM a step."""
     for b in (512, 256, 128, 64, 32, 16, 8):
         if S % b == 0:
@@ -388,47 +466,87 @@ def _flash_fwd(q, k, v, causal):
     return _from_kernel(out, h), (qr, kr, vr, out, lse)
 
 
+def _group_spec(s, group, d):
+    """BlockSpec of ALL positions of the ``group`` query heads of one KV
+    head of a ``_to_kernel`` array, on ``flash_bwd_dkv``'s grid: ``[s,
+    group*d]`` (flat) or ``[group, s, d]``.  Its index holds over the
+    k blocks and the group, so the block stays in VMEM until the KV head
+    is done."""
+    if d % 128 == 0:
+        return pl.BlockSpec((None, s, group * d),
+                            lambda i, j, r, g: idx32(i, 0, j))
+    return pl.BlockSpec((None, group, s, d),
+                        lambda i, j, r, g: idx32(i, j, 0, 0))
+
+
 def _flash_bwd_vjp(causal, res, dout):
     qr, kr, vr, out, lse = res
     b, s, h, d = dout.shape
     nkv = kr.size // (b * s * d)
+    group = h // nkv
     sm_scale = 1.0 / math.sqrt(d)
     do = _to_kernel(dout)
     bq, bk = _pick_blocks(s)
     interp = _common.interpret()
+    dq_shape = jax.ShapeDtypeStruct(qr.shape, qr.dtype)
 
-    tile, kv = _by_query_head(h // nkv)
-    dq, delta = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal,
-                          sm_scale=sm_scale, block_k=bk),
-        out_shape=(jax.ShapeDtypeStruct(qr.shape, qr.dtype),
-                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
-        grid=(b, h, s // bq),
-        in_specs=[_tile_spec(bq, d, tile), _tile_spec(s, d, kv),
-                  _tile_spec(s, d, kv), _tile_spec(bq, d, tile),
-                  _tile_spec(bq, d, tile), _stat_spec(None, bq, tile)],
-        out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
-        name="flash_bwd_dq",
-        interpret=interp,
-    )(qr, kr, vr, out, do, lse)
+    tile, head = _by_kv_head(group)
+    out_shape = [jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                 jax.ShapeDtypeStruct(vr.shape, vr.dtype)]
+    out_specs = [_tile_spec(bk, d, tile), _tile_spec(bk, d, tile)]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, d), jnp.float32)]
+    params = None
+    one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
+    if one_pass:
+        # o in delta's place; dq a third output, the group's block; the
+        # group's fp32 dQ and delta in scratch
+        last, last_spec = out, _tile_spec(s, d, head)
+        out_shape.append(dq_shape)
+        out_specs.append(_group_spec(s, group, d))
+        scratch += [pltpu.VMEM((group, s, d), jnp.float32),
+                    pltpu.VMEM((group * (s // bq), 1, bq), jnp.float32)]
+        # q, do, o and the group's dq block twice (the pipeline's two
+        # buffers) beside the fp32 dQ; the rest read 4.8 MiB at most
+        # (AOT for a described v5e, S 4096 and 8192)
+        resident = (2 * (3 + group) * qr.dtype.itemsize + 4 * group) \
+            * s * d + (8 << 20)
+        if resident > _DEFAULT_VMEM_LIMIT:
+            params = pltpu.CompilerParams(vmem_limit_bytes=resident)
+    else:
+        by_q, kv = _by_query_head(group)
+        dq, last = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, causal=causal,
+                              sm_scale=sm_scale, block_k=bk),
+            out_shape=(dq_shape,
+                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
+            grid=(b, h, s // bq),
+            in_specs=[_tile_spec(bq, d, by_q), _tile_spec(s, d, kv),
+                      _tile_spec(s, d, kv), _tile_spec(bq, d, by_q),
+                      _tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)],
+            out_specs=(_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)),
+            name="flash_bwd_dq",
+            interpret=interp,
+        )(qr, kr, vr, out, do, lse)
+        last_spec = _stat_spec(s // bq, bq, head)
 
-    tile, head = _by_kv_head(h // nkv)
-    dk, dv = pl.pallas_call(
+    grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
-                          sm_scale=sm_scale, block_q=bq),
-        out_shape=(jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                   jax.ShapeDtypeStruct(vr.shape, vr.dtype)),
-        grid=(b, nkv, s // bk, h // nkv),
+                          sm_scale=sm_scale, block_q=bq, also_dq=one_pass),
+        out_shape=out_shape,
+        grid=(b, nkv, s // bk, group),
         in_specs=[_tile_spec(s, d, head), _tile_spec(bk, d, tile),
                   _tile_spec(bk, d, tile), _tile_spec(s, d, head),
-                  _stat_spec(s // bq, bq, head),
-                  _stat_spec(s // bq, bq, head)],
-        out_specs=(_tile_spec(bk, d, tile), _tile_spec(bk, d, tile)),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                  _stat_spec(s // bq, bq, head), last_spec],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        compiler_params=params,
         name="flash_bwd_dkv",
         interpret=interp,
-    )(qr, kr, vr, do, lse, delta)
+    )(qr, kr, vr, do, lse, last)
+    dk, dv = grads[:2]
+    if one_pass:
+        dq = grads[2]
 
     return _from_kernel(dq, h), _from_kernel(dk, nkv), _from_kernel(dv, nkv)
 

@@ -2,6 +2,8 @@
 compile natively on TPU)."""
 
 import functools
+import importlib
+import re
 
 import numpy as np
 import jax
@@ -121,6 +123,76 @@ def test_flash_attention_block_choice(s, pallas):
                                atol=2e-5, rtol=2e-5)
     for a, b_ in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
                      jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
+
+
+def _flash_module():
+    """The module, not the function the package re-exports by its name."""
+    return importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+
+def _flash_inputs(seed, b, s, h, nkv, d):
+    """q, k, v and the cotangent's weights, fp32."""
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.normal(0, 1, (b, s, n, d)), jnp.float32)
+            for n in (h, nkv, nkv, h)]
+
+
+def _flash_grads(fn, q, k, v, w, causal):
+    """(dq, dk, dv) of sum(fn(q, k, v, causal) * w), and the flash
+    kernels the whole forward + backward program calls, in order."""
+    grad = jax.grad(lambda *a: (fn(*a, causal) * w).sum(),
+                    argnums=(0, 1, 2))
+    kernels = re.findall(r"\bname=(flash_\w+)",
+                         str(jax.make_jaxpr(grad)(q, k, v)))
+    return grad(q, k, v), kernels
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("h,nkv,d,s", [
+    (2, 2, 128, 512),       # group 1, flat, ONE 512-block
+    (4, 2, 128, 1024),      # group 2, flat, two 512-blocks
+    (4, 1, 128, 192),       # group 4, flat, three 64-blocks
+    (4, 2, 64, 576),        # group 2, transposed entry, nine 64-blocks
+    (2, 2, 64, 1024),       # group 1, transposed entry, two 512-blocks
+    (4, 1, 64, 512),        # group 4, transposed entry, one block
+])
+def test_flash_backward_one_pass_parity(monkeypatch, causal, h, nkv, d, s):
+    """The backward in one pass — ``flash_bwd_dkv`` sums dQ too and forms
+    delta from ``o``; ``flash_bwd_dq`` does not run — against autodiff of
+    the XLA attention AND against the two kernels on the same inputs
+    (the budget set to 0 bytes: the module constant, no flag)."""
+    fa = _flash_module()
+    args = _flash_inputs(h * 1000 + nkv * 100 + d + s, 1, s, h, nkv, d)
+    assert (h // nkv) * s * d * 4 <= fa.ONE_PASS_DQ_BYTES
+    one, kernels = _flash_grads(fa.flash_attention, *args, causal)
+    assert kernels == ["flash_fwd", "flash_bwd_dkv"]
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    two, kernels = _flash_grads(fa.flash_attention, *args, causal)
+    assert kernels == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    want, _ = _flash_grads(fa._xla_sdpa, *args, causal)
+    for name, a, b_, c in zip(("dq", "dk", "dv"), one, two, want):
+        assert a.shape == c.shape, name
+        # a re-ordered fp32 sum at most
+        np.testing.assert_allclose(a, b_, atol=2e-5, rtol=2e-5, err_msg=name)
+        np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("fits", [True, False])
+def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits):
+    """One pass where a group's fp32 dQ, group*S*d*4 B, is within
+    ``ONE_PASS_DQ_BYTES``; a byte past it the two kernels run, with the
+    same gradients."""
+    fa = _flash_module()
+    h, nkv, d, s = 4, 2, 128, 256
+    args = _flash_inputs(7, 2, s, h, nkv, d)
+    need = (h // nkv) * s * d * 4
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", need if fits else need - 1)
+    got, kernels = _flash_grads(fa.flash_attention, *args, True)
+    assert kernels == ["flash_fwd"] + ["flash_bwd_dq"] * (not fits) \
+        + ["flash_bwd_dkv"]
+    want, _ = _flash_grads(fa._xla_sdpa, *args, True)
+    for a, b_ in zip(got, want):
         np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
 
 

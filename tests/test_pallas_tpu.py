@@ -60,11 +60,14 @@ def test_flash_bwd_parity():
 
 @pytest.mark.parametrize("s,h,nkv,d", [
     (1024, 4, 4, 128), (1024, 4, 2, 128), (1024, 4, 1, 128),
-    (1024, 4, 2, 64), (192, 4, 2, 128), (576, 4, 2, 64)])
+    (1024, 4, 2, 64), (192, 4, 2, 128), (576, 4, 2, 64),
+    (2048, 16, 8, 128)])
 def test_flash_gqa_fwd_bwd_parity(s, h, nkv, d):
     """K/V at their own head count through Mosaic: head dim 128
     addressed flat in [b, s, heads*d], 64 transposed.  1024 is two
-    512-row blocks; 192 and 576 run in blocks of 64 (three, nine)."""
+    512-row blocks; 192 and 576 run in blocks of 64 (three, nine); 2048
+    x 16 / 8 heads is the pretraining cell's own shape.  Every one of
+    them takes the backward in ONE pass (``flash_bwd_dkv`` sums dQ)."""
     from paddle_tpu.ops.pallas.flash_attention import (
         flash_attention, _xla_sdpa)
     kk = jax.random.PRNGKey
@@ -84,6 +87,31 @@ def test_flash_gqa_fwd_bwd_parity(s, h, nkv, d):
         assert got.shape == want.shape
         err = float(jnp.abs(got - want).max())
         assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
+
+
+@pytest.mark.parametrize("s,h,nkv,d", [(2048, 16, 8, 128), (576, 4, 2, 64)])
+def test_flash_backward_one_pass_matches_two_kernels(monkeypatch, s, h, nkv,
+                                                     d):
+    """The one-pass backward against ``flash_bwd_dq`` + ``flash_bwd_dkv``
+    (the VMEM rule set to 0 bytes) on the same bf16 inputs: dk and dv are
+    the same sums, dq the same terms from a product turned round."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    kk = jax.random.PRNGKey
+    q = jax.random.normal(kk(0), (2, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(kk(1), (2, s, nkv, d), jnp.bfloat16)
+    v = jax.random.normal(kk(2), (2, s, nkv, d), jnp.bfloat16)
+    w = jax.random.normal(kk(3), (2, s, h, d), jnp.bfloat16)
+
+    def grads():
+        _, vjp = jax.vjp(lambda *a: fa.flash_attention(*a, True), q, k, v)
+        return [g.astype(jnp.float32) for g in vjp(w)]
+
+    one = grads()
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    for got, want in zip(one, grads()):
+        err = float(jnp.abs(got - want).max())
+        assert err <= 2 ** -7 * float(jnp.abs(want).max()), err
 
 
 def test_flash_decode_and_odd_lengths():

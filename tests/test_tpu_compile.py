@@ -115,31 +115,47 @@ def test_paged_decode_attention(one_chip, compiled, kv_quant, nkv):
     assert KERNEL in text
 
 
-def test_flash_attention_fwd_bwd(one_chip, compiled):
+def _flash_module():
+    import importlib
+    return importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+
+@pytest.mark.parametrize("s,nkv,kernels", [
+    (2048, HEADS, 2),       # MHA: the backward in one pass
+    (4096, 8, 2),           # 4 MiB of fp32 dQ a group: asks for more VMEM
+    (8192, 8, 3)])          # 8 MiB: past the rule, flash_bwd_dq runs
+def test_flash_attention_fwd_bwd(one_chip, compiled, s, nkv, kernels):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    x = _sds(one_chip, (8, 2048, HEADS, HEAD_DIM), jnp.bfloat16)
+    q = _sds(one_chip, (2, s, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = _sds(one_chip, (2, s, nkv, HEAD_DIM), jnp.bfloat16)
     text = _text(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True).astype(
-            jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
-    assert text.count(KERNEL) >= 2          # forward + backward kernels
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count(KERNEL) == kernels
+    assert ("flash_bwd_dq" in text) == (kernels == 3)
 
 
+@pytest.mark.parametrize("kernels", [2, 3])
 @pytest.mark.parametrize("s,d", [(192, 128), (576, 128), (320, 64),
                                  (24, 32)])
-def test_flash_attention_small_blocks(one_chip, compiled, s, d):
+def test_flash_attention_small_blocks(one_chip, compiled, monkeypatch, s, d,
+                                      kernels):
     """Lengths whose largest dividing block is under 128 (64, 64, 64, 8)
-    stay on the kernels, GQA 4/2, causal and not: the statistics are
-    ``[b, h, s/block, 1, block]``, a block taken by its index on an
-    untiled axis, so Mosaic is never asked to prove a lane offset of 64
-    aligned."""
+    stay on the kernels, GQA 4/2, causal and not, the backward in one
+    pass (2 kernels) or, the VMEM rule set to 0 bytes, in two (3): the
+    statistics are ``[b, h, s/block, 1, block]``, a block taken by its
+    index on an untiled axis, so Mosaic is never asked to prove a lane
+    offset of 64 aligned."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    if kernels == 3:
+        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
     q = _sds(one_chip, (2, s, 4, d), jnp.bfloat16)
     kv = _sds(one_chip, (2, s, 2, d), jnp.bfloat16)
     for causal in (True, False):
         text = _text(jax.grad(
             lambda q, k, v: flash_attention(q, k, v, causal).astype(
                 jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
-        assert text.count(KERNEL) == 3
+        assert text.count(KERNEL) == kernels
 
 
 def _moved(text, elements):
@@ -155,16 +171,22 @@ def _moved(text, elements):
     return found
 
 
+@pytest.mark.parametrize("kernels", [2, 3])
 def test_flash_attention_gqa_reads_the_projections_where_they_lie(
-        one_chip, compiled):
+        one_chip, compiled, monkeypatch, kernels):
     """The pretraining cell's shape, 16 query / 8 KV heads of 128 at 8 x
     2048, as the train step has it: the projections' ``[b, s, heads*d]``
-    through rope and flash attention, forward and backward.  The three
-    flash kernels and rope's, and NOTHING that moves a K/V-sized array
-    between them: no transpose, no relayout copy or reshape, no GQA
-    broadcast (the kernels take ``head // group``)."""
+    through rope and flash attention, forward and backward.  The flash
+    kernels — ``flash_fwd`` and the one-pass ``flash_bwd_dkv``, with
+    ``flash_bwd_dq`` ABSENT (2 MiB of fp32 dQ a group fits); present
+    with the VMEM rule set to 0 bytes — and rope's, and NOTHING that
+    moves a K/V-sized array between them: no transpose, no relayout
+    copy or reshape, no GQA broadcast (the kernels take ``head //
+    group``)."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.rope import fused_rope, rope_tables
+    if kernels == 3:
+        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
     b, s, nkv = 8, 2048, 8
     q = _sds(one_chip, (b, s, HEADS * HEAD_DIM), jnp.bfloat16)
     kv = _sds(one_chip, (b, s, nkv * HEAD_DIM), jnp.bfloat16)
@@ -180,9 +202,10 @@ def test_flash_attention_gqa_reads_the_projections_where_they_lie(
         return (out,) + vjp(dout)
 
     text = _text(fwd_bwd, q, kv, kv, q)
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rope"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "rope"):
         assert kernel in text, kernel
-    assert text.count(KERNEL) == 3 + 4      # rope: q, k, dq, dk
+    assert ("flash_bwd_dq" in text) == (kernels == 3)
+    assert text.count(KERNEL) == kernels + 4    # rope: q, k, dq, dk
     assert not _moved(text, b * s * nkv * HEAD_DIM), \
         _moved(text, b * s * nkv * HEAD_DIM)
 

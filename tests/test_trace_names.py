@@ -104,17 +104,24 @@ def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
     return found
 
 
+@pytest.mark.parametrize("kernels", [3, 4])
 @pytest.mark.parametrize("heads,kv_heads,hidden,moves", [
     (16, 8, 2048, "reshape"),       # head dim 128: addressed where it lies
     (4, 2, 256, "transpose")])      # head dim 64: the transposing entry
 def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
-                                                   moves):
+                                                   moves, kernels,
+                                                   monkeypatch):
     """What the ``attn`` scope of the train step holds with the Pallas
     attention on and GQA: the flash kernels — forward, the recompute's
-    forward, dq (which forms delta itself), dkv — and the moves its
-    addressing needs: bitcast reshapes at head dim 128, transposes at
-    64.  K/V are never repeated: no ``broadcast_in_dim``, and nothing
-    sums a group back."""
+    forward, and the backward in ONE pass (``flash_bwd_dkv`` sums dQ too
+    and forms delta itself): THREE; past the VMEM rule, here the module
+    constant set to 0 bytes, ``flash_bwd_dq`` runs before it: four — and
+    the moves its addressing needs: bitcast reshapes at head dim 128,
+    transposes at 64.  K/V are never repeated: no ``broadcast_in_dim``,
+    and nothing sums a group back."""
+    flash = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    if kernels == 4:
+        monkeypatch.setattr(flash, "ONE_PASS_DQ_BYTES", 0)
     cfg = _cfg(hidden_size=hidden, num_attention_heads=heads,
                num_key_value_heads=kv_heads, num_hidden_layers=1,
                remat=True, loss_chunks=2, use_pallas_attention=True)
@@ -127,7 +134,7 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
         jaxpr = jax.make_jaxpr(step)(
             params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
     found = scope_primitives(jaxpr.jaxpr, "attn")
-    assert found["pallas_call"] == 4, found
+    assert found["pallas_call"] == kernels, found
     assert set(found) == {"pallas_call", moves}, found
 
 
