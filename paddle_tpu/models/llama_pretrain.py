@@ -74,10 +74,39 @@ class LlamaPretrainConfig:
     # 0/1 = plain log_softmax head.  The flattened token count
     # batch*(seq-1) must be divisible by the chunk count.
     loss_chunks: int = 0
+    # LAYERS BY KIND (models/hybrid_trunk.py): the kind of every layer in
+    # order, 'attention' (this file's block) or 'mamba' (a Mamba-2 mixer
+    # before the same MLP).  The tree is then ``blocks: {kind: {leaf:
+    # [layers of the kind, ...]}}``.  None: every layer is this file's
+    # block under ``blocks: {leaf}``, the program it has always been.
+    layer_types: Optional[Tuple[str, ...]] = None
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    # what a published configuration may state beside the widths; each
+    # default leaves the program's operations as they are
+    position_embedding_type: str = "rope"           # or 'nope'
+    attention_multiplier: Optional[float] = None    # None: 1/sqrt(head_dim)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False   # the head is the table: no lm_head
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
             self.num_key_value_heads = self.num_attention_heads
+        if self.layer_types is not None:
+            self.layer_types = tuple(
+                self.layer_types[:self.num_hidden_layers])
+            from . import hybrid_trunk
+            hybrid_trunk.check(self)
+        if self.position_embedding_type not in ("rope", "nope"):
+            raise ValueError(
+                f"position_embedding_type must be 'rope' or 'nope', "
+                f"got {self.position_embedding_type!r}")
         if self.remat_policy not in ("full", "flash"):
             raise ValueError(
                 f"remat_policy must be 'full' or 'flash', "
@@ -136,12 +165,20 @@ def param_specs(cfg: LlamaPretrainConfig, pp: int,
         stacked = ("pp", None)  # [pp, layers_per_stage, ...]
     else:
         stacked = (None,)       # [layers, ...]
-    return {
+    if cfg.layer_types is not None:
+        from . import hybrid_trunk
+        blocks = hybrid_trunk.block_specs(cfg)
+    else:
+        blocks = _block_specs(cfg, stacked)
+    specs = {
         "embed": P("mp", None),             # vocab-parallel embedding
-        "blocks": _block_specs(cfg, stacked),
+        "blocks": blocks,
         "final_norm": P(None),
         "lm_head": P(None, "mp"),           # vocab-parallel unembedding
     }
+    if cfg.tie_word_embeddings:
+        del specs["lm_head"]
+    return specs
 
 
 def init_params(cfg: LlamaPretrainConfig, key, mesh: Mesh,
@@ -162,22 +199,25 @@ def init_params(cfg: LlamaPretrainConfig, key, mesh: Mesh,
             return (pp, L // pp) + shape
         return (L,) + shape
 
-    blocks = {}
-    for i, (name, shape) in enumerate(shapes.items()):
-        if name.startswith("ln"):
-            blocks[name] = jnp.ones(stacked_shape(shape), cfg.param_dtype)
-        else:
-            blocks[name] = (jax.random.normal(
-                keys[i], stacked_shape(shape), cfg.param_dtype) * std)
+    if cfg.layer_types is not None:
+        from . import hybrid_trunk
+        blocks = hybrid_trunk.init_blocks(cfg, keys[0])
+    else:
+        blocks = {
+            name: jnp.ones(stacked_shape(shape), cfg.param_dtype)
+            if name.startswith("ln") else jax.random.normal(
+                keys[i], stacked_shape(shape), cfg.param_dtype) * std
+            for i, (name, shape) in enumerate(shapes.items())}
     params = {
         "embed": jax.random.normal(keys[-2],
                                    (cfg.vocab_size, h),
                                    cfg.param_dtype) * std,
         "blocks": blocks,
         "final_norm": jnp.ones((h,), cfg.param_dtype),
-        "lm_head": jax.random.normal(keys[-1], (h, cfg.vocab_size),
-                                     cfg.param_dtype) * std,
     }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jax.random.normal(
+            keys[-1], (h, cfg.vocab_size), cfg.param_dtype) * std
     specs = param_specs(cfg, pp, vpp)
     return jax.tree_util.tree_map(
         lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
@@ -349,8 +389,13 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
         q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
         k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
         v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
-    with jax.named_scope("rope"):
-        q, k = _rope(q, k, cfg.rope_theta, mesh)
+        if cfg.attention_multiplier is not None:
+            # the kernels score at 1/sqrt(d): the rest of the
+            # configuration's own scale rides on q
+            q = q * (cfg.attention_multiplier * math.sqrt(d))
+    if cfg.position_embedding_type == "rope":
+        with jax.named_scope("rope"):
+            q, k = _rope(q, k, cfg.rope_theta, mesh)
     # GQA stays UN-repeated here: _attention's flash kernels, dense and
     # segmented, index kv heads by group natively (the whole point of
     # GQA — nkv heads of K/V HBM traffic, not n); the paths that need
@@ -362,6 +407,14 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
     return q, k, v
 
 
+def _residual(x, out, cfg):
+    """``x + residual_multiplier * out``, one rounding."""
+    if cfg.residual_multiplier == 1.0:
+        return x + out
+    return (x.astype(jnp.float32) + cfg.residual_multiplier *
+            out.astype(jnp.float32)).astype(x.dtype)
+
+
 def _block_post_attn(bp: Dict[str, Any], x, attn,
                      cfg: LlamaPretrainConfig):
     """Output projection + residual + FFN.  Weight entries may be plain
@@ -369,7 +422,8 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
     path) — see :func:`_mm`."""
     b, s, h = x.shape
     with jax.named_scope("attn_out"):
-        x = x + _mm(attn.reshape(b, s, h), bp["wo"], cfg.dtype)
+        x = _residual(x, _mm(attn.reshape(b, s, h), bp["wo"], cfg.dtype),
+                      cfg)
     with jax.named_scope("mlp"):
         return _ffn(bp, x, cfg)
 
@@ -380,7 +434,7 @@ def _ffn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
     y = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
     gate = jax.nn.silu(_mm(y, bp["w_gate"], dt))
     up = _mm(y, bp["w_up"], dt)
-    return x + _mm(gate * up, bp["w_down"], dt)
+    return _residual(x, _mm(gate * up, bp["w_down"], dt), cfg)
 
 
 def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
@@ -479,6 +533,10 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
                  pp: int = 1, microbatches: int = 1, vpp: int = 1):
     """Returns pure fn(params, tokens[B,S]) -> logits or loss parts."""
 
+    if cfg.layer_types is not None:
+        from . import hybrid_trunk
+        hybrid_trunk.check_layout(cfg, mesh, pp)
+
     def forward_loss(params, tokens, segment_ids=None):
         """``segment_ids`` [B, S] enables packed pretraining: attention
         stays within segments (segmented flash kernel) and the loss
@@ -499,7 +557,10 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
             seg_in = seg_all[:, :-1]
             seg_tg = seg_all[:, 1:]
         with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], inputs, axis=0).astype(dt)
+            x = jnp.take(params["embed"], inputs, axis=0)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * cfg.embedding_multiplier
+            x = x.astype(dt)
         cp_on = False
         if mesh is not None:
             cp_on = bool(cfg.context_parallel and
@@ -522,6 +583,12 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
             x = _trunk_pipeline(params["blocks"], x_mb, cfg, mesh, pp,
                                 vpp)
             x = x.reshape(B, *x.shape[2:])
+        elif cfg.layer_types is not None:
+            if seg_in is not None:
+                raise NotImplementedError(
+                    "packed segments with layers by kind: the state-space "
+                    "mixer has no reset at a document boundary")
+            x = hybrid_trunk.trunk(params["blocks"], x, cfg, mesh)
         else:
             x = _trunk_scan(params["blocks"], x, cfg, mesh, seg_in)
         with jax.named_scope("loss_head"):
@@ -533,11 +600,18 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
                     "head (masked chunked CE not implemented); at large "
                     "vocab this materialises full [B,S,V] logits",
                     stacklevel=2)
+            tied = cfg.tie_word_embeddings
+            head = params["embed"] if tied else params["lm_head"]
             if cfg.loss_chunks > 1 and seg_in is None:
                 from ..ops.chunked_loss import chunked_softmax_cross_entropy
                 return chunked_softmax_cross_entropy(
-                    x, params["lm_head"], targets, cfg.loss_chunks, dt)
-            logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+                    x, head, targets, cfg.loss_chunks, dt,
+                    1.0 / cfg.logits_scaling, tied)
+            if tied:
+                head = head.T
+            logits = (x @ head.astype(dt)).astype(jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
             logp = jax.nn.log_softmax(logits, -1)
             ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
             if seg_in is not None:
@@ -660,23 +734,33 @@ def init_adafactor_state(params, mesh: Optional[Mesh] = None,
             "moments": jax.tree_util.tree_map(make, params)}
 
 
-def _rms(x):
-    return jnp.sqrt(jnp.mean(jnp.square(x)) + 1e-30)
+def _rms(x, lead: int = 0):
+    """Root mean square of ONE tensor: of everything, or — for a leaf
+    that stacks tensors over its first ``lead`` axes — of each."""
+    axes = tuple(range(lead, x.ndim)) if lead else None
+    return jnp.sqrt(jnp.mean(jnp.square(x), axis=axes,
+                             keepdims=bool(lead)) + 1e-30)
 
 
 def adafactor_update(params, grads, state, lr=1e-2, weight_decay=0.0,
                      beta1: float = 0.0, clip_threshold=1.0, eps1=1e-30,
-                     eps2=1e-3, decay_pow=0.8):
+                     eps2=1e-3, decay_pow=0.8, stack_dims: int = 1):
     """One Adafactor step.  ``lr`` is the relative step size: the actual
     update is ``lr * max(eps2, rms(p)) * u_clipped`` (scale_parameter
     semantics), with beta2_t = 1 - t**-decay_pow (built-in warmup).
     ``beta1`` must match the ``init_adafactor_state`` value (momentum is
-    used iff the state carries an ``m`` slot)."""
+    used iff the state carries an ``m`` slot).
+
+    One LAYER's leaf is one Adafactor tensor: a leaf under ``blocks``
+    stacks its layers over its first ``stack_dims`` axes (1; 2 and 3
+    under a pipeline), and the update is clipped by the rms of each
+    layer's own update and scaled by the rms of each layer's own leaf,
+    as the second moment is already factored a layer at a time."""
     t = state["t"] + 1
     tf = t.astype(jnp.float32)
     beta2 = 1.0 - tf ** (-decay_pow)
 
-    def upd(p, g, st):
+    def upd(p, g, st, lead):
         if ("m" in st) != (beta1 > 0.0):
             raise ValueError(
                 f"beta1={beta1} disagrees with the optimizer state "
@@ -697,8 +781,8 @@ def adafactor_update(params, grads, state, lr=1e-2, weight_decay=0.0,
             v = beta2 * st["v"] + (1 - beta2) * g2
             new_st["v"] = v
             u = g * jax.lax.rsqrt(v)
-        u = u / jnp.maximum(1.0, _rms(u) / clip_threshold)
-        alpha = lr * jnp.maximum(eps2, _rms(p.astype(jnp.float32)))
+        u = u / jnp.maximum(1.0, _rms(u, lead) / clip_threshold)
+        alpha = lr * jnp.maximum(eps2, _rms(p.astype(jnp.float32), lead))
         step_ = alpha * u
         if "m" in st:
             m = beta1 * st["m"].astype(jnp.float32) + (1 - beta1) * step_
@@ -707,12 +791,13 @@ def adafactor_update(params, grads, state, lr=1e-2, weight_decay=0.0,
         new_p = p.astype(jnp.float32) * (1 - alpha * weight_decay) - step_
         return new_p.astype(p.dtype), new_st
 
-    flat_p, tree = jax.tree_util.tree_flatten(params)
+    with_path, tree = jax.tree_util.tree_flatten_with_path(params)
     flat_g = jax.tree_util.tree_leaves(grads)
     flat_s = tree.flatten_up_to(state["moments"])
     new_p, new_s = [], []
-    for p, g, st in zip(flat_p, flat_g, flat_s):
-        np_, ns = upd(p, g, st)
+    for (path, p), g, st in zip(with_path, flat_g, flat_s):
+        stacked = getattr(path[0], "key", None) == "blocks"
+        np_, ns = upd(p, g, st, stack_dims if stacked else 0)
         new_p.append(np_)
         new_s.append(ns)
     return (jax.tree_util.tree_unflatten(tree, new_p),
@@ -766,7 +851,8 @@ def make_train_step(cfg: LlamaPretrainConfig, mesh: Mesh, pp: int = 1,
             if optimizer == "adafactor":
                 params, opt_state = adafactor_update(
                     params, grads, opt_state, lr=lr,
-                    weight_decay=weight_decay, beta1=beta1)
+                    weight_decay=weight_decay, beta1=beta1,
+                    stack_dims=1 if pp == 1 else 2 if vpp == 1 else 3)
             else:
                 params, opt_state = adamw_update(
                     params, grads, opt_state, lr=lr,

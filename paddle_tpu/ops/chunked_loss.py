@@ -41,12 +41,15 @@ import jax
 import jax.numpy as jnp
 
 
-def _chunk_logits(xc, w, dt):
-    # bf16 inputs on the MXU, fp32 accumulation/output.
-    return jax.lax.dot_general(
+def _chunk_logits(xc, w, dt, scale=1.0, table=False):
+    # bf16 inputs on the MXU, fp32 accumulation/output.  ``table``: w is
+    # the [V, H] embedding table (a tied head), contracted over its
+    # columns where it lies; ``scale`` multiplies the fp32 logits.
+    logits = jax.lax.dot_general(
         xc.astype(dt), w.astype(dt),
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=(((1,), (1 if table else 0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    return logits if scale == 1.0 else logits * scale
 
 
 def _flatten(x, targets, num_chunks):
@@ -61,21 +64,27 @@ def _flatten(x, targets, num_chunks):
     return xf.reshape(num_chunks, C, H), tf.reshape(num_chunks, C), T
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def chunked_softmax_cross_entropy(x, w, targets, num_chunks: int = 8,
-                                  compute_dtype=jnp.bfloat16):
-    """Mean NLL of ``softmax(x @ w)`` at ``targets`` without materialising
-    the full logits tensor.
+                                  compute_dtype=jnp.bfloat16,
+                                  logits_scale: float = 1.0,
+                                  table: bool = False):
+    """Mean NLL of ``softmax(logits_scale * x @ w)`` at ``targets``
+    without materialising the full logits tensor.
 
-    x: [..., H] activations (any float dtype), w: [H, V] unembedding,
-    targets: [...] int labels; the leading dims are flattened and must be
-    divisible by ``num_chunks``.
+    x: [..., H] activations (any float dtype), w: [H, V] unembedding —
+    or, with ``table``, the [V, H] embedding table of a tied head, read
+    where it lies: dW then has the table's layout, and the caller's
+    autodiff adds the lookup's share to it —, targets: [...] int labels;
+    the leading dims are flattened and must be divisible by
+    ``num_chunks``.
     """
     xs, ts, T = _flatten(x, targets, num_chunks)
 
     def step(acc, inp):
         xc, tc = inp
-        logits = _chunk_logits(xc, w, compute_dtype)             # [C,V] f32
+        logits = _chunk_logits(xc, w, compute_dtype, logits_scale,
+                               table)                            # [C,V] f32
         lse = jax.scipy.special.logsumexp(logits, axis=-1)       # [C]
         tgt = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
         return acc + jnp.sum(lse - tgt), None
@@ -84,15 +93,16 @@ def chunked_softmax_cross_entropy(x, w, targets, num_chunks: int = 8,
     return total / T
 
 
-def _ce_fwd(x, w, targets, num_chunks, dt):
-    V = w.shape[1]
+def _ce_fwd(x, w, targets, num_chunks, dt, logits_scale=1.0, table=False):
+    V = w.shape[0 if table else 1]
     xs, ts, T = _flatten(x, targets, num_chunks)
-    scale = jnp.float32(1.0) / T
+    # d(loss)/d(x @ w): the mean's 1/T and the logits' own scale
+    scale = jnp.float32(logits_scale) / T
 
     def step(carry, inp):
         total, dw_acc = carry
         xc, tc = inp
-        logits = _chunk_logits(xc, w, dt)                        # [C,V] f32
+        logits = _chunk_logits(xc, w, dt, logits_scale, table)   # [C,V] f32
         m = jnp.max(logits, axis=-1, keepdims=True)
         e = jnp.exp(logits - m)
         s = jnp.sum(e, axis=-1, keepdims=True)
@@ -103,10 +113,11 @@ def _ce_fwd(x, w, targets, num_chunks, dt):
         d_logits_c = d_logits.astype(dt)
         dxc = jax.lax.dot_general(                               # [C,H]
             d_logits_c, w.astype(dt),
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            dimension_numbers=(((1,), (0 if table else 1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dwc = jax.lax.dot_general(                               # [H,V]
-            xc.astype(dt), d_logits_c,
+        dwc = jax.lax.dot_general(                       # [H,V]; [V,H]
+            *((d_logits_c, xc.astype(dt)) if table
+              else (xc.astype(dt), d_logits_c)),
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return ((total + jnp.sum(lse - tgt), dw_acc + dwc),
@@ -117,7 +128,7 @@ def _ce_fwd(x, w, targets, num_chunks, dt):
     return total / T, (dxs.reshape(x.shape), dw.astype(w.dtype))
 
 
-def _ce_bwd(num_chunks, dt, res, g):
+def _ce_bwd(num_chunks, dt, logits_scale, table, res, g):
     dx, dw = res
     return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None
 

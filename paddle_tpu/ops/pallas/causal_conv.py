@@ -1,0 +1,191 @@
+"""Pallas kernels for the causal depthwise convolution of a state-space
+mixer, with its SiLU: over the last ``K`` positions of every channel,
+zeros before the row,
+
+    out[t, c] = silu(bias[c] + sum_k w[c, k] x[t - (K-1) + k, c])
+
+x ``[b, s, C]`` is read once and out written once, a ``[rows, 128]``
+tile at a time; the ``K - 1`` positions before a tile come from a second
+8-row view of the same array (zeroed before the row's start), so the
+tiles are independent.  The taps are sublane rotations of the tile in
+VMEM: XLA's own form pads the row in fp32 and reads it ``K`` times.
+The backward forms the pre-activation again (also on the 8 positions
+after the tile, whose gradient reaches back into it), and gives dx, and
+dw and dbias summed over a row's tiles.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import _common
+from ._common import idx32
+
+__all__ = ["causal_conv_silu", "takes", "causal_conv_silu_xla"]
+
+F32 = jnp.float32
+LANES, HALO = 128, 8
+
+
+def causal_conv_silu_xla(x, w, bias):
+    """The same in plain ``jnp`` (fp32 inside, x's dtype out): the
+    fallback, and the kernels' yardstick.  w ``[C, K]``, bias ``[C]``."""
+    k, s = w.shape[1], x.shape[1]
+    xp = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
+    out = bias.astype(F32)
+    for i in range(k):
+        out = out + xp[:, i:i + s, :] * w[:, i].astype(F32)
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+def _rows(s: int):
+    for rows in (512, 256, 128, 64, 32, 16):
+        if s % rows == 0:
+            return rows
+    return None
+
+
+def takes(x, w) -> bool:
+    """Whole lane tiles of channels, a row tile that divides the row,
+    taps within the halo (and a row to spare for dbias)."""
+    return (x.shape[-1] % LANES == 0 and _rows(x.shape[1]) is not None
+            and w.shape[1] < HALO)
+
+
+def _roll(a, shift: int):
+    """Rows moved down by ``shift``, around the end.  The shift is pinned
+    to int32: under jax_enable_x64 a Python int lowers as i64, which
+    Mosaic's rotate refuses."""
+    return pltpu.roll(a, jnp.int32(shift), 0)
+
+
+def _pre(ext, w_ref, b_ref, taps):
+    """bias + sum_k w_k x[t - (K-1) + k] on every position of ``ext``
+    but its first 8 (the halo before, which only feeds the taps)."""
+    acc = b_ref[...] + w_ref[taps - 1:taps, :] * ext[HALO:]
+    for d in range(1, taps):
+        acc += w_ref[taps - 1 - d:taps - d, :] * \
+            _roll(ext, d)[HALO:]
+    return acc
+
+
+def _fwd_kernel(x_ref, before_ref, w_ref, b_ref, o_ref, *, taps: int):
+    first = pl.program_id(2) == 0
+    before = jnp.where(first, 0.0, before_ref[...].astype(F32))
+    ext = jnp.concatenate([before, x_ref[...].astype(F32)], axis=0)
+    o_ref[...] = jax.nn.silu(_pre(ext, w_ref, b_ref, taps)).astype(
+        o_ref.dtype)
+
+
+def _bwd_kernel(x_ref, before_ref, after_ref, g_ref, gafter_ref, w_ref,
+                b_ref, dx_ref, dwb_ref, *, taps: int):
+    blk = pl.program_id(2)
+    first, last = blk == 0, blk == pl.num_programs(2) - 1
+    rows = x_ref.shape[0]
+    before = jnp.where(first, 0.0, before_ref[...].astype(F32))
+    ext = jnp.concatenate([before, x_ref[...].astype(F32),
+                           after_ref[...].astype(F32)], axis=0)
+    pre = _pre(ext, w_ref, b_ref, taps)                 # [rows + 8, 128]
+    sig = jax.nn.sigmoid(pre)
+    dout = jnp.concatenate(
+        [g_ref[...].astype(F32),
+         jnp.where(last, 0.0, gafter_ref[...].astype(F32))], axis=0)
+    g = dout * sig * (1.0 + pre * (1.0 - sig))          # d silu
+    # dx[t] = sum_d w[K-1-d] g[t + d]: the tile's own and the 8 after
+    dx = w_ref[taps - 1:taps, :] * g[:rows]
+    for d in range(1, taps):
+        dx += w_ref[taps - 1 - d:taps - d, :] * \
+            _roll(g, rows + HALO - d)[:rows]
+    dx_ref[...] = dx.astype(dx_ref.dtype)
+
+    @pl.when(first)
+    def _start():
+        dwb_ref[...] = jnp.zeros_like(dwb_ref)
+    mine = g[:rows]
+    col = lambda a: jnp.sum(a, axis=0, keepdims=True)
+    dwb_ref[taps - 1:taps, :] += col(mine * ext[HALO:HALO + rows])
+    for d in range(1, taps):
+        dwb_ref[taps - 1 - d:taps - d, :] += col(
+            mine * _roll(ext, d)[HALO:HALO + rows])
+    dwb_ref[taps:taps + 1, :] += col(mine)
+
+
+def _specs(rows, s):
+    per = rows // HALO
+    blocks = s // HALO
+    tile = pl.BlockSpec((None, rows, LANES),
+                        lambda i, c, r: idx32(i, r, c))
+    before = pl.BlockSpec(
+        (None, HALO, LANES),
+        lambda i, c, r: idx32(i, jnp.maximum(jnp.int32(r) * per - 1, 0), c))
+    after = pl.BlockSpec(
+        (None, HALO, LANES),
+        lambda i, c, r: idx32(i, jnp.minimum((jnp.int32(r) + 1) * per,
+                                             blocks - 1), c))
+    taps = pl.BlockSpec((HALO, LANES), lambda i, c, r: idx32(0, c))
+    bias = pl.BlockSpec((1, LANES), lambda i, c, r: idx32(0, c))
+    return tile, before, after, taps, bias
+
+
+def _tables(w, bias):
+    """w ``[C, K]`` as ``[8, C]`` rows of taps (a tile's lanes are its
+    channels), bias ``[1, C]``, fp32."""
+    c, k = w.shape
+    return (jnp.pad(w.astype(F32).T, ((0, HALO - k), (0, 0))),
+            bias.astype(F32).reshape(1, c))
+
+
+@jax.custom_vjp
+def causal_conv_silu(x, w, bias):
+    """x ``[b, s, C]``, w ``[C, K]``, bias ``[C]`` -> like x."""
+    return _fwd(x, w, bias)[0]
+
+
+def _fwd(x, w, bias):
+    b, s, c = x.shape
+    rows = _rows(s)
+    tile, before, _, taps, bias_spec = _specs(rows, s)
+    wt, bt = _tables(w, bias)
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, taps=w.shape[1]),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        grid=(b, c // LANES, s // rows),
+        in_specs=[tile, before, taps, bias_spec],
+        out_specs=tile,
+        name="causal_conv_fwd",
+        interpret=_common.interpret(),
+    )(x, x, wt, bt)
+    return out, (x, w, bias)
+
+
+def _bwd(res, g):
+    x, w, bias = res
+    b, s, c = x.shape
+    k = w.shape[1]
+    rows = _rows(s)
+    tile, before, after, taps, bias_spec = _specs(rows, s)
+    wt, bt = _tables(w, bias)
+    g = g.astype(x.dtype)
+    dx, dwb = pl.pallas_call(
+        functools.partial(_bwd_kernel, taps=k),
+        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((b, HALO, c), F32)),
+        grid=(b, c // LANES, s // rows),
+        in_specs=[tile, before, after, tile, after, taps, bias_spec],
+        out_specs=(tile, pl.BlockSpec((None, HALO, LANES),
+                                      lambda i, ch, r: idx32(i, 0, ch))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="causal_conv_bwd",
+        interpret=_common.interpret(),
+    )(x, x, x, g, g, wt, bt)
+    dwb = jnp.sum(dwb, axis=0)                          # [8, C]
+    return dx, dwb[:k].T.astype(w.dtype), dwb[k].astype(bias.dtype)
+
+
+causal_conv_silu.defvjp(_fwd, _bwd)

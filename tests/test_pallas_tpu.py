@@ -320,3 +320,91 @@ def test_paged_decode_attention_q8_compiled():
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
                                 ref.astype(jnp.float32))))
     assert err < 3e-2, err
+
+
+def test_flash_gqa_8k_head_dim_64():
+    """The hybrid cell's attention layer: S 8192, 32 query / 8 KV heads
+    of 64 — the transposed entry, and ``group * S * d * 4`` = 8 MiB of
+    fp32 dQ, past ``ONE_PASS_DQ_BYTES``: the two-kernel backward.  The
+    composite holds [heads, S, S], so it is asked for one KV head's
+    group at a time (a group's gradients depend on no other)."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention, _xla_sdpa)
+    s, h, nkv, d = 8192, 32, 8, 64
+    kk = jax.random.PRNGKey
+    q = jax.random.normal(kk(0), (1, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(kk(1), (1, s, nkv, d), jnp.bfloat16)
+    v = jax.random.normal(kk(2), (1, s, nkv, d), jnp.bfloat16)
+    w = jax.random.normal(kk(3), (1, s, h, d), jnp.float32)
+    out, vjp = jax.vjp(lambda *a: flash_attention(*a, True).astype(
+        jnp.float32), q, k, v)
+    dq, dk, dv = (g.astype(jnp.float32) for g in vjp(w))
+    g = h // nkv
+    for j in (0, nkv - 1):
+        heads = slice(j * g, (j + 1) * g)
+        f32 = [x.astype(jnp.float32)
+               for x in (q[:, :, heads], k[:, :, j:j + 1], v[:, :, j:j + 1])]
+        want, ref_vjp = jax.vjp(lambda *a: _xla_sdpa(*a, True), *f32)
+        wants = [want] + list(ref_vjp(w[:, :, heads]))
+        gots = [out[:, :, heads], dq[:, :, heads], dk[:, :, j:j + 1],
+                dv[:, :, j:j + 1]]
+        for got, want in zip(gots, wants):
+            err = float(jnp.abs(got - want).max())
+            assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
+
+
+def _ssd_inputs(b, s, h, p, n, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    x = jax.random.normal(ks[0], (b, s, h, p), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h), jnp.float32)
+                         - 3.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (h,), jnp.float32, 0.0, 2.7))
+    B = (jax.random.normal(ks[3], (b, s, n), jnp.float32) * 0.3).astype(dtype)
+    C = (jax.random.normal(ks[4], (b, s, n), jnp.float32) * 0.3).astype(dtype)
+    return x, dt, A, B, C
+
+
+@pytest.mark.parametrize("b,s,h,p,n,q", [(2, 8192, 64, 64, 128, 256),
+                                         (1, 512, 4, 128, 64, 128)])
+def test_ssd_scan_kernels_match_the_jnp_form(b, s, h, p, n, q):
+    """``ssd_scan_fwd`` / ``ssd_scan_bwd`` through Mosaic at the hybrid
+    cell's own shape (2 x 8192, 64 heads of 64, state 128, chunk 256)
+    against the chunked ``jnp`` form, values and all five gradients."""
+    from paddle_tpu.ops import ssd_scan as op
+    from paddle_tpu.ops.pallas import ssd_scan as kernel
+    x, dt, A, B, C = _ssd_inputs(b, s, h, p, n, jnp.bfloat16)
+    cut = lambda a: a.reshape(b, s // q, q, *a.shape[2:])
+    xc, dtc, Bc, Cc = cut(x), cut(dt), cut(B), cut(C)
+    assert kernel.takes(xc, Bc)
+    w = jax.random.normal(jax.random.PRNGKey(9), xc.shape, jnp.float32)
+
+    def run(form):
+        def f(x, dt, A, B, C):
+            cum = jnp.cumsum(dt * A, axis=2)
+            return form(x, dt, cum, B, C).astype(jnp.float32)
+        out, vjp = jax.vjp(f, xc, dtc, A, Bc, Cc)
+        return [out] + [g.astype(jnp.float32) for g in vjp(w)]
+    for got, want in zip(run(kernel.ssd_chunked), run(op.ssd_chunked_xla)):
+        err = float(jnp.abs(got - want).max())
+        assert err < 3e-2 * max(1.0, float(jnp.abs(want).max())), err
+
+
+def test_causal_conv_kernels_match_the_jnp_form():
+    """``causal_conv_fwd`` / ``causal_conv_bwd`` through Mosaic at the
+    hybrid cell's shape: 2 x 8192 positions of 4352 channels, 4 taps."""
+    from paddle_tpu.ops.pallas import causal_conv as cc
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (2, 8192, 4352), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (4352, 4), jnp.float32, -0.5, 0.5)
+    bias = jax.random.uniform(ks[2], (4352,), jnp.float32, -0.5, 0.5)
+    r = jax.random.normal(ks[3], x.shape, jnp.float32)
+    assert cc.takes(x, w)
+
+    def run(form):
+        out, vjp = jax.vjp(lambda *a: form(*a).astype(jnp.float32),
+                           x, w, bias)
+        return [out] + [g.astype(jnp.float32) for g in vjp(r)]
+    for got, want in zip(run(cc.causal_conv_silu),
+                         run(cc.causal_conv_silu_xla)):
+        err = float(jnp.abs(got - want).max())
+        assert err < 2e-2 * max(1.0, float(jnp.abs(want).max())), err

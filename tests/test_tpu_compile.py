@@ -328,3 +328,90 @@ def test_train_step_dp2_mp2_sequence_parallel(topo, compiled, nkv):
     n_params = sum(int(np.prod(x.shape))
                    for x in jax.tree_util.tree_leaves(params))
     assert per_device < 0.75 * 4 * n_params   # sharded, not replicated
+
+
+# ---------------------------------------------------------------------------
+# layers by kind: the hybrid cell's kernels and step, at the cell's shapes
+# ---------------------------------------------------------------------------
+def test_ssd_scan_kernels_fwd_bwd(one_chip, compiled):
+    """``ssd_scan_fwd`` / ``ssd_scan_bwd`` at 2 x 8192 positions, 64
+    heads of 64, state 128, chunk 256: both lower through Mosaic, and no
+    ``[256, 256]`` matrix of a head is an array of the program."""
+    from paddle_tpu.ops.ssd_scan import ssd_scan
+    b, s, h, p, n = 2, 8192, 64, 64, 128
+    args = (_sds(one_chip, (b, s, h, p), jnp.bfloat16),
+            _sds(one_chip, (b, s, h), jnp.float32),
+            _sds(one_chip, (h,), jnp.float32),
+            _sds(one_chip, (b, s, n), jnp.bfloat16),
+            _sds(one_chip, (b, s, n), jnp.bfloat16))
+    text = _text(jax.value_and_grad(
+        lambda *a: jnp.square(ssd_scan(*a, chunk=256).astype(
+            jnp.float32)).sum(), argnums=(0, 1, 2, 3, 4)), *args)
+    assert text.count(KERNEL) == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert not re.search(r"\[[\d,]*256,256\]", text)
+
+
+def test_causal_conv_kernels_fwd_bwd(one_chip, compiled):
+    from paddle_tpu.ops.pallas.causal_conv import causal_conv_silu
+    args = (_sds(one_chip, (2, 8192, 4352), jnp.bfloat16),
+            _sds(one_chip, (4352, 4), jnp.float32),
+            _sds(one_chip, (4352,), jnp.float32))
+    text = _text(jax.value_and_grad(
+        lambda *a: jnp.square(causal_conv_silu(*a).astype(
+            jnp.float32)).sum(), argnums=(0, 1, 2)), *args)
+    assert text.count(KERNEL) == 2
+    assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
+
+
+def test_flash_attention_8k_head_dim_64(one_chip, compiled):
+    """The hybrid cell's one attention layer: 32 query / 8 KV heads of
+    64 at S 8192 — the transposed entry, the two-kernel backward."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q = _sds(one_chip, (2, 8192, 32, 64), jnp.bfloat16)
+    kv = _sds(one_chip, (2, 8192, 8, 64), jnp.bfloat16)
+    text = _text(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count(KERNEL) == 3 and "flash_bwd_dq" in text
+
+
+def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
+    """The step of ``granite-4.0-h-micro.pretrain-8k`` as the benchmark
+    builds it — depth 10 (five state-space layers, one attention layer,
+    four more), every published width, 2 x 8192 tokens — fits a
+    described v5e with no compiler rematerialization, runs the scan and
+    the convolution as kernels and holds no ``[256, 256]`` matrix."""
+    import functools
+    import operator
+    from benchmark import harness, models
+    from paddle_tpu.models.llama_pretrain import (
+        init_adafactor_state, make_train_step, param_specs)
+    cell = harness.find_cell("granite-4.0-h-micro.pretrain-8k")
+    job, fam = cell.traffic, cell.family
+    cfg = fam.build_cfg(cell.conf, train=True, job=job)
+    assert cfg.num_hidden_layers == 10 and (job["batch"], job["seq"]) == \
+        (2, 8192)
+    specs, shapes = param_specs(cfg, 1), fam.leaf_shapes(cfg)
+    with one_chip:
+        params = models.tree_of(shapes, lambda path: _sds(
+            one_chip, shapes[path], cfg.param_dtype,
+            functools.reduce(operator.getitem, path, specs)))
+        opt = jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape, x.dtype),
+            jax.eval_shape(init_adafactor_state, params))
+        step = make_train_step(cfg, one_chip, lr=job["lr"],
+                               weight_decay=job["weight_decay"],
+                               optimizer=job["optimizer"])
+        c = step.lower(params, opt, _sds(
+            one_chip, (job["batch"], job["seq"] + 1), jnp.int64)).compile()
+    text = c.as_text()
+    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
+                   "causal_conv_bwd", "flash_fwd", "flash_bwd_dq",
+                   "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    assert ".remat" not in text
+    assert not re.search(r"\[[\d,]*256,256\]", text)
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes == 3_813_571_072
+    assert ma.temp_size_in_bytes < 11.5 * 2**30

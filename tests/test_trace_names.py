@@ -85,6 +85,63 @@ def test_train_step_carries_the_scopes(accum, want):
     assert "jit(step)" in text
 
 
+def family_names() -> tuple:
+    """``(SCOPES, KERNELS)`` that the block families under
+    ``benchmark/models/`` add to the base vocabulary, all together."""
+    scopes, kernels = set(), set()
+    for path in glob.glob(os.path.join(REPO, "benchmark", "models",
+                                       "*.py")):
+        name = os.path.basename(path)[:-3]
+        if name == "__init__" or name.endswith("_reference"):
+            continue
+        mod = importlib.import_module(f"benchmark.models.{name}")
+        scopes.update(getattr(mod, "SCOPES", ()))
+        kernels.update(getattr(mod, "KERNELS", ()))
+    return scopes, kernels
+
+
+def test_train_step_by_kind_carries_the_family_s_scopes():
+    """Layers by kind: the state-space block's five scopes beside the
+    base vocabulary's, the attention kind with no ``rope`` (the
+    configuration states no rotation), every base scope of a train step
+    still there — so ``unscoped_pct.train`` stays honest."""
+    from benchmark.models import granite_hybrid
+    cfg = _cfg(remat=True, loss_chunks=2,
+               layer_types=("mamba", "attention", "mamba"),
+               num_hidden_layers=3, mamba_n_heads=2, mamba_d_head=64,
+               mamba_d_state=64, mamba_chunk_size=128,
+               position_embedding_type="nope", attention_multiplier=0.0625,
+               residual_multiplier=0.22, embedding_multiplier=12.0,
+               logits_scaling=8.0, tie_word_embeddings=True)
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = (BLOCK - {"rope"}) | {"embed", "layer_scan", "attn",
+                                 "loss_head", "optimizer"} \
+        | set(granite_hybrid.SCOPES)
+    assert want <= names, want - names
+    assert "rope" not in names
+    assert set(granite_hybrid.SCOPES) <= family_names()[0]
+    # the scan and the convolution run as their kernels, under their
+    # scopes, forward and backward
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    for scope, kernel in (("ssm_scan", "ssd_scan_fwd"),
+                          ("ssm_scan", "ssd_scan_bwd"),
+                          ("ssm_conv", "causal_conv_fwd"),
+                          ("ssm_conv", "causal_conv_bwd")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            xplane_meta.KERNELS + granite_hybrid.KERNELS) == kernel
+
+
 def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
     """The primitives of ``jaxpr`` (calls, scans and remat bodies
     walked, kernel bodies not) whose name stack holds ``scope``."""
@@ -313,10 +370,18 @@ def pallas_call_names() -> list:
 
 def test_every_pallas_call_site_carries_a_distinct_name():
     names = pallas_call_names()
-    assert len(names) == 13 and len(set(names)) == 13
+    assert len(names) == 17 and len(set(names)) == 17
     # the readers' copy still lists the three names retired with their
-    # kernels (ROADMAP D14): a subset until a benchmark PR prunes it
-    assert set(names) <= set(xplane_meta.KERNELS)
+    # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
+    # A kernel of ONE family's program is named by that family
+    # (``KERNELS`` of its module under benchmark/models/), not by the
+    # base vocabulary
+    own = family_names()[1]
+    assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
+                   "causal_conv_bwd"}
+    assert not own & set(xplane_meta.KERNELS)
+    assert set(names) <= set(xplane_meta.KERNELS) | own
+    assert own <= set(names)
 
 
 def test_the_kernels_the_entered_cell_reads_have_a_call_site():
@@ -331,6 +396,18 @@ def test_the_kernels_the_entered_cell_reads_have_a_call_site():
     spec.loader.exec_module(reader)
     assert len(reader.KERNELS) == 3
     assert set(reader.KERNELS) | {"rope"} <= set(pallas_call_names())
+
+
+def test_the_kernels_the_scan_reader_names_have_a_call_site():
+    """``ssd_scan_roofline_pct.train`` sums the two scan kernels."""
+    spec = importlib.util.spec_from_file_location(
+        "scan_reader", os.path.join(
+            REPO, "benchmark", "layer_metrics",
+            "ssd_scan_roofline_pct.train.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert set(reader.KERNELS) == {"ssd_scan_fwd", "ssd_scan_bwd"}
+    assert set(reader.KERNELS) <= set(pallas_call_names())
 
 
 def test_a_kernel_name_reaches_the_op_path():
