@@ -17,8 +17,12 @@ Mamba-2 mixer (Dao & Gu 2024) before the same MLP:
     y = ssd_scan(x, softplus(dt + dt_bias), -exp(A_log), B, C) + D * x
     h += residual_multiplier * (rms_norm(y * silu(z); gate_norm) . w_out)
 
-The scan is ``ops/ssd_scan.py``; its kernel takes the within-chunk part.
-What a layer is follows from the configuration alone.
+The scan is ``ops/ssd_scan.py``.  Its kernels and the convolution's read
+their operands where the step before left them — xBC inside the
+projection's output, x, B and C inside the convolution's — at channel
+offsets in their index maps, where those are whole lane tiles: no piece
+is sliced out for a kernel.  What a layer is follows from the
+configuration alone.
 """
 
 from __future__ import annotations
@@ -29,6 +33,7 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 KINDS = ("attention", "mamba")
@@ -152,7 +157,7 @@ def init_blocks(cfg, key) -> Dict[str, Dict[str, Any]]:
 # ---------------------------------------------------------------------------
 def _mamba_mixer(bp, v, cfg):
     from ..ops.pallas import causal_conv
-    from ..ops.ssd_scan import ssd_scan
+    from ..ops.ssd_scan import ssd_scan_xbc
     from .llama_pretrain import _rms_norm
     b, s, _ = v.shape
     dt_ = cfg.dtype
@@ -160,23 +165,33 @@ def _mamba_mixer(bp, v, cfg):
     d_inner, conv, _ = mamba_dims(cfg)
     with jax.named_scope("ssm_in_proj"):
         zxbcdt = v @ bp["w_in"].astype(dt_)
+        # the convolution's kernels read xBC inside zxbcdt where its
+        # offset is whole lane tiles; else out of a slice
+        at = d_inner if causal_conv.takes(zxbcdt, bp["conv_w"], d_inner) \
+            else 0
+        if at:
+            # a kernel takes an array row-major: the product is to leave
+            # it so (forward it would not, and XLA would copy all of it)
+            zxbcdt = with_layout_constraint(
+                zxbcdt, Layout(major_to_minor=(0, 1, 2)))
         z = zxbcdt[..., :d_inner]
-        xbc = zxbcdt[..., d_inner:d_inner + conv]
         dt = zxbcdt[..., d_inner + conv:]
     with jax.named_scope("ssm_conv"):
-        convolve = causal_conv.causal_conv_silu \
-            if causal_conv.takes(xbc, bp["conv_w"]) \
-            else causal_conv.causal_conv_silu_xla
-        xbc = convolve(xbc, bp["conv_w"], bp["conv_b"])
-        x = xbc[..., :d_inner].reshape(b, s, nh, p)
-        B = xbc[..., d_inner:d_inner + n]
-        C = xbc[..., d_inner + n:]
+        xbc = zxbcdt if at else zxbcdt[..., d_inner:d_inner + conv]
+        if causal_conv.takes(xbc, bp["conv_w"], at):
+            xbc = causal_conv.causal_conv_silu(xbc, bp["conv_w"],
+                                               bp["conv_b"], at)
+        else:
+            xbc = causal_conv.causal_conv_silu_xla(xbc, bp["conv_w"],
+                                                   bp["conv_b"])
     with jax.named_scope("ssm_scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) +
                              bp["dt_bias"].astype(jnp.float32))
         A = -jnp.exp(bp["A_log"].astype(jnp.float32))
-        y = ssd_scan(x, dt, A, B, C, cfg.mamba_chunk_size)
-        y = (y.astype(jnp.float32) + bp["D"].astype(jnp.float32)[:, None]
+        y, x = ssd_scan_xbc(xbc, dt, A, n, cfg.mamba_chunk_size)
+        x = x.reshape(b, s, nh, p)
+        y = (y.reshape(x.shape).astype(jnp.float32)
+             + bp["D"].astype(jnp.float32)[:, None]
              * x.astype(jnp.float32)).astype(dt_).reshape(b, s, d_inner)
     with jax.named_scope("ssm_gate_norm"):
         y = _rms_norm(y * jax.nn.silu(z), bp["gate_norm"], cfg.rms_norm_eps)

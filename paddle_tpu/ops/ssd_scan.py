@@ -23,7 +23,8 @@ along the row); else :func:`ssd_chunked_xla`, the same mathematics in
 ``jnp`` under autodiff, which does hold those matrices — chosen from
 shapes alone.  A row that is no whole number of chunks is padded at its
 end with ``dt = 0`` (no decay, no input), which changes no earlier
-output.
+output.  :func:`ssd_scan_xbc` takes x, B and C in the one array a
+Mamba-2 convolution leaves them in; the kernels then read them there.
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ssd_scan", "ssd_chunked_xla", "ssd_recurrence"]
+__all__ = ["ssd_scan", "ssd_scan_xbc", "ssd_chunked_xla", "ssd_recurrence"]
 
 F32 = jnp.float32
 
@@ -77,12 +78,37 @@ def ssd_scan(x, dt, A, B, C, chunk: int):
         ext = lambda a: jnp.pad(a, [(0, 0), (0, pad)] +
                                 [(0, 0)] * (a.ndim - 2))
         x, dt, B, C = ext(x), ext(dt), ext(B), ext(C)
-    nc = (s + pad) // chunk
-    cut = lambda a: a.reshape(b, nc, chunk, *a.shape[2:])
-    x, dt, B, C = cut(x), cut(dt.astype(F32)), cut(B), cut(C)
+    x, dt, B, C = (_cut(a, chunk) for a in (x, dt.astype(F32), B, C))
     cum = jnp.cumsum(dt * A.astype(F32), axis=2)            # [b,c,Q,H]
     form = kernel.ssd_chunked if kernel.takes(x, B) else ssd_chunked_xla
-    return form(x, dt, cum, B, C).reshape(b, nc * chunk, h, p)[:, :s]
+    return form(x, dt, cum, B, C).reshape(b, s + pad, h, p)[:, :s]
+
+
+def _cut(a, chunk):
+    """A row ``[b, s, ...]`` cut into its chunks ``[b, c, Q, ...]``."""
+    return a.reshape(a.shape[0], a.shape[1] // chunk, chunk, *a.shape[2:])
+
+
+def ssd_scan_xbc(xbc, dt, A, state: int, chunk: int):
+    """:func:`ssd_scan` on x, B and C as the convolution before the scan
+    leaves them, side by side in ONE array ``[b, s, H*P + 2N]`` -> y
+    and x, ``[b, s, H*P]`` each.  Where the kernels take them there
+    (``takes_xbc``: whole chunks, the state one lane tile) nothing is
+    sliced out for them, and the backward gives xbc's cotangent whole,
+    what is owed to the x returned here (the caller's skip reads it)
+    included; else the three are sliced and go the way of
+    :func:`ssd_scan`."""
+    from .pallas import ssd_scan as kernel
+    b, s, width = xbc.shape
+    h, d = dt.shape[-1], width - 2 * state
+    if s % chunk == 0 and kernel.takes_xbc(_cut(xbc, chunk), h, state):
+        dt = _cut(dt.astype(F32), chunk)
+        cum = jnp.cumsum(dt * A.astype(F32), axis=2)
+        return kernel.ssd_chunked_xbc(xbc, dt, cum, state)
+    x = xbc[..., :d]
+    y = ssd_scan(x.reshape(b, s, h, d // h), dt, A, xbc[..., d:d + state],
+                 xbc[..., d + state:], chunk)
+    return y.reshape(b, s, d), x
 
 
 def ssd_recurrence(x, dt, A, B, C):

@@ -408,3 +408,109 @@ def test_causal_conv_kernels_match_the_jnp_form():
                          run(cc.causal_conv_silu_xla)):
         err = float(jnp.abs(got - want).max())
         assert err < 2e-2 * max(1.0, float(jnp.abs(want).max())), err
+
+
+def _same_bits(got, want):
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert bool(jnp.all(a == b)), float(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+
+
+def test_causal_conv_reads_xbc_inside_the_in_projection():
+    """The hybrid cell's offsets: 4352 channels at lane tile 32 of a
+    ``[2, 8192, 8512]`` array (NaN in every other channel), through
+    Mosaic: bit for bit the kernels on the slice, and the ``jnp`` form
+    to rounding."""
+    from paddle_tpu.ops.pallas import causal_conv as cc
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    c, at, width = 4352, 4096, 8512
+    inside = (jnp.arange(width) >= at) & (jnp.arange(width) < at + c)
+    held = jnp.where(inside, jax.random.normal(ks[0], (2, 8192, width),
+                                               jnp.float32),
+                     jnp.nan).astype(jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (c, 4), jnp.float32, -0.5, 0.5)
+    bias = jax.random.uniform(ks[2], (c,), jnp.float32, -0.5, 0.5)
+    r = jax.random.normal(ks[3], (2, 8192, c), jnp.bfloat16)
+    assert cc.takes(held, w, at)
+
+    def run(form):
+        out, vjp = jax.vjp(form, held, w, bias)
+        return (out,) + vjp(r)
+    cut = lambda x: x[..., at:at + c]
+    got = run(lambda x, w, b: cc.causal_conv_silu(x, w, b, at))
+    _same_bits(got, run(lambda x, w, b: cc.causal_conv_silu(cut(x), w, b)))
+    want = run(lambda x, w, b: cc.causal_conv_silu_xla(cut(x), w, b))
+    for a, b in zip((got[0], cut(got[1])) + got[2:],
+                    (want[0], cut(want[1])) + want[2:]):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.abs(a - b).max())
+        assert err < 2e-2 * max(1.0, float(jnp.abs(b).max())), err
+
+
+def test_ssd_scan_reads_x_b_c_inside_the_convolution_s_output():
+    """x, B and C in ONE ``[2, 8192, 4352]`` array (x's 32 lane tiles, B
+    at tile 32, C at 33), x read again by a skip: y and the gradients
+    of the array (dx | dB | dC from one ``ssd_scan_bwd``, the skip's
+    share added as it stores), of dt, A and D — through Mosaic, bit for
+    bit the kernels on the three slices."""
+    from paddle_tpu.ops import ssd_scan as op
+    b, s, h, p, n, q = 2, 8192, 64, 64, 128, 256
+    x, dt, A, B, C = _ssd_inputs(b, s, h, p, n, jnp.bfloat16)
+    xbc = jnp.concatenate([x.reshape(b, s, h * p), B, C], -1)
+    D = jnp.linspace(0.5, 1.5, h, dtype=jnp.float32)
+    r = jax.random.normal(jax.random.PRNGKey(9), (b, s, h, p), jnp.bfloat16)
+
+    def sliced(xbc, dt, A):
+        x = xbc[..., :h * p]
+        y = op.ssd_scan(x.reshape(b, s, h, p), dt, A,
+                        xbc[..., h * p:h * p + n], xbc[..., h * p + n:], q)
+        return y.reshape(b, s, h * p), x
+
+    def run(scan):
+        def f(xbc, dt, A, D):
+            y, x = scan(xbc, dt, A)
+            x = x.reshape(b, s, h, p)
+            return (y.reshape(x.shape).astype(jnp.float32) + D[:, None]
+                    * x.astype(jnp.float32)).astype(xbc.dtype)
+        out, vjp = jax.vjp(f, xbc, dt, A, D)
+        return (out,) + vjp(r)
+    _same_bits(run(lambda *a: op.ssd_scan_xbc(*a, n, q)), run(sliced))
+
+
+def test_causal_conv_channel_tile_128_against_256(monkeypatch):
+    """The convolution's two kernels on the cell's 2 x 8192 x 4352 with
+    a 128- and a 256-lane channel tile: the same bits, and each one's
+    time a run (written to ``chiprun_out/causal_conv_tile.json``; the
+    module keeps, kernel by kernel, the one that was faster)."""
+    import json
+    import os
+    import time
+    from paddle_tpu.ops.pallas import causal_conv as cc
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(ks[0], (2, 8192, 4352), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (4352, 4), jnp.float32, -0.5, 0.5)
+    bias = jax.random.uniform(ks[2], (4352,), jnp.float32, -0.5, 0.5)
+    g = jax.random.normal(ks[3], x.shape, jnp.bfloat16)
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(30):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / 30 * 1e3
+    times, outs = {}, {}
+    for lanes in (128, 256):
+        monkeypatch.setattr(cc, "FWD_TILE", lanes)
+        monkeypatch.setattr(cc, "BWD_TILE", lanes)
+        fwd = jax.jit(lambda x, w, b: cc._fwd(x, w, b, 0)[0])
+        bwd = jax.jit(lambda x, w, b, g: cc._bwd(0, (x, w, b), g))
+        outs[lanes] = (fwd(x, w, bias),) + bwd(x, w, bias, g)
+        times[lanes] = {"fwd_ms": ms(fwd, x, w, bias),
+                        "bwd_ms": ms(bwd, x, w, bias, g)}
+    print("causal_conv channel tile:", times)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/causal_conv_tile.json", "w") as f:
+        json.dump(times, f)
+    _same_bits(outs[128], outs[256])

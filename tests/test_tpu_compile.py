@@ -333,32 +333,138 @@ def test_train_step_dp2_mp2_sequence_parallel(topo, compiled, nkv):
 # ---------------------------------------------------------------------------
 # layers by kind: the hybrid cell's kernels and step, at the cell's shapes
 # ---------------------------------------------------------------------------
-def test_ssd_scan_kernels_fwd_bwd(one_chip, compiled):
+# opcodes that only place data, and what may stand beside them in a fusion
+# that still computes nothing (a cotangent's pad-and-add among them)
+_PLACES = {"slice", "copy", "pad", "concatenate"}
+_IDLE = _PLACES | {"parameter", "constant", "bitcast", "convert", "add",
+                   "tuple", "get-tuple-element", "broadcast", "reshape"}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([a-z\-]+)\((.*)$")
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4}
+
+
+def _placed(text, rows, widths):
+    """Instructions of an optimized module that WRITE an array ``rows +
+    (one of widths,)`` to HBM and compute nothing: a bare slice, copy,
+    pad or concatenate, or a fusion of nothing else — what XLA puts
+    before a custom call that was handed a piece of an array, or a
+    layout it does not read.  Only a computation's own instructions
+    count: inside a fusion such an op moves nothing through HBM (a pad
+    fused into a matrix product's operand is free)."""
+    bodies, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            body = None
+        elif body is not None and _INSTRUCTION.match(line):
+            body.append(_INSTRUCTION.match(line).groups())
+    fused = set(re.findall(r"fusion\(.*?calls=%([^\s,]+)", text))
+    found = []
+    for name, instructions in bodies.items():
+        if name in fused:
+            continue
+        for result, dtype, dims, opcode, rest in instructions:
+            dims = tuple(int(n) for n in dims.split(",") if n)
+            if dims[:-1] != rows or dims[-1] not in widths \
+                    or dtype not in _BYTES:
+                continue
+            if opcode == "fusion":
+                callee = re.search(r"calls=%([^\s,]+)", rest).group(1)
+                inside = {op for *_, op, _ in bodies[callee]}
+                if not (inside <= _IDLE and inside & _PLACES):
+                    continue
+            elif opcode not in _PLACES:
+                continue
+            scope = re.search(r'op_name="([^"]*)"', rest)
+            found.append((result, opcode, dims[-1],
+                          scope.group(1).rsplit("/", 2)[-2:] if scope
+                          else None))
+    return found
+
+
+def test_placed_counts_what_is_written_and_computes_nothing():
+    """The counter on a module made by hand: a bare slice and a
+    pad-and-add fusion count, a pad inside a fusion that multiplies and
+    one fused into another fusion's body do not, nor another shape."""
+    text = """
+%pads (p0: bf16[2,64,128], p1: bf16[2,64,256]) -> bf16[2,64,256] {
+  %p0 = bf16[2,64,128]{2,1,0} parameter(0)
+  %c = bf16[] constant(0)
+  %pad.1 = bf16[2,64,256]{2,1,0} pad(%p0, %c), padding=0_0x0_0x0_128
+  %p1 = bf16[2,64,256]{2,1,0} parameter(1)
+  ROOT %add.1 = bf16[2,64,256]{2,1,0} add(%pad.1, %p1)
+}
+
+%scaled (p0: bf16[2,64,128]) -> bf16[2,64,256] {
+  %p0 = bf16[2,64,128]{2,1,0} parameter(0)
+  %c = bf16[] constant(0)
+  %pad.2 = bf16[2,64,256]{2,1,0} pad(%p0, %c), padding=0_0x0_0x0_128
+  ROOT %mul.1 = bf16[2,64,256]{2,1,0} multiply(%pad.2, %pad.2)
+}
+
+ENTRY %main (a: bf16[2,64,512], b: bf16[2,64,128]) -> bf16[2,64,256] {
+  %a = bf16[2,64,512]{2,1,0} parameter(0)
+  %b = bf16[2,64,128]{2,1,0} parameter(1)
+  %slice.1 = bf16[2,64,256]{2,1,0} slice(%a), slice={[0:2], [0:64], [0:256]}, metadata={op_name="jit(f)/ssm_in_proj/slice"}
+  %other = bf16[4,64,256]{2,1,0} slice(%a), slice={[0:2], [0:64], [0:256]}
+  %f.1 = bf16[2,64,256]{2,1,0} fusion(%b, %slice.1), kind=kLoop, calls=%pads, metadata={op_name="jit(f)/ssm_conv/add_any"}
+  ROOT %f.2 = bf16[2,64,256]{2,1,0} fusion(%b), kind=kLoop, calls=%scaled
+}
+"""
+    assert _placed(text, (2, 64), {256}) == [
+        ("slice.1", "slice", 256, ["ssm_in_proj", "slice"]),
+        ("f.1", "fusion", 256, ["ssm_conv", "add_any"])]
+
+
+# the hybrid cell's rows, and the widths only its mixer has: d_inner,
+# the convolution's channels, the in-projection
+ROWS_8K, MIXER_WIDTHS = (2, 8192), {4096, 4352, 8512}
+
+
+@pytest.mark.parametrize("in_place", [False, True])
+def test_ssd_scan_kernels_fwd_bwd(one_chip, compiled, in_place):
     """``ssd_scan_fwd`` / ``ssd_scan_bwd`` at 2 x 8192 positions, 64
     heads of 64, state 128, chunk 256: both lower through Mosaic, and no
-    ``[256, 256]`` matrix of a head is an array of the program."""
-    from paddle_tpu.ops.ssd_scan import ssd_scan
+    ``[256, 256]`` matrix of a head is an array of the program.  In
+    place: x, B and C inside the convolution's ``[2, 8192, 4352]``, x
+    read again by a skip — and nothing of that size is sliced, copied,
+    padded or joined around the two kernels."""
+    from paddle_tpu.ops.ssd_scan import ssd_scan, ssd_scan_xbc
     b, s, h, p, n = 2, 8192, 64, 64, 128
-    args = (_sds(one_chip, (b, s, h, p), jnp.bfloat16),
-            _sds(one_chip, (b, s, h), jnp.float32),
-            _sds(one_chip, (h,), jnp.float32),
-            _sds(one_chip, (b, s, n), jnp.bfloat16),
-            _sds(one_chip, (b, s, n), jnp.bfloat16))
-    text = _text(jax.value_and_grad(
-        lambda *a: jnp.square(ssd_scan(*a, chunk=256).astype(
-            jnp.float32)).sum(), argnums=(0, 1, 2, 3, 4)), *args)
+    dt = _sds(one_chip, (b, s, h), jnp.float32)
+    A = _sds(one_chip, (h,), jnp.float32)
+    if in_place:
+        def loss(xbc, dt, A):
+            y, x = ssd_scan_xbc(xbc, dt, A, n, 256)
+            return jnp.square((y + x).astype(jnp.float32)).sum()
+        args = (_sds(one_chip, (b, s, h * p + 2 * n), jnp.bfloat16), dt, A)
+    else:
+        loss = lambda *a: jnp.square(ssd_scan(*a, chunk=256).astype(
+            jnp.float32)).sum()
+        args = (_sds(one_chip, (b, s, h, p), jnp.bfloat16), dt, A,
+                _sds(one_chip, (b, s, n), jnp.bfloat16),
+                _sds(one_chip, (b, s, n), jnp.bfloat16))
+    text = _text(jax.value_and_grad(loss, argnums=tuple(range(len(args)))),
+                 *args)
     assert text.count(KERNEL) == 2
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
     assert not re.search(r"\[[\d,]*256,256\]", text)
+    if in_place:
+        assert not _placed(text, ROWS_8K, MIXER_WIDTHS)
 
 
-def test_causal_conv_kernels_fwd_bwd(one_chip, compiled):
+@pytest.mark.parametrize("width,offset", [(4352, 0), (8512, 4096)])
+def test_causal_conv_kernels_fwd_bwd(one_chip, compiled, width, offset):
+    """The cell's 4352 channels, alone and where the in-projection
+    leaves them: lane tiles 32..65 of ``[2, 8192, 8512]``."""
     from paddle_tpu.ops.pallas.causal_conv import causal_conv_silu
-    args = (_sds(one_chip, (2, 8192, 4352), jnp.bfloat16),
+    args = (_sds(one_chip, (2, 8192, width), jnp.bfloat16),
             _sds(one_chip, (4352, 4), jnp.float32),
             _sds(one_chip, (4352,), jnp.float32))
     text = _text(jax.value_and_grad(
-        lambda *a: jnp.square(causal_conv_silu(*a).astype(
+        lambda *a: jnp.square(causal_conv_silu(*a, offset).astype(
             jnp.float32)).sum(), argnums=(0, 1, 2)), *args)
     assert text.count(KERNEL) == 2
     assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
@@ -376,42 +482,78 @@ def test_flash_attention_8k_head_dim_64(one_chip, compiled):
     assert text.count(KERNEL) == 3 and "flash_bwd_dq" in text
 
 
-def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
-    """The step of ``granite-4.0-h-micro.pretrain-8k`` as the benchmark
-    builds it — depth 10 (five state-space layers, one attention layer,
-    four more), every published width, 2 x 8192 tokens — fits a
-    described v5e with no compiler rematerialization, runs the scan and
-    the convolution as kernels and holds no ``[256, 256]`` matrix."""
+def _cell_step(mesh, name):
+    """A training cell's step as the benchmark builds it, compiled for
+    ``mesh``."""
     import functools
     import operator
     from benchmark import harness, models
     from paddle_tpu.models.llama_pretrain import (
         init_adafactor_state, make_train_step, param_specs)
-    cell = harness.find_cell("granite-4.0-h-micro.pretrain-8k")
+    cell = harness.find_cell(name)
     job, fam = cell.traffic, cell.family
     cfg = fam.build_cfg(cell.conf, train=True, job=job)
-    assert cfg.num_hidden_layers == 10 and (job["batch"], job["seq"]) == \
-        (2, 8192)
     specs, shapes = param_specs(cfg, 1), fam.leaf_shapes(cfg)
-    with one_chip:
+    with mesh:
         params = models.tree_of(shapes, lambda path: _sds(
-            one_chip, shapes[path], cfg.param_dtype,
+            mesh, shapes[path], cfg.param_dtype,
             functools.reduce(operator.getitem, path, specs)))
         opt = jax.tree_util.tree_map(
-            lambda x: _sds(one_chip, x.shape, x.dtype),
+            lambda x: _sds(mesh, x.shape, x.dtype),
             jax.eval_shape(init_adafactor_state, params))
-        step = make_train_step(cfg, one_chip, lr=job["lr"],
+        step = make_train_step(cfg, mesh, lr=job["lr"],
                                weight_decay=job["weight_decay"],
                                optimizer=job["optimizer"])
-        c = step.lower(params, opt, _sds(
-            one_chip, (job["batch"], job["seq"] + 1), jnp.int64)).compile()
+        return step.lower(params, opt, _sds(
+            mesh, (job["batch"], job["seq"] + 1), jnp.int64)).compile()
+
+
+def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
+    """The step of ``granite-4.0-h-micro.pretrain-8k`` as the benchmark
+    builds it — depth 10 (five state-space layers, one attention layer,
+    four more), every published width, 2 x 8192 tokens — fits a
+    described v5e with no compiler rematerialization, runs the scan and
+    the convolution as kernels, holds no ``[256, 256]`` matrix, and
+    between the in-projection and the scan writes no array of the
+    mixer's that computes nothing (forward, recompute, backward: 8 a
+    layer before the kernels took offsets, 16 in this text)."""
+    from benchmark import harness
+    cell = harness.find_cell("granite-4.0-h-micro.pretrain-8k")
+    assert cell.conf["num_hidden_layers"] == 10 and \
+        (cell.traffic["batch"], cell.traffic["seq"]) == ROWS_8K
+    c = _cell_step(one_chip, cell.name)
     text = c.as_text()
     for kernel in ("ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
                    "causal_conv_bwd", "flash_fwd", "flash_bwd_dq",
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
+    assert text.count(KERNEL) == 16
     assert ".remat" not in text
     assert not re.search(r"\[[\d,]*256,256\]", text)
+    assert not _placed(text, ROWS_8K, MIXER_WIDTHS), \
+        _placed(text, ROWS_8K, MIXER_WIDTHS)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 3_813_571_072
-    assert ma.temp_size_in_bytes < 11.5 * 2**30
+    assert ma.temp_size_in_bytes <= 10_729_414_144      # PR 31's
+
+
+# sha256 of the dense cell's optimized step at depth 18 with the debug
+# locations out (op metadata, the kernels' serialized bodies, which
+# carry source paths, and the tables of files and frames): PR 31's
+DENSE_STEP_DIGEST = \
+    "96a31f47cd7ed014372a9e31025bb9fbc31313c7753fe8e985c59e888d047cd5"
+
+
+def test_dense_cell_step_is_the_recorded_program(one_chip, compiled):
+    """``internlm2-1.8b.pretrain-2k`` runs no line of the state-space
+    modules: its optimized HLO is, debug locations apart, the text whose
+    digest is recorded above.  A PR that MEANS to change the dense
+    cell's program records the new digest, and says so in PERF.md."""
+    import hashlib
+    text = _cell_step(one_chip, "internlm2-1.8b.pretrain-2k").as_text()
+    assert text.count(KERNEL) == 9 and ".remat" not in text
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:.*\n)*?\n", "", text, flags=re.M)
+    assert hashlib.sha256(text.encode()).hexdigest() == DENSE_STEP_DIGEST
