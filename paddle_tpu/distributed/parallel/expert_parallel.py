@@ -20,6 +20,15 @@ shapes so one XLA program covers every routing outcome:
 Capacity overflow drops tokens (their combine weight is zero), matching
 the reference's capacity semantics.  The load-balancing auxiliary loss
 is psum-averaged over the group.
+
+WHAT THIS MODULE IS FOR: Paddle's capacity API (``MoELayer`` and the
+``global_scatter`` / ``global_gather`` surface), at the sizes that API is
+used at.  Its one-hot ``[T, k, E, C]`` dispatch cannot exist at a
+training step's 16,384 tokens over 64 experts, and it drops.  THE
+TRAINER's expert layer is ``paddle_tpu/ops/moe.py`` (routing without
+capacity and without dropping, rows sorted by expert, the grouped
+products of ``ops/pallas/grouped_mm.py``), which
+``models/hybrid_trunk.py``'s kind ``mla_moe`` runs.
 """
 
 from __future__ import annotations

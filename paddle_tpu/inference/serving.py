@@ -573,6 +573,11 @@ class GenerationServer:
         self._host, self._port = host, port
         self._poll_s = poll_s
         self._lock = threading.Lock()
+        # cancel() waits for _lock holding the gate, and the drive loop
+        # passes the gate before each step: a loop with work (it re-takes
+        # _lock within microseconds) cannot starve a cancel until the
+        # request it names has finished
+        self._gate = threading.Lock()
         self._queues = {}
         self._httpd = None
         self._threads: List[threading.Thread] = []
@@ -974,8 +979,9 @@ class GenerationServer:
         drive loop delivers the terminal 499 to any still-attached
         waiter (a disconnected one is simply never read).  No-op on
         finished rids."""
-        with self._lock:
-            return self._driver.cancel(rid)
+        with self._gate:
+            with self._lock:
+                return self._driver.cancel(rid)
 
     def _drive(self):
         """Engine thread: step while there is work, fan tokens out to
@@ -991,6 +997,8 @@ class GenerationServer:
         import time as _time
         while not self._stop.is_set():
             try:
+                with self._gate:        # a waiting cancel() goes first
+                    pass
                 with self._lock:
                     drv = self._driver
                     worked = drv.has_work()

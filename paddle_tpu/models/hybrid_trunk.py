@@ -23,6 +23,34 @@ projection's output, x, B and C inside the convolution's — at channel
 offsets in their index maps, where those are whole lane tiles: no piece
 is sliced out for a kernel.  What a layer is follows from the
 configuration alone.
+
+``mla_dense`` and ``mla_moe`` are a layer of latent attention (MLA)
+before a dense SwiGLU MLP or before an expert layer (routed experts
+without dropping, ``ops/moe.py``, beside shared experts), on a residual
+of ``n = hc_mult`` STREAMS (manifold-constrained hyper-connections,
+arXiv:2512.24880 over arXiv:2409.19606).  A token's state is ``X [n,
+C]``; the trunk carries it flat, ``[b, s, n * C]`` (stream i is lanes
+``i C .. (i + 1) C``): the table's row is copied into the n streams, the
+streams are summed before the final norm.  Each of a layer's two
+sublayers F (attention; the MLP or the expert layer) has mixer leaves
+``phi [n C, n^2 + 2 n]``, ``alpha [3]``, ``b [n^2 + 2 n]``:
+
+    u = vec(X) / rms(vec(X));  m = u . phi                (fp32 from here)
+    H_pre = sigmoid(alpha_1 m[:n] + b[:n])
+    H_post = 2 sigmoid(alpha_2 m[n:2n] + b[n:2n])
+    H_res = Sinkhorn(exp(clip(alpha_3 mat(m[2n:]) + mat(b[2n:]))))
+            (``hc_sinkhorn_iters`` rounds of row then column
+            normalisation, denominators + ``hc_eps``)
+    h = H_pre . X;  y = F(rms_norm(h));  X' = H_res . X + H_post^T (x) y
+
+    MLA on x = rms_norm(h; ln1):
+    q = rms_norm(x . w_qa; q_norm) . [w_qb_nope | w_qb_rope]   [H, 128 | 64]
+    [c | k_r] = x . w_kva  (kv_lora_rank | 64);  [k_nope | v] =
+    rms_norm(c; kv_norm) . [w_kvb_k | w_kvb_v]                 [H, 128 | 128]
+    q_r, k_r rotated (rotate-half) at YaRN's blended frequencies, k_r ONE
+    vector a token for all heads;  S = (q_nope . k_nope^T + q_r . k_r^T)
+    * (128 + 64)^-1/2 * mscale^2  — ``flash_attention_split``: two
+    operand pairs, no 192-wide operand, no 32-fold copy of k_r.
 """
 
 from __future__ import annotations
@@ -33,10 +61,12 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
-KINDS = ("attention", "mamba")
+KINDS = ("attention", "mamba", "mla_dense", "mla_moe")
+MLA_KINDS = ("mla_dense", "mla_moe")
 
 
 def check(cfg) -> None:
@@ -57,6 +87,35 @@ def check(cfg) -> None:
             raise NotImplementedError(
                 f"mamba_n_groups={cfg.mamba_n_groups}: ops/ssd_scan.py "
                 "shares ONE B/C group among the heads")
+    if set(cfg.layer_types) & set(MLA_KINDS):
+        if not set(cfg.layer_types) <= set(MLA_KINDS):
+            raise NotImplementedError(
+                f"layer_types {sorted(set(cfg.layer_types))}: the kinds "
+                f"{MLA_KINDS} carry hc_mult residual streams, the others "
+                "one; a trunk has one carry")
+        if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+               cfg.v_head_dim) < 1 or cfg.qk_nope_head_dim != cfg.v_head_dim \
+                or cfg.v_head_dim % 128 or cfg.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_rope_head_dim (even) and qk_nope_head_dim = v_head_dim "
+                "(whole lane tiles: flash_attention_split reads them where "
+                "the projections wrote them)")
+        if cfg.hc_mult < 2:
+            raise NotImplementedError(
+                f"hc_mult={cfg.hc_mult}: the latent-attention kinds run on "
+                "a residual of 2 or more streams")
+        if "mla_moe" in cfg.layer_types and not (
+                0 < cfg.num_experts_per_tok <= cfg.n_routed_experts
+                and 0 < cfg.experts_held and cfg.n_shared_experts > 0
+                and 0 <= cfg.expert_first
+                and cfg.expert_first + cfg.experts_held
+                <= cfg.n_routed_experts and cfg.moe_intermediate_size > 0):
+            raise ValueError(
+                "an 'mla_moe' layer needs n_routed_experts (the router's "
+                "width), num_experts_per_tok, moe_intermediate_size, "
+                "n_shared_experts, and the share: experts_held from "
+                "expert_first on, inside the published count")
 
 
 def check_layout(cfg, mesh, pp: int) -> None:
@@ -71,7 +130,10 @@ def check_layout(cfg, mesh, pp: int) -> None:
             "runs of its own), the Mamba-2 mixer no head-parallel "
             "projections (mp), no state hand-over between sequence shards "
             "(sep) and its kernel no shard_map over the batch (dp, "
-            "sharding); one device runs it")
+            "sharding); 'mla_dense' / 'mla_moe' no exchange of routed rows "
+            "between the devices that share a layer's experts and no "
+            "shard_map around flash_attention_split and the grouped "
+            "products; one device runs it")
 
 
 # ---------------------------------------------------------------------------
@@ -87,6 +149,8 @@ def mamba_dims(cfg) -> Tuple[int, int, int]:
 def kind_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     """One layer's leaves.  Both kinds end in the same MLP."""
     from .llama_pretrain import _block_shapes
+    if kind in MLA_KINDS:
+        return _mla_shapes(cfg, kind)
     dense = _block_shapes(cfg)
     if kind == "attention":
         return dense
@@ -113,21 +177,28 @@ def block_specs(cfg) -> Dict[str, Dict[str, P]]:
     dense = _block_specs(cfg, (None,))
     whole = lambda shape: P(*([None] * (len(shape) + 1)))
     return {kind: dense if kind == "attention" else {
-        nm: dense[nm] if nm in ("w_gate", "w_up", "w_down") else whole(shape)
+        nm: dense[nm] if nm in ("w_gate", "w_up", "w_down")
+        and kind == "mamba" else whole(shape)
         for nm, shape in kind_shapes(cfg, kind).items()}
         for kind in dict.fromkeys(cfg.layer_types)}
 
 
 def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     """``layers`` layers of one leaf, stacked.  Matrices normal at
-    1/sqrt(hidden), norms and D ones, the convolution uniform in +-1 /
+    1/sqrt(hidden) (the latent-attention kinds': at 1/sqrt(the width the
+    matrix contracts), their mixers' alpha ones and b zeros, so that m =
+    u . phi is of unit size and H_res differs from token to token), norms
+    and D ones, the convolution uniform in +-1 /
     sqrt(d_conv), ``A_log = log U[1, 16]`` and ``dt_bias`` the inverse
     softplus of a step log-uniform in [1e-3, 1e-1] (the Mamba-2
     reference's: decays neither 0 nor 1)."""
     shape = (layers,) + kind_shapes(cfg, kind)[name]
     f32 = jnp.float32
-    if name in ("ln1", "ln2", "gate_norm", "D"):
+    if name in ("ln1", "ln2", "gate_norm", "D", "q_norm", "kv_norm") \
+            or name.endswith("_alpha"):
         out = jnp.ones(shape, f32)
+    elif name.endswith("_b"):
+        out = jnp.zeros(shape, f32)
     elif name in ("conv_w", "conv_b"):
         bound = 1.0 / math.sqrt(cfg.mamba_d_conv)
         out = jax.random.uniform(key, shape, f32, -bound, bound)
@@ -138,7 +209,9 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
                                         math.log(1e-1)))
         out = dt + jnp.log(-jnp.expm1(-dt))
     else:
-        out = jax.random.normal(key, shape, f32) / math.sqrt(cfg.hidden_size)
+        # a matrix: normal at 1/sqrt(its rows), the width it contracts
+        out = jax.random.normal(key, shape, f32) / math.sqrt(
+            cfg.hidden_size if kind not in MLA_KINDS else shape[-2])
     return out.astype(dtype or cfg.param_dtype)
 
 
@@ -209,6 +282,190 @@ def _mamba_block(bp, x, cfg, mesh=None, seg=None):
 
 
 # ---------------------------------------------------------------------------
+# latent attention, the expert layer, the residual streams
+# ---------------------------------------------------------------------------
+def _mla_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
+    c, n, heads = cfg.hidden_size, cfg.hc_mult, cfg.num_attention_heads
+    maps = n * n + 2 * n
+    out = {"ln1": (c,), "w_qa": (c, cfg.q_lora_rank),
+           "q_norm": (cfg.q_lora_rank,),
+           "w_qb_nope": (cfg.q_lora_rank, heads * cfg.qk_nope_head_dim),
+           "w_qb_rope": (cfg.q_lora_rank, heads * cfg.qk_rope_head_dim),
+           "w_kva": (c, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+           "kv_norm": (cfg.kv_lora_rank,),
+           "w_kvb_k": (cfg.kv_lora_rank, heads * cfg.qk_nope_head_dim),
+           "w_kvb_v": (cfg.kv_lora_rank, heads * cfg.v_head_dim),
+           "wo": (heads * cfg.v_head_dim, c), "ln2": (c,)}
+    for pre in ("hc1", "hc2"):
+        out.update({pre + "_phi": (n * c, maps), pre + "_alpha": (3,),
+                    pre + "_b": (maps,)})
+    if kind == "mla_dense":
+        f = cfg.intermediate_size
+        out.update({"w_gate": (c, f), "w_up": (c, f), "w_down": (f, c)})
+        return out
+    f, e = cfg.moe_intermediate_size, cfg.experts_held
+    fs = f * cfg.n_shared_experts
+    out.update({"w_router": (c, cfg.n_routed_experts),
+                "we_gate_up": (e, c, 2 * f), "we_down": (e, f, c),
+                "ws_gate": (c, fs), "ws_up": (c, fs), "ws_down": (fs, c)})
+    return out
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """The ``dim / 2`` rotation frequencies: plain RoPE's, or (``type:
+    yarn``) each blended between its own and its ``factor``-th by a ramp
+    over the pairs whose wavelength, at the original context, makes
+    between ``beta_slow`` and ``beta_fast`` turns.  A static table."""
+    inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not scaling:
+        return inv.astype(np.float32)
+    if scaling["type"] != "yarn":
+        raise NotImplementedError(f"rope_scaling {scaling}: yarn is built")
+    orig = scaling["original_max_position_embeddings"]
+
+    def pair_of(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+    low = max(math.floor(pair_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0, 1)
+    return (inv / scaling["factor"] * ramp + inv * (1 - ramp)).astype(
+        np.float32)
+
+
+def yarn_mscale(scaling, key: str) -> float:
+    """YaRN's attention-temperature term for ``mscale`` or
+    ``mscale_all_dim``: ``0.1 m ln(factor) + 1``."""
+    if not scaling or scaling["factor"] <= 1:
+        return 1.0
+    return 0.1 * scaling.get(key, 1) * math.log(scaling["factor"]) + 1.0
+
+
+def mla_score_scale(cfg) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 \
+        * yarn_mscale(cfg.rope_scaling, "mscale_all_dim") ** 2
+
+
+def _rotate_half(x, cos, sin):
+    """x [b, s, (heads,) d] at positions 0..s-1, in fp32, one rounding."""
+    from ..ops.pallas.rope import _rotate
+    if x.ndim == 4:
+        cos, sin = cos[:, None], sin[:, None]
+    return _rotate(x.astype(jnp.float32), cos, sin).astype(x.dtype)
+
+
+def _mla_attention(bp, x, cfg):
+    from ..ops.pallas.flash_attention import flash_attention_split
+    from .llama_pretrain import _rms_norm
+    b, s, _ = x.shape
+    dt, eps, heads = cfg.dtype, cfg.rms_norm_eps, cfg.num_attention_heads
+    rope = cfg.qk_rope_head_dim
+    with jax.named_scope("mla_q"):
+        qa = _rms_norm(x @ bp["w_qa"].astype(dt), bp["q_norm"], eps)
+        q = (qa @ bp["w_qb_nope"].astype(dt)).reshape(b, s, heads, -1)
+        q_r = (qa @ bp["w_qb_rope"].astype(dt)).reshape(b, s, heads, rope)
+    with jax.named_scope("mla_kv"):
+        ckr = x @ bp["w_kva"].astype(dt)
+        c = _rms_norm(ckr[..., :cfg.kv_lora_rank], bp["kv_norm"], eps)
+        k_r = ckr[..., cfg.kv_lora_rank:]
+        k = (c @ bp["w_kvb_k"].astype(dt)).reshape(b, s, heads, -1)
+        v = (c @ bp["w_kvb_v"].astype(dt)).reshape(b, s, heads, -1)
+    with jax.named_scope("rope"):
+        ang = jnp.arange(s, dtype=jnp.float32)[:, None] * yarn_inv_freq(
+            rope, cfg.rope_theta, cfg.rope_scaling)
+        m = yarn_mscale(cfg.rope_scaling, "mscale") \
+            / yarn_mscale(cfg.rope_scaling, "mscale_all_dim")
+        cos, sin = jnp.cos(ang) * m, jnp.sin(ang) * m
+        q_r, k_r = _rotate_half(q_r, cos, sin), _rotate_half(k_r, cos, sin)
+    with jax.named_scope("attn"):
+        o = flash_attention_split(q, q_r, k, k_r, v, mla_score_scale(cfg))
+    with jax.named_scope("attn_out"):
+        return o.reshape(b, s, -1) @ bp["wo"].astype(dt)
+
+
+def _expert_layer(bp, x, cfg):
+    """The routed experts held here, for the pairs routed to them, beside
+    the shared experts: x [b, s, C] (normed) -> [b, s, C]."""
+    from ..ops import moe
+    from .llama_pretrain import _swiglu
+    b, s, c = x.shape
+    rows = x.reshape(b * s, c)
+    with jax.named_scope("moe_route"):
+        idx, gate = moe.route(rows, bp["w_router"], cfg.num_experts_per_tok,
+                              cfg.routed_scaling_factor)
+    with jax.named_scope("moe_dispatch"):
+        p = moe.plan(idx, cfg.expert_first, cfg.experts_held)
+    routed = moe.routed_ffn(rows, gate, bp["we_gate_up"], bp["we_down"],
+                            p).reshape(b, s, c)
+    with jax.named_scope("moe_shared"):
+        return routed + _swiglu(x, bp["ws_gate"], bp["ws_up"],
+                                bp["ws_down"], cfg.dtype)
+
+
+def hc_maps(bp, pre: str, x, cfg):
+    """The three maps of one sublayer's mixer from the streams x [b, s,
+    n C]: ``H_pre [n]``, ``H_post [n]`` and ``H_res [n][n]`` (row j:
+    what stream j of the output takes of each input stream), every entry
+    fp32 ``[b, s, 1]``.  The product with phi runs on the streams as they
+    are (the compute dtype, fp32 sums) and is scaled by 1 / rms
+    afterwards; the rest is fp32, with the TOKENS on the lanes."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    b, s, _ = x.shape
+    var = jnp.mean(jnp.square(x.astype(f32)), -1, keepdims=True)
+    m = jnp.einsum("bsk,kj->bsj", x, bp[pre + "_phi"].astype(x.dtype),
+                   preferred_element_type=f32) \
+        * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+    m = m.reshape(b * s, -1).T                              # [n^2 + 2n, T]
+    alpha = bp[pre + "_alpha"].astype(f32)
+    bias = bp[pre + "_b"].astype(f32)[:, None]
+    h_pre = jax.nn.sigmoid(alpha[0] * m[:n] + bias[:n])
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + bias[n:2 * n])
+    r = (alpha[2] * m[2 * n:] + bias[2 * n:]).reshape(n, n, b * s)
+    r = jnp.exp(jnp.clip(r, cfg.mhc_h_res_clamp_min,
+                         cfg.mhc_h_res_clamp_max))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        r = r / (jnp.sum(r, axis=1, keepdims=True) + cfg.hc_eps)    # rows
+        r = r / (jnp.sum(r, axis=0, keepdims=True) + cfg.hc_eps)    # columns
+    tok = lambda a: a.reshape(b, s, 1)
+    return ([tok(h_pre[i]) for i in range(n)],
+            [tok(h_post[i]) for i in range(n)],
+            [[tok(r[j, i]) for i in range(n)] for j in range(n)])
+
+
+def _hc_sublayer(bp, pre: str, x, fn, cfg):
+    """x' = H_res . x + H_post^T (x) fn(H_pre . x) on x [b, s, n C]."""
+    n, c, f32 = cfg.hc_mult, cfg.hidden_size, jnp.float32
+    with jax.named_scope("hc_pre"):
+        h_pre, h_post, h_res = hc_maps(bp, pre, x, cfg)
+        streams = [x[..., i * c:(i + 1) * c].astype(f32) for i in range(n)]
+        h = sum(w * xi for w, xi in zip(h_pre, streams)).astype(x.dtype)
+    y = fn(h)
+    with jax.named_scope("hc_post"):
+        y = y.astype(f32)
+        return jnp.concatenate(
+            [(sum(w * xi for w, xi in zip(h_res[j], streams))
+              + h_post[j] * y).astype(x.dtype) for j in range(n)], axis=-1)
+
+
+def _mla_block(bp, x, cfg, mesh=None, seg=None):
+    from .llama_pretrain import _rms_norm, _swiglu
+    eps = cfg.rms_norm_eps
+
+    def ffn(h):
+        y = _rms_norm(h, bp["ln2"], eps)
+        if "w_router" in bp:
+            return _expert_layer(bp, y, cfg)
+        with jax.named_scope("mlp"):
+            return _swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"],
+                           cfg.dtype)
+    with jax.named_scope("block"):
+        x = _hc_sublayer(bp, "hc1", x, lambda h: _mla_attention(
+            bp, _rms_norm(h, bp["ln1"], eps), cfg), cfg)
+        return _hc_sublayer(bp, "hc2", x, ffn, cfg)
+
+
+# ---------------------------------------------------------------------------
 # the trunk
 # ---------------------------------------------------------------------------
 def layer_runs(layer_types) -> List[Tuple[str, int, int]]:
@@ -246,14 +503,22 @@ _split.defvjp(_split_fwd, _split_bwd)
 
 
 def trunk(blocks, x, cfg, mesh):
-    """x [b, s, h] through the layers in ``cfg.layer_types``' order."""
+    """x [b, s, h] through the layers in ``cfg.layer_types``' order.
+    The latent-attention kinds carry ``hc_mult`` streams, ``[b, s, n h]``:
+    the row that comes in is copied into each, and their sum goes out."""
     from .llama_pretrain import _block_forward, _remat_wrap
-    body = {"attention": _block_forward, "mamba": _mamba_block}
-    if cfg.remat_policy == "flash" and "mamba" in cfg.layer_types:
+    body = {"attention": _block_forward, "mamba": _mamba_block,
+            "mla_dense": _mla_block, "mla_moe": _mla_block}
+    if cfg.remat_policy == "flash" and \
+            set(cfg.layer_types) != {"attention"}:
         raise NotImplementedError(
-            "remat_policy='flash' saves the flash kernels' residuals; the "
-            "state-space block has none to save: use 'full'")
+            "remat_policy='flash' saves the dense flash kernels' residuals; "
+            "the other kinds' blocks have none to save: use 'full'")
     runs = layer_runs(cfg.layer_types)
+    streams = cfg.hc_mult if cfg.layer_types[0] in MLA_KINDS else 1
+    if streams > 1:
+        with jax.named_scope("hc_pre"):
+            x = jnp.tile(x, (1, 1, streams))
 
     def runs_of(kind):
         """The kind's stacked leaves, one dict a run of its layers."""
@@ -271,4 +536,8 @@ def trunk(blocks, x, cfg, mesh):
                 lambda carry, bp, fwd=fwd: (fwd(bp, carry, cfg, mesh, None),
                                             None),
                 x, parts[kind].pop(0))
+    if streams > 1:
+        with jax.named_scope("hc_post"):
+            x = jnp.sum(x.reshape(*x.shape[:2], streams, -1).astype(
+                jnp.float32), axis=2).astype(x.dtype)
     return x

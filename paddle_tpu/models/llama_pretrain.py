@@ -94,10 +94,43 @@ class LlamaPretrainConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     tie_word_embeddings: bool = False   # the head is the table: no lm_head
+    # LATENT ATTENTION, ROUTED EXPERTS, RESIDUAL STREAMS (hybrid_trunk's
+    # kinds 'mla_dense' / 'mla_moe'): ``kv_lora_rank`` > 0 makes every
+    # layer one of the two — the first ``first_k_dense_replace`` with the
+    # dense MLP, the rest with the expert layer — unless ``layer_types``
+    # says otherwise.  The published keys keep their names.  The SHARE:
+    # the router is ``n_routed_experts`` wide (the published count) and
+    # this device holds ``experts_held`` of them, from ``expert_first``
+    # on (None: all of them).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[Dict[str, Any]] = None   # YaRN's keys
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    num_experts_per_tok: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[int] = None
+    expert_first: int = 0
+    hc_mult: int = 1                    # residual streams a token
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
             self.num_key_value_heads = self.num_attention_heads
+        if self.experts_held is None:
+            self.experts_held = self.n_routed_experts
+        if self.kv_lora_rank and self.layer_types is None:
+            dense = min(self.first_k_dense_replace, self.num_hidden_layers)
+            self.layer_types = ("mla_dense",) * dense + ("mla_moe",) * (
+                self.num_hidden_layers - dense)
         if self.layer_types is not None:
             self.layer_types = tuple(
                 self.layer_types[:self.num_hidden_layers])
@@ -428,13 +461,17 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
         return _ffn(bp, x, cfg)
 
 
+def _swiglu(y, w_gate, w_up, w_down, dt):
+    gate = jax.nn.silu(_mm(y, w_gate, dt))
+    up = _mm(y, w_up, dt)
+    return _mm(gate * up, w_down, dt)
+
+
 def _ffn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig):
     """ln2 + gated FFN + residual: the ``mlp`` scope's body."""
-    dt = cfg.dtype
     y = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
-    gate = jax.nn.silu(_mm(y, bp["w_gate"], dt))
-    up = _mm(y, bp["w_up"], dt)
-    return _residual(x, _mm(gate * up, bp["w_down"], dt), cfg)
+    return _residual(x, _swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"],
+                                cfg.dtype), cfg)
 
 
 def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,

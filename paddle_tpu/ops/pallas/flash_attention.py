@@ -59,7 +59,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import _common
 from ._common import idx32
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "flash_attention_split"]
 
 NEG_INF = -1e30
 
@@ -92,19 +92,27 @@ def _visible(q0, k0, shape, q_axis):
     return q_pos >= k_pos
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
                 sm_scale: float, block_k: int):
-    # q_ref/o_ref: [Bq, d]; k_ref/v_ref: [S, d]; lse_ref: [1, Bq]
+    # q_ref/o_ref: [Bq, d]; k_ref/v_ref: [S, d]; lse_ref: [1, Bq].
+    # SPLIT scores (:func:`flash_attention_split`): q2_ref [Bq, d2] and
+    # k2_ref [S, d2] come before the outputs, and their product is added
+    # to q k^T in fp32 before the one exp pass
+    *second, o_ref, lse_ref = refs
     qi = pl.program_id(2).astype(jnp.int32)
-    Bq, d = q_ref.shape
+    Bq, d = o_ref.shape
     S = k_ref.shape[0]
     q = q_ref[:]
+    q2 = second[0][:] if second else None
 
     def body(ki, carry, masked):
         m_prev, l_prev, acc = carry
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = _scores(q, k) * jnp.float32(sm_scale)
+        s = _scores(q, k)
+        if second:
+            s = s + _scores(q2, second[1][pl.ds(ki * block_k, block_k), :])
+        s = s * jnp.float32(sm_scale)
         if masked:
             # only the diagonal block pays for the mask (iota+cmp+select
             # are pure VPU work; off-diagonal causal blocks are all-visible
@@ -138,32 +146,47 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     lse_ref[:] = (m + jnp.log(l_safe)).T
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, delta_ref, *, causal: bool, sm_scale: float,
-                   block_k: int):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
+                   causal: bool, sm_scale: float, block_k: int):
     # q/o/do/dq: [Bq, d]; k/v: [S, d]; lse_ref (in), delta_ref (out):
-    # [1, Bq] — rows here are queries, so both turn once a grid step
+    # [1, Bq] — rows here are queries, so both turn once a grid step.
+    # Split scores: q2_ref [Bq, d2], k2_ref [S, d2] before the outputs
+    # and dq2_ref [Bq, d2] after them
+    if len(refs) == 2:
+        second, (dq_ref, delta_ref) = (), refs
+    else:
+        *second, dq_ref, delta_ref, dq2_ref = refs
     qi = pl.program_id(2).astype(jnp.int32)
     Bq, d = q_ref.shape
     S = k_ref.shape[0]
     q = q_ref[:]
+    q2 = second[0][:] if second else None
     do = do_ref[:]
     lse = lse_ref[:].T          # [Bq, 1]
     delta = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
                     axis=1, keepdims=True)
 
-    def body(ki, dq, masked):
+    def body(ki, dqs, masked):
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = _scores(q, k) * jnp.float32(sm_scale)
+        s = _scores(q, k)
+        if second:
+            k2 = second[1][pl.ds(ki * block_k, block_k), :]
+            s = s + _scores(q2, k2)
+        s = s * jnp.float32(sm_scale)
         if masked:
             s = jnp.where(_visible(qi * Bq, ki * block_k, s.shape, 0),
                           s, jnp.float32(NEG_INF))
         p = jnp.exp(s - lse)
         ds = p * (_scores(do, v) - delta) * jnp.float32(sm_scale)
-        return dq + _matmul(ds.astype(k.dtype), k)
+        dq = dqs[0] + _matmul(ds.astype(k.dtype), k)
+        if second:
+            return dq, dqs[1] + _matmul(ds.astype(k2.dtype), k2)
+        return (dq,)
 
-    dq0 = jnp.zeros((Bq, d), jnp.float32)
+    dq0 = (jnp.zeros((Bq, d), jnp.float32),)
+    if second:
+        dq0 += (jnp.zeros(q2.shape, jnp.float32),)
     assert not causal or Bq == block_k, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
@@ -174,13 +197,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq = jax.lax.fori_loop(
             jnp.int32(0), jnp.int32(S // block_k),
             lambda ki, c: body(ki, c, masked=False), dq0)
-    dq_ref[:] = dq.astype(dq_ref.dtype)
+    dq_ref[:] = dq[0].astype(dq_ref.dtype)
+    if second:
+        dq2_ref[:] = dq[1].astype(dq2_ref.dtype)
     delta_ref[:] = delta.T
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
                     causal: bool, sm_scale: float, block_q: int,
-                    also_dq: bool):
+                    also_dq: bool, split: bool = False):
     # k/v/dk/dv: [Bk, d] of one KV head; q/do: [S, d], lse: [S/Bq, 1, Bq]
     # of ONE query head of its group (grid axis 3, innermost).  Scores
     # are formed TRANSPOSED, keys on rows: a block's [1, Bq] statistics
@@ -195,6 +220,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
     # steps, so it cannot be an output block of its own), and the KV
     # head's last grid step casts it into dq_ref, the group's
     # [S, group*d] (flat) or [group, S, d] block.
+    #
+    # Split scores (two kernels, a group of one): q2_ref [S, d2] and
+    # k2_ref [Bk, d2] lead ``refs`` and dk2_ref [Bk, d2] follows dv_ref:
+    # THIS head's part of the gradient of a k2 that every head shares.
     ki = pl.program_id(2).astype(jnp.int32)
     g = pl.program_id(3).astype(jnp.int32)
     Bk, d = k_ref.shape
@@ -219,18 +248,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
                 return _
             jax.lax.fori_loop(jnp.int32(0), jnp.int32(S // block_q),
                               form_delta, None)
+    elif split:
+        q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc = refs
+        delta_ref, stat0 = last_ref, jnp.int32(0)
+        k2 = k2_ref[:]
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = refs
         delta_ref, stat0 = last_ref, jnp.int32(0)
 
     def body(qi, carry, masked):
-        dk, dv = carry
+        dk, dv, *dk2 = carry
         rows = pl.ds(qi * block_q, block_q)
         q = q_ref[rows, :]
         do = do_ref[rows, :]
         lse = lse_ref[qi]
         delta = delta_ref[stat0 + qi]
-        st = _scores(k, q) * jnp.float32(sm_scale)          # [Bk, Bq]
+        st = _scores(k, q)                                  # [Bk, Bq]
+        if split:
+            q2 = q2_ref[rows, :]
+            st = st + _scores(k2, q2)
+        st = st * jnp.float32(sm_scale)
         if masked:
             st = jnp.where(_visible(qi * block_q, ki * Bk, st.shape, 1),
                            st, jnp.float32(NEG_INF))
@@ -241,6 +278,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
         dk = dk + _matmul(dst, q)
         if also_dq:
             dq_acc[g, rows, :] += _matmul_t(dst, k)
+        if split:
+            return dk, dv, dk2[0] + _matmul(dst, q2)
         return dk, dv
 
     # the group's first query head starts the fp32 sums, the others add
@@ -251,21 +290,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     carry = (dk_acc[:], dv_acc[:])
+    if split:
+        carry += (jnp.zeros(k2.shape, jnp.float32),)
     assert not causal or Bk == block_q, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
         # diagonal block qi == ki is masked; strictly-later q blocks see
         # this k block in full
         carry = body(ki, carry, masked=True)
-        dk, dv = jax.lax.fori_loop(
+        dk, dv, *dk2 = jax.lax.fori_loop(
             ki + 1, jnp.int32(S // block_q),
             lambda qi, c: body(qi, c, masked=False), carry)
     else:
-        dk, dv = jax.lax.fori_loop(
+        dk, dv, *dk2 = jax.lax.fori_loop(
             jnp.int32(0), jnp.int32(S // block_q),
             lambda qi, c: body(qi, c, masked=False), carry)
     dk_acc[:] = dk
     dv_acc[:] = dv
+    if split:
+        dk2_ref[:] = dk2[0].astype(dk2_ref.dtype)
 
     last_head = g == pl.num_programs(3) - 1
 
@@ -365,14 +408,16 @@ def flash_attention(q, k, v, causal: bool = False):
             f"{k.shape[2]}")
     if q.shape[1] == k.shape[1] and \
             _pick_blocks(q.shape[1]) is not None:
-        return _flash_pallas(q, k, v, causal)
+        return _flash_pallas(q, k, v, None, None, causal,
+                             1.0 / math.sqrt(q.shape[-1]))
     return _xla_sdpa(q, k, v, causal)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash_pallas(q, k, v, causal: bool = False):
-    out, _ = _flash_fwd(q, k, v, causal)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_pallas(q, k, v, q2, k2, causal: bool, sm_scale: float):
+    """One entry for both forms: ``q2`` / ``k2`` None is the dense one,
+    else the scores are split (:func:`flash_attention_split`)."""
+    return _flash_fwd(q, k, v, q2, k2, causal, sm_scale)[0]
 
 
 def _to_kernel(x):
@@ -444,12 +489,37 @@ def _by_kv_head(group):
     return tile, head
 
 
-def _flash_fwd(q, k, v, causal):
+def _shared_spec(rows, d2, at):
+    """BlockSpec of ``rows`` positions of the key ``[b, s, d2]`` that
+    all heads share.  ``at`` as in :func:`_tile_spec`."""
+    def index(*g):
+        b, _, r = at(*g)
+        return idx32(b, r, 0)
+    return pl.BlockSpec((None, rows, d2), index)
+
+
+def _split_vmem(s, d, itemsize):
+    """What a kernel of the SPLIT form asks of VMEM: five whole-row
+    operands of one head at most (a 64-wide one fills 128 lanes), twice
+    for the pipeline's buffers, beside the tiles and the block
+    products."""
+    resident = 2 * 5 * s * d * itemsize + (8 << 20)
+    if resident <= _DEFAULT_VMEM_LIMIT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=resident)
+
+
+def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
     b, s, h, d = q.shape
-    sm_scale = 1.0 / math.sqrt(d)
     qr, kr, vr = _to_kernel(q), _to_kernel(k), _to_kernel(v)
     bq, bk = _pick_blocks(s)
     tile, kv = _by_query_head(h // k.shape[2])
+    second, second_specs, params = (), [], None
+    if q2 is not None:
+        second = (_to_kernel(q2), k2)
+        second_specs = [_tile_spec(bq, k2.shape[2], tile),
+                        _shared_spec(s, k2.shape[2], kv)]
+        params = _split_vmem(s, d, q.dtype.itemsize)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           block_k=bk),
@@ -458,12 +528,13 @@ def _flash_fwd(q, k, v, causal):
                                         jnp.float32)),
         grid=(b, h, s // bq),
         in_specs=[_tile_spec(bq, d, tile), _tile_spec(s, d, kv),
-                  _tile_spec(s, d, kv)],
+                  _tile_spec(s, d, kv)] + second_specs,
         out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
+        compiler_params=params,
         name="flash_fwd",
         interpret=_common.interpret(),
-    )(qr, kr, vr)
-    return _from_kernel(out, h), (qr, kr, vr, out, lse)
+    )(qr, kr, vr, *second)
+    return _from_kernel(out, h), (qr, kr, vr, second, out, lse)
 
 
 def _group_spec(s, group, d):
@@ -479,30 +550,30 @@ def _group_spec(s, group, d):
                         lambda i, j, r, g: idx32(i, j, 0, 0))
 
 
-def _flash_bwd_vjp(causal, res, dout):
-    qr, kr, vr, out, lse = res
+def _flash_bwd_vjp(causal, sm_scale, res, dout):
+    qr, kr, vr, second, out, lse = res
     b, s, h, d = dout.shape
     nkv = kr.size // (b * s * d)
     group = h // nkv
-    sm_scale = 1.0 / math.sqrt(d)
     do = _to_kernel(dout)
     bq, bk = _pick_blocks(s)
     interp = _common.interpret()
-    dq_shape = jax.ShapeDtypeStruct(qr.shape, qr.dtype)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    d2 = second[1].shape[2] if second else 0
 
     tile, head = _by_kv_head(group)
-    out_shape = [jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                 jax.ShapeDtypeStruct(vr.shape, vr.dtype)]
+    out_shape = [like(kr), like(vr)]
     out_specs = [_tile_spec(bk, d, tile), _tile_spec(bk, d, tile)]
     scratch = [pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, d), jnp.float32)]
-    params = None
-    one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
+    params = _split_vmem(s, d, qr.dtype.itemsize) if second else None
+    # the split form keeps the two kernels, whatever its dQ's size
+    one_pass = not second and group * s * d * 4 <= ONE_PASS_DQ_BYTES
     if one_pass:
         # o in delta's place; dq a third output, the group's block; the
         # group's fp32 dQ and delta in scratch
         last, last_spec = out, _tile_spec(s, d, head)
-        out_shape.append(dq_shape)
+        out_shape.append(like(qr))
         out_specs.append(_group_spec(s, group, d))
         scratch += [pltpu.VMEM((group, s, d), jnp.float32),
                     pltpu.VMEM((group * (s // bq), 1, bq), jnp.float32)]
@@ -515,40 +586,88 @@ def _flash_bwd_vjp(causal, res, dout):
             params = pltpu.CompilerParams(vmem_limit_bytes=resident)
     else:
         by_q, kv = _by_query_head(group)
-        dq, last = pl.pallas_call(
+        second_in = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
+            if second else []
+        dq, last, *dq2_kernel = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, causal=causal,
                               sm_scale=sm_scale, block_k=bk),
-            out_shape=(dq_shape,
-                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
+            out_shape=[like(qr),
+                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
+            + [like(x) for x in second[:1]],
             grid=(b, h, s // bq),
             in_specs=[_tile_spec(bq, d, by_q), _tile_spec(s, d, kv),
                       _tile_spec(s, d, kv), _tile_spec(bq, d, by_q),
-                      _tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)],
-            out_specs=(_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)),
+                      _tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
+            + second_in,
+            out_specs=[_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
+            + second_in[:1],
+            compiler_params=params,
             name="flash_bwd_dq",
             interpret=interp,
-        )(qr, kr, vr, out, do, lse)
+        )(qr, kr, vr, out, do, lse, *second)
         last_spec = _stat_spec(s // bq, bq, head)
 
+    second_in = []
+    if second:
+        # a head's part of dk2 leaves in fp32, after dk and dv
+        second_in = [_tile_spec(s, d2, head), _shared_spec(bk, d2, tile)]
+        out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
+        out_specs.append(_tile_spec(bk, d2, tile))
     grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
-                          sm_scale=sm_scale, block_q=bq, also_dq=one_pass),
+                          sm_scale=sm_scale, block_q=bq, also_dq=one_pass,
+                          split=bool(second)),
         out_shape=out_shape,
         grid=(b, nkv, s // bk, group),
         in_specs=[_tile_spec(s, d, head), _tile_spec(bk, d, tile),
                   _tile_spec(bk, d, tile), _tile_spec(s, d, head),
-                  _stat_spec(s // bq, bq, head), last_spec],
+                  _stat_spec(s // bq, bq, head), last_spec] + second_in,
         out_specs=out_specs,
         scratch_shapes=scratch,
         compiler_params=params,
         name="flash_bwd_dkv",
         interpret=interp,
-    )(qr, kr, vr, do, lse, last)
+    )(qr, kr, vr, do, lse, last, *second)
     dk, dv = grads[:2]
     if one_pass:
         dq = grads[2]
+    dq2 = dk2 = None
+    if second:
+        # [b, h, s, d2] or [b, s, h*d2]: the heads' sum is the shared key's
+        dq2 = _from_kernel(dq2_kernel[0], h)
+        dk2 = _from_kernel(grads[2], h).sum(axis=2).astype(second[1].dtype)
 
-    return _from_kernel(dq, h), _from_kernel(dk, nkv), _from_kernel(dv, nkv)
+    return (_from_kernel(dq, h), _from_kernel(dk, nkv), _from_kernel(dv, nkv),
+            dq2, dk2)
 
 
 _flash_pallas.defvjp(_flash_fwd, _flash_bwd_vjp)
+
+
+# ---------------------------------------------------------------------------
+# scores from TWO operand pairs: latent attention's 128-wide per-head
+# part and its 64-wide rotated part, whose key every head shares
+# ---------------------------------------------------------------------------
+def flash_attention_split(q, q2, k, k2, v, sm_scale: float):
+    """Causal attention whose scores are the sum of two products:
+
+        S = (q k^T + q2 k2^T) * sm_scale,   out = softmax(S) v
+
+    q, k, v ``[b, s, h, d]`` with ``d % 128 == 0`` — read where they lie,
+    as the dense entry reads them; q2 ``[b, s, h, d2]``; k2 ``[b, s,
+    d2]``, ONE key a token for all heads (MLA's rotated part: d 128, d2
+    64).  Both products are summed in fp32 before the one exp pass; no
+    ``[.., d + d2]`` operand and no h-fold copy of k2 is made.  The
+    backward is the two kernels (``flash_bwd_dq``, ``flash_bwd_dkv``)
+    and returns five gradients; a head's part of dk2 leaves the kernel
+    in fp32 and the heads are summed outside it.  Same kernel bodies,
+    names and blocks as :func:`flash_attention`."""
+    b, s, h, d = q.shape
+    if d % 128 or k.shape != q.shape or v.shape != q.shape or \
+            q2.shape[:3] != (b, s, h) or k2.shape != (b, s, q2.shape[3]):
+        raise ValueError(
+            f"flash_attention_split: q {q.shape}, q2 {q2.shape}, k "
+            f"{k.shape}, k2 {k2.shape}, v {v.shape}")
+    if _pick_blocks(s) is None:
+        raise ValueError(f"flash_attention_split: no block divides S={s}")
+    return _flash_pallas(q, k, v, q2, k2, True, float(sm_scale))

@@ -366,3 +366,101 @@ def test_quantized_decode_agrees(_interpret_mode):
         # int8 flips occasional argmax ties on a random tiny model;
         # the sequences must still largely agree
         assert (t_full == t_q).mean() >= 0.5
+
+
+# -- scores from two operand pairs (latent attention) ------------------------
+def _split_inputs(b, s, h, d, d2, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    shapes = [(b, s, h, d), (b, s, h, d2), (b, s, h, d), (b, s, d2),
+              (b, s, h, d), (b, s, h, d)]
+    return [jax.random.normal(k, sh, dtype) for k, sh in zip(ks, shapes)]
+
+
+def _concatenated_attention(q, q2, k, k2, v, scale):
+    """Plain causal attention over the 192-wide operands: q | q2 against
+    k | k2 copied to every head."""
+    b, s, h, _ = q.shape
+    qq = jnp.concatenate([q, q2], -1)
+    kk = jnp.concatenate(
+        [k, jnp.broadcast_to(k2[:, :, None], (b, s, h, k2.shape[-1]))], -1)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", qq, kk, precision="highest") * scale
+    sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("s,h", [(1024, 3), (256, 2)])
+def test_flash_attention_split_forward_and_five_gradients(s, h):
+    """Two 512-row blocks (the off-diagonal loop and the masked
+    diagonal) and one 256-row block; dk2 is the SUM over the heads."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    q, q2, k, k2, v, co = _split_inputs(2, s, h, 128, 64)
+    scale = 0.137
+    got, vjp = jax.vjp(lambda *a: flash_attention_split(*a, scale),
+                       q, q2, k, k2, v)
+    want, vjp_plain = jax.vjp(
+        lambda *a: _concatenated_attention(*a, scale), q, q2, k, k2, v)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    grads, wants = vjp(co), vjp_plain(co)
+    assert [g.shape for g in grads] == [a.shape for a in (q, q2, k, k2, v)]
+    for a, b_ in zip(grads, wants):
+        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
+
+
+def test_flash_attention_split_makes_no_wide_operand():
+    """No ``[.., 192]`` operand and no h-fold copy of the shared key
+    reaches the kernels: they take the five arrays as they are."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    q, q2, k, k2, v, _ = _split_inputs(1, 512, 4, 128, 64)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: flash_attention_split(*a, 0.1).sum(),
+        argnums=(0, 1, 2, 3, 4)))(q, q2, k, k2, v))
+    assert text.count("pallas_call") == 3
+    assert "192" not in text and "concatenate" not in text
+
+
+def test_flash_attention_split_refuses_what_it_cannot_address():
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    q, q2, k, k2, v, _ = _split_inputs(1, 256, 2, 128, 64)
+    with pytest.raises(ValueError):
+        flash_attention_split(q[..., :64], q2, k[..., :64], k2,
+                              v[..., :64], 0.1)
+    with pytest.raises(ValueError):
+        flash_attention_split(q, q2, k, k2[:, :, None].repeat(2, 2), v, 0.1)
+
+
+# -- grouped products over the experts held -----------------------------------
+@pytest.mark.parametrize("sizes", [(300, 0, 257, 5), (0, 0, 0, 0),
+                                   (1024, 0, 0, 0)])
+def test_grouped_mm_matches_a_loop_over_the_experts(sizes):
+    """Groups of uneven, zero and tile-crossing sizes, laid out as
+    ``ops/moe.plan`` lays them: each at a multiple of TILE_M, one tile
+    at least."""
+    from paddle_tpu.ops.pallas.grouped_mm import (TILE_M, grouped_mm,
+                                                  grouped_mm_dw)
+    E, K, N = len(sizes), 128, 256
+    tiles = [max(-(-n // TILE_M), 1) for n in sizes]
+    M = (sum(tiles) + 3) * TILE_M               # three tiles never used
+    te = np.full((M // TILE_M,), E - 1, np.int32)
+    te[:sum(tiles)] = np.repeat(np.arange(E), tiles)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(ks[0], (M, K), jnp.float32)
+    dy = jax.random.normal(ks[1], (M, N), jnp.float32)
+    w = jax.random.normal(ks[2], (E, K, N), jnp.float32)
+    starts = np.concatenate([[0], np.cumsum(tiles)[:-1]]) * TILE_M
+    valid = np.zeros((M, 1), bool)
+    for e0, n in zip(starts, sizes):
+        valid[e0:e0 + n] = True
+    dy = jnp.where(valid, dy, 0)                # rows without a pair: zero
+    te_j, n_j = jnp.asarray(te), jnp.asarray([sum(tiles)], jnp.int32)
+    out = grouped_mm(x, w, te_j, n_j)
+    dx = grouped_mm(dy, w, te_j, n_j, trans_w=True)
+    dw = grouped_mm_dw(x, dy, te_j, n_j, E)
+    for e, (e0, t) in enumerate(zip(starts, tiles)):
+        rows = slice(e0, e0 + t * TILE_M)
+        np.testing.assert_allclose(out[rows], x[rows] @ w[e],
+                                   atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(dx[rows], dy[rows] @ w[e].T,
+                                   atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(dw[e], x[rows].T @ dy[rows],
+                                   atol=2e-3, rtol=2e-4)

@@ -353,6 +353,94 @@ def test_flash_gqa_8k_head_dim_64():
             assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
 
 
+def test_flash_split_8k_at_the_expert_cell_s_shapes():
+    """Latent attention as ``xing4.0-29b-a4b.pretrain-8k-moe`` runs it:
+    S 8192, heads of 128 | 64 | 128, one shared rotated key — scores
+    from two operand pairs, the two-kernel backward, five gradients.
+    The composite holds [S, S] a head, so it is asked for two heads."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    s, h = 8192, 4
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    bf = jnp.bfloat16
+    q = jax.random.normal(ks[0], (1, s, h, 128), bf)
+    q2 = jax.random.normal(ks[1], (1, s, h, 64), bf)
+    k = jax.random.normal(ks[2], (1, s, h, 128), bf)
+    k2 = jax.random.normal(ks[3], (1, s, 64), bf)
+    v = jax.random.normal(ks[4], (1, s, h, 128), bf)
+    w = jax.random.normal(ks[5], (1, s, h, 128), jnp.float32)
+    scale = float(192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)  # weak-typed
+    out, vjp = jax.vjp(lambda *a: flash_attention_split(*a, scale).astype(
+        jnp.float32), q, q2, k, k2, v)
+    dq, dq2, dk, dk2, dv = (g.astype(jnp.float32) for g in vjp(w))
+
+    def plain(q, q2, k, k2, v):
+        qq = jnp.concatenate([q, q2], -1)
+        kk = jnp.concatenate([k, jnp.broadcast_to(
+            k2[:, :, None], q2.shape)], -1)
+        sc = jnp.einsum("bqhd,bkhd->bhqk", qq, kk) * scale
+        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
+    f32 = [x.astype(jnp.float32) for x in (q, q2, k, k2, v)]
+    dk2_sum = 0.0
+    for heads in (slice(0, 2), slice(2, 4)):
+        cut = [f32[0][:, :, heads], f32[1][:, :, heads], f32[2][:, :, heads],
+               f32[3], f32[4][:, :, heads]]
+        want, ref_vjp = jax.vjp(plain, *cut)
+        wq, wq2, wk, wk2, wv = ref_vjp(w[:, :, heads])
+        dk2_sum = dk2_sum + wk2
+        for got, ref in ((out[:, :, heads], want), (dq[:, :, heads], wq),
+                         (dq2[:, :, heads], wq2), (dk[:, :, heads], wk),
+                         (dv[:, :, heads], wv)):
+            err = float(jnp.abs(got - ref).max())
+            assert err < 6e-2 * max(1.0, float(jnp.abs(ref).max())), err
+    err = float(jnp.abs(dk2 - dk2_sum).max())
+    assert err < 6e-2 * max(1.0, float(jnp.abs(dk2_sum).max())), err
+
+
+def test_grouped_mm_at_the_expert_cell_s_shapes():
+    """The grouped products of ``ops/moe.py`` at the cell's widths: 8
+    experts of 3584 x 2048 (gate | up) and 1024 x 3584, fp32 weights
+    cast in VMEM, bf16 rows, groups of uneven sizes with one EMPTY, tiles
+    never used behind them — forward, dx and dw against a loop over the
+    experts."""
+    from paddle_tpu.ops.pallas.grouped_mm import (TILE_M, grouped_mm,
+                                                  grouped_mm_dw)
+    E, C, F = 8, 3584, 1024
+    sizes = [1100, 0, 900, 1024, 1, 2047, 513, 700]
+    tiles = [max(-(-n // TILE_M), 1) for n in sizes]
+    M = (sum(tiles) + 40) * TILE_M
+    te = np.full((M // TILE_M,), E - 1, np.int32)
+    te[:sum(tiles)] = np.repeat(np.arange(E), tiles)
+    starts = np.concatenate([[0], np.cumsum(tiles)[:-1]]) * TILE_M
+    valid = np.zeros((M, 1), bool)
+    for e0, n in zip(starts, sizes):
+        valid[e0:e0 + n] = True
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    bf = jnp.bfloat16
+    te_j, n_j = jnp.asarray(te), jnp.asarray([sum(tiles)], jnp.int32)
+    for (K, N), kk in (((C, 2 * F), ks[:3]), ((F, C), ks[2:])):
+        x = jax.random.normal(kk[0], (M, K), bf)
+        dy = jnp.where(valid, jax.random.normal(kk[1], (M, N), bf), 0)
+        w = jax.random.normal(kk[2], (E, K, N), jnp.float32) / K ** 0.5
+        out = grouped_mm(x, w, te_j, n_j).astype(jnp.float32)
+        dx = grouped_mm(dy, w, te_j, n_j, trans_w=True).astype(jnp.float32)
+        dw = grouped_mm_dw(x, dy, te_j, n_j, E)
+        assert dw.dtype == jnp.float32
+        wb = w.astype(bf)
+        for e, (e0, t) in enumerate(zip(starts, tiles)):
+            rows = slice(int(e0), int(e0) + t * TILE_M)
+            pairs = ((out[rows], jnp.dot(x[rows], wb[e],
+                                         preferred_element_type=jnp.float32)),
+                     (dx[rows], jnp.dot(dy[rows], wb[e].T,
+                                        preferred_element_type=jnp.float32)),
+                     (dw[e], jnp.dot(x[rows].T, dy[rows],
+                                     preferred_element_type=jnp.float32)))
+            for got, want in pairs:
+                err = float(jnp.abs(got - want).max())
+                assert err < 3e-2 * max(1.0, float(jnp.abs(want).max())), \
+                    (e, err)
+
+
 def _ssd_inputs(b, s, h, p, n, dtype):
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     x = jax.random.normal(ks[0], (b, s, h, p), dtype)

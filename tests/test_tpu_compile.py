@@ -537,6 +537,50 @@ def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
     assert ma.temp_size_in_bytes <= 10_729_414_144      # PR 31's
 
 
+def test_flash_attention_split_at_the_expert_cell_s_shapes(one_chip,
+                                                           compiled):
+    """Latent attention at 2 x 8192, 32 heads of 128 | 64 | 128: Mosaic
+    takes the two operand pairs, the shared 64-wide key whole, and the
+    VMEM the whole-row operands ask for; three kernels, five
+    gradients."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    b, s = ROWS_8K
+    wide = _sds(one_chip, (b, s, 32, 128), jnp.bfloat16)
+    text = _text(jax.grad(
+        lambda *a: flash_attention_split(*a, 0.1).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4)), wide,
+        _sds(one_chip, (b, s, 32, 64), jnp.bfloat16), wide,
+        _sds(one_chip, (b, s, 64), jnp.bfloat16), wide)
+    assert text.count(KERNEL) == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+
+
+def test_train_step_of_the_expert_cell(one_chip, compiled):
+    """The step of ``xing4.0-29b-a4b.pretrain-8k-moe`` as the benchmark
+    builds it — a dense lead and four expert layers, every published
+    width, 8 of 64 experts, 2 x 8192 tokens — fits a described v5e with
+    NO compiler rematerialization (the test that chose the share: with 16
+    experts and a quarter of the vocabulary it compiled with six
+    ``.remat`` matrix products), runs attention and the grouped products
+    as kernels, and holds no bf16 copy of an expert stack."""
+    from benchmark import harness
+    cell = harness.find_cell("xing4.0-29b-a4b.pretrain-8k-moe")
+    assert cell.conf["num_hidden_layers"] == 5 and \
+        (cell.traffic["batch"], cell.traffic["seq"]) == ROWS_8K
+    c = _cell_step(one_chip, cell.name)
+    text = c.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                   "grouped_mm", "grouped_mm_dw"):
+        assert kernel in text, kernel
+    # a dense lead: 4 flash; an expert layer: 4 flash + 2 + 2 + 2 grouped
+    # products forward, recomputed, backward, + 2 dw
+    assert text.count(KERNEL) == 16
+    assert ".remat" not in text
+    assert not re.search(r"bf16\[(4,)?8,3584,2048\]", text)
+    assert c.memory_analysis().argument_size_in_bytes == 3_057_670_144
+
+
 # sha256 of the dense cell's optimized step at depth 18 with the debug
 # locations out (op metadata, the kernels' serialized bodies, which
 # carry source paths, and the tables of files and frames): PR 31's

@@ -142,6 +142,56 @@ def test_train_step_by_kind_carries_the_family_s_scopes():
             xplane_meta.KERNELS + granite_hybrid.KERNELS) == kernel
 
 
+def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
+    """``mla_dense`` / ``mla_moe``: the nine scopes of the family
+    ``xing_mhc_moe`` beside the base vocabulary's (``attn_qkv`` gives way
+    to ``mla_q`` / ``mla_kv``; the dense lead keeps ``mlp``), forward and
+    backward — the routed path is ONE custom_vjp whose backward names
+    its own scopes — and the kernels under their scopes."""
+    from benchmark.models import xing_mhc_moe
+    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
+               intermediate_size=256, num_hidden_layers=2,
+               num_attention_heads=2, num_key_value_heads=2,
+               q_lora_rank=64, kv_lora_rank=128, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, first_k_dense_replace=1,
+               rope_scaling={"type": "yarn", "factor": 4, "beta_fast": 32,
+                             "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                             "original_max_position_embeddings": 64},
+               moe_intermediate_size=128, n_routed_experts=8,
+               experts_held=2, expert_first=2, n_shared_experts=1,
+               num_experts_per_tok=2, routed_scaling_factor=2.0, hc_mult=4)
+    assert cfg.layer_types == ("mla_dense", "mla_moe")
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = (BLOCK - {"attn_qkv"}) | {"embed", "layer_scan", "attn",
+                                     "loss_head", "optimizer"} \
+        | set(xing_mhc_moe.SCOPES)
+    assert want <= names, want - names
+    assert set(xing_mhc_moe.SCOPES) <= family_names()[0]
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    for scope, kernel in (("moe_experts", "grouped_mm"),
+                          ("moe_experts", "grouped_mm_dw"),
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dq"),
+                          ("attn", "flash_bwd_dkv")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            xplane_meta.KERNELS + xing_mhc_moe.KERNELS) == kernel
+    # the backward of the routed path carries its scopes too (its ops
+    # lie in the remat's backward, outside ``rematted_computation``)
+    for scope in ("moe_dispatch", "moe_experts", "moe_combine"):
+        assert any(p.startswith(f"checkpoint/block/{scope}/")
+                   for p in paths), scope
+
+
 def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
     """The primitives of ``jaxpr`` (calls, scans and remat bodies
     walked, kernel bodies not) whose name stack holds ``scope``."""
@@ -370,7 +420,9 @@ def pallas_call_names() -> list:
 
 def test_every_pallas_call_site_carries_a_distinct_name():
     names = pallas_call_names()
-    assert len(names) == 17 and len(set(names)) == 17
+    # ``flash_attention_split`` runs through the dense kernels' three
+    # call sites: a name is one site
+    assert len(names) == 19 and len(set(names)) == 19
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family
@@ -378,7 +430,7 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     # base vocabulary
     own = family_names()[1]
     assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
-                   "causal_conv_bwd"}
+                   "causal_conv_bwd", "grouped_mm", "grouped_mm_dw"}
     assert not own & set(xplane_meta.KERNELS)
     assert set(names) <= set(xplane_meta.KERNELS) | own
     assert own <= set(names)
