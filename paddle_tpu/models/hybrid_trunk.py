@@ -395,7 +395,8 @@ def _expert_layer(bp, x, cfg):
         idx, gate = moe.route(rows, bp["w_router"], cfg.num_experts_per_tok,
                               cfg.routed_scaling_factor)
     with jax.named_scope("moe_dispatch"):
-        p = moe.plan(idx, cfg.expert_first, cfg.experts_held)
+        p = moe.plan(idx, cfg.expert_first, cfg.experts_held,
+                     cfg.n_routed_experts)
     routed = moe.routed_ffn(rows, gate, bp["we_gate_up"], bp["we_down"],
                             p).reshape(b, s, c)
     with jax.named_scope("moe_shared"):

@@ -11,16 +11,28 @@ would add is left out: on one device the layer runs without its
 exchange, and nothing stands in for the other devices.
 
 No capacity, no drop: :func:`plan` sorts the pairs by expert into a
-buffer of ``rows_bound(T, k, held)`` rows — every pair of every token,
-the static bound; all the tokens on one held expert is a legal load —
-with each expert's group starting at a multiple of ``TILE_M`` rows and
-holding one tile at least, so that a row tile belongs to one expert and
-every expert has one.  The group sizes are DATA; a
-tile that holds no rows costs an empty grid step of the grouped
-products (``ops/pallas/grouped_mm.py``).  Both ways through the buffer
-are gathers, forward and backward (:func:`routed_ffn`): a row reads its
-pair's token, a token sums its pairs' rows — no scatter-add of
-activations either way.
+buffer with each expert's group starting at a multiple of ``TILE_M``
+rows and holding one tile at least, so that a row tile belongs to one
+expert and every expert has one.  The group sizes are DATA.  The
+plan's arrays have the shapes of ``rows_bound(T, k, held)`` rows —
+every pair of every token; all the tokens on one held expert is a legal
+load — but the PASSES run on the bound the load at hand asks for
+(:func:`_at_the_load`): ``load_bound`` rows, ``LOAD_ROOM`` times the
+pairs a uniform router ``published`` wide sends here, where the plan's
+own tile count fits them, the bound of any load otherwise — one
+``lax.cond`` on data in the forward and one, on the saved plan, in the
+backward, the same code at two static sizes.  Nothing is dropped on
+either; with every expert held the two are the same rows and no branch
+is built.
+
+Both ways through the buffer are gathers, forward and backward
+(:func:`routed_ffn`): a row reads its pair's token; a token sums the
+rows IT HAS — the rows are gathered into token order (the plan's slots:
+the kept pairs sorted by pair, each with its row) and a kernel sums
+each token's run of them in fp32 (``ops/pallas/moe_sum_pairs.py``) — no
+scatter-add of activations either way, no ``k`` row reads for a token
+that holds half a pair.  A tile that holds no rows costs an empty grid
+step of the grouped products (``ops/pallas/grouped_mm.py``).
 
 ``distributed/parallel/expert_parallel.py`` is the other thing: GShard's
 capacity API (one-hot ``[T, k, E, C]``, dropping), kept for Paddle's
@@ -29,31 +41,68 @@ capacity API (one-hot ``[T, k, E, C]``, dropping), kept for Paddle's
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from .pallas.grouped_mm import TILE_M, grouped_mm, grouped_mm_dw
+from .pallas.moe_sum_pairs import moe_sum_pairs
 
-__all__ = ["Plan", "route", "plan", "rows_bound", "routed_ffn"]
+__all__ = ["Plan", "route", "plan", "rows_bound", "load_bound", "routed_ffn"]
 
 I32 = jnp.int32
 
+# The bound that follows the load holds this many times the pairs a
+# uniform router sends to the held experts.  The count of kept pairs is
+# a sum over tokens * k picks, each held with probability held /
+# published: at the widths this module is built for its deviation is
+# under a hundredth of its mean, and the tiles' padding has rows of its
+# own (``held`` tiles, as in ``rows_bound``).  What the room is for is a
+# router that has DRIFTED towards the held experts: twice their share
+# is a router the balance rule has lost, and past it the full bound
+# runs — nothing is dropped either way.
+LOAD_ROOM = 2
 
-class Plan(NamedTuple):
-    """Where every kept pair lies in the sorted buffer of ``M`` rows."""
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["row_pair", "tile_expert", "n_tiles", "slot_row",
+                 "slot_token", "first_slot"],
+    meta_fields=["k", "load_rows"])
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """Where every kept pair lies in the sorted buffer of ``M`` rows,
+    and the kept pairs in token order (the slots: ``M`` less the
+    padding, every pair there can be)."""
     row_pair: jax.Array     # [M] the pair (token * k + pick) a row holds, -1: none
-    pos: jax.Array          # [T, k] the row of a pair; 0 where its expert is not held
-    held: jax.Array         # [T, k] whether the pair's expert is held here
     tile_expert: jax.Array  # [M / TILE_M] the local expert of a row tile
     n_tiles: jax.Array      # [1] tiles in use: every expert has one at least
+    slot_row: jax.Array     # [slots] the row of a slot's pair; 0 past the last pair
+    slot_token: jax.Array   # [slots] the token of a slot's pair; -1 past the last
+    first_slot: jax.Array   # [T + 1] a token's first slot; last: the pairs kept
+    k: int                  # static: picks a token
+    load_rows: int          # static: rows of the bound that follows the load
 
 
 def rows_bound(tokens: int, k: int, held: int) -> int:
-    """Rows of the sorted buffer: every pair, and a tile's padding an
-    expert."""
-    return -(-tokens * min(k, held) // TILE_M) * TILE_M + held * TILE_M
+    """Rows of the sorted buffer that holds ANY load: every pair, and a
+    tile's padding an expert."""
+    return _padded(tokens * min(k, held), held)
+
+
+def load_bound(tokens: int, k: int, held: int, published: int) -> int:
+    """Rows of the bound that follows the load: ``LOAD_ROOM`` times the
+    pairs expected of a router ``published`` wide, padded like
+    ``rows_bound`` and never past it (``held == published``: the same)."""
+    expected = -(-tokens * k * held // published)
+    return min(_padded(LOAD_ROOM * expected, held),
+               rows_bound(tokens, k, held))
+
+
+def _padded(pairs: int, held: int) -> int:
+    return -(-pairs // TILE_M) * TILE_M + held * TILE_M
 
 
 def route(x, w_router, k: int, scale: float):
@@ -69,16 +118,19 @@ def route(x, w_router, k: int, scale: float):
     return idx.astype(I32), gate
 
 
-def plan(idx, first: int, held: int) -> Plan:
+def plan(idx, first: int, held: int, published: int) -> Plan:
     """Sort the pairs of ``idx [T, k]`` whose expert is one of ``first
-    .. first + held - 1`` by expert, groups padded to whole tiles."""
+    .. first + held - 1`` (of a router ``published`` wide) by expert,
+    groups padded to whole tiles, and list them in token order."""
     T, k = idx.shape
     M = rows_bound(T, k, held)
     local = idx - first
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(I32)
-    skey = key[order]
+    pairs = jnp.arange(T * k, dtype=I32)
+    # two sorts and one scatter of sorted rows: a gather or a scatter of
+    # T * k scalars costs five sorts of them on a v5e
+    skey, order = jax.lax.sort_key_val(key, pairs)      # stable: by expert
     sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=I32)[None, :],
                     axis=0, dtype=I32)
     # at least one tile an expert: the products' dw visits every expert
@@ -88,29 +140,78 @@ def plan(idx, first: int, held: int) -> Plan:
     padded = (ends - tiles) * TILE_M                    # in the buffer
     e = jnp.minimum(skey, held - 1)
     kept = skey < held
-    dest = jnp.where(kept, padded[e] + jnp.arange(T * k, dtype=I32)
-                     - start[e], M)
-    row_pair = jnp.full((M,), -1, I32).at[dest].set(order, mode="drop")
-    pos = jnp.zeros((T * k,), I32).at[order].set(jnp.where(kept, dest, 0))
+    dest = jnp.where(kept, padded[e] + pairs - start[e], M)
+    row_pair = jnp.full((M,), -1, I32).at[dest].set(
+        order, mode="drop", indices_are_sorted=True, unique_indices=True)
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(M // TILE_M, dtype=I32),
                          side="right"), held - 1).astype(I32)
-    return Plan(row_pair, pos.reshape(T, k), is_held, tile_expert,
-                ends[-1:])
+    # the kept pairs by pair, which is by token, each with its row
+    slot_pair, slot_row = jax.lax.sort_key_val(
+        jnp.where(kept, order, T * k), jnp.where(kept, dest, 0))
+    slot_token = jnp.where(slot_pair < T * k, slot_pair // k, -1)
+    slots = M - held * TILE_M                           # the pairs' bound
+
+    def fit(a, fill):
+        return jnp.pad(a[:slots], (0, max(slots - T * k, 0)),
+                       constant_values=fill)
+    first_slot = jnp.concatenate(
+        [jnp.zeros((1,), I32),
+         jnp.cumsum(jnp.sum(is_held, axis=1, dtype=I32), dtype=I32)])
+    return Plan(row_pair, tile_expert, ends[-1:], fit(slot_row, 0),
+                fit(slot_token, -1), first_slot, k,
+                load_bound(T, k, held, published))
+
+
+def _first_rows(p: Plan, m: int) -> Plan:
+    """The plan of a buffer of ``m`` rows: a load whose tiles fit ``m``
+    rows lies in the plan's first rows, tiles and slots."""
+    return dataclasses.replace(
+        p, row_pair=p.row_pair[:m], tile_expert=p.tile_expert[:m // TILE_M],
+        slot_row=p.slot_row[:m], slot_token=p.slot_token[:m])
 
 
 def _rows_of_pairs(buf, p: Plan):
     """[T, C]: the sum of a token's kept pairs' rows of ``buf [M, C]``,
-    in fp32 (row 0 is read for a pair whose expert is not held, and
-    masked)."""
-    picked = jnp.where(p.held[..., None], buf[p.pos], 0)
-    return jnp.sum(picked.astype(jnp.float32), axis=1)
+    summed in fp32, in buf's dtype.  XLA gathers the rows into token
+    order (the slots: the bound's pairs, not ``T * k`` row reads; a slot
+    past the last pair reads a row that is there, and belongs to no
+    token); the kernel sums each token's run of them."""
+    return moe_sum_pairs(buf[p.slot_row], p.slot_token, p.first_slot)
 
 
 def _tokens_of_rows(x, p: Plan):
     """[M, C]: the token of each row's pair (token 0 where it holds
     none: finite, and never used)."""
-    return x[jnp.maximum(p.row_pair, 0) // p.pos.shape[1]]
+    return x[jnp.maximum(p.row_pair, 0) // p.k]
+
+
+def _gate_of_rows(gate, p: Plan):
+    return jnp.where(p.row_pair >= 0,
+                     gate.reshape(-1)[jnp.maximum(p.row_pair, 0)], 0)
+
+
+def _at_the_load(fn, p: Plan, *args):
+    """``fn(plan, *args)`` on a buffer as long as the load at hand asks:
+    the plan's first ``load_rows`` rows where its tiles fit them — the
+    plan's own ``n_tiles``, data —, all of them otherwise.  One program
+    at two values of one static size; each carries a scope of its own,
+    OUTSIDE the ``moe_*`` scopes its ops are charged to, so a trace
+    shows which ran."""
+    full = p.row_pair.shape[0]
+
+    def at(m, scope):
+        def run(p, *args):
+            with jax.named_scope(scope):
+                with jax.named_scope("moe_dispatch"):
+                    p = _first_rows(p, m)
+                return fn(p, *args)
+        return run
+    if p.load_rows == full:
+        return at(full, "moe_bound_all")(p, *args)
+    return jax.lax.cond(p.n_tiles[0] * TILE_M <= p.load_rows,
+                        at(p.load_rows, "moe_bound_load"),
+                        at(full, "moe_bound_all"), p, *args)
 
 
 @jax.custom_vjp
@@ -124,16 +225,14 @@ def routed_ffn(x, gate, w_gate_up, w_down, p: Plan):
     The gate rides on the F-wide hidden rows, before the down product.
     One backward for the whole path: it keeps x, the [M, 2 F] product
     and the plan, gathers the rows again and forms the hidden rows again
-    — no [M, C] buffer outlives the pass that made it."""
-    return _routed_fwd(x, gate, w_gate_up, w_down, p)[0]
+    — no [M, C] buffer outlives the pass that made it.  Every pass runs
+    on the bound the load asks for (:func:`_at_the_load`); what is kept
+    has the full bound's shapes, filled in its first rows."""
+    return _at_the_load(lambda *a: _forward(*a)[0], p, x, gate, w_gate_up,
+                        w_down)
 
 
-def _gate_of_rows(gate, p: Plan):
-    return jnp.where(p.row_pair >= 0,
-                     gate.reshape(-1)[jnp.maximum(p.row_pair, 0)], 0)
-
-
-def _routed_fwd(x, gate, w_gate_up, w_down, p):
+def _forward(p, x, gate, w_gate_up, w_down):
     te, n, f = p.tile_expert, p.n_tiles, w_down.shape[1]
     with jax.named_scope("moe_dispatch"):
         rows = _tokens_of_rows(x, p)
@@ -144,18 +243,35 @@ def _routed_fwd(x, gate, w_gate_up, w_down, p):
         out = grouped_mm(h, w_down, te, n)
     with jax.named_scope("moe_combine"):
         y = _rows_of_pairs(out, p).astype(x.dtype)
+    return y, gu
+
+
+def _routed_fwd(x, gate, w_gate_up, w_down, p):
+    full = p.row_pair.shape[0]
+
+    def kept(p, *args):
+        y, gu = _forward(p, *args)
+        with jax.named_scope("moe_experts"):
+            return y, jnp.pad(gu, ((0, full - gu.shape[0]), (0, 0)))
+    y, gu = _at_the_load(kept, p, x, gate, w_gate_up, w_down)
     return y, (x, gate, w_gate_up, w_down, p, gu)
 
 
 def _routed_bwd(res, dy):
     x, gate, w_gate_up, w_down, p, gu = res
+    d = _at_the_load(_backward, p, x, gate, w_gate_up, w_down, gu, dy)
+    return d + (None,)
+
+
+def _backward(p, x, gate, w_gate_up, w_down, gu, dy):
     te, n, f32 = p.tile_expert, p.n_tiles, jnp.float32
     f, held = w_down.shape[1], w_down.shape[0]
     with jax.named_scope("moe_combine"):
-        # a row without a pair takes no gradient: its products are zero
-        d_out = jnp.where((p.row_pair >= 0)[:, None],
-                          _tokens_of_rows(dy, p), 0)
+        # a row without a pair reads token 0's dy: finite, and every
+        # product it enters has the row's gate, which is zero
+        d_out = _tokens_of_rows(dy, p)
     with jax.named_scope("moe_experts"):
+        gu = gu[:p.row_pair.shape[0]]
         g, u = gu[:, :f].astype(f32), gu[:, f:].astype(f32)
         g_row = _gate_of_rows(gate, p)[:, None]
         sig = jax.nn.sigmoid(g)
@@ -175,9 +291,14 @@ def _routed_bwd(res, dy):
         d_rows = grouped_mm(d_gu, w_gate_up, te, n, trans_w=True)
     with jax.named_scope("moe_dispatch"):
         dx = _rows_of_pairs(d_rows, p).astype(x.dtype)
-        d_gate = jnp.where(p.held, d_gate_row[p.pos], 0).astype(gate.dtype)
+        # the rows hand their pairs the gates' gradient: M writes, not
+        # T * k reads
+        d_gate = jnp.zeros(gate.size, gate.dtype).at[
+            jnp.where(p.row_pair >= 0, p.row_pair, gate.size)].set(
+            d_gate_row.astype(gate.dtype), mode="drop",
+            unique_indices=True).reshape(gate.shape)
     return (dx, d_gate, d_wgu.astype(w_gate_up.dtype),
-            d_wd.astype(w_down.dtype), None)
+            d_wd.astype(w_down.dtype))
 
 
 routed_ffn.defvjp(_routed_fwd, _routed_bwd)

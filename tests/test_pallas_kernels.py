@@ -464,3 +464,65 @@ def test_grouped_mm_matches_a_loop_over_the_experts(sizes):
                                    atol=2e-4, rtol=2e-4)
         np.testing.assert_allclose(dw[e], x[rows].T @ dy[rows],
                                    atol=2e-3, rtol=2e-4)
+
+
+# -- the token side of the routed experts --------------------------------------
+def _runs(counts, P):
+    """Slots of ``len(counts)`` tokens holding ``counts`` pairs each:
+    (slot_token [P], first_slot [T + 1])."""
+    first = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    token = np.full((P,), -1, np.int32)
+    token[:first[-1]] = np.repeat(np.arange(len(counts)), counts)
+    return jnp.asarray(token), jnp.asarray(first)
+
+
+@pytest.mark.parametrize("load", ["none", "one_each", "four_each", "mixed",
+                                  "all_on_the_first_tile",
+                                  "a_run_across_three_chunks"])
+@pytest.mark.parametrize("T", [512, 300])
+def test_moe_sum_pairs_sums_each_token_s_run_of_rows(load, T):
+    """``moe_sum_pairs``: rows in token order, a token's run anywhere
+    from empty to four rows, runs that start mid-chunk and cross chunks,
+    tokens that are no whole number of tiles, slots past the last pair
+    holding rows that must not be read into any sum."""
+    from paddle_tpu.ops.pallas.moe_sum_pairs import CHUNK, moe_sum_pairs
+    rng = np.random.default_rng(3)
+    counts = {"none": np.zeros(T, int), "one_each": np.ones(T, int),
+              "four_each": np.full(T, 4),
+              "mixed": rng.integers(0, 5, T),
+              "all_on_the_first_tile": np.where(np.arange(T) < 200, 4, 0),
+              "a_run_across_three_chunks": np.where(
+                  (np.arange(T) >= 100) & (np.arange(T) < 260), 4, 0),
+              }[load]
+    P = 4 * 512 + CHUNK
+    token, first = _runs(counts, P)
+    rows = jax.random.normal(jax.random.PRNGKey(8), (P, 256), jnp.float32)
+    got = np.asarray(moe_sum_pairs(rows, token, first))
+    assert got.shape == (T, 256)
+    r = np.asarray(rows)
+    # fp32 sums in the product's order: one ulp of what is summed
+    for t in range(T):
+        mine = r[int(first[t]):int(first[t + 1])]
+        want = mine.sum(0) if len(mine) else np.zeros(256, np.float32)
+        room = np.spacing(np.abs(mine).sum(0).astype(np.float32)) \
+            if len(mine) > 2 else 0
+        assert (np.abs(got[t] - want) <= room).all(), t
+
+
+def test_moe_sum_pairs_rounds_once_in_the_rows_dtype():
+    """bf16 rows: the sum is fp32 and the store is the one rounding."""
+    from paddle_tpu.ops.pallas.moe_sum_pairs import moe_sum_pairs
+    counts = np.arange(512) % 5
+    token, first = _runs(counts, 2048)
+    rows = jax.random.normal(jax.random.PRNGKey(9), (2048, 128),
+                             jnp.bfloat16)
+    got = moe_sum_pairs(rows, token, first)
+    assert got.dtype == jnp.bfloat16
+    r = np.asarray(rows.astype(jnp.float32))
+    want = np.stack([r[int(first[t]):int(first[t + 1])].sum(0)
+                     for t in range(512)])
+    assert (np.asarray(got.astype(jnp.float32))
+            == np.asarray(jnp.asarray(want).astype(jnp.bfloat16)
+                          .astype(jnp.float32))).mean() > 0.999
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=8e-3,
+                               atol=1e-6)

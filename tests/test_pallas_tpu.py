@@ -441,6 +441,31 @@ def test_grouped_mm_at_the_expert_cell_s_shapes():
                     (e, err)
 
 
+def test_moe_sum_pairs_at_the_expert_cell_s_shapes():
+    """The token side of ``ops/moe.py`` through Mosaic at the cell's
+    widths — 16,384 tokens of 3584, tokens holding 0 to 4 pairs, on the
+    bound that follows the load (18,432 slots) and on the bound of any
+    load (65,536) — against the sum of each token's run of rows."""
+    from paddle_tpu.ops.pallas.moe_sum_pairs import moe_sum_pairs
+    T, C = 16384, 3584
+    rng = np.random.default_rng(11)
+    for P, p_pair in ((18432, 0.125), (65536, 0.9)):
+        counts = rng.binomial(4, p_pair, T)
+        first = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+        assert first[-1] <= P
+        token = np.full((P,), -1, np.int32)
+        token[:first[-1]] = np.repeat(np.arange(T), counts)
+        rows = jax.random.normal(jax.random.PRNGKey(P), (P, C), jnp.bfloat16)
+        got = moe_sum_pairs(rows, jnp.asarray(token), jnp.asarray(first))
+        slot = np.minimum(first[:-1, None] + np.arange(4)[None], P - 1)
+        want = jnp.sum(jnp.where((np.arange(4)[None] < counts[:, None])
+                                 [..., None], rows[slot].astype(jnp.float32),
+                                 0), axis=1)
+        err = float(jnp.abs(got.astype(jnp.float32) - want).max())
+        assert err <= 2 ** -7 * max(1.0, float(jnp.abs(want).max())), err
+        assert not bool(jnp.any(got[counts == 0]))
+
+
 def _ssd_inputs(b, s, h, p, n, dtype):
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     x = jax.random.normal(ks[0], (b, s, h, p), dtype)

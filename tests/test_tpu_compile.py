@@ -556,6 +556,37 @@ def test_flash_attention_split_at_the_expert_cell_s_shapes(one_chip,
         assert kernel in text
 
 
+@pytest.mark.parametrize("held,kernels,conditionals", [(8, 16, 2),
+                                                       (64, 8, 0)])
+def test_routed_experts_at_the_expert_cell_s_shapes(one_chip, compiled,
+                                                    held, kernels,
+                                                    conditionals):
+    """Plan, forward and backward of ``ops/moe.routed_ffn`` alone at
+    16,384 tokens of 3584, experts of 1024, top-4 of 64: with 8 held
+    the path is built on BOTH bounds (3 + 5 kernels each, one
+    ``conditional`` forward and one backward), with all 64 held on the
+    one there is.  Mosaic takes the token side's kernel at both sizes of
+    its slots."""
+    from paddle_tpu.ops import moe
+    T, C, F, K, PUB = 16384, 3584, 1024, 4, 64
+
+    def f(x, gate, wgu, wd, idx, co):
+        p = moe.plan(idx, 0, held, PUB)
+        y, vjp = jax.vjp(lambda *a: moe.routed_ffn(*a, p), x, gate, wgu, wd)
+        return (y,) + vjp(co)
+    text = _text(f, _sds(one_chip, (T, C), jnp.bfloat16),
+                 _sds(one_chip, (T, K), jnp.float32),
+                 _sds(one_chip, (held, C, 2 * F), jnp.float32),
+                 _sds(one_chip, (held, F, C), jnp.float32),
+                 _sds(one_chip, (T, K), jnp.int32),
+                 _sds(one_chip, (T, C), jnp.bfloat16))
+    assert text.count(KERNEL) == kernels
+    assert len(re.findall(r" conditional\(", text)) == conditionals
+    for kernel in ("grouped_mm", "grouped_mm_dw", "moe_sum_pairs"):
+        assert kernel in text, kernel
+    assert ("bf16[18432,3584]" in text) == (held == 8)
+
+
 def test_train_step_of_the_expert_cell(one_chip, compiled):
     """The step of ``xing4.0-29b-a4b.pretrain-8k-moe`` as the benchmark
     builds it — a dense lead and four expert layers, every published
@@ -571,11 +602,17 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     c = _cell_step(one_chip, cell.name)
     text = c.as_text()
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                   "grouped_mm", "grouped_mm_dw"):
+                   "grouped_mm", "grouped_mm_dw", "moe_sum_pairs"):
         assert kernel in text, kernel
-    # a dense lead: 4 flash; an expert layer: 4 flash + 2 + 2 + 2 grouped
-    # products forward, recomputed, backward, + 2 dw
-    assert text.count(KERNEL) == 16
+    # a dense lead: 4 flash; an expert layer: 4 flash, and the routed
+    # path ON EACH OF ITS TWO BOUNDS (18,432 rows where the load's tiles
+    # fit them, 67,584 otherwise: one ``conditional`` a pass): 2 grouped
+    # products + the token side's sum forward, the same recomputed, 2
+    # products + 2 dw + the sum backward
+    assert text.count(KERNEL) == 8 + 2 * (3 + 3 + 5)
+    assert len(re.findall(r" conditional\(", text)) == 3
+    for rows in (18432, 67584):
+        assert f"bf16[{rows},3584]" in text
     assert ".remat" not in text
     assert not re.search(r"bf16\[(4,)?8,3584,2048\]", text)
     assert c.memory_analysis().argument_size_in_bytes == 3_057_670_144

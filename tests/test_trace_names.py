@@ -33,6 +33,10 @@ BLOCK = {"block", "attn_qkv", "rope", "attn_out", "mlp"}
 DECODE = BLOCK | {"embed", "pool_carry", "kv_write", "paged_attn",
                   "logits", "sample"}
 PREFILL = BLOCK | {"embed", "layer_scan", "varlen_attn"}
+# kernels of the program that the readers' copies do not list yet: only a
+# ``benchmark`` PR may add ``moe_sum_pairs`` (PR 34, the expert layer's
+# token side) to ``benchmark/models/xing_mhc_moe.KERNELS`` (ROADMAP D14)
+AHEAD = ("moe_sum_pairs",)
 
 
 def scope_names(lowered) -> set:
@@ -178,18 +182,65 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
     paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
     for scope, kernel in (("moe_experts", "grouped_mm"),
                           ("moe_experts", "grouped_mm_dw"),
+                          ("moe_combine", "moe_sum_pairs"),
+                          ("moe_dispatch", "moe_sum_pairs"),
                           ("attn", "flash_fwd"), ("attn", "flash_bwd_dq"),
                           ("attn", "flash_bwd_dkv")):
         assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
                    for p in paths), kernel
+        # ``moe_sum_pairs`` (PR 34) is AHEAD of the readers' copy:
+        # ``xing_mhc_moe.KERNELS`` is a benchmark file (ROADMAP D14)
         assert xplane_meta.kernel_of(
             f"jit(step)/block/{scope}/{kernel}/pallas_call",
-            xplane_meta.KERNELS + xing_mhc_moe.KERNELS) == kernel
-    # the backward of the routed path carries its scopes too (its ops
-    # lie in the remat's backward, outside ``rematted_computation``)
-    for scope in ("moe_dispatch", "moe_experts", "moe_combine"):
-        assert any(p.startswith(f"checkpoint/block/{scope}/")
-                   for p in paths), scope
+            xplane_meta.KERNELS + xing_mhc_moe.KERNELS + AHEAD) == kernel
+    # the routed path runs on one of two bounds, each under a scope of
+    # its own OUTSIDE the family's: the innermost name a reader knows is
+    # still one of the five ``moe_*``, on every op of either branch,
+    # forward (the remat's too) and backward (the routed path is one
+    # custom_vjp; its backward lies outside ``rematted_computation``)
+    routed = [p for p in paths if "moe_bound_" in p]
+    names_of = xplane_meta.SCOPES + xing_mhc_moe.SCOPES
+    for bound in ("moe_bound_load", "moe_bound_all"):
+        assert bound not in names_of
+        for scope in ("moe_dispatch", "moe_experts", "moe_combine"):
+            for phase in ("checkpoint/rematted_computation/block/cond/",
+                          "checkpoint/block/cond/"):
+                assert any(p.startswith(phase) and f"/{bound}/{scope}/" in p
+                           for p in routed), (phase, bound, scope)
+    assert len(routed) > 100
+    for p in routed:
+        assert xplane_meta.scope_of(p, names_of) in (
+            "moe_dispatch", "moe_experts", "moe_combine"), p
+
+
+@pytest.mark.parametrize("on_load,on_all", [(6, 0), (4, 2), (0, 0)])
+def test_moe_bounds_counts_the_passes_by_their_bound(on_load, on_all):
+    """``tools/moe_bounds.py``: a pass of the routed path is two
+    ``grouped_mm`` runs under one of the two bound scopes; ops of either
+    branch that are no product add to its seconds only."""
+    spec = importlib.util.spec_from_file_location(
+        "moe_bounds", os.path.join(REPO, "tools", "moe_bounds.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def op(path, t):
+        return xplane_meta.Op("x", t, t + 1e-3, 1e-3, path, "", "", 0., 0.)
+    head = "jit(step)/checkpoint/block/cond/branch_1_fun"
+    ops, t = [op("jit(step)/block/moe_shared/dot_general", 0.0)], 1.0
+    for bound, n in (("moe_bound_load", on_load), ("moe_bound_all", on_all)):
+        for _ in range(n):
+            for tail in ("moe_experts/grouped_mm/pallas_call",
+                         "moe_experts/grouped_mm/pallas_call",
+                         "moe_experts/grouped_mm_dw/pallas_call",
+                         "moe_dispatch/gather"):
+                ops.append(op(f"{head}/{bound}/{tail}", t))
+                t += 1.0
+    got = tool.count(xplane_meta.MetaTrace({0: ops}, {0: []}, []))
+    assert got["passes"] == {"moe_bound_load": on_load,
+                             "moe_bound_all": on_all}
+    assert got["self_s"]["moe_bound_load"] == pytest.approx(4e-3 * on_load)
+    assert got["share_on_the_load_bound"] == (
+        on_load / (on_load + on_all) if on_load + on_all else None)
 
 
 def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
@@ -422,7 +473,7 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     names = pallas_call_names()
     # ``flash_attention_split`` runs through the dense kernels' three
     # call sites: a name is one site
-    assert len(names) == 19 and len(set(names)) == 19
+    assert len(names) == 20 and len(set(names)) == 20
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family
@@ -432,8 +483,9 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
                    "causal_conv_bwd", "grouped_mm", "grouped_mm_dw"}
     assert not own & set(xplane_meta.KERNELS)
-    assert set(names) <= set(xplane_meta.KERNELS) | own
-    assert own <= set(names)
+    assert set(names) <= set(xplane_meta.KERNELS) | own | set(AHEAD)
+    assert own <= set(names) and set(AHEAD) <= set(names)
+    assert not set(AHEAD) & (own | set(xplane_meta.KERNELS))
 
 
 def test_the_kernels_the_entered_cell_reads_have_a_call_site():
