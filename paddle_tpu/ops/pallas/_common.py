@@ -10,10 +10,12 @@ to int32.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-__all__ = ["idx32", "interpret"]
+__all__ = ["idx32", "interpret", "nbytes"]
 
 
 def interpret() -> bool:
@@ -38,3 +40,9 @@ def interpret() -> bool:
 
 def idx32(*idx):
     return tuple(jnp.int32(i) for i in idx)
+
+
+def nbytes(shape, dtype) -> int:
+    """Bytes of one array or block of ``shape``: what a kernel's
+    ``cost_estimate`` counts a fetch or a write of it as."""
+    return math.prod(shape) * jnp.dtype(dtype).itemsize

@@ -158,6 +158,19 @@ def _table_specs(lanes):
             pl.BlockSpec((1, lanes), lambda i, c, r: idx32(0, c)))
 
 
+def _bytes(dtype, b, s, c, rows, lanes, backward: bool) -> int:
+    """HBM bytes by the BlockSpecs.  Forward: x in and out once, the
+    8-row halo before a tile at every grid step.  Backward: x and g in,
+    dx out, three halos (before and after x's tile, after g's) and the
+    ``[b, 8, C]`` fp32 sums.  Either: the fp32 tables ``[9, C]``, again
+    for every batch row where there is more than one channel tile."""
+    passes, halos = (3, 3) if backward else (2, 1)
+    tables = (b if c // lanes > 1 else 1) * (HALO + 1) * c * 4
+    return _common.nbytes((b, s, c), dtype) * passes \
+        + _common.nbytes((b, s // rows * HALO, c), dtype) * halos \
+        + tables + (b * HALO * c * 4 if backward else 0)
+
+
 def _tables(w, bias):
     """w ``[C, K]`` as ``[8, C]`` rows of taps (a tile's lanes are its
     channels), bias ``[1, C]``, fp32."""
@@ -187,6 +200,12 @@ def _fwd(x, w, bias, offset):
         in_specs=[x_tile, x_before, *_table_specs(lanes)],
         out_specs=tile,
         name="causal_conv_fwd",
+        # an output: bias + K taps (2 K), then silu's negate, add,
+        # quotient and product around its one exp
+        cost_estimate=pl.CostEstimate(
+            flops=b * s * c * (2 * w.shape[1] + 4),
+            transcendentals=b * s * c,
+            bytes_accessed=_bytes(x.dtype, b, s, c, rows, lanes, False)),
         interpret=_common.interpret(),
     )(x, x, *_tables(w, bias))
     return out, (x, w, bias)
@@ -211,6 +230,14 @@ def _bwd(offset, res, g):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="causal_conv_bwd",
+        # the pre-activation on a tile's rows and the 8 after (2 K), the
+        # sigmoid's 3 and d silu's 5 there; dx (2 K) and the column
+        # sums of dw and dbias (2 K + 1) on the tile's own
+        cost_estimate=pl.CostEstimate(
+            flops=b * c * (s + s // rows * HALO) * (2 * k + 8)
+            + b * s * c * (4 * k + 1),
+            transcendentals=b * c * (s + s // rows * HALO),
+            bytes_accessed=_bytes(x.dtype, b, s, c, rows, lanes, True)),
         interpret=_common.interpret(),
     )(x, x, x, g, g, *_tables(w, bias))
     dwb = jnp.sum(dwb, axis=0)                          # [8, C]

@@ -359,6 +359,14 @@ def _pick_blocks(S: int):
     return None
 
 
+def _pairs(s: int, block: int, causal: bool) -> int:
+    """(q block, k block) pairs one head's kernel EXECUTES: causal, the
+    loop bounds skip the pairs above the diagonal and run the diagonal's
+    whole, masked; each counts as a pair in a ``cost_estimate``."""
+    nb = s // block
+    return nb * (nb + 1) // 2 if causal else nb * nb
+
+
 def causal_mask(q_len: int, k_len: int):
     """Boolean [q_len, k_len] causal mask with the diagonal aligned to
     the END of the kv sequence, so a 1-token decode query attends to the
@@ -520,6 +528,19 @@ def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
         second_specs = [_tile_spec(bq, k2.shape[2], tile),
                         _shared_spec(s, k2.shape[2], kv)]
         params = _split_vmem(s, d, q.dtype.itemsize)
+    nkv, d2 = k.shape[2], 0 if q2 is None else k2.shape[2]
+    pairs, it = b * h * _pairs(s, bq, causal), q.dtype.itemsize
+    cost = pl.CostEstimate(
+        # a pair: q k^T (+ q2 k2^T) and p v; on its [bq, bk] scores the
+        # scale, the running max, s - m and the row sum; exp of the
+        # scores and of the max's step, a log a row
+        flops=pairs * bq * bk * (2 * (2 * d + d2) + 4),
+        transcendentals=pairs * bq * (bk + 1) + b * h * s,
+        # q (q2) in and o out a tile a grid step; K and V a whole row
+        # once a GROUP (the index holds over its heads), k2 once a batch
+        # row; lse fp32
+        bytes_accessed=it * b * s * (2 * h * d + 2 * nkv * d
+                                     + (h + 1) * d2) + 4 * b * h * s)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           block_k=bk),
@@ -532,6 +553,7 @@ def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
         out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
         compiler_params=params,
         name="flash_fwd",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(qr, kr, vr, *second)
     return _from_kernel(out, h), (qr, kr, vr, second, out, lse)
@@ -569,6 +591,10 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
     params = _split_vmem(s, d, qr.dtype.itemsize) if second else None
     # the split form keeps the two kernels, whatever its dQ's size
     one_pass = not second and group * s * d * 4 <= ONE_PASS_DQ_BYTES
+    pairs, it = b * h * _pairs(s, bq, causal), qr.dtype.itemsize
+    # on a pair's scores, either kernel: the scale, s - lse, dP - delta
+    # and the two products that give dS
+    on_scores = 5
     if one_pass:
         # o in delta's place; dq a third output, the group's block; the
         # group's fp32 dQ and delta in scratch
@@ -588,6 +614,17 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         by_q, kv = _by_query_head(group)
         second_in = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
             if second else []
+        cost = pl.CostEstimate(
+            # a pair: q k^T (+ q2 k2^T), dO v^T, dS k (+ dS k2); delta a
+            # row once
+            flops=pairs * bq * bk * (2 * (3 * d + 2 * d2) + on_scores)
+            + 2 * b * h * s * d,
+            transcendentals=pairs * bq * bk,
+            # q, o, dO (q2) in and dq (dq2) out a tile a grid step, K, V
+            # and k2 as the forward fetches them, lse in and delta out
+            bytes_accessed=it * b * s * (4 * h * d + 2 * nkv * d
+                                         + (2 * h + 1) * d2)
+            + 2 * 4 * b * h * s)
         dq, last, *dq2_kernel = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, causal=causal,
                               sm_scale=sm_scale, block_k=bk),
@@ -603,6 +640,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
             + second_in[:1],
             compiler_params=params,
             name="flash_bwd_dq",
+            cost_estimate=cost,
             interpret=interp,
         )(qr, kr, vr, out, do, lse, *second)
         last_spec = _stat_spec(s // bq, bq, head)
@@ -613,6 +651,25 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         second_in = [_tile_spec(s, d2, head), _shared_spec(bk, d2, tile)]
         out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
         out_specs.append(_tile_spec(bk, d2, tile))
+    # a query head's whole-row operands turn with the INNERMOST axis:
+    # with more than one head a group they are fetched again at every
+    # grid step, alone they stay while the head's k blocks pass
+    visits = b * nkv * (s // bk) * group if group > 1 else b * nkv
+    cost = pl.CostEstimate(
+        # a pair: k q^T (+ k2 q2^T), P^T dO, v dO^T, dS^T q (+ dS^T q2),
+        # in one pass dS k too, and delta a row once
+        flops=pairs * bq * bk * (2 * ((5 if one_pass else 4) * d + 2 * d2)
+                                 + on_scores)
+        + (2 * b * h * s * d if one_pass else 0),
+        transcendentals=pairs * bq * bk,
+        # a head's q, dO (q2), lse and o (one pass) or delta a visit; K,
+        # V (k2) a tile a grid step; dk, dv (dq in one pass) out once,
+        # a head's part of dk2 in fp32
+        bytes_accessed=visits * s * (
+            it * (2 * d + d2 + (d if one_pass else 0))
+            + 4 * (1 if one_pass else 2))
+        + it * b * s * (4 * nkv * d + h * d2 + (h * d if one_pass else 0))
+        + 4 * b * s * h * d2)
     grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=bq, also_dq=one_pass,
@@ -626,6 +683,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         scratch_shapes=scratch,
         compiler_params=params,
         name="flash_bwd_dkv",
+        cost_estimate=cost,
         interpret=interp,
     )(qr, kr, vr, do, lse, last, *second)
     dk, dv = grads[:2]

@@ -45,7 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
 from ._common import idx32
-from .flash_attention import NEG_INF, _pick_blocks
+from .flash_attention import NEG_INF, _pairs, _pick_blocks
 
 __all__ = ["flash_attention_segmented", "segment_ids_from_cu_seqlens",
            "xla_segmented_sdpa"]
@@ -365,6 +365,16 @@ def _seg_fwd(q, k, v, seg, causal):
     seg_q = seg[:, :, None]                       # [B, S, 1]
     seg_k = seg[:, None, :]                       # [B, 1, S]
     grid = (b * h, s // bq)
+    # the BOUND: one segment a row, so every pair the causal bounds (or
+    # none) leave is run; which pairs the segments skip is data.  A
+    # pair and the bytes as ``flash_fwd`` counts them, the segment ids
+    # beside: a [bq, 1] block a grid step, the [1, s] row once a batch row
+    pairs, it = b * h * _pairs(s, bq, causal), q.dtype.itemsize
+    cost = pl.CostEstimate(
+        flops=pairs * bq * bk * (4 * d + 4),
+        transcendentals=pairs * bq * (bk + 1) + b * h * s,
+        bytes_accessed=it * b * s * d * (2 * h + 2 * nkv)
+        + 4 * b * s * (2 * h + 1))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           block_k=bk, nheads=h),
@@ -395,6 +405,7 @@ def _seg_fwd(q, k, v, seg, causal):
         out_shape=(jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
                    jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)),
         name="flash_varlen_fwd",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(kmin, kmax, qr, kr, vr, seg_q, seg_k)
     return _reshape_out(out, b, h), (qr, kr, vr, seg, out, lse)
@@ -422,6 +433,18 @@ def _seg_bwd_vjp(causal, res, dout):
     seg_q = seg[:, :, None]
     seg_k = seg[:, None, :]
     interp = _common.interpret()
+    # the bound of the forward; a pair: three products (q k^T, dO v^T,
+    # dS k) here, four (q k^T, P^T dO, dO v^T, dS^T q) for dk and dv,
+    # and five passes over its scores (scale, s - lse, dP - delta, the
+    # two products of dS)
+    pairs, it = b * h * _pairs(s, bq, causal), qr.dtype.itemsize
+    cost = pl.CostEstimate(
+        flops=pairs * bq * bk * (6 * d + 5),
+        transcendentals=pairs * bq * bk,
+        # q, dO in and dq out a tile, K and V once a group; ids, lse,
+        # delta
+        bytes_accessed=it * b * s * d * (3 * h + 2 * nkv)
+        + 4 * b * s * (3 * h + 1))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal,
@@ -454,6 +477,7 @@ def _seg_bwd_vjp(causal, res, dout):
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), qr.dtype),
         name="flash_varlen_bwd_dq",
+        cost_estimate=cost,
         interpret=interp,
     )(kmin, kmax, qr, kr, vr, seg_q, seg_k, do, lse, delta)
 
@@ -463,6 +487,17 @@ def _seg_bwd_vjp(causal, res, dout):
                 + (jnp.int32(i) % jnp.int32(nkv)) * jnp.int32(group)
                 + jnp.int32(g))
 
+    # a query head's whole rows (q, dO; lse, delta fp32) turn with the
+    # innermost axis: fetched at every grid step where a group has more
+    # than one head; K, V and their ids a tile a grid step, the q ids
+    # once a batch row; dk and dv out in fp32
+    visits = b * nkv * (s // bk) * group if group > 1 else b * nkv
+    cost = pl.CostEstimate(
+        flops=pairs * bq * bk * (8 * d + 5),
+        transcendentals=pairs * bq * bk,
+        bytes_accessed=visits * s * (2 * d * it + 8)
+        + 2 * it * b * nkv * s * d + 4 * b * s * (1 + nkv)
+        + 2 * 4 * b * nkv * s * d)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=bq,
@@ -505,6 +540,7 @@ def _seg_bwd_vjp(causal, res, dout):
         out_shape=(jax.ShapeDtypeStruct((b * nkv, s, d), jnp.float32),
                    jax.ShapeDtypeStruct((b * nkv, s, d), jnp.float32)),
         name="flash_varlen_bwd_dkv",
+        cost_estimate=cost,
         interpret=interp,
     )(qmin, qmax, qr, kr, vr, seg_q, seg_k, do, lse, delta)
 

@@ -73,6 +73,12 @@ def fused_adamw(p, g, m, v, t, lr, b1=0.9, b2=0.95, eps=1e-8,
     tf = jnp.asarray(t, jnp.float32)
     c1_arr = (1.0 - jnp.float32(b1) ** tf).reshape(1)
     c2_arr = (1.0 - jnp.float32(b2) ** tf).reshape(1)
+    # an element of the padded [rows, 128]: 3 for m, 4 for v, the two
+    # corrections, 5 for p (decay, step, eps, quotient, difference), one
+    # sqrt; p, g, m, v in (g, m, v fp32) and p, m, v out once
+    cost = pl.CostEstimate(
+        flops=14 * rows * h, transcendentals=rows * h,
+        bytes_accessed=rows * h * (2 * p.dtype.itemsize + 5 * 4))
     new_p, new_m, new_v = pl.pallas_call(
         functools.partial(_kernel, b1=b1, b2=b2, eps=eps,
                           wd=weight_decay),
@@ -98,6 +104,7 @@ def fused_adamw(p, g, m, v, t, lr, b1=0.9, b2=0.95, eps=1e-8,
                    pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, h), lambda i: idx32(i, 0))),
         name="fused_adamw",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(flat2(p), flat2(g, jnp.float32), flat2(m, jnp.float32),
       flat2(v, jnp.float32), lr_arr, c1_arr, c2_arr)

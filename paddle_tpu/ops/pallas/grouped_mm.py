@@ -96,6 +96,15 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False):
     def w_index(j, i, te, n):
         e = te[_held(i, n)]
         return idx32(e, j, 0) if trans_w else idx32(e, 0, j)
+    # the BOUND the call is launched at: every tile of M holding rows
+    # (how many do is data).  x again for every column panel, every
+    # expert's weights once (a panel at a time, as the optimizer holds
+    # them), the result once
+    cost = pl.CostEstimate(
+        flops=2 * M * K * N, transcendentals=0,
+        bytes_accessed=(N // tn) * _common.nbytes((M, K), x.dtype)
+        + _common.nbytes(w.shape, w.dtype)
+        + _common.nbytes((M, N), x.dtype))
     return pl.pallas_call(
         functools.partial(_mm_kernel, trans_w=trans_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -112,6 +121,7 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         name="grouped_mm",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(tile_expert, n_tiles, x, w)
 
@@ -135,6 +145,14 @@ def grouped_mm_dw(x, dy, tile_expert, n_tiles, experts: int):
     M, K = x.shape
     N = dy.shape[1]
     tk, tn = _cols(K, 1792), _cols(N, 1792)
+    # the bound, as grouped_mm's: every tile of M.  x again for every
+    # panel of dy's columns, dy for every panel of x's, every expert's
+    # fp32 block out once
+    cost = pl.CostEstimate(
+        flops=2 * M * K * N, transcendentals=0,
+        bytes_accessed=(N // tn) * _common.nbytes((M, K), x.dtype)
+        + (K // tk) * _common.nbytes((M, N), dy.dtype)
+        + 4 * experts * K * N)
     return pl.pallas_call(
         _dw_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -152,5 +170,6 @@ def grouped_mm_dw(x, dy, tile_expert, n_tiles, experts: int):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         name="grouped_mm_dw",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(tile_expert, n_tiles, x, dy)

@@ -93,6 +93,15 @@ def int8_matmul(x, wq, scale, out_dtype=None):
     interp = _common.interpret()
     bn = _block_n(K, N, enforce_vmem=not interp)
     bm = _block_m(Mp, K)
+    # the product of the padded rows and the scale on its result; x a
+    # row block once (its index holds over the columns), the int8
+    # weights and their scales again for every row block unless there is
+    # one column block, the result out once
+    refetch = Mp // bm if N // bn > 1 else 1
+    cost = pl.CostEstimate(
+        flops=2 * Mp * K * N + Mp * N, transcendentals=0,
+        bytes_accessed=2 * Mp * K + refetch * (K * N + 4 * N)
+        + _common.nbytes((Mp, N), out_dtype))
     out = pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
@@ -107,6 +116,7 @@ def int8_matmul(x, wq, scale, out_dtype=None):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: idx32(i, j)),
         name="int8_matmul",
+        cost_estimate=cost,
         interpret=interp,
     )(x.astype(jnp.bfloat16), wq,
       scale.astype(jnp.float32).reshape(1, -1))

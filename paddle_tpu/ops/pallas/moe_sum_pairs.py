@@ -91,6 +91,14 @@ def moe_sum_pairs(rows, slot_token, first_slot):
     # a step's run of slots starts where its first token's does
     lo = jnp.concatenate(
         [first_slot[:T:TOKENS], first_slot[T:]]).astype(I32)
+    # the bound: every CHUNK of P copied once and one more a grid step
+    # (a run starts inside a piece), each added as a [TOKENS, CHUNK] 0/1
+    # product; the slots' tokens once, the sums out once
+    pieces = P // CHUNK + steps
+    cost = pl.CostEstimate(
+        flops=2 * pieces * TOKENS * CHUNK * C, transcendentals=0,
+        bytes_accessed=pieces * _common.nbytes((CHUNK, C), rows.dtype)
+        + 4 * P + _common.nbytes((steps * TOKENS, C), rows.dtype))
     out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -107,6 +115,7 @@ def moe_sum_pairs(rows, slot_token, first_slot):
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="moe_sum_pairs",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(lo, slot_token.astype(I32).reshape(P // CHUNK, CHUNK), rows)
     return out[:T]

@@ -159,6 +159,23 @@ def _kernel(tables_ref, lens_ref, q_ref, kp_ref, vp_ref, o_ref,
     o_ref[:] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
+def _cost(B, n, d, nkv, page, pages_max, kv_itemsize, q_itemsize,
+          q8: bool):
+    """The bound a decode step is launched at: every row at
+    ``pages_max`` pages (how many a row uses is data).  A page: q k^T
+    and p v for all heads, four passes over the ``[n, page]`` scores
+    (scale, max, s - m, row sum; int8 pools: two more for the scales),
+    exp of the scores and of the max's step; K's and V's page fetched
+    (int8: their fp32 scales too), q in and out once a row."""
+    steps = B * pages_max
+    return pl.CostEstimate(
+        flops=steps * n * page * (4 * d + (6 if q8 else 4)),
+        transcendentals=steps * n * (page + 1),
+        bytes_accessed=steps * 2 * nkv * page * (d * kv_itemsize
+                                                 + (4 if q8 else 0))
+        + 2 * B * n * d * q_itemsize)
+
+
 def paged_decode_attention_xla(q, kpool, vpool, block_tables,
                                context_lens, sm_scale=None):
     """Pure-XLA reference: gather each row's pages and run masked
@@ -241,6 +258,9 @@ def paged_decode_attention(q, kpool, vpool, block_tables, context_lens,
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
         name="paged_attn",
+        cost_estimate=_cost(B, n, d, nkv, page, pages_max,
+                            kpool.dtype.itemsize, q.dtype.itemsize,
+                            q8=False),
         interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool)
     return out
@@ -331,6 +351,8 @@ def paged_decode_attention_q8(q, kpool, vpool, kscale, vscale,
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, d), q.dtype),
         name="paged_attn_q8",
+        cost_estimate=_cost(B, n, d, nkv, page, pages_max, 1,
+                            q.dtype.itemsize, q8=True),
         interpret=_common.interpret(),
     )(tables, lens, q, kpool, vpool, kscale, vscale)
     return out

@@ -82,6 +82,14 @@ def _fwd(x, w, eps):
     # * w promotes to the weight dtype (master-weight setups pass f32 w
     # with bf16 x and expect f32 out)
     out_dtype = jnp.promote_types(x.dtype, w.dtype)
+    # an element: x^2 into the mean, then the two products; a row: the
+    # mean's scale and eps, one rsqrt.  x in, out and rstd once, w once
+    # (its index never turns)
+    cost = pl.CostEstimate(
+        flops=4 * n * h + 2 * n, transcendentals=n,
+        bytes_accessed=_common.nbytes((n, h), xr.dtype)
+        + _common.nbytes((n, h), out_dtype) + _common.nbytes((h,), w.dtype)
+        + 4 * n)
     out, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         out_shape=(jax.ShapeDtypeStruct((n, h), out_dtype),
@@ -92,6 +100,7 @@ def _fwd(x, w, eps):
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((br, 1), lambda i: idx32(i, 0))),
         name="rms_norm",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(xr, w.reshape(1, -1))
     return out.reshape(orig_shape), (xr, w, rstd, orig_shape)
@@ -106,6 +115,14 @@ def _bwd_vjp(eps, res, dout):
     n, h = xr.shape
     br = _block_rows(n)
     do = dout.reshape(n, h)
+    # an element: xhat, w dO, their product into the row's mean, dx's
+    # three, and xhat dO into the column sum: 9.  x, dO in and dx out
+    # once, rstd; w in and the [8, h] fp32 sums out once
+    cost = pl.CostEstimate(
+        flops=9 * n * h, transcendentals=0,
+        bytes_accessed=2 * _common.nbytes((n, h), xr.dtype)
+        + _common.nbytes((n, h), do.dtype) + _common.nbytes((h,), w.dtype)
+        + 4 * n + 4 * 8 * h)
     dx, dw_partial = pl.pallas_call(
         _bwd_kernel,
         out_shape=(jax.ShapeDtypeStruct((n, h), xr.dtype),
@@ -118,6 +135,7 @@ def _bwd_vjp(eps, res, dout):
         out_specs=(pl.BlockSpec((br, h), lambda i: idx32(i, 0)),
                    pl.BlockSpec((8, h), lambda i: idx32(0, 0))),
         name="rms_norm_bwd",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(xr, w.reshape(1, -1), rstd, do)
     dw = jnp.sum(dw_partial, axis=0).astype(w.dtype)

@@ -113,6 +113,15 @@ def _apply(x, cos, sin, neg_sin: bool):
     # costs a relayout on each side of the kernel
     spec = pl.BlockSpec((1, bs, n * d), lambda bi, si: idx32(bi, si, 0))
     table = pl.BlockSpec((bs, d // 2), lambda bi, si: idx32(si, 0))
+    # executed: 3 operations an element (two products and an add or a
+    # subtraction a half); x in and out once, the two tables again for
+    # every batch row (their block turns with the row block; one block
+    # stays)
+    cost = pl.CostEstimate(
+        flops=3 * x.size, transcendentals=0,
+        bytes_accessed=2 * _common.nbytes(x.shape, x.dtype)
+        + (b if s > bs else 1) * (_common.nbytes(cos.shape, cos.dtype)
+                                  + _common.nbytes(sin.shape, sin.dtype)))
     return pl.pallas_call(
         functools.partial(_rope_kernel, neg_sin=neg_sin),
         out_shape=jax.ShapeDtypeStruct((b, s, n * d), x.dtype),
@@ -120,6 +129,7 @@ def _apply(x, cos, sin, neg_sin: bool):
         in_specs=[spec, table, table],
         out_specs=spec,
         name="rope",
+        cost_estimate=cost,
         interpret=_common.interpret(),
     )(x.reshape(b, s, n * d), cos, sin).reshape(x.shape)
 

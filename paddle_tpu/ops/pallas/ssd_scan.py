@@ -310,6 +310,33 @@ _ORDER = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 
 
+def _cost(b, nc, q, h, p, n, itemsize, backward: bool, owed: bool = False):
+    """What a kernel EXECUTES on its (row, chunk, lane tile) grid.  A
+    head's products run on its tile's 128 lanes, the other heads'
+    zeroed: 128 wide, not ``p``.  Forward, a head: M x, (C decay) S and
+    (B weight)^T x — 2 * 128 * Q * (Q + 2 N) — and four passes over its
+    ``[Q, Q]`` matrix (cum_i - cum_j, its clamp, scores * decay * dt),
+    one exp each entry; a chunk: C B^T.  Backward, a head: dy x^T, M^T
+    dy, dy S^T, (C decay)^T dy, x dS^T and (B weight) dS — 2 * 128 * Q
+    * (2 Q + 4 N) — and twelve passes; a chunk: C B^T and the two products
+    that store dB and dC.  Bytes by the BlockSpecs: x (dy, the owed
+    cotangent) and y (dx) a tile a grid step, B and C (dB, dC) a block a
+    chunk, cum and dt (their gradients) in both layouts, fp32, the
+    entering states ``[N, H P]`` fp32 a chunk."""
+    heads, rows = b * nc * h, b * nc * q
+    per_head = 2 * LANES * q * ((2 * q + 4 * n) if backward else (q + 2 * n)) \
+        + (12 if backward else 4) * q * q
+    per_chunk = 2 * q * q * n * (3 if backward else 1)
+    tiles_moved = (4 if owed else 3) if backward else 2
+    return pl.CostEstimate(
+        flops=heads * per_head + b * nc * per_chunk,
+        transcendentals=heads * (q * q + 2 * q),
+        bytes_accessed=itemsize * rows * (tiles_moved * h * p
+                                          + (4 if backward else 2) * n)
+        + 4 * rows * h * (8 if backward else 4)
+        + 4 * b * nc * n * h * p)
+
+
 def _run_fwd(xa, ba, ca, places, dt, cum, p, n):
     """xa, ba, ca ``[b, s, .]``: the arrays x's lane tiles (from 0) and
     B's and C's blocks (at ``places``) lie in — three, or one three
@@ -329,6 +356,8 @@ def _run_fwd(xa, ba, ca, places, dt, cum, p, n):
                         pltpu.VMEM((tiles, n, LANES), F32)],
         compiler_params=_ORDER,
         name="ssd_scan_fwd",
+        cost_estimate=_cost(b, nc, q, h, p, n, xa.dtype.itemsize,
+                            backward=False),
         interpret=_common.interpret(),
     )(xa, ba, ca, *_layouts(dt, cum, per))
 
@@ -364,6 +393,8 @@ def _run_bwd(xa, ba, ca, places, dt, cum, entering, dy, p, n, owed=None):
                         pltpu.VMEM((tiles, n, LANES), F32)],
         compiler_params=_ORDER,
         name="ssd_scan_bwd",
+        cost_estimate=_cost(b, nc, q, h, p, n, xa.dtype.itemsize,
+                            backward=True, owed=owed is not None),
         interpret=_common.interpret(),
     )(xa, flat(dy), ba, ca, cumr, dtr, cumt, dtt, entering, *more)
     cols_of = lambda a: jnp.swapaxes(a.reshape(b, nc, h, q), 2, 3)

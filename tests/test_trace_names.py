@@ -453,7 +453,8 @@ def test_every_scope_of_the_vocabulary_is_tested_somewhere():
 
 # -- Pallas kernels -----------------------------------------------------------
 def pallas_call_names() -> list:
-    """The ``name=`` of every ``pallas_call`` site under ops/pallas."""
+    """The ``name=`` of every ``pallas_call`` site under ops/pallas;
+    a site that does not state its work (``cost_estimate=``) fails."""
     names = []
     for path in sorted(glob.glob(os.path.join(
             REPO, "paddle_tpu", "ops", "pallas", "*.py"))):
@@ -465,6 +466,9 @@ def pallas_call_names() -> list:
                 kw = {k.arg: k.value for k in node.keywords}
                 assert "name" in kw, f"{path}:{node.lineno} has no name="
                 assert isinstance(kw["name"], ast.Constant)
+                assert "cost_estimate" in kw, \
+                    f"{path}:{node.lineno} ({kw['name'].value}) declares " \
+                    f"no cost_estimate="
                 names.append(kw["name"].value)
     return names
 
