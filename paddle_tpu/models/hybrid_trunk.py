@@ -43,6 +43,11 @@ sublayers F (attention; the MLP or the expert layer) has mixer leaves
             normalisation, denominators + ``hc_eps``)
     h = H_pre . X;  y = F(rms_norm(h));  X' = H_res . X + H_post^T (x) y
 
+The passes over the streams (m and h; X'; their backwards) are the four
+kernels of ``ops/pallas/hc_mix.py`` where ``hc_mix.takes`` takes the
+shape, each reading the streams once; H_post and H_res — a few numbers
+a token — are XLA's in either form.
+
     MLA on x = rms_norm(h; ln1):
     q = rms_norm(x . w_qa; q_norm) . [w_qb_nope | w_qb_rope]   [H, 128 | 64]
     [c | k_r] = x . w_kva  (kv_lora_rank | 64);  [k_nope | v] =
@@ -57,7 +62,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -404,10 +409,41 @@ def _expert_layer(bp, x, cfg):
                                 bp["ws_down"], cfg.dtype)
 
 
+class _Mixer(NamedTuple):
+    """What of the configuration a mixer's maps read."""
+    n: int
+    eps: float
+    iters: int
+    hc_eps: float
+    lo: float
+    hi: float
+
+
+def _mixer_of(cfg) -> _Mixer:
+    return _Mixer(cfg.hc_mult, cfg.rms_norm_eps, cfg.hc_sinkhorn_iters,
+                  cfg.hc_eps, cfg.mhc_h_res_clamp_min,
+                  cfg.mhc_h_res_clamp_max)
+
+
+def _hc_small_maps(m, alpha, bias, mx: _Mixer):
+    """``H_post [n, T]`` and ``H_res [n, n, T]`` (row j: what stream j of
+    the output takes of each input stream) from m ``[n^2 + 2 n, T]``:
+    fp32, the TOKENS on the lanes — a few numbers a token, XLA's in
+    both forms of the sublayer and under jax's own autodiff."""
+    n, f32 = mx.n, jnp.float32
+    alpha, bias = alpha.astype(f32), bias.astype(f32)[:, None]
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + bias[n:2 * n])
+    r = (alpha[2] * m[2 * n:] + bias[2 * n:]).reshape(n, n, -1)
+    r = jnp.exp(jnp.clip(r, mx.lo, mx.hi))
+    for _ in range(mx.iters):
+        r = r / (jnp.sum(r, axis=1, keepdims=True) + mx.hc_eps)     # rows
+        r = r / (jnp.sum(r, axis=0, keepdims=True) + mx.hc_eps)     # columns
+    return h_post, r
+
+
 def hc_maps(bp, pre: str, x, cfg):
     """The three maps of one sublayer's mixer from the streams x [b, s,
-    n C]: ``H_pre [n]``, ``H_post [n]`` and ``H_res [n][n]`` (row j:
-    what stream j of the output takes of each input stream), every entry
+    n C]: ``H_pre [n]``, ``H_post [n]`` and ``H_res [n][n]``, every entry
     fp32 ``[b, s, 1]``.  The product with phi runs on the streams as they
     are (the compute dtype, fp32 sums) and is scaled by 1 / rms
     afterwards; the rest is fp32, with the TOKENS on the lanes."""
@@ -418,25 +454,113 @@ def hc_maps(bp, pre: str, x, cfg):
                    preferred_element_type=f32) \
         * jax.lax.rsqrt(var + cfg.rms_norm_eps)
     m = m.reshape(b * s, -1).T                              # [n^2 + 2n, T]
-    alpha = bp[pre + "_alpha"].astype(f32)
-    bias = bp[pre + "_b"].astype(f32)[:, None]
-    h_pre = jax.nn.sigmoid(alpha[0] * m[:n] + bias[:n])
-    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + bias[n:2 * n])
-    r = (alpha[2] * m[2 * n:] + bias[2 * n:]).reshape(n, n, b * s)
-    r = jnp.exp(jnp.clip(r, cfg.mhc_h_res_clamp_min,
-                         cfg.mhc_h_res_clamp_max))
-    for _ in range(cfg.hc_sinkhorn_iters):
-        r = r / (jnp.sum(r, axis=1, keepdims=True) + cfg.hc_eps)    # rows
-        r = r / (jnp.sum(r, axis=0, keepdims=True) + cfg.hc_eps)    # columns
+    alpha, bias = bp[pre + "_alpha"], bp[pre + "_b"]
+    h_pre = jax.nn.sigmoid(alpha[0].astype(f32) * m[:n]
+                           + bias.astype(f32)[:n, None])
+    h_post, r = _hc_small_maps(m, alpha, bias, _mixer_of(cfg))
     tok = lambda a: a.reshape(b, s, 1)
     return ([tok(h_pre[i]) for i in range(n)],
             [tok(h_post[i]) for i in range(n)],
             [[tok(r[j, i]) for i in range(n)] for j in range(n)])
 
 
+def _hc_maps_lanes(mr, alpha, bias, mx: _Mixer):
+    """``ops/pallas/hc_mix``'s view of the small maps: from mr ``[T,
+    128]`` (m in the first lanes) to maps ``[T, 128]`` — H_res row-major
+    in the first n^2 lanes, H_post in the n after, the tokens on the
+    sublanes so that a map is a column."""
+    from ..ops.pallas.hc_mix import LANES
+    n = mx.n
+    # a transposition that XLA may write as a layout leaves the TOKENS on
+    # the sublanes, a lane in eight in use, through all of Sinkhorn's
+    # rounds and their backward (a cotangent takes the same constraint):
+    # both arrays are to lie row-major
+    on_lanes = lambda a: with_layout_constraint(
+        a, Layout(major_to_minor=(0, 1)))
+    h_post, r = _hc_small_maps(on_lanes(mr[:, :n * n + 2 * n].T), alpha,
+                               bias, mx)
+    maps = on_lanes(jnp.concatenate([r.reshape(n * n, -1), h_post])).T
+    return jnp.pad(maps, ((0, 0), (0, LANES - n * n - n)))
+
+
+# The two halves of a sublayer on the kernels of ``ops/pallas/hc_mix``.
+# F's backward lies between the halves' backwards, and both need dX':
+# ``_hc_post`` hands its x the cotangent of its OUTPUT, dX' as it came,
+# and ``_hc_pre`` — which returns x for that purpose and holds H_res —
+# takes it through H_res while it forms the whole of dX in one pass.
+# Together they are the derivative; the x that ``_hc_pre`` returns goes
+# to ``_hc_post`` and nowhere else.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _hc_pre(x, phi, alpha, bias, mx: _Mixer):
+    """x [T, n C] -> h [T, C] = H_pre . x, the maps ``_hc_post`` reads
+    [T, 128], and x."""
+    return _hc_pre_fwd(x, phi, alpha, bias, mx)[0]
+
+
+def _hc_pre_fwd(x, phi, alpha, bias, mx):
+    from ..ops.pallas import hc_mix
+    with jax.named_scope("hc_pre"):
+        h, mr = hc_mix.hc_pre_fwd(x, phi, alpha[0], bias, mx.n, mx.eps)
+        maps, pull = jax.vjp(
+            functools.partial(_hc_maps_lanes, mx=mx), mr, alpha, bias)
+    return (h, maps, x), (x, phi, alpha, bias, mr, maps, pull)
+
+
+def _hc_pre_bwd(mx, res, cts):
+    from ..ops.pallas import hc_mix
+    x, phi, alpha, bias, mr, maps, pull = res
+    dh, dmaps, g = cts
+    n, f32 = mx.n, jnp.float32
+    with jax.named_scope("hc_pre"):
+        dmr, dalpha, dbias = pull(dmaps)
+        dx, dz, dphit = hc_mix.hc_pre_bwd(g, x, dh, mr, dmr, maps, phi,
+                                          alpha[0], bias, n)
+        dz = dz[:, :n]
+        dalpha = dalpha.astype(f32).at[0].add(jnp.sum(dz * mr[:, :n]))
+        dbias = dbias.astype(f32).at[:n].add(jnp.sum(dz, axis=0))
+        dphi = dphit[:phi.shape[1]].T
+    return (dx, dphi.astype(phi.dtype), dalpha.astype(alpha.dtype),
+            dbias.astype(bias.dtype))
+
+
+_hc_pre.defvjp(_hc_pre_fwd, _hc_pre_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _hc_post(x, y, maps, n: int):
+    """x' [T, n C] = H_res . x + H_post^T (x) y."""
+    return _hc_post_fwd(x, y, maps, n)[0]
+
+
+def _hc_post_fwd(x, y, maps, n):
+    from ..ops.pallas import hc_mix
+    with jax.named_scope("hc_post"):
+        return hc_mix.hc_post_fwd(x, y, maps, n), (x, y, maps)
+
+
+def _hc_post_bwd(n, res, g):
+    from ..ops.pallas import hc_mix
+    x, y, maps = res
+    with jax.named_scope("hc_post"):
+        dy, dmaps = hc_mix.hc_post_bwd(g, x, y, maps, n)
+    return g, dy, dmaps
+
+
+_hc_post.defvjp(_hc_post_fwd, _hc_post_bwd)
+
+
 def _hc_sublayer(bp, pre: str, x, fn, cfg):
-    """x' = H_res . x + H_post^T (x) fn(H_pre . x) on x [b, s, n C]."""
+    """x' = H_res . x + H_post^T (x) fn(H_pre . x) on x [b, s, n C]: on
+    the kernels where they take the shape, else as XLA's fusions."""
+    from ..ops.pallas import hc_mix
     n, c, f32 = cfg.hc_mult, cfg.hidden_size, jnp.float32
+    b, s, _ = x.shape
+    if hc_mix.takes(x, n, c):
+        h, maps, rows = _hc_pre(
+            x.reshape(b * s, n * c), bp[pre + "_phi"], bp[pre + "_alpha"],
+            bp[pre + "_b"], _mixer_of(cfg))
+        y = fn(h.reshape(b, s, c)).reshape(b * s, c).astype(x.dtype)
+        return _hc_post(rows, y, maps, n).reshape(x.shape)
     with jax.named_scope("hc_pre"):
         h_pre, h_post, h_res = hc_maps(bp, pre, x, cfg)
         streams = [x[..., i * c:(i + 1) * c].astype(f32) for i in range(n)]
@@ -519,7 +643,10 @@ def trunk(blocks, x, cfg, mesh):
     streams = cfg.hc_mult if cfg.layer_types[0] in MLA_KINDS else 1
     if streams > 1:
         with jax.named_scope("hc_pre"):
-            x = jnp.tile(x, (1, 1, streams))
+            # whole lane tiles side by side: a copy.  ``jnp.tile`` goes
+            # through [b, s, n, C], which XLA lays out for the kernels
+            # that take x by moving every stream twice
+            x = jnp.concatenate([x] * streams, axis=-1)
 
     def runs_of(kind):
         """The kind's stacked leaves, one dict a run of its layers."""
@@ -539,6 +666,7 @@ def trunk(blocks, x, cfg, mesh):
                 x, parts[kind].pop(0))
     if streams > 1:
         with jax.named_scope("hc_post"):
-            x = jnp.sum(x.reshape(*x.shape[:2], streams, -1).astype(
-                jnp.float32), axis=2).astype(x.dtype)
+            c = x.shape[-1] // streams
+            x = sum(x[..., i * c:(i + 1) * c].astype(jnp.float32)
+                    for i in range(streams)).astype(x.dtype)
     return x

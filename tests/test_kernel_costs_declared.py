@@ -17,6 +17,8 @@ from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
 from paddle_tpu.ops.pallas.flash_varlen import flash_attention_segmented
 from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
 from paddle_tpu.ops.pallas.grouped_mm import grouped_mm, grouped_mm_dw
+from paddle_tpu.ops.pallas.hc_mix import (hc_post_bwd, hc_post_fwd,
+                                          hc_pre_bwd, hc_pre_fwd)
 from paddle_tpu.ops.pallas.int8_matmul import int8_matmul
 from paddle_tpu.ops.pallas.moe_sum_pairs import moe_sum_pairs
 from paddle_tpu.ops.pallas.paged_attention import (
@@ -91,6 +93,16 @@ PAGED = (z((4, 8, 128)), POOL, POOL, z((4, 6), I32), z((4,), I32))
 PAGED_Q8 = (z((4, 8, 128)), z(POOL.shape, I8), z(POOL.shape, I8),
             z((64, 2, 16), F32), z((64, 2, 16), F32), z((4, 6), I32),
             z((4,), I32))
+
+# 256 tokens (two tiles) of 4 streams of 256: rows of 1,024; the maps'
+# numbers a token in [256, 128] fp32
+HC_X, HC_Y, HC_M = z((256, 1024)), z((256, 256)), z((256, 128), F32)
+HC_PHI = (z((1024, 24), F32), z((), F32), z((24,), F32))
+_hc_pre_fwd = lambda x, phi, a, b: hc_pre_fwd(x, phi, a, b, 4, 1e-6)
+_hc_post_fwd = lambda x, y, m: hc_post_fwd(x, y, m, 4)
+_hc_post_bwd = lambda g, x, y, m: hc_post_bwd(g, x, y, m, 4)
+_hc_pre_bwd = lambda g, x, dh, mr, dmr, m, phi, a, b: hc_pre_bwd(
+    g, x, dh, mr, dmr, m, phi, a, b, 4)
 
 # (case, kernel name, fn, args, flops, bytes_accessed, transcendentals)
 CASES = [
@@ -199,6 +211,30 @@ CASES = [
     ("moe_sum_pairs", "moe_sum_pairs", moe_sum_pairs,
      (z((1024, 256)), z((1024,), I32), z((513,), I32)),
      201_326_592, 1_052_672, 0),
+    # an element of X [256, 1024]: 2 its square, 2 * 128 the lane tile of
+    # phi, 2 its share of h; 8 on each of a token's 128 lanes (scale,
+    # H_pre's affine and sigmoid); bf16 X, h [256, 256] and phi [1024, 128]
+    # once, fp32 table [8, 128] and mr; a sigmoid a lane, a rsqrt a token
+    ("hc_pre_fwd", "hc_pre_fwd", _hc_pre_fwd, (HC_X,) + HC_PHI,
+     256 * 1024 * 260 + 256 * 128 * 8,
+     2 * (256 * 1024 + 256 * 256 + 1024 * 128) + 4 * 264 * 128, 256 * 129),
+    # an element of X': 4 + 1 products summed; X in, X' out, y, the maps
+    ("hc_post_fwd", "hc_post_fwd", _hc_post_fwd, (HC_X, HC_Y, HC_M),
+     256 * 1024 * 10, 2 * (2 * 256 * 1024 + 256 * 256) + 4 * 256 * 128, 0),
+    # an element of dX': its share of dy and its products with y and
+    # the four X_i, 2 each; dX', X, y in, dy out, maps in, dmaps out
+    ("hc_post_bwd", "hc_post_bwd", _hc_post_bwd, (HC_X, HC_X, HC_Y, HC_M),
+     256 * 1024 * 12, 2 * (2 * 256 * 1024 + 2 * 256 * 256)
+     + 4 * 2 * 256 * 128, 0),
+    # an element of X: 2 with dh, 2 * 7 the terms of dX, 2 * 128 the lane
+    # tile of phi^T, 2 * 32 the rows of phi's gradient (24 padded to
+    # packed bf16 tiles); 16 a lane a token; dX', X in, dX out, dh, phi^T
+    # [128, 1024], four [256, 128] in, one out, the table, [32, 1024] fp32
+    ("hc_pre_bwd", "hc_pre_bwd", _hc_pre_bwd,
+     (HC_X, HC_X, HC_Y, HC_M, HC_M, HC_M) + HC_PHI,
+     256 * 1024 * 336 + 256 * 128 * 16,
+     2 * (3 * 256 * 1024 + 256 * 256 + 128 * 1024) + 4 * (4 * 256 + 8) * 128
+     + 4 * 32 * 1024, 256 * 128),
     # the bound: 4 rows x 6 pages; 8 heads x 16 slots x (4 * 128 + 4); a K
     # and a V page of [2, 16, 128] bf16 a step, q in and out
     ("paged_attn", "paged_attn", _paged, PAGED, 1_585_152, 409_600, 3_264),

@@ -587,6 +587,38 @@ def test_routed_experts_at_the_expert_cell_s_shapes(one_chip, compiled,
     assert ("bf16[18432,3584]" in text) == (held == 8)
 
 
+def test_mixer_kernels_at_the_expert_cell_s_shapes(one_chip, compiled):
+    """One sublayer of the four residual streams, forward and backward,
+    at 2 x 8192 x (4 x 3584): the four kernels of ``ops/pallas/hc_mix``
+    compile within the VMEM they ask for, and the maps' few numbers a token lie with the TOKENS ON
+    THE LANES through Sinkhorn's rounds (XLA would write the transposition
+    out of the kernels' ``[T, 128]`` as a layout, an eighth of each vector
+    register in use)."""
+    import types
+    from paddle_tpu.models import hybrid_trunk
+    from paddle_tpu.ops.pallas import hc_mix
+    n, c = 4, 3584
+    cfg = types.SimpleNamespace(
+        hc_mult=n, hidden_size=c, rms_norm_eps=1e-6, hc_sinkhorn_iters=20,
+        hc_eps=1e-6, mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0)
+    x = _sds(one_chip, ROWS_8K + (n * c,), jnp.bfloat16)
+    assert hc_mix.takes(x, n, c)
+    bp = {"hc1_phi": _sds(one_chip, (n * c, n * n + 2 * n), jnp.float32),
+          "hc1_alpha": _sds(one_chip, (3,), jnp.float32),
+          "hc1_b": _sds(one_chip, (n * n + 2 * n,), jnp.float32)}
+
+    def loss(bp, x, g):
+        out = hybrid_trunk._hc_sublayer(
+            bp, "hc1", x, lambda h: h * jnp.asarray(0.5, h.dtype), cfg)
+        return jnp.sum((out * g).astype(jnp.float32))
+    text = _text(jax.value_and_grad(loss, (0, 1)), bp, x, x)
+    assert text.count(KERNEL) == 4
+    for kernel in ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd"):
+        assert kernel in text, kernel
+    assert len(re.findall(r"= f32\[16,16384\]\{1,0", text)) > 100
+    assert not re.search(r"= f32\[16,16384\]\{0,1", text)
+
+
 def test_train_step_of_the_expert_cell(one_chip, compiled):
     """The step of ``xing4.0-29b-a4b.pretrain-8k-moe`` as the benchmark
     builds it — a dense lead and four expert layers, every published
@@ -594,7 +626,8 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     NO compiler rematerialization (the test that chose the share: with 16
     experts and a quarter of the vocabulary it compiled with six
     ``.remat`` matrix products), runs attention and the grouped products
-    as kernels, and holds no bf16 copy of an expert stack."""
+    as kernels, the mixers' passes over the four streams too, and holds
+    no bf16 copy of an expert stack."""
     from benchmark import harness
     cell = harness.find_cell("xing4.0-29b-a4b.pretrain-8k-moe")
     assert cell.conf["num_hidden_layers"] == 5 and \
@@ -609,7 +642,22 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # fit them, 67,584 otherwise: one ``conditional`` a pass): 2 grouped
     # products + the token side's sum forward, the same recomputed, 2
     # products + 2 dw + the sum backward
-    assert text.count(KERNEL) == 8 + 2 * (3 + 3 + 5)
+    # + the mixers (``ops/pallas/hc_mix.py``), in the lead's loop and in
+    # the expert layers': two sublayers forward (``hc_pre_fwd``,
+    # ``hc_post_fwd``: 4), the same recomputed but the last X', which
+    # nothing reads again (3), ``hc_post_bwd`` and ``hc_pre_bwd`` of
+    # each backward (4)
+    assert text.count(KERNEL) == 8 + 2 * (3 + 3 + 5) + 2 * (4 + 3 + 4)
+    for kernel in ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd",
+                   "hc_pre_bwd"):
+        assert kernel in text, kernel
+    # no fp32 copy of the streams is an ARRAY of the program (inside a
+    # fusion — the trunk's two ends sum and pad in fp32 — it is a value
+    # on its way through registers)
+    arrays = re.sub(r"(?m)^%fused_computation\S* .*\{\n(?:.*\n)*?\}\n", "",
+                    text)
+    assert "fused_computation" in text and len(arrays) < len(text)
+    assert not re.search(r"= f32\[(2,8192|16384),14336\]", arrays)
     assert len(re.findall(r" conditional\(", text)) == 3
     for rows in (18432, 67584):
         assert f"bf16[{rows},3584]" in text

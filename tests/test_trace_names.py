@@ -35,8 +35,10 @@ DECODE = BLOCK | {"embed", "pool_carry", "kv_write", "paged_attn",
 PREFILL = BLOCK | {"embed", "layer_scan", "varlen_attn"}
 # kernels of the program that the readers' copies do not list yet: only a
 # ``benchmark`` PR may add ``moe_sum_pairs`` (PR 34, the expert layer's
-# token side) to ``benchmark/models/xing_mhc_moe.KERNELS`` (ROADMAP D14)
-AHEAD = ("moe_sum_pairs",)
+# token side) and the mixers' four (PR 36, ``ops/pallas/hc_mix.py``) to
+# ``benchmark/models/xing_mhc_moe.KERNELS`` (ROADMAP D14)
+HC_KERNELS = ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd")
+AHEAD = ("moe_sum_pairs",) + HC_KERNELS
 
 
 def scope_names(lowered) -> set:
@@ -211,6 +213,34 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
     for p in routed:
         assert xplane_meta.scope_of(p, names_of) in (
             "moe_dispatch", "moe_experts", "moe_combine"), p
+    # the mixers' passes over the streams are the four kernels of
+    # ``ops/pallas/hc_mix.py`` (the toy's 128-wide streams are a shape
+    # they take), each under the scope ``hc_mix_pct.train`` reads, in
+    # the forward, in the remat's forward and — two custom_vjps whose
+    # backwards name their scope themselves — in the backward
+    for phase, scope, kernel in (
+            ("block/", "hc_pre", "hc_pre_fwd"),
+            ("block/", "hc_post", "hc_post_fwd"),
+            ("checkpoint/rematted_computation/block/", "hc_pre",
+             "hc_pre_fwd"),
+            ("checkpoint/rematted_computation/block/", "hc_post",
+             "hc_post_fwd"),
+            ("checkpoint/block/", "hc_post", "hc_post_bwd"),
+            ("checkpoint/block/", "hc_pre", "hc_pre_bwd")):
+        assert f"{phase}{scope}/{kernel}/pallas_call" in paths, kernel
+        assert xplane_meta.scope_of(
+            f"jit(step)/{phase}{scope}/{kernel}/pallas_call",
+            names_of) == scope
+        assert xplane_meta.kernel_of(
+            f"jit(step)/{phase}{scope}/{kernel}/pallas_call",
+            AHEAD) == kernel
+    # and what stays XLA's — the maps' few numbers a token, under jax's
+    # own autodiff — is charged to ``hc_pre`` in all three phases
+    small = [p for p in paths if p.endswith("/logistic") and "hc_" in p
+             and "pallas_call" not in p and "/hc_pre_fwd/" not in p]
+    assert {xplane_meta.scope_of(p, names_of) for p in small} == {"hc_pre"}
+    assert {xplane_meta.phase_of("jit(step)/" + p) for p in small} >= {
+        "recompute"}
 
 
 @pytest.mark.parametrize("on_load,on_all", [(6, 0), (4, 2), (0, 0)])
@@ -477,7 +507,7 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     names = pallas_call_names()
     # ``flash_attention_split`` runs through the dense kernels' three
     # call sites: a name is one site
-    assert len(names) == 20 and len(set(names)) == 20
+    assert len(names) == 24 and len(set(names)) == 24
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family

@@ -14,6 +14,7 @@ on the load's bound."""
 
 from __future__ import annotations
 
+import contextlib
 import gzip
 import json
 import os
@@ -43,15 +44,23 @@ def count(mt) -> dict:
                 passes["moe_bound_load"] / total if total else None}
 
 
+@contextlib.contextmanager
+def unzipped(path: str):
+    """The path of the trace as a plain ``.xplane.pb``."""
+    if not path.endswith(".gz"):
+        yield path
+        return
+    with tempfile.NamedTemporaryFile(suffix=".xplane.pb") as tmp:
+        with gzip.open(path, "rb") as f:
+            shutil.copyfileobj(f, tmp)
+        tmp.flush()
+        yield tmp.name
+
+
 def read(path: str) -> dict:
     from benchmark import xplane_meta
-    if path.endswith(".gz"):
-        with tempfile.NamedTemporaryFile(suffix=".xplane.pb") as tmp:
-            with gzip.open(path, "rb") as f:
-                shutil.copyfileobj(f, tmp)
-            tmp.flush()
-            return read(tmp.name)
-    return count(xplane_meta.load(path))
+    with unzipped(path) as plain:
+        return count(xplane_meta.load(plain))
 
 
 if __name__ == "__main__":
