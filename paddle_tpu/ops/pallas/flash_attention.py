@@ -24,7 +24,10 @@ returns (dk, dv, dq) — five block products and one exp pass a pair;
 a function of the shapes alone: where group*S*d*4 B of dQ is past the
 budget (long rows, wide groups) ``flash_bwd_dq`` (S, dP, dQ, and delta)
 runs first and ``flash_bwd_dkv`` does what it did — seven products and
-two exp passes a pair.  One kernel body either way.
+two exp passes a pair.  One kernel body either way.  The SPLIT form
+(:func:`flash_attention_split`) goes by the same rule: in one pass
+``flash_bwd_dkv`` sums dS k2 into a second fp32 scratch beside dQ's and
+returns (dk, dv, dk2, dq, dq2) — eight products, three of them d2 deep.
 
 The kernels work on ONE head's ``[rows, dim]`` tiles with that head's
 K/V (forward, dq) or Q/dO (dkv; in one pass also O, beside the group's
@@ -221,23 +224,37 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
     # head's last grid step casts it into dq_ref, the group's
     # [S, group*d] (flat) or [group, S, d] block.
     #
-    # Split scores (two kernels, a group of one): q2_ref [S, d2] and
-    # k2_ref [Bk, d2] lead ``refs`` and dk2_ref [Bk, d2] follows dv_ref:
-    # THIS head's part of the gradient of a k2 that every head shares.
+    # Split scores: q2_ref [S, d2] and k2_ref [Bk, d2] lead ``refs`` and
+    # dk2_ref [Bk, d2] follows dv_ref: THIS head's part of the gradient
+    # of a k2 that every head shares.  In one pass dS k2 is summed into
+    # a second fp32 scratch beside dQ's and leaves as dq2_ref after
+    # dq_ref, the group's block of q2's layout.
+    #
+    # ``refs``: (q2, k2,) dk, dv, (dk2,) [dq, (dq2,)] then the scratch
+    # dk_acc, dv_acc, [dq_acc, (dq2_acc,) delta] — () split, [] one pass.
     ki = pl.program_id(2).astype(jnp.int32)
     g = pl.program_id(3).astype(jnp.int32)
     Bk, d = k_ref.shape
     S = q_ref.shape[0]
     k = k_ref[:]
     v = v_ref[:]
+    if split:
+        q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, *refs = refs
+        k2 = k2_ref[:]
+    else:
+        dk_ref, dv_ref, *refs = refs
+    # dQ (| dQ2): the key each is a product with, where it leaves, its sum
+    keys = ((k, k2) if split else (k,)) if also_dq else ()
+    dq_refs, (dk_acc, dv_acc, *dq_accs) = refs[:len(keys)], refs[len(keys):]
     if also_dq:
-        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, delta_ref = refs
+        delta_ref = dq_accs.pop()
         stat0 = g * (S // block_q)      # the head's blocks of the group's
 
         @pl.when(ki == 0)
         def _first_visit():
             # the first k block meets every q block of the head
-            dq_acc[g] = jnp.zeros((S, d), jnp.float32)
+            for acc in dq_accs:
+                acc[g] = jnp.zeros(acc.shape[1:], jnp.float32)
 
             def form_delta(qi, _):
                 rows = pl.ds(qi * block_q, block_q)
@@ -248,12 +265,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
                 return _
             jax.lax.fori_loop(jnp.int32(0), jnp.int32(S // block_q),
                               form_delta, None)
-    elif split:
-        q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc = refs
-        delta_ref, stat0 = last_ref, jnp.int32(0)
-        k2 = k2_ref[:]
     else:
-        dk_ref, dv_ref, dk_acc, dv_acc = refs
         delta_ref, stat0 = last_ref, jnp.int32(0)
 
     def body(qi, carry, masked):
@@ -276,8 +288,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
         dst = (pt * (_scores(v, do) - delta)
                * jnp.float32(sm_scale)).astype(q.dtype)
         dk = dk + _matmul(dst, q)
-        if also_dq:
-            dq_acc[g, rows, :] += _matmul_t(dst, k)
+        for acc, key in zip(dq_accs, keys):
+            acc[g, rows, :] += _matmul_t(dst, key)
         if split:
             return dk, dv, dk2[0] + _matmul(dst, q2)
         return dk, dv
@@ -320,13 +332,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
     if also_dq:
         @pl.when(last_head & (ki == pl.num_programs(2) - 1))
         def _finish_dq():
-            if len(dq_ref.shape) == 3:
-                dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
-            else:
-                # flat: a head is d lanes of the block, at a static offset
-                for h in range(dq_acc.shape[0]):
-                    dq_ref[:, h * d:(h + 1) * d] = \
-                        dq_acc[h].astype(dq_ref.dtype)
+            for acc, ref in zip(dq_accs, dq_refs):
+                if len(ref.shape) == 3:
+                    ref[:] = acc[:].astype(ref.dtype)
+                    continue
+                # flat: a head is its lanes of the block, at a static offset
+                width = acc.shape[2]
+                for h in range(acc.shape[0]):
+                    ref[:, h * width:(h + 1) * width] = \
+                        acc[h].astype(ref.dtype)
 
 
 # The backward runs in ONE pass (``flash_bwd_dkv`` sums dQ too and
@@ -335,7 +349,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
 # and wider groups keep the two kernels.  Measured at 2 MiB (S 2048,
 # group 2, d 128: 74.1 -> 53.2 ms a step) and, by the host's clock, at
 # 4 MiB (S 4096: 71.5 -> 49.6); compiled for a described v5e at 4 MiB
-# with S 8192 (PERF.md §6, PR 30).
+# with S 8192 (PERF.md §6, PR 30).  The split form at S 8192, a group of
+# one, sits on it (PERF.md §6, PR 42).
 ONE_PASS_DQ_BYTES = 4 << 20
 # what Mosaic gives a kernel unless the call asks for more (v5e: of 128 MiB)
 _DEFAULT_VMEM_LIMIT = 16 << 20
@@ -507,10 +522,11 @@ def _shared_spec(rows, d2, at):
 
 
 def _split_vmem(s, d, itemsize):
-    """What a kernel of the SPLIT form asks of VMEM: five whole-row
-    operands of one head at most (a 64-wide one fills 128 lanes), twice
-    for the pipeline's buffers, beside the tiles and the block
-    products."""
+    """What ``flash_fwd`` and the two-kernel backward of the SPLIT form
+    ask of VMEM: five whole-row operands of one head at most (a 64-wide
+    one fills 128 lanes), twice for the pipeline's buffers, beside the
+    tiles and the block products.  The one-pass backward asks for its
+    own sum (``_flash_bwd_vjp``)."""
     resident = 2 * 5 * s * d * itemsize + (8 << 20)
     if resident <= _DEFAULT_VMEM_LIMIT:
         return None
@@ -588,31 +604,43 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
     out_specs = [_tile_spec(bk, d, tile), _tile_spec(bk, d, tile)]
     scratch = [pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, d), jnp.float32)]
+    second_in = []
+    if second:
+        # a head's part of dk2 leaves in fp32, after dk and dv
+        second_in = [_tile_spec(s, d2, head), _shared_spec(bk, d2, tile)]
+        out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
+        out_specs.append(_tile_spec(bk, d2, tile))
     params = _split_vmem(s, d, qr.dtype.itemsize) if second else None
-    # the split form keeps the two kernels, whatever its dQ's size
-    one_pass = not second and group * s * d * 4 <= ONE_PASS_DQ_BYTES
+    # by the fp32 dQ of a KV head's group, dense and split form alike: the
+    # split form's dQ2 follows from the shapes and is asked of VMEM below
+    one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
     pairs, it = b * h * _pairs(s, bq, causal), qr.dtype.itemsize
     # on a pair's scores, either kernel: the scale, s - lse, dP - delta
     # and the two products that give dS
     on_scores = 5
     if one_pass:
-        # o in delta's place; dq a third output, the group's block; the
-        # group's fp32 dQ and delta in scratch
+        # o in delta's place; dq (| dq2) the last outputs, the group's
+        # blocks; the group's fp32 dQ (| dQ2) and delta in scratch
         last, last_spec = out, _tile_spec(s, d, head)
-        out_shape.append(like(qr))
-        out_specs.append(_group_spec(s, group, d))
-        scratch += [pltpu.VMEM((group, s, d), jnp.float32),
-                    pltpu.VMEM((group * (s // bq), 1, bq), jnp.float32)]
+        for x, width in [(qr, d)] + ([(second[0], d2)] if second else []):
+            out_shape.append(like(x))
+            out_specs.append(_group_spec(s, group, width))
+            scratch.append(pltpu.VMEM((group, s, width), jnp.float32))
+        scratch.append(pltpu.VMEM((group * (s // bq), 1, bq), jnp.float32))
         # q, do, o and the group's dq block twice (the pipeline's two
-        # buffers) beside the fp32 dQ; the rest read 4.8 MiB at most
-        # (AOT for a described v5e, S 4096 and 8192)
-        resident = (2 * (3 + group) * qr.dtype.itemsize + 4 * group) \
-            * s * d + (8 << 20)
+        # buffers) beside the fp32 dQ, and so q2 and the group's dq2 block
+        # beside the fp32 dQ2, each row of them a whole lane tile; the
+        # rest read 4.8 MiB at most (AOT for a described v5e, S 4096 and
+        # 8192)
+        lanes2 = -(-d2 // 128) * 128
+        resident = ((2 * (3 + group) * it + 4 * group) * d
+                    + (2 * (1 + group) * it + 4 * group) * lanes2) * s \
+            + (8 << 20)
         if resident > _DEFAULT_VMEM_LIMIT:
             params = pltpu.CompilerParams(vmem_limit_bytes=resident)
     else:
         by_q, kv = _by_query_head(group)
-        second_in = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
+        second_dq = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
             if second else []
         cost = pl.CostEstimate(
             # a pair: q k^T (+ q2 k2^T), dO v^T, dS k (+ dS k2); delta a
@@ -635,9 +663,9 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
             in_specs=[_tile_spec(bq, d, by_q), _tile_spec(s, d, kv),
                       _tile_spec(s, d, kv), _tile_spec(bq, d, by_q),
                       _tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
-            + second_in,
+            + second_dq,
             out_specs=[_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
-            + second_in[:1],
+            + second_dq[:1],
             compiler_params=params,
             name="flash_bwd_dq",
             cost_estimate=cost,
@@ -645,30 +673,26 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         )(qr, kr, vr, out, do, lse, *second)
         last_spec = _stat_spec(s // bq, bq, head)
 
-    second_in = []
-    if second:
-        # a head's part of dk2 leaves in fp32, after dk and dv
-        second_in = [_tile_spec(s, d2, head), _shared_spec(bk, d2, tile)]
-        out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
-        out_specs.append(_tile_spec(bk, d2, tile))
     # a query head's whole-row operands turn with the INNERMOST axis:
     # with more than one head a group they are fetched again at every
     # grid step, alone they stay while the head's k blocks pass
     visits = b * nkv * (s // bk) * group if group > 1 else b * nkv
     cost = pl.CostEstimate(
         # a pair: k q^T (+ k2 q2^T), P^T dO, v dO^T, dS^T q (+ dS^T q2),
-        # in one pass dS k too, and delta a row once
-        flops=pairs * bq * bk * (2 * ((5 if one_pass else 4) * d + 2 * d2)
+        # in one pass dS k (+ dS k2) too, and delta a row once
+        flops=pairs * bq * bk * (2 * (4 * d + 2 * d2
+                                      + (d + d2 if one_pass else 0))
                                  + on_scores)
         + (2 * b * h * s * d if one_pass else 0),
         transcendentals=pairs * bq * bk,
         # a head's q, dO (q2), lse and o (one pass) or delta a visit; K,
-        # V (k2) a tile a grid step; dk, dv (dq in one pass) out once,
-        # a head's part of dk2 in fp32
+        # V (k2) a tile a grid step; dk, dv (dq, dq2 in one pass) out
+        # once, a head's part of dk2 in fp32
         bytes_accessed=visits * s * (
             it * (2 * d + d2 + (d if one_pass else 0))
             + 4 * (1 if one_pass else 2))
-        + it * b * s * (4 * nkv * d + h * d2 + (h * d if one_pass else 0))
+        + it * b * s * (4 * nkv * d + h * d2
+                        + (h * (d + d2) if one_pass else 0))
         + 4 * b * s * h * d2)
     grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
@@ -688,7 +712,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
     )(qr, kr, vr, do, lse, last, *second)
     dk, dv = grads[:2]
     if one_pass:
-        dq = grads[2]
+        dq, *dq2_kernel = grads[3 if second else 2:]
     dq2 = dk2 = None
     if second:
         # [b, h, s, d2] or [b, s, h*d2]: the heads' sum is the shared key's
@@ -716,10 +740,11 @@ def flash_attention_split(q, q2, k, k2, v, sm_scale: float):
     d2]``, ONE key a token for all heads (MLA's rotated part: d 128, d2
     64).  Both products are summed in fp32 before the one exp pass; no
     ``[.., d + d2]`` operand and no h-fold copy of k2 is made.  The
-    backward is the two kernels (``flash_bwd_dq``, ``flash_bwd_dkv``)
-    and returns five gradients; a head's part of dk2 leaves the kernel
-    in fp32 and the heads are summed outside it.  Same kernel bodies,
-    names and blocks as :func:`flash_attention`."""
+    backward returns five gradients, in ONE pass by the dense form's
+    rule of shapes (``ONE_PASS_DQ_BYTES``: ``flash_bwd_dkv`` sums dQ and
+    dQ2 too), from the two kernels past it; a head's part of dk2 leaves
+    the kernel in fp32 and the heads are summed outside it.  Same kernel
+    bodies, names and blocks as :func:`flash_attention`."""
     b, s, h, d = q.shape
     if d % 128 or k.shape != q.shape or v.shape != q.shape or \
             q2.shape[:3] != (b, s, h) or k2.shape != (b, s, q2.shape[3]):

@@ -8,6 +8,14 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 if "--xla_force_host_platform_device_count" not in os.environ["XLA_FLAGS"]:
     os.environ["XLA_FLAGS"] += " --xla_force_host_platform_device_count=8"
+# XLA aborts the PROCESS when a CPU collective waits 40 s for a peer; with
+# six workers on eight cores the 8-device programs of test_graft_entry
+# wait 20-35 s (rendezvous.cc's "may be stuck ... unstuck"), and an abort
+# takes the xdist worker down with it (met twice in four whole runs).
+if "--xla_cpu_collective_call_terminate_timeout_seconds" not in \
+        os.environ["XLA_FLAGS"]:
+    os.environ["XLA_FLAGS"] += \
+        " --xla_cpu_collective_call_terminate_timeout_seconds=600"
 
 import jax  # noqa: E402
 

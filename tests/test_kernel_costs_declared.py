@@ -6,6 +6,8 @@ a parameter of the ``pallas_call`` equation, so it is read off the jaxpr
 on the CPU; nothing runs.  The numbers here are worked out by hand from
 each kernel's grid, in the comments."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -65,6 +67,19 @@ def grad_of(fn, n=1):
     def run(*args):
         out, pull = jax.vjp(lambda *a: fn(*a, *args[n:]), *args[:n])
         return pull(jax.tree_util.tree_map(jnp.ones_like, out))
+    return run
+
+
+def two_kernels(fn):
+    """``fn`` traced with the one-pass budget at 0 bytes (the module
+    constant, as the parity tests set it): the backward a row past the
+    budget runs."""
+    def run(*args):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(importlib.import_module(
+                "paddle_tpu.ops.pallas.flash_attention"),
+                "ONE_PASS_DQ_BYTES", 0)
+            return fn(*args)
     return run
 
 
@@ -134,14 +149,26 @@ CASES = [
     # (512 q o + 512 K V + 128 q2 + 64 k2 once) + 4 * 2 * 1024
     ("flash_fwd, split", "flash_fwd", _split, SPLIT,
      1_012_924_416, 2_498_560, 1_577_984),
-    # 6 * 512^2 * (2 * (384 + 128) + 5) + 2 * 2 * 1024 * 128; bytes 2 *
-    # 1024 * (1024 + 512 + 2 * 128 q2 dq2 + 64) + 8 * 2 * 1024
-    ("flash_bwd_dq, split", "flash_bwd_dq", grad_of(_split, 5), SPLIT,
+    # a head's fp32 dQ at S 1,024 is 512 KiB: ONE pass, dS k and dS k2
+    # beside the two-kernel form's six products: 6 * 512^2 * (2 * (5 * 128
+    # + 3 * 64) + 5) + delta 2 * 2 * 1024 * 128; a group of ONE: a head's
+    # rows stay, 2 visits of 1024 * (2 * (3 * 128 q dO o + 64 q2) + 4 lse),
+    # + 2 * 1024 * (4 * 256 K V dk dv + 128 k2 + 2 * (128 + 64) dq dq2)
+    # + dk2 in fp32 4 * 1024 * 2 * 64
+    ("flash_bwd_dkv, split one pass", "flash_bwd_dkv", grad_of(_split, 5),
+     SPLIT, 2_625_634_304, 5_513_216, 1_572_864),
+    # past the budget (here: the budget at 0 bytes) the split form keeps
+    # the two kernels.  6 * 512^2 * (2 * (384 + 128) + 5) + 2 * 2 * 1024 *
+    # 128; bytes 2 * 1024 * (1024 + 512 + 2 * 128 q2 dq2 + 64) + 8 * 2 *
+    # 1024
+    ("flash_bwd_dq, split", "flash_bwd_dq",
+     two_kernels(grad_of(_split, 5)), SPLIT,
      1_619_001_344, 3_817_472, 1_572_864),
     # 6 * 512^2 * (2 * (512 + 128) + 5); a group of ONE: a head's rows
     # stay, 2 visits of 1024 * (2 * (256 + 64) + 8), + 2 * 1024 * (4 * 256
     # + 128 k2) + dk2 in fp32 4 * 1024 * 2 * 64
-    ("flash_bwd_dkv, split", "flash_bwd_dkv", grad_of(_split, 5), SPLIT,
+    ("flash_bwd_dkv, split", "flash_bwd_dkv",
+     two_kernels(grad_of(_split, 5)), SPLIT,
      2_021_130_240, 4_210_688, 1_572_864),
     # the bound: one segment a row.  4 heads x 3 pairs: 12 * 512^2 * 516;
     # bytes 2 * 1024 * 128 * (8 + 4) + ids, lse 4 * 1024 * (2 * 4 + 1)
@@ -268,15 +295,25 @@ def test_every_kernel_name_has_a_case():
 
 
 def test_one_pass_and_two_kernels_declare_different_work():
-    """``flash_bwd_dkv`` is one name for two amounts of work (ROADMAP
+    """``flash_bwd_dkv`` is one name for three amounts of work (ROADMAP
     D14); the declared FLOPs of a pair tell them apart in a trace."""
     by = {c[0]: c for c in CASES}
-    one, two = by["flash_bwd_dkv, one pass"], by["flash_bwd_dkv, two kernels"]
-    per_pair = lambda c, heads: (c[4] - (2 * heads * 2048 * 128
-                                         if c is one else 0)) \
-        // (heads * 10 * 512 * 512)
-    assert per_pair(one, 2) == 2 * 5 * 128 + 5
-    assert per_pair(two, 8) == 2 * 4 * 128 + 5
+
+    def per_score(case, heads, pairs, s):
+        flops = by[case][4]
+        if "one pass" in case:
+            flops -= 2 * heads * s * 128        # delta
+        return flops // (heads * pairs * 512 * 512)
+    assert per_score("flash_bwd_dkv, one pass", 2, 10, 2048) == \
+        2 * 5 * 128 + 5
+    assert per_score("flash_bwd_dkv, two kernels", 8, 10, 2048) == \
+        2 * 4 * 128 + 5
+    # the split form: 1,669 a score in one pass against 1,285 (and
+    # ``flash_bwd_dq``'s 1,029 beside them)
+    assert per_score("flash_bwd_dkv, split one pass", 2, 3, 1024) == \
+        2 * (5 * 128 + 3 * 64) + 5 == 1669
+    assert per_score("flash_bwd_dkv, split", 2, 3, 1024) == \
+        2 * (4 * 128 + 2 * 64) + 5 == 1285
 
 
 @pytest.mark.parametrize("s,executed,of", [(2048, 10, 16), (8192, 136, 256),

@@ -138,14 +138,19 @@ def _flash_inputs(seed, b, s, h, nkv, d):
             for n in (h, nkv, nkv, h)]
 
 
-def _flash_grads(fn, q, k, v, w, causal):
-    """(dq, dk, dv) of sum(fn(q, k, v, causal) * w), and the flash
-    kernels the whole forward + backward program calls, in order."""
-    grad = jax.grad(lambda *a: (fn(*a, causal) * w).sum(),
-                    argnums=(0, 1, 2))
+def _grads_and_kernels(loss, *args):
+    """The gradient of ``loss`` with respect to every argument, and the
+    flash kernels the whole forward + backward program calls, in
+    order."""
+    grad = jax.grad(loss, argnums=tuple(range(len(args))))
     kernels = re.findall(r"\bname=(flash_\w+)",
-                         str(jax.make_jaxpr(grad)(q, k, v)))
-    return grad(q, k, v), kernels
+                         str(jax.make_jaxpr(grad)(*args)))
+    return grad(*args), kernels
+
+
+def _flash_grads(fn, q, k, v, w, causal):
+    """(dq, dk, dv) of sum(fn(q, k, v, causal) * w), and the kernels."""
+    return _grads_and_kernels(lambda *a: (fn(*a, causal) * w).sum(), q, k, v)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -178,20 +183,31 @@ def test_flash_backward_one_pass_parity(monkeypatch, causal, h, nkv, d, s):
         np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("form", ["dense", "split"])
 @pytest.mark.parametrize("fits", [True, False])
-def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits):
+def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits,
+                                                         form):
     """One pass where a group's fp32 dQ, group*S*d*4 B, is within
     ``ONE_PASS_DQ_BYTES``; a byte past it the two kernels run, with the
-    same gradients."""
+    same gradients.  The split form goes by the SAME rule (its dQ2 is
+    no part of the budget)."""
     fa = _flash_module()
-    h, nkv, d, s = 4, 2, 128, 256
-    args = _flash_inputs(7, 2, s, h, nkv, d)
+    if form == "dense":
+        h, nkv, d, s = 4, 2, 128, 256
+        args = _flash_inputs(7, 2, s, h, nkv, d)
+        run = lambda fn: _flash_grads(fn, *args, True)
+        flash, plain = fa.flash_attention, fa._xla_sdpa
+    else:
+        h, nkv, d, s = 2, 2, 128, 256
+        *args, co = _split_inputs(2, s, h, d, 64)
+        run = lambda fn: _split_grads(fn, *args, co, 0.137)
+        flash, plain = fa.flash_attention_split, _concatenated_attention
     need = (h // nkv) * s * d * 4
     monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", need if fits else need - 1)
-    got, kernels = _flash_grads(fa.flash_attention, *args, True)
+    got, kernels = run(flash)
     assert kernels == ["flash_fwd"] + ["flash_bwd_dq"] * (not fits) \
         + ["flash_bwd_dkv"]
-    want, _ = _flash_grads(fa._xla_sdpa, *args, True)
+    want, _ = run(plain)
     for a, b_ in zip(got, want):
         np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
 
@@ -389,33 +405,71 @@ def _concatenated_attention(q, q2, k, k2, v, scale):
                       precision="highest")
 
 
-@pytest.mark.parametrize("s,h", [(1024, 3), (256, 2)])
-def test_flash_attention_split_forward_and_five_gradients(s, h):
-    """Two 512-row blocks (the off-diagonal loop and the masked
-    diagonal) and one 256-row block; dk2 is the SUM over the heads."""
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
-    q, q2, k, k2, v, co = _split_inputs(2, s, h, 128, 64)
+def _split_grads(fn, q, q2, k, k2, v, co, scale):
+    """The five gradients of sum(fn(q, q2, k, k2, v, scale) * co) in
+    fp32, and the kernels."""
+    grads, kernels = _grads_and_kernels(
+        lambda *a: (fn(*a, scale).astype(jnp.float32) * co).sum(),
+        q, q2, k, k2, v)
+    return [g.astype(jnp.float32) for g in grads], kernels
+
+
+@pytest.mark.parametrize("s,h,dtype", [
+    (1024, 3, jnp.float32),     # two 512-row blocks: the off-diagonal loop
+    (256, 2, jnp.float32),      # one 256-row block: the masked diagonal alone
+    (1536, 1, jnp.float32),     # three: dQ | dQ2 summed over three k blocks
+    (1024, 2, jnp.bfloat16)])
+def test_flash_attention_split_forward_and_five_gradients(monkeypatch, s, h,
+                                                          dtype):
+    """The forward and the five gradients (dk2 is the SUM over the
+    heads) against autodiff of the plain form, the backward in ONE pass
+    — ``flash_bwd_dkv`` sums dQ and dQ2 too; ``flash_bwd_dq`` does not
+    run — AND against the two kernels on the same inputs (the budget set
+    to 0 bytes: the module constant, no flag)."""
+    fa = _flash_module()
+    *args, co = _split_inputs(2, s, h, 128, 64, dtype)
+    co = co.astype(jnp.float32)
     scale = 0.137
-    got, vjp = jax.vjp(lambda *a: flash_attention_split(*a, scale),
-                       q, q2, k, k2, v)
-    want, vjp_plain = jax.vjp(
-        lambda *a: _concatenated_attention(*a, scale), q, q2, k, k2, v)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-    grads, wants = vjp(co), vjp_plain(co)
-    assert [g.shape for g in grads] == [a.shape for a in (q, q2, k, k2, v)]
-    for a, b_ in zip(grads, wants):
-        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
+    f32 = [a.astype(jnp.float32) for a in args]
+    # bf16: the kernels' products take bf16 P and dS, the results leave
+    # in bf16 (2^-8 of a value, and a few roundings on the way)
+    out_tol, tol = (2e-5, 2e-4) if dtype == jnp.float32 else (6e-2, 6e-2)
+    np.testing.assert_allclose(
+        fa.flash_attention_split(*args, scale).astype(jnp.float32),
+        _concatenated_attention(*f32, scale), atol=out_tol, rtol=out_tol)
+    one, kernels = _split_grads(fa.flash_attention_split, *args, co, scale)
+    assert kernels == ["flash_fwd", "flash_bwd_dkv"]
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    two, kernels = _split_grads(fa.flash_attention_split, *args, co, scale)
+    assert kernels == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    want, _ = _split_grads(_concatenated_attention, *f32, co, scale)
+    for name, a, b_, c, x in zip(("dq", "dq2", "dk", "dk2", "dv"), one, two,
+                                 want, args):
+        assert a.shape == x.shape, name
+        if dtype == jnp.float32:
+            # a re-ordered fp32 sum at most
+            np.testing.assert_allclose(a, b_, atol=2e-5, rtol=2e-5,
+                                       err_msg=name)
+        else:
+            # the same terms from the same bf16 operands, rounded once
+            assert float(jnp.abs(a - b_).max()) <= \
+                2 ** -7 * float(jnp.abs(b_).max()), name
+        np.testing.assert_allclose(a, c, atol=tol, rtol=tol, err_msg=name)
 
 
-def test_flash_attention_split_makes_no_wide_operand():
+@pytest.mark.parametrize("kernels", [2, 3])
+def test_flash_attention_split_makes_no_wide_operand(monkeypatch, kernels):
     """No ``[.., 192]`` operand and no h-fold copy of the shared key
-    reaches the kernels: they take the five arrays as they are."""
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
+    reaches the kernels, one pass (2) or two kernels (3: the budget set
+    to 0 bytes): they take the five arrays as they are."""
+    fa = _flash_module()
+    if kernels == 3:
+        monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
     q, q2, k, k2, v, _ = _split_inputs(1, 512, 4, 128, 64)
     text = str(jax.make_jaxpr(jax.grad(
-        lambda *a: flash_attention_split(*a, 0.1).sum(),
+        lambda *a: fa.flash_attention_split(*a, 0.1).sum(),
         argnums=(0, 1, 2, 3, 4)))(q, q2, k, k2, v))
-    assert text.count("pallas_call") == 3
+    assert text.count("pallas_call") == kernels
     assert "192" not in text and "concatenate" not in text
 
 

@@ -353,25 +353,42 @@ def test_flash_gqa_8k_head_dim_64():
             assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
 
 
-def test_flash_split_8k_at_the_expert_cell_s_shapes():
+def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
     """Latent attention as ``xing4.0-29b-a4b.pretrain-8k-moe`` runs it:
-    S 8192, heads of 128 | 64 | 128, one shared rotated key — scores
-    from two operand pairs, the two-kernel backward, five gradients.
-    The composite holds [S, S] a head, so it is asked for two heads."""
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
-    s, h = 8192, 4
+    2 x 8192, heads of 128 | 64 | 128, one shared rotated key — scores
+    from two operand pairs, five gradients, the backward in ONE pass (a
+    head's fp32 dQ is 4 MiB: ``ONE_PASS_DQ_BYTES``) against the plain
+    form and against the two kernels (the rule set to 0 bytes).  The
+    composite holds [S, S] a head, so it is asked for two heads of the
+    first row."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    b, s, h = 2, 8192, 4
+    assert s * 128 * 4 == fa.ONE_PASS_DQ_BYTES
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     bf = jnp.bfloat16
-    q = jax.random.normal(ks[0], (1, s, h, 128), bf)
-    q2 = jax.random.normal(ks[1], (1, s, h, 64), bf)
-    k = jax.random.normal(ks[2], (1, s, h, 128), bf)
-    k2 = jax.random.normal(ks[3], (1, s, 64), bf)
-    v = jax.random.normal(ks[4], (1, s, h, 128), bf)
-    w = jax.random.normal(ks[5], (1, s, h, 128), jnp.float32)
+    q = jax.random.normal(ks[0], (b, s, h, 128), bf)
+    q2 = jax.random.normal(ks[1], (b, s, h, 64), bf)
+    k = jax.random.normal(ks[2], (b, s, h, 128), bf)
+    k2 = jax.random.normal(ks[3], (b, s, 64), bf)
+    v = jax.random.normal(ks[4], (b, s, h, 128), bf)
+    w = jax.random.normal(ks[5], (b, s, h, 128), jnp.float32)
     scale = float(192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)  # weak-typed
-    out, vjp = jax.vjp(lambda *a: flash_attention_split(*a, scale).astype(
-        jnp.float32), q, q2, k, k2, v)
-    dq, dq2, dk, dk2, dv = (g.astype(jnp.float32) for g in vjp(w))
+
+    def grads():
+        out, vjp = jax.vjp(
+            lambda *a: fa.flash_attention_split(*a, scale).astype(
+                jnp.float32), q, q2, k, k2, v)
+        return [out] + [g.astype(jnp.float32) for g in vjp(w)]
+
+    one = grads()
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    for got, want in zip(one, grads()):
+        # dk, dk2 and dv the same sums, dq and dq2 the same terms from a
+        # product turned round
+        err = float(jnp.abs(got - want).max())
+        assert err <= 2 ** -7 * float(jnp.abs(want).max()), err
+    out, dq, dq2, dk, dk2, dv = (x[:1] for x in one)
 
     def plain(q, q2, k, k2, v):
         qq = jnp.concatenate([q, q2], -1)
@@ -380,13 +397,13 @@ def test_flash_split_8k_at_the_expert_cell_s_shapes():
         sc = jnp.einsum("bqhd,bkhd->bhqk", qq, kk) * scale
         sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-    f32 = [x.astype(jnp.float32) for x in (q, q2, k, k2, v)]
+    f32 = [x[:1].astype(jnp.float32) for x in (q, q2, k, k2, v)]
     dk2_sum = 0.0
     for heads in (slice(0, 2), slice(2, 4)):
         cut = [f32[0][:, :, heads], f32[1][:, :, heads], f32[2][:, :, heads],
                f32[3], f32[4][:, :, heads]]
         want, ref_vjp = jax.vjp(plain, *cut)
-        wq, wq2, wk, wk2, wv = ref_vjp(w[:, :, heads])
+        wq, wq2, wk, wk2, wv = ref_vjp(w[:1, :, heads])
         dk2_sum = dk2_sum + wk2
         for got, ref in ((out[:, :, heads], want), (dq[:, :, heads], wq),
                          (dq2[:, :, heads], wq2), (dk[:, :, heads], wk),

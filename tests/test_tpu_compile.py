@@ -537,23 +537,33 @@ def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
     assert ma.temp_size_in_bytes <= 10_729_414_144      # PR 31's
 
 
+@pytest.mark.parametrize("kernels", [2, 3])
 def test_flash_attention_split_at_the_expert_cell_s_shapes(one_chip,
-                                                           compiled):
+                                                           compiled,
+                                                           monkeypatch,
+                                                           kernels):
     """Latent attention at 2 x 8192, 32 heads of 128 | 64 | 128: Mosaic
     takes the two operand pairs, the shared 64-wide key whole, and the
-    VMEM the whole-row operands ask for; three kernels, five
-    gradients."""
+    VMEM the whole-row operands ask for; five gradients from TWO
+    kernels — a head's fp32 dQ is 4 MiB, ``ONE_PASS_DQ_BYTES`` exactly,
+    so ``flash_bwd_dkv`` sums dQ and dQ2 too (40 MiB of VMEM asked) and
+    ``flash_bwd_dq`` is absent — or, the rule set to 0 bytes, from the
+    three a longer row keeps."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
     b, s = ROWS_8K
+    assert s * 128 * 4 == _flash_module().ONE_PASS_DQ_BYTES
+    if kernels == 3:
+        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
     wide = _sds(one_chip, (b, s, 32, 128), jnp.bfloat16)
     text = _text(jax.grad(
         lambda *a: flash_attention_split(*a, 0.1).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4)), wide,
         _sds(one_chip, (b, s, 32, 64), jnp.bfloat16), wide,
         _sds(one_chip, (b, s, 64), jnp.bfloat16), wide)
-    assert text.count(KERNEL) == 3
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    assert text.count(KERNEL) == kernels
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
         assert kernel in text
+    assert ("flash_bwd_dq" in text) == (kernels == 3)
 
 
 @pytest.mark.parametrize("held,kernels,conditionals", [(8, 16, 2),
@@ -634,10 +644,13 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
         (cell.traffic["batch"], cell.traffic["seq"]) == ROWS_8K
     c = _cell_step(one_chip, cell.name)
     text = c.as_text()
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    for kernel in ("flash_fwd", "flash_bwd_dkv",
                    "grouped_mm", "grouped_mm_dw", "moe_sum_pairs"):
         assert kernel in text, kernel
-    # a dense lead: 4 flash; an expert layer: 4 flash, and the routed
+    # the split form's backward is one pass at S 8192 (PR 42)
+    assert "flash_bwd_dq" not in text
+    # a dense lead: 3 flash (forward, the recompute's, the one-pass
+    # backward); an expert layer: 3 flash, and the routed
     # path ON EACH OF ITS TWO BOUNDS (18,432 rows where the load's tiles
     # fit them, 67,584 otherwise: one ``conditional`` a pass): 2 grouped
     # products + the token side's sum forward, the same recomputed, 2
@@ -647,7 +660,7 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # ``hc_post_fwd``: 4), the same recomputed but the last X', which
     # nothing reads again (3), ``hc_post_bwd`` and ``hc_pre_bwd`` of
     # each backward (4)
-    assert text.count(KERNEL) == 8 + 2 * (3 + 3 + 5) + 2 * (4 + 3 + 4)
+    assert text.count(KERNEL) == 6 + 2 * (3 + 3 + 5) + 2 * (4 + 3 + 4)
     for kernel in ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd",
                    "hc_pre_bwd"):
         assert kernel in text, kernel

@@ -186,8 +186,7 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
                           ("moe_experts", "grouped_mm_dw"),
                           ("moe_combine", "moe_sum_pairs"),
                           ("moe_dispatch", "moe_sum_pairs"),
-                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dq"),
-                          ("attn", "flash_bwd_dkv")):
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dkv")):
         assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
                    for p in paths), kernel
         # ``moe_sum_pairs`` (PR 34) is AHEAD of the readers' copy:
@@ -195,6 +194,9 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
         assert xplane_meta.kernel_of(
             f"jit(step)/block/{scope}/{kernel}/pallas_call",
             xplane_meta.KERNELS + xing_mhc_moe.KERNELS + AHEAD) == kernel
+    # the split form's backward is one pass at this row length (PR 42)
+    assert not any(p.endswith("attn/flash_bwd_dq/pallas_call")
+                   for p in paths)
     # the routed path runs on one of two bounds, each under a scope of
     # its own OUTSIDE the family's: the innermost name a reader knows is
     # still one of the five ``moe_*``, on every op of either branch,
