@@ -631,16 +631,21 @@ def trunk(blocks, x, cfg, mesh):
     """x [b, s, h] through the layers in ``cfg.layer_types``' order.
     The latent-attention kinds carry ``hc_mult`` streams, ``[b, s, n h]``:
     the row that comes in is copied into each, and their sum goes out."""
-    from .llama_pretrain import _block_forward, _remat_wrap
+    from .llama_pretrain import (_block_forward, _remat_wrap,
+                                 keeps_flash_outputs)
     body = {"attention": _block_forward, "mamba": _mamba_block,
             "mla_dense": _mla_block, "mla_moe": _mla_block}
-    if cfg.remat_policy == "flash" and \
-            set(cfg.layer_types) != {"attention"}:
-        raise NotImplementedError(
-            "remat_policy='flash' saves the dense flash kernels' residuals; "
-            "the other kinds' blocks have none to save: use 'full'")
+    # the kinds whose blocks call the flash kernels (``check``: latent
+    # attention does not mix with the others); their layers together
+    # count against FLASH_KEPT_BYTES, at a head's value width
+    mla = cfg.layer_types[0] in MLA_KINDS
+    flash_kinds = MLA_KINDS if mla else ("attention",)
+    keep_flash = keeps_flash_outputs(
+        x.shape[0], x.shape[1], cfg.num_attention_heads,
+        cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,
+        sum(kind in flash_kinds for kind in cfg.layer_types))
     runs = layer_runs(cfg.layer_types)
-    streams = cfg.hc_mult if cfg.layer_types[0] in MLA_KINDS else 1
+    streams = cfg.hc_mult if mla else 1
     if streams > 1:
         with jax.named_scope("hc_pre"):
             # whole lane tiles side by side: a copy.  ``jnp.tile`` goes
@@ -659,7 +664,8 @@ def trunk(blocks, x, cfg, mesh):
     with jax.named_scope("layer_scan"):
         parts = {kind: runs_of(kind) for kind in blocks}
         for kind, _, _ in runs:
-            fwd = _remat_wrap(body[kind], cfg)
+            fwd = _remat_wrap(body[kind], cfg,
+                              keep_flash and kind in flash_kinds)
             x, _ = jax.lax.scan(
                 lambda carry, bp, fwd=fwd: (fwd(bp, carry, cfg, mesh, None),
                                             None),

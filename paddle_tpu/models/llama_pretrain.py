@@ -54,9 +54,9 @@ class LlamaPretrainConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # remat_policy: 'full' recomputes the whole block (what the
-    # benchmark's train cell runs); 'flash' saves the flash-attention
-    # residuals and remats only projections/FFN (no cell has timed it).
+    # remat_policy: 'full', the one boundary there is: a layer holds its
+    # input and the block is recomputed — but for flash attention's
+    # forward outputs where FLASH_KEPT_BYTES allows (_remat_wrap).
     remat_policy: str = "full"
     sequence_parallel: bool = True
     use_pallas_attention: bool = True
@@ -140,10 +140,9 @@ class LlamaPretrainConfig:
             raise ValueError(
                 f"position_embedding_type must be 'rope' or 'nope', "
                 f"got {self.position_embedding_type!r}")
-        if self.remat_policy not in ("full", "flash"):
+        if self.remat_policy != "full":
             raise ValueError(
-                f"remat_policy must be 'full' or 'flash', "
-                f"got {self.remat_policy!r}")
+                f"remat_policy must be 'full', got {self.remat_policy!r}")
         if self.context_parallel not in (None, "ring", "ulysses"):
             raise ValueError(
                 f"context_parallel must be None, 'ring' or 'ulysses', "
@@ -411,8 +410,7 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
 def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                     mesh: Optional[Mesh] = None):
     """ln1 + QKV projections + rope -> q [b, s, n, d], k, v [b, s, nkv,
-    d]: K/V at their own head count.  Single source of block math
-    shared by both remat boundaries."""
+    d]: K/V at their own head count."""
     b, s, h = x.shape
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -484,41 +482,47 @@ def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
         return _block_post_attn(bp, x, attn, cfg)
 
 
-def _block_forward_flash_saved(bp: Dict[str, Any], x,
-                               cfg: LlamaPretrainConfig,
-                               mesh: Optional[Mesh] = None, seg=None):
-    """Block forward where only the projections/FFN are rematerialised.
-
-    The flash-attention call sits OUTSIDE the two checkpoint regions, so
-    its custom-vjp residuals (q/k/v/o/lse) are saved for the backward
-    pass instead of re-running the O(S^2) kernel during recompute, at
-    the cost of holding them (q/k/v/o at the row's size, a layer).
-    The math is the shared _block_pre_attn/_block_post_attn — only the
-    checkpoint boundaries differ from _block_forward."""
-    pre = jax.checkpoint(
-        lambda bp, x: _block_pre_attn(bp, x, cfg, mesh))
-    post = jax.checkpoint(
-        lambda bp, x, attn: _block_post_attn(bp, x, attn, cfg))
-    with jax.named_scope("block"):
-        q, k, v = pre(bp, x)
-        with jax.named_scope("attn"):
-            attn = _attention(q, k, v, cfg, mesh, seg)
-        return post(bp, x, attn)
+# Full remat holds a layer's input and runs the block again in the
+# backward pass.  The flash forward kernel's two results, ``o`` and
+# ``lse``, are the exception where their bytes over all the layers that
+# run the kernel are at most this much (a sixteenth of a v5e's HBM): the
+# recompute then reads them and ``flash_fwd`` runs once a layer a step.
+# Past it the whole block is recomputed, as before.  Bytes, because HBM
+# is what the choice spends: the expert cell keeps 5 x 136 MB and the
+# hybrid cell 69 MB, the dense cell's 18 x 68 MB = 1.23 GB would buy
+# back the compiler's own rematerialization (PERF.md section 6, PR 43).
+FLASH_KEPT_BYTES = 1 << 30
 
 
-def _remat_wrap(fwd, cfg):
-    """Apply the configured rematerialisation policy to a block forward."""
+def keeps_flash_outputs(batch: int, seq: int, heads: int, value_dim: int,
+                        dtype, layers: int) -> bool:
+    """The rule of :data:`FLASH_KEPT_BYTES`: ``layers`` flash layers'
+    ``o`` ``[batch, seq, heads, value_dim]`` in ``dtype`` and fp32
+    ``lse`` ``[batch, heads, seq]``, as the trunk sees them."""
+    o = batch * seq * heads * value_dim * jnp.dtype(dtype).itemsize
+    lse = batch * seq * heads * 4
+    return 0 < layers * (o + lse) <= FLASH_KEPT_BYTES
+
+
+def _remat_wrap(fwd, cfg, keep_flash: bool = False):
+    """``fwd`` under full remat; ``keep_flash`` (what
+    :func:`keeps_flash_outputs` said of the trunk's flash layers) makes
+    the boundary's policy keep ``flash_fwd``'s outputs."""
     if not cfg.remat:
         return fwd
-    if cfg.remat_policy == "flash":
-        # selective: block internals remat, flash residuals saved
-        return _block_forward_flash_saved
-    return jax.checkpoint(fwd, static_argnums=(2, 3))
+    policy = None
+    if keep_flash:
+        from ..ops.pallas.flash_attention import FWD_OUTPUT_NAMES
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *FWD_OUTPUT_NAMES)
+    return jax.checkpoint(fwd, static_argnums=(2, 3), policy=policy)
 
 
 def _trunk_scan(blocks, x, cfg, mesh, seg=None):
     """pp == 1: scan over the layer-stacked block params with remat."""
-    fwd = _remat_wrap(_block_forward, cfg)
+    fwd = _remat_wrap(_block_forward, cfg, keeps_flash_outputs(
+        x.shape[0], x.shape[1], cfg.num_attention_heads, cfg.head_dim,
+        cfg.dtype, cfg.num_hidden_layers))
     # Megatron-SP activation constraints are a TPU optimisation; XLA:CPU's
     # AllReducePromotion/partitioner passes crash on the collectives they
     # produce inside scan+remat, so they're disabled when the MESH is made
@@ -553,7 +557,11 @@ def _trunk_pipeline(blocks, x_mb, cfg, mesh, pp: int, vpp: int = 1):
     from ..distributed.parallel.pipeline import (gpipe_forward,
                                                  interleaved_forward)
 
-    fwd = _remat_wrap(_block_forward, cfg)
+    # every microbatch's outputs are held at once: the whole batch counts
+    fwd = _remat_wrap(_block_forward, cfg, keeps_flash_outputs(
+        x_mb.shape[0] * x_mb.shape[1], x_mb.shape[2],
+        cfg.num_attention_heads, cfg.head_dim, cfg.dtype,
+        cfg.num_hidden_layers))
 
     def stage_fn(stage_bp, x):
         def step(carry, bp):

@@ -56,6 +56,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -354,6 +355,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
 ONE_PASS_DQ_BYTES = 4 << 20
 # what Mosaic gives a kernel unless the call asks for more (v5e: of 128 MiB)
 _DEFAULT_VMEM_LIMIT = 16 << 20
+# ``flash_fwd``'s two results under ``jax.ad_checkpoint.checkpoint_name``:
+# a checkpoint boundary whose policy keeps BOTH runs the forward kernel
+# once (``models/llama_pretrain._remat_wrap`` decides, by their bytes)
+FWD_OUTPUT_NAMES = ("flash_out", "flash_lse")
 
 
 def _pick_blocks(S: int):
@@ -572,6 +577,8 @@ def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
         cost_estimate=cost,
         interpret=_common.interpret(),
     )(qr, kr, vr, *second)
+    out = checkpoint_name(out, FWD_OUTPUT_NAMES[0])
+    lse = checkpoint_name(lse, FWD_OUTPUT_NAMES[1])
     return _from_kernel(out, h), (qr, kr, vr, second, out, lse)
 
 

@@ -237,16 +237,18 @@ def test_flagship_vpp_matches_flat():
 
 
 @pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
-@pytest.mark.parametrize("policy", ["full", "flash"])
-def test_remat_policy_keeps_loss_and_gradients(policy, kv_heads):
-    """Where the checkpoint boundaries lie changes what is recomputed,
-    never a value: under ``full`` and under ``flash`` the loss and every
-    leaf's gradient are those of ``remat=False``.  ``full`` runs the
-    flash forward kernel a second time in the backward pass; ``flash``
-    keeps it outside the two checkpointed regions and runs it once."""
+@pytest.mark.parametrize("mode", ["kept", "recomputed"])
+def test_remat_policy_keeps_loss_and_gradients(mode, kv_heads, monkeypatch):
+    """What full remat holds changes what is recomputed, never a value:
+    with ``flash_fwd``'s outputs kept (their bytes within
+    ``FLASH_KEPT_BYTES``: the kernel runs once) and with the whole block
+    recomputed (the bound at 0: it runs twice) the loss and every leaf's
+    gradient are those of ``remat=False``, and the two are the same
+    bits."""
     import re
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.models import llama_pretrain
     from paddle_tpu.models.llama_pretrain import (
         LlamaPretrainConfig, build_mesh, init_params, make_forward)
 
@@ -258,7 +260,9 @@ def test_remat_policy_keeps_loss_and_gradients(policy, kv_heads):
     mesh = build_mesh(devices=jax.devices()[:1])
     toks = jnp.asarray(np.random.RandomState(2).randint(0, 64, (2, 129)))
 
-    def loss_and_grads(**kw):
+    def loss_and_grads(bound=None, **kw):
+        if bound is not None:
+            monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", bound)
         cfg = LlamaPretrainConfig(**base, **kw)
         with mesh:
             params = init_params(cfg, jax.random.PRNGKey(0), mesh)
@@ -268,9 +272,9 @@ def test_remat_policy_keeps_loss_and_gradients(policy, kv_heads):
             return jax.jit(fn)(params, toks), runs
 
     (want, want_g), _ = loss_and_grads(remat=False)
-    (got, got_g), flash_fwd_runs = loss_and_grads(remat=True,
-                                                  remat_policy=policy)
-    assert flash_fwd_runs == {"full": 2, "flash": 1}[policy]
+    bounds = {"kept": llama_pretrain.FLASH_KEPT_BYTES, "recomputed": 0}
+    (got, got_g), flash_fwd_runs = loss_and_grads(bounds[mode], remat=True)
+    assert flash_fwd_runs == {"kept": 1, "recomputed": 2}[mode]
     np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
     for (path, a), b in zip(
             jax.tree_util.tree_leaves_with_path(got_g),
@@ -278,9 +282,57 @@ def test_remat_policy_keeps_loss_and_gradients(policy, kv_heads):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7,
             err_msg=jax.tree_util.keystr(path))
+    # the other side of the rule: the kept o and lse ARE the arrays the
+    # recompute would write again
+    other_mode = "recomputed" if mode == "kept" else "kept"
+    (other, other_g), other_runs = loss_and_grads(bounds[other_mode],
+                                                  remat=True)
+    assert other_runs == 3 - flash_fwd_runs
+    assert float(other) == float(got)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(got_g),
+            jax.tree_util.tree_leaves(other_g)):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=jax.tree_util.keystr(path))
 
 
-@pytest.mark.parametrize("policy", ["dots", "names", "cheap"])
+# (batch, rows, heads, a head's value width, dtype, flash layers), the
+# bound (None: the module's own) -> kept
+KEPT_BY_BYTES = {
+    # 5 x (134,217,728 + 2,097,152) = 681,574,400 B
+    "expert_cell": ((2, 8192, 32, 128, "bfloat16", 5), None, True),
+    "expert_cell_to_the_byte": ((2, 8192, 32, 128, "bfloat16", 5),
+                                681_574_400, True),
+    "expert_cell_a_byte_short": ((2, 8192, 32, 128, "bfloat16", 5),
+                                 681_574_399, False),
+    # the one attention layer of ten: 69,206,016 B
+    "hybrid_cell": ((2, 8192, 32, 64, "bfloat16", 1), None, True),
+    # 18 x 68,157,440 = 1,226,833,920 B: past 2**30
+    "dense_cell": ((8, 2048, 16, 128, "bfloat16", 18), None, False),
+    "dense_cell_15_layers": ((8, 2048, 16, 128, "bfloat16", 15), None,
+                             True),
+    # 2**27 rows of a head x (4 + 4) B = 2**30 exactly
+    "on_the_bound": ((2, 8192, 32, 1, "float32", 256), None, True),
+    # 5 x 533 x 1321 x 61 x (1 + 4) B = 2**30 + 1
+    "a_byte_over": ((533, 1321, 61, 1, "int8", 5), None, False),
+    "no_flash_layer": ((2, 8192, 32, 128, "bfloat16", 0), None, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_BY_BYTES))
+def test_flash_outputs_are_kept_by_their_bytes(case, monkeypatch):
+    """``keeps_flash_outputs`` is a function of shapes: the layers' ``o``
+    and fp32 ``lse`` together against ``FLASH_KEPT_BYTES``."""
+    from paddle_tpu.models import llama_pretrain
+    shape, bound, kept = KEPT_BY_BYTES[case]
+    assert llama_pretrain.FLASH_KEPT_BYTES == 1 << 30
+    if bound is not None:
+        monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", bound)
+    assert llama_pretrain.keeps_flash_outputs(*shape) is kept
+
+
+@pytest.mark.parametrize("policy", ["dots", "names", "cheap", "flash"])
 def test_remat_policy_refuses_a_retired_name(policy):
     from paddle_tpu.models.llama_pretrain import LlamaPretrainConfig
     with pytest.raises(ValueError, match="remat_policy"):

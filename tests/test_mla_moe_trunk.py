@@ -24,9 +24,10 @@ import jax.numpy as jnp
 
 import paddle_tpu  # noqa: F401
 from benchmark import harness, reference, train_cell
-from paddle_tpu.models import hybrid_trunk
+from paddle_tpu.models import hybrid_trunk, llama_pretrain
 from paddle_tpu.models.llama_pretrain import (
-    adafactor_update, build_mesh, init_adafactor_state, make_train_step)
+    adafactor_update, build_mesh, init_adafactor_state, make_forward,
+    make_train_step)
 from paddle_tpu.ops import moe
 from paddle_tpu.ops.pallas.grouped_mm import TILE_M
 
@@ -554,6 +555,36 @@ def test_adafactor_takes_a_rank_4_stack_an_expert_matrix_at_a_time():
         got = new["blocks"]["moe"]["w"][layer]
         assert float(jnp.max(jnp.abs(got - want))) \
             < 1e-6 * float(jnp.max(jnp.abs(want)))
+
+
+def test_full_remat_keeps_the_split_forward_s_outputs(toy, monkeypatch):
+    """``flash_attention_split`` through ``_mla_block`` under the trunk's
+    checkpoint boundary: ``flash_fwd`` is in the program once a run of
+    layers (the forward scan's body) where its outputs are kept, twice
+    (the backward scan's too) with ``FLASH_KEPT_BYTES`` at 0, and the
+    loss and every gradient are the same bits."""
+    import re
+    mesh = build_mesh(devices=jax.devices()[:1])
+    runs = len(hybrid_trunk.layer_runs(toy.cfg.layer_types))
+    ids = jnp.asarray(toy.batches[0])
+
+    def loss_and_grads(flash_fwd_runs):
+        with mesh:
+            params = toy.cell.family.make_params(toy.cfg, SEED, mesh)
+            fn = jax.value_and_grad(make_forward(toy.cfg, mesh))
+            assert len(re.findall(
+                r"name=flash_fwd\b",
+                str(jax.make_jaxpr(fn)(params, ids)))) == flash_fwd_runs
+            return jax.jit(fn)(params, ids)
+
+    kept, kept_g = loss_and_grads(runs)
+    monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", 0)
+    again, again_g = loss_and_grads(2 * runs)
+    assert float(kept) == float(again)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept_g),
+                            jax.tree_util.tree_leaves(again_g)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
 
 
 def test_more_than_one_device_is_refused_by_name(toy):

@@ -143,7 +143,9 @@ def _grads_and_kernels(loss, *args):
     flash kernels the whole forward + backward program calls, in
     order."""
     grad = jax.grad(loss, argnums=tuple(range(len(args))))
-    kernels = re.findall(r"\bname=(flash_\w+)",
+    # a ``pallas_call``'s name, not ``name[name=flash_out]``: the names
+    # ``_flash_fwd`` gives its outputs for a checkpoint policy
+    kernels = re.findall(r"(?<!name\[)\bname=(flash_\w+)",
                          str(jax.make_jaxpr(grad)(*args)))
     return grad(*args), kernels
 

@@ -294,24 +294,33 @@ def scope_primitives(jaxpr, scope, outer="") -> collections.Counter:
     return found
 
 
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "recomputed"])
 @pytest.mark.parametrize("kernels", [3, 4])
 @pytest.mark.parametrize("heads,kv_heads,hidden,moves", [
     (16, 8, 2048, "reshape"),       # head dim 128: addressed where it lies
     (4, 2, 256, "transpose")])      # head dim 64: the transposing entry
 def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
-                                                   moves, kernels,
+                                                   moves, kernels, kept,
                                                    monkeypatch):
     """What the ``attn`` scope of the train step holds with the Pallas
     attention on and GQA: the flash kernels — forward, the recompute's
     forward, and the backward in ONE pass (``flash_bwd_dkv`` sums dQ too
     and forms delta itself): THREE; past the VMEM rule, here the module
-    constant set to 0 bytes, ``flash_bwd_dq`` runs before it: four — and
-    the moves its addressing needs: bitcast reshapes at head dim 128,
-    transposes at 64.  K/V are never repeated: no ``broadcast_in_dim``,
-    and nothing sums a group back."""
+    constant set to 0 bytes, ``flash_bwd_dq`` runs before it: four;
+    where full remat keeps the forward's outputs (their bytes within
+    ``FLASH_KEPT_BYTES``, here the module's own or 0) the recompute's
+    forward is gone: one fewer — and the moves its addressing needs:
+    bitcast reshapes at head dim 128, transposes at 64, beside the two
+    names on the forward's outputs (``name``: no op of the program) and,
+    kept, the rounding jax puts on what a checkpoint keeps.  K/V are
+    never repeated: no ``broadcast_in_dim``, and nothing sums a group
+    back."""
     flash = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    pretrain = importlib.import_module("paddle_tpu.models.llama_pretrain")
     if kernels == 4:
         monkeypatch.setattr(flash, "ONE_PASS_DQ_BYTES", 0)
+    if not kept:
+        monkeypatch.setattr(pretrain, "FLASH_KEPT_BYTES", 0)
     cfg = _cfg(hidden_size=hidden, num_attention_heads=heads,
                num_key_value_heads=kv_heads, num_hidden_layers=1,
                remat=True, loss_chunks=2, use_pallas_attention=True)
@@ -324,8 +333,9 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
         jaxpr = jax.make_jaxpr(step)(
             params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
     found = scope_primitives(jaxpr.jaxpr, "attn")
-    assert found["pallas_call"] == kernels, found
-    assert set(found) == {"pallas_call", moves}, found
+    assert found["pallas_call"] == kernels - kept, found
+    assert set(found) == {"pallas_call", moves, "name"} | (
+        {"reduce_precision"} if kept else set()), found
 
 
 def test_attention_head_dim_64_matches_the_composite():
