@@ -56,6 +56,27 @@ a token — are XLA's in either form.
     vector a token for all heads;  S = (q_nope . k_nope^T + q_r . k_r^T)
     * (128 + 64)^-1/2 * mscale^2  — ``flash_attention_split``: two
     operand pairs, no 192-wide operand, no 32-fold copy of k_r.
+
+``gqa_moe_window`` and ``gqa_moe_global`` are a layer of grouped-query
+attention (``llama_pretrain``'s own projections, rotation and flash
+entry, heads of ``head_dim``) before an expert layer WITHOUT a shared
+expert, on ONE residual stream; the router reads the attention's input:
+
+    x = rms_norm(h; ln1)
+    z = x . w_router  (fp32, ``n_routed_experts`` wide);  picks = the top
+    ``num_experts_per_tok`` of z;  g = softmax(z over the picks)
+    q, k, v = x . wq, x . wk, x . wv                      [H | KV, head_dim]
+    window: q, k rotated (rotate-half, rope_theta); key j is visible to
+            query i iff i - sliding_window_size < j <= i  (``flash_win_*``)
+    global: no rotation; j <= i                            (``flash_*``)
+    h1 = h + softmax(q k^T / sqrt(head_dim) over the visible keys) v . wo
+    u = rms_norm(h1; ln2)
+    h2 = h1 + sum over the picks whose expert is HELD here of
+              g_e (relu(u . w_gate_e) * (u . w_up_e)) . w_down_e
+
+The route and the plan (``moe_route``, ``moe_dispatch``) are issued from
+x BEFORE attention: they depend on nothing attention computes, so XLA
+may run them beside it.
 """
 
 from __future__ import annotations
@@ -70,8 +91,10 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
-KINDS = ("attention", "mamba", "mla_dense", "mla_moe")
+KINDS = ("attention", "mamba", "mla_dense", "mla_moe", "gqa_moe_global",
+         "gqa_moe_window")
 MLA_KINDS = ("mla_dense", "mla_moe")
+GQA_MOE_KINDS = ("gqa_moe_global", "gqa_moe_window")
 
 
 def check(cfg) -> None:
@@ -92,6 +115,8 @@ def check(cfg) -> None:
             raise NotImplementedError(
                 f"mamba_n_groups={cfg.mamba_n_groups}: ops/ssd_scan.py "
                 "shares ONE B/C group among the heads")
+    if set(cfg.layer_types) & set(GQA_MOE_KINDS):
+        _check_gqa_moe(cfg)
     if set(cfg.layer_types) & set(MLA_KINDS):
         if not set(cfg.layer_types) <= set(MLA_KINDS):
             raise NotImplementedError(
@@ -123,6 +148,43 @@ def check(cfg) -> None:
                 "expert_first on, inside the published count")
 
 
+def _check_gqa_moe(cfg) -> None:
+    """What the kinds ``gqa_moe_window`` / ``gqa_moe_global`` must
+    state."""
+    if not (0 < cfg.num_experts_per_tok <= cfg.n_routed_experts
+            and 0 < cfg.experts_held and 0 <= cfg.expert_first
+            and cfg.expert_first + cfg.experts_held <= cfg.n_routed_experts
+            and cfg.moe_intermediate_size > 0):
+        raise ValueError(
+            "a 'gqa_moe_*' layer needs n_routed_experts (the router's "
+            "width), num_experts_per_tok, moe_intermediate_size, and the "
+            "share: experts_held from expert_first on, inside the "
+            "published count")
+    if not cfg.moe_primary_router_apply_softmax:
+        raise NotImplementedError(
+            "moe_primary_router_apply_softmax=False: the 'gqa_moe_*' "
+            "router takes a softmax over the picked logits; a sigmoid of "
+            "them is not built")
+    if cfg.n_shared_experts or cfg.hc_mult != 1:
+        raise NotImplementedError(
+            f"n_shared_experts={cfg.n_shared_experts}, hc_mult="
+            f"{cfg.hc_mult}: the 'gqa_moe_*' kinds have no shared expert "
+            "and one residual stream")
+    if "gqa_moe_window" in cfg.layer_types and cfg.sliding_window_size < 1:
+        raise ValueError("a 'gqa_moe_window' layer needs "
+                         "sliding_window_size")
+    windows = tuple(int(k == "gqa_moe_window") for k in cfg.layer_types
+                    if k in GQA_MOE_KINDS)
+    for name in ("rope_layout", "sliding_window_layout"):
+        stated = getattr(cfg, name)
+        if stated is not None and tuple(stated) != windows:
+            raise NotImplementedError(
+                f"{name} {stated} against layer_types' windows {windows}: "
+                "a window layer rotates and a global layer does not — a "
+                "rotated global layer or an unrotated window layer is a "
+                "kind the trunk lacks")
+
+
 def check_layout(cfg, mesh, pp: int) -> None:
     """Layers by kind run on one device or, without a state-space kind,
     not at all split: what is missing is named, nothing runs wrong."""
@@ -135,10 +197,10 @@ def check_layout(cfg, mesh, pp: int) -> None:
             "runs of its own), the Mamba-2 mixer no head-parallel "
             "projections (mp), no state hand-over between sequence shards "
             "(sep) and its kernel no shard_map over the batch (dp, "
-            "sharding); 'mla_dense' / 'mla_moe' no exchange of routed rows "
-            "between the devices that share a layer's experts and no "
-            "shard_map around flash_attention_split and the grouped "
-            "products; one device runs it")
+            "sharding); 'mla_dense' / 'mla_moe' / 'gqa_moe_*' no exchange "
+            "of routed rows between the devices that share a layer's "
+            "experts and no shard_map around flash_attention_split and "
+            "the grouped products; one device runs it")
 
 
 # ---------------------------------------------------------------------------
@@ -157,6 +219,14 @@ def kind_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     if kind in MLA_KINDS:
         return _mla_shapes(cfg, kind)
     dense = _block_shapes(cfg)
+    if kind in GQA_MOE_KINDS:
+        c, f, e = (cfg.hidden_size, cfg.moe_intermediate_size,
+                   cfg.experts_held)
+        out = {nm: dense[nm] for nm in ("ln1", "wq", "wk", "wv", "wo",
+                                        "ln2")}
+        out.update({"w_router": (c, cfg.n_routed_experts),
+                    "we_gate_up": (e, c, 2 * f), "we_down": (e, f, c)})
+        return out
     if kind == "attention":
         return dense
     h = cfg.hidden_size
@@ -190,8 +260,9 @@ def block_specs(cfg) -> Dict[str, Dict[str, P]]:
 
 def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     """``layers`` layers of one leaf, stacked.  Matrices normal at
-    1/sqrt(hidden) (the latent-attention kinds': at 1/sqrt(the width the
-    matrix contracts), their mixers' alpha ones and b zeros, so that m =
+    1/sqrt(hidden) (the latent-attention and ``gqa_moe_*`` kinds': at
+    1/sqrt(the width the
+    matrix contracts), the mixers' alpha ones and b zeros, so that m =
     u . phi is of unit size and H_res differs from token to token), norms
     and D ones, the convolution uniform in +-1 /
     sqrt(d_conv), ``A_log = log U[1, 16]`` and ``dt_bias`` the inverse
@@ -216,7 +287,8 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     else:
         # a matrix: normal at 1/sqrt(its rows), the width it contracts
         out = jax.random.normal(key, shape, f32) / math.sqrt(
-            cfg.hidden_size if kind not in MLA_KINDS else shape[-2])
+            shape[-2] if kind in MLA_KINDS + GQA_MOE_KINDS
+            else cfg.hidden_size)
     return out.astype(dtype or cfg.param_dtype)
 
 
@@ -389,24 +461,57 @@ def _mla_attention(bp, x, cfg):
         return o.reshape(b, s, -1) @ bp["wo"].astype(dt)
 
 
-def _expert_layer(bp, x, cfg):
+def _routing(bp, x, cfg, rule: str = "sigmoid"):
+    """The picks' gates and their plan from the ROUTER's input x [b, s,
+    C]: the experts' own input, or whatever else a kind routes on."""
+    from ..ops import moe
+    rows = x.reshape(-1, x.shape[-1])
+    with jax.named_scope("moe_route"):
+        idx, gate = moe.route(rows, bp["w_router"], cfg.num_experts_per_tok,
+                              cfg.routed_scaling_factor, rule)
+    with jax.named_scope("moe_dispatch"):
+        return gate, moe.plan(idx, cfg.expert_first, cfg.experts_held,
+                              cfg.n_routed_experts)
+
+
+def _expert_layer(bp, x, cfg, routing=None, act: str = "silu"):
     """The routed experts held here, for the pairs routed to them, beside
-    the shared experts: x [b, s, C] (normed) -> [b, s, C]."""
+    the shared experts where the layer has them: x [b, s, C] (normed) ->
+    [b, s, C].  ``routing``: what :func:`_routing` gave for another input
+    than x (None: x routes itself)."""
     from ..ops import moe
     from .llama_pretrain import _swiglu
     b, s, c = x.shape
-    rows = x.reshape(b * s, c)
-    with jax.named_scope("moe_route"):
-        idx, gate = moe.route(rows, bp["w_router"], cfg.num_experts_per_tok,
-                              cfg.routed_scaling_factor)
-    with jax.named_scope("moe_dispatch"):
-        p = moe.plan(idx, cfg.expert_first, cfg.experts_held,
-                     cfg.n_routed_experts)
-    routed = moe.routed_ffn(rows, gate, bp["we_gate_up"], bp["we_down"],
-                            p).reshape(b, s, c)
+    gate, p = routing or _routing(bp, x, cfg)
+    routed = moe.routed_ffn(x.reshape(b * s, c), gate, bp["we_gate_up"],
+                            bp["we_down"], p, act).reshape(b, s, c)
+    if "ws_gate" not in bp:
+        return routed
     with jax.named_scope("moe_shared"):
         return routed + _swiglu(x, bp["ws_gate"], bp["ws_up"],
                                 bp["ws_down"], cfg.dtype)
+
+
+def _gqa_moe_block(bp, x, cfg, mesh=None, seg=None, *, window: bool):
+    """One ``gqa_moe_window`` / ``gqa_moe_global`` layer on x [b, s, C]
+    (the module docstring has the equations)."""
+    from .llama_pretrain import _attention, _qkv, _residual, _rms_norm
+    b, s, _ = x.shape
+    with jax.named_scope("block"):
+        with jax.named_scope("attn_qkv"):
+            y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+        # before attention, whose input it shares: nothing below until
+        # the experts reads it
+        routing = _routing(bp, y, cfg, "softmax_of_picks")
+        q, k, v = _qkv(bp, y, cfg, mesh, rotate=window)
+        with jax.named_scope("attn"):
+            attn = _attention(q, k, v, cfg, mesh, seg,
+                              cfg.sliding_window_size if window else None)
+        with jax.named_scope("attn_out"):
+            x = _residual(x, attn.reshape(b, s, -1)
+                          @ bp["wo"].astype(cfg.dtype), cfg)
+        u = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
+        return _residual(x, _expert_layer(bp, u, cfg, routing, "relu"), cfg)
 
 
 class _Mixer(NamedTuple):
@@ -634,12 +739,16 @@ def trunk(blocks, x, cfg, mesh):
     from .llama_pretrain import (_block_forward, _remat_wrap,
                                  keeps_flash_outputs)
     body = {"attention": _block_forward, "mamba": _mamba_block,
-            "mla_dense": _mla_block, "mla_moe": _mla_block}
+            "mla_dense": _mla_block, "mla_moe": _mla_block,
+            "gqa_moe_global": functools.partial(_gqa_moe_block,
+                                                window=False),
+            "gqa_moe_window": functools.partial(_gqa_moe_block,
+                                                window=True)}
     # the kinds whose blocks call the flash kernels (``check``: latent
     # attention does not mix with the others); their layers together
     # count against FLASH_KEPT_BYTES, at a head's value width
     mla = cfg.layer_types[0] in MLA_KINDS
-    flash_kinds = MLA_KINDS if mla else ("attention",)
+    flash_kinds = MLA_KINDS if mla else ("attention",) + GQA_MOE_KINDS
     keep_flash = keeps_flash_outputs(
         x.shape[0], x.shape[1], cfg.num_attention_heads,
         cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,

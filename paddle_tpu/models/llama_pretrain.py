@@ -121,19 +121,50 @@ class LlamaPretrainConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    # a head's width where the configuration states it (None: hidden /
+    # heads, what it has always been)
+    head_dim: Optional[int] = None
+    # WINDOW AND GLOBAL GQA LAYERS BEFORE AN EXPERT LAYER, ONE STREAM
+    # (hybrid_trunk's kinds 'gqa_moe_window' / 'gqa_moe_global'): a layer
+    # whose entry of ``sliding_window_layout`` is 1 sees the last
+    # ``sliding_window_size`` keys, one whose entry is 0 every earlier key;
+    # a layer whose entry of ``rope_layout`` is 1 rotates q and k.  The
+    # published keys keep their names; ``layer_types`` follows from the
+    # two lists.  The router reads the ATTENTION's input and, with
+    # ``moe_primary_router_apply_softmax``, takes the top
+    # ``num_experts_per_tok`` of its logits and a softmax over the picked;
+    # its width, the picks and the experts' width are the fields above
+    # (``n_routed_experts`` = the published ``moe_num_primary_experts``,
+    # ``num_experts_per_tok`` = ``moe_num_active_primary_experts``,
+    # ``moe_intermediate_size`` = ``moe_ffn_hidden_size``), the share
+    # ``experts_held`` / ``expert_first``.
+    rope_layout: Optional[Tuple[int, ...]] = None
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    sliding_window_size: int = 0
+    moe_primary_router_apply_softmax: bool = False
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
             self.num_key_value_heads = self.num_attention_heads
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
         if self.experts_held is None:
             self.experts_held = self.n_routed_experts
+        if self.sliding_window_layout is not None and \
+                self.layer_types is None:
+            self.layer_types = tuple(
+                "gqa_moe_window" if w else "gqa_moe_global"
+                for w in self.sliding_window_layout)
         if self.kv_lora_rank and self.layer_types is None:
             dense = min(self.first_k_dense_replace, self.num_hidden_layers)
             self.layer_types = ("mla_dense",) * dense + ("mla_moe",) * (
                 self.num_hidden_layers - dense)
         if self.layer_types is not None:
-            self.layer_types = tuple(
-                self.layer_types[:self.num_hidden_layers])
+            cut = lambda a: a if a is None else tuple(
+                a[:self.num_hidden_layers])
+            self.layer_types = cut(self.layer_types)
+            self.rope_layout = cut(self.rope_layout)
+            self.sliding_window_layout = cut(self.sliding_window_layout)
             from . import hybrid_trunk
             hybrid_trunk.check(self)
         if self.position_embedding_type not in ("rope", "nope"):
@@ -147,10 +178,6 @@ class LlamaPretrainConfig:
             raise ValueError(
                 f"context_parallel must be None, 'ring' or 'ulysses', "
                 f"got {self.context_parallel!r}")
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
 
 
 def build_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1, devices=None) -> Mesh:
@@ -169,10 +196,11 @@ def build_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1, devices=None) -> Mesh:
 # ---------------------------------------------------------------------------
 def _block_shapes(cfg: LlamaPretrainConfig) -> Dict[str, Tuple[int, ...]]:
     h, f = cfg.hidden_size, cfg.intermediate_size
+    qh = cfg.num_attention_heads * cfg.head_dim
     kvh = cfg.num_key_value_heads * cfg.head_dim
     return {
         "ln1": (h,), "ln2": (h,),
-        "wq": (h, h), "wk": (h, kvh), "wv": (h, kvh), "wo": (h, h),
+        "wq": (h, qh), "wk": (h, kvh), "wv": (h, kvh), "wo": (qh, h),
         "w_gate": (h, f), "w_up": (h, f), "w_down": (f, h),
     }
 
@@ -339,10 +367,14 @@ def _rope(q, k, theta, mesh=None):
     return rot(q), rot(k)
 
 
-def _attention(q, k, v, cfg, mesh=None, seg=None):
+def _attention(q, k, v, cfg, mesh=None, seg=None, window=None):
     """Causal attention [b, s, n, d].  Routes to context-parallel
     attention over the sep axis when configured, else the Pallas flash
     kernel when registered (ops/pallas), else the fused XLA composite.
+
+    ``window``: a query sees its last ``window`` keys, itself among
+    them (the flash kernels' windowed form, or the composite's mask; no
+    context-parallel or packed path has one).
 
     ``seg`` [b, s] int32 enables PACKED-pretrain attention: sequences
     concatenated along s attend only within their own segment, via the
@@ -360,6 +392,11 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
             v = jnp.repeat(v, rep, axis=2)
         return k, v
 
+    if window is not None and (seg is not None or (
+            cfg.context_parallel and mesh is not None
+            and mesh.shape.get("sep", 1) > 1)):
+        raise NotImplementedError(
+            "a window over packed segments or sequence shards")
     if cfg.context_parallel and mesh is not None and \
             mesh.shape.get("sep", 1) > 1:
         if seg is not None:
@@ -395,6 +432,8 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
         mp = 1 if mesh is None else mesh.shape.get("mp", 1)
         if q.shape[2] % mp == 0:
             k, v = full_heads(k, v, math.lcm(k.shape[2], mp))
+        if window is not None:
+            impl = functools.partial(impl, window=window)
         return _per_shard(lambda q, k, v: impl(q, k, v, causal=True),
                           mesh, q.shape[2], k.shape[2], 3)(q, k, v)
     k, v = full_heads(k, v)
@@ -402,6 +441,8 @@ def _attention(q, k, v, cfg, mesh=None, seg=None):
     logits = jnp.einsum("bqnd,bknd->bnqk", q, k) * scale
     s = logits.shape[-1]
     mask = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        mask &= ~jnp.tril(mask, -window)
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(v.dtype)
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
@@ -411,12 +452,22 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
                     mesh: Optional[Mesh] = None):
     """ln1 + QKV projections + rope -> q [b, s, n, d], k, v [b, s, nkv,
     d]: K/V at their own head count."""
-    b, s, h = x.shape
+    with jax.named_scope("attn_qkv"):
+        y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+    return _qkv(bp, y, cfg, mesh, cfg.position_embedding_type == "rope")
+
+
+def _qkv(bp: Dict[str, Any], y, cfg: LlamaPretrainConfig,
+         mesh: Optional[Mesh], rotate: bool):
+    """The projections of y = ln1(x), rotated where ``rotate`` says (a
+    configuration's one ``position_embedding_type``, or a kind's own
+    rule: hybrid_trunk's window layers rotate, its global ones do
+    not)."""
+    b, s, h = y.shape
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
     dt = cfg.dtype
     with jax.named_scope("attn_qkv"):
-        y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
         q = (y @ bp["wq"].astype(dt)).reshape(b, s, n, d)
         k = (y @ bp["wk"].astype(dt)).reshape(b, s, nkv, d)
         v = (y @ bp["wv"].astype(dt)).reshape(b, s, nkv, d)
@@ -424,7 +475,7 @@ def _block_pre_attn(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
             # the kernels score at 1/sqrt(d): the rest of the
             # configuration's own scale rides on q
             q = q * (cfg.attention_multiplier * math.sqrt(d))
-    if cfg.position_embedding_type == "rope":
+    if rotate:
         with jax.named_scope("rope"):
             q, k = _rope(q, k, cfg.rope_theta, mesh)
     # GQA stays UN-repeated here: _attention's flash kernels, dense and
@@ -453,7 +504,7 @@ def _block_post_attn(bp: Dict[str, Any], x, attn,
     path) — see :func:`_mm`."""
     b, s, h = x.shape
     with jax.named_scope("attn_out"):
-        x = _residual(x, _mm(attn.reshape(b, s, h), bp["wo"], cfg.dtype),
+        x = _residual(x, _mm(attn.reshape(b, s, -1), bp["wo"], cfg.dtype),
                       cfg)
     with jax.named_scope("mlp"):
         return _ffn(bp, x, cfg)

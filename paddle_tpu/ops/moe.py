@@ -5,7 +5,8 @@ The device holds ``held`` consecutive experts, ``first .. first + held
 - 1``, of the ``published`` ones.  It routes every token over ALL the
 published experts (:func:`route`: sigmoid scores, the top ``k``, gates
 normalised over the ``k`` picks whether their experts are held here or
-not), keeps the (token, pick) pairs whose expert it holds, and computes
+not — or, the second rule, the top ``k`` of the LOGITS and a softmax
+over the picked), keeps the (token, pick) pairs whose expert it holds, and computes
 those experts' part of the layer's result.  What the absent experts
 would add is left out: on one device the layer runs without its
 exchange, and nothing stands in for the other devices.
@@ -105,14 +106,30 @@ def _padded(pairs: int, held: int) -> int:
     return -(-pairs // TILE_M) * TILE_M + held * TILE_M
 
 
-def route(x, w_router, k: int, scale: float):
+RULES = ("sigmoid", "softmax_of_picks")
+ACTIVATIONS = ("silu", "relu")
+
+
+def route(x, w_router, k: int, scale: float, rule: str = "sigmoid"):
     """x [T, C], w_router [C, published] -> the picks ``idx [T, k]`` and
-    their gates ``[T, k]`` (fp32): ``scale * s_e / (sum of the picked s
-    + 1e-20)``, ``s = sigmoid(x . w_router)`` in fp32 — a pick is a
-    comparison of scores, so the scores take no rounding they need not."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               w_router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
+    their gates ``[T, k]`` (fp32), by one of two rules (static):
+
+    ``sigmoid``: ``scale * s_e / (sum of the picked s + 1e-20)``, ``s =
+    sigmoid(x . w_router)``, the top ``k`` of s.
+    ``softmax_of_picks``: the top ``k`` of the LOGITS ``z = x .
+    w_router``, ``scale * softmax(z over the k picked)`` — the gates sum
+    to ``scale`` whichever experts are held here.
+
+    The product is fp32 either way — a pick is a comparison of scores,
+    so the scores take no rounding they need not."""
+    if rule not in RULES:
+        raise ValueError(f"route: rule {rule!r}, one of {RULES}")
+    z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    if rule == "softmax_of_picks":
+        top, idx = jax.lax.top_k(z, k)
+        return idx.astype(I32), scale * jax.nn.softmax(top, axis=-1)
+    s = jax.nn.sigmoid(z)
     top, idx = jax.lax.top_k(s, k)
     gate = scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
     return idx.astype(I32), gate
@@ -214,13 +231,14 @@ def _at_the_load(fn, p: Plan, *args):
                         at(full, "moe_bound_all"), p, *args)
 
 
-@jax.custom_vjp
-def routed_ffn(x, gate, w_gate_up, w_down, p: Plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def routed_ffn(x, gate, w_gate_up, w_down, p: Plan, act: str = "silu"):
     """The held experts' part of the layer: x [T, C], gate [T, k] (fp32)
     and the stacks ``[held, C, 2 F]`` (gate | up, one product) and
     ``[held, F, C]`` as the optimizer holds them (the kernels cast a
     panel in VMEM) -> y [T, C] = sum over a token's kept pairs of gate *
-    E_e(x),  E(x) = (silu(x w_g) * x w_u) w_d.
+    E_e(x),  E(x) = (act(x w_g) * x w_u) w_d, ``act`` (static) ``silu``
+    or ``relu``.
 
     The gate rides on the F-wide hidden rows, before the down product.
     One backward for the whole path: it keeps x, the [M, 2 F] product
@@ -228,17 +246,35 @@ def routed_ffn(x, gate, w_gate_up, w_down, p: Plan):
     — no [M, C] buffer outlives the pass that made it.  Every pass runs
     on the bound the load asks for (:func:`_at_the_load`); what is kept
     has the full bound's shapes, filled in its first rows."""
-    return _at_the_load(lambda *a: _forward(*a)[0], p, x, gate, w_gate_up,
-                        w_down)
+    return _at_the_load(lambda *a: _forward(act, *a)[0], p, x, gate,
+                        w_gate_up, w_down)
 
 
-def _forward(p, x, gate, w_gate_up, w_down):
+def _activation(act: str):
+    if act not in ACTIVATIONS:
+        raise ValueError(f"routed_ffn: act {act!r}, one of {ACTIVATIONS}")
+    return jax.nn.silu if act == "silu" else jax.nn.relu
+
+
+def _hidden_and_its_gradient(act: str, g, u):
+    """``a(g) * u`` (fp32) and the function from its cotangent to (d_g,
+    d_u): silu's ``g sig(g)`` with ``sig (1 + g (1 - sig))``, or relu's
+    ``max(g, 0)`` with its step."""
+    if act == "silu":
+        sig = jax.nn.sigmoid(g)
+        return g * sig * u, lambda d: [
+            d * u * sig * (1 + g * (1 - sig)), d * g * sig]
+    a = _activation(act)(g)
+    return a * u, lambda d: [jnp.where(g > 0, d * u, 0), d * a]
+
+
+def _forward(act, p, x, gate, w_gate_up, w_down):
     te, n, f = p.tile_expert, p.n_tiles, w_down.shape[1]
     with jax.named_scope("moe_dispatch"):
         rows = _tokens_of_rows(x, p)
     with jax.named_scope("moe_experts"):
         gu = grouped_mm(rows, w_gate_up, te, n)
-        h = (jax.nn.silu(gu[:, :f].astype(jnp.float32)) * gu[:, f:]
+        h = (_activation(act)(gu[:, :f].astype(jnp.float32)) * gu[:, f:]
              * _gate_of_rows(gate, p)[:, None]).astype(x.dtype)
         out = grouped_mm(h, w_down, te, n)
     with jax.named_scope("moe_combine"):
@@ -246,24 +282,25 @@ def _forward(p, x, gate, w_gate_up, w_down):
     return y, gu
 
 
-def _routed_fwd(x, gate, w_gate_up, w_down, p):
+def _routed_fwd(x, gate, w_gate_up, w_down, p, act):
     full = p.row_pair.shape[0]
 
     def kept(p, *args):
-        y, gu = _forward(p, *args)
+        y, gu = _forward(act, p, *args)
         with jax.named_scope("moe_experts"):
             return y, jnp.pad(gu, ((0, full - gu.shape[0]), (0, 0)))
     y, gu = _at_the_load(kept, p, x, gate, w_gate_up, w_down)
     return y, (x, gate, w_gate_up, w_down, p, gu)
 
 
-def _routed_bwd(res, dy):
+def _routed_bwd(act, res, dy):
     x, gate, w_gate_up, w_down, p, gu = res
-    d = _at_the_load(_backward, p, x, gate, w_gate_up, w_down, gu, dy)
+    d = _at_the_load(functools.partial(_backward, act), p, x, gate,
+                     w_gate_up, w_down, gu, dy)
     return d + (None,)
 
 
-def _backward(p, x, gate, w_gate_up, w_down, gu, dy):
+def _backward(act, p, x, gate, w_gate_up, w_down, gu, dy):
     te, n, f32 = p.tile_expert, p.n_tiles, jnp.float32
     f, held = w_down.shape[1], w_down.shape[0]
     with jax.named_scope("moe_combine"):
@@ -274,16 +311,12 @@ def _backward(p, x, gate, w_gate_up, w_down, gu, dy):
         gu = gu[:p.row_pair.shape[0]]
         g, u = gu[:, :f].astype(f32), gu[:, f:].astype(f32)
         g_row = _gate_of_rows(gate, p)[:, None]
-        sig = jax.nn.sigmoid(g)
-        act = g * sig * u
+        hid, d_gu_of = _hidden_and_its_gradient(act, g, u)
         d_h = grouped_mm(d_out, w_down, te, n, trans_w=True).astype(f32)
-        d_wd = grouped_mm_dw((act * g_row).astype(x.dtype), d_out, te, n,
+        d_wd = grouped_mm_dw((hid * g_row).astype(x.dtype), d_out, te, n,
                              held)
-        d_gate_row = jnp.sum(d_h * act, axis=1)
-        d_act = d_h * g_row
-        d_gu = jnp.concatenate(
-            [d_act * u * sig * (1 + g * (1 - sig)), d_act * g * sig],
-            axis=1).astype(x.dtype)
+        d_gate_row = jnp.sum(d_h * hid, axis=1)
+        d_gu = jnp.concatenate(d_gu_of(d_h * g_row), axis=1).astype(x.dtype)
     with jax.named_scope("moe_dispatch"):
         rows = _tokens_of_rows(x, p)
     with jax.named_scope("moe_experts"):

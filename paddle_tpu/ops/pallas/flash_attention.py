@@ -87,17 +87,60 @@ def _matmul_t(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _visible(q0, k0, shape, q_axis):
-    """Causal visibility of the diagonal block: ``shape`` holds query
+def _visible(q0, k0, shape, q_axis, window=None):
+    """Visibility inside a block that is masked: ``shape`` holds query
     positions from ``q0`` along ``q_axis``, key positions from ``k0``
-    along the other axis."""
+    along the other axis.  The diagonal block (``window`` None) is cut
+    by the causal rule, key <= query; the window's EDGE block, ``window
+    / block`` blocks below the diagonal, by the window's far end, key >
+    query - window."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return k_pos > q_pos - jnp.int32(window)
+
+
+def _once_if(cond, at, body, carry):
+    """``body(at, carry)`` where ``cond`` holds, else ``carry``: a loop
+    of one trip or none."""
+    return jax.lax.fori_loop(at, at + cond.astype(jnp.int32), body, carry)
+
+
+def _over_keys(qi, n_window, body, init):
+    """A query block's key blocks in order, for ``flash_fwd`` and
+    ``flash_bwd_dq``: blocks [0, qi) whole and the diagonal masked — or,
+    under a window of ``n_window`` blocks, the edge block ``qi -
+    n_window`` masked where there is one, the blocks between whole, the
+    diagonal masked: ``n_window + 1`` blocks at most, the rest of the row
+    is never visited.  ``body(ki, carry, mask)``, mask None | "causal" |
+    "window"."""
+    if n_window is None:
+        carry = jax.lax.fori_loop(
+            jnp.int32(0), qi, lambda ki, c: body(ki, c, None), init)
+        return body(qi, carry, "causal")
+    edge = qi - jnp.int32(n_window)
+    carry = _once_if(edge >= 0, edge,
+                     lambda ki, c: body(ki, c, "window"), init)
+    carry = jax.lax.fori_loop(
+        jnp.maximum(edge + 1, 0), qi, lambda ki, c: body(ki, c, None), carry)
+    return body(qi, carry, "causal")
+
+
+def _masked(s, mask, q0, k0, q_axis, window):
+    """Scores ``s`` with what ``mask`` hides at NEG_INF.  Only the
+    diagonal block and a window's edge block pay for it (iota + cmp +
+    select are pure VPU work); the blocks between are all-visible because
+    the loop bounds exclude every other."""
+    if mask is None:
+        return s
+    seen = _visible(q0, k0, s.shape, q_axis,
+                    window if mask == "window" else None)
+    return jnp.where(seen, s, jnp.float32(NEG_INF))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
-                sm_scale: float, block_k: int):
+                sm_scale: float, block_k: int, window=None):
     # q_ref/o_ref: [Bq, d]; k_ref/v_ref: [S, d]; lse_ref: [1, Bq].
     # SPLIT scores (:func:`flash_attention_split`): q2_ref [Bq, d2] and
     # k2_ref [S, d2] come before the outputs, and their product is added
@@ -109,7 +152,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
     q = q_ref[:]
     q2 = second[0][:] if second else None
 
-    def body(ki, carry, masked):
+    def body(ki, carry, mask):
         m_prev, l_prev, acc = carry
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
@@ -117,12 +160,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
         if second:
             s = s + _scores(q2, second[1][pl.ds(ki * block_k, block_k), :])
         s = s * jnp.float32(sm_scale)
-        if masked:
-            # only the diagonal block pays for the mask (iota+cmp+select
-            # are pure VPU work; off-diagonal causal blocks are all-visible
-            # because the loop bound below already excludes future blocks)
-            s = jnp.where(_visible(qi * Bq, ki * block_k, s.shape, 0),
-                          s, jnp.float32(NEG_INF))
+        s = _masked(s, mask, qi * Bq, ki * block_k, 0, window)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -137,21 +175,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
     assert not causal or Bq == block_k, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
-        # blocks [0, qi) are fully visible; block qi is the masked diagonal
-        carry = jax.lax.fori_loop(
-            jnp.int32(0), qi, lambda ki, c: body(ki, c, masked=False), init)
-        m, l, acc = body(qi, carry, masked=True)
+        m, l, acc = _over_keys(qi, window and window // block_k, body, init)
     else:
         m, l, acc = jax.lax.fori_loop(
             jnp.int32(0), jnp.int32(S // block_k),
-            lambda ki, c: body(ki, c, masked=False), init)
+            lambda ki, c: body(ki, c, None), init)
     l_safe = jnp.maximum(l, jnp.float32(1e-30))
     o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[:] = (m + jnp.log(l_safe)).T
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
-                   causal: bool, sm_scale: float, block_k: int):
+                   causal: bool, sm_scale: float, block_k: int, window=None):
     # q/o/do/dq: [Bq, d]; k/v: [S, d]; lse_ref (in), delta_ref (out):
     # [1, Bq] — rows here are queries, so both turn once a grid step.
     # Split scores: q2_ref [Bq, d2], k2_ref [S, d2] before the outputs
@@ -170,7 +205,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
     delta = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
                     axis=1, keepdims=True)
 
-    def body(ki, dqs, masked):
+    def body(ki, dqs, mask):
         k = k_ref[pl.ds(ki * block_k, block_k), :]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
         s = _scores(q, k)
@@ -178,9 +213,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
             k2 = second[1][pl.ds(ki * block_k, block_k), :]
             s = s + _scores(q2, k2)
         s = s * jnp.float32(sm_scale)
-        if masked:
-            s = jnp.where(_visible(qi * Bq, ki * block_k, s.shape, 0),
-                          s, jnp.float32(NEG_INF))
+        s = _masked(s, mask, qi * Bq, ki * block_k, 0, window)
         p = jnp.exp(s - lse)
         ds = p * (_scores(do, v) - delta) * jnp.float32(sm_scale)
         dq = dqs[0] + _matmul(ds.astype(k.dtype), k)
@@ -194,13 +227,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
     assert not causal or Bq == block_k, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
     if causal:
-        dq = jax.lax.fori_loop(
-            jnp.int32(0), qi, lambda ki, c: body(ki, c, masked=False), dq0)
-        dq = body(qi, dq, masked=True)
+        dq = _over_keys(qi, window and window // block_k, body, dq0)
     else:
         dq = jax.lax.fori_loop(
             jnp.int32(0), jnp.int32(S // block_k),
-            lambda ki, c: body(ki, c, masked=False), dq0)
+            lambda ki, c: body(ki, c, None), dq0)
     dq_ref[:] = dq[0].astype(dq_ref.dtype)
     if second:
         dq2_ref[:] = dq[1].astype(dq2_ref.dtype)
@@ -209,7 +240,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
                     causal: bool, sm_scale: float, block_q: int,
-                    also_dq: bool, split: bool = False):
+                    also_dq: bool, split: bool = False, window=None):
     # k/v/dk/dv: [Bk, d] of one KV head; q/do: [S, d], lse: [S/Bq, 1, Bq]
     # of ONE query head of its group (grid axis 3, innermost).  Scores
     # are formed TRANSPOSED, keys on rows: a block's [1, Bq] statistics
@@ -269,7 +300,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
     else:
         delta_ref, stat0 = last_ref, jnp.int32(0)
 
-    def body(qi, carry, masked):
+    def body(qi, carry, mask):
         dk, dv, *dk2 = carry
         rows = pl.ds(qi * block_q, block_q)
         q = q_ref[rows, :]
@@ -281,9 +312,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
             q2 = q2_ref[rows, :]
             st = st + _scores(k2, q2)
         st = st * jnp.float32(sm_scale)
-        if masked:
-            st = jnp.where(_visible(qi * block_q, ki * Bk, st.shape, 1),
-                           st, jnp.float32(NEG_INF))
+        st = _masked(st, mask, qi * block_q, ki * Bk, 1, window)
         pt = jnp.exp(st - lse)
         dv = dv + _matmul(pt.astype(do.dtype), do)
         dst = (pt * (_scores(v, do) - delta)
@@ -307,17 +336,27 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
         carry += (jnp.zeros(k2.shape, jnp.float32),)
     assert not causal or Bk == block_q, \
         "_pick_blocks guarantees square blocks; causal masking relies on it"
-    if causal:
+    n_q = jnp.int32(S // block_q)
+    if causal and window:
+        # the diagonal masked, then the q blocks that see this k block in
+        # full, then the window's edge block ``ki + window / block``
+        # masked where the row has one: the later q blocks never come
+        edge = ki + jnp.int32(window // block_q)
+        carry = body(ki, carry, "causal")
+        carry = jax.lax.fori_loop(
+            ki + 1, jnp.minimum(edge, n_q),
+            lambda qi, c: body(qi, c, None), carry)
+        dk, dv, *dk2 = _once_if(
+            edge < n_q, edge, lambda qi, c: body(qi, c, "window"), carry)
+    elif causal:
         # diagonal block qi == ki is masked; strictly-later q blocks see
         # this k block in full
-        carry = body(ki, carry, masked=True)
+        carry = body(ki, carry, "causal")
         dk, dv, *dk2 = jax.lax.fori_loop(
-            ki + 1, jnp.int32(S // block_q),
-            lambda qi, c: body(qi, c, masked=False), carry)
+            ki + 1, n_q, lambda qi, c: body(qi, c, None), carry)
     else:
         dk, dv, *dk2 = jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(S // block_q),
-            lambda qi, c: body(qi, c, masked=False), carry)
+            jnp.int32(0), n_q, lambda qi, c: body(qi, c, None), carry)
     dk_acc[:] = dk
     dv_acc[:] = dv
     if split:
@@ -361,9 +400,10 @@ _DEFAULT_VMEM_LIMIT = 16 << 20
 FWD_OUTPUT_NAMES = ("flash_out", "flash_lse")
 
 
-def _pick_blocks(S: int):
-    """Largest power-of-two block <= 512 that divides S, or None when no
-    block >= 8 divides S (caller must fall back to the XLA path — a
+def _pick_blocks(S: int, window=None):
+    """Largest power-of-two block <= 512 that divides S — and the window,
+    where there is one: its edge then lies in ONE block — or None when no
+    block >= 8 does (caller must fall back to the XLA path — a
     non-dividing block floor-truncates the grid and leaves rows
     uninitialized).
 
@@ -374,17 +414,24 @@ def _pick_blocks(S: int):
     41.3) — 34.9 % of the bf16 peak together.  Smaller blocks pay more
     grid steps a row, larger ones more VMEM a step."""
     for b in (512, 256, 128, 64, 32, 16, 8):
-        if S % b == 0:
+        if S % b == 0 and (window or b) % b == 0:
             return b, b
     return None
 
 
-def _pairs(s: int, block: int, causal: bool) -> int:
+def _pairs(s: int, block: int, causal: bool, window=None) -> int:
     """(q block, k block) pairs one head's kernel EXECUTES: causal, the
     loop bounds skip the pairs above the diagonal and run the diagonal's
-    whole, masked; each counts as a pair in a ``cost_estimate``."""
+    whole, masked; under a window they skip the pairs below its edge
+    block too and run that whole, masked — a q block meets ``window /
+    block + 1`` k blocks at most (252 pairs a head at S 16,384, window
+    4,096, block 512, where 528 are causal and 224 the visible keys'
+    worth).  Each counts as a pair in a ``cost_estimate``."""
     nb = s // block
-    return nb * (nb + 1) // 2 if causal else nb * nb
+    if not causal:
+        return nb * nb
+    reach = nb if window is None else min(window // block + 1, nb)
+    return reach * (reach + 1) // 2 + (nb - reach) * reach
 
 
 def causal_mask(q_len: int, k_len: int):
@@ -404,7 +451,7 @@ def causal_mask(q_len: int, k_len: int):
     return q_pos >= k_pos
 
 
-def _xla_sdpa(q, k, v, causal):
+def _xla_sdpa(q, k, v, causal, window=None):
     """Reference XLA attention — fallback for shapes the Pallas kernel
     does not support (indivisible S, decode q_len != kv_len).  XLA fuses
     this well; autodiff is native.  GQA repeats K/V here: this is the
@@ -416,36 +463,53 @@ def _xla_sdpa(q, k, v, causal):
     qf = q.astype(jnp.float32) / math.sqrt(d)
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     if causal:
-        s = jnp.where(causal_mask(q.shape[1], k.shape[1]), s, NEG_INF)
+        seen = causal_mask(q.shape[1], k.shape[1])
+        if window is not None:
+            # end-aligned like the causal rule: the last ``window`` keys
+            seen &= ~jnp.tril(jnp.ones_like(seen),
+                              k.shape[1] - q.shape[1] - window)
+        s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
 
-def flash_attention(q, k, v, causal: bool = False):
+def flash_attention(q, k, v, causal: bool = False, window=None):
     """q: [b, s, h, d], k/v: [b, s, nkv, d] with nkv dividing h (GQA
     native) -> out [b, s, h, d].
 
+    ``window`` (causal only): key j is visible to query i iff ``i -
+    window < j <= i``.  A window the row does not outgrow hides nothing
+    and is the dense form; a shorter one is the WINDOWED form — the same
+    kernel bodies on fewer block pairs, under the names ``flash_win_*``.
+
     Routes to the Pallas kernel when the (static) shapes fit its blocking
     (q_len == kv_len, a block :func:`_pick_blocks` offers divides
-    S); otherwise falls back to a fused XLA attention (decode shapes, odd
-    lengths)."""
+    S and the window); otherwise falls back to a fused XLA attention
+    (decode shapes, odd lengths)."""
     if q.shape[2] % k.shape[2] != 0:
         raise ValueError(
             f"q heads {q.shape[2]} must be a multiple of kv heads "
             f"{k.shape[2]}")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"a window ({window}) is causal and >= 1")
+        if window >= k.shape[1]:
+            window = None
     if q.shape[1] == k.shape[1] and \
-            _pick_blocks(q.shape[1]) is not None:
+            _pick_blocks(q.shape[1], window) is not None:
         return _flash_pallas(q, k, v, None, None, causal,
-                             1.0 / math.sqrt(q.shape[-1]))
-    return _xla_sdpa(q, k, v, causal)
+                             1.0 / math.sqrt(q.shape[-1]), window)
+    return _xla_sdpa(q, k, v, causal, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash_pallas(q, k, v, q2, k2, causal: bool, sm_scale: float):
-    """One entry for both forms: ``q2`` / ``k2`` None is the dense one,
-    else the scores are split (:func:`flash_attention_split`)."""
-    return _flash_fwd(q, k, v, q2, k2, causal, sm_scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_pallas(q, k, v, q2, k2, causal: bool, sm_scale: float,
+                  window=None):
+    """One entry for every form: ``q2`` / ``k2`` None is the dense one,
+    else the scores are split (:func:`flash_attention_split`); ``window``
+    an int is the windowed one."""
+    return _flash_fwd(q, k, v, q2, k2, causal, sm_scale, window)[0]
 
 
 def _to_kernel(x):
@@ -526,31 +590,41 @@ def _shared_spec(rows, d2, at):
     return pl.BlockSpec((None, rows, d2), index)
 
 
-def _split_vmem(s, d, itemsize):
-    """What ``flash_fwd`` and the two-kernel backward of the SPLIT form
-    ask of VMEM: five whole-row operands of one head at most (a 64-wide
-    one fills 128 lanes), twice for the pipeline's buffers, beside the
-    tiles and the block products.  The one-pass backward asks for its
-    own sum (``_flash_bwd_vjp``)."""
-    resident = 2 * 5 * s * d * itemsize + (8 << 20)
+def _vmem_for(resident: int):
+    """The compiler parameters of a call whose whole-row operands take
+    ``resident`` bytes of VMEM, the pipeline's two buffers counted: None
+    (Mosaic's own limit) where they fit it beside 8 MiB for the tiles and
+    the block products, else a limit of that sum — by the shapes alone."""
+    resident += 8 << 20
     if resident <= _DEFAULT_VMEM_LIMIT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=resident)
 
 
-def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
+def _row_vmem(s, d, itemsize, rows: int = 2):
+    """What ``flash_fwd`` and the two-kernel backward ask of VMEM: a
+    head's ``rows`` whole-row operands — K and V, or q and dO; five at
+    most in the SPLIT form —, each row a whole number of lane tiles.
+    The dense form's stay within Mosaic's own limit up to S 8,192 at d
+    128 and ask past it (S 16,384: 16 MiB of K and V, 24 asked).  The
+    one-pass backward asks for its own sum (``_flash_bwd_vjp``)."""
+    return _vmem_for(2 * rows * s * -(-d // 128) * 128 * itemsize)
+
+
+def _flash_fwd(q, k, v, q2, k2, causal, sm_scale, window=None):
     b, s, h, d = q.shape
     qr, kr, vr = _to_kernel(q), _to_kernel(k), _to_kernel(v)
-    bq, bk = _pick_blocks(s)
+    bq, bk = _pick_blocks(s, window)
     tile, kv = _by_query_head(h // k.shape[2])
-    second, second_specs, params = (), [], None
+    second, second_specs = (), []
     if q2 is not None:
         second = (_to_kernel(q2), k2)
         second_specs = [_tile_spec(bq, k2.shape[2], tile),
                         _shared_spec(s, k2.shape[2], kv)]
-        params = _split_vmem(s, d, q.dtype.itemsize)
+    params = _row_vmem(s, d, q.dtype.itemsize, 5 if second else 2)
     nkv, d2 = k.shape[2], 0 if q2 is None else k2.shape[2]
-    pairs, it = b * h * _pairs(s, bq, causal), q.dtype.itemsize
+    pairs = b * h * _pairs(s, bq, causal, window)
+    it = q.dtype.itemsize
     cost = pl.CostEstimate(
         # a pair: q k^T (+ q2 k2^T) and p v; on its [bq, bk] scores the
         # scale, the running max, s - m and the row sum; exp of the
@@ -564,7 +638,7 @@ def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
                                      + (h + 1) * d2) + 4 * b * h * s)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
-                          block_k=bk),
+                          block_k=bk, window=window),
         out_shape=(jax.ShapeDtypeStruct(qr.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, s // bq, 1, bq),
                                         jnp.float32)),
@@ -573,7 +647,7 @@ def _flash_fwd(q, k, v, q2, k2, causal, sm_scale):
                   _tile_spec(s, d, kv)] + second_specs,
         out_specs=(_tile_spec(bq, d, tile), _stat_spec(None, bq, tile)),
         compiler_params=params,
-        name="flash_fwd",
+        name="flash_win_fwd" if window else "flash_fwd",
         cost_estimate=cost,
         interpret=_common.interpret(),
     )(qr, kr, vr, *second)
@@ -595,13 +669,13 @@ def _group_spec(s, group, d):
                         lambda i, j, r, g: idx32(i, j, 0, 0))
 
 
-def _flash_bwd_vjp(causal, sm_scale, res, dout):
+def _flash_bwd_vjp(causal, sm_scale, window, res, dout):
     qr, kr, vr, second, out, lse = res
     b, s, h, d = dout.shape
     nkv = kr.size // (b * s * d)
     group = h // nkv
     do = _to_kernel(dout)
-    bq, bk = _pick_blocks(s)
+    bq, bk = _pick_blocks(s, window)
     interp = _common.interpret()
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     d2 = second[1].shape[2] if second else 0
@@ -617,11 +691,12 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         second_in = [_tile_spec(s, d2, head), _shared_spec(bk, d2, tile)]
         out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
         out_specs.append(_tile_spec(bk, d2, tile))
-    params = _split_vmem(s, d, qr.dtype.itemsize) if second else None
+    params = _row_vmem(s, d, qr.dtype.itemsize, 5 if second else 2)
     # by the fp32 dQ of a KV head's group, dense and split form alike: the
     # split form's dQ2 follows from the shapes and is asked of VMEM below
     one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
-    pairs, it = b * h * _pairs(s, bq, causal), qr.dtype.itemsize
+    pairs = b * h * _pairs(s, bq, causal, window)
+    it = qr.dtype.itemsize
     # on a pair's scores, either kernel: the scale, s - lse, dP - delta
     # and the two products that give dS
     on_scores = 5
@@ -640,11 +715,9 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         # rest read 4.8 MiB at most (AOT for a described v5e, S 4096 and
         # 8192)
         lanes2 = -(-d2 // 128) * 128
-        resident = ((2 * (3 + group) * it + 4 * group) * d
-                    + (2 * (1 + group) * it + 4 * group) * lanes2) * s \
-            + (8 << 20)
-        if resident > _DEFAULT_VMEM_LIMIT:
-            params = pltpu.CompilerParams(vmem_limit_bytes=resident)
+        params = _vmem_for(((2 * (3 + group) * it + 4 * group) * d
+                            + (2 * (1 + group) * it + 4 * group) * lanes2)
+                           * s)
     else:
         by_q, kv = _by_query_head(group)
         second_dq = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
@@ -662,7 +735,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
             + 2 * 4 * b * h * s)
         dq, last, *dq2_kernel = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, causal=causal,
-                              sm_scale=sm_scale, block_k=bk),
+                              sm_scale=sm_scale, block_k=bk, window=window),
             out_shape=[like(qr),
                        jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
             + [like(x) for x in second[:1]],
@@ -674,7 +747,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
             out_specs=[_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
             + second_dq[:1],
             compiler_params=params,
-            name="flash_bwd_dq",
+            name="flash_win_bwd_dq" if window else "flash_bwd_dq",
             cost_estimate=cost,
             interpret=interp,
         )(qr, kr, vr, out, do, lse, *second)
@@ -704,7 +777,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
     grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=bq, also_dq=one_pass,
-                          split=bool(second)),
+                          split=bool(second), window=window),
         out_shape=out_shape,
         grid=(b, nkv, s // bk, group),
         in_specs=[_tile_spec(s, d, head), _tile_spec(bk, d, tile),
@@ -713,7 +786,7 @@ def _flash_bwd_vjp(causal, sm_scale, res, dout):
         out_specs=out_specs,
         scratch_shapes=scratch,
         compiler_params=params,
-        name="flash_bwd_dkv",
+        name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
         cost_estimate=cost,
         interpret=interp,
     )(qr, kr, vr, do, lse, last, *second)
@@ -760,4 +833,4 @@ def flash_attention_split(q, q2, k, k2, v, sm_scale: float):
             f"{k.shape}, k2 {k2.shape}, v {v.shape}")
     if _pick_blocks(s) is None:
         raise ValueError(f"flash_attention_split: no block divides S={s}")
-    return _flash_pallas(q, k, v, q2, k2, True, float(sm_scale))
+    return _flash_pallas(q, k, v, q2, k2, True, float(sm_scale), None)

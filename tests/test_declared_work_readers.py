@@ -175,17 +175,26 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
     bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
     new = {m["name"]: m for m in bench["per_layer"]
            if "declared" in m["name"]}
-    every = [DENSE, HYBRID, EXPERT]
+    # PR 44 appended its cell to the lists that apply to it, and one
+    # ratio of its own: the windowed kernels' declared work
+    window = "smallthinker-21b-a3b.pretrain-16k-moe"
+    every = [DENSE, HYBRID, EXPERT, window]
     assert {n: m["workloads"] for n, m in new.items()} == {
         "kernel_undeclared_pct.train": every,
         "flops_declared_per_needed.train": every,
         "flash_attn_declared_per_needed.train": every,
         "ssd_scan_bytes_declared_per_needed.train": [HYBRID],
-        "moe_experts_declared_per_needed.train": [EXPERT]}
+        "moe_experts_declared_per_needed.train": [EXPERT, window],
+        "flash_win_declared_per_needed.train": [window]}
     for m in new.values():
         assert (m["source"], m["moves"], m["better"], m["layer"]) == (
             "program_counter", "train_tok_s_chip", "lower", "kernels")
         assert os.path.exists(os.path.join(
             REPO, "benchmark", "layer_metrics", m["name"] + ".py"))
     # appended: the 17 entries that were there come first, unchanged
-    assert [m["name"] for m in bench["per_layer"][17:]] == list(new)
+    # (and PR 44's device-trace reader before its declared ratio)
+    assert [m["name"] for m in bench["per_layer"][17:]
+            if "declared" in m["name"]] == list(new)
+    assert [m["name"] for m in bench["per_layer"][22:]] == [
+        "flash_win_roofline_pct.train",
+        "flash_win_declared_per_needed.train"]

@@ -85,6 +85,7 @@ def two_kernels(fn):
 
 I32, I8 = jnp.int32, jnp.int8
 _causal = lambda q, k, v: flash_attention(q, k, v, causal=True)
+_window = lambda q, k, v: flash_attention(q, k, v, causal=True, window=512)
 _split = lambda q, q2, k, k2, v: flash_attention_split(q, q2, k, k2, v, 0.1)
 _varlen = lambda q, k, v, seg: flash_attention_segmented(q, k, v, seg,
                                                          causal=True)
@@ -144,6 +145,22 @@ CASES = [
     # steps: 32 * 2048 * (2 * 256 + 8), + 2 * 2048 * 4 * 128
     ("flash_bwd_dkv, two kernels", "flash_bwd_dkv", grad_of(_causal, 3),
      (Q8, KV1, KV1), 21_579_694_080, 36_175_872, 20_971_520),
+    # THE WINDOWED FORM, a window of one 512-block on the same row: a q
+    # block meets two k blocks at most, 1 + 2 + 2 + 2 = 7 pairs a head
+    # where 10 are causal — both ends' blocks run whole, masked; the
+    # whole-row operands are fetched as the dense form fetches them, so
+    # the bytes are its.  Forward, 2 heads: 14 * 512^2 * (4 * 128 + 4);
+    # exps 14 * 512 * 513 and 2 * 2048 logs
+    ("flash_win_fwd", "flash_win_fwd", _window, (Q2, KV1, KV1),
+     14 * 512 ** 2 * 516, 3_162_112, 14 * 512 * 513 + 4096),
+    # a group of 8 keeps the two kernels: dq 56 * 512^2 * (6 * 128 + 5)
+    # + delta 2 * 8 * 2048 * 128 ...
+    ("flash_win_bwd_dq", "flash_win_bwd_dq", grad_of(_window, 3),
+     (Q8, KV1, KV1), 56 * 512 ** 2 * 773 + 4_194_304, 17_956_864,
+     56 * 512 ** 2),
+    # ... and dkv 56 * 512^2 * (8 * 128 + 5)
+    ("flash_win_bwd_dkv", "flash_win_bwd_dkv", grad_of(_window, 3),
+     (Q8, KV1, KV1), 56 * 512 ** 2 * 1029, 36_175_872, 56 * 512 ** 2),
     # split scores, S 1,024: 2 heads x 3 pairs, a 64-deep product beside
     # the 128-deep one: 6 * 512^2 * (2 * (256 + 64) + 4); bytes 2 * 1024 *
     # (512 q o + 512 K V + 128 q2 + 64 k2 once) + 4 * 2 * 1024

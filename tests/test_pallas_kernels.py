@@ -185,6 +185,87 @@ def test_flash_backward_one_pass_parity(monkeypatch, causal, h, nkv, d, s):
         np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4, err_msg=name)
 
 
+def _masked_attention(q, k, v, window):
+    """Plain attention under the window's own rule, key j visible to
+    query i iff i - window < j <= i: a [s, s] mask, no kernel."""
+    s, d = q.shape[1], q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / d ** .5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    sc = jnp.where((j <= i) & (j > i - window), sc, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("windows,h,nkv,d,window,kernels", [
+    # the cell's group of 7 at head dim 128, blocks of 512, a window of
+    # two blocks: 7 * S * 128 * 4 B of dQ is past the budget — two kernels
+    (2, 7, 1, 128, 1024, 3), (3, 7, 1, 128, 1024, 3),
+    # four windows, a group of 2: the ONE-pass backward under a window
+    (4, 2, 1, 128, 1024, 2),
+    # the transposed entry, a window of ONE 64-block, GQA 4 / 2
+    (4, 4, 2, 64, 64, 2), (3, 4, 2, 64, 64, 2),
+])
+def test_flash_window_parity(windows, h, nkv, d, window, kernels):
+    """The windowed form — the dense kernels' bodies on the block pairs
+    a window leaves, under the names ``flash_win_*`` — against a plain
+    masked attention: the output and the three gradients, on rows of 2,
+    3 and 4 windows, the first and the last query block alike."""
+    fa = _flash_module()
+    s = windows * window
+    q, k, v, w = _flash_inputs(windows * 100 + h * 10 + d, 1, s, h, nkv, d)
+    block = fa._pick_blocks(s, window)[0]
+    assert block == min(512, window) and window % block == 0
+    flash = lambda q, k, v, causal: fa.flash_attention(q, k, v, causal,
+                                                       window=window)
+    plain = lambda q, k, v, causal: _masked_attention(q, k, v, window)
+    got, names = _flash_grads(flash, q, k, v, w, True)
+    assert names == ["flash_win_fwd"] + ["flash_win_bwd_dq"] * (
+        kernels == 3) + ["flash_win_bwd_dkv"]
+    want, _ = _flash_grads(plain, q, k, v, w, True)
+    out, ref = flash(q, k, v, True), plain(q, k, v, True)
+    for rows in (slice(0, block), slice(s - block, s), slice(None)):
+        np.testing.assert_allclose(out[:, rows], ref[:, rows], atol=2e-5,
+                                   rtol=2e-5)
+        for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a[:, rows], b_[:, rows], atol=2e-4,
+                                       rtol=2e-4, err_msg=name)
+    # and it is NOT the causal form's result: the window hides keys
+    dense = fa.flash_attention(q, k, v, True)
+    assert float(jnp.max(jnp.abs(dense[:, -block:] - ref[:, -block:]))) \
+        > 1e-2
+
+
+def test_flash_window_picks_its_form_by_the_shapes():
+    """A window the row does not outgrow hides nothing and is the dense
+    form, kernel names and all; one that fits no block falls to the
+    composite's mask; a window is causal."""
+    fa = _flash_module()
+    q, k, v, w = _flash_inputs(3, 1, 256, 2, 1, 128)
+    for window in (256, 4096):
+        got, names = _flash_grads(
+            lambda *a: fa.flash_attention(*a, window=window), q, k, v, w,
+            True)
+        assert names == ["flash_fwd", "flash_bwd_dkv"]
+        want, _ = _flash_grads(fa.flash_attention, q, k, v, w, True)
+        for a, b_ in zip(got, want):
+            assert bool(jnp.all(a == b_))
+    assert fa._pick_blocks(256, 63) is None
+    odd = fa.flash_attention(q, k, v, True, window=63)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *a: fa.flash_attention(*a, True, window=63))(q, k, v))
+    np.testing.assert_allclose(odd, _masked_attention(q, k, v, 63),
+                               atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError):
+        fa.flash_attention(q, k, v, False, window=64)
+    # the pairs a head's kernels execute: the cell's row, and a toy's
+    assert fa._pairs(16384, 512, True, 4096) == 252
+    assert fa._pairs(16384, 512, True) == 528
+    assert fa._pairs(2048, 512, True, 1024) == 1 + 2 + 3 + 3
+    assert fa._pairs(2048, 512, True, 2048) == fa._pairs(2048, 512, True)
+
+
 @pytest.mark.parametrize("form", ["dense", "split"])
 @pytest.mark.parametrize("fits", [True, False])
 def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits,

@@ -353,6 +353,97 @@ def test_flash_gqa_8k_head_dim_64():
             assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_16k_at_the_window_cell_s_shapes(window):
+    """The window cell's attention: ONE row of 16,384, 28 query / 4 KV
+    heads of 128 — the dense form (the global layers; 16 MiB of K and V
+    resident, the calls ask for their VMEM) and the windowed form (a
+    window of 4,096: eight blocks and the two masked ends), the
+    two-kernel backward in both.  The composite holds [heads, S, S], so
+    it is asked for two query heads of one KV head at a time."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention, _xla_sdpa)
+    s, h, nkv, d = 16384, 28, 4, 128
+    kk = jax.random.PRNGKey
+    q = jax.random.normal(kk(0), (1, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(kk(1), (1, s, nkv, d), jnp.bfloat16)
+    v = jax.random.normal(kk(2), (1, s, nkv, d), jnp.bfloat16)
+    w = jax.random.normal(kk(3), (1, s, h, d), jnp.float32)
+    out, vjp = jax.vjp(lambda *a: flash_attention(
+        *a, True, window=window).astype(jnp.float32), q, k, v)
+    dq, dk, dv = (g.astype(jnp.float32) for g in vjp(w))
+    g = h // nkv
+    for j, head in ((0, 0), (nkv - 1, h - 1)):
+        f32 = [x.astype(jnp.float32) for x in (
+            q[:, :, head:head + 1], k[:, :, j:j + 1], v[:, :, j:j + 1])]
+        want, ref_vjp = jax.vjp(
+            lambda *a: _xla_sdpa(*a, True, window), *f32)
+        dq_want = ref_vjp(w[:, :, head:head + 1])[0]
+        for got, ref in ((out[:, :, head:head + 1], want),
+                         (dq[:, :, head:head + 1], dq_want)):
+            err = float(jnp.abs(got - ref).max())
+            assert err < 6e-2 * max(1.0, float(jnp.abs(ref).max())), err
+    # dK and dV sum a group's seven heads: one KV head's whole group
+    for j in (0, nkv - 1):
+        heads = slice(j * g, (j + 1) * g)
+        dkv = [0.0, 0.0]
+        for head in range(j * g, (j + 1) * g):
+            f32 = [x.astype(jnp.float32) for x in (
+                q[:, :, head:head + 1], k[:, :, j:j + 1], v[:, :, j:j + 1])]
+            _, ref_vjp = jax.vjp(
+                lambda *a: _xla_sdpa(*a, True, window), *f32)
+            _, dk1, dv1 = ref_vjp(w[:, :, head:head + 1])
+            dkv = [dkv[0] + dk1, dkv[1] + dv1]
+        for got, ref in ((dk[:, :, j:j + 1], dkv[0]),
+                         (dv[:, :, j:j + 1], dkv[1])):
+            err = float(jnp.abs(got - ref).max())
+            assert err < 6e-2 * max(1.0, float(jnp.abs(ref).max())), err
+
+
+def test_routed_ffn_relu_at_the_window_cell_s_shapes():
+    """``routed_ffn`` under the ReLU gate at the window cell's widths
+    (2560, experts of 768, top-6 of 64, 16 held) on 4,096 tokens, against
+    the plain masked sum, value and the four gradients."""
+    from paddle_tpu.ops import moe
+    T, C, F, K, PUB, HELD = 4096, 2560, 768, 6, 64, 16
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    x = jax.random.normal(ks[0], (T, C), jnp.bfloat16)
+    wgu = jax.random.normal(ks[1], (HELD, C, 2 * F), jnp.float32) / C ** .5
+    wd = jax.random.normal(ks[2], (HELD, F, C), jnp.float32) / F ** .5
+    co = jax.random.normal(ks[3], (T, C), jnp.bfloat16)
+    idx, gate = moe.route(x, jax.random.normal(ks[4], (C, PUB)) / C ** .5,
+                          K, 1.0, "softmax_of_picks")
+    p = moe.plan(idx, 0, HELD, PUB)
+
+    def plain(x, gate, wgu, wd):
+        xf, y = x.astype(jnp.float32), jnp.zeros(x.shape, jnp.float32)
+        for e in range(HELD):
+            mine = jnp.sum(jnp.where(idx == e, gate, 0.0), -1)
+            hid = jax.nn.relu(xf @ wgu[e][:, :F]) * (xf @ wgu[e][:, F:])
+            y = y + mine[:, None] * (hid @ wd[e])
+        return y
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(plain, x, gate, wgu, wd)
+        wants = [want] + list(ref_vjp(co.astype(jnp.float32)))
+    got, vjp = jax.vjp(lambda *a: moe.routed_ffn(*a, p, "relu"), x, gate,
+                       wgu, wd)
+    # bf16 rows and hidden rows against float32: a gate unit whose
+    # product lies within a rounding of zero takes the other side of the
+    # ReLU, so single entries differ by a unit's whole term; the arrays
+    # agree in the norm.  Read on the chip (PR 44, call 2): norms y 0.46 %,
+    # dx 2.30 %, dgate 0.41 %, dwgu 2.28 %, dwd 0.37 %; the worst entry
+    # 23 % of the largest (dwgu) — the bounds leave those twice their room
+    errs = {}
+    for name, a, b in zip(("y", "dx", "dgate", "dwgu", "dwd"),
+                          [got] + list(vjp(co)), wants):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        errs[name] = (float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
+                      float(jnp.abs(a - b).max() / jnp.abs(b).max()))
+    print("routed_ffn relu, (norm, max) errors:", errs)
+    assert all(norm < 5e-2 for norm, _ in errs.values()), errs
+    assert all(worst < 0.5 for _, worst in errs.values()), errs
+
+
 def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
     """Latent attention as ``xing4.0-29b-a4b.pretrain-8k-moe`` runs it:
     2 x 8192, heads of 128 | 64 | 128, one shared rotated key — scores

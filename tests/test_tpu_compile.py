@@ -688,6 +688,73 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     assert ma.temp_size_in_bytes <= 17_408_805_376
 
 
+@pytest.mark.parametrize("window,kernels", [
+    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    (4096, ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"))])
+def test_flash_attention_16k_at_the_window_cell_s_shapes(one_chip, compiled,
+                                                         window, kernels):
+    """One row of 16,384 tokens, 28 query / 4 KV heads of 128: BOTH forms
+    compile for a described v5e.  A head's K and V (forward, dq) or q and
+    dO (dkv) are 16 MiB resident with the pipeline's two buffers, past
+    Mosaic's own limit, so the calls ask for what they hold (until PR 44
+    the dense forward stopped near 8k at d 128).  7 * 16384 * 128 * 4 B of
+    fp32 dQ is past ``ONE_PASS_DQ_BYTES``: the two-kernel backward."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q = _sds(one_chip, (1, 16384, 28, 128), jnp.bfloat16)
+    kv = _sds(one_chip, (1, 16384, 4, 128), jnp.bfloat16)
+    text = _text(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, True, window=window).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count(KERNEL) == 3
+    for kernel in kernels:
+        assert kernel in text, kernel
+    if window:
+        assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
+
+
+def test_train_step_of_the_window_cell(one_chip, compiled):
+    """The step of ``smallthinker-21b-a3b.pretrain-16k-moe`` as the
+    benchmark builds it — two periods of a global and three window
+    layers, every published width, 16 of 64 experts, ONE row of 16,384
+    tokens — fits a described v5e with NO compiler rematerialization at
+    depth 8 (the issue's first choice; 4 was its fallback), runs the
+    global layers on the dense kernels and the window layers on the
+    windowed form, the two-kernel backward in both, and ``flash_fwd`` /
+    ``flash_win_fwd`` once a layer: full remat keeps their outputs (8 x
+    119 MB = 954 MB, within ``FLASH_KEPT_BYTES``)."""
+    from benchmark import harness
+    from paddle_tpu.models.llama_pretrain import keeps_flash_outputs
+    cell = harness.find_cell("smallthinker-21b-a3b.pretrain-16k-moe")
+    assert cell.conf["num_hidden_layers"] == 8 and \
+        (cell.traffic["batch"], cell.traffic["seq"]) == (1, 16384)
+    assert keeps_flash_outputs(1, 16384, 28, 128, jnp.bfloat16, 8)
+    c = _cell_step(one_chip, cell.name)
+    text = c.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                   "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv",
+                   "grouped_mm", "grouped_mm_dw", "moe_sum_pairs", "rope"):
+        assert kernel in text, kernel
+    # four runs of layers (global, window x 3, global, window x 3), each a
+    # forward loop and a backward loop.  A layer forward: flash 1 + the
+    # routed path on each of its two bounds, 2 grouped products + the
+    # token side's sum; backward: the recompute's gate | up product on
+    # each bound (the routed path's backward reads that product alone;
+    # attention's outputs are kept), flash's two kernels, and the routed
+    # backward on each bound, 2 products + 2 dw + the sum; a window layer
+    # rotates q and k: 2 rope kernels forward, 2 recomputed, 2 backward
+    per_run = 1 + 2 * 3 + 2 * 1 + 2 + 2 * 5
+    assert text.count(KERNEL) == 4 * per_run + 2 * 6 == 96
+    assert len(re.findall(r" conditional\(", text)) == 4 * 3
+    for rows in (53248, 102400):
+        assert f"bf16[{rows},2560]" in text
+    assert ".remat" not in text
+    # no bf16 copy of an expert stack
+    assert not re.search(r"bf16\[(\d+,)?16,2560,1536\]", text)
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes == 4_484_826_624
+    assert ma.temp_size_in_bytes <= 13_100_000_000
+
+
 # sha256 of the dense cell's optimized step at depth 18 with the debug
 # locations out (op metadata, the kernels' serialized bodies, which
 # carry source paths, and the tables of files and frames): PR 35's — the

@@ -39,6 +39,9 @@ PREFILL = BLOCK | {"embed", "layer_scan", "varlen_attn"}
 # ``benchmark/models/xing_mhc_moe.KERNELS`` (ROADMAP D14)
 HC_KERNELS = ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd")
 AHEAD = ("moe_sum_pairs",) + HC_KERNELS
+# ... of which ``moe_sum_pairs`` is named by a family since PR 44
+# (``benchmark/models/smallthinker_moe.KERNELS``, a new file)
+AHEAD_OF_EVERY_FAMILY = HC_KERNELS
 
 
 def scope_names(lowered) -> set:
@@ -243,6 +246,69 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
     assert {xplane_meta.scope_of(p, names_of) for p in small} == {"hc_pre"}
     assert {xplane_meta.phase_of("jit(step)/" + p) for p in small} >= {
         "recompute"}
+
+
+def test_train_step_of_the_window_kinds_carries_the_family_s_scopes():
+    """``gqa_moe_global`` / ``gqa_moe_window``: the base vocabulary's
+    attention scopes (``rope`` in the window layers only) and the four
+    ``moe_*`` of the family ``smallthinker_moe`` — no ``moe_shared``, no
+    ``mlp`` —, the route and the plan issued BEFORE attention, the dense
+    kernels in the global layer and the windowed form under its own
+    names in the window layers, nothing of a block under no scope."""
+    from benchmark.models import smallthinker_moe
+    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
+               num_hidden_layers=4, num_attention_heads=2,
+               num_key_value_heads=1, head_dim=128, max_seq_len=256,
+               rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+               sliding_window_size=64, moe_primary_router_apply_softmax=True,
+               moe_intermediate_size=128, n_routed_experts=8,
+               experts_held=2, expert_first=2, num_experts_per_tok=3,
+               use_pallas_attention=True)
+    assert cfg.layer_types == ("gqa_moe_global",) + ("gqa_moe_window",) * 3
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = (BLOCK - {"mlp"}) | {"embed", "layer_scan", "attn", "loss_head",
+                                "optimizer"} | set(smallthinker_moe.SCOPES)
+    assert want <= names, want - names
+    assert not {"moe_shared", "mlp", "hc_pre", "mla_q"} & names
+    assert smallthinker_moe.SCOPES == smallthinker_moe.MOE_SCOPES == (
+        "moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    vocabulary = xplane_meta.KERNELS + smallthinker_moe.KERNELS
+    for scope, kernel in (("moe_experts", "grouped_mm"),
+                          ("moe_experts", "grouped_mm_dw"),
+                          ("moe_combine", "moe_sum_pairs"),
+                          ("moe_dispatch", "moe_sum_pairs"),
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dkv"),
+                          ("attn", "flash_win_fwd"),
+                          ("attn", "flash_win_bwd_dkv"), ("rope", "rope")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            vocabulary) == kernel
+    # a reader of the dense kernels does not take the windowed form's
+    # time, nor the other way about: the names are told apart whole
+    assert xplane_meta.kernel_of(
+        "jit(step)/block/attn/flash_win_fwd/pallas_call",
+        xplane_meta.KERNELS) == ""
+    # the rotation is the window layers' alone (three of the four)
+    rope = [p for p in paths if p.endswith("rope/rope/pallas_call")]
+    assert rope and all("block" in p for p in rope)
+    names_of = xplane_meta.SCOPES + smallthinker_moe.SCOPES
+    # every op of a block is charged to one of the names: the innermost
+    # known name is never the bare loop's
+    inside = [p for p in paths if "/block/" in p or p.startswith("block/")]
+    assert len(inside) > 200
+    assert {xplane_meta.scope_of(p, names_of) for p in inside} <= (
+        (BLOCK - {"mlp"}) | {"attn"} | set(smallthinker_moe.SCOPES))
 
 
 @pytest.mark.parametrize("on_load,on_all", [(6, 0), (4, 2), (0, 0)])
@@ -507,19 +573,28 @@ def pallas_call_names() -> list:
                     getattr(node.func, "attr", "") == "pallas_call":
                 kw = {k.arg: k.value for k in node.keywords}
                 assert "name" in kw, f"{path}:{node.lineno} has no name="
-                assert isinstance(kw["name"], ast.Constant)
+                # a constant, or a choice between two: a site that runs
+                # ANOTHER AMOUNT OF WORK under a static argument says so
+                # in its name (``flash_win_*``: the windowed form)
+                name = kw["name"]
+                either = [name.body, name.orelse] \
+                    if isinstance(name, ast.IfExp) else [name]
+                assert all(isinstance(n, ast.Constant) for n in either), \
+                    f"{path}:{node.lineno}"
                 assert "cost_estimate" in kw, \
-                    f"{path}:{node.lineno} ({kw['name'].value}) declares " \
+                    f"{path}:{node.lineno} ({either[0].value}) declares " \
                     f"no cost_estimate="
-                names.append(kw["name"].value)
+                names.extend(n.value for n in either)
     return names
 
 
 def test_every_pallas_call_site_carries_a_distinct_name():
     names = pallas_call_names()
     # ``flash_attention_split`` runs through the dense kernels' three
-    # call sites: a name is one site
-    assert len(names) == 24 and len(set(names)) == 24
+    # call sites: a name is one site — but for the windowed form, which
+    # runs the same three sites on fewer block pairs under names of its
+    # own (24 sites, 27 names)
+    assert len(names) == 27 and len(set(names)) == 27
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family
@@ -527,11 +602,14 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     # base vocabulary
     own = family_names()[1]
     assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
-                   "causal_conv_bwd", "grouped_mm", "grouped_mm_dw"}
+                   "causal_conv_bwd", "grouped_mm", "grouped_mm_dw",
+                   "moe_sum_pairs", "flash_win_fwd", "flash_win_bwd_dq",
+                   "flash_win_bwd_dkv"}
     assert not own & set(xplane_meta.KERNELS)
-    assert set(names) <= set(xplane_meta.KERNELS) | own | set(AHEAD)
-    assert own <= set(names) and set(AHEAD) <= set(names)
-    assert not set(AHEAD) & (own | set(xplane_meta.KERNELS))
+    ahead = set(AHEAD_OF_EVERY_FAMILY)
+    assert set(names) <= set(xplane_meta.KERNELS) | own | ahead
+    assert own <= set(names) and ahead <= set(names)
+    assert not ahead & (own | set(xplane_meta.KERNELS))
 
 
 def test_the_kernels_the_entered_cell_reads_have_a_call_site():
