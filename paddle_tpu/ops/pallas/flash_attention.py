@@ -15,23 +15,45 @@ group``, so no repeated K/V exists anywhere and a K/V block is fetched
 once a group; ``flash_bwd_dkv`` sums a group's query heads into the
 shared dK/dV block in fp32 (innermost grid axis) and casts once.
 
-The backward visits a (q block, k block) pair ONCE where it can
-(``ONE_PASS_DQ_BYTES``): ``flash_bwd_dkv`` holds dS^T for every pair it
-visits, so it adds dS K to the fp32 dQ of the KV head's whole group in
-VMEM scratch as well, forms delta = rowsum(dO*O) itself from ``o``, and
-returns (dk, dv, dq) — five block products and one exp pass a pair;
-``flash_bwd_dq`` does not run and no delta array exists.  The choice is
-a function of the shapes alone: where group*S*d*4 B of dQ is past the
-budget (long rows, wide groups) ``flash_bwd_dq`` (S, dP, dQ, and delta)
-runs first and ``flash_bwd_dkv`` does what it did — seven products and
-two exp passes a pair.  One kernel body either way.  The SPLIT form
-(:func:`flash_attention_split`) goes by the same rule: in one pass
-``flash_bwd_dkv`` sums dS k2 into a second fp32 scratch beside dQ's and
-returns (dk, dv, dk2, dq, dq2) — eight products, three of them d2 deep.
+The backward visits a (q block, k block) pair ONCE where it can, by a
+three-way rule of the shapes alone (``_flash_bwd_vjp``; no flag):
+
+(a) ``group*S*d*4 B <= ONE_PASS_DQ_BYTES`` (4 MiB) — KEY-major:
+    ``flash_bwd_dkv`` holds dS^T for every pair it visits, so it adds
+    dS K to the fp32 dQ of the KV head's whole group in VMEM scratch as
+    well, forms delta = rowsum(dO*O) itself from ``o``, and returns (dk,
+    dv, dq) — five block products and one exp pass a pair;
+    ``flash_bwd_dq`` does not run and no delta array exists.  The SPLIT
+    form (:func:`flash_attention_split`) goes by this rule too: in one
+    pass ``flash_bwd_dkv`` sums dS k2 into a second fp32 scratch beside
+    dQ's and returns (dk, dv, dk2, dq, dq2) — eight products, three of
+    them d2 deep.
+(b) else, dense and windowed forms, ``2*S*lanes(d)*4 B <=
+    ONE_PASS_DKV_BYTES`` (16 MiB; ``lanes(d)`` is d rounded up to 128) —
+    QUERY-major, on ``flash_bwd_dq``'s call site, grid and NAME: a q
+    block's dQ is whole inside one grid step (delta stays in the step),
+    and what waits across steps is the fp32 dK and dV of ONE KV head,
+    two ``[S, d]`` scratches whatever the group, zeroed at the KV
+    head's first step and cast into the whole-row ``dk`` / ``dv`` blocks
+    at its last.  The same five products and one exp pass a pair;
+    ``flash_bwd_dkv`` does not run.  VMEM, asked by the shapes: K and V,
+    the dk and dv blocks (each pair twice, the pipeline's buffers) and
+    the two sums — 48 MiB at S 16,384, d 128, + 8 for the tiles.
+(c) else the two kernels: ``flash_bwd_dq`` (S, dP, dQ, and delta) runs
+    first and ``flash_bwd_dkv`` forms dV and dK — seven products and two
+    exp passes a pair.  The split form past (a) stays here (no cell runs
+    it there).
+
+So ``flash_bwd_dkv`` names three amounts of work (two kernels, one pass,
+one pass split) and ``flash_bwd_dq`` two (the first of two kernels, the
+query-major pass) until a ``benchmark`` PR renames (ROADMAP D14): the
+readers sum a form's three names, and a kernel that does not run counts
+0.  One body a call site either way.
 
 The kernels work on ONE head's ``[rows, dim]`` tiles with that head's
-K/V (forward, dq) or Q/dO (dkv; in one pass also O, beside the group's
-dq block) resident in VMEM (seq*dim*2B <= ~1MB at seq 4k, d 128; a call
+K/V (forward, dq; in the query-major pass beside the KV head's dk and dv)
+or Q/dO (dkv; in one pass also O, beside the group's dq block) resident
+in VMEM (seq*dim*2B <= ~1MB at seq 4k, d 128; a call
 whose resident operands pass Mosaic's 16 MiB asks for its sum).  How a
 tile is ADDRESSED depends on ``dim`` alone (``_to_kernel``): with ``dim
 % 128 == 0`` the operands stay where the projections wrote them — ``[b,
@@ -186,13 +208,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
-                   causal: bool, sm_scale: float, block_k: int, window=None):
+                   causal: bool, sm_scale: float, block_k: int, window=None,
+                   group=None):
     # q/o/do/dq: [Bq, d]; k/v: [S, d]; lse_ref (in), delta_ref (out):
     # [1, Bq] — rows here are queries, so both turn once a grid step.
     # Split scores: q2_ref [Bq, d2], k2_ref [S, d2] before the outputs
-    # and dq2_ref [Bq, d2] after them
-    if len(refs) == 2:
-        second, (dq_ref, delta_ref) = (), refs
+    # and dq2_ref [Bq, d2] after them.
+    #
+    # ONE pass, query-major (``group`` given; dense and windowed forms):
+    # ``refs`` is dq, dk, dv then the fp32 scratch dk_acc, dv_acc, all
+    # four ``[S, d]`` of the KV head.  Every pair adds P^T dO and dS^T q
+    # at its key block's rows; the KV head's first grid step (first head
+    # of its group, q block 0) zeroes the sums and its last casts them
+    # into dk_ref / dv_ref, whose block index holds over those steps.
+    # delta stays in the step, and ``flash_bwd_dkv`` does not run
+    second = ()
+    if group is not None:
+        dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
+    elif len(refs) == 2:
+        dq_ref, delta_ref = refs
     else:
         *second, dq_ref, delta_ref, dq2_ref = refs
     qi = pl.program_id(2).astype(jnp.int32)
@@ -204,13 +238,45 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
     lse = lse_ref[:].T          # [Bq, 1]
     delta = jnp.sum(do.astype(jnp.float32) * o_ref[:].astype(jnp.float32),
                     axis=1, keepdims=True)
+    delta_t = delta.T           # [1, Bq]: as it leaves, or as one pass reads
+    n_k = jnp.int32(S // block_k)
+
+    def over_key_rows(fn):
+        def step(ki, _):
+            fn(pl.ds(ki * block_k, block_k))
+            return _
+        jax.lax.fori_loop(jnp.int32(0), n_k, step, None)
+
+    if group is not None:
+        head = jax.lax.rem(pl.program_id(1).astype(jnp.int32),
+                           jnp.int32(group))
+
+        @pl.when((head == 0) & (qi == 0))
+        def _start():
+            def zero(rows):
+                dk_acc[rows, :] = jnp.zeros((block_k, d), jnp.float32)
+                dv_acc[rows, :] = jnp.zeros((block_k, d), jnp.float32)
+            over_key_rows(zero)
 
     def body(ki, dqs, mask):
-        k = k_ref[pl.ds(ki * block_k, block_k), :]
-        v = v_ref[pl.ds(ki * block_k, block_k), :]
+        rows = pl.ds(ki * block_k, block_k)
+        k = k_ref[rows, :]
+        v = v_ref[rows, :]
+        if group is not None:
+            # keys on rows, as ``_bwd_dkv_kernel`` forms its scores: dV
+            # and dK are plain products, dQ the one with its left operand
+            # turned (PERF.md §6, PR 45: timed against q k^T)
+            st = _scores(k, q) * jnp.float32(sm_scale)      # [Bk, Bq]
+            st = _masked(st, mask, qi * Bq, ki * block_k, 1, window)
+            pt = jnp.exp(st - lse_ref[:])
+            dv_acc[rows, :] += _matmul(pt.astype(do.dtype), do)
+            dst = (pt * (_scores(v, do) - delta_t)
+                   * jnp.float32(sm_scale)).astype(q.dtype)
+            dk_acc[rows, :] += _matmul(dst, q)
+            return (dqs[0] + _matmul_t(dst, k),)
         s = _scores(q, k)
         if second:
-            k2 = second[1][pl.ds(ki * block_k, block_k), :]
+            k2 = second[1][rows, :]
             s = s + _scores(q2, k2)
         s = s * jnp.float32(sm_scale)
         s = _masked(s, mask, qi * Bq, ki * block_k, 0, window)
@@ -230,12 +296,20 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *refs,
         dq = _over_keys(qi, window and window // block_k, body, dq0)
     else:
         dq = jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(S // block_k),
-            lambda ki, c: body(ki, c, None), dq0)
+            jnp.int32(0), n_k, lambda ki, c: body(ki, c, None), dq0)
     dq_ref[:] = dq[0].astype(dq_ref.dtype)
     if second:
         dq2_ref[:] = dq[1].astype(dq2_ref.dtype)
-    delta_ref[:] = delta.T
+    if group is None:
+        delta_ref[:] = delta_t
+        return
+
+    @pl.when((head == group - 1) & (qi == pl.num_programs(2) - 1))
+    def _finish():
+        def cast(rows):
+            dk_ref[rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+            dv_ref[rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        over_key_rows(cast)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
@@ -392,6 +466,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
 # with S 8192 (PERF.md §6, PR 30).  The split form at S 8192, a group of
 # one, sits on it (PERF.md §6, PR 42).
 ONE_PASS_DQ_BYTES = 4 << 20
+# Past it, the dense and the windowed form still run ONE pass where the
+# fp32 dK and dV of ONE KV head, 2*S*lanes(d)*4 B whatever the group, are
+# at most this much VMEM scratch: the QUERY-major pass, under the name
+# ``flash_bwd_dq`` (its call site and grid), and ``flash_bwd_dkv`` does
+# not run.  The window cell sits on it (S 16,384, d 128; PERF.md §6, PR 45)
+ONE_PASS_DKV_BYTES = 16 << 20
 # what Mosaic gives a kernel unless the call asks for more (v5e: of 128 MiB)
 _DEFAULT_VMEM_LIMIT = 16 << 20
 # ``flash_fwd``'s two results under ``jax.ad_checkpoint.checkpoint_name``:
@@ -556,7 +636,10 @@ def _stat_spec(blocks, block, at):
 def _by_query_head(group):
     """Index maps of the (batch, query head, q block) grid that
     ``flash_fwd`` and ``flash_bwd_dq`` share: the block's own tile, and
-    the whole K/V of the head's group.  BOTH operands int32 before
+    the whole K/V of the head's group — its index holds over the group's
+    heads and their q blocks, consecutive grid steps, so the query-major
+    pass's dk / dv blocks stay in VMEM until the KV head is done and
+    leave once.  BOTH operands int32 before
     dividing: under jax_enable_x64 the grid indices trace as i64, and
     Mosaic's floor_divide lowering recurses on a scalar that is not
     int32."""
@@ -590,6 +673,11 @@ def _shared_spec(rows, d2, at):
     return pl.BlockSpec((None, rows, d2), index)
 
 
+def _lanes(d: int) -> int:
+    """``d`` rounded up to whole lane tiles: a row's width in VMEM."""
+    return -(-d // 128) * 128
+
+
 def _vmem_for(resident: int):
     """The compiler parameters of a call whose whole-row operands take
     ``resident`` bytes of VMEM, the pipeline's two buffers counted: None
@@ -608,7 +696,7 @@ def _row_vmem(s, d, itemsize, rows: int = 2):
     The dense form's stay within Mosaic's own limit up to S 8,192 at d
     128 and ask past it (S 16,384: 16 MiB of K and V, 24 asked).  The
     one-pass backward asks for its own sum (``_flash_bwd_vjp``)."""
-    return _vmem_for(2 * rows * s * -(-d // 128) * 128 * itemsize)
+    return _vmem_for(2 * rows * s * _lanes(d) * itemsize)
 
 
 def _flash_fwd(q, k, v, q2, k2, causal, sm_scale, window=None):
@@ -695,6 +783,10 @@ def _flash_bwd_vjp(causal, sm_scale, window, res, dout):
     # by the fp32 dQ of a KV head's group, dense and split form alike: the
     # split form's dQ2 follows from the shapes and is asked of VMEM below
     one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
+    # past it by the fp32 dK and dV of ONE KV head, query-major (the split
+    # form keeps the two kernels there: no cell runs it)
+    by_query = not one_pass and not second \
+        and 2 * s * _lanes(d) * 4 <= ONE_PASS_DKV_BYTES
     pairs = b * h * _pairs(s, bq, causal, window)
     it = qr.dtype.itemsize
     # on a pair's scores, either kernel: the scale, s - lse, dP - delta
@@ -714,43 +806,61 @@ def _flash_bwd_vjp(causal, sm_scale, window, res, dout):
         # beside the fp32 dQ2, each row of them a whole lane tile; the
         # rest read 4.8 MiB at most (AOT for a described v5e, S 4096 and
         # 8192)
-        lanes2 = -(-d2 // 128) * 128
         params = _vmem_for(((2 * (3 + group) * it + 4 * group) * d
-                            + (2 * (1 + group) * it + 4 * group) * lanes2)
+                            + (2 * (1 + group) * it + 4 * group) * _lanes(d2))
                            * s)
     else:
         by_q, kv = _by_query_head(group)
         second_dq = [_tile_spec(bq, d2, by_q), _shared_spec(s, d2, kv)] \
             if second else []
+        if by_query:
+            # dk, dv in delta's place: the KV head's whole rows, beside
+            # their fp32 sums; K, V and the two twice (the pipeline's two
+            # buffers)
+            dq_out = [like(kr), like(vr)]
+            dq_specs = [_tile_spec(s, d, kv), _tile_spec(s, d, kv)]
+            dq_scratch = [pltpu.VMEM((s, d), jnp.float32)] * 2
+            dq_params = _vmem_for((2 * 4 * it + 2 * 4) * s * _lanes(d))
+        else:
+            dq_out = [jax.ShapeDtypeStruct(lse.shape, jnp.float32)] \
+                + [like(x) for x in second[:1]]
+            dq_specs = [_stat_spec(None, bq, by_q)] + second_dq[:1]
+            dq_scratch, dq_params = [], params
         cost = pl.CostEstimate(
-            # a pair: q k^T (+ q2 k2^T), dO v^T, dS k (+ dS k2); delta a
-            # row once
-            flops=pairs * bq * bk * (2 * (3 * d + 2 * d2) + on_scores)
+            # a pair: q k^T (+ q2 k2^T), dO v^T, dS k (+ dS k2) — in one
+            # pass P^T dO and dS^T q too; delta a row once
+            flops=pairs * bq * bk * (2 * ((5 if by_query else 3) * d
+                                          + 2 * d2) + on_scores)
             + 2 * b * h * s * d,
             transcendentals=pairs * bq * bk,
             # q, o, dO (q2) in and dq (dq2) out a tile a grid step, K, V
-            # and k2 as the forward fetches them, lse in and delta out
+            # and k2 as the forward fetches them, lse in and delta out —
+            # or, in one pass, dk and dv out once a KV head
             bytes_accessed=it * b * s * (4 * h * d + 2 * nkv * d
-                                         + (2 * h + 1) * d2)
-            + 2 * 4 * b * h * s)
-        dq, last, *dq2_kernel = pl.pallas_call(
+                                         + (2 * h + 1) * d2
+                                         + (2 * nkv * d if by_query else 0))
+            + (1 if by_query else 2) * 4 * b * h * s)
+        dq, *rest = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, causal=causal,
-                              sm_scale=sm_scale, block_k=bk, window=window),
-            out_shape=[like(qr),
-                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
-            + [like(x) for x in second[:1]],
+                              sm_scale=sm_scale, block_k=bk, window=window,
+                              group=group if by_query else None),
+            out_shape=[like(qr)] + dq_out,
             grid=(b, h, s // bq),
             in_specs=[_tile_spec(bq, d, by_q), _tile_spec(s, d, kv),
                       _tile_spec(s, d, kv), _tile_spec(bq, d, by_q),
                       _tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
             + second_dq,
-            out_specs=[_tile_spec(bq, d, by_q), _stat_spec(None, bq, by_q)]
-            + second_dq[:1],
-            compiler_params=params,
+            out_specs=[_tile_spec(bq, d, by_q)] + dq_specs,
+            scratch_shapes=dq_scratch,
+            compiler_params=dq_params,
             name="flash_win_bwd_dq" if window else "flash_bwd_dq",
             cost_estimate=cost,
             interpret=interp,
         )(qr, kr, vr, out, do, lse, *second)
+        if by_query:
+            return (_from_kernel(dq, h), _from_kernel(rest[0], nkv),
+                    _from_kernel(rest[1], nkv), None, None)
+        last, *dq2_kernel = rest
         last_spec = _stat_spec(s // bq, bq, head)
 
     # a query head's whole-row operands turn with the INNERMOST axis:
@@ -822,7 +932,9 @@ def flash_attention_split(q, q2, k, k2, v, sm_scale: float):
     ``[.., d + d2]`` operand and no h-fold copy of k2 is made.  The
     backward returns five gradients, in ONE pass by the dense form's
     rule of shapes (``ONE_PASS_DQ_BYTES``: ``flash_bwd_dkv`` sums dQ and
-    dQ2 too), from the two kernels past it; a head's part of dk2 leaves
+    dQ2 too), from the two kernels past it (the query-major pass is the
+    dense and the windowed form's: no cell runs a split row past that
+    budget); a head's part of dk2 leaves
     the kernel in fp32 and the heads are summed outside it.  Same kernel
     bodies, names and blocks as :func:`flash_attention`."""
     b, s, h, d = q.shape

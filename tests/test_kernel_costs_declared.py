@@ -71,14 +71,15 @@ def grad_of(fn, n=1):
 
 
 def two_kernels(fn):
-    """``fn`` traced with the one-pass budget at 0 bytes (the module
-    constant, as the parity tests set it): the backward a row past the
-    budget runs."""
+    """``fn`` traced with both one-pass budgets at 0 bytes (the module
+    constants, as the parity tests set them): the backward a row past
+    both budgets runs."""
     def run(*args):
         with pytest.MonkeyPatch.context() as patch:
-            patch.setattr(importlib.import_module(
-                "paddle_tpu.ops.pallas.flash_attention"),
-                "ONE_PASS_DQ_BYTES", 0)
+            flash = importlib.import_module(
+                "paddle_tpu.ops.pallas.flash_attention")
+            patch.setattr(flash, "ONE_PASS_DQ_BYTES", 0)
+            patch.setattr(flash, "ONE_PASS_DKV_BYTES", 0)
             return fn(*args)
     return run
 
@@ -135,15 +136,26 @@ CASES = [
     # 256 dq)
     ("flash_bwd_dkv, one pass", "flash_bwd_dkv", grad_of(_causal, 3),
      (Q2, KV1, KV1), 6_738_149_376, 15_794_176, 5_242_880),
-    # a group of 8 (8 MiB of dQ) keeps the two kernels; dq: three products
-    # a pair, 80 * 512^2 * (6 * 128 + 5) + delta 2 * 8 * 2048 * 128; bytes
-    # 2 * 2048 * (4 * 1024 q o dO dq + 256 K V) + 2 * 4 * 8 * 2048
-    ("flash_bwd_dq", "flash_bwd_dq", grad_of(_causal, 3), (Q8, KV1, KV1),
-     16_215_179_264, 17_956_864, 20_971_520),
+    # a group of 8 holds 8 MiB of dQ, past the key-major budget, and 2 MiB
+    # of fp32 dK and dV a KV head: ONE pass, QUERY-major, under
+    # ``flash_bwd_dq``'s name — five products a pair, 80 * 512^2 * (10 *
+    # 128 + 5) + delta 2 * 8 * 2048 * 128; K and V once the KV head, dk
+    # and dv out once: 2 * 2048 * (4 * 1024 q o dO dq + 256 K V + 256 dk
+    # dv) + lse 4 * 8 * 2048
+    ("flash_bwd_dq, one pass", "flash_bwd_dq", grad_of(_causal, 3),
+     (Q8, KV1, KV1), 80 * 512 ** 2 * 1285 + 4_194_304,
+     2 * 2048 * (4096 + 512) + 65_536, 20_971_520),
+    # past both budgets (here: both at 0 bytes) the two kernels; dq: three
+    # products a pair, 80 * 512^2 * (6 * 128 + 5) + delta 2 * 8 * 2048 *
+    # 128; bytes 2 * 2048 * (4 * 1024 q o dO dq + 256 K V) + 2 * 4 * 8 *
+    # 2048
+    ("flash_bwd_dq", "flash_bwd_dq", two_kernels(grad_of(_causal, 3)),
+     (Q8, KV1, KV1), 16_215_179_264, 17_956_864, 20_971_520),
     # ... and dkv FOUR products a pair, not the one pass's five: 80 * 512^2
     # * (8 * 128 + 5); a head's q, dO, lse, delta at each of 4 * 8 grid
     # steps: 32 * 2048 * (2 * 256 + 8), + 2 * 2048 * 4 * 128
-    ("flash_bwd_dkv, two kernels", "flash_bwd_dkv", grad_of(_causal, 3),
+    ("flash_bwd_dkv, two kernels", "flash_bwd_dkv",
+     two_kernels(grad_of(_causal, 3)),
      (Q8, KV1, KV1), 21_579_694_080, 36_175_872, 20_971_520),
     # THE WINDOWED FORM, a window of one 512-block on the same row: a q
     # block meets two k blocks at most, 1 + 2 + 2 + 2 = 7 pairs a head
@@ -153,13 +165,20 @@ CASES = [
     # exps 14 * 512 * 513 and 2 * 2048 logs
     ("flash_win_fwd", "flash_win_fwd", _window, (Q2, KV1, KV1),
      14 * 512 ** 2 * 516, 3_162_112, 14 * 512 * 513 + 4096),
-    # a group of 8 keeps the two kernels: dq 56 * 512^2 * (6 * 128 + 5)
+    # a group of 8: the query-major one pass on the window's 56 pairs, 56
+    # * 512^2 * (10 * 128 + 5) + delta; the dense form's bytes
+    ("flash_win_bwd_dq, one pass", "flash_win_bwd_dq", grad_of(_window, 3),
+     (Q8, KV1, KV1), 56 * 512 ** 2 * 1285 + 4_194_304,
+     2 * 2048 * (4096 + 512) + 65_536, 56 * 512 ** 2),
+    # past both budgets the two kernels: dq 56 * 512^2 * (6 * 128 + 5)
     # + delta 2 * 8 * 2048 * 128 ...
-    ("flash_win_bwd_dq", "flash_win_bwd_dq", grad_of(_window, 3),
+    ("flash_win_bwd_dq", "flash_win_bwd_dq",
+     two_kernels(grad_of(_window, 3)),
      (Q8, KV1, KV1), 56 * 512 ** 2 * 773 + 4_194_304, 17_956_864,
      56 * 512 ** 2),
     # ... and dkv 56 * 512^2 * (8 * 128 + 5)
-    ("flash_win_bwd_dkv", "flash_win_bwd_dkv", grad_of(_window, 3),
+    ("flash_win_bwd_dkv", "flash_win_bwd_dkv",
+     two_kernels(grad_of(_window, 3)),
      (Q8, KV1, KV1), 56 * 512 ** 2 * 1029, 36_175_872, 56 * 512 ** 2),
     # split scores, S 1,024: 2 heads x 3 pairs, a 64-deep product beside
     # the 128-deep one: 6 * 512^2 * (2 * (256 + 64) + 4); bytes 2 * 1024 *
@@ -312,8 +331,9 @@ def test_every_kernel_name_has_a_case():
 
 
 def test_one_pass_and_two_kernels_declare_different_work():
-    """``flash_bwd_dkv`` is one name for three amounts of work (ROADMAP
-    D14); the declared FLOPs of a pair tell them apart in a trace."""
+    """``flash_bwd_dkv`` is one name for three amounts of work and
+    ``flash_bwd_dq`` for two (ROADMAP D14); the declared FLOPs of a pair
+    tell them apart in a trace."""
     by = {c[0]: c for c in CASES}
 
     def per_score(case, heads, pairs, s):
@@ -325,6 +345,17 @@ def test_one_pass_and_two_kernels_declare_different_work():
         2 * 5 * 128 + 5
     assert per_score("flash_bwd_dkv, two kernels", 8, 10, 2048) == \
         2 * 4 * 128 + 5
+    # ``flash_bwd_dq`` (and ``flash_win_bwd_dq``) is one name for TWO
+    # amounts of work since PR 45: the query-major one pass, five products
+    # a pair, against the first of two kernels, three; K and V cross HBM
+    # once a KV head either way, and the one pass writes dk and dv once
+    for name, pairs in (("flash_bwd_dq", 10), ("flash_win_bwd_dq", 7)):
+        assert per_score(f"{name}, one pass", 8, pairs, 2048) == \
+            2 * 5 * 128 + 5
+        assert (by[name][4] - 2 * 8 * 2048 * 128) \
+            // (8 * pairs * 512 * 512) == 2 * 3 * 128 + 5
+        assert by[f"{name}, one pass"][5] - by[name][5] == \
+            2 * 2048 * 2 * 128 - 4 * 8 * 2048      # + dk, dv; - delta
     # the split form: 1,669 a score in one pass against 1,285 (and
     # ``flash_bwd_dq``'s 1,029 beside them)
     assert per_score("flash_bwd_dkv, split one pass", 2, 3, 1024) == \

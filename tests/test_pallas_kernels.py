@@ -155,6 +155,13 @@ def _flash_grads(fn, q, k, v, w, causal):
     return _grads_and_kernels(lambda *a: (fn(*a, causal) * w).sum(), q, k, v)
 
 
+def _two_kernels(monkeypatch, fa):
+    """Both one-pass budgets at 0 bytes (the module constants, no flag):
+    ``flash_bwd_dq`` then ``flash_bwd_dkv``, whatever the shapes."""
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    monkeypatch.setattr(fa, "ONE_PASS_DKV_BYTES", 0)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("h,nkv,d,s", [
     (2, 2, 128, 512),       # group 1, flat, ONE 512-block
@@ -168,13 +175,13 @@ def test_flash_backward_one_pass_parity(monkeypatch, causal, h, nkv, d, s):
     """The backward in one pass — ``flash_bwd_dkv`` sums dQ too and forms
     delta from ``o``; ``flash_bwd_dq`` does not run — against autodiff of
     the XLA attention AND against the two kernels on the same inputs
-    (the budget set to 0 bytes: the module constant, no flag)."""
+    (the budgets set to 0 bytes: the module constants, no flag)."""
     fa = _flash_module()
     args = _flash_inputs(h * 1000 + nkv * 100 + d + s, 1, s, h, nkv, d)
     assert (h // nkv) * s * d * 4 <= fa.ONE_PASS_DQ_BYTES
     one, kernels = _flash_grads(fa.flash_attention, *args, causal)
     assert kernels == ["flash_fwd", "flash_bwd_dkv"]
-    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    _two_kernels(monkeypatch, fa)
     two, kernels = _flash_grads(fa.flash_attention, *args, causal)
     assert kernels == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     want, _ = _flash_grads(fa._xla_sdpa, *args, causal)
@@ -183,6 +190,76 @@ def test_flash_backward_one_pass_parity(monkeypatch, causal, h, nkv, d, s):
         # a re-ordered fp32 sum at most
         np.testing.assert_allclose(a, b_, atol=2e-5, rtol=2e-5, err_msg=name)
         np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("d", [128, 64])       # in place | transposed
+@pytest.mark.parametrize("group", [1, 4, 7])
+@pytest.mark.parametrize("form", ["causal", "window", "full"])
+def test_flash_backward_by_query_parity(monkeypatch, form, group, d):
+    """The QUERY-major one pass — ``flash_bwd_dq``'s site and grid also
+    sums dK and dV of the KV head in two fp32 ``[S, d]`` scratches;
+    ``flash_bwd_dkv`` does not run — on a row of five 64-blocks, two KV
+    heads (the sums start again at the second), a window of two blocks
+    (its edge in one): dq, dk, dv against the two kernels on the same
+    inputs and against autodiff of the XLA attention."""
+    fa = _flash_module()
+    s, nkv = 320, 2
+    causal, window = form != "full", 128 if form == "window" else None
+    args = _flash_inputs(group * 100 + d, 1, s, group * nkv, nkv, d)
+    assert fa._pick_blocks(s, window) == (64, 64)
+    flash = lambda q, k, v, c: fa.flash_attention(q, k, v, c, window=window)
+    plain = lambda q, k, v, c: fa._xla_sdpa(q, k, v, c, window)
+    names = tuple(("flash_win_" if window else "flash_") + x
+                  for x in ("fwd", "bwd_dq", "bwd_dkv"))
+    # past rule (a) at any size; 2 * 320 * 128 * 4 B is within rule (b)
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    one, kernels = _flash_grads(flash, *args, causal)
+    assert tuple(kernels) == names[:2]
+    monkeypatch.setattr(fa, "ONE_PASS_DKV_BYTES", 0)
+    two, kernels = _flash_grads(flash, *args, causal)
+    assert tuple(kernels) == names
+    want, _ = _flash_grads(plain, *args, causal)
+    for name, a, b_, c in zip(("dq", "dk", "dv"), one, two, want):
+        assert a.shape == c.shape, name
+        # a re-ordered fp32 sum at most
+        np.testing.assert_allclose(a, b_, atol=2e-5, rtol=2e-5, err_msg=name)
+        np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("case,shape,kernels", [
+    # (a) the dense cell: 2 MiB of fp32 dQ a group — key-major, as ever
+    ("a", (8, 2048, 16, 8, 128, None), ["flash_fwd", "flash_bwd_dkv"]),
+    # (b) the window cell's two forms: 58.7 MB of dQ, 16 MiB of dK and dV
+    ("b", (1, 16384, 28, 4, 128, None), ["flash_fwd", "flash_bwd_dq"]),
+    ("b, window", (1, 16384, 28, 4, 128, 4096),
+     ["flash_win_fwd", "flash_win_bwd_dq"]),
+    # (b) the hybrid cell: 8 MiB of dQ; a 64-wide row fills a lane tile:
+    # 8 MiB of dK and dV
+    ("b, d 64", (2, 8192, 32, 8, 64, None), ["flash_fwd", "flash_bwd_dq"]),
+    # (c) a row of 32,768: 32 MiB of dK and dV — the two kernels
+    ("c", (1, 32768, 28, 4, 128, None),
+     ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+    ("c, window", (1, 32768, 28, 4, 128, 4096),
+     ["flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"]),
+])
+def test_flash_backward_form_follows_the_shapes(case, shape, kernels):
+    """The three-way rule of ``_flash_bwd_vjp`` at the cells' own
+    shapes, by the kernels' names in the traced program (nothing runs):
+    the module's constants as they are, no flag."""
+    fa = _flash_module()
+    b, s, h, nkv, d, window = shape
+    q, kv = (jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
+             for n in (h, nkv))
+    group = h // nkv
+    lanes = -(-d // 128) * 128
+    assert case[0] == ("a" if group * s * d * 4 <= fa.ONE_PASS_DQ_BYTES
+                       else "b" if 2 * s * lanes * 4 <= fa.ONE_PASS_DKV_BYTES
+                       else "c")
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, True, window=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, kv, kv))
+    assert re.findall(r"(?<!name\[)\bname=(flash_\w+)", text) == kernels
 
 
 def _masked_attention(q, k, v, window):
@@ -198,21 +275,26 @@ def _masked_attention(q, k, v, window):
                       precision="highest")
 
 
-@pytest.mark.parametrize("windows,h,nkv,d,window,kernels", [
+@pytest.mark.parametrize("windows,h,nkv,d,window,backward", [
     # the cell's group of 7 at head dim 128, blocks of 512, a window of
-    # two blocks: 7 * S * 128 * 4 B of dQ is past the budget — two kernels
-    (2, 7, 1, 128, 1024, 3), (3, 7, 1, 128, 1024, 3),
-    # four windows, a group of 2: the ONE-pass backward under a window
-    (4, 2, 1, 128, 1024, 2),
+    # two blocks: 7 * S * 128 * 4 B of dQ is past the key-major budget —
+    # the QUERY-major one pass, and (both budgets at 0) the two kernels
+    (2, 7, 1, 128, 1024, ["dq"]), (3, 7, 1, 128, 1024, ["dq"]),
+    (3, 7, 1, 128, 1024, ["dq", "dkv"]),
+    # four windows, a group of 2: the key-major one pass under a window
+    (4, 2, 1, 128, 1024, ["dkv"]),
     # the transposed entry, a window of ONE 64-block, GQA 4 / 2
-    (4, 4, 2, 64, 64, 2), (3, 4, 2, 64, 64, 2),
+    (4, 4, 2, 64, 64, ["dkv"]), (3, 4, 2, 64, 64, ["dkv"]),
 ])
-def test_flash_window_parity(windows, h, nkv, d, window, kernels):
+def test_flash_window_parity(monkeypatch, windows, h, nkv, d, window,
+                             backward):
     """The windowed form — the dense kernels' bodies on the block pairs
     a window leaves, under the names ``flash_win_*`` — against a plain
     masked attention: the output and the three gradients, on rows of 2,
     3 and 4 windows, the first and the last query block alike."""
     fa = _flash_module()
+    if len(backward) == 2:
+        _two_kernels(monkeypatch, fa)
     s = windows * window
     q, k, v, w = _flash_inputs(windows * 100 + h * 10 + d, 1, s, h, nkv, d)
     block = fa._pick_blocks(s, window)[0]
@@ -221,8 +303,8 @@ def test_flash_window_parity(windows, h, nkv, d, window, kernels):
                                                        window=window)
     plain = lambda q, k, v, causal: _masked_attention(q, k, v, window)
     got, names = _flash_grads(flash, q, k, v, w, True)
-    assert names == ["flash_win_fwd"] + ["flash_win_bwd_dq"] * (
-        kernels == 3) + ["flash_win_bwd_dkv"]
+    assert names == ["flash_win_fwd"] + ["flash_win_bwd_" + x
+                                         for x in backward]
     want, _ = _flash_grads(plain, q, k, v, w, True)
     out, ref = flash(q, k, v, True), plain(q, k, v, True)
     for rows in (slice(0, block), slice(s - block, s), slice(None)):
@@ -267,13 +349,16 @@ def test_flash_window_picks_its_form_by_the_shapes():
 
 
 @pytest.mark.parametrize("form", ["dense", "split"])
-@pytest.mark.parametrize("fits", [True, False])
+@pytest.mark.parametrize("fits", ["dq", "dkv", "neither"])
 def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits,
                                                          form):
-    """One pass where a group's fp32 dQ, group*S*d*4 B, is within
-    ``ONE_PASS_DQ_BYTES``; a byte past it the two kernels run, with the
-    same gradients.  The split form goes by the SAME rule (its dQ2 is
-    no part of the budget)."""
+    """Key-major in one pass where a group's fp32 dQ, group*S*d*4 B, is
+    within ``ONE_PASS_DQ_BYTES``; a byte past it the dense form goes
+    query-major in one pass where a KV head's fp32 dK and dV,
+    2*S*lanes(d)*4 B, are within ``ONE_PASS_DKV_BYTES``, and a byte past
+    that the two kernels run — with the same gradients.  The split form
+    goes by the FIRST rule alone (its dQ2 is no part of the budget) and
+    keeps the two kernels past it."""
     fa = _flash_module()
     if form == "dense":
         h, nkv, d, s = 4, 2, 128, 256
@@ -285,11 +370,16 @@ def test_flash_backward_pass_count_follows_the_vmem_rule(monkeypatch, fits,
         *args, co = _split_inputs(2, s, h, d, 64)
         run = lambda fn: _split_grads(fn, *args, co, 0.137)
         flash, plain = fa.flash_attention_split, _concatenated_attention
-    need = (h // nkv) * s * d * 4
-    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", need if fits else need - 1)
+    need, need_dkv = (h // nkv) * s * d * 4, 2 * s * d * 4
+    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES",
+                        need if fits == "dq" else need - 1)
+    monkeypatch.setattr(fa, "ONE_PASS_DKV_BYTES",
+                        need_dkv if fits == "dkv" else need_dkv - 1)
     got, kernels = run(flash)
-    assert kernels == ["flash_fwd"] + ["flash_bwd_dq"] * (not fits) \
-        + ["flash_bwd_dkv"]
+    assert kernels == ["flash_fwd"] + {
+        "dq": ["flash_bwd_dkv"],
+        "dkv": ["flash_bwd_dq"] + ["flash_bwd_dkv"] * (form == "split"),
+        "neither": ["flash_bwd_dq", "flash_bwd_dkv"]}[fits]
     want, _ = run(plain)
     for a, b_ in zip(got, want):
         np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)

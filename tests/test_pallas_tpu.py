@@ -89,12 +89,18 @@ def test_flash_gqa_fwd_bwd_parity(s, h, nkv, d):
         assert err < 6e-2 * max(1.0, float(jnp.abs(want).max())), err
 
 
-@pytest.mark.parametrize("s,h,nkv,d", [(2048, 16, 8, 128), (576, 4, 2, 64)])
+@pytest.mark.parametrize("s,h,nkv,d", [
+    (2048, 16, 8, 128), (576, 4, 2, 64),
+    # past ``ONE_PASS_DQ_BYTES``: the QUERY-major pass, a group of 8 at
+    # head dim 128 (in place) and the hybrid cell's 32 / 8 x 64
+    (4096, 16, 2, 128), (8192, 32, 8, 64)])
 def test_flash_backward_one_pass_matches_two_kernels(monkeypatch, s, h, nkv,
                                                      d):
-    """The one-pass backward against ``flash_bwd_dq`` + ``flash_bwd_dkv``
-    (the VMEM rule set to 0 bytes) on the same bf16 inputs: dk and dv are
-    the same sums, dq the same terms from a product turned round."""
+    """The one-pass backward — key-major within ``ONE_PASS_DQ_BYTES``,
+    query-major within ``ONE_PASS_DKV_BYTES`` — against ``flash_bwd_dq``
+    + ``flash_bwd_dkv`` (both rules set to 0 bytes) on the same bf16
+    inputs: dk and dv are the same sums, dq the same terms from a product
+    turned round."""
     import importlib
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     kk = jax.random.PRNGKey
@@ -109,6 +115,7 @@ def test_flash_backward_one_pass_matches_two_kernels(monkeypatch, s, h, nkv,
 
     one = grads()
     monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    monkeypatch.setattr(fa, "ONE_PASS_DKV_BYTES", 0)
     for got, want in zip(one, grads()):
         err = float(jnp.abs(got - want).max())
         assert err <= 2 ** -7 * float(jnp.abs(want).max()), err
@@ -322,23 +329,28 @@ def test_paged_decode_attention_q8_compiled():
     assert err < 3e-2, err
 
 
-def test_flash_gqa_8k_head_dim_64():
-    """The hybrid cell's attention layer: S 8192, 32 query / 8 KV heads
-    of 64 — the transposed entry, and ``group * S * d * 4`` = 8 MiB of
-    fp32 dQ, past ``ONE_PASS_DQ_BYTES``: the two-kernel backward.  The
-    composite holds [heads, S, S], so it is asked for one KV head's
-    group at a time (a group's gradients depend on no other)."""
+@pytest.mark.parametrize("b", [1, 2])
+def test_flash_gqa_8k_head_dim_64(b):
+    """The hybrid cell's attention layer (``b`` 2 is the cell's step):
+    S 8192, 32 query / 8 KV heads of 64 — the transposed entry, and
+    ``group * S * d * 4`` = 8 MiB of fp32 dQ, past ``ONE_PASS_DQ_BYTES``,
+    with 8 MiB of fp32 dK and dV a KV head (a 64-wide row fills a lane
+    tile) within ``ONE_PASS_DKV_BYTES``: the query-major one-pass
+    backward.  The composite holds [heads, S, S], so it is asked for one
+    KV head's group of the last row at a time (a group's gradients
+    depend on no other)."""
     from paddle_tpu.ops.pallas.flash_attention import (
         flash_attention, _xla_sdpa)
     s, h, nkv, d = 8192, 32, 8, 64
     kk = jax.random.PRNGKey
-    q = jax.random.normal(kk(0), (1, s, h, d), jnp.bfloat16)
-    k = jax.random.normal(kk(1), (1, s, nkv, d), jnp.bfloat16)
-    v = jax.random.normal(kk(2), (1, s, nkv, d), jnp.bfloat16)
-    w = jax.random.normal(kk(3), (1, s, h, d), jnp.float32)
+    q = jax.random.normal(kk(0), (b, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(kk(1), (b, s, nkv, d), jnp.bfloat16)
+    v = jax.random.normal(kk(2), (b, s, nkv, d), jnp.bfloat16)
+    w = jax.random.normal(kk(3), (b, s, h, d), jnp.float32)
     out, vjp = jax.vjp(lambda *a: flash_attention(*a, True).astype(
         jnp.float32), q, k, v)
-    dq, dk, dv = (g.astype(jnp.float32) for g in vjp(w))
+    dq, dk, dv = (g.astype(jnp.float32)[-1:] for g in vjp(w))
+    out, q, k, v, w = (x[-1:] for x in (out, q, k, v, w))
     g = h // nkv
     for j in (0, nkv - 1):
         heads = slice(j * g, (j + 1) * g)
@@ -359,8 +371,10 @@ def test_flash_16k_at_the_window_cell_s_shapes(window):
     heads of 128 — the dense form (the global layers; 16 MiB of K and V
     resident, the calls ask for their VMEM) and the windowed form (a
     window of 4,096: eight blocks and the two masked ends), the
-    two-kernel backward in both.  The composite holds [heads, S, S], so
-    it is asked for two query heads of one KV head at a time."""
+    query-major one-pass backward in both (58.7 MB of fp32 dQ a group is
+    past ``ONE_PASS_DQ_BYTES``; 16 MiB of fp32 dK and dV a KV head is
+    ``ONE_PASS_DKV_BYTES``).  The composite holds [heads, S, S], so it is
+    asked for one query head of one KV head at a time."""
     from paddle_tpu.ops.pallas.flash_attention import (
         flash_attention, _xla_sdpa)
     s, h, nkv, d = 16384, 28, 4, 128

@@ -120,19 +120,33 @@ def _flash_module():
     return importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
-@pytest.mark.parametrize("s,nkv,kernels", [
-    (2048, HEADS, 2),       # MHA: the backward in one pass
-    (4096, 8, 2),           # 4 MiB of fp32 dQ a group: asks for more VMEM
-    (8192, 8, 3)])          # 8 MiB: past the rule, flash_bwd_dq runs
-def test_flash_attention_fwd_bwd(one_chip, compiled, s, nkv, kernels):
+def _two_kernels(monkeypatch):
+    """Both one-pass budgets at 0 bytes (the module's constants): the
+    two-kernel backward at any shape."""
+    monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
+    monkeypatch.setattr(_flash_module(), "ONE_PASS_DKV_BYTES", 0)
+
+
+@pytest.mark.parametrize("s,nkv,backward", [
+    (2048, HEADS, ["dkv"]),     # MHA: the backward in one pass, key-major
+    (4096, 8, ["dkv"]),         # 4 MiB of fp32 dQ a group: asks for more VMEM
+    # 8 MiB: past that rule; 8 MiB of fp32 dK and dV a KV head are within
+    # the second: ONE pass, query-major, under flash_bwd_dq's name
+    (8192, 8, ["dq"]),
+    (8192, 8, ["dq", "dkv"])])  # both rules at 0 bytes: the two kernels
+def test_flash_attention_fwd_bwd(one_chip, compiled, monkeypatch, s, nkv,
+                                 backward):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    if len(backward) == 2:
+        _two_kernels(monkeypatch)
     q = _sds(one_chip, (2, s, HEADS, HEAD_DIM), jnp.bfloat16)
     kv = _sds(one_chip, (2, s, nkv, HEAD_DIM), jnp.bfloat16)
     text = _text(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True).astype(
             jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count(KERNEL) == kernels
-    assert ("flash_bwd_dq" in text) == (kernels == 3)
+    assert text.count(KERNEL) == 1 + len(backward)
+    for name in ("dq", "dkv"):
+        assert (f"flash_bwd_{name}" in text) == (name in backward)
 
 
 @pytest.mark.parametrize("kernels", [2, 3])
@@ -142,13 +156,13 @@ def test_flash_attention_small_blocks(one_chip, compiled, monkeypatch, s, d,
                                       kernels):
     """Lengths whose largest dividing block is under 128 (64, 64, 64, 8)
     stay on the kernels, GQA 4/2, causal and not, the backward in one
-    pass (2 kernels) or, the VMEM rule set to 0 bytes, in two (3): the
+    pass (2 kernels) or, the VMEM rules set to 0 bytes, in two (3): the
     statistics are ``[b, h, s/block, 1, block]``, a block taken by its
     index on an untiled axis, so Mosaic is never asked to prove a lane
     offset of 64 aligned."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     if kernels == 3:
-        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
+        _two_kernels(monkeypatch)
     q = _sds(one_chip, (2, s, 4, d), jnp.bfloat16)
     kv = _sds(one_chip, (2, s, 2, d), jnp.bfloat16)
     for causal in (True, False):
@@ -179,14 +193,14 @@ def test_flash_attention_gqa_reads_the_projections_where_they_lie(
     through rope and flash attention, forward and backward.  The flash
     kernels — ``flash_fwd`` and the one-pass ``flash_bwd_dkv``, with
     ``flash_bwd_dq`` ABSENT (2 MiB of fp32 dQ a group fits); present
-    with the VMEM rule set to 0 bytes — and rope's, and NOTHING that
+    with the VMEM rules set to 0 bytes — and rope's, and NOTHING that
     moves a K/V-sized array between them: no transpose, no relayout
     copy or reshape, no GQA broadcast (the kernels take ``head //
     group``)."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.rope import fused_rope, rope_tables
     if kernels == 3:
-        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
+        _two_kernels(monkeypatch)
     b, s, nkv = 8, 2048, 8
     q = _sds(one_chip, (b, s, HEADS * HEAD_DIM), jnp.bfloat16)
     kv = _sds(one_chip, (b, s, nkv * HEAD_DIM), jnp.bfloat16)
@@ -470,16 +484,25 @@ def test_causal_conv_kernels_fwd_bwd(one_chip, compiled, width, offset):
     assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
 
 
-def test_flash_attention_8k_head_dim_64(one_chip, compiled):
+@pytest.mark.parametrize("kernels", [2, 3])
+def test_flash_attention_8k_head_dim_64(one_chip, compiled, monkeypatch,
+                                        kernels):
     """The hybrid cell's one attention layer: 32 query / 8 KV heads of
-    64 at S 8192 — the transposed entry, the two-kernel backward."""
+    64 at S 8192 — the transposed entry; 8 MiB of fp32 dQ a group is past
+    the key-major rule and 8 MiB of fp32 dK and dV a KV head (a 64-wide
+    row fills a lane tile) within the query-major one: ONE pass under
+    ``flash_bwd_dq``'s name — or, both rules at 0 bytes, the two
+    kernels."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    if kernels == 3:
+        _two_kernels(monkeypatch)
     q = _sds(one_chip, (2, 8192, 32, 64), jnp.bfloat16)
     kv = _sds(one_chip, (2, 8192, 8, 64), jnp.bfloat16)
     text = _text(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True).astype(
             jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count(KERNEL) == 3 and "flash_bwd_dq" in text
+    assert text.count(KERNEL) == kernels and "flash_bwd_dq" in text
+    assert ("flash_bwd_dkv" in text) == (kernels == 3)
 
 
 def _cell_step(mesh, name):
@@ -518,7 +541,9 @@ def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
     mixer's that computes nothing (forward, recompute, backward: 8 a
     layer before the kernels took offsets, 16 in this text).  The one
     attention layer's ``flash_fwd`` runs once: full remat keeps its
-    outputs (69 MB, within ``FLASH_KEPT_BYTES``)."""
+    outputs (69 MB, within ``FLASH_KEPT_BYTES``), and its backward is
+    ONE pass since PR 45 (query-major, ``flash_bwd_dq``'s name;
+    ``flash_bwd_dkv`` is absent)."""
     from benchmark import harness
     cell = harness.find_cell("granite-4.0-h-micro.pretrain-8k")
     assert cell.conf["num_hidden_layers"] == 10 and \
@@ -526,10 +551,10 @@ def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
     c = _cell_step(one_chip, cell.name)
     text = c.as_text()
     for kernel in ("ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
-                   "causal_conv_bwd", "flash_fwd", "flash_bwd_dq",
-                   "flash_bwd_dkv"):
+                   "causal_conv_bwd", "flash_fwd", "flash_bwd_dq"):
         assert kernel in text, kernel
-    assert text.count(KERNEL) == 15
+    assert "flash_bwd_dkv" not in text
+    assert text.count(KERNEL) == 14
     assert ".remat" not in text
     assert not re.search(r"\[[\d,]*256,256\]", text)
     assert not _placed(text, ROWS_8K, MIXER_WIDTHS), \
@@ -689,27 +714,38 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
 
 
 @pytest.mark.parametrize("window,kernels", [
-    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
-    (4096, ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"))])
+    (None, ("flash_fwd", "flash_bwd_dq")),
+    (4096, ("flash_win_fwd", "flash_win_bwd_dq"))])
 def test_flash_attention_16k_at_the_window_cell_s_shapes(one_chip, compiled,
                                                          window, kernels):
     """One row of 16,384 tokens, 28 query / 4 KV heads of 128: BOTH forms
-    compile for a described v5e.  A head's K and V (forward, dq) or q and
-    dO (dkv) are 16 MiB resident with the pipeline's two buffers, past
-    Mosaic's own limit, so the calls ask for what they hold (until PR 44
-    the dense forward stopped near 8k at d 128).  7 * 16384 * 128 * 4 B of
-    fp32 dQ is past ``ONE_PASS_DQ_BYTES``: the two-kernel backward."""
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    compile for a described v5e.  A head's K and V are 16 MiB resident
+    with the pipeline's two buffers, past Mosaic's own limit, so the
+    calls ask for what they hold (until PR 44 the dense forward stopped
+    near 8k at d 128): the forward 16 + 8 MiB.  7 * 16384 * 128 * 4 B of
+    fp32 dQ is past ``ONE_PASS_DQ_BYTES`` and 2 * 16384 * 128 * 4 B of
+    fp32 dK and dV IS ``ONE_PASS_DKV_BYTES``: the query-major one pass —
+    K and V, the dk and dv blocks (16 MiB each with two buffers), the
+    two fp32 sums (16) and 8 for the tiles: 56 MiB asked, and
+    ``flash_(win_)bwd_dkv`` is absent."""
+    from paddle_tpu.ops.pallas.flash_attention import (ONE_PASS_DKV_BYTES,
+                                                       flash_attention)
+    assert 2 * 16384 * 128 * 4 == ONE_PASS_DKV_BYTES
     q = _sds(one_chip, (1, 16384, 28, 128), jnp.bfloat16)
     kv = _sds(one_chip, (1, 16384, 4, 128), jnp.bfloat16)
     text = _text(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True, window=window).astype(
             jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count(KERNEL) == 3
+    assert text.count(KERNEL) == 2
     for kernel in kernels:
         assert kernel in text, kernel
+    assert "bwd_dkv" not in text
     if window:
-        assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
+        assert "flash_bwd_dq" not in text
+    asked = [int(n) for n in re.findall(
+        KERNEL + r'".*"scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', text)]
+    assert sorted(asked) == [(16 + 8) << 20, (16 + 16 + 16 + 8) << 20]
 
 
 def test_train_step_of_the_window_cell(one_chip, compiled):
@@ -719,7 +755,8 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     tokens — fits a described v5e with NO compiler rematerialization at
     depth 8 (the issue's first choice; 4 was its fallback), runs the
     global layers on the dense kernels and the window layers on the
-    windowed form, the two-kernel backward in both, and ``flash_fwd`` /
+    windowed form, the query-major ONE-pass backward in both (since PR
+    45: ``flash_(win_)bwd_dkv`` absent), and ``flash_fwd`` /
     ``flash_win_fwd`` once a layer: full remat keeps their outputs (8 x
     119 MB = 954 MB, within ``FLASH_KEPT_BYTES``)."""
     from benchmark import harness
@@ -730,20 +767,22 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     assert keeps_flash_outputs(1, 16384, 28, 128, jnp.bfloat16, 8)
     c = _cell_step(one_chip, cell.name)
     text = c.as_text()
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                   "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv",
-                   "grouped_mm", "grouped_mm_dw", "moe_sum_pairs", "rope"):
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_win_fwd",
+                   "flash_win_bwd_dq", "grouped_mm", "grouped_mm_dw",
+                   "moe_sum_pairs", "rope"):
         assert kernel in text, kernel
+    assert "bwd_dkv" not in text
     # four runs of layers (global, window x 3, global, window x 3), each a
     # forward loop and a backward loop.  A layer forward: flash 1 + the
     # routed path on each of its two bounds, 2 grouped products + the
     # token side's sum; backward: the recompute's gate | up product on
     # each bound (the routed path's backward reads that product alone;
-    # attention's outputs are kept), flash's two kernels, and the routed
-    # backward on each bound, 2 products + 2 dw + the sum; a window layer
-    # rotates q and k: 2 rope kernels forward, 2 recomputed, 2 backward
-    per_run = 1 + 2 * 3 + 2 * 1 + 2 + 2 * 5
-    assert text.count(KERNEL) == 4 * per_run + 2 * 6 == 96
+    # attention's outputs are kept), flash's ONE backward kernel, and the
+    # routed backward on each bound, 2 products + 2 dw + the sum; a window
+    # layer rotates q and k: 2 rope kernels forward, 2 recomputed, 2
+    # backward
+    per_run = 1 + 2 * 3 + 2 * 1 + 1 + 2 * 5
+    assert text.count(KERNEL) == 4 * per_run + 2 * 6 == 92
     assert len(re.findall(r" conditional\(", text)) == 4 * 3
     for rows in (53248, 102400):
         assert f"bf16[{rows},2560]" in text
@@ -752,7 +791,9 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     assert not re.search(r"bf16\[(\d+,)?16,2560,1536\]", text)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 4_484_826_624
-    assert ma.temp_size_in_bytes <= 13_100_000_000
+    # 12,977,658,368 B with the two-kernel backward (PR 44), 12,977,271,296
+    # with the one pass: the delta arrays are gone, the sums live in VMEM
+    assert ma.temp_size_in_bytes <= 12_977_658_368
 
 
 # sha256 of the dense cell's optimized step at depth 18 with the debug

@@ -371,8 +371,8 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
     """What the ``attn`` scope of the train step holds with the Pallas
     attention on and GQA: the flash kernels — forward, the recompute's
     forward, and the backward in ONE pass (``flash_bwd_dkv`` sums dQ too
-    and forms delta itself): THREE; past the VMEM rule, here the module
-    constant set to 0 bytes, ``flash_bwd_dq`` runs before it: four;
+    and forms delta itself): THREE; past both VMEM rules, here the
+    module constants set to 0 bytes, ``flash_bwd_dq`` runs before it: four;
     where full remat keeps the forward's outputs (their bytes within
     ``FLASH_KEPT_BYTES``, here the module's own or 0) the recompute's
     forward is gone: one fewer — and the moves its addressing needs:
@@ -385,6 +385,7 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
     pretrain = importlib.import_module("paddle_tpu.models.llama_pretrain")
     if kernels == 4:
         monkeypatch.setattr(flash, "ONE_PASS_DQ_BYTES", 0)
+        monkeypatch.setattr(flash, "ONE_PASS_DKV_BYTES", 0)
     if not kept:
         monkeypatch.setattr(pretrain, "FLASH_KEPT_BYTES", 0)
     cfg = _cfg(hidden_size=hidden, num_attention_heads=heads,
