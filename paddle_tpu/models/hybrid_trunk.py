@@ -76,7 +76,9 @@ expert, on ONE residual stream; the router reads the attention's input:
 
 The route and the plan (``moe_route``, ``moe_dispatch``) are issued from
 x BEFORE attention: they depend on nothing attention computes, so XLA
-may run them beside it.
+may run them beside it.  Both run ONCE a layer a step: the trunk's
+checkpoint boundary keeps what ``ops/moe.ROUTING_NAMES`` names for the
+kinds in ``ROUTED_KINDS``, and the recompute reads it.
 """
 
 from __future__ import annotations
@@ -95,6 +97,7 @@ KINDS = ("attention", "mamba", "mla_dense", "mla_moe", "gqa_moe_global",
          "gqa_moe_window")
 MLA_KINDS = ("mla_dense", "mla_moe")
 GQA_MOE_KINDS = ("gqa_moe_global", "gqa_moe_window")
+ROUTED_KINDS = ("mla_moe",) + GQA_MOE_KINDS
 
 
 def check(cfg) -> None:
@@ -774,7 +777,8 @@ def trunk(blocks, x, cfg, mesh):
         parts = {kind: runs_of(kind) for kind in blocks}
         for kind, _, _ in runs:
             fwd = _remat_wrap(body[kind], cfg,
-                              keep_flash and kind in flash_kinds)
+                              keep_flash and kind in flash_kinds,
+                              kind in ROUTED_KINDS)
             x, _ = jax.lax.scan(
                 lambda carry, bp, fwd=fwd: (fwd(bp, carry, cfg, mesh, None),
                                             None),

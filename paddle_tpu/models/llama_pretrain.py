@@ -542,6 +542,11 @@ def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
 # is what the choice spends: the expert cell keeps 5 x 136 MB and the
 # hybrid cell 69 MB, the dense cell's 18 x 68 MB = 1.23 GB would buy
 # back the compiler's own rematerialization (PERF.md section 6, PR 43).
+# The other exception has no bound: an expert layer's ROUTING
+# (``ops/moe.ROUTING_NAMES``: the picks, their scores and the plan's
+# integer arrays, 2-6 MB a layer beside the 84 MB of its kept input) is
+# kept wherever a layer routes, so the recompute has no router's
+# product, no ``top_k`` and no sort (PERF.md section 6, PR 46).
 FLASH_KEPT_BYTES = 1 << 30
 
 
@@ -555,17 +560,23 @@ def keeps_flash_outputs(batch: int, seq: int, heads: int, value_dim: int,
     return 0 < layers * (o + lse) <= FLASH_KEPT_BYTES
 
 
-def _remat_wrap(fwd, cfg, keep_flash: bool = False):
+def _remat_wrap(fwd, cfg, keep_flash: bool = False, routes: bool = False):
     """``fwd`` under full remat; ``keep_flash`` (what
     :func:`keeps_flash_outputs` said of the trunk's flash layers) makes
-    the boundary's policy keep ``flash_fwd``'s outputs."""
+    the boundary's policy keep ``flash_fwd``'s outputs, ``routes`` (the
+    layer holds routed experts) what ``ops/moe`` names of a layer's
+    routing."""
     if not cfg.remat:
         return fwd
-    policy = None
+    names = ()
     if keep_flash:
         from ..ops.pallas.flash_attention import FWD_OUTPUT_NAMES
-        policy = jax.checkpoint_policies.save_only_these_names(
-            *FWD_OUTPUT_NAMES)
+        names += FWD_OUTPUT_NAMES
+    if routes:
+        from ..ops.moe import ROUTING_NAMES
+        names += ROUTING_NAMES
+    policy = jax.checkpoint_policies.save_only_these_names(*names) \
+        if names else None
     return jax.checkpoint(fwd, static_argnums=(2, 3), policy=policy)
 
 

@@ -26,6 +26,17 @@ backward, the same code at two static sizes.  Nothing is dropped on
 either; with every expert held the two are the same rows and no branch
 is built.
 
+Under a checkpoint boundary the routing is decided ONCE: :func:`route`
+and :func:`plan` hand on the scores, the picks and the plan's arrays
+under the names :data:`ROUTING_NAMES`, and a boundary whose policy saves
+them (``models/llama_pretrain._remat_wrap`` for the kinds that route)
+recomputes no router's product, no ``top_k`` and no sort — a few MB of
+integers and, under the ``sigmoid`` rule, one fp32 ``[T, published]`` a
+layer.  What the routed path itself keeps for its backward, the gate |
+up product, is written once, where it is kept: the kernel writes the
+load's rows into a result of the bound of any load, the one shape both
+branches of the ``cond`` have to give.
+
 Both ways through the buffer are gathers, forward and backward
 (:func:`routed_ffn`): a row reads its pair's token; a token sums the
 rows IT HAS — the rows are gathered into token order (the plan's slots:
@@ -47,11 +58,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .pallas.grouped_mm import TILE_M, grouped_mm, grouped_mm_dw
 from .pallas.moe_sum_pairs import moe_sum_pairs
 
-__all__ = ["Plan", "route", "plan", "rows_bound", "load_bound", "routed_ffn"]
+__all__ = ["Plan", "route", "plan", "rows_bound", "load_bound", "routed_ffn",
+           "ROUTING_NAMES"]
 
 I32 = jnp.int32
 
@@ -109,6 +122,11 @@ def _padded(pairs: int, held: int) -> int:
 RULES = ("sigmoid", "softmax_of_picks")
 ACTIVATIONS = ("silu", "relu")
 
+# The names :func:`route` and :func:`plan` hand their results on under
+# (``checkpoint_name``): the scores where the rule's derivative reads
+# them (``sigmoid``), the picks with their scores, the plan's six arrays.
+ROUTING_NAMES = ("moe_scores", "moe_picks", "moe_plan")
+
 
 def route(x, w_router, k: int, scale: float, rule: str = "sigmoid"):
     """x [T, C], w_router [C, published] -> the picks ``idx [T, k]`` and
@@ -127,12 +145,48 @@ def route(x, w_router, k: int, scale: float, rule: str = "sigmoid"):
     z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)
     if rule == "softmax_of_picks":
-        top, idx = jax.lax.top_k(z, k)
-        return idx.astype(I32), scale * jax.nn.softmax(top, axis=-1)
-    s = jax.nn.sigmoid(z)
-    top, idx = jax.lax.top_k(s, k)
+        top, idx = _top_k(z, k)
+        return idx, scale * jax.nn.softmax(top, axis=-1)
+    # the sigmoid's derivative reads the scores, the product's does not
+    s = jax.nn.sigmoid(checkpoint_name(z, ROUTING_NAMES[0]))
+    top, idx = _top_k(s, k)
     gate = scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
-    return idx.astype(I32), gate
+    return idx, gate
+
+
+def _named_top_k(scores, k: int):
+    top, idx = jax.lax.top_k(scores, k)
+    return (checkpoint_name(top, ROUTING_NAMES[1]),
+            checkpoint_name(idx.astype(I32), ROUTING_NAMES[1]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(scores, k: int):
+    """``lax.top_k`` of scores ``[T, E]`` (the picks int32), handed on
+    under their name — and a derivative that reads the NAMED picks:
+    ``lax.top_k``'s own gathers by the primitive's raw result, which a
+    policy of names does not keep, so the recompute would pick again
+    (and form the scores again to pick from)."""
+    return _named_top_k(scores, k)
+
+
+def _top_k_fwd(scores, k):
+    top, idx = _named_top_k(scores, k)
+    # lax.top_k's own rule: a pick's tangent is its score's, gathered
+    # along the row — here by the named picks, int32 as they are
+    rows_apart = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    _, pull = jax.vjp(lambda s: jax.lax.gather(
+        s, idx[..., None], rows_apart, (1, 1)), scores)
+    return (top, idx), pull
+
+
+def _top_k_bwd(k, pull, cts):
+    return pull(cts[0])
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
 def plan(idx, first: int, held: int, published: int) -> Plan:
@@ -175,9 +229,11 @@ def plan(idx, first: int, held: int, published: int) -> Plan:
     first_slot = jnp.concatenate(
         [jnp.zeros((1,), I32),
          jnp.cumsum(jnp.sum(is_held, axis=1, dtype=I32), dtype=I32)])
-    return Plan(row_pair, tile_expert, ends[-1:], fit(slot_row, 0),
-                fit(slot_token, -1), first_slot, k,
-                load_bound(T, k, held, published))
+    return jax.tree_util.tree_map(
+        lambda a: checkpoint_name(a, ROUTING_NAMES[2]),
+        Plan(row_pair, tile_expert, ends[-1:], fit(slot_row, 0),
+             fit(slot_token, -1), first_slot, k,
+             load_bound(T, k, held, published)))
 
 
 def _first_rows(p: Plan, m: int) -> Plan:
@@ -245,7 +301,9 @@ def routed_ffn(x, gate, w_gate_up, w_down, p: Plan, act: str = "silu"):
     and the plan, gathers the rows again and forms the hidden rows again
     — no [M, C] buffer outlives the pass that made it.  Every pass runs
     on the bound the load asks for (:func:`_at_the_load`); what is kept
-    has the full bound's shapes, filled in its first rows."""
+    has the full bound's shapes, filled in its first rows — the product
+    by the kernel that forms it (``grouped_mm(..., out_rows=)``: no pad,
+    the rows past the load's are never written and never read)."""
     return _at_the_load(lambda *a: _forward(act, *a)[0], p, x, gate,
                         w_gate_up, w_down)
 
@@ -268,13 +326,16 @@ def _hidden_and_its_gradient(act: str, g, u):
     return a * u, lambda d: [jnp.where(g > 0, d * u, 0), d * a]
 
 
-def _forward(act, p, x, gate, w_gate_up, w_down):
+def _forward(act, p, x, gate, w_gate_up, w_down, kept_rows: int = 0):
+    """y and the gate | up product; ``kept_rows`` (static): the rows of
+    the array the product is handed on in, its own where fewer."""
     te, n, f = p.tile_expert, p.n_tiles, w_down.shape[1]
+    m = p.row_pair.shape[0]
     with jax.named_scope("moe_dispatch"):
         rows = _tokens_of_rows(x, p)
     with jax.named_scope("moe_experts"):
-        gu = grouped_mm(rows, w_gate_up, te, n)
-        h = (_activation(act)(gu[:, :f].astype(jnp.float32)) * gu[:, f:]
+        gu = grouped_mm(rows, w_gate_up, te, n, out_rows=kept_rows)
+        h = (_activation(act)(gu[:m, :f].astype(jnp.float32)) * gu[:m, f:]
              * _gate_of_rows(gate, p)[:, None]).astype(x.dtype)
         out = grouped_mm(h, w_down, te, n)
     with jax.named_scope("moe_combine"):
@@ -283,12 +344,9 @@ def _forward(act, p, x, gate, w_gate_up, w_down):
 
 
 def _routed_fwd(x, gate, w_gate_up, w_down, p, act):
-    full = p.row_pair.shape[0]
-
-    def kept(p, *args):
-        y, gu = _forward(act, p, *args)
-        with jax.named_scope("moe_experts"):
-            return y, jnp.pad(gu, ((0, full - gu.shape[0]), (0, 0)))
+    # one shape from both branches, the bound of any load's: the load's
+    # bound writes its rows of the product into an array that long
+    kept = functools.partial(_forward, act, kept_rows=p.row_pair.shape[0])
     y, gu = _at_the_load(kept, p, x, gate, w_gate_up, w_down)
     return y, (x, gate, w_gate_up, w_down, p, gu)
 

@@ -84,10 +84,13 @@ def _mm_kernel(te_ref, n_ref, x_ref, w_ref, o_ref, w_cast, *,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False):
+def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
+               out_rows: int = 0):
     """x [M, K] @ w[e] ([E, K, N]; ``trans_w``: [E, N, K]; any float
     dtype) -> [M, N] in x's dtype, ``e`` the expert of the row's tile.
-    Tiles past ``n_tiles`` are left unwritten."""
+    Tiles past ``n_tiles`` are left unwritten — and so are the rows past
+    M of a result asked for at ``out_rows`` > M rows (a caller that has
+    to hand on a longer array gets it without a copy)."""
     M, K = x.shape
     N = w.shape[1] if trans_w else w.shape[2]
     tn = _cols(N)
@@ -117,7 +120,7 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False):
             out_specs=pl.BlockSpec(
                 (TILE_M, tn), lambda j, i, te, n: idx32(_held(i, n), j)),
             scratch_shapes=[pltpu.VMEM(w_block[1:], x.dtype)]),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((max(M, out_rows), N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         name="grouped_mm",

@@ -1,0 +1,288 @@
+"""Full remat keeps an expert layer's routing (``ops/moe.ROUTING_NAMES``
+in the policy of ``models/llama_pretrain._remat_wrap`` for the kinds
+that route): under the trunk's one checkpoint boundary the router's
+product, ``top_k`` and the plan's two sorts are in the gradient's program
+once a run of layers — twice with the names left out of the policy — and
+the loss and every gradient are the same bits either way, for both
+routing rules and both families' blocks at toy size.  And what the
+routed path keeps for its backward, the gate | up product, is written
+once: by the kernel, into an array of the one shape both branches of the
+``cond`` give, with no pad.
+"""
+
+import dataclasses
+import functools
+import os
+from unittest import mock
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu  # noqa: F401
+from benchmark import harness
+from paddle_tpu.models import hybrid_trunk
+from paddle_tpu.ops import moe
+from paddle_tpu.ops.pallas.grouped_mm import TILE_M, grouped_mm
+
+TOY = os.path.join(harness.HERE, "tests", "toy")
+SEQ, ROWS = 128, 1
+# family -> (toy configuration, its job, the layers kept): one routed
+# layer (rule ``sigmoid``, four streams, a shared expert) and two runs
+# of one (``softmax_of_picks``)
+FAMILIES = {
+    "xing_mhc_moe": ("config_xing.json", "train_job.json", ("mla_moe",)),
+    "smallthinker_moe": ("config_smallthinker.json",
+                         "train_job_smallthinker.json",
+                         ("gqa_moe_global", "gqa_moe_window")),
+}
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (kernel bodies apart)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _routing_ops(closed_jaxpr, published: int) -> dict:
+    """The sorts, the ``top_k`` and the router's products (fp32
+    ``Precision.HIGHEST`` onto ``published`` scores) in a program."""
+    out = {"sort": 0, "top_k": 0, "router": 0}
+    for eqn in _eqns(closed_jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name in ("sort", "top_k"):
+            out[name] += 1
+        elif name == "dot_general" and "HIGHEST" in str(
+                eqn.params["precision"]) \
+                and eqn.outvars[0].aval.shape[-1] == published:
+            out["router"] += 1
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _toy(family: str):
+    """(cfg, blocks, x): the family's toy configuration cut to the layers
+    of ``FAMILIES``, seeded leaves and one row of inputs, in the cells'
+    dtypes — bf16 rows, fp32 leaves and scores.  (All in fp32 the CPU
+    compiles the forward loop's router product and the backward loop's
+    to different last bits, and "the same bits" is no longer the
+    recomputing program's own property.)"""
+    conf_file, job_file, layers = FAMILIES[family]
+    conf = harness.load_json(os.path.join(TOY, conf_file))
+    job = dict(harness.load_json(os.path.join(TOY, job_file)), seq=SEQ,
+               batch=ROWS)
+    cell = harness.Cell.detached(f"toy-{family}.train_job", 1, conf, job)
+    cfg = dataclasses.replace(
+        cell.family.build_cfg(conf, True, job), layer_types=layers, num_hidden_layers=len(layers),
+        rope_layout=None, sliding_window_layout=None)
+    hybrid_trunk.check(cfg)
+    assert cfg.remat and cfg.experts_held < cfg.n_routed_experts
+    assert (cfg.dtype, cfg.param_dtype) == (jnp.bfloat16, jnp.float32)
+    key = jax.random.PRNGKey(46)
+    x = jax.random.normal(jax.random.fold_in(key, 1),
+                          (ROWS, SEQ, cfg.hidden_size), cfg.dtype)
+    return cfg, hybrid_trunk.init_blocks(cfg, key), x
+
+
+def _loss(cfg, blocks, x):
+    out = hybrid_trunk.trunk(blocks, x, cfg, None)
+    return jnp.mean(jnp.square(out.astype(jnp.float32)))
+
+
+def _routed_runs(cfg) -> int:
+    return sum(kind in hybrid_trunk.ROUTED_KINDS
+               for kind, _, _ in hybrid_trunk.layer_runs(cfg.layer_types))
+
+
+@functools.lru_cache(maxsize=None)
+def _program(family: str, kept: bool):
+    """The toy trunk's loss and gradients (of every leaf and of the
+    input) with the routing's names in the boundary's policy or left
+    out of it: the program's jaxpr, and what it computes."""
+    cfg, blocks, x = _toy(family)
+    fn = jax.jit(jax.value_and_grad(functools.partial(_loss, cfg),
+                                    argnums=(0, 1)))
+    with mock.patch.object(hybrid_trunk, "ROUTED_KINDS",
+                           hybrid_trunk.ROUTED_KINDS if kept else ()):
+        jax.clear_caches()      # a traced loop body is kept by its avals
+        traced = fn.trace(blocks, x)
+        return traced.jaxpr, traced.lower().compile()(blocks, x)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_recompute_has_no_route_and_no_plan(family):
+    """A run of routed layers is a forward loop and a backward loop, the
+    recompute in the second: with the routing kept the router's product,
+    its ``top_k`` and the plan's two sorts are in the first alone."""
+    cfg = _toy(family)[0]
+    runs = _routed_runs(cfg)
+    assert runs == (1 if family == "xing_mhc_moe" else 2)
+    count = lambda kept: _routing_ops(_program(family, kept)[0],
+                                      cfg.n_routed_experts)
+    assert count(True) == {"sort": 2 * runs, "top_k": runs, "router": runs}
+    assert count(False) == {"sort": 4 * runs, "top_k": 2 * runs,
+                            "router": 2 * runs}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_kept_routing_gives_the_recomputed_routing_s_bits(family):
+    (kept, kept_g), (again, again_g) = (_program(family, kept)[1]
+                                        for kept in (True, False))
+    assert np.isfinite(float(kept)) and float(kept) == float(again)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept_g),
+                            jax.tree_util.tree_leaves(again_g)):
+        assert float(jnp.max(jnp.abs(a))) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("rule", moe.RULES)
+def test_the_picks_derivative_is_lax_top_k_s(rule):
+    """``route`` differentiates its ``top_k`` by the picks it NAMES; the
+    gates, the picks and both gradients are the bits of the same rule
+    written over ``lax.top_k`` and its own derivative."""
+    T, c, pub, k, scale = 512, 128, 16, 3, 2.5
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(key, (T, c), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (c, pub)) / c ** 0.5
+    co = jax.random.normal(jax.random.fold_in(key, 2), (T, k), jnp.float32)
+
+    def plain(x, w):
+        z = jnp.dot(x, w.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        if rule == "softmax_of_picks":
+            top, idx = jax.lax.top_k(z, k)
+            return idx, scale * jax.nn.softmax(top, axis=-1)
+        top, idx = jax.lax.top_k(jax.nn.sigmoid(z), k)
+        return idx, scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+
+    def gates_and_grads(route):
+        gate, pull, idx = jax.vjp(lambda x, w: route(x, w)[::-1], x, w,
+                                  has_aux=True)
+        return (idx, gate) + pull(co)
+    named = gates_and_grads(lambda x, w: moe.route(x, w, k, scale, rule))
+    for a, b in zip(named, gates_and_grads(plain)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert named[0].dtype == jnp.int32 and float(jnp.max(jnp.abs(
+        named[2]))) > 0
+
+
+def _routed_case(T, c, f, held, k, seed=3):
+    key = jax.random.PRNGKey(seed)
+    ks = jax.random.split(key, 5)
+    bf = jnp.bfloat16
+    return (jax.random.normal(ks[0], (T, c), jnp.float32).astype(bf),
+            jax.random.uniform(ks[1], (T, k), jnp.float32, 0.1, 1.0),
+            jax.random.normal(ks[2], (held, c, 2 * f), jnp.float32) / c ** .5,
+            jax.random.normal(ks[3], (held, f, c), jnp.float32) / f ** .5,
+            jax.random.normal(ks[4], (T, c), jnp.float32).astype(bf))
+
+
+def test_the_kept_product_is_written_where_it_is_kept():
+    """What ``routed_ffn`` keeps of its forward for the backward: the
+    gate | up product in an array of the bound of any load (one shape
+    from both branches), its first ``load_rows`` rows written by the
+    kernel itself — the program pads nothing to that shape."""
+    T, c, f, held, k, pub, first = 2048, 128, 128, 2, 2, 16, 4
+    x, gate, wgu, wd, _ = _routed_case(T, c, f, held, k)
+    idx = jnp.stack([jnp.arange(T) % pub, (jnp.arange(T) + 5) % pub],
+                    1).astype(jnp.int32)
+    p = moe.plan(idx, first, held, pub)
+    full = p.row_pair.shape[0]
+    assert p.load_rows == 1536 < full == 4608
+    assert int(p.n_tiles[0]) * TILE_M <= p.load_rows    # the load's bound
+    fwd = functools.partial(moe._routed_fwd, p=p, act="silu")
+    assert not [eqn for eqn in _eqns(
+        jax.make_jaxpr(fwd)(x, gate, wgu, wd).jaxpr)
+        if eqn.primitive.name == "pad"
+        and eqn.outvars[0].aval.shape == (full, 2 * f)]
+    y, res = jax.jit(fwd)(x, gate, wgu, wd)
+    assert res[-1].shape == (full, 2 * f)
+    rows = x[jnp.maximum(p.row_pair[:p.load_rows], 0) // k]
+    want = grouped_mm(rows, wgu, p.tile_expert[:p.load_rows // TILE_M],
+                      p.n_tiles)
+    tiles = int(p.n_tiles[0]) * TILE_M
+    np.testing.assert_array_equal(np.asarray(res[-1][:tiles]),
+                                  np.asarray(want[:tiles]))
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(moe.routed_ffn(x, gate, wgu, wd, p)))
+
+
+@pytest.mark.parametrize("act", moe.ACTIVATIONS)
+def test_a_load_past_its_bound_loses_nothing(act):
+    """All the tokens on the held experts: the plan's tiles do not fit
+    the load's bound and the passes run on the bound of any load, where
+    the kept product is the kernel's whole result.  Value and the four
+    gradients are BIT FOR BIT those of the same pairs planned with
+    ``held == published``, the program of one bound."""
+    T, c, f, held, k, pub, first = 2048, 128, 128, 2, 2, 16, 4
+    x, gate, wgu, wd, co = _routed_case(T, c, f, held, k, seed=5)
+    local = jnp.stack([jnp.arange(T) % held, (jnp.arange(T) + 1) % held],
+                      1).astype(jnp.int32)
+    p = moe.plan(local + first, first, held, pub)
+    one = moe.plan(local, 0, held, held)
+    assert int(p.n_tiles[0]) * TILE_M > p.load_rows
+    assert one.load_rows == one.row_pair.shape[0] == p.row_pair.shape[0]
+    for a, b in zip(jax.tree_util.tree_leaves(p),
+                    jax.tree_util.tree_leaves(one)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def value_and_grads(p):
+        y, vjp = jax.vjp(lambda *a: moe.routed_ffn(*a, p, act), x, gate,
+                         wgu, wd)
+        return (y,) + vjp(co)
+    got, want = jax.jit(value_and_grads)(p), jax.jit(value_and_grads)(one)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and float(jnp.max(jnp.abs(b))) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_moe_passes_splits_the_scopes_by_pass():
+    """``tools/moe_passes.py``: an op of a ``moe_*`` scope is charged to
+    its scope AND the pass its path names; a ``sort`` or a ``pad`` is
+    listed wherever it runs."""
+    import importlib.util
+    from benchmark import xplane_meta
+    from benchmark.models import smallthinker_moe
+    spec = importlib.util.spec_from_file_location(
+        "moe_passes", os.path.join(os.path.dirname(harness.HERE), "tools",
+                                   "moe_passes.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def op(path, category="fusion"):
+        return xplane_meta.Op("x", 0.0, 1e-3, 1e-3, path, "", category,
+                              0., 0.)
+    fwd = "jit(step)/jvp(layer_scan)/while/body/closed_call/block"
+    bwd = "jit(step)/transpose(jvp(layer_scan))/while/body/closed_call" \
+          "/checkpoint"
+    mt = xplane_meta.MetaTrace({0: [
+        op(f"{fwd}/moe_dispatch/sort", "sort"),
+        op(f"{fwd}/moe_dispatch/gather"),
+        op(f"{bwd}/rematted_computation/block/moe_dispatch/gather"),
+        op(f"{bwd}/rematted_computation/block/moe_experts/pad", "pad"),
+        op(f"{bwd}/block/moe_combine/gather"),
+        op(f"{bwd}/block/attn/dot_general"),
+        op("jit(step)/transpose(jvp(embed))/scatter-add", "sort")]},
+        {0: []}, []).named(xplane_meta.SCOPES + smallthinker_moe.SCOPES,
+                           xplane_meta.KERNELS)
+    got = tool.by_pass(mt)
+    assert got["scope_pass"] == pytest.approx({
+        "moe_dispatch|forward": 2e-3, "moe_dispatch|recompute": 1e-3,
+        "moe_experts|recompute": 1e-3, "moe_combine|backward": 1e-3})
+    assert got["sort_pad"] == pytest.approx({
+        "sort|moe_dispatch|forward": 1e-3, "pad|moe_experts|recompute": 1e-3,
+        "sort|embed|backward": 1e-3})
+    assert len(got["recompute_ops"]) == 2

@@ -603,7 +603,9 @@ def test_routed_experts_at_the_expert_cell_s_shapes(one_chip, compiled,
     the path is built on BOTH bounds (3 + 5 kernels each, one
     ``conditional`` forward and one backward), with all 64 held on the
     one there is.  Mosaic takes the token side's kernel at both sizes of
-    its slots."""
+    its slots.  What the forward keeps of the gate | up product has the
+    67,584 rows of any load, and the load's bound writes its 18,432 there
+    from the kernel (PR 46): nothing pads it."""
     from paddle_tpu.ops import moe
     T, C, F, K, PUB = 16384, 3584, 1024, 4, 64
 
@@ -622,6 +624,23 @@ def test_routed_experts_at_the_expert_cell_s_shapes(one_chip, compiled,
     for kernel in ("grouped_mm", "grouped_mm_dw", "moe_sum_pairs"):
         assert kernel in text, kernel
     assert ("bf16[18432,3584]" in text) == (held == 8)
+    assert not _padded_from(text, 18432, 67584, 2 * F)
+
+
+def _padded_from(text, rows, to_rows, width) -> list:
+    """The ``pad`` instructions that write a bf16 ``[rows, width]`` array
+    out again at ``to_rows`` rows."""
+    return re.findall(rf"bf16\[{to_rows},{width}\]\S* pad\(.*"
+                      rf"padding=0_{to_rows - rows}x0_0", text)
+
+
+def _routing_sorts(text) -> tuple:
+    """(forward, backward) ``sort`` instructions of the routed path — the
+    router's ``top_k`` is one, the plan has two — by the loop their op
+    path names: the backward loops hold the recompute."""
+    paths = re.findall(r' sort\(.*op_name="([^"]*/moe_[^"]*)"', text)
+    backward = sum("transpose(jvp(" in path for path in paths)
+    return len(paths) - backward, backward
 
 
 def test_mixer_kernels_at_the_expert_cell_s_shapes(one_chip, compiled):
@@ -681,8 +700,9 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # and the one-pass backward); an expert layer: 2 flash, and the routed
     # path ON EACH OF ITS TWO BOUNDS (18,432 rows where the load's tiles
     # fit them, 67,584 otherwise: one ``conditional`` a pass): 2 grouped
-    # products + the token side's sum forward, the same recomputed, 2
-    # products + 2 dw + the sum backward
+    # products + the token side's sum forward, the same recomputed (the
+    # mixer's ``hc_post`` reads the sublayer's output), 2 products + 2 dw
+    # + the sum backward
     # + the mixers (``ops/pallas/hc_mix.py``), in the lead's loop and in
     # the expert layers': two sublayers forward (``hc_pre_fwd``,
     # ``hc_post_fwd``: 4), the same recomputed but the last X', which
@@ -702,6 +722,12 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     assert len(re.findall(r" conditional\(", text)) == 3
     for rows in (18432, 67584):
         assert f"bf16[{rows},3584]" in text
+    # full remat keeps the routing (PR 46): the router's ``top_k`` and the
+    # plan's two sorts are in the forward loop alone, and the recompute's
+    # gate | up product is written where it is kept — no pad to the
+    # bound of any load
+    assert _routing_sorts(text) == (3, 0)
+    assert not _padded_from(text, 18432, 67584, 2048)
     assert ".remat" not in text
     assert not re.search(r"bf16\[(4,)?8,3584,2048\]", text)
     ma = c.memory_analysis()
@@ -709,8 +735,9 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # PR 43's reading with the outputs kept (15,981,031,936 without).
     # The figure is no allocation's size: the buffer assignment holds the
     # kept stacks once, and its one HBM temp allocation grew by
-    # 392,691,712 B to 11,188,912,640 (PERF.md section 6)
-    assert ma.temp_size_in_bytes <= 17_408_805_376
+    # 392,691,712 B to 11,188,912,640 (PERF.md section 6); + 64,929,280
+    # with the four layers' routing kept (PR 46: 17,408,482,816 before)
+    assert ma.temp_size_in_bytes <= 17_473_412_096
 
 
 @pytest.mark.parametrize("window,kernels", [
@@ -786,14 +813,20 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     assert len(re.findall(r" conditional\(", text)) == 4 * 3
     for rows in (53248, 102400):
         assert f"bf16[{rows},2560]" in text
+    # full remat keeps the routing (PR 46): the sorts of the four runs'
+    # routers and plans are in the forward loops alone, and no recompute
+    # pads its gate | up product
+    assert _routing_sorts(text) == (4 * 3, 0)
+    assert not _padded_from(text, 53248, 102400, 1536)
     assert ".remat" not in text
     # no bf16 copy of an expert stack
     assert not re.search(r"bf16\[(\d+,)?16,2560,1536\]", text)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 4_484_826_624
     # 12,977,658,368 B with the two-kernel backward (PR 44), 12,977,271,296
-    # with the one pass: the delta arrays are gone, the sums live in VMEM
-    assert ma.temp_size_in_bytes <= 12_977_658_368
+    # with the one pass (PR 45): the delta arrays are gone, the sums live
+    # in VMEM; + 52,790,272 with the eight layers' routing kept (PR 46)
+    assert ma.temp_size_in_bytes <= 13_030_061_568
 
 
 # sha256 of the dense cell's optimized step at depth 18 with the debug
