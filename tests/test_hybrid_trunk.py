@@ -1,17 +1,12 @@
 """Layers by kind on the normal path: ``make_train_step`` over
 ``models/hybrid_trunk.py`` (a Mamba-2 mixer, NoPE GQA at its own score
 scale, a residual multiplier, a tied and scaled head) held to the plain
-reference ``benchmark/models/granite_hybrid_reference.py`` at toy size —
-float32 on the CPU, the published PATTERN (one period of ten: five
-state-space layers, one attention layer, four more), two state-space
-heads, a GQA group of 2, two chunks a row so that the state crosses a
-chunk, seeded weights.  Then one thing is changed at a time, in the
-program or in the reference, and the comparison must fail.
+reference ``benchmark/models/granite_hybrid_reference.py`` at toy size
+(``tests/_hybrid_toy.py``) — float32 on the CPU, seeded weights.  Then
+one line of the REFERENCE is changed at a time and the comparison must
+fail; one key of the PROGRAM's configuration:
+``test_hybrid_program_altered.py``.
 """
-
-import dataclasses
-import os
-import types
 
 import numpy as np
 import pytest
@@ -19,66 +14,21 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import paddle_tpu  # noqa: F401
-from benchmark import harness, reference, train_cell
+import _toy_cell
+from _hybrid_toy import ref, sound, toy  # noqa: F401
+from _toy_cell import (BROKEN, SEQ, SOUND, altered_reference,
+                       follow_reference, worst_gap)
+from benchmark import reference, train_cell
 from paddle_tpu.models import hybrid_trunk, llama_pretrain
 from paddle_tpu.models.llama_pretrain import (
     LlamaPretrainConfig, adafactor_update, build_mesh,
     init_adafactor_state, make_train_step)
 
-TOY = os.path.join(harness.HERE, "tests", "toy")
-SEED, SEQ, ROWS = 2**31 + 77, 256, 2
-SOUND, BROKEN = 1e-5, 1e-3
 
-
-@pytest.fixture(scope="module")
-def toy():
-    conf = harness.load_json(os.path.join(TOY, "config_granite.json"))
-    job = dict(harness.load_json(os.path.join(TOY, "train_job.json")),
-               seq=SEQ, batch=ROWS)
-    cell = harness.Cell.detached("toy-granite.train_job", 1, conf, job)
-    cfg = dataclasses.replace(cell.family.build_cfg(conf, True, job),
-                              dtype=jnp.float32)
-    key = cell.family.seed_key(SEED)
-    batches = [np.stack([train_cell.token_row(SEED, ROWS * s + r, SEQ,
-                                              conf["vocab_size"])
-                         for r in range(ROWS)]) for s in range(2)]
-    return types.SimpleNamespace(
-        cell=cell, conf=conf, job=job, cfg=cfg, batches=batches,
-        leaf0=train_cell.leaf_maker(cell.family, cfg, key))
-
-
-def follow(toy, cfg, extra_leaves=None):
-    """The program's two steps under ``cfg``: losses, the first
-    gradient's norm and the two-step change, leaf by leaf."""
-    mesh = build_mesh(devices=jax.devices()[:1])
-    with mesh:
-        params = toy.cell.family.make_params(toy.cfg, SEED, mesh)
-        params.update(extra_leaves(params) if extra_leaves else {})
-        step = make_train_step(cfg, mesh, lr=toy.job["lr"],
-                               weight_decay=toy.job["weight_decay"],
-                               optimizer="adafactor")
-        # an untied head starts as the table's transpose
-        leaf0 = lambda path: toy.leaf0(("embed",)).T \
-            if path == ("lm_head",) else toy.leaf0(path)
-        return train_cell.follow_program(
-            step, params, init_adafactor_state(params), toy.batches,
-            leaf0)[2]
-
-
-def gaps(prog, ref):
-    return max(train_cell.gap_numbers(prog, ref).values())
-
-
-@pytest.fixture(scope="module")
-def sound(toy):
-    return follow(toy, toy.cfg)
-
-
-@pytest.fixture(scope="module")
-def ref(toy):
-    return train_cell.run_reference(toy.cell, toy.job, toy.leaf0,
-                                    toy.batches)
+def test_the_made_tree_is_the_leaf_maker_s(toy):
+    _toy_cell.made_tree_is_the_leaf_maker_s(toy, [
+        ("embed",), ("blocks", "mamba", "w_in"),
+        ("blocks", "attention", "wq")])
 
 
 def test_the_toy_has_what_the_cell_has(toy):
@@ -100,10 +50,7 @@ def test_two_steps_match_the_reference(sound, ref):
 
 
 def test_logits_match_the_reference(toy):
-    cfg, fam = toy.cfg, toy.cell.family
-    mesh = build_mesh(devices=jax.devices()[:1])
-    with mesh:
-        params = fam.make_params(cfg, SEED, mesh)
+    cfg, params = toy.cfg, toy.params0
     ids = toy.batches[0][0, :SEQ]
 
     def program(params, ids):
@@ -120,34 +67,6 @@ def test_logits_match_the_reference(toy):
     assert np.max(np.abs(got - want)) < SOUND * np.max(np.abs(want))
 
 
-def _untied(params):
-    return {"lm_head": params["embed"].T.copy()}
-
-
-# one thing changed in the PROGRAM's configuration
-PROGRAM = {
-    "pattern_shifted_by_a_layer": lambda c, full: dict(
-        layer_types=tuple(full[1:1 + c.num_hidden_layers])),
-    "residual_multiplier_dropped": lambda c, full: dict(
-        residual_multiplier=1.0),
-    "score_scale_one_over_sqrt_d": lambda c, full: dict(
-        attention_multiplier=None),
-    "rope_left_on": lambda c, full: dict(position_embedding_type="rope"),
-    "embedding_multiplier_dropped": lambda c, full: dict(
-        embedding_multiplier=1.0),
-    "logits_not_divided": lambda c, full: dict(logits_scaling=1.0),
-    "tie_broken": lambda c, full: dict(tie_word_embeddings=False),
-}
-
-
-@pytest.mark.parametrize("what", sorted(PROGRAM))
-def test_a_program_altered_in_one_place_fails(toy, ref, what):
-    change = PROGRAM[what](toy.cfg, toy.conf["layer_types"])
-    cfg = dataclasses.replace(toy.cfg, **change)
-    prog = follow(toy, cfg, _untied if what == "tie_broken" else None)
-    assert gaps(prog, ref) > BROKEN
-
-
 # one line changed in the REFERENCE
 REFERENCE = {
     "D_ignored": (' + w["D"][:, None] * xs.reshape(b, s, nh, p)', ""),
@@ -162,25 +81,11 @@ REFERENCE = {
 }
 
 
-def altered_reference(old: str, new: str):
-    path = os.path.join(harness.HERE, "models",
-                        "granite_hybrid_reference.py")
-    with open(path) as f:
-        src = f.read()
-    assert src.count(old) == 1, old
-    mod = types.ModuleType("benchmark.models.granite_hybrid_altered")
-    mod.__package__ = "benchmark.models"
-    exec(compile(src.replace(old, new), path, "exec"), mod.__dict__)
-    return mod
-
-
 @pytest.mark.parametrize("what", sorted(REFERENCE))
 def test_a_reference_altered_in_one_line_fails(toy, sound, what):
-    cell = types.SimpleNamespace(
-        block_reference=altered_reference(*REFERENCE[what]),
-        conf=toy.conf)
-    other = train_cell.run_reference(cell, toy.job, toy.leaf0, toy.batches)
-    assert gaps(sound, other) > BROKEN
+    other = follow_reference(
+        toy, altered_reference("granite_hybrid", *REFERENCE[what]))
+    assert worst_gap(sound, other) > BROKEN
 
 
 def test_adafactor_takes_a_stacked_leaf_a_layer_at_a_time():

@@ -1,6 +1,14 @@
 """Flagship LLaMA tests: Layer model, functional pretrain engine,
-hybrid-mesh train step, graft entry."""
+hybrid-mesh train step, graft entry.
 
+``test_graft_entry`` is the suite's longest case by far (five minutes of
+one worker under the other five's load, ~1,200 core-seconds: it RUNS the
+8-device programs of ``__graft_entry__.dryrun_multichip``) and stays in
+this file of 23 cases on purpose: xdist hands files out by their NUMBER
+OF TESTS, largest first, so a one-case file would start last and be the
+run's tail (read: +67 s; PERF.md section 6)."""
+
+import os
 import sys
 
 import numpy as np
@@ -115,7 +123,8 @@ def test_pipeline_matches_single_stage():
 
 
 def test_graft_entry():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     import __graft_entry__ as ge
     import jax
     fn, args = ge.entry()

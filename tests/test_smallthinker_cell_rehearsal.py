@@ -9,110 +9,22 @@ the entered cell's arithmetic: the cut's parameter counts, the needed
 work that counts the window, the catalog row key by key.
 """
 
-import filecmp
 import json
 import os
-import shutil
-import subprocess
-import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-LEFT_BEHIND = ("out", "__pycache__", ".pytest_cache")
-
-DRIVER = '''import json, os, sys, types
-
-
-def main():
-    copy_root, repo = sys.argv[1], sys.argv[2]
-    sys.path[:0] = [copy_root, repo]    # benchmark: the copy; the program: the repo's
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    from benchmark import (harness, kernel_costs, rehearse, train_cell,
-                           xplane_meta)
-    seen = rehearse.patch_for_cpu(harness)
-    counts_only = harness.result_line
-
-    def with_names(cell, devices, traced, correct, attempted, failed,
-                   metrics, *rest, **kw):
-        seen["names"] = sorted(metrics)
-        return counts_only(cell, devices, traced, correct, attempted,
-                           failed, metrics, *rest, **kw)
-    harness.result_line = with_names
-    toy = os.path.join(os.path.dirname(harness.__file__), "tests", "toy")
-    conf = harness.load_json(os.path.join(toy, "config_smallthinker.json"))
-    job = harness.load_json(os.path.join(toy, "train_job_smallthinker.json"))
-    cell = harness.Cell.detached("toy-smallthinker.train_job", 1, conf, job)
-    out = {"harness": harness.__file__}
-
-    def run(name, override=None):
-        args = types.SimpleNamespace(workload=cell.name, seed=2**31 + 33,
-                                     seconds=1.0, trace=1)
-        rc = train_cell.run(args, cell, step_override=override)
-        out[name] = {"rc": rc, "correct": seen["correct"],
-                     "attempted": seen["attempted"],
-                     "failed": seen["failed"]}
-
-    def drifting(compiled):
-        """The timed path broken underneath: after every step the
-        routed experts' down projections are 5 % larger."""
-        def step(params, opt, tokens):
-            new, opt, loss = compiled(params, opt, tokens)
-            moe = dict(new["blocks"]["gqa_moe_window"])
-            moe["we_down"] = moe["we_down"] * 1.05
-            blocks = dict(new["blocks"], gqa_moe_window=moe)
-            return dict(new, blocks=blocks), opt, loss
-        return step
-    run("sound")
-    run("broken", drifting)
-    scopes, kernels = xplane_meta.names_of(cell)
-    out["scopes_added"] = scopes[len(xplane_meta.SCOPES):]
-    out["kernels_added"] = kernels[len(xplane_meta.KERNELS):]
-    out["kinds"] = [list(c) for c in kernel_costs.layer_costs(conf)]
-    out["total_params"] = kernel_costs.total_params(conf)
-    out["metrics"] = seen["names"]
-    print("REHEARSED " + json.dumps(out), flush=True)
-
-
-if __name__ == "__main__":      # the DataLoader's workers import this file
-    main()
-'''
-
-
-def tree_files(root):
-    out = set()
-    for d, dirs, files in os.walk(root):
-        dirs[:] = [x for x in dirs if x not in LEFT_BEHIND]
-        out.update(os.path.relpath(os.path.join(d, f), root)
-                   for f in files if not f.endswith(".pyc"))
-    return out
+import _cell_rehearsal
 
 
 @pytest.fixture(scope="module")
 def rehearsed(tmp_path_factory):
-    tmp_path = tmp_path_factory.mktemp("smallthinker_cell")
-    copy = tmp_path / "benchmark"
-    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns(*LEFT_BEHIND))
-    before = tree_files(copy)
-    driver = tmp_path / "driver.py"
-    driver.write_text(DRIVER)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("PYTHONPATH", "XLA_FLAGS")}
-    p = subprocess.run([sys.executable, str(driver), str(tmp_path), REPO],
-                       capture_output=True, text=True, timeout=900,
-                       env=env, cwd=str(tmp_path))
-    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
-    line = [l for l in p.stdout.splitlines()
-            if l.startswith("REHEARSED ")][-1]
-    got = json.loads(line[10:])
-    assert os.path.dirname(got["harness"]) == str(copy)
-    # the family is files: the run changed none of them
-    assert tree_files(copy) == before
-    for rel in sorted(before):
-        assert filecmp.cmp(os.path.join(BENCH, rel), copy / rel,
-                           shallow=False), rel
-    return got
+    """Sound, then broken underneath: after every step the window
+    layers' routed down projections are 5 % larger."""
+    return _cell_rehearsal.rehearse(
+        tmp_path_factory, "smallthinker", "config_smallthinker.json",
+        "train_job_smallthinker.json", seed=2**31 + 33,
+        drifts=("gqa_moe_window", "we_down"))
 
 
 def test_the_real_step_is_judged_correct_by_the_family_s_reference(rehearsed):
@@ -235,7 +147,8 @@ def test_the_entered_cell_s_costs_are_the_issue_s_arithmetic():
 def test_every_why_and_source_fits_its_line():
     """A ``why`` or ``source`` over 200 characters is refused before
     any run (PR 33's second session)."""
-    bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    bench = json.load(open(os.path.join(_cell_rehearsal.REPO,
+                                        "BENCHMARK.json")))
     for entry in bench["configs"] + bench["workloads"]:
         for key in ("why", "source"):
             assert 1 <= len(entry.get(key, "x")) <= 200, (entry["name"], key)
