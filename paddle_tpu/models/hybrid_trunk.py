@@ -79,6 +79,36 @@ x BEFORE attention: they depend on nothing attention computes, so XLA
 may run them beside it.  Both run ONCE a layer a step: the trunk's
 checkpoint boundary keeps what ``ops/moe.ROUTING_NAMES`` names for the
 kinds in ``ROUTED_KINDS``, and the recompute reads it.
+
+``conv_dense``, ``conv_moe`` and ``gqa_qknorm_moe`` are the layers of a
+short-convolution / attention model (LFM2's ``lfm2_moe``): an OPERATOR —
+a gated short convolution, or grouped-query attention whose q and k
+heads are normed — before a dense SwiGLU MLP (the leading layers) or an
+expert layer WITHOUT a shared expert whose router picks by a bias it
+does not gate by, on ONE residual stream:
+
+    y = rms_norm(h; ln1)
+    conv:  [B | Cg | X] = y . w_in                     (three groups of C)
+           v[t] = sum_{k<K} conv_w[:, k] (B X)[t - (K-1) + k]   (zeros
+                  before the row; no bias, no activation; K =
+                  conv_L_cache;  ops/pallas/causal_conv.py, gated form)
+           h1 = h + (Cg * v) . w_out
+    gqa_qknorm: q, k, v = y . wq, y . wk, y . wv         [H | KV, head_dim]
+           q = rms_norm(q; q_layernorm), k = rms_norm(k; k_layernorm),
+           a head at a time, BEFORE the rotation (rotate-half, rope_theta)
+           h1 = h + softmax(q k^T / sqrt(head_dim), j <= i) v . wo
+    u = rms_norm(h1; ln2)
+    conv_dense: h2 = h1 + (silu(u . w_gate) * (u . w_up)) . w_down
+    *_moe: s = sigmoid(u . w_router)  (fp32, ``n_routed_experts`` wide)
+           picks = the top ``num_experts_per_tok`` of s + expert_bias
+                   (held, fp32; under ``stop_gradient``: it selects only)
+           g_e = routed_scaling_factor s_e / (sum of the picked s + 1e-6)
+           h2 = h1 + sum over the picks whose expert is HELD here of
+                     g_e (silu(u . w_gate_e) * (u . w_up_e)) . w_down_e
+
+The three groups are read where the in-projection left them, and the
+convolution's backward writes dB | dCg | dX as the one array that
+product's backward reads.
 """
 
 from __future__ import annotations
@@ -94,10 +124,11 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 KINDS = ("attention", "mamba", "mla_dense", "mla_moe", "gqa_moe_global",
-         "gqa_moe_window")
+         "gqa_moe_window", "conv_dense", "conv_moe", "gqa_qknorm_moe")
 MLA_KINDS = ("mla_dense", "mla_moe")
 GQA_MOE_KINDS = ("gqa_moe_global", "gqa_moe_window")
-ROUTED_KINDS = ("mla_moe",) + GQA_MOE_KINDS
+CONV_KINDS = ("conv_dense", "conv_moe", "gqa_qknorm_moe")
+ROUTED_KINDS = ("mla_moe",) + GQA_MOE_KINDS + CONV_KINDS[1:]
 
 
 def check(cfg) -> None:
@@ -120,6 +151,8 @@ def check(cfg) -> None:
                 "shares ONE B/C group among the heads")
     if set(cfg.layer_types) & set(GQA_MOE_KINDS):
         _check_gqa_moe(cfg)
+    if set(cfg.layer_types) & set(CONV_KINDS):
+        _check_conv(cfg)
     if set(cfg.layer_types) & set(MLA_KINDS):
         if not set(cfg.layer_types) <= set(MLA_KINDS):
             raise NotImplementedError(
@@ -188,6 +221,55 @@ def _check_gqa_moe(cfg) -> None:
                 "kind the trunk lacks")
 
 
+def conv_kinds(layer_types, num_dense_layers: int) -> Tuple[str, ...]:
+    """The kinds of a published list of 'conv' / 'full_attention': the
+    first ``num_dense_layers`` layers before the dense MLP, the rest
+    before the expert layer."""
+    def kind(i, t):
+        if t == "conv":
+            return "conv_dense" if i < num_dense_layers else "conv_moe"
+        if t == "full_attention" and i >= num_dense_layers:
+            return "gqa_qknorm_moe"
+        raise NotImplementedError(
+            f"layer {i} is {t!r}"
+            f"{' before a dense MLP' if t == 'full_attention' else ''}: "
+            "with conv_L_cache the trunk has a 'conv' layer before either "
+            "MLP and a 'full_attention' layer before the expert layer")
+    return tuple(kind(i, t) for i, t in enumerate(layer_types))
+
+
+def _check_conv(cfg) -> None:
+    """What the kinds ``conv_dense`` / ``conv_moe`` / ``gqa_qknorm_moe``
+    must state."""
+    if not set(cfg.layer_types) <= set(CONV_KINDS):
+        raise NotImplementedError(
+            f"layer_types {sorted(set(cfg.layer_types))}: the kinds "
+            f"{CONV_KINDS} are one model's layers and mix with no other")
+    if cfg.conv_L_cache < 1:
+        raise ValueError("a 'conv_*' layer needs conv_L_cache, the taps")
+    if cfg.n_shared_experts or cfg.hc_mult != 1:
+        raise NotImplementedError(
+            f"n_shared_experts={cfg.n_shared_experts}, hc_mult="
+            f"{cfg.hc_mult}: the kinds {CONV_KINDS} have no shared expert "
+            "and one residual stream")
+    if not set(cfg.layer_types) & set(ROUTED_KINDS):
+        return
+    if not (0 < cfg.num_experts_per_tok <= cfg.n_routed_experts
+            and 0 < cfg.experts_held and 0 <= cfg.expert_first
+            and cfg.expert_first + cfg.experts_held <= cfg.n_routed_experts
+            and cfg.moe_intermediate_size > 0):
+        raise ValueError(
+            "a 'conv_moe' / 'gqa_qknorm_moe' layer needs n_routed_experts "
+            "(the router's width), num_experts_per_tok, "
+            "moe_intermediate_size, and the share: experts_held from "
+            "expert_first on, inside the published count")
+    if not cfg.use_expert_bias:
+        raise NotImplementedError(
+            "use_expert_bias=False: the router of these kinds picks by "
+            "its scores plus a held bias; without one it is ops/moe's "
+            "rule 'sigmoid' at another epsilon, which no kind here takes")
+
+
 def check_layout(cfg, mesh, pp: int) -> None:
     """Layers by kind run on one device or, without a state-space kind,
     not at all split: what is missing is named, nothing runs wrong."""
@@ -200,10 +282,12 @@ def check_layout(cfg, mesh, pp: int) -> None:
             "runs of its own), the Mamba-2 mixer no head-parallel "
             "projections (mp), no state hand-over between sequence shards "
             "(sep) and its kernel no shard_map over the batch (dp, "
-            "sharding); 'mla_dense' / 'mla_moe' / 'gqa_moe_*' no exchange "
-            "of routed rows between the devices that share a layer's "
-            "experts and no shard_map around flash_attention_split and "
-            "the grouped products; one device runs it")
+            "sharding); 'mla_dense' / 'mla_moe' / 'gqa_moe_*' / 'conv_moe' "
+            "/ 'gqa_qknorm_moe' no exchange of routed rows between the "
+            "devices that share a layer's experts and no shard_map around "
+            "flash_attention_split, the grouped products and the gated "
+            "short convolution of 'conv_dense' / 'conv_moe'; one device "
+            "runs it")
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +306,8 @@ def kind_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     if kind in MLA_KINDS:
         return _mla_shapes(cfg, kind)
     dense = _block_shapes(cfg)
+    if kind in CONV_KINDS:
+        return _conv_shapes(cfg, kind, dense)
     if kind in GQA_MOE_KINDS:
         c, f, e = (cfg.hidden_size, cfg.moe_intermediate_size,
                    cfg.experts_held)
@@ -243,6 +329,25 @@ def kind_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
+def _conv_shapes(cfg, kind: str, dense) -> Dict[str, Tuple[int, ...]]:
+    c, d = cfg.hidden_size, cfg.head_dim
+    if kind == "gqa_qknorm_moe":
+        out = {nm: dense[nm] for nm in ("ln1", "wq", "wk", "wv", "wo")}
+        out.update({"q_layernorm": (d,), "k_layernorm": (d,)})
+    else:
+        out = {"ln1": (c,), "w_in": (c, 3 * c),
+               "conv_w": (c, cfg.conv_L_cache), "w_out": (c, c)}
+    out["ln2"] = (c,)
+    if kind == "conv_dense":
+        out.update({nm: dense[nm] for nm in ("w_gate", "w_up", "w_down")})
+        return out
+    f, e = cfg.moe_intermediate_size, cfg.experts_held
+    out.update({"w_router": (c, cfg.n_routed_experts),
+                "expert_bias": (cfg.n_routed_experts,),
+                "we_gate_up": (e, c, 2 * f), "we_down": (e, f, c)})
+    return out
+
+
 def layers_of(cfg, kind: str) -> int:
     return cfg.layer_types.count(kind)
 
@@ -261,6 +366,17 @@ def block_specs(cfg) -> Dict[str, Dict[str, P]]:
         for kind in dict.fromkeys(cfg.layer_types)}
 
 
+# The seeded ``expert_bias``: of the order of the gap between a token's
+# fourth and fifth sigmoid score of 64 (logits of unit size: ~0.016 in
+# the mean), so that a measurable share of the picks is the bias's doing
+# — and no larger, because a bias moves the LOAD: one expert's picks
+# change by ~29 % a 0.02 of bias, so at std 0.02 the pairs a chip's 16
+# experts keep spread by +-5.5 % from seed to seed (and its step time
+# with them: PERF.md section 6, PR 48), at 0.005 by +-1.6 %, at none by
+# +-0.9 %.
+EXPERT_BIAS_STD = 0.005
+
+
 def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     """``layers`` layers of one leaf, stacked.  Matrices normal at
     1/sqrt(hidden) (the latent-attention and ``gqa_moe_*`` kinds': at
@@ -270,14 +386,22 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     and D ones, the convolution uniform in +-1 /
     sqrt(d_conv), ``A_log = log U[1, 16]`` and ``dt_bias`` the inverse
     softplus of a step log-uniform in [1e-3, 1e-1] (the Mamba-2
-    reference's: decays neither 0 nor 1)."""
+    reference's: decays neither 0 nor 1).  The short-convolution kinds':
+    the taps normal at 1/sqrt(conv_L_cache), so that the convolution's
+    result is of its input's size, and ``expert_bias`` normal at
+    ``EXPERT_BIAS_STD`` — seeded NON-ZERO, since the rule that would move
+    it off zero (the balance update) is not built."""
     shape = (layers,) + kind_shapes(cfg, kind)[name]
     f32 = jnp.float32
-    if name in ("ln1", "ln2", "gate_norm", "D", "q_norm", "kv_norm") \
-            or name.endswith("_alpha"):
+    if name in ("ln1", "ln2", "gate_norm", "D", "q_norm", "kv_norm",
+                "q_layernorm", "k_layernorm") or name.endswith("_alpha"):
         out = jnp.ones(shape, f32)
     elif name.endswith("_b"):
         out = jnp.zeros(shape, f32)
+    elif name == "expert_bias":
+        out = jax.random.normal(key, shape, f32) * EXPERT_BIAS_STD
+    elif name == "conv_w" and kind in CONV_KINDS:
+        out = jax.random.normal(key, shape, f32) / math.sqrt(shape[-1])
     elif name in ("conv_w", "conv_b"):
         bound = 1.0 / math.sqrt(cfg.mamba_d_conv)
         out = jax.random.uniform(key, shape, f32, -bound, bound)
@@ -290,7 +414,7 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     else:
         # a matrix: normal at 1/sqrt(its rows), the width it contracts
         out = jax.random.normal(key, shape, f32) / math.sqrt(
-            shape[-2] if kind in MLA_KINDS + GQA_MOE_KINDS
+            shape[-2] if kind in MLA_KINDS + GQA_MOE_KINDS + CONV_KINDS
             else cfg.hidden_size)
     return out.astype(dtype or cfg.param_dtype)
 
@@ -471,7 +595,8 @@ def _routing(bp, x, cfg, rule: str = "sigmoid"):
     rows = x.reshape(-1, x.shape[-1])
     with jax.named_scope("moe_route"):
         idx, gate = moe.route(rows, bp["w_router"], cfg.num_experts_per_tok,
-                              cfg.routed_scaling_factor, rule)
+                              cfg.routed_scaling_factor, rule,
+                              bp.get("expert_bias"))
     with jax.named_scope("moe_dispatch"):
         return gate, moe.plan(idx, cfg.expert_first, cfg.experts_held,
                               cfg.n_routed_experts)
@@ -515,6 +640,54 @@ def _gqa_moe_block(bp, x, cfg, mesh=None, seg=None, *, window: bool):
                           @ bp["wo"].astype(cfg.dtype), cfg)
         u = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
         return _residual(x, _expert_layer(bp, u, cfg, routing, "relu"), cfg)
+
+
+def _short_conv(bp, y, cfg):
+    """The convolution operator on y [b, s, C] (normed) -> [b, s, C]."""
+    from ..ops.pallas import causal_conv
+    dt = cfg.dtype
+    with jax.named_scope("conv_in_proj"):
+        bcx = y @ bp["w_in"].astype(dt)
+        kernel = causal_conv.takes_gated(bcx, bp["conv_w"])
+        if kernel:
+            # a kernel takes an array row-major: the product is to leave
+            # it so
+            bcx = with_layout_constraint(
+                bcx, Layout(major_to_minor=(0, 1, 2)))
+    with jax.named_scope("short_conv"):
+        op = causal_conv.short_conv_gated if kernel \
+            else causal_conv.short_conv_gated_xla
+        v = op(bcx, bp["conv_w"])
+    with jax.named_scope("conv_out_proj"):
+        return v @ bp["w_out"].astype(dt)
+
+
+def _conv_block(bp, x, cfg, mesh=None, seg=None):
+    """One ``conv_dense`` / ``conv_moe`` / ``gqa_qknorm_moe`` layer on x
+    [b, s, C] (the module docstring has the equations): which operator
+    and which MLP follow from the leaves the layer holds."""
+    from .llama_pretrain import (_attention, _ffn, _qkv, _residual,
+                                 _rms_norm)
+    b, s, _ = x.shape
+    with jax.named_scope("block"):
+        if "w_in" in bp:
+            y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+            x = _residual(x, _short_conv(bp, y, cfg), cfg)
+        else:
+            with jax.named_scope("attn_qkv"):
+                y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+            q, k, v = _qkv(bp, y, cfg, mesh, rotate=True)
+            with jax.named_scope("attn"):
+                attn = _attention(q, k, v, cfg, mesh, seg)
+            with jax.named_scope("attn_out"):
+                x = _residual(x, attn.reshape(b, s, -1)
+                              @ bp["wo"].astype(cfg.dtype), cfg)
+        if "w_router" not in bp:
+            with jax.named_scope("mlp"):
+                return _ffn(bp, x, cfg)
+        u = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
+        return _residual(x, _expert_layer(
+            bp, u, cfg, _routing(bp, u, cfg, "sigmoid_biased_picks")), cfg)
 
 
 class _Mixer(NamedTuple):
@@ -746,12 +919,15 @@ def trunk(blocks, x, cfg, mesh):
             "gqa_moe_global": functools.partial(_gqa_moe_block,
                                                 window=False),
             "gqa_moe_window": functools.partial(_gqa_moe_block,
-                                                window=True)}
+                                                window=True),
+            "conv_dense": _conv_block, "conv_moe": _conv_block,
+            "gqa_qknorm_moe": _conv_block}
     # the kinds whose blocks call the flash kernels (``check``: latent
     # attention does not mix with the others); their layers together
     # count against FLASH_KEPT_BYTES, at a head's value width
     mla = cfg.layer_types[0] in MLA_KINDS
-    flash_kinds = MLA_KINDS if mla else ("attention",) + GQA_MOE_KINDS
+    flash_kinds = MLA_KINDS if mla else ("attention", "gqa_qknorm_moe") \
+        + GQA_MOE_KINDS
     keep_flash = keeps_flash_outputs(
         x.shape[0], x.shape[1], cfg.num_attention_heads,
         cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,

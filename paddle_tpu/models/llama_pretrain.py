@@ -142,6 +142,19 @@ class LlamaPretrainConfig:
     sliding_window_layout: Optional[Tuple[int, ...]] = None
     sliding_window_size: int = 0
     moe_primary_router_apply_softmax: bool = False
+    # GATED SHORT-CONVOLUTION LAYERS AMONG GQA LAYERS WITH PER-HEAD Q/K
+    # NORMS, A DENSE LEAD, THEN EXPERT LAYERS PICKED BY A BIAS (LFM2's
+    # ``lfm2_moe``; hybrid_trunk's kinds 'conv_dense' / 'conv_moe' /
+    # 'gqa_qknorm_moe'): ``conv_L_cache`` > 0 reads ``layer_types`` as the
+    # published list of 'conv' / 'full_attention' — the first
+    # ``num_dense_layers`` before the dense MLP of ``intermediate_size``,
+    # the rest before ``n_routed_experts`` experts of
+    # ``moe_intermediate_size`` (the share as above) whose router takes
+    # the top ``num_experts_per_tok`` of sigmoid scores PLUS the held
+    # ``expert_bias`` (``use_expert_bias``) and gates by the scores alone.
+    conv_L_cache: int = 0               # the short convolution's taps
+    num_dense_layers: int = 0
+    use_expert_bias: bool = False
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
@@ -163,9 +176,13 @@ class LlamaPretrainConfig:
             cut = lambda a: a if a is None else tuple(
                 a[:self.num_hidden_layers])
             self.layer_types = cut(self.layer_types)
+            from . import hybrid_trunk
+            if self.conv_L_cache and not set(self.layer_types) & set(
+                    hybrid_trunk.KINDS):        # the published names
+                self.layer_types = hybrid_trunk.conv_kinds(
+                    self.layer_types, self.num_dense_layers)
             self.rope_layout = cut(self.rope_layout)
             self.sliding_window_layout = cut(self.sliding_window_layout)
-            from . import hybrid_trunk
             hybrid_trunk.check(self)
         if self.position_embedding_type not in ("rope", "nope"):
             raise ValueError(
@@ -462,7 +479,8 @@ def _qkv(bp: Dict[str, Any], y, cfg: LlamaPretrainConfig,
     """The projections of y = ln1(x), rotated where ``rotate`` says (a
     configuration's one ``position_embedding_type``, or a kind's own
     rule: hybrid_trunk's window layers rotate, its global ones do
-    not)."""
+    not); q and k normed a head first where the layer holds
+    ``q_layernorm`` / ``k_layernorm``."""
     b, s, h = y.shape
     n, d = cfg.num_attention_heads, cfg.head_dim
     nkv = cfg.num_key_value_heads
@@ -475,6 +493,12 @@ def _qkv(bp: Dict[str, Any], y, cfg: LlamaPretrainConfig,
             # the kernels score at 1/sqrt(d): the rest of the
             # configuration's own scale rides on q
             q = q * (cfg.attention_multiplier * math.sqrt(d))
+    if "q_layernorm" in bp:
+        # a kind that holds them: every head of q and of k normed over
+        # its own ``head_dim``, BEFORE the rotation
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, bp["q_layernorm"], cfg.rms_norm_eps)
+            k = _rms_norm(k, bp["k_layernorm"], cfg.rms_norm_eps)
     if rotate:
         with jax.named_scope("rope"):
             q, k = _rope(q, k, cfg.rope_theta, mesh)

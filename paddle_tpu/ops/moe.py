@@ -6,8 +6,10 @@ The device holds ``held`` consecutive experts, ``first .. first + held
 published experts (:func:`route`: sigmoid scores, the top ``k``, gates
 normalised over the ``k`` picks whether their experts are held here or
 not — or, the second rule, the top ``k`` of the LOGITS and a softmax
-over the picked), keeps the (token, pick) pairs whose expert it holds, and computes
-those experts' part of the layer's result.  What the absent experts
+over the picked — or, the third, the top ``k`` of the sigmoid scores
+PLUS A BIAS that selects and gates nothing), keeps the (token, pick)
+pairs whose expert it holds, and computes those experts' part of the
+layer's result.  What the absent experts
 would add is left out: on one device the layer runs without its
 exchange, and nothing stands in for the other devices.
 
@@ -119,7 +121,7 @@ def _padded(pairs: int, held: int) -> int:
     return -(-pairs // TILE_M) * TILE_M + held * TILE_M
 
 
-RULES = ("sigmoid", "softmax_of_picks")
+RULES = ("sigmoid", "softmax_of_picks", "sigmoid_biased_picks")
 ACTIVATIONS = ("silu", "relu")
 
 # The names :func:`route` and :func:`plan` hand their results on under
@@ -128,20 +130,29 @@ ACTIVATIONS = ("silu", "relu")
 ROUTING_NAMES = ("moe_scores", "moe_picks", "moe_plan")
 
 
-def route(x, w_router, k: int, scale: float, rule: str = "sigmoid"):
+def route(x, w_router, k: int, scale: float, rule: str = "sigmoid",
+          bias=None):
     """x [T, C], w_router [C, published] -> the picks ``idx [T, k]`` and
-    their gates ``[T, k]`` (fp32), by one of two rules (static):
+    their gates ``[T, k]`` (fp32), by one of three rules (static):
 
     ``sigmoid``: ``scale * s_e / (sum of the picked s + 1e-20)``, ``s =
     sigmoid(x . w_router)``, the top ``k`` of s.
     ``softmax_of_picks``: the top ``k`` of the LOGITS ``z = x .
     w_router``, ``scale * softmax(z over the k picked)`` — the gates sum
     to ``scale`` whichever experts are held here.
+    ``sigmoid_biased_picks``: the top ``k`` of ``s + bias`` (``bias
+    [published]``: it SELECTS, gates nothing and reads no gradient),
+    ``scale * s_e / (sum of the picked s + 1e-6)`` with the scores
+    themselves — a pick's gate may so be smaller than the gate of an
+    expert the bias passed over.
 
     The product is fp32 either way — a pick is a comparison of scores,
     so the scores take no rounding they need not."""
     if rule not in RULES:
         raise ValueError(f"route: rule {rule!r}, one of {RULES}")
+    if (bias is None) != (rule != "sigmoid_biased_picks"):
+        raise ValueError(f"route: rule {rule!r} with"
+                         f"{'out' if bias is None else ''} a bias")
     z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)
     if rule == "softmax_of_picks":
@@ -149,9 +160,18 @@ def route(x, w_router, k: int, scale: float, rule: str = "sigmoid"):
         return idx, scale * jax.nn.softmax(top, axis=-1)
     # the sigmoid's derivative reads the scores, the product's does not
     s = jax.nn.sigmoid(checkpoint_name(z, ROUTING_NAMES[0]))
-    top, idx = _top_k(s, k)
-    gate = scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
-    return idx, gate
+    if rule == "sigmoid":
+        top, idx = _top_k(s, k)
+        return idx, scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+    # nothing flows back through a pick: the named picks take the scores
+    # they gate by — as a masked sum over the row, not a gather (T * k
+    # scalars out of [T, published] cost a v5e 0.5–1.6 ms a pass as a
+    # gather, and its scatter again backward)
+    _, idx = _named_top_k(jax.lax.stop_gradient(
+        s + bias.astype(jnp.float32)), k)
+    picked = idx[..., None] == jnp.arange(s.shape[-1], dtype=I32)
+    top = jnp.sum(jnp.where(picked, s[:, None, :], 0.0), axis=-1)
+    return idx, scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-6)
 
 
 def _named_top_k(scores, k: int):

@@ -178,14 +178,18 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
     # PR 44 appended its cell to the lists that apply to it, and one
     # ratio of its own: the windowed kernels' declared work
     window = "smallthinker-21b-a3b.pretrain-16k-moe"
-    every = [DENSE, HYBRID, EXPERT, window]
+    # ... and PR 48 its cell likewise, with the gated short convolution's
+    # declared bytes
+    conv = "lfm2-24b-a2b.pretrain-8k-conv-moe"
+    every = [DENSE, HYBRID, EXPERT, window, conv]
     assert {n: m["workloads"] for n, m in new.items()} == {
         "kernel_undeclared_pct.train": every,
         "flops_declared_per_needed.train": every,
         "flash_attn_declared_per_needed.train": every,
         "ssd_scan_bytes_declared_per_needed.train": [HYBRID],
-        "moe_experts_declared_per_needed.train": [EXPERT, window],
-        "flash_win_declared_per_needed.train": [window]}
+        "moe_experts_declared_per_needed.train": [EXPERT, window, conv],
+        "flash_win_declared_per_needed.train": [window],
+        "short_conv_bytes_declared_per_needed.train": [conv]}
     for m in new.values():
         assert (m["source"], m["moves"], m["better"], m["layer"]) == (
             "program_counter", "train_tok_s_chip", "lower", "kernels")
@@ -197,4 +201,6 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
             if "declared" in m["name"]] == list(new)
     assert [m["name"] for m in bench["per_layer"][22:]] == [
         "flash_win_roofline_pct.train",
-        "flash_win_declared_per_needed.train"]
+        "flash_win_declared_per_needed.train",
+        "short_conv_mixer_pct.train", "short_conv_roofline_pct.train",
+        "short_conv_bytes_declared_per_needed.train"]

@@ -2,7 +2,8 @@
 compiled for a DESCRIBED TPU v5e with no chip attached, and the kernels
 only the two expert cells' shapes reach — the split flash form and the
 mixers' four kernels at the expert cell's, both flash forms at the window
-cell's 16k row (fixtures and rules: ``tests/_tpu_compile.py``; the other
+cell's 16k row, and the convolution cell's step with its two kernels
+(fixtures and rules: ``tests/_tpu_compile.py``; the other
 kernels and ``routed_ffn`` alone: ``tests/test_tpu_compile.py``; the
 window, dense and hybrid cells' steps: ``tests/test_tpu_compile_cells.py``).
 """
@@ -15,8 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from _tpu_compile import (KERNEL, ROWS_8K, _cell_step, _flash_module,  # noqa: F401
-                          _padded_from, _routing_sorts, _sds, _text,
-                          compiled, one_chip, topo)
+                          _padded_from, _placed, _routing_sorts, _sds,
+                          _text, compiled, one_chip, topo)
 
 
 def test_train_step_of_the_expert_cell(one_chip, compiled):
@@ -82,6 +83,53 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # 392,691,712 B to 11,188,912,640 (PERF.md section 6); + 64,929,280
     # with the four layers' routing kept (PR 46: 17,408,482,816 before)
     assert ma.temp_size_in_bytes <= 17_473_412_096
+
+
+def test_train_step_of_the_convolution_cell(one_chip, compiled):
+    """The step of ``lfm2-24b-a2b.pretrain-8k-conv-moe`` as the benchmark
+    builds it — a dense lead and one period (attention, three convolution
+    layers), every published width, 16 of 64 experts, 2 x 8192 tokens —
+    fits a described v5e with NO compiler rematerialization (depth 9,
+    ISSUE 48's first choice, is refused: 15.91G of 15.75G, 4.5G of it the
+    copies of each kind's two runs' slices of its stack), runs the
+    operator's middle as ``short_conv_fwd`` / ``short_conv_bwd`` on the
+    in-projection's ``[2, 8192, 6144]`` WHERE IT LIES, and routes once a
+    layer a step."""
+    from benchmark import harness
+    cell = harness.find_cell("lfm2-24b-a2b.pretrain-8k-conv-moe")
+    assert cell.conf["num_hidden_layers"] == 5 and \
+        (cell.traffic["batch"], cell.traffic["seq"]) == ROWS_8K
+    c = _cell_step(one_chip, cell.name)
+    text = c.as_text()
+    call = lambda kernel: len(re.findall(
+        rf'custom_call_target="tpu_custom_call".*/{kernel}/pallas_call',
+        text))
+    # a convolution kind's loop: the forward, the recompute's, the backward
+    assert (call("short_conv_fwd"), call("short_conv_bwd")) == (4, 2)
+    # the attention layer: full remat keeps ``flash_fwd``'s outputs, and
+    # the backward at 32 / 8 heads of 64, S 8,192 is the query-major one
+    # pass (the hybrid cell's shape); the rotation at head dim 64 is XLA's
+    assert (call("flash_fwd"), call("flash_bwd_dq"), call("flash_bwd_dkv"),
+            call("rope")) == (1, 1, 0, 0)
+    # a routed kind's loop ON EACH OF ITS TWO BOUNDS (36,864 rows where
+    # the load's tiles fit them, 69,632 otherwise): 2 grouped products +
+    # the token side's sum forward, the gate | up product again for the
+    # recompute (its result is what the backward keeps; the layer's
+    # output is read by nothing), 2 products + 2 dw + the sum backward
+    assert text.count(KERNEL) == 3 + (2 + 2 * 9) + (3 + 2 * 9) == 44
+    assert len(re.findall(r" conditional\(", text)) == 2 * 3
+    for rows in (36864, 69632):
+        assert f"bf16[{rows},2048]" in text
+    # the routing is kept (PR 46's rule, for the two new kinds): the
+    # router's ``top_k`` and the plan's two sorts in the forward loops only
+    assert _routing_sorts(text) == (2 * 3, 0)
+    assert ".remat" not in text
+    # no array of the operator's widths is only sliced, copied, padded or
+    # joined between the in-projection and the kernels, or behind them
+    assert not _placed(text, ROWS_8K, {6144})
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes == 3_157_483_008
+    assert ma.temp_size_in_bytes <= 9_717_807_104
 
 
 @pytest.mark.parametrize("kernels", [2, 3])

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu  # noqa: F401  (x64 before any array)
-from paddle_tpu.ops.pallas.causal_conv import causal_conv_silu
+from paddle_tpu.ops.pallas.causal_conv import (causal_conv_silu,
+                                               short_conv_gated)
 from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
                                                    flash_attention_split)
 from paddle_tpu.ops.pallas.flash_varlen import flash_attention_segmented
@@ -105,6 +106,7 @@ SSD = (z((1, 2, 256, 4, 64)), z((1, 2, 256, 4), F32), z((1, 2, 256, 4), F32),
        z((1, 2, 256, 128)), z((1, 2, 256, 128)))
 XBC = (z((1, 512, 512)), z((1, 2, 256, 4), F32), z((1, 2, 256, 4), F32))
 CONV = (z((2, 1024, 768)), z((512, 4), F32), z((512,), F32))
+GATED = (z((2, 1024, 768)), z((256, 3), F32))
 POOL = z((64, 2, 16, 128))
 PAGED = (z((4, 8, 128)), POOL, POOL, z((4, 6), I32), z((4,), I32))
 PAGED_Q8 = (z((4, 8, 128)), z(POOL.shape, I8), z(POOL.shape, I8),
@@ -253,6 +255,18 @@ CASES = [
     # halos; tables; [2, 8, 512] fp32 sums
     ("causal_conv_bwd", "causal_conv_bwd", grad_of(_conv, 3), CONV,
      34_865_152, 6_459_392, 1_064_960),
+    # B | Cg | X of 256 channels in [2, 1024, 768], 3 taps: B X, the taps
+    # and the gate, 7 an output; four [2, 1024, 256] bf16 passes, 2 tiles x
+    # 8 halo rows a batch row of B and of X, the fp32 taps [8, 256] once
+    # (one channel tile)
+    ("short_conv_fwd", "short_conv_fwd", short_conv_gated, GATED,
+     3_670_016, 4_235_264, 0),
+    # 21 an element of a tile's own rows (the convolution again, g Cg,
+    # what the taps hand back, dB dCg dX, dw's sums), 1 on the 8 rows after
+    # each of 4 tiles; seven passes, four halos, the taps, [2, 8, 256] fp32
+    # sums
+    ("short_conv_bwd", "short_conv_bwd", grad_of(short_conv_gated, 2), GATED,
+     11_018_240, 7_430_144, 0),
     # the bound it is launched at: ALL 8 tiles of 256 rows; x once a
     # 1024-column panel (2), the fp32 stack once, the result
     ("grouped_mm, 2,048 rows", "grouped_mm", grouped_mm,

@@ -3,8 +3,8 @@ in the policy of ``models/llama_pretrain._remat_wrap`` for the kinds
 that route): under the trunk's one checkpoint boundary the router's
 product, ``top_k`` and the plan's two sorts are in the gradient's program
 once a run of layers — twice with the names left out of the policy — and
-the loss and every gradient are the same bits either way, for both
-routing rules and both families' blocks at toy size.  And what the
+the loss and every gradient are the same bits either way, for the three
+routing rules and three families' blocks at toy size.  And what the
 routed path keeps for its backward, the gate | up product, is written
 once: by the kernel, into an array of the one shape both branches of the
 ``cond`` give, with no pad.
@@ -31,12 +31,14 @@ TOY = os.path.join(harness.HERE, "tests", "toy")
 SEQ, ROWS = 128, 1
 # family -> (toy configuration, its job, the layers kept): one routed
 # layer (rule ``sigmoid``, four streams, a shared expert) and two runs
-# of one (``softmax_of_picks``)
+# of one (``softmax_of_picks``; ``sigmoid_biased_picks``)
 FAMILIES = {
     "xing_mhc_moe": ("config_xing.json", "train_job.json", ("mla_moe",)),
     "smallthinker_moe": ("config_smallthinker.json",
                          "train_job_smallthinker.json",
                          ("gqa_moe_global", "gqa_moe_window")),
+    "lfm2_conv_moe": ("config_lfm2.json", "train_job_lfm2.json",
+                      ("gqa_qknorm_moe", "conv_moe")),
 }
 
 
@@ -142,7 +144,10 @@ def test_the_kept_routing_gives_the_recomputed_routing_s_bits(family):
     assert np.isfinite(float(kept)) and float(kept) == float(again)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept_g),
                             jax.tree_util.tree_leaves(again_g)):
-        assert float(jnp.max(jnp.abs(a))) > 0, jax.tree_util.keystr(path)
+        # a bias that selects reads no gradient, by construction
+        assert (float(jnp.max(jnp.abs(a))) > 0) \
+            != ("expert_bias" in jax.tree_util.keystr(path)), \
+            jax.tree_util.keystr(path)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=jax.tree_util.keystr(path))
 
@@ -157,6 +162,8 @@ def test_the_picks_derivative_is_lax_top_k_s(rule):
     x = jax.random.normal(key, (T, c), jnp.float32)
     w = jax.random.normal(jax.random.fold_in(key, 1), (c, pub)) / c ** 0.5
     co = jax.random.normal(jax.random.fold_in(key, 2), (T, k), jnp.float32)
+    bias = 0.02 * jax.random.normal(jax.random.fold_in(key, 3), (pub,)) \
+        if rule == "sigmoid_biased_picks" else None
 
     def plain(x, w):
         z = jnp.dot(x, w.astype(jnp.float32),
@@ -164,14 +171,21 @@ def test_the_picks_derivative_is_lax_top_k_s(rule):
         if rule == "softmax_of_picks":
             top, idx = jax.lax.top_k(z, k)
             return idx, scale * jax.nn.softmax(top, axis=-1)
-        top, idx = jax.lax.top_k(jax.nn.sigmoid(z), k)
+        s = jax.nn.sigmoid(z)
+        if bias is not None:
+            _, idx = jax.lax.top_k(jax.lax.stop_gradient(s + bias), k)
+            top = jnp.take_along_axis(s, idx, axis=-1)
+            return idx, scale * top / (jnp.sum(top, -1, keepdims=True)
+                                       + 1e-6)
+        top, idx = jax.lax.top_k(s, k)
         return idx, scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
 
     def gates_and_grads(route):
         gate, pull, idx = jax.vjp(lambda x, w: route(x, w)[::-1], x, w,
                                   has_aux=True)
         return (idx, gate) + pull(co)
-    named = gates_and_grads(lambda x, w: moe.route(x, w, k, scale, rule))
+    named = gates_and_grads(
+        lambda x, w: moe.route(x, w, k, scale, rule, bias))
     for a, b in zip(named, gates_and_grads(plain)):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

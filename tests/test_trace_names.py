@@ -311,6 +311,70 @@ def test_train_step_of_the_window_kinds_carries_the_family_s_scopes():
         (BLOCK - {"mlp"}) | {"attn"} | set(smallthinker_moe.SCOPES))
 
 
+def test_train_step_of_the_conv_kinds_carries_the_family_s_scopes():
+    """``conv_dense`` / ``conv_moe`` / ``gqa_qknorm_moe``: the operator's
+    three scopes with its two kernels under ``short_conv``, ``qk_norm``
+    between ``attn_qkv`` and ``rope`` in the attention layer, the four
+    ``moe_*`` of the family ``lfm2_conv_moe`` — no ``moe_shared`` —,
+    ``mlp`` in the dense lead alone, nothing of a block under no scope."""
+    from benchmark.models import lfm2_conv_moe
+    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
+               intermediate_size=256, num_hidden_layers=5,
+               num_attention_heads=4, num_key_value_heads=2, max_seq_len=256,
+               layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+               num_dense_layers=1, conv_L_cache=3, use_expert_bias=True,
+               moe_intermediate_size=128, n_routed_experts=8,
+               experts_held=2, expert_first=2, num_experts_per_tok=3,
+               tie_word_embeddings=True, use_pallas_attention=True)
+    assert cfg.layer_types == ("conv_dense", "gqa_qknorm_moe") \
+        + ("conv_moe",) * 3
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = BLOCK | {"embed", "layer_scan", "attn", "loss_head",
+                    "optimizer"} | set(lfm2_conv_moe.SCOPES)
+    assert want <= names, want - names
+    assert not {"moe_shared", "hc_pre", "mla_q", "ssm_conv"} & names
+    assert lfm2_conv_moe.SCOPES == (
+        "conv_in_proj", "short_conv", "conv_out_proj", "qk_norm",
+        "moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+    assert lfm2_conv_moe.KERNELS == (
+        "short_conv_fwd", "short_conv_bwd", "grouped_mm", "grouped_mm_dw",
+        "moe_sum_pairs")
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    vocabulary = xplane_meta.KERNELS + lfm2_conv_moe.KERNELS
+    for scope, kernel in (("short_conv", "short_conv_fwd"),
+                          ("short_conv", "short_conv_bwd"),
+                          ("moe_experts", "grouped_mm"),
+                          ("moe_experts", "grouped_mm_dw"),
+                          ("moe_combine", "moe_sum_pairs"),
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dkv")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            vocabulary) == kernel
+    # the hybrid cell's convolution is another kernel under another name
+    assert xplane_meta.kernel_of(
+        "jit(step)/block/short_conv/short_conv_fwd/pallas_call",
+        xplane_meta.KERNELS + ("causal_conv_fwd",)) == ""
+    names_of = xplane_meta.SCOPES + lfm2_conv_moe.SCOPES
+    inside = [p for p in paths if "/block/" in p or p.startswith("block/")]
+    assert len(inside) > 200
+    assert {xplane_meta.scope_of(p, names_of) for p in inside} <= (
+        BLOCK | {"attn"} | set(lfm2_conv_moe.SCOPES))
+    # the norms of q and k stand before the rotation: ``qk_norm``'s ops
+    # feed ``rope``'s, in the one attention layer
+    assert any("/qk_norm/" in p for p in inside) and \
+        any("/rope/" in p for p in inside)
+
+
 @pytest.mark.parametrize("on_load,on_all", [(6, 0), (4, 2), (0, 0)])
 def test_moe_bounds_counts_the_passes_by_their_bound(on_load, on_all):
     """``tools/moe_bounds.py``: a pass of the routed path is two
@@ -594,8 +658,8 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     # ``flash_attention_split`` runs through the dense kernels' three
     # call sites: a name is one site — but for the windowed form, which
     # runs the same three sites on fewer block pairs under names of its
-    # own (24 sites, 27 names)
-    assert len(names) == 27 and len(set(names)) == 27
+    # own (26 sites, 29 names)
+    assert len(names) == 29 and len(set(names)) == 29
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family
@@ -605,7 +669,7 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
                    "causal_conv_bwd", "grouped_mm", "grouped_mm_dw",
                    "moe_sum_pairs", "flash_win_fwd", "flash_win_bwd_dq",
-                   "flash_win_bwd_dkv"}
+                   "flash_win_bwd_dkv", "short_conv_fwd", "short_conv_bwd"}
     assert not own & set(xplane_meta.KERNELS)
     ahead = set(AHEAD_OF_EVERY_FAMILY)
     assert set(names) <= set(xplane_meta.KERNELS) | own | ahead
