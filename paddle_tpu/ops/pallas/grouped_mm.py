@@ -21,12 +21,41 @@ VMEM already, so a product reads each expert's weights once.  The
 weights come AS THE OPTIMIZER HOLDS THEM (fp32): an expert's first tile
 casts its panel to the rows' dtype into VMEM scratch, so no bf16 copy of
 the stack exists in HBM, and ``grouped_mm_dw`` adds a group's tiles up
-in the fp32 output block itself, which leaves for HBM when the next tile
-is another expert's — the gradient in fp32, no rounding on the way.  An
+in an fp32 block in VMEM, which leaves for HBM when the next tile is
+another expert's — the gradient in fp32, no rounding on the way.  An
 expert WITHOUT A TILE is never visited and its block of dw is never
 written: the layout gives every expert one (``ops/moe.plan``).  Rows of
 a tile past the group's own count are whatever the layout put there:
 the caller keeps them finite going in and zero coming back.
+
+WHEN AN EXPERT'S BLOCK CROSSES between HBM and VMEM is the kernels' own
+business, not the pipeline's that ``pallas_call`` builds around blocked
+operands: a row tile's product takes ``512 K tn / 197e12`` s and the
+fp32 block ``4 K tn / 819e9`` s — 1.88 grid steps whatever K and tn —
+and that pipeline asks for a block ONE step ahead, in the same queue as
+the next row tile, and has an output block back one step behind.  So
+``w`` and ``dw`` stay in HBM (``pl.ANY``), with two fp32 slots in VMEM
+each:
+
+    grouped_mm     a group's FIRST tile waits for the group's panel,
+                   asks for the NEXT group's into the other slot — the
+                   next expert's, after the last expert the first one's
+                   of the next column panel: the layout lists the
+                   experts in order — at the DMA's low priority, behind
+                   the row tiles, and casts its own.  The next panel has
+                   the whole group to arrive (4–7 tiles at the cells'
+                   loads); behind a group of ONE tile it is late and is
+                   waited for.  Exposed: a call's first panel, and every
+                   panel's cast (tried under the last product of the
+                   group before: no faster, PERF.md section 6, PR 49).
+    grouped_mm_dw  a group's first tile WRITES its product over the
+                   slot (no zero-fill), the later ones add; its LAST
+                   tile starts the block's copy to HBM, which is waited
+                   for before the slot is written again, a group later —
+                   and the last two at the call's last grid step.
+
+Tiles past ``n_tiles`` start and wait for nothing.  The arithmetic and
+its order are what blocked operands gave: bit for bit.
 """
 
 from __future__ import annotations
@@ -43,10 +72,11 @@ from ._common import idx32
 
 __all__ = ["TILE_M", "grouped_mm", "grouped_mm_dw"]
 
+I32 = jnp.int32
 TILE_M = 256
 # the widest panel of columns a grid step takes: an expert's [K, tn]
-# fp32 weight block twice (the pipeline's two buffers) and its cast are
-# 37 MiB at K 3584
+# fp32 weight block twice (the two slots) and its cast are 37 MiB at
+# K 3584
 _MAX_COLS = 1024
 _VMEM_LIMIT = 64 << 20
 
@@ -69,15 +99,56 @@ def _held(i, n_ref):
     return jnp.minimum(jnp.int32(i), last)
 
 
-def _mm_kernel(te_ref, n_ref, x_ref, w_ref, o_ref, w_cast, *,
+def _group(te_ref, n_ref, i):
+    """Of the tile ``i`` (one that holds rows): whether it is its
+    group's first, whether its last, its expert, the first and the last
+    expert of the call.  ``ops/moe.plan`` gives every expert a tile and
+    lays them in order, so the experts of a walk over the tiles are
+    ``first .. last`` one after the other."""
+    n, e = n_ref[0], te_ref[i]
+    starts = (i == 0) | (te_ref[jnp.maximum(i - 1, 0)] != e)
+    ends = (i == n - 1) | (te_ref[jnp.minimum(i + 1, n - 1)] != e)
+    return starts, ends, e, te_ref[0], te_ref[jnp.maximum(n - 1, 0)]
+
+
+def _mm_kernel(te_ref, n_ref, x_ref, w_hbm, o_ref, w_f32, w_cast, sem, *,
                trans_w: bool):
-    i = pl.program_id(1).astype(jnp.int32)
+    j = pl.program_id(0).astype(I32)
+    i = pl.program_id(1).astype(I32)
+    tn = o_ref.shape[1]
+
+    def fetch(e, j, slot):
+        cols = pl.ds(pl.multiple_of(j * I32(tn), tn), tn)
+        panel = w_hbm.at[e, cols, :] if trans_w else w_hbm.at[e, :, cols]
+        return pltpu.make_async_copy(panel, w_f32.at[slot], sem.at[slot])
 
     @pl.when(i < n_ref[0])
     def _():
-        @pl.when((i == 0) | (te_ref[jnp.maximum(i - 1, 0)] != te_ref[i]))
+        starts, _, e, first, last = _group(te_ref, n_ref, i)
+
+        @pl.when(starts)
         def _():
-            w_cast[:] = w_ref[:].astype(w_cast.dtype)
+            slot = (j * (last - first + 1) + e - first) % I32(2)
+
+            @pl.when((j == 0) & (i == 0))
+            def _():
+                fetch(e, j, slot).start()     # the one fetch left exposed
+
+            fetch(e, j, slot).wait()
+
+            # the NEXT group's panel, a whole group ahead of its first
+            # product: the next expert's, or the first one's of the next
+            # column panel; the other slot was cast a group ago.  Behind
+            # the row tiles in the DMA queues (priority 1): at the same
+            # priority the panel's 1.88 steps of traffic hold up the
+            # tiles that the next products wait for (timed: PERF.md
+            # section 6, PR 49)
+            @pl.when((e < last) | (j + 1 < pl.num_programs(0)))
+            def _():
+                fetch(jnp.where(e < last, e + 1, first),
+                      jnp.where(e < last, j, j + 1),
+                      I32(1) - slot).start(priority=1)
+            w_cast[:] = w_f32[slot].astype(w_cast.dtype)
         dims = (((1,), (1 if trans_w else 0,)), ((), ()))
         o_ref[:] = jax.lax.dot_general(
             x_ref[:], w_cast[:], dims,
@@ -94,11 +165,7 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
     M, K = x.shape
     N = w.shape[1] if trans_w else w.shape[2]
     tn = _cols(N)
-    w_block = (None, tn, K) if trans_w else (None, K, tn)
-
-    def w_index(j, i, te, n):
-        e = te[_held(i, n)]
-        return idx32(e, j, 0) if trans_w else idx32(e, 0, j)
+    w_block = (tn, K) if trans_w else (K, tn)
     # the BOUND the call is launched at: every tile of M holding rows
     # (how many do is data).  x again for every column panel, every
     # expert's weights once (a panel at a time, as the optimizer holds
@@ -116,10 +183,12 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
             in_specs=[
                 pl.BlockSpec((TILE_M, K),
                              lambda j, i, te, n: idx32(_held(i, n), 0)),
-                pl.BlockSpec(w_block, w_index)],
+                pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(
                 (TILE_M, tn), lambda j, i, te, n: idx32(_held(i, n), j)),
-            scratch_shapes=[pltpu.VMEM(w_block[1:], x.dtype)]),
+            scratch_shapes=[pltpu.VMEM((2,) + w_block, w.dtype),
+                            pltpu.VMEM(w_block, x.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((max(M, out_rows), N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -129,17 +198,48 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
     )(tile_expert, n_tiles, x, w)
 
 
-def _dw_kernel(te_ref, n_ref, x_ref, dy_ref, dw_ref):
-    i = pl.program_id(2).astype(jnp.int32)
+def _dw_kernel(te_ref, n_ref, x_ref, dy_ref, dw_hbm, acc, sem):
+    a, b, i = (pl.program_id(d).astype(I32) for d in range(3))
+    panels = pl.num_programs(0) * pl.num_programs(1)
+    tk, tn = acc.shape[1:]
+
+    def leave(e, a, b, slot):
+        rows = pl.ds(pl.multiple_of(a * I32(tk), tk), tk)
+        cols = pl.ds(pl.multiple_of(b * I32(tn), tn), tn)
+        return pltpu.make_async_copy(acc.at[slot], dw_hbm.at[e, rows, cols],
+                                     sem.at[slot])
 
     @pl.when(i < n_ref[0])
     def _():
-        @pl.when((i == 0) | (te_ref[jnp.maximum(i - 1, 0)] != te_ref[i]))
+        starts, ends, e, first, last = _group(te_ref, n_ref, i)
+        g = (a * pl.num_programs(1) + b) * (last - first + 1) + e - first
+        slot = g % I32(2)
+        # the block that was summed here two groups ago has had the whole
+        # of the group between to leave
+        @pl.when(starts & (g >= 2))
         def _():
-            dw_ref[:] = jnp.zeros_like(dw_ref)
-        dw_ref[:] += jax.lax.dot_general(
+            leave(e, a, b, slot).wait()
+        # a group's first tile writes its product over whatever the slot
+        # holds (nothing is zero-filled, nothing that is there is added)
+        acc[slot] = jnp.where(starts, 0.0, acc[slot]) + jax.lax.dot_general(
             x_ref[:], dy_ref[:], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+        @pl.when(ends)
+        def _():
+            leave(e, a, b, slot).start()
+
+    # the call's end: the last two groups' blocks are still on their way
+    @pl.when((a * pl.num_programs(1) + b == panels - 1)
+             & (i == pl.num_programs(2) - 1) & (n_ref[0] > 0))
+    def _():
+        _, _, e, first, last = _group(te_ref, n_ref, n_ref[0] - 1)
+        groups = panels * (last - first + 1)
+        leave(e, a, b, (groups - 1) % I32(2)).wait()
+
+        @pl.when(groups >= 2)
+        def _():
+            leave(e, a, b, groups % I32(2)).wait()
 
 
 def grouped_mm_dw(x, dy, tile_expert, n_tiles, experts: int):
@@ -166,9 +266,9 @@ def grouped_mm_dw(x, dy, tile_expert, n_tiles, experts: int):
                              lambda a, b, i, te, n: idx32(_held(i, n), a)),
                 pl.BlockSpec((TILE_M, tn),
                              lambda a, b, i, te, n: idx32(_held(i, n), b))],
-            out_specs=pl.BlockSpec(
-                (None, tk, tn),
-                lambda a, b, i, te, n: idx32(te[_held(i, n)], a, b))),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((2, tk, tn), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((experts, K, N), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),

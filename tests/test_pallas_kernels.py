@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as paddle
+from _grouped_rows import laid_out
 from _pallas_flash import _interpret_mode  # noqa: F401
 
 
@@ -172,40 +173,137 @@ def test_quantized_decode_agrees(_interpret_mode):
 
 
 # -- grouped products over the experts held -----------------------------------
-@pytest.mark.parametrize("sizes", [(300, 0, 257, 5), (0, 0, 0, 0),
-                                   (1024, 0, 0, 0)])
-def test_grouped_mm_matches_a_loop_over_the_experts(sizes):
-    """Groups of uneven, zero and tile-crossing sizes, laid out as
-    ``ops/moe.plan`` lays them: each at a multiple of TILE_M, one tile
-    at least."""
+# sizes of the groups, K, N, tiles never used behind the last group
+GROUPED = {
+    "uneven_zero_and_tile_crossing": ((300, 0, 257, 5), 128, 256, 3),
+    "every_group_empty": ((0, 0, 0, 0), 128, 256, 3),
+    "one_group_of_four_tiles": ((1024, 0, 0, 0), 128, 256, 3),
+    # a change of expert EVERY step: the next panel is asked for one
+    # step ahead and must be waited for, a block leaves every step
+    "every_group_one_tile": ((256, 1, 200, 256, 17, 256, 3), 128, 256, 3),
+    "one_expert_only": ((700,), 128, 256, 2),
+    # the last expert hands over to the next column panel's first
+    "two_column_panels": ((300, 0, 257, 5), 128, 2048, 3),
+    "three_column_panels": ((300, 0, 257), 128, 3072, 1),
+    "one_expert_three_column_panels": ((300,), 128, 3072, 2),
+    "no_empty_step": ((300, 0, 257, 5), 128, 256, 0),
+    "no_empty_step_two_panels_one_tile_each": ((256, 9, 256), 128, 2048, 0),
+    "only_empty_tiles_behind_the_last_group": ((5, 300), 128, 256, 9),
+    # grouped_mm_dw with K and N both in two panels of 1,024
+    "dw_two_by_two_panels": ((300, 0, 257, 5), 2048, 2048, 3),
+    "dw_two_by_two_panels_one_tile_each": ((256, 9, 256), 2048, 2048, 0),
+}
+
+
+def _grouped_fp32_against_a_loop(case, seed):
+    """fp32 rows and weights of ``GROUPED[case]`` through the three
+    products, against a loop over the experts."""
     from paddle_tpu.ops.pallas.grouped_mm import (TILE_M, grouped_mm,
                                                   grouped_mm_dw)
-    E, K, N = len(sizes), 128, 256
-    tiles = [max(-(-n // TILE_M), 1) for n in sizes]
-    M = (sum(tiles) + 3) * TILE_M               # three tiles never used
-    te = np.full((M // TILE_M,), E - 1, np.int32)
-    te[:sum(tiles)] = np.repeat(np.arange(E), tiles)
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    sizes, K, N, spare = GROUPED[case]
+    E = len(sizes)
+    M, te_j, n_j, starts, tiles, valid = laid_out(sizes, spare)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     x = jax.random.normal(ks[0], (M, K), jnp.float32)
     dy = jax.random.normal(ks[1], (M, N), jnp.float32)
     w = jax.random.normal(ks[2], (E, K, N), jnp.float32)
-    starts = np.concatenate([[0], np.cumsum(tiles)[:-1]]) * TILE_M
-    valid = np.zeros((M, 1), bool)
-    for e0, n in zip(starts, sizes):
-        valid[e0:e0 + n] = True
     dy = jnp.where(valid, dy, 0)                # rows without a pair: zero
-    te_j, n_j = jnp.asarray(te), jnp.asarray([sum(tiles)], jnp.int32)
     out = grouped_mm(x, w, te_j, n_j)
     dx = grouped_mm(dy, w, te_j, n_j, trans_w=True)
     dw = grouped_mm_dw(x, dy, te_j, n_j, E)
     for e, (e0, t) in enumerate(zip(starts, tiles)):
         rows = slice(e0, e0 + t * TILE_M)
+        # fp32 sums K (N) long
         np.testing.assert_allclose(out[rows], x[rows] @ w[e],
-                                   atol=2e-4, rtol=2e-4)
+                                   atol=2e-4 * K / 128, rtol=2e-4)
         np.testing.assert_allclose(dx[rows], dy[rows] @ w[e].T,
-                                   atol=2e-4, rtol=2e-4)
+                                   atol=2e-4 * N / 256, rtol=2e-4)
         np.testing.assert_allclose(dw[e], x[rows].T @ dy[rows],
                                    atol=2e-3, rtol=2e-4)
+
+
+@pytest.mark.parametrize("case", list(GROUPED))
+def test_grouped_mm_matches_a_loop_over_the_experts(case):
+    """Groups of uneven, zero and tile-crossing sizes, laid out as
+    ``ops/moe.plan`` lays them: each at a multiple of TILE_M, one tile
+    at least — and what the kernels' own walk of the experts' blocks can
+    get wrong: a change every step, one expert, more column panels than
+    one, no empty step, empty steps only behind the last group."""
+    _grouped_fp32_against_a_loop(case, seed=5)
+
+
+@pytest.mark.parametrize("case", ["uneven_zero_and_tile_crossing",
+                                  "every_group_one_tile",
+                                  "two_column_panels",
+                                  "dw_two_by_two_panels"])
+def test_grouped_mm_is_bit_for_bit_a_plain_loop(case):
+    """bf16 rows, fp32 weights: the kernels' results are those of a plain
+    loop over (column panel, tile) in the same dtypes and order — the
+    panel cast to bf16, fp32 accumulation, one rounding at the store; dw
+    a group's tiles summed in tile order in fp32 — forward, ``trans_w``
+    and dw, whenever the experts' blocks cross between HBM and VMEM."""
+    from paddle_tpu.ops.pallas.grouped_mm import (TILE_M, _cols, grouped_mm,
+                                                  grouped_mm_dw)
+    sizes, K, N, spare = GROUPED[case]
+    E, bf, f32 = len(sizes), jnp.bfloat16, jnp.float32
+    M, te_j, n_j, starts, tiles, valid = laid_out(sizes, spare)
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    x = jax.random.normal(ks[0], (M, K), bf)
+    dy = jnp.where(valid, jax.random.normal(ks[1], (M, N), bf), 0)
+    w = jax.random.normal(ks[2], (E, K, N), f32) / K ** 0.5
+    out = np.asarray(grouped_mm(x, w, te_j, n_j).astype(f32))
+    dx = np.asarray(grouped_mm(dy, w, te_j, n_j, trans_w=True).astype(f32))
+    dw = np.asarray(grouped_mm_dw(x, dy, te_j, n_j, E))
+    assert dw.dtype == np.float32
+    tn, tkT = _cols(N), _cols(K)                # forward's, trans_w's panel
+    dk, dn = _cols(K, 1792), _cols(N, 1792)     # dw's block
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=f32)
+    for e, (e0, t) in enumerate(zip(starts, tiles)):
+        for i in range(t):
+            rows = slice(e0 + i * TILE_M, e0 + (i + 1) * TILE_M)
+            for j in range(N // tn):
+                cols = slice(j * tn, (j + 1) * tn)
+                want = dot(x[rows], w[e][:, cols].astype(bf),
+                           ((1,), (0,))).astype(bf).astype(f32)
+                np.testing.assert_array_equal(out[rows, cols], want)
+            for j in range(K // tkT):
+                cols = slice(j * tkT, (j + 1) * tkT)
+                want = dot(dy[rows], w[e][cols].astype(bf),
+                           ((1,), (1,))).astype(bf).astype(f32)
+                np.testing.assert_array_equal(dx[rows, cols], want)
+        for a in range(K // dk):
+            for b in range(N // dn):
+                ka, nb = slice(a * dk, (a + 1) * dk), slice(b * dn, (b + 1) * dn)
+                want = jnp.zeros((dk, dn), f32)
+                for i in range(t):
+                    rows = slice(e0 + i * TILE_M, e0 + (i + 1) * TILE_M)
+                    want = want + dot(x[rows, ka], dy[rows, nb],
+                                      ((0,), (0,)))
+                np.testing.assert_array_equal(dw[e, ka, nb], want)
+
+
+@pytest.mark.parametrize("case", ["every_group_one_tile",
+                                  "three_column_panels",
+                                  "only_empty_tiles_behind_the_last_group",
+                                  "dw_two_by_two_panels_one_tile_each"])
+def test_grouped_mm_waits_for_every_copy_it_starts(case, monkeypatch):
+    """The kernels move the experts' blocks themselves.  Under the TPU
+    interpreter a copy lands only WHEN IT IS WAITED FOR (the plain
+    interpreter copies at the start, so a product that read its panel
+    before the wait, or a block that nobody waited for at the call's
+    end, would pass there) and two accesses of one buffer that no wait
+    orders are reported as a race."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.pallas import _common
+    on_wait = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                    detect_races=True)
+    monkeypatch.setattr(_common, "interpret", lambda: on_wait)
+    _grouped_fp32_against_a_loop(case, seed=7)
+    assert not interpret_pallas_call.races.races_found
 
 
 # -- the token side of the routed experts --------------------------------------

@@ -16,6 +16,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _grouped_rows import laid_out
+
+
 @pytest.fixture(autouse=True)
 def _needs_tpu():
     """The platform is asked when a test of THIS file starts — never
@@ -519,28 +522,18 @@ def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
     assert err < 6e-2 * max(1.0, float(jnp.abs(dk2_sum).max())), err
 
 
-def test_grouped_mm_at_the_expert_cell_s_shapes():
-    """The grouped products of ``ops/moe.py`` at the cell's widths: 8
-    experts of 3584 x 2048 (gate | up) and 1024 x 3584, fp32 weights
-    cast in VMEM, bf16 rows, groups of uneven sizes with one EMPTY, tiles
-    never used behind them — forward, dx and dw against a loop over the
-    experts."""
+def _grouped_against_a_loop(E, shapes, sizes, spare):
+    """Forward, dx and dw of the grouped products at ``shapes`` ([K, N]
+    of each product), ``E`` experts with groups of ``sizes`` rows and
+    ``spare`` tiles never used behind them, against a loop over the
+    experts: fp32 weights cast in VMEM, bf16 rows."""
     from paddle_tpu.ops.pallas.grouped_mm import (TILE_M, grouped_mm,
                                                   grouped_mm_dw)
-    E, C, F = 8, 3584, 1024
-    sizes = [1100, 0, 900, 1024, 1, 2047, 513, 700]
-    tiles = [max(-(-n // TILE_M), 1) for n in sizes]
-    M = (sum(tiles) + 40) * TILE_M
-    te = np.full((M // TILE_M,), E - 1, np.int32)
-    te[:sum(tiles)] = np.repeat(np.arange(E), tiles)
-    starts = np.concatenate([[0], np.cumsum(tiles)[:-1]]) * TILE_M
-    valid = np.zeros((M, 1), bool)
-    for e0, n in zip(starts, sizes):
-        valid[e0:e0 + n] = True
-    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    M, te_j, n_j, starts, tiles, valid = laid_out(sizes, spare)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3 * len(shapes))
     bf = jnp.bfloat16
-    te_j, n_j = jnp.asarray(te), jnp.asarray([sum(tiles)], jnp.int32)
-    for (K, N), kk in (((C, 2 * F), ks[:3]), ((F, C), ks[2:])):
+    for p, (K, N) in enumerate(shapes):
+        kk = ks[3 * p:3 * p + 3]
         x = jax.random.normal(kk[0], (M, K), bf)
         dy = jnp.where(valid, jax.random.normal(kk[1], (M, N), bf), 0)
         w = jax.random.normal(kk[2], (E, K, N), jnp.float32) / K ** 0.5
@@ -560,7 +553,32 @@ def test_grouped_mm_at_the_expert_cell_s_shapes():
             for got, want in pairs:
                 err = float(jnp.abs(got - want).max())
                 assert err < 3e-2 * max(1.0, float(jnp.abs(want).max())), \
-                    (e, err)
+                    (K, N, e, err)
+
+
+def test_grouped_mm_at_the_expert_cell_s_shapes():
+    """The grouped products of ``ops/moe.py`` at the cell's widths: 8
+    experts of 3584 x 2048 (gate | up) and 1024 x 3584, fp32 weights
+    cast in VMEM, bf16 rows, groups of uneven sizes with one EMPTY, tiles
+    never used behind them — forward, dx and dw against a loop over the
+    experts."""
+    _grouped_against_a_loop(
+        8, ((3584, 2048), (1024, 3584)),
+        [1100, 0, 900, 1024, 1, 2047, 513, 700], spare=40)
+
+
+@pytest.mark.parametrize("cell, shapes", [
+    ("convolution", ((2048, 3072), (1536, 2048))),
+    ("window", ((2560, 1536), (768, 2560)))])
+def test_grouped_mm_at_the_sixteen_expert_cells_shapes(cell, shapes):
+    """The same through Mosaic at the other two cells' widths, 16
+    experts each — three column panels of 1,024 and two, narrow panels
+    of 512 and 640 —: one group EMPTY, one of ONE row, groups of one
+    tile next to each other (the next expert's panel is asked for one
+    product ahead and has to be waited for), half the tiles never used."""
+    _grouped_against_a_loop(
+        16, shapes, [1100, 0, 900, 1024, 1, 2047, 513, 700, 256, 255, 257,
+                     1536, 3, 1024, 800, 1200], spare=56)
 
 
 def test_moe_sum_pairs_at_the_expert_cell_s_shapes():

@@ -300,3 +300,58 @@ def test_moe_passes_splits_the_scopes_by_pass():
         "sort|moe_dispatch|forward": 1e-3, "pad|moe_experts|recompute": 1e-3,
         "sort|embed|backward": 1e-3})
     assert len(got["recompute_ops"]) == 2
+
+
+def test_moe_passes_lists_the_grouped_products_by_call_site():
+    """``tools/moe_passes.py``'s ``calls``: a call site is one instruction
+    of the step; its text gives ``[K, N]``, the grid's tiles and the
+    experts held, its runs the ms a run — set against the ms the tiles
+    EXPECTED in use take at the peak, the rest spread over the changes
+    of expert."""
+    import importlib.util
+    from benchmark import xplane_meta
+    spec = importlib.util.spec_from_file_location(
+        "moe_passes", os.path.join(os.path.dirname(harness.HERE), "tools",
+                                   "moe_passes.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    cell = harness.find_cell("lfm2-24b-a2b.pretrain-8k-conv-moe")
+    tiles = "s32[144]{0:T(256)} %slice.65, s32[1]{0:T(128)} %gte.630"
+    mm = ("%grouped_mm.26 = bf16[69632,3072]{1,0:T(8,128)(2,1)} "
+          f"custom-call({tiles}, bf16[36864,2048]{{1,0}} %fusion.13, "
+          "f32[16,2048,3072]{2,1,0:T(8,128)} %gte.390), custom_call_target="
+          '"tpu_custom_call"')
+    dx = ("%grouped_mm.32 = bf16[36864,1536]{1,0} "
+          f"custom-call({tiles}, bf16[36864,2048]{{1,0}} %fusion.24, "
+          "f32[16,1536,2048]{2,1,0} %gte.631)")
+    dw = ("%grouped_mm_dw.10 = f32[16,2048,3072]{2,1,0:T(8,128)} "
+          f"custom-call({tiles}, bf16[36864,2048]{{1,0}} %fusion.27, "
+          "bf16[36864,3072]{1,0} %pad_maximum_fusion.1)")
+
+    def op(text, kernel, path, ms):
+        return xplane_meta.Op(
+            text, 0.0, 1e-3 * ms, 1e-3 * ms,
+            f"jit(step)/{path}/moe_bound_load/moe_experts/{kernel}"
+            "/pallas_call", "", "custom-call", 0., 0.)
+    fwd, bwd = "jvp(layer_scan)", "transpose(jvp(layer_scan))"
+    mt = xplane_meta.MetaTrace({0: [
+        op(mm, "grouped_mm", fwd, 1.7), op(mm, "grouped_mm", fwd, 1.9),
+        op(dx, "grouped_mm", bwd, 0.9), op(dw, "grouped_mm_dw", bwd, 1.8),
+        op("%fusion.1 = f32[8]{0} fusion()", "gather", fwd, 5.0)]},
+        {0: []}, []).named(*xplane_meta.names_of(cell))
+    rows = {r["site"]: r for r in tool.by_call(mt, cell, 2, 197e12)}
+    assert sorted(rows) == ["grouped_mm.26", "grouped_mm.32",
+                            "grouped_mm_dw.10"]
+    # 16,384 pairs expected of 16,384 tokens: 64 tiles + half a tile each
+    # of 16 experts; 3 panels of 1,024, 2 of 768, 2 x 2 blocks of dw
+    assert [(r["K"], r["N"], r["tiles"], r["tiles_in_use"], r["changes"],
+             r["pass"], r["bound"]) for r in rows.values()] == [
+        (2048, 3072, 144, 72.0, 48, "forward", "moe_bound_load"),
+        (2048, 1536, 144, 72.0, 32, "backward", "moe_bound_load"),
+        (2048, 3072, 144, 72.0, 64, "backward", "moe_bound_load")]
+    gate_up = rows["grouped_mm.26"]
+    assert gate_up["runs_a_step"] == 1.0 and gate_up["ms_a_run"] == 1.8
+    at_peak = 2e3 * 72 * 256 * 2048 * 3072 / 197e12
+    assert gate_up["ms_at_peak"] == pytest.approx(at_peak, abs=1e-4)
+    assert gate_up["us_a_change"] == pytest.approx(
+        1e3 * (1.8 - at_peak) / 48, abs=0.01)
