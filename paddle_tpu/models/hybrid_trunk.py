@@ -606,13 +606,19 @@ def _expert_layer(bp, x, cfg, routing=None, act: str = "silu"):
     """The routed experts held here, for the pairs routed to them, beside
     the shared experts where the layer has them: x [b, s, C] (normed) ->
     [b, s, C].  ``routing``: what :func:`_routing` gave for another input
-    than x (None: x routes itself)."""
+    than x (None: x routes itself).  ``bp["we_stacks"]`` and
+    ``bp["we_layer"]`` (the trunk's loops hand them on): the kind's
+    whole ``we_gate_up`` / ``we_down`` and this layer's index in them,
+    which the grouped products read in place of the layer's own two
+    leaves."""
     from ..ops import moe
     from .llama_pretrain import _swiglu
     b, s, c = x.shape
     gate, p = routing or _routing(bp, x, cfg)
     routed = moe.routed_ffn(x.reshape(b * s, c), gate, bp["we_gate_up"],
-                            bp["we_down"], p, act).reshape(b, s, c)
+                            bp["we_down"], p, act,
+                            bp["we_stacks"] + (bp["we_layer"],)
+                            if "we_stacks" in bp else None).reshape(b, s, c)
     if "ws_gate" not in bp:
         return routed
     with jax.named_scope("moe_shared"):
@@ -951,14 +957,26 @@ def trunk(blocks, x, cfg, mesh):
                 for i in range(len(cuts))]
     with jax.named_scope("layer_scan"):
         parts = {kind: runs_of(kind) for kind in blocks}
-        for kind, _, _ in runs:
+        for kind, a, b in runs:
             fwd = _remat_wrap(body[kind], cfg,
                               keep_flash and kind in flash_kinds,
                               kind in ROUTED_KINDS)
+            layers, whole = parts[kind].pop(0), {}
+            if kind in ROUTED_KINDS:
+                # the grouped products read a layer's experts out of the
+                # kind's WHOLE stacks, at the layer's index in them
+                # (``ops/moe.routed_ffn``): the loop's own slices of the
+                # two leaves — and a run's piece of them — take the
+                # cotangents and are read by no pass
+                whole = {"we_stacks": tuple(
+                    jax.lax.stop_gradient(blocks[kind][leaf])
+                    for leaf in ("we_gate_up", "we_down"))}
+                layers = dict(layers,
+                              we_layer=jnp.arange(a, b, dtype=jnp.int32))
             x, _ = jax.lax.scan(
-                lambda carry, bp, fwd=fwd: (fwd(bp, carry, cfg, mesh, None),
-                                            None),
-                x, parts[kind].pop(0))
+                lambda carry, bp, fwd=fwd, whole=whole: (
+                    fwd({**bp, **whole}, carry, cfg, mesh, None), None),
+                x, layers)
     if streams > 1:
         with jax.named_scope("hc_post"):
             c = x.shape[-1] // streams

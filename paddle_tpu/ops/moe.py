@@ -48,6 +48,14 @@ scatter-add of activations either way, no ``k`` row reads for a token
 that holds half a pair.  A tile that holds no rows costs an empty grid
 step of the grouped products (``ops/pallas/grouped_mm.py``).
 
+The experts' weights are read WHERE THE OPTIMIZER HOLDS THEM: fp32, a
+panel at a time, cast in VMEM — and, for a layer of a loop over layers,
+out of the kind's stacked leaves at the layer's index
+(:func:`routed_ffn`'s ``stacked``): no pass is handed ``stack[layer]``,
+so the program holds no copy of a layer's experts and no slice of a
+stack, forward or backward; the weights' cotangents are a layer's, as
+the loop's transpose wants them.
+
 ``distributed/parallel/expert_parallel.py`` is the other thing: GShard's
 capacity API (one-hot ``[T, k, E, C]``, dropping), kept for Paddle's
 ``MoELayer`` surface.
@@ -308,7 +316,8 @@ def _at_the_load(fn, p: Plan, *args):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def routed_ffn(x, gate, w_gate_up, w_down, p: Plan, act: str = "silu"):
+def routed_ffn(x, gate, w_gate_up, w_down, p: Plan, act: str = "silu",
+               stacked=None):
     """The held experts' part of the layer: x [T, C], gate [T, k] (fp32)
     and the stacks ``[held, C, 2 F]`` (gate | up, one product) and
     ``[held, F, C]`` as the optimizer holds them (the kernels cast a
@@ -323,9 +332,36 @@ def routed_ffn(x, gate, w_gate_up, w_down, p: Plan, act: str = "silu"):
     on the bound the load asks for (:func:`_at_the_load`); what is kept
     has the full bound's shapes, filled in its first rows — the product
     by the kernel that forms it (``grouped_mm(..., out_rows=)``: no pad,
-    the rows past the load's are never written and never read)."""
+    the rows past the load's are never written and never read).
+
+    The products READ the experts out of ``stacked = (gate | up [L,
+    held, C, 2 F], down [L, held, F, C], layer)`` — ``layer`` an int32
+    scalar, data — where they lie (``grouped_mm``'s ``layer``); None:
+    the two arrays themselves, stacks of one layer read at 0.  A layer
+    of a loop over layers hands over the kind's whole stacks — constants
+    of the loop, under ``stop_gradient`` — and as ``w_gate_up`` /
+    ``w_down`` the loop's own slices ``stack[layer]``, whose VALUES no
+    pass reads and no backward holds, so XLA writes none of them out (a
+    kernel handed ``stack[layer]`` is handed a copy of it, once a pass).
+    The weights' COTANGENTS go to ``w_gate_up`` / ``w_down`` either way,
+    a layer's ``[held, C, 2 F]`` / ``[held, F, C]``, which the loop's
+    transpose stacks; ``stacked`` gets none (a loop constant WITH one is
+    padded to the whole stack and added up every iteration)."""
     return _at_the_load(lambda *a: _forward(act, *a)[0], p, x, gate,
-                        w_gate_up, w_down)
+                        *_read(w_gate_up, w_down, stacked))
+
+
+def _read(w_gate_up, w_down, stacked):
+    """What the products read, as ``grouped_mm`` takes it: the layer's
+    two arrays and no index, or the two stacks and the layer."""
+    if stacked is None:
+        return w_gate_up, w_down, None
+    gate_up, down, layer = stacked
+    if gate_up.shape[1:] != w_gate_up.shape or down.shape[1:] != w_down.shape:
+        raise ValueError(
+            f"routed_ffn: stacks {gate_up.shape}, {down.shape} of layers "
+            f"{w_gate_up.shape}, {w_down.shape}")
+    return gate_up, down, layer.astype(I32).reshape(1)
 
 
 def _activation(act: str):
@@ -346,41 +382,45 @@ def _hidden_and_its_gradient(act: str, g, u):
     return a * u, lambda d: [jnp.where(g > 0, d * u, 0), d * a]
 
 
-def _forward(act, p, x, gate, w_gate_up, w_down, kept_rows: int = 0):
-    """y and the gate | up product; ``kept_rows`` (static): the rows of
-    the array the product is handed on in, its own where fewer."""
-    te, n, f = p.tile_expert, p.n_tiles, w_down.shape[1]
+def _forward(act, p, x, gate, w_gate_up, w_down, layer, kept_rows: int = 0):
+    """y and the gate | up product; ``layer``: whose experts of stacked
+    ones (None: the two arrays are one layer's); ``kept_rows`` (static):
+    the rows of the array the product is handed on in, its own where
+    fewer."""
+    te, n, f = p.tile_expert, p.n_tiles, w_down.shape[-2]
     m = p.row_pair.shape[0]
     with jax.named_scope("moe_dispatch"):
         rows = _tokens_of_rows(x, p)
     with jax.named_scope("moe_experts"):
-        gu = grouped_mm(rows, w_gate_up, te, n, out_rows=kept_rows)
+        gu = grouped_mm(rows, w_gate_up, te, n, out_rows=kept_rows,
+                        layer=layer)
         h = (_activation(act)(gu[:m, :f].astype(jnp.float32)) * gu[:m, f:]
              * _gate_of_rows(gate, p)[:, None]).astype(x.dtype)
-        out = grouped_mm(h, w_down, te, n)
+        out = grouped_mm(h, w_down, te, n, layer=layer)
     with jax.named_scope("moe_combine"):
         y = _rows_of_pairs(out, p).astype(x.dtype)
     return y, gu
 
 
-def _routed_fwd(x, gate, w_gate_up, w_down, p, act):
+def _routed_fwd(x, gate, w_gate_up, w_down, p, act, stacked=None):
     # one shape from both branches, the bound of any load's: the load's
     # bound writes its rows of the product into an array that long
     kept = functools.partial(_forward, act, kept_rows=p.row_pair.shape[0])
-    y, gu = _at_the_load(kept, p, x, gate, w_gate_up, w_down)
-    return y, (x, gate, w_gate_up, w_down, p, gu)
+    read = _read(w_gate_up, w_down, stacked)
+    y, gu = _at_the_load(kept, p, x, gate, *read)
+    return y, (x, gate, read, p, gu)
 
 
 def _routed_bwd(act, res, dy):
-    x, gate, w_gate_up, w_down, p, gu = res
-    d = _at_the_load(functools.partial(_backward, act), p, x, gate,
-                     w_gate_up, w_down, gu, dy)
-    return d + (None,)
+    x, gate, read, p, gu = res
+    d = _at_the_load(functools.partial(_backward, act), p, x, gate, *read,
+                     gu, dy)
+    return d + (None, None)
 
 
-def _backward(act, p, x, gate, w_gate_up, w_down, gu, dy):
+def _backward(act, p, x, gate, w_gate_up, w_down, layer, gu, dy):
     te, n, f32 = p.tile_expert, p.n_tiles, jnp.float32
-    f, held = w_down.shape[1], w_down.shape[0]
+    held, f = w_down.shape[-3:-1]
     with jax.named_scope("moe_combine"):
         # a row without a pair reads token 0's dy: finite, and every
         # product it enters has the row's gate, which is zero
@@ -390,7 +430,8 @@ def _backward(act, p, x, gate, w_gate_up, w_down, gu, dy):
         g, u = gu[:, :f].astype(f32), gu[:, f:].astype(f32)
         g_row = _gate_of_rows(gate, p)[:, None]
         hid, d_gu_of = _hidden_and_its_gradient(act, g, u)
-        d_h = grouped_mm(d_out, w_down, te, n, trans_w=True).astype(f32)
+        d_h = grouped_mm(d_out, w_down, te, n, trans_w=True,
+                         layer=layer).astype(f32)
         d_wd = grouped_mm_dw((hid * g_row).astype(x.dtype), d_out, te, n,
                              held)
         d_gate_row = jnp.sum(d_h * hid, axis=1)
@@ -399,7 +440,8 @@ def _backward(act, p, x, gate, w_gate_up, w_down, gu, dy):
         rows = _tokens_of_rows(x, p)
     with jax.named_scope("moe_experts"):
         d_wgu = grouped_mm_dw(rows, d_gu, te, n, held)
-        d_rows = grouped_mm(d_gu, w_gate_up, te, n, trans_w=True)
+        d_rows = grouped_mm(d_gu, w_gate_up, te, n, trans_w=True,
+                            layer=layer)
     with jax.named_scope("moe_dispatch"):
         dx = _rows_of_pairs(d_rows, p).astype(x.dtype)
         # the rows hand their pairs the gates' gradient: M writes, not

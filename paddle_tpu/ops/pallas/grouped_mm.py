@@ -56,6 +56,16 @@ each:
 
 Tiles past ``n_tiles`` start and wait for nothing.  The arithmetic and
 its order are what blocked operands gave: bit for bit.
+
+Because the kernel forms a panel's address itself, ``grouped_mm`` reads
+it out of the experts of SEVERAL LAYERS as they lie stacked, ``[L, E, K,
+N]``, at a layer's index — a third scalar, data: ``w_hbm.at[l, e, :,
+cols]``.  A loop over layers hands every iteration the same array; a
+kernel handed ``w[l]`` is handed a copy of the layer's experts that XLA
+writes first (a custom call takes whole arrays).  One layer's ``[E, K,
+N]`` is the stack of one, read at 0.  The product and the work it
+declares are one layer's.  ``grouped_mm_dw`` writes a layer's ``[E, K,
+N]``.
 """
 
 from __future__ import annotations
@@ -111,15 +121,16 @@ def _group(te_ref, n_ref, i):
     return starts, ends, e, te_ref[0], te_ref[jnp.maximum(n - 1, 0)]
 
 
-def _mm_kernel(te_ref, n_ref, x_ref, w_hbm, o_ref, w_f32, w_cast, sem, *,
-               trans_w: bool):
+def _mm_kernel(te_ref, n_ref, l_ref, x_ref, w_hbm, o_ref, w_f32, w_cast, sem,
+               *, trans_w: bool):
     j = pl.program_id(0).astype(I32)
     i = pl.program_id(1).astype(I32)
     tn = o_ref.shape[1]
 
     def fetch(e, j, slot):
         cols = pl.ds(pl.multiple_of(j * I32(tn), tn), tn)
-        panel = w_hbm.at[e, cols, :] if trans_w else w_hbm.at[e, :, cols]
+        l = l_ref[0]
+        panel = w_hbm.at[l, e, cols, :] if trans_w else w_hbm.at[l, e, :, cols]
         return pltpu.make_async_copy(panel, w_f32.at[slot], sem.at[slot])
 
     @pl.when(i < n_ref[0])
@@ -156,36 +167,45 @@ def _mm_kernel(te_ref, n_ref, x_ref, w_hbm, o_ref, w_f32, w_cast, sem, *,
 
 
 def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
-               out_rows: int = 0):
-    """x [M, K] @ w[e] ([E, K, N]; ``trans_w``: [E, N, K]; any float
-    dtype) -> [M, N] in x's dtype, ``e`` the expert of the row's tile.
-    Tiles past ``n_tiles`` are left unwritten — and so are the rows past
-    M of a result asked for at ``out_rows`` > M rows (a caller that has
-    to hand on a longer array gets it without a copy)."""
+               out_rows: int = 0, layer=None):
+    """x [M, K] @ w[layer, e] ([L, E, K, N]; ``trans_w``: [L, E, N, K];
+    any float dtype) -> [M, N] in x's dtype, ``e`` the expert of the
+    row's tile, ``layer`` (int32 ``[1]``, data) the layer of the stack
+    whose experts are read — where they lie: a caller in a loop over the
+    layers hands over the whole stack, not ``w[l]``.  ``w [E, K, N]`` is
+    the stack of one layer, read at 0.  Tiles past ``n_tiles`` are left
+    unwritten — and so are the rows past M of a result asked for at
+    ``out_rows`` > M rows (a caller that has to hand on a longer array
+    gets it without a copy)."""
+    if (w.ndim == 4) != (layer is not None):
+        raise ValueError(f"grouped_mm: w of rank {w.ndim} with"
+                         f"{'out' if layer is None else ''} a layer")
+    if layer is None:
+        w, layer = w[None], jnp.zeros((1,), I32)
     M, K = x.shape
-    N = w.shape[1] if trans_w else w.shape[2]
+    N = w.shape[2] if trans_w else w.shape[3]
     tn = _cols(N)
     w_block = (tn, K) if trans_w else (K, tn)
     # the BOUND the call is launched at: every tile of M holding rows
     # (how many do is data).  x again for every column panel, every
     # expert's weights once (a panel at a time, as the optimizer holds
-    # them), the result once
+    # them: ONE layer's of the stack), the result once
     cost = pl.CostEstimate(
         flops=2 * M * K * N, transcendentals=0,
         bytes_accessed=(N // tn) * _common.nbytes((M, K), x.dtype)
-        + _common.nbytes(w.shape, w.dtype)
+        + _common.nbytes(w.shape[1:], w.dtype)
         + _common.nbytes((M, N), x.dtype))
     return pl.pallas_call(
         functools.partial(_mm_kernel, trans_w=trans_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(N // tn, M // TILE_M),
             in_specs=[
                 pl.BlockSpec((TILE_M, K),
-                             lambda j, i, te, n: idx32(_held(i, n), 0)),
+                             lambda j, i, te, n, l: idx32(_held(i, n), 0)),
                 pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(
-                (TILE_M, tn), lambda j, i, te, n: idx32(_held(i, n), j)),
+                (TILE_M, tn), lambda j, i, te, n, l: idx32(_held(i, n), j)),
             scratch_shapes=[pltpu.VMEM((2,) + w_block, w.dtype),
                             pltpu.VMEM(w_block, x.dtype),
                             pltpu.SemaphoreType.DMA((2,))]),
@@ -195,7 +215,7 @@ def grouped_mm(x, w, tile_expert, n_tiles, trans_w: bool = False,
         name="grouped_mm",
         cost_estimate=cost,
         interpret=_common.interpret(),
-    )(tile_expert, n_tiles, x, w)
+    )(tile_expert, n_tiles, layer, x, w)
 
 
 def _dw_kernel(te_ref, n_ref, x_ref, dy_ref, dw_hbm, acc, sem):

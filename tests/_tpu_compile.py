@@ -26,6 +26,7 @@ one place the kernels ask (``_common.interpret``) is steered from the
 ``compiled`` fixture — not through an option of the program.
 """
 
+import functools
 import re
 
 import pytest
@@ -112,22 +113,23 @@ def _two_kernels(monkeypatch):
 # ---------------------------------------------------------------------------
 # opcodes that only place data, and what may stand beside them in a fusion
 # that still computes nothing (a cotangent's pad-and-add among them)
-_PLACES = {"slice", "copy", "pad", "concatenate"}
+_PLACES = {"slice", "dynamic-slice", "copy", "pad", "concatenate"}
 _IDLE = _PLACES | {"parameter", "constant", "bitcast", "convert", "add",
                    "tuple", "get-tuple-element", "broadcast", "reshape"}
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([a-z\-]+)\((.*)$")
+# an instruction of several results — a fusion that writes both runs'
+# slices of a stack is one — and a result of it
+_SEVERAL = re.compile(r"^\s*(?:ROOT )?%(\S+) = \((.*?)\) ([a-z\-]+)\((.*)$")
+_RESULT = re.compile(r"(\w+)\[([\d,]*)\]")
 _BYTES = {"bf16": 2, "f16": 2, "f32": 4}
 
 
-def _placed(text, rows, widths):
-    """Instructions of an optimized module that WRITE an array ``rows +
-    (one of widths,)`` to HBM and compute nothing: a bare slice, copy,
-    pad or concatenate, or a fusion of nothing else — what XLA puts
-    before a custom call that was handed a piece of an array, or a
-    layout it does not read.  Only a computation's own instructions
-    count: inside a fusion such an op moves nothing through HBM (a pad
-    fused into a matrix product's operand is free)."""
+@functools.lru_cache(maxsize=2)
+def _computations(text):
+    """An optimized module's computations, each the (result, dtype,
+    dims, opcode, rest) of its instructions — one a result where an
+    instruction has several — and the names of those a fusion calls."""
     bodies, body = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
@@ -137,7 +139,23 @@ def _placed(text, rows, widths):
             body = None
         elif body is not None and _INSTRUCTION.match(line):
             body.append(_INSTRUCTION.match(line).groups())
-    fused = set(re.findall(r"fusion\(.*?calls=%([^\s,]+)", text))
+        elif body is not None and _SEVERAL.match(line):
+            result, shapes, opcode, rest = _SEVERAL.match(line).groups()
+            body.extend((result, dtype, dims, opcode, rest)
+                        for dtype, dims in _RESULT.findall(shapes))
+    return bodies, set(re.findall(r"fusion\(.*?calls=%([^\s,]+)", text))
+
+
+def _placed(text, rows, widths):
+    """Instructions of an optimized module that WRITE an array ``rows +
+    (one of widths,)`` to HBM and compute nothing: a bare slice (static
+    or dynamic), copy, pad or concatenate, or a fusion of nothing else,
+    of one result or several — what XLA puts before a custom call that
+    was handed a piece of an array, or a layout it does not read.  Only
+    a computation's own instructions count: inside a fusion such an op
+    moves nothing through HBM (a pad fused into a matrix product's
+    operand is free)."""
+    bodies, fused = _computations(text)
     found = []
     for name, instructions in bodies.items():
         if name in fused:
@@ -159,6 +177,16 @@ def _placed(text, rows, widths):
                           scope.group(1).rsplit("/", 2)[-2:] if scope
                           else None))
     return found
+
+
+def _experts_placed(text, held, c, f, layers) -> list:
+    """What of a routed kind's fp32 experts — gate | up ``[held, c, 2
+    f]``, down ``[held, f, c]`` — an optimized module only MOVES: one
+    layer's (what a kernel handed ``stack[layer]`` gets), a run's slice
+    of ``1 .. layers`` of them, a whole stack's."""
+    return [found for lead in [()] + [(n,) for n in range(1, layers + 1)]
+            for found in (_placed(text, lead + (held, c), {2 * f})
+                          + _placed(text, lead + (held, f), {c}))]
 
 
 # the hybrid cell's rows, and the widths only its mixer has: d_inner,

@@ -15,9 +15,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _tpu_compile import (KERNEL, ROWS_8K, _cell_step, _flash_module,  # noqa: F401
-                          _padded_from, _placed, _routing_sorts, _sds,
-                          _text, compiled, one_chip, topo)
+from _tpu_compile import (KERNEL, ROWS_8K, _cell_step,  # noqa: F401
+                          _experts_placed, _flash_module, _padded_from,
+                          _placed, _routing_sorts, _sds, _text, compiled,
+                          one_chip, topo)
 
 
 def test_train_step_of_the_expert_cell(one_chip, compiled):
@@ -75,14 +76,21 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     assert not _padded_from(text, 18432, 67584, 2048)
     assert ".remat" not in text
     assert not re.search(r"bf16\[(4,)?8,3584,2048\]", text)
+    # nor an fp32 copy of a layer's experts (PR 51): the grouped products
+    # read the panels of ``[4, 8, 3584, 2048]`` / ``[4, 8, 1024, 3584]``
+    # at the layer's index — until then a ``dynamic-slice`` fusion wrote
+    # both leaves of a layer out, 352 MB, in the forward loop and in the
+    # backward loop (4 such fusions)
+    assert not _experts_placed(text, 8, 3584, 1024, layers=4)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 3_057_670_144
     # PR 43's reading with the outputs kept (15,981,031,936 without).
     # The figure is no allocation's size: the buffer assignment holds the
     # kept stacks once, and its one HBM temp allocation grew by
     # 392,691,712 B to 11,188,912,640 (PERF.md section 6); + 64,929,280
-    # with the four layers' routing kept (PR 46: 17,408,482,816 before)
-    assert ma.temp_size_in_bytes <= 17_473_412_096
+    # with the four layers' routing kept (PR 46: 17,408,482,816 before);
+    # 17,473,412,096 until a layer's experts had no copy (PR 51)
+    assert ma.temp_size_in_bytes <= 17_121_155_072
 
 
 def test_train_step_of_the_convolution_cell(one_chip, compiled):
@@ -127,9 +135,16 @@ def test_train_step_of_the_convolution_cell(one_chip, compiled):
     # no array of the operator's widths is only sliced, copied, padded or
     # joined between the in-projection and the kernels, or behind them
     assert not _placed(text, ROWS_8K, {6144})
+    # nor a layer's fp32 experts, ``[16, 2048, 3072]`` and ``[16, 1536,
+    # 2048]`` (604 MB), before the grouped products of either routed
+    # kind's loops (PR 51; four ``dynamic-slice`` fusions until then:
+    # the forward loop's and the backward loop's of the three
+    # convolution layers' scan)
+    assert not _experts_placed(text, 16, 2048, 1536, layers=3)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 3_157_483_008
-    assert ma.temp_size_in_bytes <= 9_717_807_104
+    # 9,712,517,632 with a layer's experts copied (PR 49's program)
+    assert ma.temp_size_in_bytes <= 9_019_862_528
 
 
 @pytest.mark.parametrize("kernels", [2, 3])

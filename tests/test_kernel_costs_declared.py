@@ -277,6 +277,13 @@ CASES = [
     ("grouped_mm, 512 rows", "grouped_mm", grouped_mm,
      (z((512, 256)), z((4, 256, 2048), F32), z((2,), I32), z((1,), I32)),
      536_870_912, 11_010_048, 0),
+    # the experts of three layers stacked and a layer's index: the work of
+    # ONE layer's call, the first case's to the byte
+    ("grouped_mm, a layer of a stack", "grouped_mm",
+     lambda x, w, te, n, at: grouped_mm(x, w, te, n, layer=at),
+     (z((2048, 256)), z((3, 4, 256, 2048), F32), z((8,), I32), z((1,), I32),
+      z((1,), I32)),
+     2_147_483_648, 18_874_368, 0),
     # x [2048, 3584] once (one panel of dy's 512 columns), dy twice (two
     # 1792-row panels of K), [4, 3584, 512] fp32 out
     ("grouped_mm_dw", "grouped_mm_dw",

@@ -306,6 +306,60 @@ def test_grouped_mm_waits_for_every_copy_it_starts(case, monkeypatch):
     assert not interpret_pallas_call.races.races_found
 
 
+# what of a call the stack's layer could get wrong: a panel's address in
+# every walk (a change of expert every step, the hand-over to the next
+# column panel), a call that fetches nothing
+STACKED = [("uneven_zero_and_tile_crossing", "plain"),
+           ("uneven_zero_and_tile_crossing", "trans_w"),
+           ("uneven_zero_and_tile_crossing", "out_rows"),
+           ("every_group_one_tile", "plain"),
+           ("two_column_panels", "trans_w"),
+           ("no_tile_in_use", "out_rows")]
+
+
+@pytest.mark.parametrize("case,form", STACKED)
+def test_grouped_mm_reads_a_layer_where_the_stack_holds_it(case, form):
+    """``grouped_mm(x, stack, te, n, layer=l)`` on the experts of three
+    layers stacked is ``grouped_mm(x, stack[l], te, n)`` BIT FOR BIT —
+    the first layer, the middle one and the last, the layer data of ONE
+    program — and one layer's ``[E, K, N]`` alone goes through the same
+    kernel as the stack of one: three scalars, a rank-4 operand."""
+    from paddle_tpu.ops.pallas.grouped_mm import grouped_mm
+    sizes, K, N, spare = GROUPED.get(case, GROUPED[STACKED[0][0]])
+    E, L, bf, f32 = len(sizes), 3, jnp.bfloat16, jnp.float32
+    M, te, n, *_ = laid_out(sizes, spare)
+    if case == "no_tile_in_use":
+        n = jnp.zeros_like(n)
+    trans = form == "trans_w"
+    kw = dict(trans_w=trans, out_rows=M + 512 if form == "out_rows" else 0)
+    ks = jax.random.split(jax.random.PRNGKey(51), 2)
+    x = jax.random.normal(ks[0], (M, N if trans else K), bf)
+    stack = jax.random.normal(ks[1], (L, E, K, N), f32) / K ** 0.5
+    of_stack = jax.jit(lambda w, l: grouped_mm(x, w, te, n, layer=l, **kw))
+    of_layer = jax.jit(lambda w: grouped_mm(x, w, te, n, **kw))
+    rows = int(n[0]) * 256                      # the rows a call writes
+    seen = []
+    for l in range(L):
+        got = np.asarray(of_stack(stack, jnp.asarray([l], jnp.int32))
+                         .astype(f32))[:rows]
+        np.testing.assert_array_equal(
+            got, np.asarray(of_layer(stack[l]).astype(f32))[:rows])
+        seen.append(got)
+    assert got.shape == (rows, K if trans else N)
+    if rows:
+        assert not np.array_equal(seen[0], seen[1]) \
+            and not np.array_equal(seen[1], seen[2])
+    calls = [eqn for eqn in jax.make_jaxpr(
+        lambda w: grouped_mm(x, w, te, n, **kw))(stack[0]).jaxpr.eqns
+        if eqn.primitive.name == "pallas_call"]
+    assert [v.aval.shape for v in calls[0].invars[2:]] \
+        == [(1,), x.shape, (1, E, K, N)]
+    with pytest.raises(ValueError, match="rank 4 without a layer"):
+        grouped_mm(x, stack, te, n, **kw)
+    with pytest.raises(ValueError, match="rank 3 with a layer"):
+        grouped_mm(x, stack[0], te, n, layer=jnp.zeros((1,), jnp.int32), **kw)
+
+
 # -- the token side of the routed experts --------------------------------------
 def _runs(counts, P):
     """Slots of ``len(counts)`` tokens holding ``counts`` pairs each:

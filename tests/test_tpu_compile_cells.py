@@ -16,9 +16,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from _tpu_compile import (HEADS, KERNEL, MIXER_WIDTHS, ROWS_8K,  # noqa: F401
-                          _cell_step, _cfg, _padded_from, _param_sds,
-                          _placed, _routing_sorts, _sds, compiled, one_chip,
-                          topo)
+                          _cell_step, _cfg, _experts_placed, _padded_from,
+                          _param_sds, _placed, _routing_sorts, _sds,
+                          compiled, one_chip, topo)
 
 
 @pytest.mark.parametrize("nkv", [HEADS, 8])
@@ -132,12 +132,21 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     assert ".remat" not in text
     # no bf16 copy of an expert stack
     assert not re.search(r"bf16\[(\d+,)?16,2560,1536\]", text)
+    # and no fp32 copy of any part of one (PR 51): each kind's layers lie
+    # in TWO runs, and the grouped products read a layer's panels out of
+    # the kind's whole ``[6 | 2, 16, 2560, 1536]`` / ``[6 | 2, 16, 768,
+    # 2560]`` at the layer's index in it.  Until then: a run's slice of
+    # each leaf, written once a step and held through it (four fusions
+    # of two results, 8 x 377 MB), and a layer's two leaves before the
+    # kernels of every loop (eight ``dynamic-slice`` fusions)
+    assert not _experts_placed(text, 16, 2560, 768, layers=6)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 4_484_826_624
     # 12,977,658,368 B with the two-kernel backward (PR 44), 12,977,271,296
     # with the one pass (PR 45): the delta arrays are gone, the sums live
-    # in VMEM; + 52,790,272 with the eight layers' routing kept (PR 46)
-    assert ma.temp_size_in_bytes <= 13_030_061_568
+    # in VMEM; + 52,790,272 with the eight layers' routing kept (PR 46):
+    # 13,030,061,568 until the experts were read where they lie (PR 51)
+    assert ma.temp_size_in_bytes <= 11_337_099_776
 
 
 # sha256 of the dense cell's optimized step at depth 18 with the debug
