@@ -109,6 +109,45 @@ does not gate by, on ONE residual stream:
 The three groups are read where the in-projection left them, and the
 convolution's backward writes dB | dCg | dX as the one array that
 product's backward reads.
+
+``kda_moe`` and ``gqa_gated_moe`` are the layers of a Kimi-Delta-
+Attention / gated-attention expert model (``solar_open2``; KDA is Kimi
+Linear's, arXiv:2510.26692): a layer of ``gqa_layers`` is softmax GQA
+whose output is gated, the others a delta rule whose decay is a vector a
+head behind a depthwise causal convolution, every one before an expert
+layer BESIDE a shared expert, on ONE residual stream (H =
+``kda_num_heads`` heads of K = V = ``kda_head_dim``):
+
+    y  = rms_norm(h; ln1);   h1 = h + Mixer(y)
+    u  = rms_norm(h1; ln2);  h2 = h1 + Experts(u)
+    Experts(u) = sum over the picks whose expert is HELD here of
+                   g_e (silu(u . w_gate_e) * (u . w_up_e)) . w_down_e
+               + (silu(u . ws_gate) * (u . ws_up)) . ws_down       (shared)
+      s = sigmoid(u . w_router)  (fp32);  picks = the top k of s
+      g_e = routed_scaling_factor s_e / (sum of the picked s + 1e-20)
+    gqa_gated_moe:
+      q, k, v = y . wq [H, d], y . wk [KV, d], y . wv [KV, d];  z = y . wg
+      a = softmax(q k^T / sqrt(d) over j <= i) v   (rotated only where the
+                                                    configuration rotates)
+      Mixer = (a * sigmoid(z)) . wo
+    kda_moe:
+      [q | k | v] = y . w_qkv                                      [3 H K]
+      q, k, v = silu(conv(.))   depthwise, causal, ``short_conv_kernel_size``
+                                taps [conv_q | conv_k | conv_v], zeros before
+                                the row, no bias (ops/pallas/causal_conv.py)
+      q = q / sqrt(sum_K q^2 + 1e-6) / sqrt(K);  k = k / sqrt(sum_K k^2 +
+          1e-6), a head at a time                (inside ops/kda.kda_chunk)
+      g = -exp(A_log_h) softplus((y . w_fa) . w_fb + dt_bias)   fp32 [s, H, K]
+      beta = 2 sigmoid(y . w_beta)   (an eigenvalue of I - beta k k^T may
+                                      reach -1)
+      S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+      o_t = S_t^T q_t          S [K, V] fp32 a head, S_0 = 0   (ops/kda.py)
+      Mixer = (rms_norm(o_t; o_norm [V], a head at a time)
+               * sigmoid((y . w_ga) . w_gb + gate_b)) . wo
+
+q | k | v are read where the convolution left them and the recurrence's
+backward writes dq | dk | dv as the one array the convolution's backward
+reads.
 """
 
 from __future__ import annotations
@@ -124,11 +163,13 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 KINDS = ("attention", "mamba", "mla_dense", "mla_moe", "gqa_moe_global",
-         "gqa_moe_window", "conv_dense", "conv_moe", "gqa_qknorm_moe")
+         "gqa_moe_window", "conv_dense", "conv_moe", "gqa_qknorm_moe",
+         "kda_moe", "gqa_gated_moe")
 MLA_KINDS = ("mla_dense", "mla_moe")
 GQA_MOE_KINDS = ("gqa_moe_global", "gqa_moe_window")
 CONV_KINDS = ("conv_dense", "conv_moe", "gqa_qknorm_moe")
-ROUTED_KINDS = ("mla_moe",) + GQA_MOE_KINDS + CONV_KINDS[1:]
+KDA_KINDS = ("kda_moe", "gqa_gated_moe")
+ROUTED_KINDS = ("mla_moe",) + GQA_MOE_KINDS + CONV_KINDS[1:] + KDA_KINDS
 
 
 def check(cfg) -> None:
@@ -153,6 +194,8 @@ def check(cfg) -> None:
         _check_gqa_moe(cfg)
     if set(cfg.layer_types) & set(CONV_KINDS):
         _check_conv(cfg)
+    if set(cfg.layer_types) & set(KDA_KINDS):
+        _check_kda(cfg)
     if set(cfg.layer_types) & set(MLA_KINDS):
         if not set(cfg.layer_types) <= set(MLA_KINDS):
             raise NotImplementedError(
@@ -270,6 +313,40 @@ def _check_conv(cfg) -> None:
             "rule 'sigmoid' at another epsilon, which no kind here takes")
 
 
+def kda_kinds(gqa_layers, depth: int) -> Tuple[str, ...]:
+    """The kinds of ``depth`` layers of which the published ``gqa_layers``
+    are gated GQA and the others Kimi Delta Attention."""
+    return tuple("gqa_gated_moe" if i in gqa_layers else "kda_moe"
+                 for i in range(depth))
+
+
+def _check_kda(cfg) -> None:
+    """What the kinds ``kda_moe`` / ``gqa_gated_moe`` must state."""
+    if not set(cfg.layer_types) <= set(KDA_KINDS):
+        raise NotImplementedError(
+            f"layer_types {sorted(set(cfg.layer_types))}: the kinds "
+            f"{KDA_KINDS} are one model's layers and mix with no other")
+    if cfg.hc_mult != 1:
+        raise NotImplementedError(
+            f"hc_mult={cfg.hc_mult}: the kinds {KDA_KINDS} have one "
+            "residual stream")
+    if not (0 < cfg.num_experts_per_tok <= cfg.n_routed_experts
+            and 0 < cfg.experts_held and 0 <= cfg.expert_first
+            and cfg.expert_first + cfg.experts_held <= cfg.n_routed_experts
+            and cfg.moe_intermediate_size > 0):
+        raise ValueError(
+            "a 'kda_moe' / 'gqa_gated_moe' layer needs n_routed_experts "
+            "(the router's width), num_experts_per_tok, "
+            "moe_intermediate_size, and the share: experts_held from "
+            "expert_first on, inside the published count")
+    if "kda_moe" not in cfg.layer_types:
+        return
+    if min(cfg.kda_num_heads, cfg.kda_head_dim,
+           cfg.short_conv_kernel_size) < 1:
+        raise ValueError("a 'kda_moe' layer needs kda_num_heads, "
+                         "kda_head_dim and short_conv_kernel_size")
+
+
 def check_layout(cfg, mesh, pp: int) -> None:
     """Layers by kind run on one device or, without a state-space kind,
     not at all split: what is missing is named, nothing runs wrong."""
@@ -283,11 +360,14 @@ def check_layout(cfg, mesh, pp: int) -> None:
             "projections (mp), no state hand-over between sequence shards "
             "(sep) and its kernel no shard_map over the batch (dp, "
             "sharding); 'mla_dense' / 'mla_moe' / 'gqa_moe_*' / 'conv_moe' "
-            "/ 'gqa_qknorm_moe' no exchange of routed rows between the "
-            "devices that share a layer's experts and no shard_map around "
-            "flash_attention_split, the grouped products and the gated "
-            "short convolution of 'conv_dense' / 'conv_moe'; one device "
-            "runs it")
+            "/ 'gqa_qknorm_moe' / 'kda_moe' / 'gqa_gated_moe' no exchange "
+            "of routed rows between the devices that share a layer's "
+            "experts and no shard_map around flash_attention_split, the "
+            "grouped products and the gated short convolution of "
+            "'conv_dense' / 'conv_moe'; 'kda_moe' no head-parallel "
+            "projections, no hand-over of its matrix state between "
+            "sequence shards and no shard_map around the kda_chunk "
+            "kernels; one device runs it")
 
 
 # ---------------------------------------------------------------------------
@@ -308,6 +388,8 @@ def kind_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     dense = _block_shapes(cfg)
     if kind in CONV_KINDS:
         return _conv_shapes(cfg, kind, dense)
+    if kind in KDA_KINDS:
+        return _kda_shapes(cfg, kind, dense)
     if kind in GQA_MOE_KINDS:
         c, f, e = (cfg.hidden_size, cfg.moe_intermediate_size,
                    cfg.experts_held)
@@ -345,6 +427,30 @@ def _conv_shapes(cfg, kind: str, dense) -> Dict[str, Tuple[int, ...]]:
     out.update({"w_router": (c, cfg.n_routed_experts),
                 "expert_bias": (cfg.n_routed_experts,),
                 "we_gate_up": (e, c, 2 * f), "we_down": (e, f, c)})
+    return out
+
+
+def _kda_shapes(cfg, kind: str, dense) -> Dict[str, Tuple[int, ...]]:
+    c, heads, d = cfg.hidden_size, cfg.kda_num_heads, cfg.kda_head_dim
+    if kind == "gqa_gated_moe":
+        out = {nm: dense[nm] for nm in ("ln1", "wq", "wk", "wv")}
+        out["wg"], out["wo"] = dense["wq"], dense["wo"]
+    else:
+        wide, taps = heads * d, cfg.short_conv_kernel_size
+        out = {"ln1": (c,), "w_qkv": (c, 3 * wide),
+               "conv_q": (wide, taps), "conv_k": (wide, taps),
+               "conv_v": (wide, taps),
+               "w_fa": (c, d), "w_fb": (d, wide), "A_log": (heads,),
+               "dt_bias": (wide,), "w_beta": (c, heads),
+               "w_ga": (c, d), "w_gb": (d, wide), "gate_b": (wide,),
+               "o_norm": (d,), "wo": (wide, c)}
+    f, e = cfg.moe_intermediate_size, cfg.experts_held
+    fs = f * cfg.n_shared_experts
+    out.update({"ln2": (c,), "w_router": (c, cfg.n_routed_experts),
+                "we_gate_up": (e, c, 2 * f), "we_down": (e, f, c)})
+    if fs:
+        out.update({"ws_gate": (c, fs), "ws_up": (c, fs),
+                    "ws_down": (fs, c)})
     return out
 
 
@@ -386,21 +492,26 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
     and D ones, the convolution uniform in +-1 /
     sqrt(d_conv), ``A_log = log U[1, 16]`` and ``dt_bias`` the inverse
     softplus of a step log-uniform in [1e-3, 1e-1] (the Mamba-2
-    reference's: decays neither 0 nor 1).  The short-convolution kinds':
-    the taps normal at 1/sqrt(conv_L_cache), so that the convolution's
+    reference's: decays neither 0 nor 1; the delta-rule kind's ``A_log``
+    [heads] and ``dt_bias`` [heads x head_dim] by the same rule: a channel
+    decays by between ~0.2 and ~0.999 a step; its ``gate_b`` zeros).  The
+    short-convolution and delta-rule kinds': the taps normal at 1/sqrt(the
+    taps), so that the convolution's
     result is of its input's size, and ``expert_bias`` normal at
     ``EXPERT_BIAS_STD`` — seeded NON-ZERO, since the rule that would move
     it off zero (the balance update) is not built."""
     shape = (layers,) + kind_shapes(cfg, kind)[name]
     f32 = jnp.float32
     if name in ("ln1", "ln2", "gate_norm", "D", "q_norm", "kv_norm",
-                "q_layernorm", "k_layernorm") or name.endswith("_alpha"):
+                "q_layernorm", "k_layernorm", "o_norm") \
+            or name.endswith("_alpha"):
         out = jnp.ones(shape, f32)
     elif name.endswith("_b"):
         out = jnp.zeros(shape, f32)
     elif name == "expert_bias":
         out = jax.random.normal(key, shape, f32) * EXPERT_BIAS_STD
-    elif name == "conv_w" and kind in CONV_KINDS:
+    elif name in ("conv_w", "conv_q", "conv_k", "conv_v") \
+            and kind in CONV_KINDS + KDA_KINDS:
         out = jax.random.normal(key, shape, f32) / math.sqrt(shape[-1])
     elif name in ("conv_w", "conv_b"):
         bound = 1.0 / math.sqrt(cfg.mamba_d_conv)
@@ -415,7 +526,7 @@ def init_leaf(cfg, key, kind: str, name: str, layers: int, dtype=None):
         # a matrix: normal at 1/sqrt(its rows), the width it contracts
         out = jax.random.normal(key, shape, f32) / math.sqrt(
             shape[-2] if kind in MLA_KINDS + GQA_MOE_KINDS + CONV_KINDS
-            else cfg.hidden_size)
+            + KDA_KINDS else cfg.hidden_size)
     return out.astype(dtype or cfg.param_dtype)
 
 
@@ -696,6 +807,101 @@ def _conv_block(bp, x, cfg, mesh=None, seg=None):
             bp, u, cfg, _routing(bp, u, cfg, "sigmoid_biased_picks")), cfg)
 
 
+def _kda_mixer(bp, y, cfg):
+    """Kimi Delta Attention on y [b, s, C] (normed) -> [b, s, C]."""
+    from ..ops import kda
+    from ..ops.pallas import causal_conv
+    from .llama_pretrain import _rms_norm
+    b, s, _ = y.shape
+    dt, f32 = cfg.dtype, jnp.float32
+    heads, d = cfg.kda_num_heads, cfg.kda_head_dim
+    # a layer's two wide matrices are cast where they are used: XLA would
+    # otherwise cast the kind's whole fp32 stacks once, ahead of the loop
+    # over the layers, and hold the copies (0.77 GB at three layers of
+    # 4096 x 24,576 and 8192 x 4096) through the step
+    w_qkv, wo = jax.lax.optimization_barrier((bp["w_qkv"], bp["wo"]))
+    with jax.named_scope("kda_in_proj"):
+        qkv = y @ w_qkv.astype(dt)
+        taps = jnp.concatenate([bp["conv_q"], bp["conv_k"], bp["conv_v"]])
+        kernel = causal_conv.takes(qkv, taps)
+        if kernel:
+            # a kernel takes an array row-major: the product is to leave
+            # it so
+            qkv = with_layout_constraint(
+                qkv, Layout(major_to_minor=(0, 1, 2)))
+        beta_in = jnp.dot(y, bp["w_beta"].astype(dt),
+                          preferred_element_type=f32)
+
+    # The two low-rank maps give ``[s, H K]`` arrays from ``[s,
+    # kda_head_dim]`` factors by a 128-deep product: each is formed
+    # inside a checkpoint of its own with what reads it, so that the
+    # backward pass forms it again and holds no fp32 ``[s, H K]`` array
+    # for it (beside g itself, which the recurrence's backward reads).
+    @jax.checkpoint
+    def log_decay(y, w_fa, w_fb, a_log, dt_bias):
+        with jax.named_scope("kda_in_proj"):
+            # fp32 out: a rounding of the softplus's input is a relative
+            # error of the log decay
+            x = jnp.dot(y @ w_fa.astype(dt), w_fb.astype(dt),
+                        preferred_element_type=f32)
+        with jax.named_scope("kda_gates"):
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+                x.reshape(b, s, heads, d)
+                + dt_bias.astype(f32).reshape(heads, d))
+            return g.reshape(b, s, heads * d)
+
+    @jax.checkpoint
+    def out_gate(o, y, w_ga, w_gb, gate_b, o_norm):
+        with jax.named_scope("kda_in_proj"):
+            x = (y @ w_ga.astype(dt)) @ w_gb.astype(dt)
+        with jax.named_scope("kda_out_gate"):
+            gate = jax.nn.sigmoid(x.astype(f32) + gate_b.astype(f32))
+            return _rms_norm(o.reshape(b, s, heads, d), o_norm,
+                             cfg.rms_norm_eps).reshape(b, s, heads * d) \
+                * gate.astype(dt)
+    with jax.named_scope("kda_conv"):
+        # the state-space mixer's form, silu(bias + conv(x)), at a zero
+        # bias
+        conv = causal_conv.causal_conv_silu if kernel \
+            else causal_conv.causal_conv_silu_xla
+        qkv = conv(qkv, taps, jnp.zeros(taps.shape[:1], f32))
+    g = log_decay(y, bp["w_fa"], bp["w_fb"], bp["A_log"], bp["dt_bias"])
+    with jax.named_scope("kda_gates"):
+        beta = 2.0 * jax.nn.sigmoid(beta_in)
+    with jax.named_scope("kda_chunk"):
+        o = kda.kda_chunk(qkv, g, beta, heads)
+    o = out_gate(o, y, bp["w_ga"], bp["w_gb"], bp["gate_b"], bp["o_norm"])
+    with jax.named_scope("kda_out_proj"):
+        return o @ wo.astype(dt)
+
+
+def _kda_block(bp, x, cfg, mesh=None, seg=None):
+    """One ``kda_moe`` / ``gqa_gated_moe`` layer on x [b, s, C] (the
+    module docstring has the equations): which mixer follows from the
+    leaves the layer holds."""
+    from .llama_pretrain import _attention, _qkv, _residual, _rms_norm
+    b, s, _ = x.shape
+    with jax.named_scope("block"):
+        if "w_qkv" in bp:
+            y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+            x = _residual(x, _kda_mixer(bp, y, cfg), cfg)
+        else:
+            with jax.named_scope("attn_qkv"):
+                y = _rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+            q, k, v = _qkv(bp, y, cfg, mesh,
+                           rotate=cfg.position_embedding_type == "rope")
+            with jax.named_scope("attn"):
+                attn = _attention(q, k, v, cfg, mesh, seg).reshape(b, s, -1)
+            with jax.named_scope("attn_gate"):
+                z = y @ bp["wg"].astype(cfg.dtype)
+                attn = attn * jax.nn.sigmoid(
+                    z.astype(jnp.float32)).astype(cfg.dtype)
+            with jax.named_scope("attn_out"):
+                x = _residual(x, attn @ bp["wo"].astype(cfg.dtype), cfg)
+        u = _rms_norm(x, bp["ln2"], cfg.rms_norm_eps)
+        return _residual(x, _expert_layer(bp, u, cfg), cfg)
+
+
 class _Mixer(NamedTuple):
     """What of the configuration a mixer's maps read."""
     n: int
@@ -927,13 +1133,14 @@ def trunk(blocks, x, cfg, mesh):
             "gqa_moe_window": functools.partial(_gqa_moe_block,
                                                 window=True),
             "conv_dense": _conv_block, "conv_moe": _conv_block,
-            "gqa_qknorm_moe": _conv_block}
+            "gqa_qknorm_moe": _conv_block,
+            "kda_moe": _kda_block, "gqa_gated_moe": _kda_block}
     # the kinds whose blocks call the flash kernels (``check``: latent
     # attention does not mix with the others); their layers together
     # count against FLASH_KEPT_BYTES, at a head's value width
     mla = cfg.layer_types[0] in MLA_KINDS
-    flash_kinds = MLA_KINDS if mla else ("attention", "gqa_qknorm_moe") \
-        + GQA_MOE_KINDS
+    flash_kinds = MLA_KINDS if mla else (
+        "attention", "gqa_qknorm_moe", "gqa_gated_moe") + GQA_MOE_KINDS
     keep_flash = keeps_flash_outputs(
         x.shape[0], x.shape[1], cfg.num_attention_heads,
         cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,

@@ -155,6 +155,22 @@ class LlamaPretrainConfig:
     conv_L_cache: int = 0               # the short convolution's taps
     num_dense_layers: int = 0
     use_expert_bias: bool = False
+    # KIMI-DELTA-ATTENTION LAYERS AMONG GATED GQA LAYERS, EVERY ONE BEFORE
+    # AN EXPERT LAYER BESIDE A SHARED EXPERT (``solar_open2``;
+    # hybrid_trunk's kinds 'kda_moe' / 'gqa_gated_moe'): ``gqa_layers``
+    # names the layers that are softmax GQA gated by the sigmoid of a
+    # second q-sized projection, rotated only where
+    # ``position_embedding_type`` rotates, and every other layer is a
+    # delta rule of ``kda_num_heads`` heads of ``kda_head_dim`` keys x
+    # values whose decay is a vector a head and whose beta is 2 x a
+    # sigmoid, behind a depthwise causal convolution of
+    # ``short_conv_kernel_size`` taps; the decay's and the output gate's
+    # maps are low-rank pairs through ``kda_head_dim``.  The router is
+    # the ``sigmoid`` rule, the experts and the share the fields above.
+    gqa_layers: Optional[Tuple[int, ...]] = None
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    short_conv_kernel_size: int = 0
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
@@ -168,6 +184,10 @@ class LlamaPretrainConfig:
             self.layer_types = tuple(
                 "gqa_moe_window" if w else "gqa_moe_global"
                 for w in self.sliding_window_layout)
+        if self.gqa_layers is not None and self.layer_types is None:
+            from .hybrid_trunk import kda_kinds
+            self.layer_types = kda_kinds(self.gqa_layers,
+                                         self.num_hidden_layers)
         if self.kv_lora_rank and self.layer_types is None:
             dense = min(self.first_k_dense_replace, self.num_hidden_layers)
             self.layer_types = ("mla_dense",) * dense + ("mla_moe",) * (
@@ -718,7 +738,8 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
             if seg_in is not None:
                 raise NotImplementedError(
                     "packed segments with layers by kind: the state-space "
-                    "mixer has no reset at a document boundary")
+                    "mixer and the delta rule's matrix state have no reset "
+                    "at a document boundary")
             x = hybrid_trunk.trunk(params["blocks"], x, cfg, mesh)
         else:
             x = _trunk_scan(params["blocks"], x, cfg, mesh, seg_in)

@@ -194,17 +194,18 @@ def _experts_placed(text, held, c, f, layers) -> list:
 ROWS_8K, MIXER_WIDTHS = (2, 8192), {4096, 4352, 8512}
 
 
-def _cell_step(mesh, name):
+def _cell_step(mesh, name, conf=None, job=None):
     """A training cell's step as the benchmark builds it, compiled for
-    ``mesh``."""
+    ``mesh``; ``conf`` / ``job``: keys of the configuration / the traffic
+    stated otherwise (another rung of a cut's ladder)."""
     import functools
     import operator
     from benchmark import harness, models
     from paddle_tpu.models.llama_pretrain import (
         init_adafactor_state, make_train_step, param_specs)
     cell = harness.find_cell(name)
-    job, fam = cell.traffic, cell.family
-    cfg = fam.build_cfg(cell.conf, train=True, job=job)
+    job, fam = dict(cell.traffic, **(job or {})), cell.family
+    cfg = fam.build_cfg(dict(cell.conf, **(conf or {})), train=True, job=job)
     specs, shapes = param_specs(cfg, 1), fam.leaf_shapes(cfg)
     with mesh:
         params = models.tree_of(shapes, lambda path: _sds(

@@ -181,15 +181,19 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
     # ... and PR 48 its cell likewise, with the gated short convolution's
     # declared bytes
     conv = "lfm2-24b-a2b.pretrain-8k-conv-moe"
-    every = [DENSE, HYBRID, EXPERT, window, conv]
+    # ... and PR 52 its cell, with the chunked delta rule's declared FLOPs
+    kda = "solar-open2-250b.pretrain-kda-moe"
+    every = [DENSE, HYBRID, EXPERT, window, conv, kda]
     assert {n: m["workloads"] for n, m in new.items()} == {
         "kernel_undeclared_pct.train": every,
         "flops_declared_per_needed.train": every,
         "flash_attn_declared_per_needed.train": every,
         "ssd_scan_bytes_declared_per_needed.train": [HYBRID],
-        "moe_experts_declared_per_needed.train": [EXPERT, window, conv],
+        "moe_experts_declared_per_needed.train": [EXPERT, window, conv,
+                                                  kda],
         "flash_win_declared_per_needed.train": [window],
-        "short_conv_bytes_declared_per_needed.train": [conv]}
+        "short_conv_bytes_declared_per_needed.train": [conv],
+        "kda_chunk_declared_per_needed.train": [kda]}
     for m in new.values():
         assert (m["source"], m["moves"], m["better"], m["layer"]) == (
             "program_counter", "train_tok_s_chip", "lower", "kernels")
@@ -203,4 +207,6 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
         "flash_win_roofline_pct.train",
         "flash_win_declared_per_needed.train",
         "short_conv_mixer_pct.train", "short_conv_roofline_pct.train",
-        "short_conv_bytes_declared_per_needed.train"]
+        "short_conv_bytes_declared_per_needed.train",
+        "kda_mixer_pct.train", "kda_chunk_roofline_pct.train",
+        "kda_chunk_declared_per_needed.train"]

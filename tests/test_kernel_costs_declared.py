@@ -23,6 +23,7 @@ from paddle_tpu.ops.pallas.grouped_mm import grouped_mm, grouped_mm_dw
 from paddle_tpu.ops.pallas.hc_mix import (hc_post_bwd, hc_post_fwd,
                                           hc_pre_bwd, hc_pre_fwd)
 from paddle_tpu.ops.pallas.int8_matmul import int8_matmul
+from paddle_tpu.ops.pallas.kda_chunk import kda_chunked
 from paddle_tpu.ops.pallas.moe_sum_pairs import moe_sum_pairs
 from paddle_tpu.ops.pallas.paged_attention import (
     paged_decode_attention, paged_decode_attention_q8)
@@ -93,6 +94,7 @@ _varlen = lambda q, k, v, seg: flash_attention_segmented(q, k, v, seg,
                                                          causal=True)
 _xbc = lambda xbc, dt, cum: ssd_chunked_xbc(xbc, dt, cum, 128)
 _conv = lambda x, w, b: causal_conv_silu(x, w, b, 256)
+_kda = lambda qkv, g, beta: kda_chunked(qkv, g, beta, 64)
 _paged = lambda *a: paged_decode_attention(*a, force_kernel=True)
 _paged_q8 = lambda *a: paged_decode_attention_q8(*a, force_kernel=True)
 
@@ -107,6 +109,8 @@ SSD = (z((1, 2, 256, 4, 64)), z((1, 2, 256, 4), F32), z((1, 2, 256, 4), F32),
 XBC = (z((1, 512, 512)), z((1, 2, 256, 4), F32), z((1, 2, 256, 4), F32))
 CONV = (z((2, 1024, 768)), z((512, 4), F32), z((512,), F32))
 GATED = (z((2, 1024, 768)), z((256, 3), F32))
+# one row of 512 positions, two heads of 128: two blocks of four chunks
+KDA = (z((1, 512, 768)), z((1, 512, 256), F32), z((1, 512, 2), F32))
 POOL = z((64, 2, 16, 128))
 PAGED = (z((4, 8, 128)), POOL, POOL, z((4, 6), I32), z((4,), I32))
 PAGED_Q8 = (z((4, 8, 128)), z(POOL.shape, I8), z(POOL.shape, I8),
@@ -267,6 +271,18 @@ CASES = [
     # sums
     ("short_conv_bwd", "short_conv_bwd", grad_of(short_conv_gated, 2), GATED,
      11_018_240, 7_430_144, 0),
+    # 16 (head, chunk)s of Q 64, K 128: 9 products of 2 Q^2 K (two an
+    # anchor x 3, g's running sum, T on [Q, 2 K]), 4 of 2 Q^3 and the
+    # blocks' spread 2 Q^2 16 (the inverse), 3 of 2 Q K^2 and P N,
+    # 20,244,480 with the 140 passes over [Q, K] and the 15 eliminations'
+    # 6 over [Q, 16]; 24 exps a key channel a position + 128; q, k, v, o
+    # bf16 + g fp32 = 12 B a channel, beta [512, 2], four states
+    ("kda_chunk_fwd", "kda_chunk_fwd", _kda, KDA,
+     323_911_680, 1_839_104, 3_147_776),
+    # that forward again and twice more; seven bf16 tiles, g and dg,
+    # beta and d beta, the states
+    ("kda_chunk_bwd", "kda_chunk_bwd", grad_of(_kda, 3), KDA,
+     971_735_040, 3_153_920, 3_147_776),
     # the bound it is launched at: ALL 8 tiles of 256 rows; x once a
     # 1024-column panel (2), the fp32 stack once, the result
     ("grouped_mm, 2,048 rows", "grouped_mm", grouped_mm,

@@ -375,6 +375,74 @@ def test_train_step_of_the_conv_kinds_carries_the_family_s_scopes():
         any("/rope/" in p for p in inside)
 
 
+def test_train_step_of_the_kda_kinds_carries_the_family_s_scopes():
+    """``kda_moe`` / ``gqa_gated_moe``: the mixer's six scopes with the
+    convolution's kernels under ``kda_conv`` and the recurrence's under
+    ``kda_chunk``, ``attn_gate`` between ``attn`` and ``attn_out`` in the
+    attention layer, the five ``moe_*`` of the family ``solar_kda_moe`` —
+    ``moe_shared`` among them —, no ``mlp``, no ``rope``, nothing of a
+    block under no scope."""
+    from benchmark.models import solar_kda_moe
+    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
+               intermediate_size=256, num_hidden_layers=4,
+               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+               max_seq_len=256, position_embedding_type="nope",
+               gqa_layers=(0,), kda_num_heads=2, kda_head_dim=128,
+               short_conv_kernel_size=4, moe_intermediate_size=128,
+               n_routed_experts=8, n_shared_experts=1, experts_held=2,
+               expert_first=2, num_experts_per_tok=3,
+               use_pallas_attention=True)
+    assert cfg.layer_types == ("gqa_gated_moe",) + ("kda_moe",) * 3
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = (BLOCK - {"mlp", "rope"}) | {
+        "embed", "layer_scan", "attn", "loss_head", "optimizer"} \
+        | set(solar_kda_moe.SCOPES)
+    assert want <= names, want - names
+    assert not {"mlp", "rope", "qk_norm", "hc_pre", "mla_q", "ssm_conv",
+                "short_conv"} & names
+    assert solar_kda_moe.SCOPES == (
+        "kda_in_proj", "kda_conv", "kda_gates", "kda_chunk", "kda_out_gate",
+        "kda_out_proj", "attn_gate", "moe_route", "moe_dispatch",
+        "moe_experts", "moe_combine", "moe_shared")
+    assert solar_kda_moe.KERNELS == (
+        "kda_chunk_fwd", "kda_chunk_bwd", "causal_conv_fwd",
+        "causal_conv_bwd", "grouped_mm", "grouped_mm_dw", "moe_sum_pairs")
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    vocabulary = xplane_meta.KERNELS + solar_kda_moe.KERNELS
+    for scope, kernel in (("kda_chunk", "kda_chunk_fwd"),
+                          ("kda_chunk", "kda_chunk_bwd"),
+                          ("kda_conv", "causal_conv_fwd"),
+                          ("kda_conv", "causal_conv_bwd"),
+                          ("moe_experts", "grouped_mm"),
+                          ("moe_experts", "grouped_mm_dw"),
+                          ("moe_combine", "moe_sum_pairs"),
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dkv")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            vocabulary) == kernel
+    # a kernel of this family's is no name of the base vocabulary
+    assert xplane_meta.kernel_of(
+        "jit(step)/block/kda_chunk/kda_chunk_fwd/pallas_call",
+        xplane_meta.KERNELS) == ""
+    names_of = xplane_meta.SCOPES + solar_kda_moe.SCOPES
+    inside = [p for p in paths if "/block/" in p or p.startswith("block/")]
+    assert len(inside) > 200
+    assert {xplane_meta.scope_of(p, names_of) for p in inside} <= (
+        (BLOCK - {"mlp", "rope"}) | {"attn"} | set(solar_kda_moe.SCOPES))
+    # the gate stands between the kernel and the out-projection
+    assert any("/attn_gate/" in p for p in inside)
+
+
 @pytest.mark.parametrize("on_load,on_all", [(6, 0), (4, 2), (0, 0)])
 def test_moe_bounds_counts_the_passes_by_their_bound(on_load, on_all):
     """``tools/moe_bounds.py``: a pass of the routed path is two
@@ -659,7 +727,7 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     # call sites: a name is one site — but for the windowed form, which
     # runs the same three sites on fewer block pairs under names of its
     # own (26 sites, 29 names)
-    assert len(names) == 29 and len(set(names)) == 29
+    assert len(names) == 31 and len(set(names)) == 31
     # the readers' copy still lists the three names retired with their
     # kernels (ROADMAP D14): a subset until a benchmark PR prunes it.
     # A kernel of ONE family's program is named by that family
@@ -669,7 +737,8 @@ def test_every_pallas_call_site_carries_a_distinct_name():
     assert own == {"ssd_scan_fwd", "ssd_scan_bwd", "causal_conv_fwd",
                    "causal_conv_bwd", "grouped_mm", "grouped_mm_dw",
                    "moe_sum_pairs", "flash_win_fwd", "flash_win_bwd_dq",
-                   "flash_win_bwd_dkv", "short_conv_fwd", "short_conv_bwd"}
+                   "flash_win_bwd_dkv", "short_conv_fwd", "short_conv_bwd",
+                   "kda_chunk_fwd", "kda_chunk_bwd"}
     assert not own & set(xplane_meta.KERNELS)
     ahead = set(AHEAD_OF_EVERY_FAMILY)
     assert set(names) <= set(xplane_meta.KERNELS) | own | ahead
