@@ -777,6 +777,40 @@ def make_forward(cfg: LlamaPretrainConfig, mesh: Optional[Mesh] = None,
 
 
 # ---------------------------------------------------------------------------
+# optimizer state: born where the jitted step leaves it.  A state leaf
+# handed in on another placement than the step returns it with (a bare
+# ``jnp.zeros`` carries no mesh) makes the step's SECOND call a new
+# program: traced and compiled again.
+# ---------------------------------------------------------------------------
+def _mesh_of(p) -> Optional[Mesh]:
+    sh = getattr(p, "sharding", None)
+    if isinstance(sh, NamedSharding) and isinstance(sh.mesh, Mesh):
+        return sh.mesh
+    return None
+
+
+def _zeros_less(p, axis: int):
+    """fp32 zeros for ``p``'s mean over ``axis``: on ``p``'s mesh with
+    the axes that are left sharded as ``p`` shards them — what the
+    reduction leaves; a plain array where ``p`` carries no mesh."""
+    keep = [a for a in range(p.ndim) if a != axis % p.ndim]
+    mesh = _mesh_of(p)
+    where = None
+    if mesh is not None:
+        spec = tuple(p.sharding.spec) + (None,) * p.ndim
+        where = NamedSharding(mesh, P(*(spec[a] for a in keep)))
+    return jnp.zeros([p.shape[a] for a in keep], jnp.float32, device=where)
+
+
+def _step_count(params):
+    """``t`` = 0, on every device of the parameters' mesh."""
+    mesh = next(filter(None, map(_mesh_of,
+                                 jax.tree_util.tree_leaves(params))), None)
+    return jnp.zeros((), jnp.int32,
+                     device=mesh and NamedSharding(mesh, P()))
+
+
+# ---------------------------------------------------------------------------
 # fused AdamW (sharded states = ZeRO-1/2)
 # ---------------------------------------------------------------------------
 def init_adamw_state(params, mesh: Optional[Mesh] = None,
@@ -802,7 +836,7 @@ def init_adamw_state(params, mesh: Optional[Mesh] = None,
             v = jax.device_put(v, sh)
         return {"m": m, "v": v}
 
-    return {"t": jnp.zeros((), jnp.int32),
+    return {"t": _step_count(params),
             "moments": jax.tree_util.tree_map(make, params)}
 
 
@@ -866,9 +900,10 @@ def init_adafactor_state(params, mesh: Optional[Mesh] = None,
     def make(p):
         st = {}
         if _factored(p):
-            # vr/vc are per-row/col vectors (KBs) — left replicated
-            st["vr"] = jnp.zeros(p.shape[:-1], jnp.float32)
-            st["vc"] = jnp.zeros(p.shape[:-2] + p.shape[-1:], jnp.float32)
+            # vr/vc are per-row/col vectors (KBs): the means over the
+            # last axis and over the last but one
+            st["vr"] = _zeros_less(p, -1)
+            st["vc"] = _zeros_less(p, -2)
         else:
             # full copy for small params: inherit the param's sharding
             st["v"] = jnp.zeros_like(p, dtype=jnp.float32)
@@ -882,7 +917,7 @@ def init_adafactor_state(params, mesh: Optional[Mesh] = None,
             st["m"] = m
         return st
 
-    return {"t": jnp.zeros((), jnp.int32),
+    return {"t": _step_count(params),
             "moments": jax.tree_util.tree_map(make, params)}
 
 
@@ -1011,4 +1046,40 @@ def make_train_step(cfg: LlamaPretrainConfig, mesh: Mesh, pp: int = 1,
                     weight_decay=weight_decay)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    if mesh.size == 1:      # nothing to pin: the program it has been
+        return jax.jit(step, donate_argnums=(0, 1))
+    return _PinnedStep(step)
+
+
+class _PinnedStep:
+    """``step(params, opt_state, tokens)`` on a mesh of several devices:
+    jitted with its first two operands donated and every leaf it
+    returns for them pinned where the donated leaf lives, one program a
+    placement of the operands.  Left to the compiler an output comes
+    back on a sharding of ITS choosing (AdamW under ZeRO returned the
+    tensor-parallel weights replicated), and the second and the third
+    call each compiled a program for what the call before had left.  A
+    leaf that carries no mesh is left to the compiler."""
+
+    def __init__(self, step):
+        self._step, self._jitted = step, {}
+
+    def _for(self, params, opt_state):
+        flat, tree = jax.tree_util.tree_flatten((params, opt_state))
+        where = tuple(x.sharding if _mesh_of(x) is not None else None
+                      for x in flat)
+        if (tree, where) not in self._jitted:
+            pinned = jax.tree_util.tree_unflatten(tree, where)
+            self._jitted[tree, where] = jax.jit(
+                self._step, donate_argnums=(0, 1),
+                out_shardings=(*pinned, None))
+        return self._jitted[tree, where]
+
+    def __call__(self, params, opt_state, tokens):
+        return self._for(params, opt_state)(params, opt_state, tokens)
+
+    def lower(self, params, opt_state, tokens):
+        return self._for(params, opt_state).lower(params, opt_state, tokens)
+
+    def _cache_size(self) -> int:
+        return sum(j._cache_size() for j in self._jitted.values())

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 import _toy_cell
+from _toy_cell import layer_of  # noqa: F401
 
 toy, sound, ref = _toy_cell.fixtures("solar", "config_solar.json",
                                      "train_job_solar.json")
@@ -22,12 +23,6 @@ F32 = jnp.float32
 # a block alone, fp32 against fp32: rounding (measured <= 1.6e-5 of the
 # largest output, <= 7e-6 of a leaf's gradient norm)
 BLOCK = 1e-4
-
-
-def layer_of(toy, kind, layer=0):
-    """One layer's leaves out of the kind's stack."""
-    return {nm: leaf[layer] for nm, leaf in
-            toy.params0["blocks"][kind].items()}
 
 
 def x_of(toy, seed=0):

@@ -95,6 +95,39 @@ def follow(toy, cfg, steps=2, extra_leaves=None):
             toy.batches[:steps], functools.partial(_leaf, start))[2]
 
 
+def layer_of(toy, kind, layer=0):
+    """One layer's leaves out of the kind's stack."""
+    return {nm: leaf[layer] for nm, leaf in
+            toy.params0["blocks"][kind].items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _block_case(toy, kind, streams):
+    """The kind's first layer, a seeded input of ``streams`` residual
+    streams, and the plain reference's block on them."""
+    bp = layer_of(toy, kind)
+    x = jax.random.normal(jax.random.PRNGKey(3),
+                          (1, SEQ, streams * toy.cfg.hidden_size),
+                          jnp.float32)
+    blk = toy.cell.block_reference
+    want = jax.jit(lambda bp, x: blk.KINDS[kind][1](
+        x, bp, blk.dims_of(toy.conf))[0])(bp, x)
+    return bp, x, want
+
+
+def block_gap(toy, cfg, kind, body, streams=1):
+    """How far the PROGRAM's block of ``kind`` (``body(leaves, x, cfg)``)
+    under ``cfg`` lies from the plain reference's under the toy's own
+    configuration: one layer of the seed's leaves, one seeded input, the
+    norm of the outputs' difference over the reference's.  What a
+    one-place alteration of a BLOCK changes shows here in a program of
+    one layer (seconds) as it does in the first step of the whole toy."""
+    bp, x, want = _block_case(toy, kind, streams)
+    got = jax.jit(lambda bp, x: body(bp, x, cfg))(bp, x)
+    return float(jnp.linalg.norm((got - want).ravel())
+                 / jnp.linalg.norm(want.ravel()))
+
+
 def follow_reference(toy, block_reference=None, steps=2):
     """The plain reference's first ``steps`` steps, by the family's
     blocks or by ``block_reference`` in their place."""

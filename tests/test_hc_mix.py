@@ -133,9 +133,14 @@ def worst(a, b):
 
 
 def everything(fn, w, x, g):
-    """The output and every cotangent of ``fn(w, x)`` pulled from g."""
-    out, pull = jax.vjp(fn, w, x)
-    return out, pull(g.astype(out.dtype))
+    """The output and every cotangent of ``fn(w, x)`` pulled from g, as
+    ONE jitted program: op by op a form is hundreds of dispatches, each
+    its own small compile (under six workers' load 23-40 s a case
+    where this is 5-8)."""
+    def both(w, x, g):
+        out, pull = jax.vjp(fn, w, x)
+        return out, pull(g.astype(out.dtype))
+    return jax.jit(both)(w, x, g)
 
 
 @functools.lru_cache(maxsize=None)

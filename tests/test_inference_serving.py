@@ -100,11 +100,13 @@ def test_concurrent_requests_no_cross_leak(artifact):
             if not np.allclose(got, want, atol=1e-5):
                 errs.append(i)
 
-    ts = [threading.Thread(target=worker, args=(i,)) for i in range(4)]
+    ts = [threading.Thread(target=worker, args=(i,), daemon=True)
+          for i in range(4)]
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        t.join(120)
+        assert not t.is_alive(), "a request never came back from the pool"
     assert not errs, f"cross-request leaks from threads {errs}"
 
 

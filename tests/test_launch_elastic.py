@@ -36,6 +36,8 @@ def test_pod_failure_detection():
     p.add_container([sys.executable, "-c", "import sys; sys.exit(3)"])
     p.add_container([sys.executable, "-c", "pass"])
     p.deploy()
+    for c in p.containers:          # join() itself waits without a bound
+        assert c.wait(60) is not None
     p.join()
     failed = p.failed_containers()
     assert len(failed) == 1 and failed[0].exit_code == 3

@@ -1,12 +1,22 @@
 """Flagship LLaMA tests: Layer model, functional pretrain engine,
 hybrid-mesh train step, graft entry.
 
-``test_graft_entry`` is the suite's longest case by far (five minutes of
-one worker under the other five's load, ~1,200 core-seconds: it RUNS the
-8-device programs of ``__graft_entry__.dryrun_multichip``) and stays in
-this file of 23 cases on purpose: xdist hands files out by their NUMBER
-OF TESTS, largest first, so a one-case file would start last and be the
-run's tail (read: +67 s; PERF.md section 6)."""
+``test_graft_entry`` holds the entry point to a finite loss under
+``jax.jit`` (seconds).  The seven 8-device programs of
+``__graft_entry__.dryrun_multichip`` RUN in
+``test_graft_entry_dryrun_multichip``, which is marked ``slow``: alone
+they are three minutes, under the other five workers' load five to seven
+of one worker and ~1,200 core-seconds — a seventh of tier-1 — because
+eight device threads spin in CPU collectives while the other workers
+want the cores, and nearly all of it is ONE program, the 32k-vocabulary
+step at hidden 1024.  What each program exercises stays in tier-1 at
+dims that compile in seconds: the dp x pp x mp step in
+``test_pretrain_engine_hybrid_meshes``, the three other train steps in
+``test_hybrid_step_layouts`` below, expert parallelism in
+``test_expert_parallel.py``, the tensor-parallel engines in
+``test_serving_tp.py`` and ``test_serving_mixed.py`` (CHANGES.md, PR 53,
+has the table).  ``pytest -m slow tests/test_llama_flagship.py -k
+dryrun`` runs the long case."""
 
 import os
 import sys
@@ -122,15 +132,164 @@ def test_pipeline_matches_single_stage():
                                rtol=2e-5)
 
 
-def test_graft_entry():
+def _graft_entry():
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    import __graft_entry__ as ge
+    import __graft_entry__
+    return __graft_entry__
+
+
+def test_graft_entry():
     import jax
-    fn, args = ge.entry()
+    fn, args = _graft_entry().entry()
     out = jax.jit(fn)(*args)
     assert np.isfinite(float(out))
-    ge.dryrun_multichip(8)
+
+
+@pytest.mark.slow
+def test_graft_entry_dryrun_multichip():
+    _graft_entry().dryrun_multichip(8)
+
+
+# The three train steps of ``dryrun_multichip(8)`` that
+# ``test_pretrain_engine_hybrid_meshes`` does not run, on the same meshes
+# with the same switches.  ``bench_layout`` keeps what the BENCH-DIMS
+# program is there for — heads 128 wide, the vocabulary sharded over
+# ``mp``, ZeRO over ``sharding``, the ring over ``sep``, remat — and cuts
+# hidden 1024 -> 256, the vocabulary 32,000 -> 512 and the row 128 -> 64.
+STEP_LAYOUTS = {
+    "ring_over_sep": dict(
+        mesh=dict(dp=4, sep=2), zero_axis=None, rows=8, seq=33,
+        cfg=dict(context_parallel="ring")),
+    "vpp_zero_over_sharding": dict(
+        mesh=dict(dp=2, pp=2, sharding=2), zero_axis="sharding", rows=16,
+        seq=32, step=dict(pp=2, microbatches=2, vpp=2),
+        cfg=dict(num_hidden_layers=4)),
+    "bench_layout": dict(
+        mesh=dict(sharding=2, sep=2, mp=2), zero_axis="sharding", rows=2,
+        seq=65,
+        cfg=dict(vocab_size=512, hidden_size=256, intermediate_size=512,
+                 num_attention_heads=2, num_key_value_heads=2,
+                 max_seq_len=64, remat=True, context_parallel="ring",
+                 loss_chunks=1)),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(STEP_LAYOUTS))
+def test_hybrid_step_layouts(layout):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama_pretrain import (
+        LlamaPretrainConfig, build_mesh, init_params, init_adamw_state,
+        make_train_step)
+    case = STEP_LAYOUTS[layout]
+    how = case.get("step", {})
+    cfg = LlamaPretrainConfig(**dict(
+        dict(vocab_size=128, hidden_size=64, intermediate_size=192,
+             num_hidden_layers=2, num_attention_heads=4,
+             num_key_value_heads=4, max_seq_len=32,
+             use_pallas_attention=False, sequence_parallel=False,
+             remat=False, dtype=jnp.float32), **case["cfg"]))
+    assert cfg.head_dim == (128 if layout == "bench_layout" else 16)
+    mesh = build_mesh(**case["mesh"])
+    with mesh:
+        params = init_params(
+            cfg, jax.random.PRNGKey(0), mesh,
+            **{k: how[k] for k in ("pp", "vpp") if k in how})
+        if layout == "bench_layout":
+            assert params["lm_head"].sharding.spec[-1] == "mp"
+            assert params["embed"].sharding.spec[0] == "mp"
+        opt = init_adamw_state(params, mesh, zero_axis=case["zero_axis"])
+        step = make_train_step(cfg, mesh, lr=1e-3, **how)
+        toks = jnp.asarray(np.random.RandomState(4).randint(
+            0, cfg.vocab_size, (case["rows"], case["seq"])))
+        _, _, loss = step(params, opt, toks)
+    assert np.isfinite(float(loss))
+
+
+ONCE = {"adafactor-1": ("adafactor", {}),
+        "adafactor-dp4xmp2": ("adafactor", dict(dp=4, mp=2)),
+        "adamw-1": ("adamw", {}),
+        "adamw-dp4xmp2-zero": ("adamw", dict(dp=4, mp=2))}
+
+
+@pytest.mark.parametrize("case", sorted(ONCE))
+def test_a_step_compiles_once(case):
+    """A step from ``make_train_step`` called three times with the state
+    its constructor made is ONE program: lowered once and compiled once
+    (the events ``benchmark/harness.CompileClock`` counts), and where
+    the mesh has more than one device one entry in jit's cache, every
+    leaf coming back where it went in.  Before PR 53 the constructors
+    made ``t`` and adafactor's ``vr`` / ``vc`` with a bare ``jnp.zeros``
+    — arrays that carry no mesh, which the step returns placed on it —
+    and the step left its outputs' shardings to the compiler: 2, 2, 2
+    and 3 programs in these four cases, each traced, lowered and
+    compiled.  On ONE device the step stays unpinned, so that a cell's
+    compiled text stays what it was: the compiler's words for
+    "replicated" then differ from the constructor's, and jit keeps a
+    second entry for the same executable (no trace, no lowering, no
+    compile: the second call of the ``solar`` toy's step takes 0.26 s,
+    its first 20.7)."""
+    import jax
+    import jax.monitoring
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama_pretrain import (
+        LlamaPretrainConfig, build_mesh, init_params, init_adafactor_state,
+        init_adamw_state, make_train_step)
+    optimizer, dims = ONCE[case]
+    cfg = LlamaPretrainConfig(
+        vocab_size=256, hidden_size=128, intermediate_size=256,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_seq_len=32, use_pallas_attention=False,
+        sequence_parallel=False, remat=False, dtype=jnp.float32)
+    n = int(np.prod(list(dims.values()) or [1]))
+    mesh = build_mesh(devices=jax.devices()[:n], **dims)
+    seen = []
+
+    def listener(event, secs, fun_name=None, **_):
+        if fun_name == "jit(step)":
+            seen.append(event.rsplit("/", 1)[-1])
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        with mesh:
+            params = init_params(cfg, jax.random.PRNGKey(0), mesh)
+            if optimizer == "adafactor":
+                state = init_adafactor_state(params)
+                bare = init_adafactor_state(
+                    jax.tree_util.tree_map(np.asarray, params))
+            else:
+                state = init_adamw_state(params, mesh, zero_axis="dp")
+                bare = init_adamw_state(
+                    jax.tree_util.tree_map(np.asarray, params))
+            # the bits of a state over parameters that carry no mesh,
+            # every leaf on the parameters' mesh
+            for (path, a), b in zip(
+                    jax.tree_util.tree_flatten_with_path(state)[0],
+                    jax.tree_util.tree_leaves(bare)):
+                assert a.sharding.mesh == mesh, path
+                assert a.dtype == b.dtype, path
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              str(path))
+            step = make_train_step(cfg, mesh, optimizer=optimizer)
+            toks = jnp.asarray(np.random.RandomState(0).randint(
+                0, cfg.vocab_size, (4, 33)))
+            placed = jax.tree_util.tree_map(lambda x: x.sharding,
+                                            (params, state))
+            losses = []
+            for _ in range(3):
+                params, state, loss = step(params, state, toks)
+                losses.append(float(loss))
+                if n > 1:
+                    assert jax.tree_util.tree_map(
+                        lambda x: x.sharding, (params, state)) == placed
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert seen == ["jaxpr_to_mlir_module_duration",
+                    "backend_compile_duration"], seen
+    if n > 1:
+        assert step._cache_size() == 1
+    assert losses[2] < losses[1] < losses[0], losses
 
 
 @pytest.mark.slow

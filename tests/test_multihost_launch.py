@@ -69,7 +69,6 @@ def _dense_reference():
     return losses
 
 
-@pytest.mark.timeout(420)
 def test_two_node_pod_launch_hybrid_dp_mp(tmp_path):
     port = _free_port()
     out = tmp_path / "pod.json"

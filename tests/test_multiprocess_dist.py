@@ -48,7 +48,6 @@ def _reference_losses():
     return losses
 
 
-@pytest.mark.timeout(300)
 def test_two_process_launch_allreduce_and_dp_step(tmp_path):
     port = _free_port()
     out = tmp_path / "rank0.json"

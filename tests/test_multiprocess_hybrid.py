@@ -144,7 +144,6 @@ def _pp_reference():
     return losses
 
 
-@pytest.mark.timeout(420)
 def test_fleet_tp_pp_zero2_across_process_boundaries(tmp_path):
     port = _free_port()
     out = tmp_path / "rank0.json"
@@ -204,7 +203,6 @@ def test_fleet_tp_pp_zero2_across_process_boundaries(tmp_path):
         data["moe"], mod.moe_losses(jax.devices()[:2]), atol=1e-4)
 
 
-@pytest.mark.timeout(700)
 def test_combined_dp_mp_hybrid_across_4_processes(tmp_path):
     """dp=2 x mp=2 over FOUR OS processes at bench-ish dims (head_dim
     128, vocab 8192): the hybrid train-step losses must match the same

@@ -1,14 +1,16 @@
-"""One key of the PROGRAM's configuration changed, and the toy window
-cell's comparison with the plain reference (``tests/_toy_cell.py``,
-``tests/test_smallthinker_trunk.py``) must fail.
+"""One key of the PROGRAM's configuration changed, and a window layer's
+comparison with the plain reference's block (``_toy_cell.block_gap``;
+the toy: ``tests/_smallthinker_toy.py``) must fail.
 """
 
 import dataclasses
+import functools
 
 import pytest
 
-from _smallthinker_toy import ref, toy  # noqa: F401
-from _toy_cell import BROKEN, first_step_gap, follow
+from _smallthinker_toy import toy  # noqa: F401
+from _toy_cell import BROKEN, SOUND, block_gap
+from paddle_tpu.models import hybrid_trunk
 
 # one thing changed in the PROGRAM's configuration
 PROGRAM = {
@@ -20,9 +22,11 @@ PROGRAM = {
 
 
 @pytest.mark.parametrize("what", sorted(PROGRAM))
-def test_a_program_altered_in_one_place_fails(toy, ref, what):
-    """Every alteration shows in the FIRST step's gradient (the least:
-    4.0e-3, the window one key short), so the second step is not
-    followed."""
+def test_a_program_altered_in_one_place_fails(toy, what):
+    """Every key is read inside a window layer, so ONE layer shows it
+    (read: 1e-7 sound; the least 2.4e-2, the window one key short)."""
     cfg = dataclasses.replace(toy.cfg, **PROGRAM[what](toy.cfg))
-    assert first_step_gap(follow(toy, cfg, steps=1), ref) > BROKEN
+    gap = functools.partial(
+        block_gap, toy, kind="gqa_moe_window",
+        body=functools.partial(hybrid_trunk._gqa_moe_block, window=True))
+    assert gap(toy.cfg) < SOUND and gap(cfg) > BROKEN
