@@ -125,34 +125,22 @@ def log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
 
-class CompileClock:
-    """Sums jax's own compile-time events: backend compile seconds and
-    persistent-cache hits/misses for everything this process compiles."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.backend_s = 0.0
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._dur)
-        mon.register_event_listener(self._ev)
-
-    def _dur(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.backend_s += secs
-
-    def _ev(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snap(self):
-        return (self.backend_s, self.hits, self.misses)
-
-    def since(self, snap) -> str:
-        return (f"compile_s={self.backend_s - snap[0]:.1f} "
-                f"cache_hits={self.hits - snap[1]} "
-                f"cache_misses={self.misses - snap[2]}")
+def compiled_since(t_epoch_s: float) -> str:
+    """What the process traced, lowered and compiled (or loaded from the
+    persistent cache) since ``time.time()`` read ``t_epoch_s``, from the
+    program's own log (``paddle_tpu.observability.compile_log``), and
+    its three slowest programs by name."""
+    from paddle_tpu.observability import compile_log
+    t = compile_log.totals(since_epoch_s=t_epoch_s)
+    slow = ", ".join(
+        f"{r['program']} {compile_log.total_s(r):.1f}s" for r in
+        compile_log.by_program(top=3, since_epoch_s=t_epoch_s))
+    # ``compile_s=`` first and ``cache_misses=`` last: tools/chip_proof.sh
+    # cuts a line at the one and ends its warm check on the other
+    return (f"compile_s={t['backend_s']:.1f} trace_s={t['trace_s']:.1f} "
+            f"lower_s={t['lower_s']:.1f} programs={t['programs']} "
+            f"slowest=[{slow}] cache_hits={t['hits']} "
+            f"cache_misses={t['misses']}")
 
 
 def _model_cfg(sz: SmokeSizes, depth: int, train: bool,
@@ -278,7 +266,7 @@ def _check_first_loss(sz: SmokeSizes, loss0: float) -> None:
              f"ln(vocab)+0.5 = {lnv + 0.5:.3f}")
 
 
-def phase_trainer(sz: SmokeSizes, clock: CompileClock) -> None:
+def phase_trainer(sz: SmokeSizes) -> None:
     import jax
     from paddle_tpu.models.llama_pretrain import (
         build_mesh, init_adafactor_state, init_params, make_train_step)
@@ -302,11 +290,11 @@ def phase_trainer(sz: SmokeSizes, clock: CompileClock) -> None:
         _require(loader.transport == "shm",
                  "shared-memory transport was asked for and not got "
                  f"(live transport: {loader.transport})")
-        snap = clock.snap()
+        snap = time.time()
         t0 = time.perf_counter()
         compiled = step.lower(params, opt_state, tokens).compile()
         log(f"trainer: step compiled in {time.perf_counter() - t0:.1f}s "
-            f"({clock.since(snap)})")
+            f"({compiled_since(snap)})")
         ma = compiled.memory_analysis()
         if ma is not None:
             log("trainer: compiled memory: args "
@@ -412,7 +400,7 @@ def _wait_queued(url: str, n: int, timeout: float = 60.0) -> None:
         time.sleep(0.005)
 
 
-def phase_server(sz: SmokeSizes, clock: CompileClock) -> None:
+def phase_server(sz: SmokeSizes) -> None:
     import urllib.request
 
     import jax
@@ -439,7 +427,7 @@ def phase_server(sz: SmokeSizes, clock: CompileClock) -> None:
     log(f"server: page pools {pool_bytes / 2**30:.2f} GiB; HBM bytes in "
         f"use {_hbm(jax.devices()[:1])}")
     fallbacks0 = flash_varlen.dense_fallback_count
-    snap = clock.snap()
+    snap = time.time()
     srv = GenerationServer(cfg, params, cache)
     port = srv.start()
     url = f"http://127.0.0.1:{port}"
@@ -520,7 +508,7 @@ def phase_server(sz: SmokeSizes, clock: CompileClock) -> None:
                  "/metrics lacks the engine or HTTP counters")
         eng = srv.engine
         log(f"server: decode steps {eng.decode_steps}, prefill dispatches "
-            f"{eng.prefill_calls}, {clock.since(snap)}")
+            f"{eng.prefill_calls}, {compiled_since(snap)}")
         _require(eng.decode_steps > 0 and eng.prefill_calls == 2,
                  "expected decode steps and exactly two prefill "
                  "dispatches: the fixed prompt, then one packed wave")
@@ -552,7 +540,7 @@ def _check_all_devices_hold_bytes(what: str) -> None:
              f"— state was not spread over the mesh")
 
 
-def phase_tp_engine(sz: SmokeSizes, clock: CompileClock) -> None:
+def phase_tp_engine(sz: SmokeSizes) -> None:
     import re
 
     import jax
@@ -580,14 +568,14 @@ def phase_tp_engine(sz: SmokeSizes, clock: CompileClock) -> None:
                 for r in eng.run_to_completion()}
         return params, eng, [done[r] for r in rids]
 
-    snap = clock.snap()
+    snap = time.time()
     mesh1 = build_mesh(mp=1, devices=devs[:1])
     params1, _, ref = run(mesh1)
-    log(f"tp engine: one-chip engine done ({clock.since(snap)})")
-    snap = clock.snap()
+    log(f"tp engine: one-chip engine done ({compiled_since(snap)})")
+    snap = time.time()
     mesh4 = build_mesh(mp=4, devices=devs[:4])
     _, eng4, got = run(mesh4)
-    log(f"tp engine: mp=4 engine done ({clock.since(snap)})")
+    log(f"tp engine: mp=4 engine done ({compiled_since(snap)})")
     _check_all_devices_hold_bytes("tp engine")
     for i, ((p, new), r, g) in enumerate(zip(prompts, ref, got)):
         _require(len(g) == max(new, COMPARE_TOKENS),
@@ -607,7 +595,7 @@ def phase_tp_engine(sz: SmokeSizes, clock: CompileClock) -> None:
              "no cross-device all-reduce in the TP decode step")
 
 
-def phase_sp_train(sz: SmokeSizes, clock: CompileClock) -> None:
+def phase_sp_train(sz: SmokeSizes) -> None:
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -639,13 +627,13 @@ def phase_sp_train(sz: SmokeSizes, clock: CompileClock) -> None:
     log(f"sp train: depth {sz.depth4} (cut), hidden {sz.hidden}, "
         f"b={sz.batch} s={sz.seq}; dp2 x mp2 + sequence_parallel vs one "
         f"device, same tokens, 3 steps")
-    snap = clock.snap()
+    snap = time.time()
     ref = run(build_mesh(devices=devs[:1]), sp=False)
-    log(f"sp train: one-device losses {ref} ({clock.since(snap)})")
+    log(f"sp train: one-device losses {ref} ({compiled_since(snap)})")
     _free_device_memory()
-    snap = clock.snap()
+    snap = time.time()
     got = run(build_mesh(dp=2, mp=2, devices=devs[:4]), sp=True)
-    log(f"sp train: dp2 x mp2 losses {got} ({clock.since(snap)})")
+    log(f"sp train: dp2 x mp2 losses {got} ({compiled_since(snap)})")
     for i, (r, g) in enumerate(zip(ref, got)):
         _require(math.isfinite(g) and abs(g - r) <= SP_LOSS_TOL * abs(r),
                  f"sp train: step {i} loss {g} vs one-device {r} "
@@ -678,21 +666,21 @@ def main(argv=None) -> int:
     # a second run shows the cache works (jax's default skips sub-second
     # compiles)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    clock = CompileClock()
+    t_start = time.time()
     log(f"device {platform} / {devices[0].device_kind} x {len(devices)}; "
         f"compile cache at {cache_dir}")
     sz = SmokeSizes(seed=args.seed)
     t0 = time.perf_counter()
     if args.chips == 4:
-        phase_tp_engine(sz, clock)
+        phase_tp_engine(sz)
         _free_device_memory()
-        phase_sp_train(sz, clock)
+        phase_sp_train(sz)
     else:
-        phase_trainer(sz, clock)
+        phase_trainer(sz)
         _free_device_memory()
-        phase_server(sz, clock)
+        phase_server(sz)
     log(f"all phases passed in {time.perf_counter() - t0:.0f}s; "
-        f"{clock.since((0.0, 0, 0))}")
+        f"{compiled_since(t_start)}")
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": devices[0].device_kind,
         "count": len(devices)}}), flush=True)

@@ -12,6 +12,15 @@ The public namespace mirrors ``paddle``:
 
 from __future__ import annotations
 
+import sys as _sys
+import time as _time
+
+# the package's own import as ONE event of the ring (``paddle_tpu.import``,
+# handed over at the bottom of this file): two clock reads, on both clocks
+# (observability/events.stamp, which cannot be imported yet)
+_import_t0 = _time.monotonic()
+_jax_preloaded = "jax" in _sys.modules
+
 __version__ = "0.1.0"
 
 # Paddle's default integer dtype is int64 and float64 ops are part of the
@@ -19,6 +28,11 @@ __version__ = "0.1.0"
 # (bf16/f32) are always set explicitly, so this does not slow the TPU path.
 import jax as _jax
 _jax.config.update("jax_enable_x64", True)
+
+# the compile log listens before anything the package or its caller
+# compiles (no flag: docs/OBSERVABILITY.md, "Compile log")
+from .observability import compile_log as _compile_log
+_compile_log.enable()
 
 # flags must exist before anything reads them
 from .flags import get_flags, set_flags, flags  # noqa: F401
@@ -122,3 +136,9 @@ class _Version:
 
 
 version = _Version()
+
+from .observability.events import default_ring as _ring, stamp as _stamp
+_import_t1 = _stamp()
+_ring().emit("paddle_tpu.import", at=_import_t1,
+             dur_s=_import_t1[0] - _import_t0,
+             jax_preloaded=_jax_preloaded)

@@ -24,6 +24,7 @@ from typing import Any, Callable, List, Optional
 
 import numpy as np
 
+from ..observability.events import default_ring, stamp
 from ..profiler.utils import RecordEvent, TracerEventType
 
 __all__ = ["np_collate", "MultiprocessBatchIterator"]
@@ -125,6 +126,15 @@ class MultiprocessBatchIterator:
                  mp_context: Optional[str] = None,
                  use_shared_memory: Optional[bool] = None,
                  shm_ring_bytes: int = 64 << 20):
+        # ``dataloader.start``: from here — the shared-memory rings, the
+        # worker processes' spawn — to the first batch handed out; once
+        # per iterator, a span for an open profiler session and ONE ring
+        # event with its end on both clocks (a set-up lies before any
+        # session a benchmark opens)
+        self._start_t0 = stamp()[0]
+        self._start_span = RecordEvent("dataloader.start",
+                                       TracerEventType.Dataloader)
+        self._start_span.begin()
         self._batches = list(batch_indices)
         self._collate = collate_fn or np_collate
         self._timeout = timeout or None
@@ -223,7 +233,24 @@ class MultiprocessBatchIterator:
     def __iter__(self):
         return self
 
+    def _started(self):
+        span, self._start_span = self._start_span, None
+        found = {"num_workers": self._num_workers,
+                 "transport": self.transport}
+        span.annotate(**found)
+        span.end()
+        at = stamp()
+        default_ring().emit("dataloader.start", at=at,
+                            dur_s=at[0] - self._start_t0, **found)
+
     def __next__(self):
+        try:
+            return self._next()
+        finally:
+            if self._start_span is not None:
+                self._started()
+
+    def _next(self):
         if self._rcvd_idx >= len(self._batches):
             self.shutdown()
             raise StopIteration

@@ -14,6 +14,10 @@ scrape/export recipes):
 * :mod:`.engine_metrics` — the instrument bundle the
   continuous-batching serving stack records into (single source of
   truth for the metric catalogue).
+* :mod:`.compile_log` — the program's own log of what it traced,
+  lowered, compiled or loaded from the persistent cache, by program
+  name and on both clocks (a ``jax.monitoring`` listener registered
+  when ``paddle_tpu`` is imported).
 * :mod:`.tracing` — end-to-end per-request distributed tracing:
   trace-context propagation across router/engine/handoff/failover
   boundaries, retirement-time span materialization from per-request
@@ -25,7 +29,7 @@ jitted programs and never forces a device sync — values are recorded
 from numbers the engine already materializes on host.
 """
 
-from .events import EventRing, default_ring            # noqa: F401
+from .events import EventRing, default_ring, stamp     # noqa: F401
 from .metrics import (Counter, Gauge, Histogram,       # noqa: F401
                       MetricsRegistry, default_registry)
 from .engine_metrics import (EngineMetrics,            # noqa: F401
@@ -33,12 +37,14 @@ from .engine_metrics import (EngineMetrics,            # noqa: F401
 from .fleet_metrics import FleetMetrics                # noqa: F401
 from .disagg_metrics import DisaggMetrics              # noqa: F401
 from .transport_metrics import TransportMetrics        # noqa: F401
+from . import compile_log                              # noqa: F401
 from .tracing import (PHASES, TraceContext, Tracer,    # noqa: F401
                       TraceStore, advance_phase, default_tracer,
                       finalize_request_trace, phase_clocks)
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "default_registry", "EventRing", "default_ring",
+           "default_registry", "EventRing", "default_ring", "stamp",
+           "compile_log",
            "EngineMetrics", "bind_engine_gauges", "FleetMetrics",
            "DisaggMetrics", "TransportMetrics", "PHASES",
            "TraceContext", "Tracer",

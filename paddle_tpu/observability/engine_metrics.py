@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import weakref
 
+from . import compile_log
 from .events import EventRing
 from .metrics import MetricsRegistry, default_registry
 
@@ -321,6 +322,9 @@ class EngineMetrics:
         self.spec_acceptance = r.gauge(
             "paddle_tpu_engine_spec_acceptance_ratio",
             "Accepted draft tokens / drafted tokens, lifetime")
+
+        # -- what the process compiled (observability/compile_log.py) ---
+        compile_log.bind(r)
 
 
 def _weak_fn(obj, fn, default: float = 0.0):

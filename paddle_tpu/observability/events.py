@@ -7,10 +7,14 @@ timeouts, bench backend-init attempts — without unbounded growth
 and drops the oldest).
 
 Events are plain dicts (JSON lines on export).  Timestamps carry BOTH
-clocks: ``ts`` is ``timeit.default_timer()`` (the profiler's clock, so
-ring events and profiler ``RecordEvent`` spans land on ONE chrome
-timeline) and ``wall`` is ``time.time()`` (for humans and cross-host
-correlation).  ``seq`` increments per event so a tailer
+clocks, read back to back by :func:`stamp`: ``ts`` is
+``time.monotonic()`` (durations; the tracer's phase clocks and the
+compile log run on it) and ``epoch_ns`` is Unix-epoch nanoseconds (the
+clock of a profiler session's ``.xplane.pb`` — its ``profile_start_time``
+— so a ring event can be laid beside device ops and ``RecordEvent``
+spans; the chrome export runs on it); ``wall`` is the same instant in
+seconds, for humans.  An event that carries ``dur_s`` is a span that
+ENDED at its stamp.  ``seq`` increments per event so a tailer
 (tools/metrics_dump.py) can poll ``/events?since=<seq>`` without
 duplicates.
 
@@ -27,11 +31,19 @@ from __future__ import annotations
 import json
 import threading
 import time
-import timeit
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
-__all__ = ["EventRing", "default_ring"]
+__all__ = ["EventRing", "default_ring", "stamp"]
+
+
+def stamp() -> Tuple[float, int]:
+    """``(time.monotonic() seconds, Unix-epoch nanoseconds)`` of one
+    instant: what every ring event, compile record and set-up span is
+    stamped with, so that none of them needs an offset between clocks
+    sampled at some other time."""
+    return time.monotonic(), time.time_ns()
+
 
 # RecordEvent/TracerEventType resolved ONCE at first use: re-running
 # the import statement inside every span __enter__ put an
@@ -64,14 +76,15 @@ class _RingSpan:
                                 TracerEventType.UserDefined,
                                 **self._fields)
         self._rec.begin()
-        self._t0 = timeit.default_timer()
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = timeit.default_timer() - self._t0
+        at = stamp()
         if self._rec is not None:
             self._rec.end()
-        self._ring.emit(self._name, dur_s=dur, **self._fields)
+        self._ring.emit(self._name, at=at, dur_s=at[0] - self._t0,
+                        **self._fields)
         return False
 
 
@@ -87,10 +100,14 @@ class EventRing:
         self._seq = 0
         self._dropped = 0         # events pushed out of the ring
 
-    def emit(self, name: str, **fields) -> dict:
-        ev = {"name": name,
-              "ts": timeit.default_timer(),
-              "wall": time.time(),
+    def emit(self, name: str, at: Optional[Tuple[float, int]] = None,
+             **fields) -> dict:
+        """Append one event, stamped now — or ``at`` a :func:`stamp`
+        its caller took (a span reported after it closed: the compile
+        log's records, the package's import)."""
+        ts, epoch_ns = at if at is not None else stamp()
+        ev = {"name": name, "ts": ts, "epoch_ns": epoch_ns,
+              "wall": epoch_ns * 1e-9,
               "tid": threading.get_ident()}
         ev.update(fields)
         with self._lock:
@@ -161,34 +178,38 @@ class EventRing:
         """The ring (and optionally the profiler's buffered host
         spans) as chrome trace-event dicts — the building block
         :meth:`export_chrome_trace` writes out and the per-trace
-        Perfetto export (observability/tracing.py) merges onto."""
+        Perfetto export (observability/tracing.py) merges onto.
+        ``ts`` is Unix-epoch microseconds, each event's own stamp."""
         import os
         pid = os.getpid()
         trace_events = []
         for ev in self.recent():
             args = {k: v for k, v in ev.items()
-                    if k not in ("name", "ts", "tid", "wall", "seq",
-                                 "dur_s")
+                    if k not in ("name", "ts", "epoch_ns", "tid", "wall",
+                                 "seq", "dur_s")
                     and isinstance(v, (str, int, float, bool,
                                        type(None)))}
+            end_us = ev["epoch_ns"] * 1e-3
             if "dur_s" in ev:
                 trace_events.append({
                     "name": ev["name"], "ph": "X", "cat": "event",
-                    "ts": (ev["ts"] - ev["dur_s"]) * 1e6,
+                    "ts": end_us - ev["dur_s"] * 1e6,
                     "dur": ev["dur_s"] * 1e6,
                     "pid": pid, "tid": ev["tid"], "args": args})
             else:
                 trace_events.append({
                     "name": ev["name"], "ph": "i", "cat": "event",
-                    "ts": ev["ts"] * 1e6, "s": "t",
+                    "ts": end_us, "s": "t",
                     "pid": pid, "tid": ev["tid"], "args": args})
         if include_profiler_spans:
             try:
                 from ..profiler.utils import _peek_spans
-                for name, etype, start, end, tid in _peek_spans():
+                for name, etype, start, end, tid, epoch_ns \
+                        in _peek_spans():
                     trace_events.append({
                         "name": name, "ph": "X", "cat": etype,
-                        "ts": start * 1e6, "dur": (end - start) * 1e6,
+                        "ts": epoch_ns * 1e-3,
+                        "dur": (end - start) * 1e6,
                         "pid": pid, "tid": tid})
             except Exception:
                 pass              # profiler unavailable: events only

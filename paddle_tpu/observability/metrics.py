@@ -60,7 +60,10 @@ def _fmt(v: float) -> str:
 
 class Counter:
     """Monotonic counter.  ``inc`` with a negative amount raises —
-    silent decrements would corrupt every rate() over the series."""
+    silent decrements would corrupt every rate() over the series.
+    ``set_function`` hands the counter to an owner that already keeps
+    the monotonic sum (the compile log's lifetime totals): it is read
+    when scraped, as a :class:`Gauge`'s callback is."""
 
     kind = "counter"
 
@@ -69,6 +72,7 @@ class Counter:
         self.help = help
         self._lock = threading.Lock()
         self._value = 0.0
+        self._fn: Optional[Callable[[], float]] = None
 
     def inc(self, amount: float = 1.0) -> None:
         if amount < 0:
@@ -77,10 +81,17 @@ class Counter:
         with self._lock:
             self._value += amount
 
+    def set_function(self, fn: Callable[[], float]) -> None:
+        with self._lock:
+            self._fn = fn
+
     @property
     def value(self) -> float:
         with self._lock:
-            return self._value
+            fn = self._fn
+            if fn is None:
+                return self._value
+        return float(fn())
 
     def snapshot(self) -> dict:
         return {"type": self.kind, "value": self.value}

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import threading
 import time
-import timeit
 from typing import Dict, List, Optional
 
 __all__ = ["PHASES", "TraceContext", "Tracer", "TraceStore",
@@ -130,12 +129,12 @@ def chrome_trace_for(doc: dict, ring=None) -> dict:
     """One trace as a Perfetto/chrome-tracing document, optionally
     MERGED with the event ring's timeline (which itself merges the
     profiler's RecordEvent spans) — request phases, engine events and
-    host profiler spans side by side.  Span timestamps are
-    ``time.monotonic``; the ring runs on ``timeit.default_timer`` —
-    both are CLOCK_MONOTONIC on the platforms we run, so a one-shot
-    offset sample aligns them to well under a millisecond."""
+    host profiler spans side by side, on the Unix epoch (the ring's
+    events carry their own stamp; a span's ``t0`` is
+    ``time.monotonic`` and the trace holds both clocks of its start,
+    ``t0`` and ``wall0``)."""
     import os
-    off = timeit.default_timer() - time.monotonic()
+    epoch0 = doc["wall0"] - doc["t0"]
     pid = os.getpid()
     tids: Dict[str, int] = {}
     events = []
@@ -146,7 +145,7 @@ def chrome_trace_for(doc: dict, ring=None) -> dict:
         tid = tids.setdefault(str(track), len(tids))
         events.append({
             "name": span["name"], "ph": "X", "cat": "trace",
-            "ts": (span["t0"] + off) * 1e6,
+            "ts": (span["t0"] + epoch0) * 1e6,
             "dur": max(float(span.get("dur_s") or 0.0), 0.0) * 1e6,
             "pid": pid, "tid": tid,
             "args": dict(attrs, span_id=span["id"],

@@ -326,7 +326,7 @@ class Profiler:
                 "ts": start * 1e6, "dur": (end - start) * 1e6,
                 "pid": pid, "tid": 0,
             })
-        for name, etype, start, end, tid in self._spans:
+        for name, etype, start, end, tid, _epoch in self._spans:
             events.append({
                 "name": name, "ph": "X", "cat": etype,
                 "ts": start * 1e6, "dur": (end - start) * 1e6,
@@ -346,7 +346,7 @@ class Profiler:
         """Print (and return) the statistical table (reference :840)."""
         scale = {'s': 1.0, 'ms': 1e3, 'us': 1e6, 'ns': 1e9}[time_unit]
         stats = defaultdict(_StatRecord)
-        for name, etype, start, end, _tid in self._spans:
+        for name, etype, start, end, _tid, _epoch in self._spans:
             stats[(etype, name)].add(end - start)
         step_stat = _StatRecord()
         for _s, start, end in self._step_marks:

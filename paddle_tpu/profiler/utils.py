@@ -15,6 +15,7 @@ individual ops on host, which would fence the async dispatch queue.
 from __future__ import annotations
 
 import threading
+import time
 import timeit
 from contextlib import ContextDecorator
 
@@ -35,16 +36,21 @@ class TracerEventType:
 
 
 class _SpanBuffer:
-    """Thread-safe buffer of completed host spans."""
+    """Thread-safe buffer of completed host spans: ``(name, event_type,
+    start, end, tid, start_epoch_ns)``, start and end on
+    ``timeit.default_timer`` (the Profiler's summary and export) and
+    the start on the Unix epoch as well (the event ring's chrome
+    export, which runs on it)."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._spans = []
         self.enabled = False
 
-    def add(self, name, event_type, start, end, tid):
+    def add(self, name, event_type, start, end, tid, start_epoch_ns):
         with self._lock:
-            self._spans.append((name, event_type, start, end, tid))
+            self._spans.append((name, event_type, start, end, tid,
+                                start_epoch_ns))
 
     def drain(self):
         with self._lock:
@@ -105,6 +111,7 @@ class RecordEvent(ContextDecorator):
         self.event_type = event_type
         self.attrs = attrs
         self._start = None
+        self._start_epoch_ns = 0
         self._ann = None
 
     def _recreate_cm(self):
@@ -117,11 +124,20 @@ class RecordEvent(ContextDecorator):
         self._ann.__enter__()
         if _buffer.enabled:
             self._start = timeit.default_timer()
+            self._start_epoch_ns = time.time_ns()
+
+    def annotate(self, **attrs):
+        """Attributes that are known only while the span is open (what a
+        set-up found): call between ``begin()`` and ``end()``."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
     def end(self):
         if self._start is not None:
             _buffer.add(self.name, self.event_type, self._start,
-                        timeit.default_timer(), threading.get_ident())
+                        timeit.default_timer(), threading.get_ident(),
+                        self._start_epoch_ns)
             self._start = None
         if self._ann is not None:
             self._ann.__exit__(None, None, None)

@@ -209,4 +209,11 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
         "short_conv_mixer_pct.train", "short_conv_roofline_pct.train",
         "short_conv_bytes_declared_per_needed.train",
         "kda_mixer_pct.train", "kda_chunk_roofline_pct.train",
-        "kda_chunk_declared_per_needed.train"]
+        "kda_chunk_declared_per_needed.train",
+        # PR 54: the five that move ``setup_s``, in every cell
+        "setup_step_compile_s.train", "setup_trace_lower_s.train",
+        "setup_small_programs_s.train", "setup_cache_miss_pct.train",
+        "setup_loader_start_s.train"]
+    for m in bench["per_layer"][-5:]:
+        assert (m["moves"], m["better"], m["layer"], m["workloads"]) == (
+            "setup_s", "lower", "set-up", every)

@@ -359,6 +359,14 @@ def test_generation_server_metrics_and_stats_endpoints():
         assert samples['paddle_tpu_request_ttft_seconds_bucket'
                        '{le="+Inf"}'] == 2
         assert samples["paddle_tpu_http_generate_requests_total"] == 2
+        # what the process compiled, from the program's own log (PR 54):
+        # this server's engine programs are among them
+        from paddle_tpu.observability import compile_log
+        assert {n for n in samples if n.startswith("paddle_tpu_compile_")} \
+            == set(compile_log.INSTRUMENTS)
+        assert samples["paddle_tpu_compile_programs_total"] >= 2
+        assert samples["paddle_tpu_compile_programs_total"] \
+            <= compile_log.totals()["programs"]
 
         # /stats: the JSON snapshot of the same registry
         with urllib.request.urlopen(url + "/stats", timeout=10) as r:

@@ -11,6 +11,7 @@ import glob
 import importlib.util
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,9 @@ AHEAD = ("moe_sum_pairs",) + HC_KERNELS
 # ... of which ``moe_sum_pairs`` is named by a family since PR 44
 # (``benchmark/models/smallthinker_moe.KERNELS``, a new file)
 AHEAD_OF_EVERY_FAMILY = HC_KERNELS
+# a span of the program that the readers' copy (``xplane_meta.SPANS``)
+# does not list yet (PR 54: the loader's start; ROADMAP D14)
+SPANS_AHEAD = ("dataloader.start",)
 
 
 def scope_names(lowered) -> set:
@@ -902,21 +906,70 @@ def test_engine_spans_nest_on_one_thread_line(cpu_trace):
     assert len({h.thread for h in mt.spans(("engine.step",))}) == 1
 
 
+def span_call_sites() -> set:
+    """The name of every ``RecordEvent("...")`` / ``<ring>.span("...")``
+    site of the program (a literal first argument with a dot in it: the
+    tracer's ``ctx.span(phase, t0, t1)`` takes its names from data)."""
+    names = set()
+    for path in sorted(glob.glob(os.path.join(
+            REPO, "paddle_tpu", "**", "*.py"), recursive=True)):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and node.args and \
+                    (getattr(node.func, "id", "") == "RecordEvent"
+                     or getattr(node.func, "attr", "") == "span") and \
+                    isinstance(node.args[0], ast.Constant) and \
+                    isinstance(node.args[0].value, str) and \
+                    "." in node.args[0].value:
+                names.add(node.args[0].value)
+    return names
+
+
+def test_every_span_call_site_is_in_the_vocabulary():
+    sites = span_call_sites()
+    assert sites == set(xplane_meta.SPANS) | set(SPANS_AHEAD)
+    assert not set(SPANS_AHEAD) & set(xplane_meta.SPANS)
+
+
 def test_dataloader_spans(cpu_trace):
+    """``dataloader.start`` opens once an iterator, at its construction,
+    closes when the first batch is handed out — around that batch's
+    ``dataloader.next`` — and carries what the set-up found; it is ONE
+    event of the process-wide ring as well, on both clocks."""
     from paddle_tpu.io.worker import MultiprocessBatchIterator
+    from paddle_tpu.observability import default_ring
+    seq0 = (default_ring().recent()[-1:] or [{"seq": 0}])[0]["seq"]
+    t0 = time.time()
     it = MultiprocessBatchIterator(
         _Rows(), [[0, 1], [2, 3]], num_workers=1,
         to_device=lambda b: jnp.asarray(b))
     try:
         assert next(it).shape == (2, 4)
+        t1 = time.time()
+        assert next(it).shape == (2, 4)
     finally:
         it.shutdown()
     mt = cpu_trace()
-    nxt, = mt.spans(("dataloader.next",))
-    wait, = mt.spans(("dataloader.wait",))
-    dev, = mt.spans(("dataloader.to_device",))
+    nxt, second = mt.spans(("dataloader.next",))
+    wait, _ = mt.spans(("dataloader.wait",))
+    dev, _ = mt.spans(("dataloader.to_device",))
     assert nxt.start_s <= wait.start_s <= wait.end_s <= dev.start_s
     assert dev.end_s <= nxt.end_s
+    start, = mt.spans(("dataloader.start",))
+    assert start.start_s <= nxt.start_s and nxt.end_s <= start.end_s \
+        <= second.start_s
+    assert int(start.attrs["num_workers"]) == 1
+    assert start.attrs["transport"] in ("shm", "queue")
+    assert start.thread == nxt.thread
+    ev, = [e for e in default_ring().recent(since=seq0)
+           if e["name"] == "dataloader.start"]
+    assert ev["num_workers"] == 1
+    assert ev["transport"] == start.attrs["transport"]
+    assert ev["dur_s"] == pytest.approx(start.end_s - start.start_s,
+                                        abs=5e-3)
+    assert t0 - 1e-3 <= ev["epoch_ns"] * 1e-9 - ev["dur_s"]
+    assert ev["epoch_ns"] * 1e-9 <= t1 + 1e-3
 
 
 class _Rows:
