@@ -1120,12 +1120,47 @@ def _split_bwd(cuts, _, parts):
 _split.defvjp(_split_fwd, _split_bwd)
 
 
+def flash_kinds(cfg) -> Tuple[str, ...]:
+    """The kinds of ``cfg``'s trunk whose blocks call the flash kernels
+    (``check``: latent attention does not mix with the others)."""
+    if cfg.layer_types[0] in MLA_KINDS:
+        return MLA_KINDS
+    return ("attention", "gqa_qknorm_moe", "gqa_gated_moe") + GQA_MOE_KINDS
+
+
+def kept_outputs(cfg, batch: int, seq: int) -> Tuple[bool, bool]:
+    """What full remat keeps of the trunk's forward kernels' results for
+    ``batch`` rows of ``seq``, from shapes alone: (``flash_fwd``'s,
+    ``kda_chunk_fwd``'s).  ONE budget a trunk,
+    ``llama_pretrain.KEPT_BYTES``: the flash layers' ``o`` and ``lse``
+    are reckoned first, against all of it; the ``kda_moe`` layers'
+    ``o`` and entering states (``kda_chunk.kept_bytes``) are kept where
+    they fit beside what flash keeps — and where the kernels take the
+    shapes: ``kda_chunked_xla`` names nothing."""
+    from ..ops import kda
+    from ..ops.pallas import kda_chunk
+    from .llama_pretrain import (KEPT_BYTES, flash_output_bytes,
+                                 keeps_flash_outputs)
+    mla = cfg.layer_types[0] in MLA_KINDS
+    flash = (batch, seq, cfg.num_attention_heads,
+             cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,
+             sum(kind in flash_kinds(cfg) for kind in cfg.layer_types))
+    keep_flash = keeps_flash_outputs(*flash)
+    layers = cfg.layer_types.count("kda_moe")
+    if not layers or not kda_chunk.takes(jax.ShapeDtypeStruct(
+            (batch, seq, 3 * cfg.kda_num_heads * cfg.kda_head_dim),
+            cfg.dtype), cfg.kda_num_heads, kda.CHUNK):
+        return keep_flash, False
+    held = flash_output_bytes(*flash) if keep_flash else 0
+    return keep_flash, held + layers * kda_chunk.kept_bytes(
+        batch, seq, cfg.kda_num_heads, cfg.dtype, kda.CHUNK) <= KEPT_BYTES
+
+
 def trunk(blocks, x, cfg, mesh):
     """x [b, s, h] through the layers in ``cfg.layer_types``' order.
     The latent-attention kinds carry ``hc_mult`` streams, ``[b, s, n h]``:
     the row that comes in is copied into each, and their sum goes out."""
-    from .llama_pretrain import (_block_forward, _remat_wrap,
-                                 keeps_flash_outputs)
+    from .llama_pretrain import _block_forward, _remat_wrap
     body = {"attention": _block_forward, "mamba": _mamba_block,
             "mla_dense": _mla_block, "mla_moe": _mla_block,
             "gqa_moe_global": functools.partial(_gqa_moe_block,
@@ -1135,16 +1170,9 @@ def trunk(blocks, x, cfg, mesh):
             "conv_dense": _conv_block, "conv_moe": _conv_block,
             "gqa_qknorm_moe": _conv_block,
             "kda_moe": _kda_block, "gqa_gated_moe": _kda_block}
-    # the kinds whose blocks call the flash kernels (``check``: latent
-    # attention does not mix with the others); their layers together
-    # count against FLASH_KEPT_BYTES, at a head's value width
     mla = cfg.layer_types[0] in MLA_KINDS
-    flash_kinds = MLA_KINDS if mla else (
-        "attention", "gqa_qknorm_moe", "gqa_gated_moe") + GQA_MOE_KINDS
-    keep_flash = keeps_flash_outputs(
-        x.shape[0], x.shape[1], cfg.num_attention_heads,
-        cfg.v_head_dim if mla else cfg.head_dim, cfg.dtype,
-        sum(kind in flash_kinds for kind in cfg.layer_types))
+    keep_flash, keep_kda = kept_outputs(cfg, x.shape[0], x.shape[1])
+    flash = flash_kinds(cfg)
     runs = layer_runs(cfg.layer_types)
     streams = cfg.hc_mult if mla else 1
     if streams > 1:
@@ -1166,8 +1194,9 @@ def trunk(blocks, x, cfg, mesh):
         parts = {kind: runs_of(kind) for kind in blocks}
         for kind, a, b in runs:
             fwd = _remat_wrap(body[kind], cfg,
-                              keep_flash and kind in flash_kinds,
-                              kind in ROUTED_KINDS)
+                              keep_flash and kind in flash,
+                              kind in ROUTED_KINDS,
+                              keep_kda and kind == "kda_moe")
             layers, whole = parts[kind].pop(0), {}
             if kind in ROUTED_KINDS:
                 # the grouped products read a layer's experts out of the

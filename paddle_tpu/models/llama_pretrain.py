@@ -56,7 +56,8 @@ class LlamaPretrainConfig:
     remat: bool = True
     # remat_policy: 'full', the one boundary there is: a layer holds its
     # input and the block is recomputed — but for flash attention's
-    # forward outputs where FLASH_KEPT_BYTES allows (_remat_wrap).
+    # forward outputs, and the delta rule's, where KEPT_BYTES allows
+    # (_remat_wrap).
     remat_policy: str = "full"
     sequence_parallel: bool = True
     use_pallas_attention: bool = True
@@ -578,38 +579,54 @@ def _block_forward(bp: Dict[str, Any], x, cfg: LlamaPretrainConfig,
 
 
 # Full remat holds a layer's input and runs the block again in the
-# backward pass.  The flash forward kernel's two results, ``o`` and
-# ``lse``, are the exception where their bytes over all the layers that
-# run the kernel are at most this much (a sixteenth of a v5e's HBM): the
-# recompute then reads them and ``flash_fwd`` runs once a layer a step.
-# Past it the whole block is recomputed, as before.  Bytes, because HBM
-# is what the choice spends: the expert cell keeps 5 x 136 MB and the
-# hybrid cell 69 MB, the dense cell's 18 x 68 MB = 1.23 GB would buy
-# back the compiler's own rematerialization (PERF.md section 6, PR 43).
-# The other exception has no bound: an expert layer's ROUTING
+# backward pass, with three exceptions.  Two are a forward kernel's
+# results, kept where their bytes over all the layers that run the
+# kernel fit ONE budget a trunk, this much (a sixteenth of a v5e's HBM):
+# ``flash_fwd``'s ``o`` and ``lse``, reckoned first
+# (:func:`keeps_flash_outputs`), and ``kda_chunk_fwd``'s ``o`` and
+# entering states, kept where they fit beside what flash keeps
+# (``hybrid_trunk.kept_outputs``).  The recompute then reads them and
+# the kernel runs once a layer a step; past the budget the whole block
+# is recomputed, as before.  Bytes, because HBM is what the choice
+# spends: the expert cell keeps 5 x 136 MB and the hybrid cell 69 MB,
+# the dense cell's 18 x 68 MB = 1.23 GB would buy back the compiler's
+# own rematerialization (PERF.md section 6, PR 43); the delta-rule cell
+# keeps 136 MB + 3 x 268 MB = 942 MB, and at a row of 16,384 the three
+# layers' 1.61 GB are recomputed (PR 55).
+# The third exception has no bound: an expert layer's ROUTING
 # (``ops/moe.ROUTING_NAMES``: the picks, their scores and the plan's
 # integer arrays, 2-6 MB a layer beside the 84 MB of its kept input) is
 # kept wherever a layer routes, so the recompute has no router's
 # product, no ``top_k`` and no sort (PERF.md section 6, PR 46).
-FLASH_KEPT_BYTES = 1 << 30
+KEPT_BYTES = 1 << 30
+
+
+def flash_output_bytes(batch: int, seq: int, heads: int, value_dim: int,
+                       dtype, layers: int) -> int:
+    """``layers`` flash layers' ``o`` ``[batch, seq, heads, value_dim]``
+    in ``dtype`` and fp32 ``lse`` ``[batch, heads, seq]``, as the trunk
+    sees them."""
+    o = batch * seq * heads * value_dim * jnp.dtype(dtype).itemsize
+    lse = batch * seq * heads * 4
+    return layers * (o + lse)
 
 
 def keeps_flash_outputs(batch: int, seq: int, heads: int, value_dim: int,
                         dtype, layers: int) -> bool:
-    """The rule of :data:`FLASH_KEPT_BYTES`: ``layers`` flash layers'
-    ``o`` ``[batch, seq, heads, value_dim]`` in ``dtype`` and fp32
-    ``lse`` ``[batch, heads, seq]``, as the trunk sees them."""
-    o = batch * seq * heads * value_dim * jnp.dtype(dtype).itemsize
-    lse = batch * seq * heads * 4
-    return 0 < layers * (o + lse) <= FLASH_KEPT_BYTES
+    """The rule of :data:`KEPT_BYTES` for flash, which is reckoned
+    first: :func:`flash_output_bytes` against the whole budget."""
+    return 0 < flash_output_bytes(batch, seq, heads, value_dim, dtype,
+                                  layers) <= KEPT_BYTES
 
 
-def _remat_wrap(fwd, cfg, keep_flash: bool = False, routes: bool = False):
-    """``fwd`` under full remat; ``keep_flash`` (what
-    :func:`keeps_flash_outputs` said of the trunk's flash layers) makes
-    the boundary's policy keep ``flash_fwd``'s outputs, ``routes`` (the
-    layer holds routed experts) what ``ops/moe`` names of a layer's
-    routing."""
+def _remat_wrap(fwd, cfg, keep_flash: bool = False, routes: bool = False,
+                keep_kda: bool = False):
+    """``fwd`` under full remat, but for what the boundary's policy
+    keeps: ``keep_flash`` (what :func:`keeps_flash_outputs` said of the
+    trunk's flash layers) ``flash_fwd``'s outputs, ``routes`` (the layer
+    holds routed experts) what ``ops/moe`` names of a layer's routing,
+    ``keep_kda`` (``hybrid_trunk.kept_outputs``, for the layers that run
+    the delta rule) ``kda_chunk_fwd``'s outputs."""
     if not cfg.remat:
         return fwd
     names = ()
@@ -619,6 +636,9 @@ def _remat_wrap(fwd, cfg, keep_flash: bool = False, routes: bool = False):
     if routes:
         from ..ops.moe import ROUTING_NAMES
         names += ROUTING_NAMES
+    if keep_kda:
+        from ..ops.pallas.kda_chunk import FWD_OUTPUT_NAMES
+        names += FWD_OUTPUT_NAMES
     policy = jax.checkpoint_policies.save_only_these_names(*names) \
         if names else None
     return jax.checkpoint(fwd, static_argnums=(2, 3), policy=policy)

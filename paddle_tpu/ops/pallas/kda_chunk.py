@@ -17,6 +17,14 @@ its block's chunks forward again from that state (it forms every matrix
 again anyway) and pulls the cotangents back through them: the derivative
 is jax's own of ``chunk_step``, taken inside the kernel's body.
 
+The forward's two results carry ``jax.ad_checkpoint.checkpoint_name``s,
+:data:`FWD_OUTPUT_NAMES`: o (the out gate's input) and ``entering`` (the
+backward kernel's) are all that a later pass reads of the call, so a
+checkpoint boundary whose policy keeps BOTH has no ``kda_chunk_fwd`` in
+its recompute; keeping one alone buys nothing, the other still needs the
+run.  :func:`kept_bytes` says what the two take, from shapes alone
+(``models/hybrid_trunk.kept_outputs`` decides by it).
+
 Layout.  q, k and v are read where a convolution leaves them, side by
 side in ONE array ``[b, s, 3 H K]`` with ``K = V = 128``: a head's q, k
 and v are the lane tiles ``h``, ``H + h`` and ``2 H + h``, which the
@@ -38,6 +46,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,7 +54,8 @@ from .. import kda
 from . import _common
 from ._common import idx32
 
-__all__ = ["kda_chunked", "takes", "block_rows", "BLOCK_CHUNKS"]
+__all__ = ["kda_chunked", "takes", "block_rows", "kept_bytes",
+           "BLOCK_CHUNKS", "FWD_OUTPUT_NAMES"]
 
 F32 = jnp.float32
 LANES = 128
@@ -53,6 +63,9 @@ BLOCK_CHUNKS = 4
 # a block's tiles, the state scratch (4 MB at 64 heads) and, in the
 # backward, what autodiff keeps of a block's four chunks
 VMEM_LIMIT = 64 << 20
+# ``kda_chunk_fwd``'s two results, o and the states that entered each
+# block, under ``checkpoint_name`` (the module docstring says why both)
+FWD_OUTPUT_NAMES = ("kda_out", "kda_entering")
 
 
 def takes(qkv, heads: int, chunk: int) -> bool:
@@ -66,6 +79,17 @@ def block_rows(s: int, chunk: int) -> int:
     """The positions of a grid step's block: ``BLOCK_CHUNKS`` chunks, or
     all of a shorter row's."""
     return min(BLOCK_CHUNKS, -(-s // chunk)) * chunk
+
+
+def kept_bytes(b: int, s: int, heads: int, dtype, chunk: int) -> int:
+    """The bytes of ``kda_chunk_fwd``'s two results for ``b`` rows of
+    ``s`` positions (padded to whole blocks, as ``ops/kda.kda_chunk``
+    pads them): o ``[b, s', H V]`` in ``dtype`` and the entering states
+    ``[b, s' / block_rows, H, K, V]`` fp32."""
+    rows = block_rows(s, chunk)
+    blocks = -(-s // rows)
+    o = b * blocks * rows * heads * LANES * jnp.dtype(dtype).itemsize
+    return o + b * blocks * heads * LANES * LANES * 4
 
 
 def _column(table, head):
@@ -200,7 +224,7 @@ def _run_fwd(qkv, g, beta, chunk):
     rows = block_rows(s, chunk)
     blocks = s // rows
     tile, beta_spec, state = _specs(rows, heads, lambda c: c)
-    return pl.pallas_call(
+    o, entering = pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, dt=qkv.dtype),
         out_shape=(jax.ShapeDtypeStruct((b, s, heads * LANES), qkv.dtype),
                    jax.ShapeDtypeStruct((b, blocks, heads, LANES, LANES),
@@ -219,6 +243,8 @@ def _run_fwd(qkv, g, beta, chunk):
                            qkv.dtype.itemsize, backward=False),
         interpret=_common.interpret(),
     )(qkv, qkv, qkv, g, beta)
+    return (checkpoint_name(o, FWD_OUTPUT_NAMES[0]),
+            checkpoint_name(entering, FWD_OUTPUT_NAMES[1]))
 
 
 def _run_bwd(qkv, g, beta, entering, do, chunk):

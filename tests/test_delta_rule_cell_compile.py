@@ -36,8 +36,11 @@ def test_train_step_of_the_delta_rule_cell(one_chip, compiled):
     call = lambda kernel: len(re.findall(
         rf'custom_call_target="tpu_custom_call".*/{kernel}/pallas_call',
         text))
-    # the delta-rule kind's loop: the forward, the recompute's, the backward
-    assert (call("kda_chunk_fwd"), call("kda_chunk_bwd")) == (2, 1)
+    # the delta-rule kind's loop: the forward and the backward — full
+    # remat keeps ``kda_chunk_fwd``'s o and entering states (3 x 268 MB
+    # beside the GQA layer's 136 MB, within ``KEPT_BYTES``: 941,621,248
+    # B), so the recompute has none (PR 55; ``(2, 1)`` before)
+    assert (call("kda_chunk_fwd"), call("kda_chunk_bwd")) == (1, 1)
     assert (call("causal_conv_fwd"), call("causal_conv_bwd")) == (2, 1)
     # the gated GQA layer: full remat keeps ``flash_fwd``'s outputs, and
     # the backward at 64 / 8 heads of 128, S 8,192 is the query-major one
@@ -47,7 +50,7 @@ def test_train_step_of_the_delta_rule_cell(one_chip, compiled):
     # a routed kind's loop ON EACH OF ITS TWO BOUNDS (5,376 rows where the
     # load's tiles fit them — twice the 1,639 pairs top-8 of 320 sends to
     # 8 experts, and a tile an expert — 67,584 otherwise)
-    assert text.count(KERNEL) == 3 + 3 + 2 + 2 * 2 * 9 == 44
+    assert text.count(KERNEL) == 2 + 3 + 2 + 2 * 2 * 9 == 43
     assert len(re.findall(r" conditional\(", text)) == 2 * 3
     for rows in (5376, 67584):
         assert f"bf16[{rows},4096]" in text
@@ -62,8 +65,10 @@ def test_train_step_of_the_delta_rule_cell(one_chip, compiled):
     assert not _experts_placed(text, 8, 4096, 1280, layers=3)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes == 5_188_808_704
-    # what the donated parameters' new values take is in this figure
-    assert ma.temp_size_in_bytes <= 14_612_799_488
+    # what the donated parameters' new values take is in this figure;
+    # 14,612,799,488 with the delta rule's outputs recomputed, +40.8 MB
+    # with them kept (PR 55)
+    assert ma.temp_size_in_bytes <= 14_653_609_472
 
 
 @pytest.mark.slow

@@ -42,7 +42,7 @@ def test_train_step_of_the_expert_cell(one_chip, compiled):
     # the split form's backward is one pass at S 8192 (PR 42)
     assert "flash_bwd_dq" not in text
     # a dense lead: 2 flash (forward — full remat keeps its outputs, 5 x
-    # 136 MB within ``FLASH_KEPT_BYTES``, so the recompute has none —
+    # 136 MB within ``KEPT_BYTES``, so the recompute has none —
     # and the one-pass backward); an expert layer: 2 flash, and the routed
     # path ON EACH OF ITS TWO BOUNDS (18,432 rows where the load's tiles
     # fit them, 67,584 otherwise: one ``conditional`` a pass): 2 grouped

@@ -409,7 +409,7 @@ def test_flagship_vpp_matches_flat():
 def test_remat_policy_keeps_loss_and_gradients(mode, kv_heads, monkeypatch):
     """What full remat holds changes what is recomputed, never a value:
     with ``flash_fwd``'s outputs kept (their bytes within
-    ``FLASH_KEPT_BYTES``: the kernel runs once) and with the whole block
+    ``KEPT_BYTES``: the kernel runs once) and with the whole block
     recomputed (the bound at 0: it runs twice) the loss and every leaf's
     gradient are those of ``remat=False``, and the two are the same
     bits."""
@@ -430,7 +430,7 @@ def test_remat_policy_keeps_loss_and_gradients(mode, kv_heads, monkeypatch):
 
     def loss_and_grads(bound=None, **kw):
         if bound is not None:
-            monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", bound)
+            monkeypatch.setattr(llama_pretrain, "KEPT_BYTES", bound)
         cfg = LlamaPretrainConfig(**base, **kw)
         with mesh:
             params = init_params(cfg, jax.random.PRNGKey(0), mesh)
@@ -440,7 +440,7 @@ def test_remat_policy_keeps_loss_and_gradients(mode, kv_heads, monkeypatch):
             return jax.jit(fn)(params, toks), runs
 
     (want, want_g), _ = loss_and_grads(remat=False)
-    bounds = {"kept": llama_pretrain.FLASH_KEPT_BYTES, "recomputed": 0}
+    bounds = {"kept": llama_pretrain.KEPT_BYTES, "recomputed": 0}
     (got, got_g), flash_fwd_runs = loss_and_grads(bounds[mode], remat=True)
     assert flash_fwd_runs == {"kept": 1, "recomputed": 2}[mode]
     np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
@@ -491,13 +491,130 @@ KEPT_BY_BYTES = {
 @pytest.mark.parametrize("case", sorted(KEPT_BY_BYTES))
 def test_flash_outputs_are_kept_by_their_bytes(case, monkeypatch):
     """``keeps_flash_outputs`` is a function of shapes: the layers' ``o``
-    and fp32 ``lse`` together against ``FLASH_KEPT_BYTES``."""
+    and fp32 ``lse`` together against ``KEPT_BYTES``."""
     from paddle_tpu.models import llama_pretrain
     shape, bound, kept = KEPT_BY_BYTES[case]
-    assert llama_pretrain.FLASH_KEPT_BYTES == 1 << 30
+    assert llama_pretrain.KEPT_BYTES == 1 << 30
     if bound is not None:
-        monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", bound)
+        monkeypatch.setattr(llama_pretrain, "KEPT_BYTES", bound)
     assert llama_pretrain.keeps_flash_outputs(*shape) is kept
+
+
+SOLAR, PERIOD = "solar-open2-250b.pretrain-kda-moe", \
+    ("gqa_gated_moe", "kda_moe", "kda_moe", "kda_moe")
+# an entered cell, keys of its program's configuration stated otherwise,
+# (rows, positions) where not the cell's own, the budget (None: the
+# module's own) -> (``flash_fwd``'s outputs kept, ``kda_chunk_fwd``'s)
+KEPT_OF_A_TRUNK = {
+    # 136,314,880 + 3 x (134,217,728 + 134,217,728) = 941,621,248 B
+    "delta_rule_cell": (SOLAR, {}, None, None, (True, True)),
+    "delta_rule_cell_to_the_byte": (SOLAR, {}, None, 941_621_248,
+                                    (True, True)),
+    "delta_rule_cell_a_byte_short": (SOLAR, {}, None, 941_621_247,
+                                     (True, False)),
+    # a layer deeper, 1,210,056,704 B: the delta rule's are recomputed,
+    # flash's are kept as they were
+    "a_layer_deeper": (SOLAR, {"layer_types": PERIOD + ("kda_moe",)}, None,
+                       None, (True, False)),
+    # ISSUE 52's rungs (a) / (b): 3 x 536,870,912 B alone are past 2**30
+    "a_row_of_16k": (SOLAR, {}, (1, 16384), None, (True, False)),
+    # four delta-rule layers and no flash layer: 2**30 to the byte
+    "on_the_budget": (SOLAR, {"layer_types": ("kda_moe",) * 4}, None, None,
+                      (False, True)),
+    "on_the_budget_beside_flash": (
+        SOLAR, {"layer_types": ("gqa_gated_moe",) + ("kda_moe",) * 4}, None,
+        None, (True, False)),
+    # flash past the budget keeps nothing, and what it would have taken
+    # is not counted against the delta rule's
+    "flash_over_the_budget": (SOLAR, {"layer_types": PERIOD}, None,
+                              136_314_879, (False, False)),
+    # seven flash layers' 7 x 8,519,680 B do not fit, three delta-rule
+    # layers' 3 x 16,777,216 do
+    "flash_over_delta_rule_within": (
+        SOLAR, {"layer_types": ("gqa_gated_moe",) * 7 + ("kda_moe",) * 3},
+        (1, 512), 3 * 16_777_216, (False, True)),
+    # 8,000 positions are padded to 32 blocks of 256: the kernel writes
+    # 8,192 rows of o; flash's 8,000 are 133,120,000 B
+    "a_padded_row": (SOLAR, {}, (1, 8000), 133_120_000 + 805_306_368,
+                     (True, True)),
+    "a_padded_row_a_byte_short": (SOLAR, {}, (1, 8000),
+                                  133_120_000 + 805_306_367, (True, False)),
+    # heads the kernels do not take run ``kda_chunked_xla``: nothing named
+    "heads_of_64": (SOLAR, {"kda_head_dim": 64}, None, None, (True, False)),
+    "fp16_rows": (SOLAR, {"dtype": "float16"}, None, None, (True, False)),
+    # no delta-rule layer: flash's decision, the four trunks by kind
+    "hybrid_cell": ("granite-4.0-h-micro.pretrain-8k", {}, None, None,
+                    (True, False)),
+    "expert_cell": ("xing4.0-29b-a4b.pretrain-8k-moe", {}, None, None,
+                    (True, False)),
+    "expert_cell_a_byte_short": ("xing4.0-29b-a4b.pretrain-8k-moe", {}, None,
+                                 681_574_399, (False, False)),
+    "window_cell": ("smallthinker-21b-a3b.pretrain-16k-moe", {}, None, None,
+                    (True, False)),
+    "convolution_cell": ("lfm2-24b-a2b.pretrain-8k-conv-moe", {}, None, None,
+                         (True, False)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_OF_A_TRUNK))
+def test_a_trunk_keeps_kernel_outputs_by_one_budget(case, monkeypatch):
+    """``hybrid_trunk.kept_outputs`` is a function of shapes: flash's
+    bytes against ``KEPT_BYTES`` first, exactly ``keeps_flash_outputs``
+    (a trunk with no delta-rule layer decides as it did), then the
+    ``kda_moe`` layers' ``kda_chunk.kept_bytes`` beside what flash
+    keeps, where the kernels take the shapes."""
+    import dataclasses
+    import jax.numpy as jnp
+    from benchmark import harness
+    from paddle_tpu.models import hybrid_trunk, llama_pretrain
+    name, stated, shape, bound, kept = KEPT_OF_A_TRUNK[case]
+    cell = harness.find_cell(name)
+    cfg = cell.family.build_cfg(cell.conf, True, cell.traffic)
+    if "dtype" in stated:
+        stated = dict(stated, dtype=jnp.dtype(stated["dtype"]))
+    if "layer_types" in stated:     # the configuration cuts them to depth
+        stated = dict(stated, num_hidden_layers=len(stated["layer_types"]))
+    cfg = dataclasses.replace(cfg, **stated)
+    assert cfg.layer_types == stated.get("layer_types", cfg.layer_types)
+    rows, seq = shape or (cell.traffic["batch"], cell.traffic["seq"])
+    if bound is not None:
+        monkeypatch.setattr(llama_pretrain, "KEPT_BYTES", bound)
+    assert hybrid_trunk.kept_outputs(cfg, rows, seq) == kept
+    kinds = hybrid_trunk.flash_kinds(cfg)
+    assert kept[0] is llama_pretrain.keeps_flash_outputs(
+        rows, seq, cfg.num_attention_heads,
+        cfg.v_head_dim if "mla_moe" in kinds else cfg.head_dim, cfg.dtype,
+        sum(kind in kinds for kind in cfg.layer_types))
+
+
+def test_the_delta_rule_s_kept_bytes_are_the_kernel_s_outputs():
+    """``kda_chunk.kept_bytes`` against the two arrays ``kda_chunk_fwd``
+    declares, at a row of whole blocks, a padded one and one shorter
+    than a block."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kda
+    from paddle_tpu.ops.pallas import kda_chunk
+    assert kda_chunk.kept_bytes(1, 8192, 64, jnp.bfloat16, 64) == \
+        134_217_728 + 134_217_728
+    for s, dtype in ((512, jnp.bfloat16), (300, jnp.float32),
+                     (100, jnp.bfloat16)):
+        outs = []
+
+        def spy(qkv, g, beta, chunk):
+            outs.extend(jax.eval_shape(functools.partial(
+                kda_chunk._run_fwd, chunk=chunk), qkv, g, beta))
+            return jnp.zeros(outs[0].shape, outs[0].dtype)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(kda_chunk, "kda_chunked", spy)
+            jax.eval_shape(
+                lambda *a: kda.kda_chunk(*a, 2),
+                jax.ShapeDtypeStruct((3, s, 3 * 2 * 128), dtype),
+                jax.ShapeDtypeStruct((3, s, 2 * 128), jnp.float32),
+                jax.ShapeDtypeStruct((3, s, 2), jnp.float32))
+        assert kda_chunk.kept_bytes(3, s, 2, dtype, kda.CHUNK) == sum(
+            o.size * o.dtype.itemsize for o in outs), s
 
 
 @pytest.mark.parametrize("policy", ["dots", "names", "cheap", "flash"])

@@ -93,7 +93,7 @@ def test_full_remat_keeps_the_split_forward_s_outputs(toy, monkeypatch):
     """``flash_attention_split`` through ``_mla_block`` under the trunk's
     checkpoint boundary: ``flash_fwd`` is in the program once a run of
     layers (the forward scan's body) where its outputs are kept, twice
-    (the backward scan's too) with ``FLASH_KEPT_BYTES`` at 0, and the
+    (the backward scan's too) with ``KEPT_BYTES`` at 0, and the
     loss and every gradient are the same bits."""
     mesh = build_mesh(devices=jax.devices()[:1])
     runs = len(hybrid_trunk.layer_runs(toy.cfg.layer_types))
@@ -109,7 +109,7 @@ def test_full_remat_keeps_the_split_forward_s_outputs(toy, monkeypatch):
             return traced.lower().compile()(params, ids)
 
     kept, kept_g = loss_and_grads(runs)
-    monkeypatch.setattr(llama_pretrain, "FLASH_KEPT_BYTES", 0)
+    monkeypatch.setattr(llama_pretrain, "KEPT_BYTES", 0)
     again, again_g = loss_and_grads(2 * runs)
     assert float(kept) == float(again)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept_g),

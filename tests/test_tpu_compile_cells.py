@@ -63,7 +63,7 @@ def test_train_step_by_kind_at_the_hybrid_cell_s_shapes(one_chip, compiled):
     mixer's that computes nothing (forward, recompute, backward: 8 a
     layer before the kernels took offsets, 16 in this text).  The one
     attention layer's ``flash_fwd`` runs once: full remat keeps its
-    outputs (69 MB, within ``FLASH_KEPT_BYTES``), and its backward is
+    outputs (69 MB, within ``KEPT_BYTES``), and its backward is
     ONE pass since PR 45 (query-major, ``flash_bwd_dq``'s name;
     ``flash_bwd_dkv`` is absent)."""
     from benchmark import harness
@@ -96,7 +96,7 @@ def test_train_step_of_the_window_cell(one_chip, compiled):
     windowed form, the query-major ONE-pass backward in both (since PR
     45: ``flash_(win_)bwd_dkv`` absent), and ``flash_fwd`` /
     ``flash_win_fwd`` once a layer: full remat keeps their outputs (8 x
-    119 MB = 954 MB, within ``FLASH_KEPT_BYTES``)."""
+    119 MB = 954 MB, within ``KEPT_BYTES``)."""
     from benchmark import harness
     from paddle_tpu.models.llama_pretrain import keeps_flash_outputs
     cell = harness.find_cell("smallthinker-21b-a3b.pretrain-16k-moe")

@@ -379,6 +379,20 @@ def test_train_step_of_the_conv_kinds_carries_the_family_s_scopes():
         any("/rope/" in p for p in inside)
 
 
+def _kda_cfg():
+    """One period of the delta-rule kinds at toy widths, the recurrence's
+    heads one lane tile wide (the kernels take them)."""
+    return _cfg(remat=True, loss_chunks=2, hidden_size=128,
+                intermediate_size=256, num_hidden_layers=4,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+                max_seq_len=256, position_embedding_type="nope",
+                gqa_layers=(0,), kda_num_heads=2, kda_head_dim=128,
+                short_conv_kernel_size=4, moe_intermediate_size=128,
+                n_routed_experts=8, n_shared_experts=1, experts_held=2,
+                expert_first=2, num_experts_per_tok=3,
+                use_pallas_attention=True)
+
+
 def test_train_step_of_the_kda_kinds_carries_the_family_s_scopes():
     """``kda_moe`` / ``gqa_gated_moe``: the mixer's six scopes with the
     convolution's kernels under ``kda_conv`` and the recurrence's under
@@ -387,15 +401,7 @@ def test_train_step_of_the_kda_kinds_carries_the_family_s_scopes():
     ``moe_shared`` among them —, no ``mlp``, no ``rope``, nothing of a
     block under no scope."""
     from benchmark.models import solar_kda_moe
-    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
-               intermediate_size=256, num_hidden_layers=4,
-               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-               max_seq_len=256, position_embedding_type="nope",
-               gqa_layers=(0,), kda_num_heads=2, kda_head_dim=128,
-               short_conv_kernel_size=4, moe_intermediate_size=128,
-               n_routed_experts=8, n_shared_experts=1, experts_held=2,
-               expert_first=2, num_experts_per_tok=3,
-               use_pallas_attention=True)
+    cfg = _kda_cfg()
     assert cfg.layer_types == ("gqa_gated_moe",) + ("kda_moe",) * 3
     mesh = _mesh()
     with mesh:
@@ -510,7 +516,7 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
     and forms delta itself): THREE; past both VMEM rules, here the
     module constants set to 0 bytes, ``flash_bwd_dq`` runs before it: four;
     where full remat keeps the forward's outputs (their bytes within
-    ``FLASH_KEPT_BYTES``, here the module's own or 0) the recompute's
+    ``KEPT_BYTES``, here the module's own or 0) the recompute's
     forward is gone: one fewer — and the moves its addressing needs:
     bitcast reshapes at head dim 128, transposes at 64, beside the two
     names on the forward's outputs (``name``: no op of the program) and,
@@ -523,7 +529,7 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
         monkeypatch.setattr(flash, "ONE_PASS_DQ_BYTES", 0)
         monkeypatch.setattr(flash, "ONE_PASS_DKV_BYTES", 0)
     if not kept:
-        monkeypatch.setattr(pretrain, "FLASH_KEPT_BYTES", 0)
+        monkeypatch.setattr(pretrain, "KEPT_BYTES", 0)
     cfg = _cfg(hidden_size=hidden, num_attention_heads=heads,
                num_key_value_heads=kv_heads, num_hidden_layers=1,
                remat=True, loss_chunks=2, use_pallas_attention=True)
@@ -539,6 +545,37 @@ def test_train_step_attention_is_the_kernels_alone(heads, kv_heads, hidden,
     assert found["pallas_call"] == kernels - kept, found
     assert set(found) == {"pallas_call", moves, "name"} | (
         {"reduce_precision"} if kept else set()), found
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "recomputed"])
+def test_train_step_delta_rule_is_the_kernels_alone(kept, monkeypatch):
+    """What the ``kda_chunk`` scope of a delta-rule step holds: the
+    recurrence's kernels — forward, the recompute's forward, backward:
+    THREE a run of layers; where full remat keeps the forward's outputs
+    (their bytes within ``KEPT_BYTES``, here the module's own or what
+    flash keeps and no more) the recompute's forward is gone: two —
+    beside the names on the forward's outputs (``name``: no op of the
+    program).  The row is whole blocks: no pad, no slice, no cast."""
+    pretrain = importlib.import_module("paddle_tpu.models.llama_pretrain")
+    cfg = _kda_cfg()
+    if not kept:
+        monkeypatch.setattr(pretrain, "KEPT_BYTES",
+                            pretrain.flash_output_bytes(
+                                2, 256, cfg.num_attention_heads,
+                                cfg.head_dim, cfg.dtype, 1))
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        step = make_train_step(cfg, mesh, lr=1e-2, optimizer="adafactor")
+        jaxpr = jax.make_jaxpr(step)(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    found = scope_primitives(jaxpr.jaxpr, "kda_chunk")
+    assert found["pallas_call"] == 3 - kept, found
+    assert set(found) == {"pallas_call", "name"}, found
+    # flash's decision was made first and is the same in both forms
+    assert scope_primitives(jaxpr.jaxpr, "attn")["pallas_call"] == 2
 
 
 def test_attention_head_dim_64_matches_the_composite():
