@@ -26,8 +26,24 @@ configuration alone.
 
 ``mla_dense`` and ``mla_moe`` are a layer of latent attention (MLA)
 before a dense SwiGLU MLP or before an expert layer (routed experts
-without dropping, ``ops/moe.py``, beside shared experts), on a residual
-of ``n = hc_mult`` STREAMS (manifold-constrained hyper-connections,
+without dropping, ``ops/moe.py``, beside shared experts).  What
+``hc_mult`` states is the residual they run on.  ``hc_mult == 1``, ONE
+stream — the block every model of the family is built on, the trunk
+carrying ``[b, s, C]``, no mixer leaf and no pass over streams:
+
+    x1 = x  + MLA(rms_norm(x; ln1))
+    x2 = x1 + F(rms_norm(x1; ln2))      F: the MLP or the expert layer
+      dense:   F(u) = (silu(u . w_gate) * (u . w_up)) . w_down
+      experts: s = sigmoid(u . w_router)  (fp32, ``n_routed_experts``
+               wide);  picks = the top ``num_experts_per_tok`` of s
+               g_e = routed_scaling_factor s_e / (sum of the picked s +
+                     1e-20)
+               F(u) = sum over the picks whose expert is HELD here of
+                      g_e E_e(u)  +  SwiGLU(u; ws_*)   (the shared
+                      experts as one SwiGLU of n_shared_experts x the
+                      experts' width)
+
+``hc_mult = n >= 2``, n STREAMS (manifold-constrained hyper-connections,
 arXiv:2512.24880 over arXiv:2409.19606).  A token's state is ``X [n,
 C]``; the trunk carries it flat, ``[b, s, n * C]`` (stream i is lanes
 ``i C .. (i + 1) C``): the table's row is copied into the n streams, the
@@ -48,12 +64,16 @@ kernels of ``ops/pallas/hc_mix.py`` where ``hc_mix.takes`` takes the
 shape, each reading the streams once; H_post and H_res — a few numbers
 a token — are XLA's in either form.
 
-    MLA on x = rms_norm(h; ln1):
+    MLA on x = rms_norm(h; ln1), in either form of the residual:
     q = rms_norm(x . w_qa; q_norm) . [w_qb_nope | w_qb_rope]   [H, 128 | 64]
+        (``q_lora_rank`` > 0), or, WITHOUT a latent (``q_lora_rank`` 0),
+    q = x . [w_q_nope | w_q_rope]                 (no norm of the query)
     [c | k_r] = x . w_kva  (kv_lora_rank | 64);  [k_nope | v] =
     rms_norm(c; kv_norm) . [w_kvb_k | w_kvb_v]                 [H, 128 | 128]
-    q_r, k_r rotated (rotate-half) at YaRN's blended frequencies, k_r ONE
-    vector a token for all heads;  S = (q_nope . k_nope^T + q_r . k_r^T)
+    q_r, k_r rotated (rotate-half; a published INTERLEAVED pairing is a
+    fixed permutation of the rope columns, which the leaves hold) at
+    ``rope_theta``'s frequencies or YaRN's blend of them, k_r ONE vector
+    a token for all heads;  S = (q_nope . k_nope^T + q_r . k_r^T)
     * (128 + 64)^-1/2 * mscale^2  — ``flash_attention_split``: two
     operand pairs, no 192-wide operand, no 32-fold copy of k_r.
 
@@ -200,20 +220,26 @@ def check(cfg) -> None:
         if not set(cfg.layer_types) <= set(MLA_KINDS):
             raise NotImplementedError(
                 f"layer_types {sorted(set(cfg.layer_types))}: the kinds "
-                f"{MLA_KINDS} carry hc_mult residual streams, the others "
-                "one; a trunk has one carry")
-        if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim,
-               cfg.v_head_dim) < 1 or cfg.qk_nope_head_dim != cfg.v_head_dim \
+                f"{MLA_KINDS} are one model's layers and mix with no "
+                "other (at hc_mult >= 2 they carry hc_mult residual "
+                "streams and a trunk has one carry; at hc_mult 1 the "
+                "kept flash outputs are reckoned at one value width)")
+        if min(cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim) < 1 \
+                or cfg.q_lora_rank < 0 \
+                or cfg.qk_nope_head_dim != cfg.v_head_dim \
                 or cfg.v_head_dim % 128 or cfg.qk_rope_head_dim % 2:
             raise ValueError(
-                "latent attention needs q_lora_rank, kv_lora_rank, "
-                "qk_rope_head_dim (even) and qk_nope_head_dim = v_head_dim "
-                "(whole lane tiles: flash_attention_split reads them where "
-                "the projections wrote them)")
-        if cfg.hc_mult < 2:
-            raise NotImplementedError(
-                f"hc_mult={cfg.hc_mult}: the latent-attention kinds run on "
-                "a residual of 2 or more streams")
+                "latent attention is built with kv_lora_rank > 0, "
+                "q_lora_rank > 0 (a query behind its own latent and norm) "
+                "or 0 (a direct query projection), qk_rope_head_dim even "
+                "and qk_nope_head_dim = v_head_dim in whole lane tiles "
+                "(flash_attention_split reads them where the projections "
+                "wrote them)")
+        if cfg.hc_mult < 1:
+            raise ValueError(
+                f"hc_mult={cfg.hc_mult}: the latent-attention kinds are "
+                "built on ONE residual stream (hc_mult 1: x + F(norm(x))) "
+                "or on hc_mult >= 2 streams behind their mixers")
         if "mla_moe" in cfg.layer_types and not (
                 0 < cfg.num_experts_per_tok <= cfg.n_routed_experts
                 and 0 < cfg.experts_held and cfg.n_shared_experts > 0
@@ -602,16 +628,21 @@ def _mamba_block(bp, x, cfg, mesh=None, seg=None):
 def _mla_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     c, n, heads = cfg.hidden_size, cfg.hc_mult, cfg.num_attention_heads
     maps = n * n + 2 * n
-    out = {"ln1": (c,), "w_qa": (c, cfg.q_lora_rank),
-           "q_norm": (cfg.q_lora_rank,),
-           "w_qb_nope": (cfg.q_lora_rank, heads * cfg.qk_nope_head_dim),
-           "w_qb_rope": (cfg.q_lora_rank, heads * cfg.qk_rope_head_dim),
+    nope, rope = heads * cfg.qk_nope_head_dim, heads * cfg.qk_rope_head_dim
+    # the query's two leaves, nope | rope columns apart, behind a latent
+    # or straight from the stream: ``flash_attention_split`` reads whole
+    # lane tiles where either projection wrote them
+    query = {"w_qa": (c, cfg.q_lora_rank), "q_norm": (cfg.q_lora_rank,),
+             "w_qb_nope": (cfg.q_lora_rank, nope),
+             "w_qb_rope": (cfg.q_lora_rank, rope)} if cfg.q_lora_rank \
+        else {"w_q_nope": (c, nope), "w_q_rope": (c, rope)}
+    out = {"ln1": (c,), **query,
            "w_kva": (c, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
            "kv_norm": (cfg.kv_lora_rank,),
-           "w_kvb_k": (cfg.kv_lora_rank, heads * cfg.qk_nope_head_dim),
+           "w_kvb_k": (cfg.kv_lora_rank, nope),
            "w_kvb_v": (cfg.kv_lora_rank, heads * cfg.v_head_dim),
            "wo": (heads * cfg.v_head_dim, c), "ln2": (c,)}
-    for pre in ("hc1", "hc2"):
+    for pre in ("hc1", "hc2") if n > 1 else ():
         out.update({pre + "_phi": (n * c, maps), pre + "_alpha": (3,),
                     pre + "_b": (maps,)})
     if kind == "mla_dense":
@@ -677,9 +708,13 @@ def _mla_attention(bp, x, cfg):
     dt, eps, heads = cfg.dtype, cfg.rms_norm_eps, cfg.num_attention_heads
     rope = cfg.qk_rope_head_dim
     with jax.named_scope("mla_q"):
-        qa = _rms_norm(x @ bp["w_qa"].astype(dt), bp["q_norm"], eps)
-        q = (qa @ bp["w_qb_nope"].astype(dt)).reshape(b, s, heads, -1)
-        q_r = (qa @ bp["w_qb_rope"].astype(dt)).reshape(b, s, heads, rope)
+        if "w_qa" in bp:
+            qa = _rms_norm(x @ bp["w_qa"].astype(dt), bp["q_norm"], eps)
+            w_nope, w_rope = bp["w_qb_nope"], bp["w_qb_rope"]
+        else:
+            qa, w_nope, w_rope = x, bp["w_q_nope"], bp["w_q_rope"]
+        q = (qa @ w_nope.astype(dt)).reshape(b, s, heads, -1)
+        q_r = (qa @ w_rope.astype(dt)).reshape(b, s, heads, rope)
     with jax.named_scope("mla_kv"):
         ckr = x @ bp["w_kva"].astype(dt)
         c = _rms_norm(ckr[..., :cfg.kv_lora_rank], bp["kv_norm"], eps)
@@ -1067,8 +1102,14 @@ def _hc_sublayer(bp, pre: str, x, fn, cfg):
 
 
 def _mla_block(bp, x, cfg, mesh=None, seg=None):
-    from .llama_pretrain import _rms_norm, _swiglu
+    """One ``mla_dense`` / ``mla_moe`` layer (the module docstring has
+    the equations): on x [b, s, C] each sublayer is added to the one
+    stream; on x [b, s, n C] it runs between its mixer's two halves."""
+    from .llama_pretrain import _residual, _rms_norm, _swiglu
     eps = cfg.rms_norm_eps
+
+    def attn(h):
+        return _mla_attention(bp, _rms_norm(h, bp["ln1"], eps), cfg)
 
     def ffn(h):
         y = _rms_norm(h, bp["ln2"], eps)
@@ -1078,8 +1119,10 @@ def _mla_block(bp, x, cfg, mesh=None, seg=None):
             return _swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"],
                            cfg.dtype)
     with jax.named_scope("block"):
-        x = _hc_sublayer(bp, "hc1", x, lambda h: _mla_attention(
-            bp, _rms_norm(h, bp["ln1"], eps), cfg), cfg)
+        if cfg.hc_mult == 1:
+            x = _residual(x, attn(x), cfg)
+            return _residual(x, ffn(x), cfg)
+        x = _hc_sublayer(bp, "hc1", x, attn, cfg)
         return _hc_sublayer(bp, "hc2", x, ffn, cfg)
 
 
@@ -1158,8 +1201,9 @@ def kept_outputs(cfg, batch: int, seq: int) -> Tuple[bool, bool]:
 
 def trunk(blocks, x, cfg, mesh):
     """x [b, s, h] through the layers in ``cfg.layer_types``' order.
-    The latent-attention kinds carry ``hc_mult`` streams, ``[b, s, n h]``:
-    the row that comes in is copied into each, and their sum goes out."""
+    At ``hc_mult`` >= 2 the latent-attention kinds carry that many
+    streams, ``[b, s, n h]``: the row that comes in is copied into each,
+    and their sum goes out."""
     from .llama_pretrain import _block_forward, _remat_wrap
     body = {"attention": _block_forward, "mamba": _mamba_block,
             "mla_dense": _mla_block, "mla_moe": _mla_block,

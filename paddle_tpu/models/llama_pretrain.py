@@ -99,10 +99,14 @@ class LlamaPretrainConfig:
     # kinds 'mla_dense' / 'mla_moe'): ``kv_lora_rank`` > 0 makes every
     # layer one of the two — the first ``first_k_dense_replace`` with the
     # dense MLP, the rest with the expert layer — unless ``layer_types``
-    # says otherwise.  The published keys keep their names.  The SHARE:
-    # the router is ``n_routed_experts`` wide (the published count) and
-    # this device holds ``experts_held`` of them, from ``expert_first``
-    # on (None: all of them).
+    # says otherwise.  The published keys keep their names.  ``hc_mult``
+    # 1 (the default) is the plain block on ONE residual stream, ``x +
+    # F(norm(x))`` twice; ``hc_mult`` >= 2 puts each sublayer behind a
+    # mixer over that many streams.  ``q_lora_rank`` 0 (the default) is
+    # a query projected straight from the stream, > 0 one behind its own
+    # latent and norm.  The SHARE: the router is ``n_routed_experts``
+    # wide (the published count) and this device holds ``experts_held``
+    # of them, from ``expert_first`` on (None: all of them).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0

@@ -171,6 +171,55 @@ def test_the_experts_tiles_in_the_expert_cell_only(monkeypatch):
         object(), {"tokens_per_step": 16384, "chips": 1}, {}, dense) is None
 
 
+PLAIN_MLA = "kanana-2-30b-a3b.pretrain-16k-mla-moe"
+
+
+def test_the_plain_latent_attention_cell_reads_by_its_family_s_costs(
+        monkeypatch):
+    """PR 56's cell: the experts' tiles against ``kanana_mla_moe``'s
+    ``expert_flops_per_token`` (1.5 pairs a token in six expert layers),
+    the flash kernels' against the ``attn_width`` its ``block_costs``
+    states (192-wide scores, 128-wide values, seven layers at 16,384),
+    the whole step against the sum."""
+    products = whole_step() + [
+        kernel("grouped_mm", 0.8, 0.9, "moe_experts", 4e12, 1e9),
+        kernel("grouped_mm_dw", 0.9, 1.0, "moe_experts", 2.5e12, 1e9),
+        kernel("moe_sum_pairs", 1.0, 1.1, "moe_combine", 9e12, 1e9)]
+    cell, got = read_all(monkeypatch, PLAIN_MLA, trace(products))
+    need = cell.family.expert_flops_per_token(cell.conf) * 16384
+    assert need == pytest.approx(9 * 2 * 2048 * 768 * 1.5 * 6 * 16384)
+    assert got["moe_experts_declared_per_needed.train"] == \
+        pytest.approx(6.5e12 / need)
+    attn = kernel_costs_kernels.flash_attn_train_flops_per_token(
+        cell.conf, 16384) * 16384
+    assert attn == 6 * 16384 * 7 * 32 * 160 * 16384
+    assert got["flash_attn_declared_per_needed.train"] == \
+        pytest.approx(3e12 / attn)
+    whole = kernel_costs.train_flops_per_token(cell.conf, 16384) * 16384
+    assert got["flops_declared_per_needed.train"] == \
+        pytest.approx((1e14 + 3e12 + 6.5e12 + 9e12) / whole)
+    assert set(got) == {"kernel_undeclared_pct.train",
+                        "flops_declared_per_needed.train",
+                        "flash_attn_declared_per_needed.train",
+                        "moe_experts_declared_per_needed.train"}
+    # its own reader: the five scopes' share where an ``mla_*`` scope is
+    # named (0.1 s of ``attn`` + 0.2 s of ``mla_q`` of 1.3 s), nothing on
+    # a program that names none — the dense cell's ``attn`` alone
+    mla = products + [("%fusion.2 = fusion()", 1.1, 1.3,
+                       BODY + "mla_q/dot_general", "convolution fusion",
+                       1e12, 1e9)]
+    view = trace(mla).named(*xplane_meta.names_of(cell))
+    monkeypatch.setattr(xplane_meta, "of_cell", lambda c, t: view)
+    by = view.self_time_by("scope")
+    assert reader("mla_mixer_pct.train")(object(), {}, {}, cell) == \
+        pytest.approx(100 * (by["attn"] + by["mla_q"]) / sum(by.values()))
+    assert by["mla_q"] == pytest.approx(0.2)
+    dense = harness.find_cell(DENSE)
+    plain = trace(products).named(*xplane_meta.names_of(dense))
+    monkeypatch.setattr(xplane_meta, "of_cell", lambda c, t: plain)
+    assert reader("mla_mixer_pct.train")(object(), {}, {}, dense) is None
+
+
 def test_the_new_metrics_are_entered_as_the_issue_lists_them():
     bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
     new = {m["name"]: m for m in bench["per_layer"]
@@ -183,14 +232,15 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
     conv = "lfm2-24b-a2b.pretrain-8k-conv-moe"
     # ... and PR 52 its cell, with the chunked delta rule's declared FLOPs
     kda = "solar-open2-250b.pretrain-kda-moe"
-    every = [DENSE, HYBRID, EXPERT, window, conv, kda]
+    # ... and PR 56 its cell, which brings no ratio of its own
+    every = [DENSE, HYBRID, EXPERT, window, conv, kda, PLAIN_MLA]
     assert {n: m["workloads"] for n, m in new.items()} == {
         "kernel_undeclared_pct.train": every,
         "flops_declared_per_needed.train": every,
         "flash_attn_declared_per_needed.train": every,
         "ssd_scan_bytes_declared_per_needed.train": [HYBRID],
         "moe_experts_declared_per_needed.train": [EXPERT, window, conv,
-                                                  kda],
+                                                  kda, PLAIN_MLA],
         "flash_win_declared_per_needed.train": [window],
         "short_conv_bytes_declared_per_needed.train": [conv],
         "kda_chunk_declared_per_needed.train": [kda]}
@@ -213,7 +263,9 @@ def test_the_new_metrics_are_entered_as_the_issue_lists_them():
         # PR 54: the five that move ``setup_s``, in every cell
         "setup_step_compile_s.train", "setup_trace_lower_s.train",
         "setup_small_programs_s.train", "setup_cache_miss_pct.train",
-        "setup_loader_start_s.train"]
-    for m in bench["per_layer"][-5:]:
+        "setup_loader_start_s.train",
+        # PR 56: the latent-attention mixer's share, in its cell
+        "mla_mixer_pct.train"]
+    for m in bench["per_layer"][-6:-1]:
         assert (m["moves"], m["better"], m["layer"], m["workloads"]) == (
             "setup_s", "lower", "set-up", every)

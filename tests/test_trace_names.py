@@ -252,6 +252,74 @@ def test_train_step_of_the_expert_kinds_carries_the_family_s_scopes():
         "recompute"}
 
 
+def test_train_step_of_the_plain_latent_attention_kinds_names_no_mixer():
+    """``mla_dense`` / ``mla_moe`` on ONE residual stream with a direct
+    query (``hc_mult`` 1, ``q_lora_rank`` 0: the fields' defaults): the
+    seven scopes of the family ``kanana_mla_moe`` beside the base
+    vocabulary's name the plain block's work exactly as they name the
+    mHC block's — ``mla_q`` is still the query's, ``rope``, ``attn`` and
+    ``attn_out`` attention's, the dense lead keeps ``mlp``, the routed
+    path its two bounds — and ``hc_pre`` / ``hc_post`` appear nowhere."""
+    from benchmark.models import kanana_mla_moe
+    cfg = _cfg(remat=True, loss_chunks=2, hidden_size=128,
+               intermediate_size=256, num_hidden_layers=2,
+               num_attention_heads=2, num_key_value_heads=2,
+               kv_lora_rank=64, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, first_k_dense_replace=1,
+               rope_theta=1e6, moe_intermediate_size=128, n_routed_experts=8,
+               experts_held=2, expert_first=2, n_shared_experts=2,
+               num_experts_per_tok=3, routed_scaling_factor=2.448)
+    assert cfg.layer_types == ("mla_dense", "mla_moe")
+    assert (cfg.hc_mult, cfg.q_lora_rank) == (1, 0)
+    mesh = _mesh()
+    with mesh:
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), mesh))
+        opt = jax.eval_shape(init_adafactor_state, params)
+        low = make_train_step(cfg, mesh, lr=1e-2,
+                              optimizer="adafactor").lower(
+            params, opt, jax.ShapeDtypeStruct((2, 257), jnp.int64))
+    names = scope_names(low)
+    want = (BLOCK - {"attn_qkv"}) | {"embed", "layer_scan", "attn",
+                                     "loss_head", "optimizer"} \
+        | set(kanana_mla_moe.SCOPES)
+    assert want <= names, want - names
+    assert not {"hc_pre", "hc_post", "attn_qkv"} & names
+    assert set(kanana_mla_moe.SCOPES) <= family_names()[0]
+    paths = re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))
+    assert not [p for p in paths if "/hc_" in p or p.startswith("hc_")]
+    names_of = xplane_meta.SCOPES + kanana_mla_moe.SCOPES
+    for scope, kernel in (("moe_experts", "grouped_mm"),
+                          ("moe_experts", "grouped_mm_dw"),
+                          ("moe_combine", "moe_sum_pairs"),
+                          ("moe_dispatch", "moe_sum_pairs"),
+                          ("attn", "flash_fwd"), ("attn", "flash_bwd_dkv")):
+        assert any(p.endswith(f"{scope}/{kernel}/pallas_call")
+                   for p in paths), kernel
+        assert xplane_meta.kernel_of(
+            f"jit(step)/block/{scope}/{kernel}/pallas_call",
+            xplane_meta.KERNELS + kanana_mla_moe.KERNELS) == kernel
+    # the direct query's two products are ``mla_q``'s, in the forward,
+    # the remat's forward and the backward; the block's two residual adds
+    # are ``block``'s own
+    for phase in ("block/", "checkpoint/rematted_computation/block/",
+                  "checkpoint/block/"):
+        assert any(p.startswith(phase + "mla_q/") and "dot_general" in p
+                   for p in paths), phase
+    for p in paths:
+        if "/mla_q/" in p or "/mla_kv/" in p:
+            assert xplane_meta.scope_of(p, names_of) in ("mla_q", "mla_kv")
+    # the routed path on its two bounds, each under a scope outside the
+    # family's, as in the mHC block
+    routed = [p for p in paths if "moe_bound_" in p]
+    for bound in ("moe_bound_load", "moe_bound_all"):
+        assert bound not in names_of
+        assert any(f"/{bound}/moe_experts/" in p for p in routed), bound
+    for p in routed:
+        assert xplane_meta.scope_of(p, names_of) in (
+            "moe_dispatch", "moe_experts", "moe_combine"), p
+
+
 def test_train_step_of_the_window_kinds_carries_the_family_s_scopes():
     """``gqa_moe_global`` / ``gqa_moe_window``: the base vocabulary's
     attention scopes (``rope`` in the window layers only) and the four
