@@ -16,7 +16,8 @@ once a group; ``flash_bwd_dkv`` sums a group's query heads into the
 shared dK/dV block in fp32 (innermost grid axis) and casts once.
 
 The backward visits a (q block, k block) pair ONCE where it can, by a
-three-way rule of the shapes alone (``_flash_bwd_vjp``; no flag):
+rule of the shapes alone (``_flash_bwd_vjp``; no flag) — two budgets of
+fp32 sums that wait in VMEM across grid steps, three outcomes:
 
 (a) ``group*S*d*4 B <= ONE_PASS_DQ_BYTES`` (4 MiB) — KEY-major:
     ``flash_bwd_dkv`` holds dS^T for every pair it visits, so it adds
@@ -27,22 +28,32 @@ three-way rule of the shapes alone (``_flash_bwd_vjp``; no flag):
     form (:func:`flash_attention_split`) goes by this rule too: in one
     pass ``flash_bwd_dkv`` sums dS k2 into a second fp32 scratch beside
     dQ's and returns (dk, dv, dk2, dq, dq2) — eight products, three of
-    them d2 deep.
-(b) else, dense and windowed forms, ``2*S*lanes(d)*4 B <=
-    ONE_PASS_DKV_BYTES`` (16 MiB; ``lanes(d)`` is d rounded up to 128) —
-    QUERY-major, on ``flash_bwd_dq``'s call site, grid and NAME: a q
-    block's dQ is whole inside one grid step (delta stays in the step),
-    and what waits across steps is the fp32 dK and dV of ONE KV head,
-    two ``[S, d]`` scratches whatever the group, zeroed at the KV
-    head's first step and cast into the whole-row ``dk`` / ``dv`` blocks
-    at its last.  The same five products and one exp pass a pair;
-    ``flash_bwd_dkv`` does not run.  VMEM, asked by the shapes: K and V,
-    the dk and dv blocks (each pair twice, the pipeline's buffers) and
-    the two sums — 48 MiB at S 16,384, d 128, + 8 for the tiles.
+    them d2 deep.  The dense cell (S 2,048, a group of two: 2 MiB) and
+    the 8k split cell (S 8,192, a group of one: 4 MiB) sit here.
+(b) else, by what waits within ``ONE_PASS_DKV_BYTES`` (16 MiB;
+    ``lanes(d)`` is d rounded up to 128), each form in the loop order
+    whose body it has:
+    the SPLIT form, ``group*S*(lanes(d) + lanes(d2))*4 B`` — the SAME
+    key-major pass as (a), the group's fp32 dQ and dQ2 counted as they
+    lie in VMEM.  The plain-MLA cell sits here (S 16,384, d 128, d2 64,
+    a group of one: 16 MiB exactly; 64 MiB of whole rows and sums
+    resident, 72 asked; PERF.md §6, PR 57);
+    the dense and windowed forms, ``2*S*lanes(d)*4 B`` — QUERY-major, on
+    ``flash_bwd_dq``'s call site, grid and NAME: a q block's dQ is whole
+    inside one grid step (delta stays in the step), and what waits
+    across steps is the fp32 dK and dV of ONE KV head, two ``[S, d]``
+    scratches whatever the group, zeroed at the KV head's first step and
+    cast into the whole-row ``dk`` / ``dv`` blocks at its last.  The
+    same five products and one exp pass a pair; ``flash_bwd_dkv`` does
+    not run.  VMEM, asked by the shapes: K and V, the dk and dv blocks
+    (each pair twice, the pipeline's buffers) and the two sums — 48 MiB
+    at S 16,384, d 128, + 8 for the tiles.  The window cell's two forms
+    (S 16,384), the hybrid, convolution and delta-rule cells' attention
+    layers (S 8,192, groups of 4 and 8) sit here.
 (c) else the two kernels: ``flash_bwd_dq`` (S, dP, dQ, and delta) runs
     first and ``flash_bwd_dkv`` forms dV and dK — seven products and two
-    exp passes a pair.  The split form past (a) stays here (no cell runs
-    it there).
+    exp passes a pair, eleven in the split form.  No cell sits here (a
+    row of 32,768 would, in either form).
 
 So ``flash_bwd_dkv`` names three amounts of work (two kernels, one pass,
 one pass split) and ``flash_bwd_dq`` two (the first of two kernels, the
@@ -459,18 +470,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, last_ref, *refs,
 
 # The backward runs in ONE pass (``flash_bwd_dkv`` sums dQ too and
 # ``flash_bwd_dq`` does not run) where the fp32 dQ of one KV head's
-# group, group*S*d*4 B, is at most this much VMEM scratch; longer rows
-# and wider groups keep the two kernels.  Measured at 2 MiB (S 2048,
-# group 2, d 128: 74.1 -> 53.2 ms a step) and, by the host's clock, at
-# 4 MiB (S 4096: 71.5 -> 49.6); compiled for a described v5e at 4 MiB
-# with S 8192 (PERF.md §6, PR 30).  The split form at S 8192, a group of
-# one, sits on it (PERF.md §6, PR 42).
+# group, group*S*d*4 B, is at most this much VMEM scratch — rule (a),
+# dense and split form alike.  Measured at 2 MiB (S 2048, group 2, d 128:
+# 74.1 -> 53.2 ms a step) and, by the host's clock, at 4 MiB (S 4096:
+# 71.5 -> 49.6); compiled for a described v5e at 4 MiB with S 8192
+# (PERF.md §6, PR 30).  The split form at S 8192, a group of one, sits on
+# it (PERF.md §6, PR 42).
 ONE_PASS_DQ_BYTES = 4 << 20
-# Past it, the dense and the windowed form still run ONE pass where the
-# fp32 dK and dV of ONE KV head, 2*S*lanes(d)*4 B whatever the group, are
-# at most this much VMEM scratch: the QUERY-major pass, under the name
-# ``flash_bwd_dq`` (its call site and grid), and ``flash_bwd_dkv`` does
-# not run.  The window cell sits on it (S 16,384, d 128; PERF.md §6, PR 45)
+# Past it a form still runs ONE pass where the fp32 sums that wait across
+# ITS pass's grid steps are at most this much VMEM scratch — rule (b).
+# The dense and the windowed form: the dK and dV of ONE KV head,
+# 2*S*lanes(d)*4 B whatever the group — the QUERY-major pass, under the
+# name ``flash_bwd_dq`` (its call site and grid), and ``flash_bwd_dkv``
+# does not run; the window cell sits on it (S 16,384, d 128; PERF.md §6,
+# PR 45).  The split form: the dQ and dQ2 of the group,
+# group*S*(lanes(d) + lanes(d2))*4 B — rule (a)'s key-major pass at a
+# longer row; the plain-MLA cell sits on it (S 16,384, d 128, d2 64, a
+# group of one: exactly this; PERF.md §6, PR 57)
 ONE_PASS_DKV_BYTES = 16 << 20
 # what Mosaic gives a kernel unless the call asks for more (v5e: of 128 MiB)
 _DEFAULT_VMEM_LIMIT = 16 << 20
@@ -780,13 +796,17 @@ def _flash_bwd_vjp(causal, sm_scale, window, res, dout):
         out_shape.append(jax.ShapeDtypeStruct(second[0].shape, jnp.float32))
         out_specs.append(_tile_spec(bk, d2, tile))
     params = _row_vmem(s, d, qr.dtype.itemsize, 5 if second else 2)
-    # by the fp32 dQ of a KV head's group, dense and split form alike: the
-    # split form's dQ2 follows from the shapes and is asked of VMEM below
-    one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES
-    # past it by the fp32 dK and dV of ONE KV head, query-major (the split
-    # form keeps the two kernels there: no cell runs it)
-    by_query = not one_pass and not second \
-        and 2 * s * _lanes(d) * 4 <= ONE_PASS_DKV_BYTES
+    # (a) by the fp32 dQ of a KV head's group, dense and split form alike
+    # (the split form's dQ2 follows from the shapes and is asked of VMEM
+    # below); (b) past it by the fp32 sums that wait across the grid steps
+    # of the pass the form has past (a): the split form's key-major one —
+    # the group's dQ AND dQ2, each row whole lane tiles —, the dense and
+    # windowed forms' query-major one — dK and dV of ONE KV head
+    waits = group * s * (_lanes(d) + _lanes(d2)) * 4 if second \
+        else 2 * s * _lanes(d) * 4
+    one_pass = group * s * d * 4 <= ONE_PASS_DQ_BYTES \
+        or bool(second) and waits <= ONE_PASS_DKV_BYTES
+    by_query = not one_pass and waits <= ONE_PASS_DKV_BYTES
     pairs = b * h * _pairs(s, bq, causal, window)
     it = qr.dtype.itemsize
     # on a pair's scores, either kernel: the scale, s - lse, dP - delta
@@ -930,12 +950,13 @@ def flash_attention_split(q, q2, k, k2, v, sm_scale: float):
     d2]``, ONE key a token for all heads (MLA's rotated part: d 128, d2
     64).  Both products are summed in fp32 before the one exp pass; no
     ``[.., d + d2]`` operand and no h-fold copy of k2 is made.  The
-    backward returns five gradients, in ONE pass by the dense form's
-    rule of shapes (``ONE_PASS_DQ_BYTES``: ``flash_bwd_dkv`` sums dQ and
-    dQ2 too), from the two kernels past it (the query-major pass is the
-    dense and the windowed form's: no cell runs a split row past that
-    budget); a head's part of dk2 leaves
-    the kernel in fp32 and the heads are summed outside it.  Same kernel
+    backward returns five gradients, in ONE key-major pass
+    (``flash_bwd_dkv`` sums dQ and dQ2 too) where the group's fp32 dQ is
+    within ``ONE_PASS_DQ_BYTES`` — the dense form's rule: the 8k cell —
+    or its dQ and dQ2 together, each row whole lane tiles, within
+    ``ONE_PASS_DKV_BYTES`` — the plain-MLA cell's row of 16,384 —, from
+    the two kernels past both; a head's part of dk2 leaves the kernel in
+    fp32 and the heads are summed outside it.  Same kernel
     bodies, names and blocks as :func:`flash_attention`."""
     b, s, h, d = q.shape
     if d % 128 or k.shape != q.shape or v.shape != q.shape or \

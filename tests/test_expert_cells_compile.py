@@ -17,8 +17,8 @@ import jax.numpy as jnp
 
 from _tpu_compile import (KERNEL, ROWS_8K, _cell_step,  # noqa: F401
                           _experts_placed, _flash_module, _padded_from,
-                          _placed, _routing_sorts, _sds, _text, compiled,
-                          one_chip, topo)
+                          _placed, _routing_sorts, _sds, _text, _two_kernels,
+                          compiled, one_chip, topo)
 
 
 def test_train_step_of_the_expert_cell(one_chip, compiled):
@@ -157,13 +157,13 @@ def test_flash_attention_split_at_the_expert_cell_s_shapes(one_chip,
     VMEM the whole-row operands ask for; five gradients from TWO
     kernels — a head's fp32 dQ is 4 MiB, ``ONE_PASS_DQ_BYTES`` exactly,
     so ``flash_bwd_dkv`` sums dQ and dQ2 too (40 MiB of VMEM asked) and
-    ``flash_bwd_dq`` is absent — or, the rule set to 0 bytes, from the
-    three a longer row keeps."""
+    ``flash_bwd_dq`` is absent — or, both budgets set to 0 bytes, from
+    the three a row past both keeps."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_split
     b, s = ROWS_8K
     assert s * 128 * 4 == _flash_module().ONE_PASS_DQ_BYTES
     if kernels == 3:
-        monkeypatch.setattr(_flash_module(), "ONE_PASS_DQ_BYTES", 0)
+        _two_kernels(monkeypatch)
     wide = _sds(one_chip, (b, s, 32, 128), jnp.bfloat16)
     text = _text(jax.grad(
         lambda *a: flash_attention_split(*a, 0.1).astype(jnp.float32).sum(),

@@ -102,6 +102,8 @@ _paged_q8 = lambda *a: paged_decode_attention_q8(*a, force_kernel=True)
 Q2, Q8, KV1 = z((1, 2048, 2, 128)), z((1, 2048, 8, 128)), z((1, 2048, 1, 128))
 SPLIT = (z((1, 1024, 2, 128)), z((1, 1024, 2, 64)), z((1, 1024, 2, 128)),
          z((1, 1024, 64)), z((1, 1024, 2, 128)))
+SPLIT_16K = (z((1, 16384, 1, 128)), z((1, 16384, 1, 64)),
+             z((1, 16384, 1, 128)), z((1, 16384, 64)), z((1, 16384, 1, 128)))
 VARLEN = (z((1, 1024, 4, 128)), z((1, 1024, 2, 128)), z((1, 1024, 2, 128)),
           z((1, 1024), I32))
 SSD = (z((1, 2, 256, 4, 64)), z((1, 2, 256, 4), F32), z((1, 2, 256, 4), F32),
@@ -199,7 +201,16 @@ CASES = [
     # + dk2 in fp32 4 * 1024 * 2 * 64
     ("flash_bwd_dkv, split one pass", "flash_bwd_dkv", grad_of(_split, 5),
      SPLIT, 2_625_634_304, 5_513_216, 1_572_864),
-    # past the budget (here: the budget at 0 bytes) the split form keeps
+    # the plain-MLA cell's row, ONE head of it: a head's fp32 dQ at S
+    # 16,384 is 8 MiB, past the first budget, and dQ with dQ2 the second's
+    # 16 MiB exactly — the SAME pass and the same declaration (PR 57).
+    # 32 * 33 / 2 = 528 pairs: 528 * 512^2 * 1669 + delta 2 * 16384 * 128;
+    # one visit of 16384 * (2 * (3 * 128 + 64) + 4) + 2 * 16384 * (4 * 128
+    # + 64 + 192) + dk2 in fp32 4 * 16384 * 64
+    ("flash_bwd_dkv, split one pass at 16k", "flash_bwd_dkv",
+     grad_of(_split, 5), SPLIT_16K, 231_013_875_712, 44_105_728,
+     138_412_032),
+    # past both budgets (here: both at 0 bytes) the split form keeps
     # the two kernels.  6 * 512^2 * (2 * (384 + 128) + 5) + 2 * 2 * 1024 *
     # 128; bytes 2 * 1024 * (1024 + 512 + 2 * 128 q2 dq2 + 64) + 8 * 2 *
     # 1024
@@ -397,6 +408,8 @@ def test_one_pass_and_two_kernels_declare_different_work():
     # ``flash_bwd_dq``'s 1,029 beside them)
     assert per_score("flash_bwd_dkv, split one pass", 2, 3, 1024) == \
         2 * (5 * 128 + 3 * 64) + 5 == 1669
+    assert per_score("flash_bwd_dkv, split one pass at 16k", 1, 528,
+                     16384) == 1669
     assert per_score("flash_bwd_dkv, split", 2, 3, 1024) == \
         2 * (4 * 128 + 2 * 64) + 5 == 1285
 

@@ -12,8 +12,8 @@ import collections
 import re
 
 from _tpu_compile import (KERNEL, _cell_step, _experts_placed,  # noqa: F401
-                          _padded_from, _routing_sorts, compiled, one_chip,
-                          topo)
+                          _flash_module, _padded_from, _routing_sorts,
+                          compiled, one_chip, topo)
 
 
 def test_train_step_of_the_plain_latent_attention_cell(one_chip, compiled):
@@ -22,8 +22,9 @@ def test_train_step_of_the_plain_latent_attention_cell(one_chip, compiled):
     residual stream, every published width, a direct query, 32 of 128
     experts beside the two shared ones, 1 x 16,384 tokens — fits a
     described v5e with NO compiler rematerialization, runs attention as
-    the split-score flash kernels and the routed path as the grouped
-    products, names no mixer, and routes once a layer a step."""
+    the split-score flash kernels (a block pair visited once a pass) and
+    the routed path as the grouped products, names no mixer, and routes
+    once a layer a step."""
     from benchmark import harness
     cell = harness.find_cell("kanana-2-30b-a3b.pretrain-16k-mla-moe")
     assert cell.conf["num_hidden_layers"] == 7 and \
@@ -36,11 +37,15 @@ def test_train_step_of_the_plain_latent_attention_cell(one_chip, compiled):
         text))
     # either kind's loop: ``flash_fwd`` once — full remat keeps its
     # outputs, 7 x 136.3 MB = 954 MB within ``KEPT_BYTES`` — and the
-    # split form's backward as its TWO kernels: at S 16,384 a head's fp32
-    # dQ is past ``flash_attention.ONE_PASS_DQ_BYTES`` (the 8k cell's is
-    # one pass)
+    # split form's backward in ONE key-major pass (PR 57): at S 16,384 a
+    # head's fp32 dQ, 8 MiB, is past ``flash_attention.ONE_PASS_DQ_BYTES``
+    # (the 8k cell's is within it) and its dQ and dQ2, a lane tile each
+    # a row, are ``ONE_PASS_DKV_BYTES`` exactly — ``flash_bwd_dq`` absent
+    flash = _flash_module()
+    assert 16384 * 128 * 4 > flash.ONE_PASS_DQ_BYTES and \
+        16384 * (128 + 128) * 4 == flash.ONE_PASS_DKV_BYTES
     assert (calls["flash_fwd"], calls["flash_bwd_dq"],
-            calls["flash_bwd_dkv"]) == (2, 2, 2)
+            calls["flash_bwd_dkv"]) == (2, 0, 2)
     # the routed path ON EACH OF ITS TWO BOUNDS (57,344 rows where the
     # load's tiles fit them — twice the 24,576 pairs top-6 of 128 sends
     # to 32 experts, and a tile an expert — 106,496 otherwise): 2
@@ -50,7 +55,7 @@ def test_train_step_of_the_plain_latent_attention_cell(one_chip, compiled):
     # sum backward
     assert (calls["grouped_mm"], calls["grouped_mm_dw"],
             calls["moe_sum_pairs"]) == (2 * 5, 2 * 2, 2 * 2)
-    assert text.count(KERNEL) == sum(calls.values()) == 6 + 2 * 9
+    assert text.count(KERNEL) == sum(calls.values()) == 6 + 2 * 9 - 2
     assert not [k for k in calls if k.startswith("hc_")]
     assert "hc_pre" not in text and "hc_post" not in text
     assert len(re.findall(r" conditional\(", text)) == 3

@@ -97,8 +97,8 @@ def test_flash_attention_split_forward_and_five_gradients(monkeypatch, s, h,
     """The forward and the five gradients (dk2 is the SUM over the
     heads) against autodiff of the plain form, the backward in ONE pass
     — ``flash_bwd_dkv`` sums dQ and dQ2 too; ``flash_bwd_dq`` does not
-    run — AND against the two kernels on the same inputs (the budget set
-    to 0 bytes: the module constant, no flag)."""
+    run — AND against the two kernels on the same inputs (the budgets set
+    to 0 bytes: the module constants, no flag)."""
     fa = _flash_module()
     *args, co = _split_inputs(2, s, h, 128, 64, dtype)
     co = co.astype(jnp.float32)
@@ -112,7 +112,7 @@ def test_flash_attention_split_forward_and_five_gradients(monkeypatch, s, h,
         _concatenated_attention(*f32, scale), atol=out_tol, rtol=out_tol)
     one, kernels = _split_grads(fa.flash_attention_split, *args, co, scale)
     assert kernels == ["flash_fwd", "flash_bwd_dkv"]
-    monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    _two_kernels(monkeypatch, fa)
     two, kernels = _split_grads(fa.flash_attention_split, *args, co, scale)
     assert kernels == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     want, _ = _split_grads(_concatenated_attention, *f32, co, scale)
@@ -133,11 +133,11 @@ def test_flash_attention_split_forward_and_five_gradients(monkeypatch, s, h,
 @pytest.mark.parametrize("kernels", [2, 3])
 def test_flash_attention_split_makes_no_wide_operand(monkeypatch, kernels):
     """No ``[.., 192]`` operand and no h-fold copy of the shared key
-    reaches the kernels, one pass (2) or two kernels (3: the budget set
+    reaches the kernels, one pass (2) or two kernels (3: the budgets set
     to 0 bytes): they take the five arrays as they are."""
     fa = _flash_module()
     if kernels == 3:
-        monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+        _two_kernels(monkeypatch, fa)
     q, q2, k, k2, v, _ = _split_inputs(1, 512, 4, 128, 64)
     text = str(jax.make_jaxpr(jax.grad(
         lambda *a: fa.flash_attention_split(*a, 0.1).sum(),

@@ -461,18 +461,23 @@ def test_routed_ffn_relu_at_the_window_cell_s_shapes():
     assert all(worst < 0.5 for _, worst in errs.values()), errs
 
 
-def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
-    """Latent attention as ``xing4.0-29b-a4b.pretrain-8k-moe`` runs it:
-    2 x 8192, heads of 128 | 64 | 128, one shared rotated key — scores
-    from two operand pairs, five gradients, the backward in ONE pass (a
-    head's fp32 dQ is 4 MiB: ``ONE_PASS_DQ_BYTES``) against the plain
-    form and against the two kernels (the rule set to 0 bytes).  The
-    composite holds [S, S] a head, so it is asked for two heads of the
-    first row."""
+@pytest.mark.parametrize("b,s,at_once", [(2, 8192, 2), (1, 16384, 1)],
+                         ids=["expert_cell_8k", "plain_latent_cell_16k"])
+def test_flash_split_at_the_latent_cells_shapes(monkeypatch, b, s, at_once):
+    """Latent attention as ``xing4.0-29b-a4b.pretrain-8k-moe`` (2 x 8192)
+    and ``kanana-2-30b-a3b.pretrain-16k-mla-moe`` (1 x 16,384) run it:
+    heads of 128 | 64 | 128, one shared rotated key — scores from two
+    operand pairs, five gradients, the backward in ONE key-major pass (a
+    head's fp32 dQ is 4 MiB at 8k: ``ONE_PASS_DQ_BYTES``; its dQ and dQ2
+    are 16 MiB at 16k: ``ONE_PASS_DKV_BYTES``, 72 MiB of VMEM asked)
+    against the plain form and against the two kernels (both budgets set
+    to 0 bytes).  The composite holds [S, S] a head, so it is asked for
+    ``at_once`` heads at a time of the first row."""
     import importlib
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    b, s, h = 2, 8192, 4
-    assert s * 128 * 4 == fa.ONE_PASS_DQ_BYTES
+    h = 4
+    assert (s * 128 * 4 <= fa.ONE_PASS_DQ_BYTES) == (s == 8192) and \
+        s * (128 + 128) * 4 <= fa.ONE_PASS_DKV_BYTES
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     bf = jnp.bfloat16
     q = jax.random.normal(ks[0], (b, s, h, 128), bf)
@@ -491,6 +496,7 @@ def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
 
     one = grads()
     monkeypatch.setattr(fa, "ONE_PASS_DQ_BYTES", 0)
+    monkeypatch.setattr(fa, "ONE_PASS_DKV_BYTES", 0)
     for got, want in zip(one, grads()):
         # dk, dk2 and dv the same sums, dq and dq2 the same terms from a
         # product turned round
@@ -507,7 +513,7 @@ def test_flash_split_8k_at_the_expert_cell_s_shapes(monkeypatch):
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
     f32 = [x[:1].astype(jnp.float32) for x in (q, q2, k, k2, v)]
     dk2_sum = 0.0
-    for heads in (slice(0, 2), slice(2, 4)):
+    for heads in (slice(at, at + at_once) for at in range(0, h, at_once)):
         cut = [f32[0][:, :, heads], f32[1][:, :, heads], f32[2][:, :, heads],
                f32[3], f32[4][:, :, heads]]
         want, ref_vjp = jax.vjp(plain, *cut)
